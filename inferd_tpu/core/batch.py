@@ -59,7 +59,11 @@ class BatchedEngine:
         kv_blocks: int = 0,
     ):
         self.cfg = cfg
-        self.params = params
+        # one host->device transfer, here: a stage checkpoint loads as numpy
+        # (parallel.stages.load_stage_checkpoint), and numpy leaves handed to
+        # a jit are copied to the device again on EVERY call — the whole
+        # model per token on a chip. Arrays already on a device stay put.
+        self.params = jax.device_put(params)
         self.lanes = lanes
         self.max_len = max_len
         self.sampling = sampling_cfg or SamplingConfig()
@@ -164,8 +168,8 @@ class BatchedEngine:
 
             Serial over tokens by data dependency; per-lane PRNG chains
             split exactly like the per-step path, so the emitted tokens
-            are bit-identical to `s` calls of _decode_all. Over a
-            tunneled/remote device this turns s host round trips into one —
+            are bit-identical to `s` calls of _decode_all. This
+            turns s host dispatches and syncs into one —
             the device-rate path for throughput serving and the batched
             bench. The scan body is the SHARED multi-step inner loop
             (models/qwen3.decode_k — one definition for the solo, batched,

@@ -304,8 +304,8 @@ class Engine:
 
         `chunk` > 1 fuses up to that many decode steps per dispatch (one
         compiled scan instead of N host round trips — the solo analogue of
-        BatchedEngine's fused decode; kills the per-step host RTT on
-        remote/tunneled devices). Tokens are bit-identical to chunk=1: the
+        BatchedEngine's fused decode; removes the per-step host dispatch
+        and sync). Tokens are bit-identical to chunk=1: the
         in-graph key chain equals the host loop's, and an EOS mid-chunk
         just discards the chunk's tail (bounded waste, like the batched
         engine)."""

@@ -462,8 +462,9 @@ def _paged_decode_kernel(
     meta_ref,  # SMEM scalar-prefetch [B, 3] int32: (qpos, kv_len, window)
     sink_ref,  # SMEM [Nkv, G] f32 (whole array) — sinks (NEG_INF = none)
     q_ref,  # VMEM [1, 1, g_pad, D] — one (lane, kv head)'s query group
-    k_ref,  # VMEM [1, bs, 1, D] — ONE pool block, fetched VIA THE TABLE
-    v_ref,  # VMEM [1, bs, 1, D]
+    k_ref,  # VMEM [1, bs, D] — ONE head's columns of ONE pool block,
+    #         fetched VIA THE TABLE from the [NB, bs, Nkv*D] view of the pool
+    v_ref,  # VMEM [1, bs, D]
     o_ref,  # VMEM [1, 1, g_pad, D]
     m_scr,  # VMEM scratch [g_pad, 1] f32 — running max across chain blocks
     l_scr,  # VMEM scratch [g_pad, 1] f32 — running denominator
@@ -513,8 +514,8 @@ def _paged_decode_kernel(
         q = q_ref[0, 0]  # [g_pad, D]
         # compressed-KV pools (cfg.kv_dtype): the narrow bytes are what the
         # pipeline fetched; upcast in-register — dequant-fused, in-kernel
-        kb = k_ref[0, :, 0, :].astype(q.dtype)  # [bs, D]
-        vb = v_ref[0, :, 0, :].astype(q.dtype)
+        kb = k_ref[0].astype(q.dtype)  # [bs, D]
+        vb = v_ref[0].astype(q.dtype)
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [g_pad, bs]
@@ -571,7 +572,15 @@ def paged_decode_gqa(
     The block table and the per-lane (qpos, kv_len, window) meta ride as
     SCALAR-PREFETCH operands (pltpu.PrefetchScalarGridSpec), so the
     K/V BlockSpec index maps read `tbl[b, j]` and Pallas pipelines each
-    chain block's DMA directly from its pool slot in HBM."""
+    chain block's DMA directly from its pool slot in HBM.
+
+    The pools are read through their [NB, bs, Nkv*D] view (a reshape of a
+    contiguous array: no copy, and no other path sees it): head h of a
+    block is then the column range [h*D, (h+1)*D), a (bs, D) block whose
+    last two dimensions Mosaic's (8, 128) tiling accepts whenever
+    bs % 8 == 0 and D % 128 == 0 — a (bs, 1, D) block of the 4-D pool,
+    one head out of Nkv on the second-to-last axis, is refused. Narrower
+    heads or odd block sizes raise the compiler's message at lowering."""
     b, s, nq, d = q.shape
     if s != 1:
         raise ValueError(f"paged_decode_gqa is S == 1 only, got S={s}")
@@ -619,12 +628,10 @@ def paged_decode_gqa(
                 (1, 1, g_pad, d), lambda bb, h, j, tbl, meta: (bb, h, 0, 0)
             ),
             pl.BlockSpec(
-                (1, bs, 1, d),
-                lambda bb, h, j, tbl, meta: (tbl[bb, j], 0, h, 0),
+                (1, bs, d), lambda bb, h, j, tbl, meta: (tbl[bb, j], 0, h)
             ),
             pl.BlockSpec(
-                (1, bs, 1, d),
-                lambda bb, h, j, tbl, meta: (tbl[bb, j], 0, h, 0),
+                (1, bs, d), lambda bb, h, j, tbl, meta: (tbl[bb, j], 0, h)
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -641,7 +648,10 @@ def paged_decode_gqa(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, g_pad, d), q.dtype),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), meta, sink_arr, qt, k_pool, v_pool)
+    )(
+        block_table.astype(jnp.int32), meta, sink_arr, qt,
+        k_pool.reshape(-1, bs, nkv * d), v_pool.reshape(-1, bs, nkv * d),
+    )
     # [B, Nkv, g_pad, D] -> [B, Nkv, G, D] -> [B, 1, Nq*D]
     return out[:, :, :g, :].reshape(b, 1, nq * d)
 
@@ -665,10 +675,13 @@ def decode_gqa(
     decode steps on CPU/XLA.
 
     With `block_table`, k/v are paged block pools and the read gathers
-    through the table first (gather_block_kv) — exact vs the dense path
-    by construction (the gathered view is position-contiguous), including
-    compressed-KV layouts (the gather preserves the narrow dtype, so the
-    upcast stays dequant-fused in the contraction operand stream below).
+    through the table first (gather_block_kv) — the same math as the dense
+    path over a position-contiguous view, so tokens match the dense path
+    exactly and logits match it to float32 rounding (XLA fuses a gathered
+    operand differently from a dense slab: the last bit may differ). That
+    holds for compressed-KV layouts too (the gather preserves the narrow
+    dtype, so the upcast stays dequant-fused in the contraction operand
+    stream below).
 
     Identical math to models/qwen3.gqa_attention at S == 1 with the query
     axis dropped from every intermediate: scores are [B, Nkv, G, T] (not
@@ -686,7 +699,8 @@ def decode_gqa(
     if block_table is not None:
         # paged decode dispatch: the Pallas chain-walk kernel when this
         # chip MEASURED it winning (autotune registry / FORCE_PAGED_KERNEL
-        # test hook); cold registry -> the XLA gather path, bit-for-bit
+        # test hook); cold registry -> the XLA gather path, the same
+        # program as before the kernel existed
         if kv_positions is None and paged_kernel_enabled():
             return paged_decode_gqa(
                 q, k, v, block_table, q_positions, kv_valid_len,
@@ -764,7 +778,8 @@ def paged_kernel_enabled() -> bool:
 # prefill (S=T 512-4096) shape — XLA's own fusion already runs these
 # bandwidth-bound — so the kernels' structural win is MEMORY at large S*T
 # (long-prompt prefill over a long cache), where the XLA path's score tensor
-# stops fitting. Sweep: sweep results in BASELINE.md "attention dispatch".
+# stops fitting. (That sweep predates the current kernels and has no
+# artifact in the tree: tools/sweep_attn re-measures it.)
 _XLA_SCORE_BUDGET = 256 * 1024 * 1024
 
 
@@ -823,8 +838,16 @@ def flash_enabled(
 
 
 def flash_interpret(cfg) -> bool:
-    """Run the kernel in the Pallas interpreter? Always off TPU (where the
-    Mosaic compiler is unavailable), and on explicit request."""
-    return getattr(cfg, "attn_impl", "auto") == "flash_interpret" or (
-        not is_tpu()
-    )
+    """Run the kernel in the Pallas interpreter? Off the TPU always (there
+    it is the only way to run a Mosaic kernel: the CPU tests). On a TPU
+    never: the kernel is compiled, and what the compiler refuses raises.
+    Asking for the interpreter on a chip (attn_impl="flash_interpret") is
+    refused too — it would be a slow path under a fast path's name."""
+    if not is_tpu():
+        return True
+    if getattr(cfg, "attn_impl", "auto") == "flash_interpret":
+        raise ValueError(
+            "attn_impl='flash_interpret' runs the kernel in the Pallas "
+            "interpreter, the CPU test path; on a TPU use 'flash'"
+        )
+    return False

@@ -105,8 +105,8 @@ def _w4a16_kernel(
 ):
     """Dequant-fused int4 decode GEMV: the packed nibbles are the ONLY
     weight bytes that cross HBM (quarter of bf16); unpack (arithmetic-
-    shift sign extension, the exact Int4Weight.unpacked recipe) and the
-    group-scale application both happen in VMEM. Both Int4Weight
+    shift sign extension, the values of the Int4Weight.unpacked recipe) and
+    the group-scale application both happen in VMEM. Both Int4Weight
     contraction schemes are implemented so the kernel's sibling is
     whatever _int4_mode picked — "dequant" widens group-wise and runs ONE
     dot; "grouped" contracts per group on the narrow tensor and applies
@@ -115,8 +115,13 @@ def _w4a16_kernel(
     x = x_ref[...]
     q = q_ref[...]
     if packed:
-        lo = jnp.left_shift(q, 4) >> 4  # low nibble, sign-extended
-        hi = q >> 4  # arithmetic shift sign-extends
+        # widen BEFORE shifting: Mosaic does not legalize shifts on int8
+        # vectors for the v5e ("failed to legalize operation 'arith.shli'"),
+        # it does on int32. Same values as the int8 recipe: shifting the
+        # sign-extended byte up 28 and back down sign-extends the low nibble.
+        q32 = q.astype(jnp.int32)
+        lo = (q32 << 28) >> 28  # low nibble, sign-extended
+        hi = q32 >> 4  # arithmetic shift sign-extends
         w = jnp.stack([lo, hi], axis=-2).reshape(2 * q.shape[0], q.shape[1])
     else:
         w = q
@@ -139,6 +144,14 @@ def _w4a16_kernel(
             )
             acc = acc + yg * s_ref[g]
     o_ref[...] = acc.astype(out_dtype)
+
+
+# The unpacked [K, block_n] block lives in VMEM as int32, then f32, then the
+# activation dtype: ~11 bytes an element, 30.7 MB at the Qwen3-8B down
+# projection (K = 12288) — over Mosaic's default 16 MB scoped limit, far
+# under the v5e's 128 MiB of VMEM. (Splitting K across the grid would lift
+# the need; until the kernel has a chip number that is not worth the code.)
+_W4_VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def w4a16_matvec(
@@ -179,6 +192,9 @@ def w4a16_matvec(
         ],
         out_specs=pl.BlockSpec((m_pad, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m_pad, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_W4_VMEM_LIMIT
+        ),
         interpret=interpret,
     )(xp, w.q, w.scale.astype(jnp.float32))
     return out[:m, :n]

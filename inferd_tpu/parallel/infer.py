@@ -55,7 +55,6 @@ from inferd_tpu.config import ModelConfig, SamplingConfig
 from inferd_tpu.core import sampling as samplib
 from inferd_tpu.core.generate import bucket_len
 from inferd_tpu.models import qwen3
-from inferd_tpu.parallel import compat
 from inferd_tpu.parallel import mesh as meshlib
 
 Params = Dict[str, Any]
@@ -189,7 +188,7 @@ def _pipeline_pass(
     read/write O(window) rings — the same program on every rank, which is
     what shard_map requires. The traced-offset design this replaces could
     never make the pattern static (mesh_executor r03 fallback)."""
-    pp = compat.axis_size("pp")
+    pp = lax.axis_size("pp")
     idx = lax.axis_index("pp")
     perm = [(i, (i + 1) % pp) for i in range(pp)]
     n, b, s = x.shape
@@ -308,7 +307,7 @@ def make_sp_prefill_pass(cfg: ModelConfig, mesh: Mesh, params: Params):
     kv_spec = P("pp", None, None, "tp") if tp_on else P("pp")
 
     def _pass(p, x, positions, n):
-        pp = compat.axis_size("pp")
+        pp = lax.axis_size("pp")
         idx = lax.axis_index("pp")
         perm = [(i, (i + 1) % pp) for i in range(pp)]
         n_local = jax.tree.leaves(p["layers"])[0].shape[0]
@@ -349,7 +348,7 @@ def make_sp_prefill_pass(cfg: ModelConfig, mesh: Mesh, params: Params):
         v_full = lax.all_gather(vs_buf, "sp", axis=2, tiled=True)
         return k_full, v_full, logits
 
-    return compat.shard_map(
+    return jax.shard_map(
         _pass,
         mesh=mesh,
         in_specs=(pspecs, P(None, "sp"), P(None, "sp"), P()),
@@ -384,7 +383,7 @@ def make_pipeline_pass(
         ring and ring_split_ok(cfg, mesh.shape["pp"])
     )
     if split:
-        return compat.shard_map(
+        return jax.shard_map(
             partial(
                 _pipeline_pass, cfg=cfg, tp_axis=tp_axis, ep_axis=ep_axis,
                 split=True, full_logits=full_logits,
@@ -394,7 +393,7 @@ def make_pipeline_pass(
             out_specs=(kv, kv, kv, kv, P()),
             check_vma=False,
         )
-    return compat.shard_map(
+    return jax.shard_map(
         partial(
             _pipeline_pass, cfg=cfg, tp_axis=tp_axis, ep_axis=ep_axis,
             full_logits=full_logits,

@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from inferd_tpu.parallel import compat
 from inferd_tpu.ops.attention import NEG_INF as NEG  # shared masking sentinel
 from inferd_tpu.ops.attention import apply_softcap, apply_window_mask
 
@@ -53,7 +52,7 @@ def ring_gqa_attention(
     sinks: Optional[jax.Array] = None,  # [Nq] per-q-head sink logits (GPT-OSS)
 ) -> jax.Array:
     """Exact causal attention over the ring; returns [B, S, Nq*D]."""
-    sp = compat.axis_size(axis)
+    sp = lax.axis_size(axis)
     b, s, nq, d = q.shape
     nkv = k.shape[2]
     g = nq // nkv

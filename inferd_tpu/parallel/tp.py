@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from inferd_tpu.parallel import compat
 from inferd_tpu.config import ModelConfig
 from inferd_tpu.ops.quant import qdot, qeinsum
 from inferd_tpu.models.qwen3 import (
@@ -162,7 +161,7 @@ def moe_mlp_sharded(
     stride = 1
     for ax in reversed(expert_axes):
         rank = rank + lax.axis_index(ax) * stride
-        stride *= compat.axis_size(ax)
+        stride *= lax.axis_size(ax)
     offset = rank * e_local
     local_ids = offset + jnp.arange(e_local)  # [E_local] global expert ids
     match = topi[:, :, None] == local_ids[None, None, :]  # [T, K, E_local]
@@ -181,12 +180,12 @@ def moe_mlp_sharded(
         f, p = _route_fractions(probs, topi, cfg.num_experts)
         n_shards = 1.0
         for ax in aux_token_axes:
-            n_shards *= compat.axis_size(ax)
+            n_shards *= lax.axis_size(ax)
         f = psum_replicated(f / n_shards, tuple(aux_token_axes))
         p = psum_replicated(p / n_shards, tuple(aux_token_axes))
         denom = 1.0
         for ax in expert_axes:
-            denom *= compat.axis_size(ax)
+            denom *= lax.axis_size(ax)
         aux = cfg.num_experts * jnp.sum(f * p[None, :]) / denom
         return out.reshape(b, s, h), aux
     return out.reshape(b, s, h)

@@ -39,7 +39,6 @@ from inferd_tpu.config import ModelConfig
 from inferd_tpu.models.qwen3 import embed as qwen3_embed
 from inferd_tpu.models.qwen3 import rms_norm
 from inferd_tpu.ops.attention import apply_softcap
-from inferd_tpu.parallel import compat
 from inferd_tpu.parallel import mesh as meshlib
 from inferd_tpu.parallel.tp import sharded_forward_layers
 
@@ -67,7 +66,7 @@ def _pipeline_forward(
     collect_aux: also return this rank's summed MoE load-balancing loss
     over its layers and all REAL microbatch ticks (bubble ticks compute on
     garbage activations and are masked out)."""
-    pp = compat.axis_size("pp")
+    pp = lax.axis_size("pp")
     idx = lax.axis_index("pp")
     mb = tokens.shape[0]
     perm = [(i, (i + 1) % pp) for i in range(pp)]
@@ -318,7 +317,7 @@ def make_train_step(
             # leaf by data_norm to turn summed per-shard CE grads into the
             # mean — pre-multiplying aux by data_norm cancels that division
             # exactly for its gradient paths.
-            ce = jnp.where(lax.axis_index("pp") == compat.axis_size("pp") - 1, local, 0.0)
+            ce = jnp.where(lax.axis_index("pp") == lax.axis_size("pp") - 1, local, 0.0)
             dn = float(plan.dp * plan.sp)
             return ce + moe_aux_coef * dn * aux, (ce, aux)
 
@@ -411,7 +410,7 @@ def make_train_step(
         return g
 
     state_specs = train_state_specs(pspecs, optimizer)
-    shmapped = compat.shard_map(
+    shmapped = jax.shard_map(
         per_rank,
         mesh=mesh,
         in_specs=(state_specs, data_spec, data_spec),

@@ -93,17 +93,22 @@ _KIND_MAP = (
 
 def detect_chip() -> ChipSpec:
     """ChipSpec for the ATTACHED backend (initializes it — never call at
-    import time). Unknown TPU generations fall back to v5e (the repo's
-    only measured chip so far) rather than failing."""
-    from inferd_tpu.utils.platform import device_kind, is_tpu
+    import time). A TPU whose kind is not in the table is an error: a
+    roofline share computed against another generation's peaks is a wrong
+    number with a right-looking name."""
+    from inferd_tpu.utils import platform
 
-    if not is_tpu():
+    if not platform.is_tpu():
         return CHIP_SPECS["cpu"]
-    kind = device_kind().lower()
+    kind = platform.device_kind()
     for needle, key in _KIND_MAP:
-        if needle in kind:
+        if needle in kind.lower():
             return CHIP_SPECS[key]
-    return CHIP_SPECS["v5e"]
+    raise KeyError(
+        f"TPU device_kind {kind!r} is not in perf.roofline's peaks table "
+        f"(have {sorted(k for k in CHIP_SPECS if k != 'cpu')}); add its "
+        "published peaks rather than borrowing another generation's"
+    )
 
 
 def get_chip(key: str) -> ChipSpec:
@@ -305,7 +310,7 @@ def decode_step_cost(
 # Pallas INTERPRETER, not the kernel, so the CPU-proxy artifact grades
 # structural bytes (what the roofline is made of) and leaves wall-clock
 # verdicts to `sweep_attn --kernels` on real hardware. Every model is
-# written down here, not in the bench, so BASELINE.md's re-derivations
+# written down here, not in the bench, so docs/PERF.md's re-derivations
 # and the gate read the same arithmetic.
 # ---------------------------------------------------------------------------
 

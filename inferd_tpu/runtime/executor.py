@@ -243,7 +243,11 @@ class Qwen3StageExecutor:
     ):
         self.cfg = cfg
         self.spec = spec
-        self.params = stage_params
+        # one host->device transfer, here: a stage checkpoint loads as numpy
+        # (parallel.stages.load_stage_checkpoint), and numpy leaves handed to
+        # a jit are copied to the device again on EVERY call — the whole
+        # model per token on a chip. Arrays already on a device stay put.
+        self.params = jax.device_put(stage_params)
         self.max_len = max_len
         self.initial_kv_len = initial_kv_len
         self.sessions = SessionStore(max_sessions, session_ttl_s)

@@ -249,6 +249,7 @@ class Node:
         standby_repl: bool = False,
         repl_interval_s: float = 0.5,
         rescue_bounces: int = 6,
+        compile_cache: Optional[Any] = None,
     ):
         self.info = info
         self.cfg = cfg
@@ -479,6 +480,7 @@ class Node:
 
         from inferd_tpu import native as _native
 
+        self.wire_codec = "native" if _native.codec is not None else "python"
         if _native.codec is None:
             log.info(
                 "native wire codec unavailable — running the pure-Python "
@@ -486,6 +488,19 @@ class Node:
             )
 
         self.executor = self._load_executor(info.stage)
+        # what this node computes on, as JAX reports it (node.start, /stats):
+        # a caller checks the device instead of trusting the flag it passed.
+        # The model-free counter backend never touches a JAX backend, so it
+        # must not claim a chip just to describe one.
+        from inferd_tpu.utils.platform import device_facts
+
+        self.device: Dict[str, Any] = (
+            device_facts() if backend != "counter"
+            else {"platform": "none", "device_kind": "", "device_count": 0}
+        )
+        # persistent compile cache counters of this process
+        # (utils.platform.CompileCacheStats; None = cache not enabled)
+        self.compile_cache = compile_cache
         # continuous batching coalesces decode steps of CONCURRENT requests:
         # the worker pool must admit at least one thread per lane (plus the
         # flusher's) or the batch window can never fill past the pool size
@@ -785,12 +800,21 @@ class Node:
         await self._runner.setup()
         site = web.TCPSite(self._runner, self.info.host, self.info.port)
         await site.start()
-        self.announce()
-        self.balancer.start()
         self.journal.emit(
             "node.start", stage=self.info.stage,
-            num_stages=self.info.num_stages,
+            num_stages=self.info.num_stages, **self.device,
         )
+        # pay the decode-step compile before the swarm can route here (the
+        # same eager warm-up a stage migration runs): the first real request
+        # must not eat it, and a warm-up that failed is on the record
+        # (executor.warmup_failed) before any request is. The counter
+        # backend has nothing to compile.
+        if self.backend != "counter":
+            await self._loop.run_in_executor(
+                None, _warmup_executor, self.executor, self.journal
+            )
+        self.announce()
+        self.balancer.start()
         self._sweep_task = asyncio.create_task(self._sweep_loop())
         self._tsdb_task = asyncio.create_task(self._tsdb_loop())
         if lockwatch.watching() and eventslib.enabled():
@@ -4758,6 +4782,14 @@ class Node:
     async def handle_stats(self, request: web.Request) -> web.Response:
         self._update_gauges()
         snap = self.metrics.snapshot()
+        # per-device bytes ride along (empty where the backend reports no
+        # memory_stats): on a mesh it shows every chip holding its share
+        snap["device"] = dict(
+            self.device, memory=devtellib.device_memory_stats()
+        )
+        snap["wire_codec"] = self.wire_codec
+        if self.compile_cache is not None:
+            snap["compile_cache"] = self.compile_cache.as_dict()
         snap["trace"] = self.tracer.stats()
         proposed = snap["counters"].get("spec.proposed", 0)
         if proposed:
@@ -4960,7 +4992,7 @@ class Node:
         # serving path, and time it — reassign -> ready-to-serve is the
         # latency half of BASELINE config 4 ("re-shards layer blocks
         # live"), exported as reshard.ms_to_serving. With a
-        # persistent compilation cache (--compile-cache) the warm path
+        # persistent compilation cache (always on in run_node) the warm path
         # skips XLA re-compiles and this interval collapses to checkpoint
         # load + cache hits.
         await loop.run_in_executor(
@@ -4989,8 +5021,8 @@ class Node:
         self.announce()
         self.metrics.inc("migrations")
         seconds = time.perf_counter() - t0
-        # wider buckets than the hop histograms: a cold migration (no
-        # --compile-cache) pays XLA recompiles and runs well past the
+        # wider buckets than the hop histograms: a cold migration (nothing
+        # in the compile cache yet) pays XLA recompiles and runs well past the
         # default 10 s cap — quantiles must not saturate to inf there
         self.metrics.observe(
             "reshard.ms_to_serving", seconds * 1e3,

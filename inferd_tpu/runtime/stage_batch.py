@@ -80,7 +80,11 @@ class BatchedStageExecutor(AdapterBindingMixin):
 
         self.cfg = cfg
         self.spec = spec
-        self.params = stage_params
+        # one host->device transfer, here: a stage checkpoint loads as numpy
+        # (parallel.stages.load_stage_checkpoint), and numpy leaves handed to
+        # a jit are copied to the device again on EVERY call — the whole
+        # model per token on a chip. Arrays already on a device stay put.
+        self.params = jax.device_put(stage_params)
         self.lanes = lanes
         self.max_len = max_len
         self.ttl_s = session_ttl_s

@@ -65,7 +65,10 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--top-p", type=float, default=0.95)
     ap.add_argument("--min-p", type=float, default=0.0,
                     help="min-p filtering: drop tokens below min_p * max-prob")
-    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="sampling seed; with --random-init also the weights' "
+                    "seed (the same weights `split_model --random-init "
+                    "--seed` writes)")
     ap.add_argument("--max-len", type=int, default=2048)
     ap.add_argument("--device", default="auto", choices=["auto", "tpu", "cpu"])
     ap.add_argument("--pin-prefix-ids", default="",
@@ -93,9 +96,13 @@ def _load_params(cfg, random_init: bool, seed: int):
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    from inferd_tpu.utils.platform import force_platform
+    from inferd_tpu.utils.platform import (
+        enable_compile_cache, force_platform, require_platform,
+    )
 
-    force_platform(None if args.device == "auto" else args.device)
+    force_platform(args.device)
+    cache_stats = enable_compile_cache()
+    facts = require_platform(args.device)
 
     from inferd_tpu.config import SamplingConfig, get_config
     from inferd_tpu.ops import quant as quantlib
@@ -110,7 +117,7 @@ def main(argv=None) -> int:
         min_p=args.min_p
     )
 
-    params = _load_params(cfg, args.random_init, seed=0)
+    params = _load_params(cfg, args.random_init, seed=args.seed)
     if args.lora:
         from inferd_tpu.ops import lora as loralib
 
@@ -171,7 +178,7 @@ def main(argv=None) -> int:
             dcfg = get_config(args.draft_model or args.model)
             if args.draft_layers:
                 dcfg = dcfg.with_layers(args.draft_layers)
-            draft_params = _load_params(dcfg, args.random_init, seed=1)
+            draft_params = _load_params(dcfg, args.random_init, seed=args.seed + 1)
         eng = SpeculativeEngine(
             cfg, params, dcfg, draft_params, k=args.spec_k,
             max_len=args.max_len, sampling_cfg=sampling,
@@ -187,7 +194,12 @@ def main(argv=None) -> int:
         print("generated ids:", out)
     rate = len(out) / dt if dt > 0 else 0.0
     extra = f", draft acceptance {acceptance:.2f}" if acceptance is not None else ""
-    print(f"[{len(out)} tokens in {dt:.2f}s = {rate:.1f} tok/s{extra}]", file=sys.stderr)
+    print(
+        f"[{len(out)} tokens in {dt:.2f}s = {rate:.1f} tok/s{extra}; "
+        f"device {facts['platform']} {facts['device_kind']!r} "
+        f"x{facts['device_count']}; compile cache {cache_stats.as_dict()}]",
+        file=sys.stderr,
+    )
     return 0
 
 

@@ -44,7 +44,7 @@ def main(argv=None) -> None:
 
     from inferd_tpu.utils.platform import force_platform
 
-    force_platform(None if args.device == "auto" else args.device)
+    force_platform(args.device)
 
     if args.manifest:
         manifest = Manifest.from_yaml(args.manifest)

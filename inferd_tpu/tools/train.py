@@ -78,7 +78,7 @@ def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     from inferd_tpu.utils.platform import force_platform
 
-    force_platform(None if args.device == "auto" else args.device)
+    force_platform(args.device)
 
     import jax
     import numpy as np

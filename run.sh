@@ -7,9 +7,16 @@
 #
 #   ./run.sh            # tiny random-init demo, counter-checked
 #   ./run.sh --hf       # real qwen3-0.6b weights (needs HF cache)
+#
+# A CPU demo: it starts one PROCESS per node on this host, and a chip belongs
+# to one process at a time. The chip is exercised by `python chip_smoke.py`
+# (one node process, then the plain engine, one after the other).
 set -euo pipefail
 cd "$(dirname "$0")"
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
+export JAX_PLATFORMS=cpu
+# XLA:CPU can refuse, or crash on, executables a sibling process cached on
+# the same host (tests/conftest.py): the CPU demo compiles anew
+export JAX_ENABLE_COMPILATION_CACHE=false
 
 MODEL=tiny
 EXTRA=(--random-init)
@@ -20,7 +27,7 @@ trap 'kill $(jobs -p) 2>/dev/null || true; rm -rf "$WORK"' EXIT
 
 echo "== 0/4 jaxlint static analysis (docs/ANALYSIS.md)"
 python -m inferd_tpu.analysis check inferd_tpu/ tests/ bench.py \
-    __graft_entry__.py --baseline analysis-baseline.json --jobs 0
+    __graft_entry__.py chip_smoke.py --baseline analysis-baseline.json --jobs 0
 
 echo "== 0a/4 observability contract drift (HARD — docs/ANALYSIS.md 'contracts')"
 # emitted journal events / /metrics series / gossip keys must match the
@@ -176,7 +183,7 @@ open(f"{work}/cluster.yaml", "w").write(m.to_yaml())
 EOF
 python -m inferd_tpu.tools.deploy --manifest "$WORK/cluster.yaml" \
     --mode local --out "$WORK/launch.sh" --parts "$WORK/parts" \
-    --device "${INFERD_DEVICE:-cpu}"
+    --device cpu
 
 echo "== 3/4 launch cluster"
 MANIFEST="$WORK/cluster.yaml" bash "$WORK/launch.sh" &

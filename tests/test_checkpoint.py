@@ -15,7 +15,6 @@ from inferd_tpu.models import qwen3
 from inferd_tpu.parallel import checkpoint as ckpt
 from inferd_tpu.parallel import mesh as meshlib
 from inferd_tpu.parallel.train import make_train_step
-from conftest import requires_native_shard_map
 
 
 def test_roundtrip_and_meta(tmp_path):
@@ -51,7 +50,6 @@ def test_restore_missing_raises(tmp_path):
         ckpt.restore(str(tmp_path / "empty"))
 
 
-@requires_native_shard_map
 def test_sharded_resume_continues_identically(tmp_path, devices8):
     """Train 4 steps straight vs train 2 + checkpoint + restore-onto-mesh +
     train 2: final params must match exactly."""
@@ -105,7 +103,6 @@ def test_meta_step_key_is_reserved(tmp_path):
     assert meta["step"] == 5 and meta["lr"] == 0.1
 
 
-@requires_native_shard_map
 def test_adam_resume_bit_identity(tmp_path, devices8):
     """Adam training: 4 steps straight vs 2 + snapshot(params+moments+count)
     + restore-with-target-onto-mesh + 2 — params AND moments must match
@@ -154,7 +151,6 @@ def test_adam_resume_bit_identity(tmp_path, devices8):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@requires_native_shard_map
 def test_adam_loss_decreases(devices8):
     plan = meshlib.MeshPlan(pp=2)
     mesh = meshlib.make_mesh(plan, devices8[:2])
@@ -171,7 +167,6 @@ def test_adam_loss_decreases(devices8):
     assert losses[-1] < losses[0] and all(np.isfinite(losses)), losses
 
 
-@requires_native_shard_map
 def test_adam_requires_state():
     import pytest as _pytest
 

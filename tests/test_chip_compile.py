@@ -1,0 +1,247 @@
+"""The chip's compiler, asked without the chip: every Pallas kernel in ops/
+and one whole serving step, compiled for a DESCRIBED v5e at the published
+widths of Qwen3-0.6B and Qwen3-8B.
+
+Interpret mode (tests/test_kernels.py) checks what a kernel computes; it
+cannot see what Mosaic refuses — a block whose last two dimensions break the
+(8, 128) tiling, a shift the v5e does not lower, a kernel over the VMEM
+budget. These compiles cost about two seconds each and no chip time. Nothing
+here runs: a compile that passes says nothing about results or speed.
+
+The topology is described inside a module-scoped fixture and nowhere else
+(never at import, in a skipif or a parametrize argument): only one process
+may load the TPU library, and every xdist worker imports every test file.
+All of these tests live in this one file for the same reason — a second file
+could land on another worker, where the fixture would skip in silence.
+"""
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from inferd_tpu.config import get_config
+from inferd_tpu.ops import attention as att
+from inferd_tpu.ops import lora as lora_ops
+from inferd_tpu.ops import qmatmul, quant
+
+BF16 = jnp.bfloat16
+FP8 = jnp.float8_e4m3fn
+
+# (Nq, Nkv, D, hidden, intermediate) at published widths
+WIDTHS = {
+    "qwen3-0.6b": (16, 8, 128, 1024, 3072),
+    "qwen3-8b": (32, 8, 128, 4096, 12288),
+}
+VOCAB = 151936
+LANES = 8  # run_node --batch-lanes 8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _on(tree, sharding):
+    """Shapes of an abstract pytree, placed on the described chip."""
+    return jax.tree.map(lambda a: _sds(a.shape, a.dtype, sharding), tree)
+
+
+def _compile(fn, *args):
+    """Lower + compile for the described chip; returns the compiled text.
+    Raises whatever the chip's compiler would raise."""
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _assert_kernel(text):
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the compiled program"
+
+
+# ---------------------------------------------------------------------------
+# attention kernels
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("model", sorted(WIDTHS))
+@pytest.mark.parametrize("kv_dtype", [BF16, FP8], ids=["bf16kv", "fp8kv"])
+@pytest.mark.parametrize("stream", [True, False], ids=["stream", "resident"])
+@pytest.mark.parametrize("s,t", [(2048, 4096), (1, 4096)],
+                         ids=["prefill", "decode"])
+def test_flash_gqa_compiles(one_chip, no_compile_cache, model, kv_dtype,
+                            stream, s, t):
+    nq, nkv, d, _h, _i = WIDTHS[model]
+    q = _sds((1, s, nq, d), BF16, one_chip)
+    k = _sds((1, t, nkv, d), kv_dtype, one_chip)
+    start = _sds((1,), jnp.int32, one_chip)
+
+    def fn(q, k, v, q_start, kv_len):
+        return att.flash_gqa(q, k, v, q_start, kv_len, stream=stream)
+
+    _assert_kernel(_compile(fn, q, k, k, start, start))
+
+
+@pytest.mark.parametrize("model", sorted(WIDTHS))
+@pytest.mark.parametrize("kv_dtype", [BF16, FP8], ids=["bf16kv", "fp8kv"])
+def test_paged_decode_gqa_compiles(one_chip, no_compile_cache, model,
+                                   kv_dtype):
+    """run_node --batch-lanes 8 --paged-kv 32 at max_len 4096: 128-block
+    chains over a fully provisioned pool."""
+    nq, nkv, d, _h, _i = WIDTHS[model]
+    bs, mb = 32, 4096 // 32
+    q = _sds((LANES, 1, nq, d), BF16, one_chip)
+    pool = _sds((1 + LANES * mb, bs, nkv, d), kv_dtype, one_chip)
+    table = _sds((LANES, mb), jnp.int32, one_chip)
+    qpos = _sds((LANES, 1), jnp.int32, one_chip)
+    kv_len = _sds((LANES,), jnp.int32, one_chip)
+
+    def fn(q, kp, vp, tbl, qpos, kv_len):
+        return att.paged_decode_gqa(q, kp, vp, tbl, qpos, kv_len)
+
+    _assert_kernel(_compile(fn, q, pool, pool, table, qpos, kv_len))
+
+
+# ---------------------------------------------------------------------------
+# dequant-fused decode matmuls
+# ---------------------------------------------------------------------------
+
+
+def _matmul_shapes():
+    """(M, K, N) of the decode matmuls: the fused q/k/v, the MLP up and down
+    projections and the vocabulary head, at one lane and at eight."""
+    out = []
+    for name, (nq, nkv, d, h, i) in sorted(WIDTHS.items()):
+        out += [
+            pytest.param(1, h, (nq + 2 * nkv) * d, id=f"{name}-qkv-m1"),
+            pytest.param(LANES, h, i, id=f"{name}-up-m8"),
+            pytest.param(LANES, i, h, id=f"{name}-down-m8"),
+            pytest.param(LANES, h, VOCAB, id=f"{name}-head-m8"),
+        ]
+    return out
+
+
+@pytest.mark.parametrize("m,k,n", _matmul_shapes())
+def test_w8a16_matmul_compiles(one_chip, no_compile_cache, m, k, n):
+    x = _sds((m, k), BF16, one_chip)
+    q = _sds((k, n), jnp.int8, one_chip)
+    scale = _sds((n,), jnp.float32, one_chip)
+    _assert_kernel(_compile(qmatmul.w8a16_matmul, x, q, scale))
+
+
+@pytest.mark.parametrize("scheme", ["dequant", "grouped"])
+@pytest.mark.parametrize("m,k,n", _matmul_shapes())
+def test_w4a16_matvec_compiles(one_chip, no_compile_cache, scheme, m, k, n):
+    w = jax.eval_shape(
+        quant.quantize_int4, jax.ShapeDtypeStruct((k, n), jnp.float32)
+    )
+    assert w.packed
+    x = _sds((m, k), BF16, one_chip)
+
+    def fn(x, w):
+        return qmatmul.w4a16_matvec(x, w, scheme=scheme)
+
+    _assert_kernel(_compile(fn, x, _on(w, one_chip)))
+
+
+# ---------------------------------------------------------------------------
+# fused LoRA lane-delta
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("model", sorted(WIDTHS))
+def test_fused_lane_delta_compiles(one_chip, no_compile_cache, model):
+    """Eight lanes, rank 16, the widest projection pair of each model."""
+    _nq, _nkv, _d, h, i = WIDTHS[model]
+    slots, layers, r = 5, 4, 16
+    x = _sds((LANES, 1, h), BF16, one_chip)
+    a = _sds((slots, layers, h, r), BF16, one_chip)
+    b = _sds((slots, layers, r, i), BF16, one_chip)
+    scale = _sds((slots,), jnp.float32, one_chip)
+    ids = _sds((LANES,), jnp.int32, one_chip)
+    layer = _sds((), jnp.int32, one_chip)
+    _assert_kernel(_compile(lora_ops.fused_lane_delta, x, a, b, scale, ids, layer))
+
+
+# ---------------------------------------------------------------------------
+# one whole serving step, as run_node --batch-lanes 8 builds it
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("block_size", [0, 32], ids=["dense", "paged32"])
+def test_batched_decode_step_compiles(one_chip, no_compile_cache, block_size):
+    """The jitted decode step of core.batch.BatchedEngine — the program a
+    --batch-lanes 8 node runs per token — for all 28 layers of Qwen3-0.6B
+    over a 4096-token cache, dense and --paged-kv 32. The engine is built
+    over a 64-token cache (its jits close over nothing the cache length
+    changes) and lowered with the serving shapes."""
+    from inferd_tpu.core.batch import BatchedEngine
+    from inferd_tpu.core.cache import KVCache, PagedKVCache
+    from inferd_tpu.models import qwen3
+
+    cfg = get_config("qwen3-0.6b")
+    max_len = 4096
+    params = jax.eval_shape(
+        lambda: qwen3.init_params(cfg, jax.random.PRNGKey(0))
+    )
+    eng = BatchedEngine(
+        cfg, None, lanes=LANES, max_len=64, block_size=block_size
+    )
+    toks = _sds((LANES,), jnp.int32, one_chip)
+    if block_size:
+        mb = max_len // block_size
+        pool = (cfg.num_layers, 1 + LANES * mb, block_size,
+                cfg.num_kv_heads, cfg.head_dim)
+        small = eng.cache
+        cache = PagedKVCache(
+            k=_sds(pool, small.k.dtype, one_chip),
+            v=_sds(pool, small.v.dtype, one_chip),
+            table=_sds((LANES, mb), jnp.int32, one_chip),
+            length=_sds(small.length.shape, small.length.dtype, one_chip),
+        )
+        active = _sds((LANES,), jnp.bool_, one_chip)
+        compiled = eng._decode_logits_paged.lower(
+            _on(params, one_chip), cache, toks, toks, active
+        ).compile()
+    else:
+        cache = _on(
+            jax.eval_shape(
+                lambda: KVCache.create(cfg, cfg.num_layers, LANES, max_len)
+            ),
+            one_chip,
+        )
+        compiled = eng._decode_logits.lower(
+            _on(params, one_chip), cache, toks, toks
+        ).compile()
+    mem = compiled.memory_analysis()
+    # weights + cache + logits of this one program fit a 16 GB chip
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9

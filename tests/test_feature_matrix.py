@@ -15,7 +15,6 @@ from inferd_tpu.core.generate import Engine
 from inferd_tpu.core.speculative import SpeculativeEngine
 from inferd_tpu.models import qwen3
 from inferd_tpu.ops import quant
-from conftest import requires_native_shard_map
 
 VARIANTS = [
     ("bf16", "none", "model"),
@@ -67,7 +66,6 @@ def test_engines_agree_under_variant(base_params, name, quant_flag, kv_dtype):
     ("fp8kv", "none", "float8_e4m3fn"),
     ("int8+fp8kv", "int8", "float8_e4m3fn"),
 ], ids=["int8", "fp8kv", "int8+fp8kv"])
-@requires_native_shard_map
 def test_pipelined_engine_agrees_under_variant(base_params, name, quant_flag, kv_dtype):
     """The in-mesh pp pipeline under the same variants: sharded QuantWeight
     placement + compressed sharded caches must not perturb tokens."""
@@ -93,7 +91,6 @@ def test_pipelined_engine_agrees_under_variant(base_params, name, quant_flag, kv
         quant.QDOT_MODE = "dequant"
 
 
-@requires_native_shard_map
 def test_pipelined_pp_tp_maximal_composition(base_params):
     """The maximal serving stack in one program: pp x tp mesh x int8
     weights x fp8 KV. Sharded QuantWeight leaves (q + scale specs), a

@@ -19,11 +19,6 @@ from inferd_tpu.parallel.infer import PipelinedEngine
 GREEDY = SamplingConfig(temperature=0.0)
 
 
-
-from conftest import requires_native_shard_map
-
-pytestmark = requires_native_shard_map
-
 def make_engine(cfg, pp, mb, devices8, batch=1, max_len=32, sampling=GREEDY):
     mesh = meshlib.make_mesh(meshlib.MeshPlan(pp=pp), devices8[:pp])
     params = qwen3.init_params(cfg, jax.random.PRNGKey(0))

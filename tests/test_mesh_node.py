@@ -22,10 +22,6 @@ from inferd_tpu.parallel.stages import Manifest, split_and_save
 from inferd_tpu.runtime.node import Node, NodeInfo
 
 
-from conftest import requires_native_shard_map
-
-pytestmark = requires_native_shard_map
-
 BASE = 18600
 GREEDY = SamplingConfig(temperature=0.0)
 

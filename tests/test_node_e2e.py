@@ -885,3 +885,48 @@ async def test_graceful_entry_death_hands_off_and_failover_continues(tiny_parts)
         assert toks == expected
     finally:
         await _stop_all([n for n in nodes if n not in stopped])
+
+
+@pytest.mark.asyncio
+async def test_node_says_what_device_it_computes_on(tiny_parts):
+    """`node.start` and /stats carry platform, device_kind and device count
+    as JAX reports them — a caller (chip_smoke.py) checks the device the
+    node resolved instead of trusting the flag it passed — and a node that
+    has started has its warm-up on the record. The model-free counter
+    backend describes no device: it never initializes a JAX backend."""
+    import aiohttp
+    import jax
+
+    parts, _params = tiny_parts
+    nodes = [
+        _mk_node(40 + i, i, 2, backend="qwen3", parts=parts, bootstrap_idx=40)
+        for i in range(2)
+    ]
+    counter = _mk_node(45, 0, 1, bootstrap_idx=45)
+    await _start_all(nodes)
+    await counter.start()
+    try:
+        want = {
+            "platform": jax.devices()[0].platform,
+            "device_kind": jax.devices()[0].device_kind,
+            "device_count": len(jax.devices()),
+        }
+        async with aiohttp.ClientSession() as http:
+            async with http.get(f"http://127.0.0.1:{BASE + 40}/stats") as r:
+                stats = await r.json()
+            async with http.get(f"http://127.0.0.1:{BASE + 45}/stats") as r:
+                cstats = await r.json()
+        memory = stats["device"].pop("memory")
+        assert stats["device"] == want and isinstance(memory, list)
+        assert stats["wire_codec"] in ("native", "python")
+        events = {e["type"]: e for e in nodes[0].journal.events()}
+        start = events["node.start"]["attrs"]
+        assert {k: start[k] for k in want} == want
+        assert "executor.warmup_ok" in events
+        assert "executor.warmup_failed" not in events
+        assert cstats["device"]["platform"] == "none"
+        assert "executor.warmup_ok" not in {
+            e["type"] for e in counter.journal.events()
+        }
+    finally:
+        await _stop_all(nodes + [counter])

@@ -327,3 +327,21 @@ def test_flash_consumes_fp8_kv_directly(stream):
         q, k8, v8, q_start=100, kv_len=108, interpret=True, stream=stream
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("impl,want", [
+    ("auto", False), ("flash", False), ("flash_interpret", ValueError),
+])
+def test_no_interpreted_kernel_on_a_tpu(monkeypatch, impl, want):
+    """On a TPU the kernels are compiled: nothing can select the Pallas
+    interpreter there, and asking for it by name is refused."""
+    from inferd_tpu.ops import attention as att
+
+    cfg = dataclasses.replace(TINY, attn_impl=impl)
+    assert att.flash_interpret(cfg) is True  # off the TPU: the only way
+    monkeypatch.setattr(att, "is_tpu", lambda: True)
+    if want is ValueError:
+        with pytest.raises(ValueError, match="interpreter"):
+            att.flash_interpret(cfg)
+    else:
+        assert att.flash_interpret(cfg) is want

@@ -2,7 +2,8 @@
 prefix caching, chunked prefill, and the block-pool allocator itself
 (core/cache.BlockPool, ops/attention block-table path, both batched
 executors' --paged-kv mode). The correctness bar everywhere is the dense
-layout: same tokens, same logits, bit for bit."""
+layout: the same tokens exactly, the same logits to float32 rounding
+(assert_same_logits)."""
 
 import threading
 import time
@@ -19,6 +20,17 @@ from inferd_tpu.core.cache import BlockPool, KVCache, PagedKVCache, grow
 from inferd_tpu.models import qwen3
 
 TINY = PRESETS["tiny"]
+
+
+def assert_same_logits(a, b):
+    """Dense vs paged logits: the same math over a gathered, position-
+    contiguous view. XLA fuses a gathered operand differently from a dense
+    slab, so float32 logits agree to rounding — not to the bit, as they
+    happened to under an older XLA:CPU — while the tokens they select must
+    agree exactly. Tolerance: 100 float32 ulps of these O(1) logits."""
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    assert np.array_equal(a.argmax(-1), b.argmax(-1))
+    np.testing.assert_allclose(a, b, rtol=1.2e-5, atol=1.2e-5)
 
 
 @pytest.fixture(scope="module")
@@ -209,7 +221,7 @@ def _prefill_both(params, pool, toks):
                                   dense, jnp.int32(0), real_end=jnp.int32(n))
     lp, pc = qwen3.forward_cached(params, TINY, jnp.asarray(toks), pos,
                                   paged, jnp.int32(0), real_end=jnp.int32(n))
-    assert jnp.array_equal(ld, lp)
+    assert_same_logits(ld, lp)
     return ld, dc, pc
 
 
@@ -239,7 +251,7 @@ def test_forward_cached_paged_parity_prefill_decode(tiny_params):
             pc, jnp.asarray(lens), real_end=jnp.asarray(lens) + 1,
             write_mask=jnp.ones((2,), bool),
         )
-        assert jnp.array_equal(ld, lp)
+        assert_same_logits(ld, lp)
         tok = jnp.argmax(ld[:, 0], -1).astype(jnp.int32)
         lens += 1
 
@@ -414,7 +426,7 @@ def test_cow_divergence_does_not_corrupt_sharers(whole_stage):
     _drive(dense, "a", prompt, 2)
     rd = dense.process("a", {"tokens": [[a1[-1]]],
                              "start_pos": len(prompt) + 2, "real_len": 1})
-    assert np.array_equal(ra["logits"], rd["logits"])
+    assert_same_logits(ra["logits"], rd["logits"])
     # and b's rewritten stream equals a dense executor given the same
     # divergent history
     dense_b = _mk_stage(whole_stage)
@@ -422,7 +434,7 @@ def test_cow_divergence_does_not_corrupt_sharers(whole_stage):
                           "real_len": len(prompt)})
     rdb = dense_b.process("b", {"tokens": [alt], "start_pos": pos,
                                 "real_len": 4})
-    assert np.array_equal(rb["logits"], rdb["logits"])
+    assert_same_logits(rb["logits"], rdb["logits"])
 
 
 def test_cow_protects_registered_blocks_from_rollback(whole_stage):
@@ -468,7 +480,7 @@ def test_cow_protects_fork_parent_blocks_from_rollback(tiny_params):
                               "real_len": len(tail)})
     rd = dense.process("child", {"tokens": [tail], "start_pos": 16,
                                  "real_len": len(tail)})
-    assert np.array_equal(rp["logits"], rd["logits"])
+    assert_same_logits(rp["logits"], rd["logits"])
 
 
 def test_export_after_fork_before_dispatch(tiny_params):
@@ -490,7 +502,7 @@ def test_export_after_fork_before_dispatch(tiny_params):
                                "real_len": len(tail)})
     r2 = dense.process("child", {"tokens": [tail], "start_pos": 18,
                                  "real_len": len(tail)})
-    assert np.array_equal(r1["logits"], r2["logits"])
+    assert_same_logits(r1["logits"], r2["logits"])
 
 
 def test_paged_cobatch_mixed_lanes_parity(whole_stage):
@@ -563,7 +575,7 @@ def test_paged_fork_and_export_import_roundtrip(tiny_params):
                                "real_len": len(tail)})
     rd = dense.process("child", {"tokens": [tail], "start_pos": 18,
                                  "real_len": len(tail)})
-    assert np.array_equal(rp["logits"], rd["logits"])
+    assert_same_logits(rp["logits"], rd["logits"])
     # export from paged, import into a FRESH paged executor, keep decoding
     exp = dict(src.export_sessions(only="parent"))
     dst = _mk_batch(tiny_params, block_size=16)
@@ -573,7 +585,7 @@ def test_paged_fork_and_export_import_roundtrip(tiny_params):
                                 "real_len": 1})
     r2 = dense.process("parent", {"tokens": [[b[-1]]], "start_pos": pos,
                                   "real_len": 1})
-    assert np.array_equal(r1["logits"], r2["logits"])
+    assert_same_logits(r1["logits"], r2["logits"])
 
 
 def test_paged_rejects_spec_and_library_loop(tiny_params):

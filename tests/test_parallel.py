@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from inferd_tpu.parallel import compat
 from inferd_tpu.config import TINY, TINY_GEMMA2, TINY_GPT_OSS, TINY_MOE, TINY_QWEN2
 from inferd_tpu.models import qwen3
 from inferd_tpu.parallel import mesh as meshlib
@@ -18,11 +17,6 @@ from inferd_tpu.parallel.ring import ring_gqa_attention
 from inferd_tpu.parallel.tp import sharded_forward_layers
 from inferd_tpu.parallel.train import make_train_step
 
-
-
-from conftest import requires_native_shard_map
-
-pytestmark = requires_native_shard_map
 
 def _mesh(dp=1, pp=1, sp=1, tp=1, ep=1):
     plan = meshlib.MeshPlan(dp=dp, pp=pp, sp=sp, tp=tp, ep=ep)
@@ -46,7 +40,7 @@ def test_ring_attention_matches_full():
         return ring_gqa_attention(q, k, v, pos, pos, "sp")
 
     out = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             f,
             mesh=mesh,
             in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp"), P(None, "sp")),
@@ -83,7 +77,7 @@ def test_ring_attention_window_softcap_scale_matches_full():
         )
 
     out = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             f, mesh=mesh,
             in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp"), P(None, "sp")),
             out_specs=P(None, "sp"),
@@ -114,7 +108,7 @@ def test_ring_attention_sinks_matches_full():
         return ring_gqa_attention(q, k, v, pos, pos, "sp", sinks=sinks)
 
     out = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             f, mesh=mesh,
             in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp"), P(None, "sp")),
             out_specs=P(None, "sp"),
@@ -144,7 +138,7 @@ def test_sharded_layers_match_single_device(cfg):
         return sharded_forward_layers(layers_local, cfg, h, pos, "tp", "sp")
 
     out = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             f,
             mesh=mesh,
             in_specs=(lspecs, P(None, "sp", None), P(None, "sp")),
@@ -329,7 +323,7 @@ def test_pipeline_forward_matches_single_device():
         return _unembed_local(p, cfg, out.reshape(mb * b, s, -1)).reshape(mb, b, s, -1)
 
     got = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             f, mesh=mesh, in_specs=(pspecs, P()), out_specs=P(), check_vma=False
         )
     )(params, tokens)

@@ -154,6 +154,19 @@ def test_chip_table_and_detect():
     assert rl.detect_chip().key == "cpu"  # tests run on CPU
 
 
+def test_unknown_tpu_kind_is_an_error(monkeypatch):
+    """A TPU whose kind is not in the peaks table raises — it is not handed
+    another generation's numbers."""
+    from inferd_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "is_tpu", lambda: True)
+    monkeypatch.setattr(platform, "device_kind", lambda: "TPU v5 lite")
+    assert rl.detect_chip().key == "v5e"
+    monkeypatch.setattr(platform, "device_kind", lambda: "TPU v99 mega")
+    with pytest.raises(KeyError, match="v99"):
+        rl.detect_chip()
+
+
 # ---------------------------------------------------------------------------
 # autotune registry
 # ---------------------------------------------------------------------------
@@ -935,7 +948,9 @@ def test_battery_has_round8_legs():
     from inferd_tpu.tools.bench_battery import DEFAULT_LEGS, SMOKE_LEGS
 
     names = {n for n, _, _ in DEFAULT_LEGS}
-    assert "swarm_mixed" in names
+    # the config starts CPU-pinned node processes: no chip form, so it is
+    # not in the chip battery (bench.py refuses it under --device tpu)
+    assert "swarm_mixed" not in names
     smoke = dict((n, t) for n, t, _ in SMOKE_LEGS)
     assert "swarm_mixed_tiny" in smoke
     assert "swarm-mixed" in smoke["swarm_mixed_tiny"]
@@ -1039,7 +1054,7 @@ def test_battery_has_round10_legs():
     from inferd_tpu.tools.bench_battery import DEFAULT_LEGS, SMOKE_LEGS
 
     names = {n for n, _, _ in DEFAULT_LEGS}
-    assert "overload" in names
+    assert "overload" not in names  # CPU-pinned node processes: no chip form
     smoke = dict((n, t) for n, t, _ in SMOKE_LEGS)
     assert "overload_tiny" in smoke
     assert "overload" in smoke["overload_tiny"]

@@ -21,11 +21,6 @@ from inferd_tpu.parallel.infer import PipelinedEngine
 GREEDY = SamplingConfig(temperature=0.0)
 
 
-
-from conftest import requires_native_shard_map
-
-pytestmark = requires_native_shard_map
-
 @pytest.fixture(scope="module")
 def target():
     return TINY, qwen3.init_params(TINY, jax.random.PRNGKey(0))

@@ -16,11 +16,6 @@ from inferd_tpu.parallel import mesh as meshlib
 from inferd_tpu.parallel.infer import MeshSpecRunner, PipelinedEngine
 
 
-
-from conftest import requires_native_shard_map
-
-pytestmark = requires_native_shard_map
-
 @pytest.fixture(scope="module")
 def target():
     return TINY, qwen3.init_params(TINY, jax.random.PRNGKey(0))

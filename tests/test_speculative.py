@@ -153,7 +153,7 @@ def test_sampled_distribution_matches_target(target):
     # one jitted prefill builds fresh cache buffers per trial (the spec step
     # donates its cache args, so each trial needs new buffers; jitting this
     # also avoids repeated eager scan dispatch, which segfaults XLA:CPU
-    # under the pytest plugin environment)
+    # when run under pytest)
     @jax.jit
     def prefill_caches(tp, dp, toks):
         tc = KVCache.create(cfg, cfg.num_layers, 1, 64, ring=False)

@@ -9,7 +9,6 @@ import pytest
 
 from inferd_tpu import data as datalib
 from inferd_tpu.tools.train import main as train_main, parse_train_mesh
-from conftest import requires_native_shard_map
 
 
 def test_dataset_windows_and_determinism(tmp_path):
@@ -70,7 +69,6 @@ def test_parse_train_mesh():
         parse_train_mesh("zz=2")
 
 
-@requires_native_shard_map
 def test_train_cli_synthetic_mesh(capsys):
     """End-to-end CLI run on a dp=2,pp=2 mesh: loss finite, JSON summary."""
     rc = train_main([
@@ -85,7 +83,6 @@ def test_train_cli_synthetic_mesh(capsys):
     assert np.isfinite(out["final_loss"])
 
 
-@requires_native_shard_map
 def test_train_cli_resume(tmp_path, capsys):
     """--resume continues from the snapshot: a 2+2 run's final state equals
     the step counter having advanced past the restore point."""
