@@ -41,16 +41,24 @@ from inferd_tpu.obs import events as eventslib
 #: runtime/stage_batch's co-batched decode + per-lane prefill jits, and
 #: the core.batch.BatchedEngine jits the --batch-lanes executor serves
 #: through (reached via its `engine` sub-object — see
-#: instrument_executor). The mesh executor's programs are shard_map
-#: products without a _cache_size surface; its compiles stay visible
-#: only through warmup timing.
+#: instrument_executor), as the --mesh executor's PipelinedEngine is.
 _EXECUTOR_JIT_ATTRS = (
     "_run", "_decode_all", "_prefill_lane",
     "_decode_scan", "_decode_logits", "_prefill_lane_logits", "_fork_lane",
     # paged-KV (--paged-kv) dispatch surfaces
     "_decode_all_paged", "_prefill_lane_paged",
     "_decode_logits_paged", "_prefill_lane_logits_paged", "_copy_blocks",
+    # the mesh engine's serving programs (parallel.infer.PipelinedEngine,
+    # reached as executor.engine): jit products around the shard_map pass
+    "_step_raw", "_step_raw_multi", "_fork_slot",
 )
+
+
+def program_name(fn: Any) -> str:
+    """The name a profiler trace prints for a jitted callable's program
+    (`jit_<function>`), through CompileWatch's wrapper."""
+    return "jit_" + getattr(getattr(fn, "__wrapped__", fn), "__name__", "?")
+
 
 _COMPILE_BOUNDS_MS = [10, 50, 100, 500, 1000, 5000, 10_000, 60_000, 120_000]
 

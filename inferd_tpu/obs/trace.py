@@ -20,7 +20,12 @@ Phase vocabulary (the `phase` tag): `queue`, `compute`, `wire`, `relay`,
 `rescue`, `handoff`, `sample`, `window` (a decode step's co-batching
 wait in the stage arrival window, runtime/node._run_stage_window) for
 timed request phases, plus the structural umbrellas `client` (a
-client's whole generate call) and `server` (a node's whole handler). Disabled-by-config tracing
+client's whole generate call) and `server` (a node's whole handler).
+Inside an executor call (`compute`) the executors stamp four child
+phases through `region`/`holding`: `batch_wait` (the arrival window of
+runtime/window.py), `lock_wait` (the executor's device lock), `device`
+(jitted call -> block_until_ready) and `copy_out` (device -> host).
+Disabled-by-config tracing
 (INFERD_TRACE=0, read per call) records nothing and leaves the wire
 envelope byte-identical to the untraced format.
 """
@@ -36,11 +41,12 @@ import time
 import uuid
 from collections import deque
 from contextlib import contextmanager
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Dict, Iterable, List, Optional
 
 PHASES = (
     "queue", "compute", "wire", "relay", "rescue", "handoff", "sample",
     "window",
+    "batch_wait", "lock_wait", "device", "copy_out",
     "client", "server",
 )
 
@@ -153,9 +159,7 @@ def attach_wire(env: Dict[str, Any]) -> Dict[str, Any]:
 
 def nearest_rank_quantile(sorted_values, q: float) -> float:
     """Nearest-rank quantile over an ascending list — the ONE estimator
-    shared by SpanRecorder.phase_quantiles (node-gossiped hop numbers)
-    and merge.hop_summary (the CLI's swarm-wide numbers), so the two can
-    never silently diverge."""
+    merge.hop_summary (the CLI's swarm-wide numbers) uses."""
     idx = min(len(sorted_values) - 1, max(0, int(q * len(sorted_values) + 0.5) - 1))
     return sorted_values[idx]
 
@@ -166,6 +170,49 @@ def header_ctx() -> Optional[Dict[str, str]]:
     if ctx is None or not enabled():
         return None
     return {TRACE_HEADER: ctx.to_header()}
+
+
+@contextmanager
+def region(recorder: Optional["SpanRecorder"], name: str, parents=None, **attrs):
+    """Time the block as one span `name` (phase = name) on `recorder`, a
+    child of the CURRENT context — or one span per context in `parents`
+    (a flusher stamping a wait it served for every entry of its batch).
+    Yields the attrs dict, so the block can add what it only knows at its
+    end (`bytes`). While the recorder is `annotating` (a profiler capture
+    is running) the block is also the profiler-trace annotation
+    `inferd.<name>`. Does nothing without a recorder or with
+    INFERD_TRACE=0."""
+    if recorder is None or not enabled():
+        yield attrs
+        return
+    ann = None
+    if recorder.annotating:
+        import jax
+
+        ann = jax.profiler.TraceAnnotation("inferd." + name)
+        ann.__enter__()
+    t0 = now()
+    try:
+        yield attrs
+    finally:
+        t1 = now()
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        for parent in (current(),) if parents is None else parents:
+            recorder.record_span(
+                name, name, t0, t1, parent=parent, attrs=dict(attrs) or None
+            )
+
+
+@contextmanager
+def holding(lock, recorder: Optional["SpanRecorder"], parents=None, **attrs):
+    """`with lock:` whose wait is the span `lock_wait` (see `region`)."""
+    with region(recorder, "lock_wait", parents, **attrs):
+        lock.acquire()
+    try:
+        yield
+    finally:
+        lock.release()
 
 
 class SpanRecorder:
@@ -186,6 +233,12 @@ class SpanRecorder:
         self.count = 0
         self.overhead_ms = 0.0
         self._flushed = 0  # high-water mark for flush_jsonl
+        # True while a jax.profiler capture runs in this process
+        # (utils.profiling.Profiler sets it): `region`s then also enter a
+        # jax.profiler.TraceAnnotation, so the written trace shows them
+        # above the device's operations on the profiler's own clock.
+        # Otherwise no annotation object is made and jax is not imported.
+        self.annotating = False
 
     # ------------------------------------------------------------ recording
 
@@ -287,27 +340,6 @@ class SpanRecorder:
                 "dropped": self.dropped,
                 "overhead_ms": round(self.overhead_ms, 3),
             }
-
-    def phase_quantiles(
-        self,
-        phases: Tuple[str, ...] = ("relay", "rescue"),
-        qs: Tuple[float, ...] = (0.5, 0.99),
-    ) -> Optional[Dict[str, float]]:
-        """{"p50_ms": ..., "p99_ms": ...} over the buffered spans of the
-        given phases, or None when there are none. (The node's GOSSIPED
-        hop quantiles moved to the trailing-window tsdb in PR 7 — this
-        stays as the ad-hoc all-time view over the live ring.)"""
-        durs = sorted(
-            (s["t1"] - s["t0"]) * 1e3
-            for s in self.spans()
-            if s.get("phase") in phases
-        )
-        if not durs:
-            return None
-        return {
-            f"p{int(q * 100)}_ms": round(nearest_rank_quantile(durs, q), 3)
-            for q in qs
-        }
 
     # ------------------------------------------------------------ export
 

@@ -55,6 +55,8 @@ from inferd_tpu.config import ModelConfig, SamplingConfig
 from inferd_tpu.core import sampling as samplib
 from inferd_tpu.core.generate import bucket_len
 from inferd_tpu.models import qwen3
+from inferd_tpu.obs import trace as tracelib
+from inferd_tpu.obs.devtel import program_name
 from inferd_tpu.parallel import mesh as meshlib
 
 Params = Dict[str, Any]
@@ -645,6 +647,16 @@ class PipelinedEngine:
         # copy of the weights for nothing
         self._sp_raw_params = params if mesh.shape.get("sp", 1) > 1 else None
         self._sp_prefill_fn = None
+        # span recorder handed down by the serving layer (MeshExecutor):
+        # the raw serving steps stamp `device` and `copy_out` with it
+        self.tracer = None
+        # pipeline-pass counters of the raw serving steps, host arithmetic
+        # from each pass's shape and active mask: a pass over n in-flight
+        # microbatches is a scan of n + pp - 1 ticks on pp stages, of
+        # which a live slot uses pp (one tick on every stage)
+        self.passes = 0
+        self.stage_ticks = 0
+        self.stage_ticks_useful = 0
 
     @property
     def sp_active(self) -> bool:
@@ -912,11 +924,21 @@ class PipelinedEngine:
             padded[0, :, :s] = tokens
         else:
             padded = np.asarray(tokens, np.int32)[None]
-        self.caches, logits = self._step_raw(
-            self.params, self.caches, jnp.asarray(padded),
-            jnp.int32(slot), jnp.int32(real_len), jnp.bool_(reset),
-        )
-        return np.asarray(logits)
+        with tracelib.region(
+            self.tracer, "device", kind="prefill" if s > 1 else "decode",
+            tokens=real_len, cobatch=1,
+            program=program_name(self._step_raw),
+        ):
+            self.caches, logits = self._step_raw(
+                self.params, self.caches, jnp.asarray(padded),
+                jnp.int32(slot), jnp.int32(real_len), jnp.bool_(reset),
+            )
+            logits.block_until_ready()
+        self._count_pass(1, 1)
+        with tracelib.region(self.tracer, "copy_out") as at:
+            out = np.asarray(logits)
+            at["bytes"] = out.nbytes
+        return out
 
     def step_slots(self, tokens_by_slot) -> dict:
         """Decode ONE token for several slots in a single pipeline pass
@@ -929,11 +951,28 @@ class PipelinedEngine:
         for slot, tok in tokens_by_slot.items():
             toks[slot] = tok
             active[slot] = True
-        self.caches, logits = self._step_raw_multi(
-            self.params, self.caches, jnp.asarray(toks), jnp.asarray(active)
-        )
-        out = np.asarray(logits, np.float32)  # [MB, V]
+        live = len(tokens_by_slot)
+        with tracelib.region(
+            self.tracer, "device", kind="decode", tokens=live, cobatch=live,
+            program=program_name(self._step_raw_multi),
+        ):
+            self.caches, logits = self._step_raw_multi(
+                self.params, self.caches, jnp.asarray(toks), jnp.asarray(active)
+            )
+            logits.block_until_ready()
+        self._count_pass(self.mb, live)
+        with tracelib.region(self.tracer, "copy_out") as at:
+            out = np.asarray(logits, np.float32)  # [MB, V]
+            at["bytes"] = out.nbytes
         return {slot: out[slot] for slot in tokens_by_slot}
+
+    def _count_pass(self, n: int, live: int) -> None:
+        """One pipeline pass over n in-flight microbatches, `live` of them
+        doing a session's work (see the counters in __init__)."""
+        pp = self.mesh.shape["pp"]
+        self.passes += 1
+        self.stage_ticks += (n + pp - 1) * pp
+        self.stage_ticks_useful += live * pp
 
     def slot_length(self, slot: int) -> int:
         return int(self.caches.lengths[slot])

@@ -24,6 +24,7 @@ Concurrency design (process() runs on the node's worker thread pool):
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Any, Dict, Optional
@@ -35,6 +36,8 @@ from inferd_tpu.core.batch import BatchedEngine
 from inferd_tpu.core.cache import RING_MARGIN, sync_paged
 from inferd_tpu.core import prefix as prefixlib
 from inferd_tpu.core.generate import bucket_len
+from inferd_tpu.obs import trace as tracelib
+from inferd_tpu.obs.devtel import program_name
 from inferd_tpu.obs.events import emit_safely
 from inferd_tpu.runtime.adapters import AdapterBindingMixin
 from inferd_tpu.runtime.spec_serving import SpecForkMiss, SpecServing
@@ -137,6 +140,17 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 # joinable against the gossiped `pfx` digest entries
                 key=prefixlib.digest_key(key),
             )
+
+    @property
+    def tracer(self):
+        """Span recorder (the node wires its own, next to on_event): the
+        decode flush and the dense prefill stamp lock_wait / device /
+        copy_out under the call's `compute` span, the batcher batch_wait."""
+        return self._batcher.tracer
+
+    @tracer.setter
+    def tracer(self, recorder) -> None:
+        self._batcher.tracer = recorder
 
     # -- lane-batched speculative serving (core.spec_batch) ------------------
     #
@@ -625,60 +639,77 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         end = start + n
         step = self.prefill_chunk if self.prefill_chunk > 0 else end - pos
         logits = None
-        while pos < end:
-            c = min(step, end - pos)
-            # cap the padded bucket so the in-jit dynamic_update_slice can
-            # never clamp into older slots near the end of the cache (the
-            # stage executor's _cache_for guards the same invariant); a
-            # capped tail shape compiles its own program — rare and bounded
-            b = min(bucket_len(c), self.max_len - pos)
-            padded = np.zeros((1, b), np.int32)
-            padded[0, :c] = toks[0, pos - start: pos - start + c]
-            if self.pool is not None:
-                with self._mu:
-                    self.pool.ensure(lane, pos + c, owner=owner)
-            with self._dev_lock:
+        with contextlib.ExitStack() as dev:
+            while pos < end:
+                c = min(step, end - pos)
+                # cap the padded bucket so the in-jit dynamic_update_slice can
+                # never clamp into older slots near the end of the cache (the
+                # stage executor's _cache_for guards the same invariant); a
+                # capped tail shape compiles its own program — rare and bounded
+                b = min(bucket_len(c), self.max_len - pos)
+                padded = np.zeros((1, b), np.int32)
+                padded[0, :c] = toks[0, pos - start: pos - start + c]
                 if self.pool is not None:
-                    cache = self._sync_paged()
-                    self.engine.cache, logits = (
-                        self.engine._prefill_lane_logits_paged(
-                            self.engine.params, cache, jnp.asarray(padded),
-                            jnp.asarray(self.pool.table[lane:lane + 1]),
-                            jnp.int32(pos), jnp.int32(c), ads=ads,
+                    with self._mu:
+                        self.pool.ensure(lane, pos + c, owner=owner)
+                with tracelib.holding(self._dev_lock, self.tracer, kind="prefill"):
+                    if self.pool is not None:
+                        cache = self._sync_paged()
+                        self.engine.cache, logits = (
+                            self.engine._prefill_lane_logits_paged(
+                                self.engine.params, cache, jnp.asarray(padded),
+                                jnp.asarray(self.pool.table[lane:lane + 1]),
+                                jnp.int32(pos), jnp.int32(c), ads=ads,
+                            )
                         )
-                    )
-                else:
-                    self.engine.cache, logits = (
-                        self.engine._prefill_lane_logits(
+                    else:
+                        fn = self.engine._prefill_lane_logits
+                        if pos + c == end:
+                            # the chunk whose logits are the response: its
+                            # `device` span closes below, once the lock is
+                            # released and the result is ready (earlier
+                            # chunks of a --prefill-chunk admission are
+                            # dispatched without a wait and get no span)
+                            dev.enter_context(tracelib.region(
+                                self.tracer, "device", kind="prefill",
+                                tokens=c, cobatch=1, program=program_name(fn),
+                            ))
+                        self.engine.cache, logits = fn(
                             self.engine.params, self.engine.cache,
                             jnp.asarray(padded),
                             jnp.int32(lane), jnp.int32(pos), jnp.int32(c),
                             ads=ads,
                         )
-                    )
-                # advance the lane BEFORE releasing the device lock: a
-                # flusher snapshots lengths under the same lock order
-                # (_dev_lock, _mu), so it can never scatter a decode write
-                # over these fresh rows at the stale position
+                    # advance the lane BEFORE releasing the device lock: a
+                    # flusher snapshots lengths under the same lock order
+                    # (_dev_lock, _mu), so it can never scatter a decode write
+                    # over these fresh rows at the stale position
+                    with self._mu:
+                        self.engine.lengths[lane] = pos + c  # real tokens only
+                        self.prefill_tokens += c
+                pos += c
+                if self.prefill_chunk > 0 and pos < end:
+                    # explicit yield between chunks: threading.Lock is NOT
+                    # fair — without this, the chunk loop can re-acquire the
+                    # device before a waiting decode flusher ever wakes, and
+                    # chunking would bound nothing. Sub-ms: noise next to a
+                    # chunk dispatch. The ticketed FairDeviceLock grants in
+                    # arrival order, so there the yield is dead weight.
+                    if not lockwatch.is_fair(self._dev_lock):
+                        time.sleep(0.0005)
+            if self.pool is not None and keys:
                 with self._mu:
-                    self.engine.lengths[lane] = pos + c  # real tokens only
-                    self.prefill_tokens += c
-            pos += c
-            if self.prefill_chunk > 0 and pos < end:
-                # explicit yield between chunks: threading.Lock is NOT
-                # fair — without this, the chunk loop can re-acquire the
-                # device before a waiting decode flusher ever wakes, and
-                # chunking would bound nothing. Sub-ms: noise next to a
-                # chunk dispatch. The ticketed FairDeviceLock grants in
-                # arrival order, so there the yield is dead weight.
-                if not lockwatch.is_fair(self._dev_lock):
-                    time.sleep(0.0005)
-        if self.pool is not None and keys:
-            with self._mu:
-                self.pool.register_prefix(lane, keys)
+                    self.pool.register_prefix(lane, keys)
+            # the wait np.asarray below would make anyway — OUTSIDE the
+            # device lock, as it always was: the next step of another
+            # session is dispatched while this chunk still runs
+            logits.block_until_ready()
         # ONE boundary transfer: only the LAST chunk's logits are the
         # response — mid-chunk logits never leave the device
-        return np.asarray(logits, np.float32), saved
+        with tracelib.region(self.tracer, "copy_out") as at:
+            out = np.asarray(logits, np.float32)
+            at["bytes"] = out.nbytes
+        return out, saved
 
     def _decode_batched(self, session_id: str, lane: int, token: int, ks=None):
         return self._batcher.submit((lane, token, ks))
@@ -718,7 +749,11 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         legacy = [e for e in entries if e.payload[2] is None]
         kstep = [e for e in entries if e.payload[2] is not None]
         poisoned: Optional[Exception] = None
-        with self._dev_lock:
+        # one lock_wait per entry, under each entry's own `compute`
+        waiting = [e.ctx for e in entries] if self.tracer is not None else None
+        with tracelib.holding(
+            self._dev_lock, self.tracer, waiting, kind="decode"
+        ):
             if legacy:
                 try:
                     with self._mu:
@@ -742,13 +777,22 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                             )
                         )
                     else:
-                        self.engine.cache, logits = self.engine._decode_logits(
-                            self.engine.params, self.engine.cache,
-                            jnp.asarray(toks, jnp.int32),
-                            jnp.asarray(lens, jnp.int32),
-                            ads=ads,
-                        )
-                    out = np.asarray(logits, np.float32)
+                        fn = self.engine._decode_logits
+                        with tracelib.region(
+                            self.tracer, "device", kind="decode",
+                            tokens=len(legacy), cobatch=len(legacy),
+                            program=program_name(fn),
+                        ):
+                            self.engine.cache, logits = fn(
+                                self.engine.params, self.engine.cache,
+                                jnp.asarray(toks, jnp.int32),
+                                jnp.asarray(lens, jnp.int32),
+                                ads=ads,
+                            )
+                            logits.block_until_ready()
+                    with tracelib.region(self.tracer, "copy_out") as at:
+                        out = np.asarray(logits, np.float32)
+                        at["bytes"] = out.nbytes
                     with self._mu:
                         for e in legacy:
                             self.engine.lengths[e.payload[0]] += 1

@@ -30,6 +30,7 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from inferd_tpu.config import ModelConfig
+from inferd_tpu.obs import trace as tracelib
 from inferd_tpu.parallel import mesh as meshlib
 from inferd_tpu.parallel.infer import PipelinedEngine
 from inferd_tpu.runtime.spec_serving import SpecForkMiss, SpecServing
@@ -199,6 +200,18 @@ class MeshExecutor(SpecServing):
                 self.enable_spec(spec_draft_layers, spec_k, params)
             except (ValueError, RuntimeError) as e:
                 log.warning("mesh speculation disabled (%s); serving without", e)
+
+    @property
+    def tracer(self):
+        """Span recorder (the node wires its own): the decode flush and the
+        prefill step stamp lock_wait here, the engine's raw steps device /
+        copy_out, the batcher batch_wait — all under the call's `compute`."""
+        return self._batcher.tracer
+
+    @tracer.setter
+    def tracer(self, recorder) -> None:
+        self._batcher.tracer = recorder
+        self.engine.tracer = recorder
 
     # -- slot-batched speculative serving (parallel.infer.MeshSpecRunner) ----
     #
@@ -498,7 +511,7 @@ class MeshExecutor(SpecServing):
                     logits = self.engine.sp_prefill_slot(slot, toks, real_len)
                     self._session_len[session_id] = real_len
             else:
-                with self._lock:
+                with tracelib.holding(self._lock, self.tracer, kind="prefill"):
                     logits = self.engine.step_slot(
                         slot, toks, real_len, reset=new, start_pos=start_pos
                     )
@@ -613,13 +626,23 @@ class MeshExecutor(SpecServing):
             "sessions": len(self.sessions),
             "kv_window_fallback": self.kv_window_fallback,
             **self._batcher.stats(),
+            # pipeline passes of the raw serving steps and how many of
+            # their stage-ticks did a live session's work (the rest are
+            # fill, drain and idle-slot bubbles)
+            "pipeline": {
+                "passes": self.engine.passes,
+                "stage_ticks": self.engine.stage_ticks,
+                "stage_ticks_useful": self.engine.stage_ticks_useful,
+            },
             **self.spec_stats(),
         }
 
     def _run_decode_batch(self, entries) -> None:
         """Flush callback (runtime/window.py): ONE pipeline pass advances
         every waiting slot together."""
-        with self._lock:
+        # one lock_wait per entry, under each entry's own `compute`
+        waiting = [e.ctx for e in entries] if self.tracer is not None else None
+        with tracelib.holding(self._lock, self.tracer, waiting, kind="decode"):
             out = self.engine.step_slots(
                 {e.payload[0]: e.payload[1] for e in entries}
             )

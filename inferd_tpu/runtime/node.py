@@ -147,6 +147,17 @@ def _is_decode_step(payload) -> bool:
         return False  # malformed payloads fail in the guarded compute
 
 
+def _committed_tokens(result) -> int:
+    """Real tokens one executor call committed, for the `compute` span:
+    K of a fused K-step result, else the chunk's real length as the
+    executor reports it (1 for a decode step; never the padded bucket)."""
+    if not isinstance(result, dict):
+        return 1
+    if "tokens" in result:
+        return len(result["tokens"][0])
+    return int(result.get("real_len", 1))
+
+
 #: Buckets for the /generate user-SLI histograms: the SAME whole-chain
 #: ladder the canary probes use (obs.canary), so probe and user latency
 #: compare bucket for bucket.
@@ -445,7 +456,9 @@ class Node:
         from inferd_tpu.core.spec_batch import SPEC_TOP_N
 
         self._spec_top_n = SPEC_TOP_N
-        self.profiler = Profiler(device_lock=self._capture_lock)
+        self.profiler = Profiler(
+            device_lock=self._capture_lock, recorder=self.tracer
+        )
         if mesh_plan is not None and batch_lanes > 0:
             raise ValueError(
                 "--mesh and --batch-lanes are mutually exclusive executor "
@@ -606,6 +619,10 @@ class Node:
         ex = self._build_executor(stage)
         if hasattr(ex, "on_event"):
             ex.on_event = self._executor_event
+        if hasattr(ex, "tracer"):
+            # the lane and mesh executors stamp the parts of `compute`
+            # (batch_wait, lock_wait, device, copy_out) on this recorder
+            ex.tracer = self.tracer
         self.compile_watch.instrument_executor(ex)
         return ex
 
@@ -1788,9 +1805,9 @@ class Node:
                     executor.window.submit, (session_id, env, tin, t_q)
                 )
             else:
-                result, pure_ms, w0, w1 = await self.scheduler.run(
+                result, pure_ms, w0, w1, cctx = await self.scheduler.run(
                     self._timed_process, executor, session_id,
-                    env.get("payload", {}),
+                    env.get("payload", {}), tin,
                 )
         except BufferError as e:  # KV budget exceeded: deterministic
             # the executors' BufferError now names the session AND lane
@@ -1876,11 +1893,16 @@ class Node:
                     attrs={"stage": stage},
                 )
                 self.tracer.record_span(
-                    "compute", "compute", w0, w1, parent=tin,
-                    # a prefill that mapped cached prefix blocks carries
+                    "compute", "compute", w0, w1, parent=tin, ctx=cctx,
+                    # `kind`/`tokens` as the window path has them: a decode
+                    # step or a prefill chunk, and the tokens it committed.
+                    # A prefill that mapped cached prefix blocks carries
                     # how many tokens it SKIPPED — per-request memory-
                     # plane attribution in merged timelines
                     attrs={"stage": stage, "ms": round(pure_ms, 3),
+                           "kind": "decode" if _is_decode_step(_pl)
+                           else "prefill",
+                           "tokens": _committed_tokens(result),
                            **({"tokens_saved": saved} if saved else {})},
                 )
             # service-time EWMA: announced as svc_ms, feeding every
@@ -2445,18 +2467,31 @@ class Node:
         body = {"ok": ok, "length": have} if ok else {"ok": False, "have": have}
         return web.Response(body=wire.pack(body))
 
-    def _timed_process(self, executor, session_id: str, payload: Dict[str, Any]):
+    def _timed_process(self, executor, session_id: str,
+                       payload: Dict[str, Any], tin=None):
         """Executor call + its pure compute time in ms and wall-clock
         start/end stamps (runs in the worker thread, so the measurement
         excludes the pool's queue wait; the wall stamps become the
         compute span and bound the queue span). The executor is passed
         in, bound at request entry — see handle_forward's migration-race
         note."""
-        w0 = tracelib.now()
-        t = time.perf_counter()
-        result = executor.process(session_id, payload)
-        pure_ms = (time.perf_counter() - t) * 1e3
-        return result, pure_ms, w0, w0 + pure_ms / 1e3
+        # the compute span's context is allocated NOW and made current in
+        # this worker thread, so what the executor stamps inside the call
+        # (obs.trace.region: batch_wait, lock_wait, device, copy_out)
+        # parents to the span the caller records afterwards
+        ctx = token = None
+        if tin is not None and tracelib.enabled():
+            ctx = tracelib.SpanContext(tin.trace_id, tracelib.new_id())
+            token = tracelib.set_current(ctx)
+        try:
+            w0 = tracelib.now()
+            t = time.perf_counter()
+            result = executor.process(session_id, payload)
+            pure_ms = (time.perf_counter() - t) * 1e3
+        finally:
+            if token is not None:
+                tracelib.reset_current(token)
+        return result, pure_ms, w0, w0 + pure_ms / 1e3, ctx
 
     def _is_final(self, result: Dict[str, Any]) -> bool:
         # "tokens": a multi-step fused decode result (single-stage
@@ -4831,10 +4866,11 @@ class Node:
         --capture): {"action": "window", "seconds": S, "capture_id": ID}
         starts a BOUNDED capture that stops itself after S seconds (S
         clamped to 60), tagged with the fleet-wide capture_id. The
-        capture window is recorded as a `capture` span (so the
-        clock-skew-corrected span merge lines wire spans up with the
-        on-device trace), journaled, and the obs artifacts flush when it
-        closes so the collector can assemble the bundle immediately.
+        capture window is recorded as a `capture` span as it STARTS (so
+        the clock-skew-corrected span merge lines wire spans up with the
+        on-device trace; `capture_close` times the write), journaled,
+        and the obs artifacts flush when it closes so the collector can
+        assemble the bundle immediately.
         Start/stop/window all hold the shared capture lock for the whole
         trace, so live-anatomy ticks (obs.prof) never interleave.
 
@@ -4883,7 +4919,17 @@ class Node:
             capture_id, self.info.node_id.replace(":", "_")
         )
         d = await loop.run_in_executor(None, self.profiler.start, label)
-        t_start = tracelib.now()
+        # the capture span: t0 is the instant the trace's own anchor event
+        # ends (Profiler.start), t1 the planned end. Its [t0, t1] brackets
+        # the on-device trace, so after the skew-corrected merge the wire
+        # spans of every node line up against every node's device
+        # timeline. Recorded NOW: writing a large trace takes seconds, and
+        # a reader must not be left without its clock anchor meanwhile
+        t_start = self.profiler.started_at
+        self.tracer.record_span(
+            "capture", "capture", t_start, t_start + seconds,
+            attrs={"capture_id": capture_id, "dir": d},
+        )
         if eventslib.enabled():
             self.metrics.inc("prof.captures")
         self.journal.emit(
@@ -4892,17 +4938,16 @@ class Node:
         )
 
         async def _close() -> None:
-            await asyncio.sleep(seconds)
+            await asyncio.sleep(max(0.0, t_start + seconds - tracelib.now()))
+            t_stop = tracelib.now()
             try:
                 await loop.run_in_executor(None, self.profiler.stop)
             except Exception:
                 log.exception("capture %s stop failed", capture_id)
-            # the capture span: its [t0, t1] brackets the on-device trace,
-            # so after the skew-corrected merge the wire spans of every
-            # node line up against every node's device timeline
+            # stop called -> trace written: what closing the capture cost
             self.tracer.record_span(
-                "capture", "capture", t_start, tracelib.now(),
-                attrs={"capture_id": capture_id, "dir": d},
+                "capture_close", "capture", t_stop, tracelib.now(),
+                attrs={"capture_id": capture_id},
             )
             self.journal.emit(
                 "profile.capture_done", capture_id=capture_id, dir=d

@@ -35,17 +35,27 @@ import threading
 import time
 from typing import Any, Callable, List, Optional  # noqa: F401
 
+from inferd_tpu.obs import trace as tracelib
 from inferd_tpu.utils import lockwatch
 
 
 class Entry:
-    __slots__ = ("payload", "event", "result", "error")
+    __slots__ = ("payload", "event", "result", "error",
+                 "t_submit", "t_taken", "ctx")
 
     def __init__(self, payload: Any):
         self.payload = payload
         self.event = threading.Event()
         self.result: Any = None
         self.error: Optional[Exception] = None
+        # the arrival-window wait: submit() entry -> a flush takes the
+        # entry into its batch (the `batch_wait` span and the
+        # queue_wait_ms_sum counter); `ctx` is the submitter's span
+        # context, so the flusher can parent what it waits for on the
+        # entry's behalf (`lock_wait`) to the entry's own `compute`
+        self.t_submit = tracelib.now()
+        self.t_taken: Optional[float] = None
+        self.ctx = tracelib.current()
 
 
 class WindowedBatcher:
@@ -88,6 +98,11 @@ class WindowedBatcher:
         self._flusher_active = False
         self.n_steps = 0  # flushed batches
         self.n_served = 0  # entries served across those batches
+        self.queue_waits = 0  # entries a flush took into a batch
+        self.queue_wait_ms_sum = 0.0  # their summed arrival-window waits
+        # the node's span recorder (obs.trace.SpanRecorder), handed to
+        # the executor that owns this batcher; None records no span
+        self.tracer: Optional[tracelib.SpanRecorder] = None
         # optional flight-recorder hook (the node wires its journal's
         # emit): a flusher that never completes within the wait timeout
         # is a wedged device step — the single worst windowing failure —
@@ -103,6 +118,18 @@ class WindowedBatcher:
             timeout_s=self._wait_timeout_s,
         )
 
+    def _take(self) -> List[Entry]:
+        """Swap the pending list out (under self._mu) and stamp the end
+        of each live entry's arrival-window wait."""
+        batch, self._pending = self._pending, []
+        now = tracelib.now()
+        for e in batch:
+            if e.error is None:
+                e.t_taken = now
+                self.queue_waits += 1
+                self.queue_wait_ms_sum += (now - e.t_submit) * 1e3
+        return batch
+
     def submit(self, payload: Any) -> Any:
         entry = Entry(payload)
         with self._mu:
@@ -111,7 +138,16 @@ class WindowedBatcher:
             if i_flush:
                 self._flusher_active = True
             wait = self._co_possible()
+        try:
+            return self._serve(entry, i_flush, wait)
+        finally:
+            if self.tracer is not None and entry.t_taken is not None:
+                self.tracer.record_span(
+                    "batch_wait", "batch_wait", entry.t_submit, entry.t_taken,
+                    parent=entry.ctx, attrs={"flusher": int(i_flush)},
+                )
 
+    def _serve(self, entry: Entry, i_flush: bool, wait: bool) -> Any:
         if not i_flush:
             entry.event.wait(timeout=self._wait_timeout_s)
             if entry.error is not None:
@@ -166,7 +202,7 @@ class WindowedBatcher:
                 raise TimeoutError("batched decode flusher never completed")
             return entry.result
         with self._mu:
-            batch, self._pending = self._pending, []
+            batch = self._take()
             self._flusher_active = False
         # entries invalidated between swap and here already have error set;
         # run the rest
@@ -203,6 +239,10 @@ class WindowedBatcher:
             "mean_batch": round(self.n_served / self.n_steps, 3)
             if self.n_steps
             else 0.0,
+            # arrival-window wait (submit -> taken into a batch): the mean
+            # is sum / count for an operator without /spans
+            "queue_waits": self.queue_waits,
+            "queue_wait_ms_sum": round(self.queue_wait_ms_sum, 3),
         }
 
     def drain_pending(self) -> List[Entry]:
@@ -218,7 +258,7 @@ class WindowedBatcher:
         a flusher whose own entry was drained waits on its event like any
         co-arrival."""
         with self._mu:
-            batch, self._pending = self._pending, []
+            batch = self._take()
         live = [e for e in batch if e.error is None]
         if live:
             self.n_steps += 1

@@ -4,7 +4,9 @@ The reference had no tracing at all (SURVEY §5: 'Tracing / profiling:
 ABSENT' — print statements only). Here every node can capture an XLA/TPU
 profile on demand — `POST /profile {"action": "start"}` ... `{"action":
 "stop"}` — producing a TensorBoard-loadable trace directory with device
-timelines, HLO cost analysis, and host/device transfer spans. Combined with
+timelines, HLO cost analysis, host/device transfer spans and the program's
+own `inferd.*` regions (obs.trace.region; python call stacks are not
+recorded). Combined with
 the per-hop latency histograms (utils.metrics via /stats), this is the
 instrumentation for the north-star p50 hop-latency metric.
 """
@@ -15,6 +17,8 @@ import os
 import threading
 import time
 from typing import Optional
+
+from inferd_tpu.obs import trace as tracelib
 
 
 def chained_attention_rate(fn, q, k, v, n: int, reps: int = 3) -> float:
@@ -130,12 +134,18 @@ class Profiler:
     which is exactly what stop() relies on (start and stop arrive on
     different executor threads)."""
 
-    def __init__(self, base_dir: str = "profiles", device_lock=None):
+    def __init__(self, base_dir: str = "profiles", device_lock=None,
+                 recorder: Optional[tracelib.SpanRecorder] = None):
         self.base_dir = base_dir
         self.device_lock = device_lock
+        # the node's span recorder: `annotating` while a capture runs, so
+        # the program's regions (obs.trace.region) show in the trace
+        self.recorder = recorder
         self._lock = threading.Lock()
         self._active_dir: Optional[str] = None
         self._holds_device = False
+        # obs.trace.now() as the trace's anchor event ended (see start)
+        self.started_at: Optional[float] = None
 
     @property
     def active_dir(self) -> Optional[str]:
@@ -166,10 +176,25 @@ class Profiler:
                 self._holds_device = True
             try:
                 os.makedirs(d, exist_ok=True)
-                jax.profiler.start_trace(d)
+                # no python call stacks: the tracer that records them
+                # slows the host threads the capture is there to time,
+                # and the program's own `inferd.*` regions (obs.trace)
+                # say what the host was doing. Host level 2 keeps
+                # TraceAnnotations.
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+                jax.profiler.start_trace(d, profiler_options=opts)
             except BaseException:
                 self._release_device()
                 raise
+            # the clock anchor, an event INSIDE the trace: its end and
+            # `started_at` name the same instant on the profiler's clock
+            # and on the spans', so a reader can put the two together
+            with jax.profiler.TraceAnnotation("inferd.start_trace.anchor"):
+                pass
+            self.started_at = tracelib.now()
+            if self.recorder is not None:
+                self.recorder.annotating = True
             self._active_dir = d
             return d
 
@@ -186,6 +211,8 @@ class Profiler:
             if self._active_dir is None:
                 raise RuntimeError("no profile running")
             d = self._active_dir
+            if self.recorder is not None:
+                self.recorder.annotating = False
             try:
                 jax.profiler.stop_trace()
             finally:

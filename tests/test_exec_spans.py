@@ -1,0 +1,361 @@
+"""Inside the executor call (docs/OBSERVABILITY.md "Inside `compute`"):
+the lane and mesh executors stamp `batch_wait`, `lock_wait`, `device` and
+`copy_out` under the call's `compute` span, the arrival window counts its
+waits, the mesh engine counts its pipeline's stage-ticks and journals its
+compiles, and a capture keeps its clock anchor from the moment it starts.
+
+Each executor is built once (module fixtures): two sessions prefill one
+after the other, then their decode steps co-arrive behind a barrier inside
+a wide window, so the step is a co-batch of 2 by construction. The tests
+read what that left behind."""
+
+import asyncio
+import glob
+import threading
+
+import jax
+import numpy as np
+import pytest
+
+from inferd_tpu.config import TINY
+from inferd_tpu.models import qwen3
+from inferd_tpu.obs import trace as tracelib
+from inferd_tpu.obs.devtel import CompileWatch
+from inferd_tpu.parallel.mesh import MeshPlan
+from inferd_tpu.runtime.node import Node
+from inferd_tpu.runtime.window import WindowedBatcher
+
+PROMPTS = {"a": [3, 7, 11], "b": [5, 13, 17]}
+PARTS = ("batch_wait", "lock_wait", "device", "copy_out")
+PP, SLOTS = 4, 4
+
+
+class Journal:
+    def __init__(self):
+        self.events = []
+
+    def emit(self, etype, **attrs):
+        self.events.append((etype, attrs))
+
+
+def timed(ex, rec, sid, payload):
+    """One executor call as the node makes it: Node._timed_process
+    allocates the `compute` context and makes it current in this thread;
+    the caller records the span afterwards."""
+    tin = tracelib.SpanContext(tracelib.new_id(), tracelib.new_id())
+    result, ms, w0, w1, ctx = Node._timed_process(None, ex, sid, payload, tin)
+    if ctx is not None:
+        rec.record_span("compute", "compute", w0, w1, parent=tin, ctx=ctx,
+                        attrs={"sid": sid})
+    return result
+
+
+def drive(ex, prefix=""):
+    """Prefill both sessions, then one co-arriving decode step of each.
+    Returns {(sid, "prefill"|"decode"): logits}."""
+    rec = tracelib.SpanRecorder("test")
+    ex.tracer = rec
+    out = {}
+    for s, ids in PROMPTS.items():
+        r = timed(ex, rec, prefix + s, {"tokens": [ids], "start_pos": 0, "real_len": 3})
+        out[s, "prefill"] = np.asarray(r["logits"])
+    barrier = threading.Barrier(len(PROMPTS))
+
+    def step(s):
+        barrier.wait()
+        tok = int(out[s, "prefill"][0].argmax())
+        r = timed(ex, rec, prefix + s, {"tokens": [[tok]], "start_pos": 3, "real_len": 1})
+        out[s, "decode"] = np.asarray(r["logits"])
+
+    threads = [threading.Thread(target=step, args=(s,)) for s in PROMPTS]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert len(out) == 4
+    return rec, out
+
+
+@pytest.fixture(scope="module")
+def params():
+    return qwen3.init_params(TINY, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def lanes(params):
+    from inferd_tpu.runtime.batch_executor import BatchedExecutor
+
+    ex = BatchedExecutor(TINY, params, lanes=4, max_len=64, window_ms=400.0)
+    rec, out = drive(ex)
+    return {"ex": ex, "spans": rec.spans(), "out": out, "stats": ex.stats(),
+            "programs": {"prefill": "jit__prefill_lane_logits",
+                         "decode": "jit__decode_logits"}}
+
+
+@pytest.fixture(scope="module")
+def mesh(params):
+    from inferd_tpu.runtime.mesh_executor import MeshExecutor
+
+    ex = MeshExecutor(TINY, params, MeshPlan(pp=PP), num_slots=SLOTS, max_len=64,
+                      devices=jax.devices()[:PP], window_ms=400.0)
+    journal = Journal()
+    CompileWatch(journal=journal).instrument_executor(ex)
+    rec, out = drive(ex)
+    return {"ex": ex, "spans": rec.spans(), "out": out, "stats": ex.stats(),
+            "events": journal.events,
+            "programs": {"prefill": "jit__step_raw", "decode": "jit__step_raw_multi"}}
+
+
+@pytest.fixture(params=["lanes", "mesh"])
+def driven(request):
+    return request.getfixturevalue(request.param)
+
+
+def children(spans, compute):
+    return sorted((s for s in spans if s["parent"] == compute["span"]),
+                  key=lambda s: s["t0"])
+
+
+def computes(spans, kind):
+    """The `compute` spans whose call was a prefill / a decode step: a
+    decode call is one that waited in the arrival window."""
+    out = []
+    for c in (s for s in spans if s["name"] == "compute"):
+        waited = any(k["name"] == "batch_wait" for k in children(spans, c))
+        if waited == (kind == "decode"):
+            out.append(c)
+    return out
+
+
+def test_prefill_call_leaves_its_three_parts(driven):
+    spans = driven["spans"]
+    calls = computes(spans, "prefill")
+    assert len(calls) == 2
+    for c in calls:
+        kids = children(spans, c)
+        assert [k["name"] for k in kids] == ["lock_wait", "device", "copy_out"]
+        lock, dev, copy = kids
+        assert lock["attrs"] == {"kind": "prefill"}
+        assert dev["attrs"] == {"kind": "prefill", "tokens": 3, "cobatch": 1,
+                                "program": driven["programs"]["prefill"]}
+        assert copy["attrs"] == {"bytes": TINY.vocab_size * 4}
+
+
+def test_decode_step_is_one_device_step_and_a_wait_per_entry(driven):
+    spans = driven["spans"]
+    calls = computes(spans, "decode")
+    assert len(calls) == 2
+    names = [[k["name"] for k in children(spans, c)] for c in calls]
+    # the flusher's call holds the step; the co-arrival's only its waits
+    assert sorted(names, key=len) == [
+        ["batch_wait", "lock_wait"],
+        ["batch_wait", "lock_wait", "device", "copy_out"],
+    ]
+    dev = [s for s in spans if s["name"] == "device" and s["attrs"]["kind"] == "decode"]
+    assert len(dev) == 1
+    assert dev[0]["attrs"] == {"kind": "decode", "tokens": 2, "cobatch": 2,
+                               "program": driven["programs"]["decode"]}
+    locks = [s for s in spans if s["name"] == "lock_wait" and s["attrs"]["kind"] == "decode"]
+    assert len(locks) == 2 and locks[0]["t0"] == locks[1]["t0"]
+    waits = [s for s in spans if s["name"] == "batch_wait"]
+    assert sorted(w["attrs"]["flusher"] for w in waits) == [0, 1]
+    copies = [k for c in calls for k in children(spans, c) if k["name"] == "copy_out"]
+    assert [k["attrs"]["bytes"] for k in copies] == [4 * TINY.vocab_size * 4]  # every lane's / slot's row
+
+
+def test_parts_lie_inside_their_compute_and_do_not_overlap(driven):
+    spans = driven["spans"]
+    for c in (s for s in spans if s["name"] == "compute"):
+        kids = children(spans, c)
+        assert kids and all(k["name"] in PARTS for k in kids)
+        assert c["t0"] <= kids[0]["t0"] and kids[-1]["t1"] <= c["t1"]
+        for a, b in zip(kids, kids[1:]):
+            assert a["t1"] <= b["t0"]
+        assert all(k["trace"] == c["trace"] for k in kids)
+
+
+def test_window_counters_are_fed_by_the_same_stamps(driven):
+    st = driven["stats"]
+    assert st["queue_waits"] == 2 == st["batched_tokens"]
+    waits = [s for s in driven["spans"] if s["name"] == "batch_wait"]
+    assert st["queue_wait_ms_sum"] == pytest.approx(
+        sum((w["t1"] - w["t0"]) * 1e3 for w in waits), abs=0.01)
+    assert st["queue_wait_ms_sum"] >= 300  # the flusher sat out its 400 ms window
+
+
+def test_pipeline_counters_count_ticks_and_live_slots(mesh):
+    """Two one-slot prefill passes (n = 1) and one decode pass with 2 of
+    the 4 slots live (n = MB): (n + PP - 1) * PP stage-ticks each, a live
+    slot using PP of them."""
+    assert mesh["stats"]["pipeline"] == {
+        "passes": 3,
+        "stage_ticks": 2 * (1 + PP - 1) * PP + (SLOTS + PP - 1) * PP,
+        "stage_ticks_useful": 2 * PP + 2 * PP,
+    }
+
+
+def test_one_more_decode_pass_adds_its_ticks(mesh):
+    ex = mesh["ex"]
+    before = dict(ex.stats()["pipeline"])
+    ex.process("a", {"tokens": [[1]], "start_pos": 4, "real_len": 1})
+    after = ex.stats()["pipeline"]
+    assert after["passes"] - before["passes"] == 1
+    assert after["stage_ticks"] - before["stage_ticks"] == (SLOTS + PP - 1) * PP
+    assert after["stage_ticks_useful"] - before["stage_ticks_useful"] == 1 * PP
+
+
+def test_mesh_engine_compiles_are_journaled(mesh):
+    begun = [a["name"] for t, a in mesh["events"] if t == "compile.begin"]
+    assert "MeshExecutor.engine._step_raw" in begun
+    assert "MeshExecutor.engine._step_raw_multi" in begun
+    assert len([t for t, _a in mesh["events"] if t == "compile.end"]) == len(begun)
+
+
+def test_trace_off_records_nothing_and_changes_no_result(driven, monkeypatch):
+    monkeypatch.setenv("INFERD_TRACE", "0")
+    rec, out = drive(driven["ex"], prefix="off-")
+    assert rec.spans() == []
+    for key, logits in driven["out"].items():
+        np.testing.assert_array_equal(out[key], logits)
+
+
+# ------------------------------------------------ the arrival window alone
+
+
+def _released_together(batcher, n):
+    barrier, got = threading.Barrier(n), {}
+
+    def one(i):
+        barrier.wait()
+        got[i] = batcher.submit(i)
+
+    threads = [threading.Thread(target=one, args=(i,)) for i in range(n)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+    return got
+
+
+@pytest.mark.parametrize("mode", ["plain", "swap_in_run", "absorbed"])
+def test_every_entry_served_has_its_wait_stamped(mode):
+    """queue_waits equals the entries served whichever way a flush takes
+    them: the flusher's own swap, a swap_in_run callback's drain, or a
+    running step's drain_pending absorbing a later arrival."""
+    rec = tracelib.SpanRecorder("w")
+    first_running, may_finish = threading.Event(), threading.Event()
+
+    def deliver(entries):
+        for e in entries:
+            e.result = ("ok", e.payload)
+            e.event.set()
+
+    def run_batch(entries):
+        if mode == "swap_in_run":
+            deliver(batcher.drain_pending())
+        elif mode == "absorbed" and not first_running.is_set():
+            first_running.set()
+            may_finish.wait(timeout=30)
+            deliver(entries + batcher.drain_pending())
+        else:
+            deliver(entries)
+
+    batcher = WindowedBatcher(
+        0.2, run_batch, co_possible=lambda: mode != "absorbed" or first_running.is_set(),
+        swap_in_run=(mode == "swap_in_run"),
+    )
+    batcher.tracer = rec
+    if mode == "absorbed":
+        got = {}
+        a = threading.Thread(target=lambda: got.update(a=batcher.submit("a")))
+        a.start()
+        assert first_running.wait(timeout=30)
+        b = threading.Thread(target=lambda: got.update(b=batcher.submit("b")))
+        b.start()
+        while not batcher._pending:  # b sits in its window: a's step takes it
+            threading.Event().wait(0.005)
+        may_finish.set()
+        a.join(timeout=30)
+        b.join(timeout=30)
+        assert got == {"a": ("ok", "a"), "b": ("ok", "b")}
+        n = 2
+    else:
+        n = 3
+        assert _released_together(batcher, n) == {i: ("ok", i) for i in range(n)}
+    st = batcher.stats()
+    assert st["queue_waits"] == n == st["batched_tokens"]
+    waits = [s for s in rec.spans() if s["name"] == "batch_wait"]
+    assert len(waits) == n and all(w["t1"] >= w["t0"] for w in waits)
+    assert st["queue_wait_ms_sum"] == pytest.approx(
+        sum((w["t1"] - w["t0"]) * 1e3 for w in waits), abs=0.01)
+
+
+def test_region_is_a_no_op_without_a_recorder_or_with_tracing_off(monkeypatch):
+    with tracelib.region(None, "device", kind="decode") as at:
+        at["bytes"] = 1
+    rec = tracelib.SpanRecorder("r")
+    monkeypatch.setenv("INFERD_TRACE", "0")
+    with tracelib.region(rec, "device"):
+        pass
+    lock = threading.Lock()
+    with tracelib.holding(lock, rec, kind="decode"):
+        assert lock.locked()
+    assert not lock.locked() and rec.spans() == []
+
+
+def test_region_records_one_span_per_parent():
+    rec = tracelib.SpanRecorder("r")
+    parents = [tracelib.SpanContext("t1", "p1"), tracelib.SpanContext("t2", "p2")]
+    with tracelib.region(rec, "lock_wait", parents, kind="decode"):
+        pass
+    got = rec.spans()
+    assert [(s["trace"], s["parent"]) for s in got] == [("t1", "p1"), ("t2", "p2")]
+    assert got[0]["t0"] == got[1]["t0"] and got[0]["attrs"] == {"kind": "decode"}
+
+
+# ------------------------------------------------------------ the capture
+
+
+@pytest.mark.asyncio
+async def test_capture_span_is_there_before_the_capture_closes(tmp_path):
+    """POST /profile window: the `capture` span (the clock anchor of every
+    reader) is in /spans while the capture is still open, `capture_close`
+    once the trace is written; the written trace holds the anchor event
+    whose end the span's t0 names, and no python call stack."""
+    import aiohttp
+
+    from inferd_tpu.runtime import wire
+    from test_node_e2e import BASE, _mk_node
+
+    node = _mk_node(190, 0, 1, bootstrap_idx=190)
+    node.enable_profiling = True
+    node.profiler.base_dir = str(tmp_path / "profiles")
+    await node.start()
+    try:
+        async with aiohttp.ClientSession() as http:
+            body = wire.pack({"action": "window", "seconds": 1.0, "capture_id": "t"})
+            async with http.post(f"http://127.0.0.1:{BASE + 190}/profile", data=body) as r:
+                assert r.status == 200
+            assert node.profiler.active_dir is not None  # still capturing
+            open_spans = {s["name"]: s for s in node.tracer.spans()}
+            cap = open_spans["capture"]
+            assert "capture_close" not in open_spans
+            assert cap["t1"] - cap["t0"] == pytest.approx(1.0)
+            assert cap["t0"] == node.profiler.started_at
+            assert node.tracer.annotating
+            await asyncio.wait_for(node._capture_task, timeout=60)
+        assert node.profiler.active_dir is None and not node.tracer.annotating
+        closed = {s["name"]: s for s in node.tracer.spans()}
+        assert closed["capture_close"]["t0"] >= cap["t1"] - 1e-3
+        assert closed["capture_close"]["attrs"] == {"capture_id": "t"}
+        types = [ev["type"] for ev in node.journal.events()]
+        assert types.index("profile.capture") < types.index("profile.capture_done")
+    finally:
+        await node.stop()
+
+    (path,) = glob.glob(str(tmp_path / "profiles" / "**" / "*.xplane.pb"), recursive=True)
+    names = [e.name for plane in jax.profiler.ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events]
+    anchors = [n for n in names if "start_trace" in n]
+    assert anchors == ["inferd.start_trace.anchor"]
+    assert not any(n.startswith("$") for n in names)  # the python tracer's events
