@@ -12,10 +12,11 @@ by it (weights are read once per BATCHED step).
 
 Concurrency design (process() runs on the node's worker thread pool):
   * decode steps (real_len == 1 at the session's frontier) enqueue into a
-    pending batch; the FIRST arrival becomes the flusher — it waits up to
-    `window_ms` for co-arrivals, takes the device lock, runs one batched
-    step for every pending lane, and distributes each lane's logits to its
-    waiting thread;
+    pending batch; the FIRST arrival becomes the flusher — it waits out a
+    step that is still running, then (no lock held) for the lanes the
+    last two steps served, takes the device lock, runs one batched step
+    for every lane pending AT THAT MOMENT, and distributes each lane's
+    logits to its waiting thread (runtime/window.py, formation);
   * prefill chunks (multi-token or unknown session) run solo under the
     same device lock (per-lane cache writes, other lanes untouched);
   * whole-model executor: is_first and is_last (tokens in, last-token
@@ -118,10 +119,18 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         self._inflight: Dict[str, int] = {}  # session -> active request count
         self._dying: Dict[int, str] = {}  # lane -> ended session awaiting drain
         self._batcher = WindowedBatcher(
+            # only the start value of the batcher's own estimate of a
+            # session's turn (result out -> next submit); see `expect`
             window_ms / 1e3,
             self._run_decode_batch,
             # a solo session should not pay the window latency
             co_possible=lambda: len(self._sessions) > 1,
+            # formation: the batch is drained under _dev_lock, and the
+            # flusher waits for the lanes the last two steps served.
+            # _drop (end, eviction, sweep) and a prefill take a lane out
+            # of that expectation at once.
+            swap_in_run=True,
+            expect=lambda payload: payload[0],
         )
         self._spec_window_s = window_ms / 1e3
         # lane-batched speculation (enable_spec): None until enabled
@@ -144,8 +153,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
     @property
     def tracer(self):
         """Span recorder (the node wires its own, next to on_event): the
-        decode flush and the dense prefill stamp lock_wait / device /
-        copy_out under the call's `compute` span, the batcher batch_wait."""
+        decode flush stamps device / copy_out and the dense prefill also
+        its lock_wait under the call's `compute` span; the batcher stamps
+        a decode entry's lock_wait and batch_wait."""
         return self._batcher.tracer
 
     @tracer.setter
@@ -476,6 +486,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
 
     def _process_inner(self, session_id: str, payload: Dict[str, Any],
                        toks, start_pos: int, real_len: int, acquired):
+        # one token at an established frontier; anything else is a prefill
+        decode = real_len == 1 and start_pos > 0
         with self._mu:
             if self._inflight.get(session_id):
                 # a duplicate/replayed request racing the original would
@@ -543,7 +555,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                             from_pos=start_pos,
                             blocks=self.pool.cow_splits - before,
                         )
-            if self.pool is not None and real_len == 1 and start_pos > 0:
+            if self.pool is not None and decode:
                 # decode dispatches write positions [start_pos,
                 # start_pos + K): the chain must cover them before the jit
                 # scatters (prefill ensures per chunk instead)
@@ -552,9 +564,13 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 self.pool.ensure(lane, start_pos + k_req, owner=owner)
             self._bind_adapter_locked(session_id, lane, start_pos, acquired)
             self._inflight[session_id] = 1
+            if not decode:
+                # inside a prefill: no decode step waits for this lane
+                # until one has served it again
+                self._batcher.unexpect(lambda p, _lane=lane: p[0] == _lane)
 
         try:
-            if real_len == 1 and start_pos > 0:
+            if decode:
                 from inferd_tpu.runtime.executor import parse_kstep
 
                 ks = parse_kstep(payload, self.cap - start_pos)
@@ -714,9 +730,14 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
     def _decode_batched(self, session_id: str, lane: int, token: int, ks=None):
         return self._batcher.submit((lane, token, ks))
 
-    def _run_decode_batch(self, entries) -> None:
-        """Flush callback: ONE batched device step for every waiting lane
-        (runtime/window.py calls this with no locks held).
+    def _run_decode_batch(self, _empty) -> None:
+        """Flush callback: ONE batched device step for every lane whose
+        entry is pending when the device lock is acquired
+        (runtime/window.py calls this with an empty list and no locks
+        held, once its formation wait is over; an entry that arrived
+        while the previous step or a prefill held the device is drained
+        with the rest). A drain that finds nothing (every waiting entry
+        was invalidated meanwhile) runs no program and counts no step.
 
         Entries partition into the classic logits contract (client-side
         sampling, one token per dispatch) and multi-step fused decode
@@ -746,14 +767,12 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             cache_intact, fuse_kstep_group, kstep_hi,
         )
 
-        legacy = [e for e in entries if e.payload[2] is None]
-        kstep = [e for e in entries if e.payload[2] is not None]
         poisoned: Optional[Exception] = None
-        # one lock_wait per entry, under each entry's own `compute`
-        waiting = [e.ctx for e in entries] if self.tracer is not None else None
-        with tracelib.holding(
-            self._dev_lock, self.tracer, waiting, kind="decode"
-        ):
+        with self._dev_lock:
+            # the batcher stamps each entry's lock_wait and batch_wait
+            entries = self._batcher.drain_pending()
+            legacy = [e for e in entries if e.payload[2] is None]
+            kstep = [e for e in entries if e.payload[2] is not None]
             if legacy:
                 try:
                     with self._mu:
@@ -801,9 +820,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 except Exception as exc:
                     for e in legacy:
                         e.error = exc
-                    # the window flush counts every live entry as served
-                    # AFTER this callback returns; net failed entries to
-                    # zero so /stats batched_tokens stays token-true
+                    # the drain counted every live entry as served; net
+                    # failed entries to zero so /stats batched_tokens
+                    # stays token-true
                     self._batcher.n_served -= len(legacy)
                     if not cache_intact(self.engine.cache):
                         poisoned = exc
@@ -857,7 +876,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                             "decode_steps": kg,
                             "key": nkeys[lane].tolist(),  # jaxlint: disable=J003 -- host array row unpack, no device sync
                         }
-                    # token-true stats: the window flush loop counts one
+                    # token-true stats: the drain counts one
                     # served unit per ENTRY; a K-step entry really served
                     # n tokens — /stats batched_tokens and mean_batch
                     # must reflect tokens, not dispatches

@@ -17,8 +17,8 @@ errors raised by run_batch are propagated to every entry in the batch.
 still waiting in the window (never started), so a freed lane/slot can be
 reused without a stale write racing its new owner.
 
-Two opt-in modes power STAGE-level continuous batching (runtime/node +
-runtime/stage_batch — see docs/SERVING.md):
+Opt-in modes power CONTINUOUS batching (runtime/node + runtime/stage_batch
+for stages, runtime/batch_executor for lanes — see docs/SERVING.md):
   * `swap_in_run`: the flusher passes run_batch an EMPTY list and the
     callback pulls the batch itself via `drain_pending()` once it holds
     the device — entries arriving mid-step join the next step instead of
@@ -26,14 +26,23 @@ runtime/stage_batch — see docs/SERVING.md):
   * `gang_target`: the window wait ends early once every live idle
     session's entry is pending, which merges phase-offset session
     cohorts into one lockstep co-batch and lets the window be sized
-    generously without charging steady-state latency.
+    generously without charging steady-state latency;
+  * `expect` (with `swap_in_run`): FORMATION. The batcher watches whom
+    its steps serve. A flusher first waits out a step that is still
+    running, then — the device free, no lock held — for the entry of
+    every session served by the last step or the one before it, or until
+    a cap since the device freed runs out; only then does the callback
+    take the device and drain. The flusher keeps its slot until that
+    drain, so no second flusher ever queues on the device lock behind it.
+    The cap is derived: three times a running mean of a served session's
+    result -> next submit, never above the last measured step.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Callable, List, Optional  # noqa: F401
+from typing import Any, Callable, Dict, Hashable, List, Optional  # noqa: F401
 
 from inferd_tpu.obs import trace as tracelib
 from inferd_tpu.utils import lockwatch
@@ -41,7 +50,7 @@ from inferd_tpu.utils import lockwatch
 
 class Entry:
     __slots__ = ("payload", "event", "result", "error",
-                 "t_submit", "t_taken", "ctx")
+                 "t_submit", "t_lock", "t_taken", "ctx")
 
     def __init__(self, payload: Any):
         self.payload = payload
@@ -54,6 +63,10 @@ class Entry:
         # context, so the flusher can parent what it waits for on the
         # entry's behalf (`lock_wait`) to the entry's own `compute`
         self.t_submit = tracelib.now()
+        # formation only: submit -> t_lock is the part of the wait during
+        # which the device was held by a step that did not serve this
+        # entry (`lock_wait`), t_lock -> t_taken the rest (`batch_wait`)
+        self.t_lock: Optional[float] = None
         self.t_taken: Optional[float] = None
         self.ctx = tracelib.current()
 
@@ -67,7 +80,10 @@ class WindowedBatcher:
         wait_timeout_s: float = 120.0,
         swap_in_run: bool = False,
         gang_target: Optional[Callable[[], int]] = None,
+        expect: Optional[Callable[[Any], Hashable]] = None,
     ):
+        if expect is not None and not swap_in_run:
+            raise ValueError("expect needs swap_in_run (the callback drains)")
         self.window_s = window_s
         self._run_batch = run_batch
         self._co_possible = co_possible
@@ -93,9 +109,29 @@ class WindowedBatcher:
         # convoy of mini-batches queued on the device lock). The callback
         # owns every drained entry: result/error AND event delivery.
         self._swap_in_run = swap_in_run
+        # formation (see the module docstring): `expect(payload)` names the
+        # session an entry belongs to. Everything below is guarded by
+        # self._mu; self._cv wakes a forming flusher on a submit, a step's
+        # end and an invalidation.
+        self._expect = expect
         self._mu = lockwatch.make_lock("window")
+        self._cv = threading.Condition(self._mu)
         self._pending: List[Entry] = []
         self._flusher_active = False
+        self._running = False  # a drained step has not returned yet
+        self._ticket: Optional[list] = None  # the forming flusher's drain slot
+        self._t_drain = 0.0  # when the last batch was taken
+        self._t_freed = 0.0  # when the last drained step returned
+        self._t_formed = 0.0  # when the last formation wait ended
+        # session -> its last served payload, for the last step and the
+        # one before it: the sessions a formation waits for
+        self._served: List[Dict[Hashable, Any]] = [{}, {}]
+        self._delivered: Dict[Hashable, float] = {}  # session -> result out
+        self._turn_s = window_s  # running mean: result out -> next submit
+        self._step_s: Optional[float] = None  # the last measured step
+        self.gang_full = 0  # formations that ended with every expected entry
+        self.gang_timeout = 0  # ... because the cap ran out
+        self.empty_drains = 0  # drains that found no live entry: no step
         self.n_steps = 0  # flushed batches
         self.n_served = 0  # entries served across those batches
         self.queue_waits = 0  # entries a flush took into a batch
@@ -122,12 +158,25 @@ class WindowedBatcher:
         """Swap the pending list out (under self._mu) and stamp the end
         of each live entry's arrival-window wait."""
         batch, self._pending = self._pending, []
-        now = tracelib.now()
+        now = self._t_drain = tracelib.now()
         for e in batch:
             if e.error is None:
                 e.t_taken = now
+                waited_from = e.t_submit
+                if self._expect is not None:
+                    # the device was not this entry's to have while the
+                    # step it arrived under still ran, and from the end
+                    # of the formation wait until the callback got the
+                    # lock (a prefill had cut in); the rest was spent
+                    # waiting for the expected sessions
+                    held = max(0.0, self._t_freed - e.t_submit) + (
+                        now - max(e.t_submit, self._t_formed)
+                    )
+                    waited_from = e.t_lock = e.t_submit + min(
+                        held, now - e.t_submit
+                    )
                 self.queue_waits += 1
-                self.queue_wait_ms_sum += (now - e.t_submit) * 1e3
+                self.queue_wait_ms_sum += (now - waited_from) * 1e3
         return batch
 
     def submit(self, payload: Any) -> Any:
@@ -138,24 +187,39 @@ class WindowedBatcher:
             if i_flush:
                 self._flusher_active = True
             wait = self._co_possible()
+            if self._expect is not None:
+                self._returned(entry)
         try:
             return self._serve(entry, i_flush, wait)
         finally:
             if self.tracer is not None and entry.t_taken is not None:
+                t_wait = entry.t_submit
+                if entry.t_lock is not None:
+                    t_wait = entry.t_lock
+                    self.tracer.record_span(
+                        "lock_wait", "lock_wait", entry.t_submit, t_wait,
+                        parent=entry.ctx, attrs={"kind": "decode"},
+                    )
                 self.tracer.record_span(
-                    "batch_wait", "batch_wait", entry.t_submit, entry.t_taken,
+                    "batch_wait", "batch_wait", t_wait, entry.t_taken,
                     parent=entry.ctx, attrs={"flusher": int(i_flush)},
                 )
 
+    def _await(self, entry: Entry, where: str) -> Any:
+        """Block until another thread's step delivers `entry`."""
+        entry.event.wait(timeout=self._wait_timeout_s)
+        if entry.error is not None:
+            raise entry.error
+        if not entry.event.is_set():
+            self._stall(where)
+            raise TimeoutError("batched decode flusher never completed")
+        return entry.result
+
     def _serve(self, entry: Entry, i_flush: bool, wait: bool) -> Any:
         if not i_flush:
-            entry.event.wait(timeout=self._wait_timeout_s)
-            if entry.error is not None:
-                raise entry.error
-            if not entry.event.is_set():
-                self._stall("co_arrival")
-                raise TimeoutError("batched decode flusher never completed")
-            return entry.result
+            return self._await(entry, "co_arrival")
+        if self._expect is not None:
+            return self._flush_formed(entry, wait)
 
         if wait:
             if self._gang_target is None:
@@ -194,13 +258,7 @@ class WindowedBatcher:
                 if not entry.event.is_set():
                     entry.error = entry.error or exc
                     entry.event.set()
-            entry.event.wait(timeout=self._wait_timeout_s)
-            if entry.error is not None:
-                raise entry.error
-            if not entry.event.is_set():
-                self._stall("swap_in_run")
-                raise TimeoutError("batched decode flusher never completed")
-            return entry.result
+            return self._await(entry, "swap_in_run")
         with self._mu:
             batch = self._take()
             self._flusher_active = False
@@ -223,17 +281,136 @@ class WindowedBatcher:
             # a concurrent flusher's drain_pending() absorbed this entry
             # into ITS device step before we could swap — wait for that
             # step to deliver, exactly like a non-flusher co-arrival
-            entry.event.wait(timeout=self._wait_timeout_s)
-            if not entry.event.is_set():
-                self._stall("absorbed")
-                raise TimeoutError("batched decode flusher never completed")
+            return self._await(entry, "absorbed")
         if entry.error is not None:
             raise entry.error
         return entry.result
 
+    # -- formation (expect=...) ---------------------------------------------
+
+    def _returned(self, entry: Entry) -> None:
+        """A submit under self._mu: feed the turn estimate with this
+        session's result-out -> submit, and wake a forming flusher."""
+        out = self._delivered.pop(self._expect(entry.payload), None)
+        if out is not None:
+            turn = max(0.0, entry.t_submit - out)
+            if self._step_s is not None:
+                # the cap never passes a step, so neither need a sample
+                turn = min(turn, self._step_s)
+            # quick to rise, slow to fall: the cap has to cover the
+            # slowest of the sessions it waits for, not the typical one
+            gain = 0.5 if turn > self._turn_s else 0.05
+            self._turn_s += gain * (turn - self._turn_s)
+        self._cv.notify_all()
+
+    def _cap_s(self) -> float:
+        """The longest a formation waits once the device is free: a few
+        turns of a served session, never more than a step — beyond that
+        two alternating cohorts would serve more than lockstep does."""
+        cap = 3.0 * self._turn_s
+        return cap if self._step_s is None else min(cap, self._step_s)
+
+    def _form(self, wait: bool) -> None:
+        """The flusher's wait before it asks for the device (under
+        self._mu through self._cv, no other lock held): a step still
+        running is waited out whatever it takes; then, the device free,
+        the entries of the sessions the last two steps served, until
+        `_cap_s` since the device freed."""
+        stall_at = time.monotonic() + self._wait_timeout_s
+        while self._running:
+            left = stall_at - time.monotonic()
+            if left <= 0:
+                self._stall("formation")
+                raise TimeoutError("batched decode step never completed")
+            self._cv.wait(timeout=left)
+        while wait:
+            here = {
+                self._expect(e.payload) for e in self._pending if e.error is None
+            }
+            if not here:
+                break  # every waiting entry was invalidated
+            missing = [
+                k for served in self._served for k in served if k not in here
+            ]
+            if not missing:
+                self.gang_full += 1
+                break
+            left = self._t_freed + self._cap_s() - tracelib.now()
+            if left <= 0:
+                # whoever is not back by now is not waited for again
+                # until a step has served it
+                self.gang_timeout += 1
+                for served in self._served:
+                    for k in missing:
+                        served.pop(k, None)
+                break
+            self._cv.wait(timeout=left)
+        self._t_formed = tracelib.now()
+
+    def _flush_formed(self, entry: Entry, wait: bool) -> Any:
+        """Form, let the callback take the device and drain, then note
+        whom the step served and when, and deliver."""
+        ticket: list = [None]  # drain_pending() leaves the step's entries
+        failed: Optional[Exception] = None
+        try:
+            with self._cv:
+                self._ticket = ticket
+                self._form(wait)
+            self._run_batch([])
+        except Exception as exc:
+            failed = exc
+        now = tracelib.now()
+        with self._cv:
+            drained = self._ticket is not ticket
+            if drained:
+                batch = ticket[0]
+                self._running = False
+                self._t_freed = now
+            else:
+                # the callback never drained: whatever is pending has no
+                # flusher any more and would hang its submitter
+                self._ticket = None
+                self._flusher_active = False
+                failed = failed or RuntimeError("run_batch never drained")
+                batch = [e for e in self._take() if e.error is None]
+            served = {}
+            for e in batch:
+                if e.error is None and e.result is None:
+                    e.error = failed or RuntimeError("entry was not served")
+                if e.error is None:
+                    served[self._expect(e.payload)] = e.payload
+            if served:
+                self._step_s = now - self._t_drain
+                self._served = [served, self._served[0]]
+                self._delivered.update(dict.fromkeys(served, now))
+            self._cv.notify_all()
+        for e in batch:
+            e.event.set()
+        return self._await(entry, "formation")
+
+    def unexpect(self, pred: Callable[[Any], bool]) -> None:
+        """Stop waiting for the sessions whose last served payload matches
+        `pred` (ended, evicted, or inside a prefill): the next step that
+        serves one expects it again."""
+        with self._cv:
+            self._unexpect(pred)
+
+    def _unexpect(self, pred: Callable[[Any], bool]) -> None:
+        for served in self._served:
+            for k in [k for k, payload in served.items() if pred(payload)]:
+                del served[k]
+                self._delivered.pop(k, None)
+        self._cv.notify_all()
+
     def stats(self) -> dict:
         """Coalescing effectiveness counters (shared by both executors)."""
+        formation = {} if self._expect is None else {
+            "gang_full": self.gang_full,
+            "gang_timeout": self.gang_timeout,
+            "empty_drains": self.empty_drains,
+        }
         return {
+            **formation,
             "batched_steps": self.n_steps,
             "batched_tokens": self.n_served,
             "mean_batch": round(self.n_served / self.n_steps, 3)
@@ -259,7 +436,17 @@ class WindowedBatcher:
         co-arrival."""
         with self._mu:
             batch = self._take()
-        live = [e for e in batch if e.error is None]
+            live = [e for e in batch if e.error is None]
+            if self._ticket is not None:
+                # formation: the flusher's slot goes with its drain, so
+                # whoever submits from here on forms the NEXT step and
+                # first waits this one out
+                self._ticket[0] = live
+                self._ticket = None
+                self._flusher_active = False
+                self._running = True
+                if not live:
+                    self.empty_drains += 1
         if live:
             self.n_steps += 1
             self.n_served += len(live)
@@ -279,3 +466,5 @@ class WindowedBatcher:
                 else:
                     still.append(e)
             self._pending[:] = still
+            if self._expect is not None:
+                self._unexpect(pred)

@@ -142,7 +142,9 @@ def build_parser() -> argparse.ArgumentParser:
         "--window-ms", type=float,
         default=float(os.environ.get("INFERD_WINDOW_MS", "2.0")),
         help="arrival-window length for --stage-lanes decode co-batching "
-        "(env INFERD_WINDOW_MS); a solo session never pays it",
+        "(env INFERD_WINDOW_MS); a solo session never pays it. Reaches "
+        "the --stage-lanes window only: --batch-lanes derives its wait "
+        "from the turns and steps it observes, --mesh keeps its 3 ms",
     )
     ap.add_argument(
         "--paged-kv", type=int,

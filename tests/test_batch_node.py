@@ -65,50 +65,75 @@ async def test_concurrent_generations_match_solo(whole_parts):
 
 
 def test_decode_steps_actually_batch(whole_parts):
-    """Decode steps of co-arriving sessions must coalesce into one device
-    step. Driven directly (threads + barrier) so co-arrival is guaranteed
-    rather than hoped for from HTTP timing."""
+    """Five closed-loop sessions (a client's token loop each: one decode
+    step, sample, the next) ride the same device steps: the batch forms
+    when the device frees and waits for the sessions just served
+    (runtime/window.py, formation), so after the first few steps every
+    step serves all five — and each session still decodes exactly what it
+    decodes alone. Driven directly through process() in threads, so the
+    closed loops are the only timing there is; the tiny model's step is
+    stretched to 20 ms, a step's length against a host turn as on a chip
+    (where a loaded test machine's scheduler cannot pass for a client
+    that went away)."""
     import threading
+    import time
+
+    import numpy as np
 
     from inferd_tpu.runtime.batch_executor import BatchedExecutor
 
     parts, params = whole_parts
-    ex = BatchedExecutor(TINY, params, lanes=4, max_len=64, window_ms=100.0)
+    ex = BatchedExecutor(TINY, params, lanes=6, max_len=64)  # five and a warm-up
+    prompts = {
+        "s0": [3, 7, 11], "s1": [2, 5, 13, 17], "s2": [23, 29],
+        "s3": [31, 37, 41, 43, 47], "s4": [53, 59, 61],
+    }
+    new = 32
+    engine = Engine(TINY, params, max_len=64,
+                    sampling_cfg=SamplingConfig(temperature=0.0))
+    want = {s: engine.generate(p, max_new_tokens=new, seed=0)
+            for s, p in prompts.items()}
 
-    hwm = {"n": 0}
+    got = {}
+    for s, p in prompts.items():
+        r = ex.process(s, {"tokens": [p], "start_pos": 0, "real_len": len(p)})
+        got[s] = [int(np.argmax(r["logits"][0]))]
+    # one decode step of one session, so that no loop below waits for XLA
+    ex.process("warm", {"tokens": [[1, 2]], "start_pos": 0, "real_len": 2})
+    ex.process("warm", {"tokens": [[3]], "start_pos": 2, "real_len": 1})
+    ex.end_session("warm")
+    program = ex.engine._decode_logits
 
-    class TrackingList(list):
-        def append(self, item):
-            super().append(item)
-            hwm["n"] = max(hwm["n"], len(self))
+    def slow_step(*args, **kwargs):
+        time.sleep(0.02)
+        return program(*args, **kwargs)
 
-    ex._batcher._pending = TrackingList(ex._batcher._pending)
+    ex.engine._decode_logits = slow_step
+    before = ex.stats()
+    barrier = threading.Barrier(len(prompts))
 
-    sessions = [f"s{i}" for i in range(3)]
-    last = {}
-    for i, s in enumerate(sessions):
-        r = ex.process(s, {"tokens": [[3 + i, 7, 11]], "start_pos": 0, "real_len": 3})
-        last[s] = int(r["logits"][0].argmax())
-
-    barrier = threading.Barrier(len(sessions))
-    results = {}
-
-    def step(s):
+    def loop(s):
         barrier.wait()
-        results[s] = ex.process(
-            s, {"tokens": [[last[s]]], "start_pos": 3, "real_len": 1}
-        )
+        for i in range(new - 1):
+            r = ex.process(s, {"tokens": [[got[s][-1]]],
+                               "start_pos": len(prompts[s]) + i, "real_len": 1})
+            assert r["logits"].shape == (1, TINY.vocab_size)
+            got[s].append(int(np.argmax(r["logits"][0])))
+        ex.end_session(s)
 
-    threads = [threading.Thread(target=step, args=(s,)) for s in sessions]
+    threads = [threading.Thread(target=loop, args=(s,)) for s in prompts]
     for t in threads:
         t.start()
     for t in threads:
-        t.join(timeout=60)
-    assert len(results) == 3
-    assert hwm["n"] >= 2, "no decode step ever batched >1 session"
-    # and the batched logits match a solo decode of the same session state
-    for s in sessions:
-        assert results[s]["logits"].shape == (1, TINY.vocab_size)
+        t.join(timeout=120)
+    assert got == want
+    st = ex.stats()
+    tokens = st["batched_tokens"] - before["batched_tokens"]
+    steps = st["batched_steps"] - before["batched_steps"]
+    assert tokens == 5 * (new - 1)  # token-true: one count a decoded token
+    assert tokens / steps >= 4.0, (tokens, steps, st)
+    assert st["gang_full"] >= steps - st["gang_timeout"] - 1
+    assert st["empty_drains"] == 0
 
 
 @pytest.mark.asyncio

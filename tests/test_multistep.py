@@ -380,9 +380,8 @@ def test_batched_kstep_device_failure_poisons_window_clearly(solo_setup):
     leaves the shared KV buffers deleted: the window must stop
     dispatching and fail the remaining groups with a clear 'KV cache
     invalidated' error instead of handing them dead buffers."""
-    import types
-
     from inferd_tpu.runtime.batch_executor import BatchedExecutor
+    from inferd_tpu.runtime.window import Entry
 
     cfg, params, _spec, _sp = solo_setup
     bx = BatchedExecutor(cfg, params, lanes=4, max_len=64, window_ms=5.0)
@@ -396,14 +395,14 @@ def test_batched_kstep_device_failure_poisons_window_clearly(solo_setup):
         raise RuntimeError("injected device failure")
 
     la, lb = bx._sessions["a"], bx._sessions["b"]
-    ea = types.SimpleNamespace(payload=(la, ta, None), result=None,
-                               error=None)
+    ea = Entry((la, ta, None))
     ks = {"k": 3, "sampling": (0.0, 0, 1.0, 0.0), "eos": -1,
           "key": np.zeros(2, np.uint32)}
-    eb = types.SimpleNamespace(payload=(lb, tb, ks), result=None,
-                               error=None)
+    eb = Entry((lb, tb, ks))
     bx.engine._decode_logits = boom
-    bx._run_decode_batch([ea, eb])
+    # both wait in the window; the flush drains them under the device lock
+    bx._batcher._pending.extend([ea, eb])
+    bx._run_decode_batch([])
     assert "injected device failure" in str(ea.error)
     assert "KV cache invalidated" in str(eb.error)
     assert eb.result is None
