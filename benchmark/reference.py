@@ -4,7 +4,10 @@ float32, `default_matmul_precision("highest")`, no cache, no kernel, no
 batching, written from the published equations (Qwen3 technical report;
 HF `modeling_qwen3.py`) and independent of `inferd_tpu/models/qwen3.py`.
 Of the program it uses only `parallel.stages.load_stage_checkpoint`, to
-read the file the node serves.
+read the file the node serves. Every size comes from `--config`, the
+configuration's file of published keys (`num_attention_heads`,
+`num_key_value_heads`, `head_dim`, `rms_norm_eps`, `rope_theta`,
+`tie_word_embeddings`), which run.py has tied to the program's preset.
 
     x   = E[tokens]
     per layer:
@@ -16,11 +19,20 @@ read the file the node serves.
       x   = x + o Wo
       m   = RMSNorm(x; w_post)
       x   = x + (silu(m Wg) * (m Wu)) Wd
-    logits = RMSNorm(x[-1]; w_final) @ (E^T if tied else W_head)
+    logits = RMSNorm(x[-M:]; w_final) @ (E^T if tied else W_head)
 
-Layers stream through one device one at a time, so a model that does not
-fit a chip in float32 (or at all) still has a reference. Output: the
-last-position log-probabilities over the whole vocabulary, as .npy.
+One full forward pass over `prompt + continue` (teacher forcing: the
+continuation is given, nothing is sampled, nothing is cached). Layers
+stream through one device one at a time, so a model that does not fit a
+chip in float32 (or at all) still has a reference. Output: `[M, V]` float32
+log-probabilities as .npy, M = 1 + len(continue), row j at position
+len(prompt) - 1 + j: what follows the prompt, then what follows each token
+of the continuation but the last.
+
+A reference of another architecture is a file `references/<name>.py` of its
+own, with the same arguments and output, that takes nothing from this one.
+`logprobs` takes one sequence or several of one length, each on its own
+(`control.py` reads a dozen probes in one pass over the weights).
 
 Departure from the published model: none in the equations; the weights are
 the seeded random bf16 values of the checkpoint, read as float32.
@@ -29,6 +41,7 @@ the seeded random bf16 values of the checkpoint, read as float32.
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import sys
 
@@ -77,30 +90,43 @@ def layer(x, p, heads, kv_heads, d, eps, theta):
     return x + (jax.nn.silu(m @ p["gate_proj"]) * (m @ p["up_proj"])) @ p["down_proj"]
 
 
-def last_logprobs(params, tokens, heads, kv_heads, d, eps, theta, tied):
+def logprobs(params, tokens, rows, config):
+    """Log-probabilities [rows, V] at the last `rows` positions of `tokens`
+    [S]; of tokens [B, S], sequences that do not see each other, [B, rows, V]."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    heads, kv_heads = config["num_attention_heads"], config["num_key_value_heads"]
+    d, eps, theta = config["head_dim"], config["rms_norm_eps"], config["rope_theta"]
     f32 = lambda a: jnp.asarray(np.asarray(a), jnp.float32)  # noqa: E731
-    step = jax.jit(layer, static_argnums=(2, 3, 4, 5, 6))
+    step = jax.jit(jax.vmap(layer, in_axes=(0,) + (None,) * 6), static_argnums=(2, 3, 4, 5, 6))
+    tokens = np.asarray(tokens)
     with jax.default_matmul_precision("highest"):
-        x = f32(np.asarray(params["embed"])[np.asarray(tokens)])
+        x = f32(np.asarray(params["embed"])[np.atleast_2d(tokens)])
         n_layers = np.asarray(params["layers"]["input_norm"]).shape[0]
         for i in range(n_layers):
             p = {k: f32(np.asarray(v)[i]) for k, v in params["layers"].items()}
             x = step(x, p, heads, kv_heads, d, eps, theta)
-        h = rms_norm(x[-1], f32(params["final_norm"]), eps)
+        h = rms_norm(x[:, -rows:], f32(params["final_norm"]), eps)
+        tied = config["tie_word_embeddings"]
         head = f32(params["embed"]).T if tied else f32(params["lm_head"])
-        return np.asarray(jax.nn.log_softmax(h @ head))
+        lp = np.asarray(jax.nn.log_softmax(h @ head, axis=-1))
+        return lp if tokens.ndim == 2 else lp[0]
+
+
+def ids(text: str):
+    return [int(t) for t in text.split(",") if t]
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--ckpt", required=True)
-    ap.add_argument("--model", required=True, help="the program's preset, for its sizes")
+    ap.add_argument("--model", required=True, help="the program's preset; no size is read from it")
+    ap.add_argument("--config", required=True, help="the configuration's file: every size")
     ap.add_argument("--device", required=True, choices=["tpu", "cpu"])
     ap.add_argument("--prompt-ids", required=True)
+    ap.add_argument("--continue-ids", default="", help="the tokens that follow, but the last")
     ap.add_argument("--out", required=True)
     args = ap.parse_args(argv)
     os.environ["JAX_PLATFORMS"] = args.device
@@ -111,14 +137,13 @@ def main(argv=None) -> int:
     if jax.devices()[0].platform != args.device:
         print(f"asked for {args.device}, JAX gave {jax.devices()[0].platform}", file=sys.stderr)
         return 2
-    from inferd_tpu.config import get_config
     from inferd_tpu.parallel.stages import load_stage_checkpoint
 
-    cfg = get_config(args.model)
+    with open(args.config) as f:
+        config = json.load(f)
     params, _spec, _name = load_stage_checkpoint(args.ckpt)
-    tokens = [int(t) for t in args.prompt_ids.split(",")]
-    lp = last_logprobs(params, tokens, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-                       cfg.rms_norm_eps, cfg.rope_theta, cfg.tie_word_embeddings)
+    more = ids(args.continue_ids)
+    lp = logprobs(params, ids(args.prompt_ids) + more, 1 + len(more), config)
     if not np.isfinite(lp).all():
         print("the reference's log-probabilities are not finite", file=sys.stderr)
         return 3

@@ -18,18 +18,45 @@ metric by name: a cell of BENCHMARK.json names `configs/<config>.json` and
 per-layer metric is `layer_metrics/<metric-name>.py` with one function
 `read(run) -> number | None`. A later PR adds files and entries.
 
+What a configuration's file may say besides its published keys, its
+`preset`, `node_flags`, `slots`, `weights_seed`, `logprob_tolerance` and
+`rehearse`; each key optional, an absent key meaning the default:
+
+  "reference": "<name>"   the plain reference is `references/<name>.py`;
+                          default `reference.py`. The contract of either:
+                          arguments `--ckpt --model --config <the
+                          configuration's file> --device --prompt-ids
+                          --continue-ids --out`; of the program it uses only
+                          `parallel.stages.load_stage_checkpoint`; every
+                          size comes from `--config`; float32,
+                          `default_matmul_precision("highest")`, layers
+                          streamed; it writes `[M, V]` float32
+                          log-probabilities, row j at position
+                          len(prompt) - 1 + j of ONE full forward pass over
+                          prompt + continue (no cache, no sampling).
+  "preset_check": {...}   published key (a dotted path into the file) ->
+                          the attribute of the program's preset it must
+                          equal; default PRESET_CHECK. Every key of the
+                          manifest entry's `reduced` has to be among them.
+  "probe": {"prompt_len": N, "new": M}   default 64 and 16.
+
 Set-up (all of it `setup_s`): weights through `tools.split_model
 --random-init --seed <weights_seed>` into `benchmark/.cache/` on the first
-run of a configuration in a checkout, then `reference.py` on the chip
-(float32 log-probabilities of the probe, kept beside the checkpoint); the
-node; one warm-up request per prompt length the mix can draw; the probe;
-the lead-in. Then the window of `--seconds`.
+run of a configuration in a checkout; the node; one warm-up request per
+prompt length the mix can draw; the probe; the lead-in. Then the window of
+`--seconds`. Once the node has exited and freed the chip, the reference runs
+there over the probe's prompt and the tokens the node answered (kept beside
+the checkpoint under a digest of both, of the reference's file and of the
+configuration's, so one run per configuration and checkout); that is not
+set-up.
 
 `correct`: the probe alone and the probe with other sessions resident give
-the same tokens; the probe's top log-probabilities agree with the float32
-reference within the configuration's tolerance; every streamed token is in
-the vocabulary and no request got more than it asked; nothing compiled in
-the window; the node exited with code 0.
+the same tokens; the node's top log-probabilities of the probe's first token
+(prefill) and of the later ones (decode through the cache) lie, in the mean,
+within the configuration's tolerance of the float32 reference's one forward
+pass, whose argmax is among them at every position;
+every streamed token is in the vocabulary and no request got more than it
+asked; nothing compiled in the window; the node exited with code 0.
 
 The parent never initializes a JAX backend. There is no CPU fall-back:
 a node that does not report platform `tpu` and the cell's chips ends the
@@ -42,7 +69,9 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import copy
 import glob
+import hashlib
 import json
 import math
 import os
@@ -60,10 +89,24 @@ sys.path[:0] = [HERE, REPO]
 
 import arith  # noqa: E402
 import traffic  # noqa: E402
+import validate_manifest  # noqa: E402
 from procs import Children, Out, Refused, free_port, parent_backend_live  # noqa: E402
 
 CACHE = os.path.join(HERE, ".cache")
-PROBE_LEN, PROBE_NEW, PROBE_TOP = 64, 16, 8
+PROBE, PROBE_TOP = {"prompt_len": 64, "new": 16}, 8
+# published key -> attribute of the program's preset, where the file names no pairs of its own
+PRESET_CHECK = {
+    "hidden_size": "hidden_size",
+    "intermediate_size": "intermediate_size",
+    "num_hidden_layers": "num_layers",
+    "num_attention_heads": "num_heads",
+    "num_key_value_heads": "num_kv_heads",
+    "head_dim": "head_dim",
+    "vocab_size": "vocab_size",
+    "tie_word_embeddings": "tie_word_embeddings",
+    "rope_theta": "rope_theta",
+    "rms_norm_eps": "rms_norm_eps",
+}
 TRACE_SECONDS = 4.0
 OUT = Out()
 say = OUT.say
@@ -90,29 +133,66 @@ def load_cell(workload: str) -> dict:
     return {
         "cell": cell,
         "config": config,
+        "config_file": os.path.join(REPO, entry["file"]),
+        "reduced": entry["reduced"],
         "mix": traffic.load_mix(cell["traffic"]),
         "end_to_end": [m for m in manifest["end_to_end"] if reported(m)],
         "per_layer": [m for m in manifest["per_layer"] if reported(m)],
     }
 
 
-def check_preset(config: dict, preset) -> None:
-    """The file's published sizes are the ones the program's preset runs."""
-    pairs = {
-        "hidden_size": preset.hidden_size,
-        "intermediate_size": preset.intermediate_size,
-        "num_hidden_layers": preset.num_layers,
-        "num_attention_heads": preset.num_heads,
-        "num_key_value_heads": preset.num_kv_heads,
-        "head_dim": preset.head_dim,
-        "vocab_size": preset.vocab_size,
-        "tie_word_embeddings": preset.tie_word_embeddings,
-        "rope_theta": preset.rope_theta,
-        "rms_norm_eps": preset.rms_norm_eps,
-    }
-    wrong = {k: (config.get(k), v) for k, v in pairs.items() if config.get(k) != v}
+def check_preset(config: dict, reduced, preset) -> None:
+    """The file's published sizes are the ones the program's preset runs,
+    and what the configuration cuts is among what is compared."""
+    pairs = config.get("preset_check", PRESET_CHECK)
+    unchecked = [k for k in reduced if k not in pairs]
+    if unchecked:
+        raise Refused(f"reduced keys that the preset check does not compare: {unchecked}")
+    lacks = {k: a for k, a in pairs.items() if not hasattr(preset, a)}
+    if lacks:
+        raise Refused(f"preset {preset.name!r} has no attribute for (key: attribute) {lacks}")
+    wrong = {k: (arith.dig(config, k, "(absent)"), getattr(preset, a)) for k, a in pairs.items()
+             if arith.dig(config, k, "(absent)") != getattr(preset, a)}
     if wrong:
         raise Refused(f"preset {preset.name!r} differs from the file (file, program): {wrong}")
+
+
+def rehearsal_config(config: dict, preset, path: str) -> str:
+    """A rehearsal serves another preset than the file describes: its
+    reference reads a copy of the file in which every checked key holds the
+    rehearsal preset's value."""
+    out = copy.deepcopy(config)
+    for key, attr in config.get("preset_check", PRESET_CHECK).items():
+        *parents, leaf = key.split(".")
+        group = arith.dig(out, ".".join(parents)) if parents else out
+        group[leaf] = getattr(preset, attr)
+    with open(path, "w") as f:
+        json.dump(out, f)
+    return path
+
+
+def reference_script(config: dict) -> str:
+    name = config.get("reference")
+    if name is None:
+        return os.path.join(HERE, "reference.py")
+    if not (isinstance(name, str) and validate_manifest.NAME.match(name)):
+        raise Refused(f"reference {name!r} is no name (letters, digits, '_', '.', '-')")
+    path = os.path.join(HERE, "references", f"{name}.py")
+    if not os.path.isfile(path):
+        raise Refused(f"no reference {name!r}: {path} is missing")
+    return path
+
+
+def probe_sizes(config: dict, flags) -> tuple:
+    """(prompt length, new tokens) of the probe; it has to fit a session."""
+    probe = {**PROBE, **config.get("probe", {})}
+    n, m = probe["prompt_len"], probe["new"]
+    if not (isinstance(n, int) and isinstance(m, int) and n >= 1 and m >= 2):
+        raise Refused(f"probe {probe}: prompt_len >= 1 and new >= 2, whole numbers")
+    max_len = int(flags[flags.index("--max-len") + 1]) if "--max-len" in flags else None
+    if max_len is not None and n + m > max_len:
+        raise Refused(f"probe of {n} + {m} tokens does not fit the node's --max-len {max_len}")
+    return n, m
 
 
 def load_reader(metric_name: str):
@@ -225,19 +305,27 @@ class Ctx:
 
 
 # ---------------------------------------------------------------------------
-# set-up: weights, reference, node
+# set-up: weights, node; after the node: the reference
 # ---------------------------------------------------------------------------
 
 
-def ensure_weights(config: dict, model: str, vocab: int, dev: str, children: Children,
-                   parts_dir: str, timings: dict) -> str:
-    """The seeded checkpoint and the reference's log-probabilities of the
-    probe, made once per configuration and checkout."""
-    ref_path = os.path.join(os.path.dirname(parts_dir), "probe_ref.npy")
-    ckpt = os.path.join(parts_dir, "stage_000.msgpack")
-    if os.path.isfile(ref_path) and os.path.isfile(ckpt):
-        return ref_path
+def file_digest(*paths: str) -> str:
+    h = hashlib.sha256()
+    for path in paths:
+        with open(path, "rb") as f:
+            h.update(hashlib.sha256(f.read()).digest())
+    return h.hexdigest()
+
+
+def ensure_weights(config: dict, model: str, preset, children: Children, parts_dir: str,
+                   timings: dict) -> None:
+    """The seeded checkpoint, made once per configuration and checkout, and
+    anew where the program's preset is no longer the one it was made of."""
     home = os.path.dirname(parts_dir)
+    made = os.path.join(home, "checkpoint.ok")  # the split does not write atomically
+    of = f"{config['weights_seed']} {preset!r}\n"
+    if os.path.isfile(made) and open(made).read() == of:
+        return
     shutil.rmtree(home, ignore_errors=True)
     os.makedirs(home)
     t0 = time.monotonic()
@@ -246,21 +334,35 @@ def ensure_weights(config: dict, model: str, vocab: int, dev: str, children: Chi
         "--stages", "1", "--random-init", "--seed", str(config["weights_seed"]),
         "--device", "cpu", "--out", parts_dir,
     ], timeout=900)
+    with open(made, "w") as f:
+        f.write(of)
     timings["checkpoint_s"] = time.monotonic() - t0
-    t0 = time.monotonic()
-    children.run("reference", [
-        sys.executable, os.path.join(HERE, "reference.py"), "--ckpt", ckpt,
-        "--model", model, "--device", dev, "--out", ref_path + ".tmp.npy",
-        "--prompt-ids", ",".join(map(str, probe_prompt(config, vocab))),
-    ], timeout=900)
-    os.replace(ref_path + ".tmp.npy", ref_path)
-    timings["reference_s"] = time.monotonic() - t0
+
+
+def run_reference(script: str, model: str, config_file: str, dev: str, children: Children,
+                  parts_dir: str, prompt, more) -> str:
+    """The reference's `[1 + len(more), V]` log-probabilities of one forward
+    pass over `prompt + more`, kept beside the checkpoint under a digest of
+    the tokens, the reference's file and the configuration's (an edit to
+    either makes the rows anew). The chip has to be free: the node has exited."""
+    ids = [",".join(map(str, x)) for x in (prompt, more)]
+    digest = hashlib.sha256(
+        "/".join(ids + [file_digest(script, config_file)]).encode()).hexdigest()[:16]
+    ref_path = os.path.join(os.path.dirname(parts_dir), f"probe_ref-{digest}.npy")
+    if not os.path.isfile(ref_path):
+        children.run("reference", [
+            sys.executable, script,
+            "--ckpt", os.path.join(parts_dir, "stage_000.msgpack"), "--model", model,
+            "--config", config_file, "--device", dev, "--prompt-ids", ids[0],
+            "--continue-ids", ids[1], "--out", ref_path + ".tmp.npy",
+        ], timeout=900)
+        os.replace(ref_path + ".tmp.npy", ref_path)
     return ref_path
 
 
-def probe_prompt(config: dict, vocab: int):
+def probe_prompt(config: dict, vocab: int, n: int):
     rng = random.Random(f"probe/{config['weights_seed']}")
-    return [rng.randrange(vocab) for _ in range(PROBE_LEN)]
+    return [rng.randrange(vocab) for _ in range(n)]
 
 
 async def wait_ready(node, client: NodeClient, children: Children, timeout: float):
@@ -289,23 +391,50 @@ def compiles(events, stats) -> int:
     return sum(1 for e in events if e["type"] == "compile.begin") + int(cc.get("misses", 0))
 
 
-def check_reference(probe: dict, ref_path: str, tolerance: float):
-    """The node's top log-probabilities of the probe's first token against
-    the float32 reference. Returns (ok, detail)."""
+def check_reference(probe: dict, ref_path: str, tolerance: dict) -> dict:
+    """The node's top log-probabilities of every token of the probe against
+    the float32 reference's rows: the first token (the prefill's) as
+    `probe_reference`, the others (decoded through the cache) as
+    `probe_decode_reference`, each (ok, detail). The number compared is the
+    MEAN |node - reference| over the node's top log-probabilities at the
+    check's positions, held to the configuration's one tolerance: the
+    largest single difference swings from probe to probe by as much as a
+    step down in precision moves it, the mean does not (PERF.md section 4).
+    At every position the reference's argmax is among the node's top. The
+    detail names the position of the largest difference."""
     import numpy as np
 
-    if not probe["tops"]:
-        return False, "the probe came back without top_logprobs"
     ref = np.load(ref_path)
-    ids, lps = probe["tops"][0]  # a streamed `top` field: [ids, log-probabilities]
-    pairs = [(int(i), float(lp)) for i, lp in zip(ids, lps)]
-    worst = max(abs(lp - float(ref[i])) for i, lp in pairs)
-    ok = worst <= tolerance and int(ref.argmax()) in [i for i, _ in pairs]
-    return ok, (
-        f"largest |node - reference| over the node's top {len(pairs)} "
-        f"log-probabilities {worst:.5f} (tolerance {tolerance}); reference "
-        f"argmax {int(ref.argmax())}, node's first {pairs[0][0]}"
-    )
+    tops = probe["tops"]  # a streamed `top` field: [ids, log-probabilities]
+    if len(tops) != len(ref) or len(tops) != len(probe["tokens"]):
+        why = (f"the probe's {len(probe['tokens'])} tokens came with {len(tops)} top_logprobs; "
+               f"the reference has {len(ref)} rows")
+        return {"probe_reference": (False, why), "probe_decode_reference": (False, why)}
+    rows = []  # per position: differences, argmax among the node's top, argmax, node's first
+    for (ids, lps), row in zip(tops, ref):
+        ids = [int(i) for i in ids]
+        diffs = [abs(float(lp) - float(row[i])) for i, lp in zip(ids, lps)]
+        rows.append((diffs, int(row.argmax()) in ids, int(row.argmax()), ids[0]))
+    limit = float(tolerance["value"])
+
+    def verdict(positions):
+        if not positions:
+            return False, "the probe answered one token: none went through the cache"
+        every = [d for j in positions for d in rows[j][0]]
+        mean = sum(every) / len(every)
+        j = max(positions, key=lambda j: (not rows[j][1], max(rows[j][0])))
+        diffs, among, argmax, node_first = rows[j]
+        return mean <= limit and all(rows[j][1] for j in positions), (
+            f"mean |node - reference| over the node's top {len(diffs)} log-probabilities "
+            f"{mean:.4g} (tolerance {limit}); largest {max(diffs):.4g} at position {j} of "
+            f"{positions[0]}-{positions[-1]}; there the reference's argmax {argmax}"
+            f"{'' if among else ' is NOT among them'}, the node's first {node_first}"
+            + (f"; mean by position {' '.join(f'{sum(rows[j][0]) / len(rows[j][0]):.3g}' for j in positions)}"
+               if len(positions) > 1 else "")
+        )
+
+    return {"probe_reference": verdict(range(0, 1)),
+            "probe_decode_reference": verdict(range(1, len(rows)))}
 
 
 # ---------------------------------------------------------------------------
@@ -313,8 +442,8 @@ def check_reference(probe: dict, ref_path: str, tolerance: float):
 # ---------------------------------------------------------------------------
 
 
-async def drive(args, loaded: dict, node, port: int, children: Children, ref_path: str,
-                timings: dict, checks: dict, work: str) -> dict:
+async def drive(args, loaded: dict, node, port: int, children: Children, probe_ids,
+                probe_new: int, timings: dict, checks: dict, work: str) -> dict:
     import aiohttp
 
     from inferd_tpu.config import get_config
@@ -346,7 +475,6 @@ async def drive(args, loaded: dict, node, port: int, children: Children, ref_pat
         # client make of a length (buckets, chunks) are compiled here ---------
         t0 = time.monotonic()
         pool = traffic.size_pool(mix, scale)
-        probe_ids = probe_prompt(config, vocab)
         for n_prompt in sorted({n for n, _n_out in pool}):
             ids = traffic.prompt_ids(args.seed, -1, n_prompt, n_prompt, vocab)
             rec = await client.generate(ids, 3, top=PROBE_TOP)
@@ -357,13 +485,10 @@ async def drive(args, loaded: dict, node, port: int, children: Children, ref_pat
 
         # -- probe, alone ----------------------------------------------------
         t0 = time.monotonic()
-        solo = await client.generate(probe_ids, PROBE_NEW, top=PROBE_TOP)
+        solo = await client.generate(probe_ids, probe_new, top=PROBE_TOP)
         timings["probe_s"] = time.monotonic() - t0
         if solo["error"]:
             raise Refused(f"the probe failed: {solo['error']}")
-        checks["probe_reference"] = check_reference(
-            solo, ref_path, float(config["logprob_tolerance"]["value"])
-        )
 
         # -- lead-in: traffic starts, the probe again with sessions resident -
         kind = traffic.load_kind(mix["kind"])
@@ -394,7 +519,7 @@ async def drive(args, loaded: dict, node, port: int, children: Children, ref_pat
 
         try:
             await asyncio.sleep(0.3 * float(mix["lead_in_s"]))
-            again = await client.generate(probe_ids, PROBE_NEW, top=PROBE_TOP)
+            again = await client.generate(probe_ids, probe_new, top=PROBE_TOP)
             gate.set()
             checks["probe_same_with_sessions_resident"] = (
                 not again["error"] and again["tokens"] == solo["tokens"],
@@ -453,7 +578,7 @@ async def drive(args, loaded: dict, node, port: int, children: Children, ref_pat
         "requests": ctx.requests, "lags_ms": ctx.lags_ms, "slots": slots,
         "stats0": stats0, "stats1": stats1, "events0": events0, "events1": events1,
         "spans": spans, "polls": polls, "device": device, "capture": capture,
-        "work": work, "rehearse": args.rehearse,
+        "work": work, "rehearse": args.rehearse, "probe": solo,
     }
 
 
@@ -498,8 +623,11 @@ def run_cell(args) -> dict:
     except ImportError as e:
         raise Refused(f"the program is not in this checkout: {e}")
     model = config["rehearse"]["model"] if args.rehearse else config["preset"]
-    if not args.rehearse:
-        check_preset(config, get_config(model))
+    check_preset(config, loaded["reduced"], get_config(config["preset"]))
+    flags = config["rehearse"]["node_flags"] if args.rehearse else config["node_flags"]
+    probe_len, probe_new = probe_sizes(config, flags)
+    probe_ids = probe_prompt(config, get_config(model).vocab_size, probe_len)
+    script = reference_script(config)  # one that is not there is refused before the node starts
     dev = "cpu" if args.rehearse else "tpu"
     tag = f"{args.workload}-s{args.seed}-t{args.trace}"
     work = os.path.join(CACHE, "work", args.workload)
@@ -519,9 +647,7 @@ def run_cell(args) -> dict:
                         ("-rehearse" if args.rehearse else ""))
     parts_dir = os.path.join(home, "parts")
     try:
-        ref_path = ensure_weights(config, model, get_config(model).vocab_size, dev, children,
-                                  parts_dir, timings)
-        flags = config["rehearse"]["node_flags"] if args.rehearse else config["node_flags"]
+        ensure_weights(config, model, get_config(model), children, parts_dir, timings)
         port = free_port()
         node = children.spawn("node", [
             sys.executable, "-m", "inferd_tpu.tools.run_node", "--model", model, *flags,
@@ -530,11 +656,19 @@ def run_cell(args) -> dict:
             *(["--enable-profiling"] if args.trace else []),
         ])
         try:
-            run = asyncio.run(drive(args, loaded, node, port, children, ref_path,
+            run = asyncio.run(drive(args, loaded, node, port, children, probe_ids, probe_new,
                                     timings, checks, work))
         finally:
             code = children.stop(node)
         checks["node_exit_0"] = (code == 0, f"exit code {code}")
+        # the chip is free and the node's peak memory has been read: the reference
+        t0 = time.monotonic()
+        config_file = loaded["config_file"] if not args.rehearse else rehearsal_config(
+            config, get_config(model), os.path.join(work, "reference_config.json"))
+        ref_path = run_reference(script, model, config_file, dev, children, parts_dir,
+                                 probe_ids, run["probe"]["tokens"][:-1])
+        timings["reference_s"] = time.monotonic() - t0
+        checks.update(check_reference(run["probe"], ref_path, config["logprob_tolerance"]))
     finally:
         children.stop_all()
 
@@ -543,7 +677,8 @@ def run_cell(args) -> dict:
     reasons = [x for x in why_failed.values() if x]
     failed = sum(1 for r in reqs if why_failed[id(r)])
     checks["every_token_well_formed"] = (not reasons, "; ".join(reasons[:3]))
-    say("set-up by part: " + ", ".join(f"{k} {v:.1f}" for k, v in timings.items()))
+    say("time by part (the reference runs after the window): "
+        + ", ".join(f"{k} {v:.1f}" for k, v in timings.items()))
 
     if args.trace:
         run["trace"] = reduce_trace(run, children)
@@ -568,7 +703,9 @@ def run_cell(args) -> dict:
 
     checks["parent_held_no_backend"] = (not parent_backend_live(), "")
     for name, (ok, detail) in checks.items():
-        say(f"{'PASS' if ok else 'FAIL'} {name}{': ' + detail if detail else ''}")
+        line = f"{'PASS' if ok else 'FAIL'} {name}{': ' + detail if detail else ''}"
+        say(line)
+        print(line, file=sys.stderr)  # the driver's record keeps the end of standard error
     odd = {k: v["value"] for k, v in values.items()
            if not (isinstance(v["value"], (int, float)) and math.isfinite(v["value"]))}
     if odd:

@@ -81,6 +81,12 @@ CASES = {
 }
 
 
+def test_depth_is_no_width():
+    m = copy.deepcopy(manifest())
+    m["configs"][0]["reduced"] = ["num_hidden_layers", "num_experts", "vocab_size"]
+    assert faults(m) == []
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_rule_refuses(case):
     change, expect = CASES[case]

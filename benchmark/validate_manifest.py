@@ -125,8 +125,9 @@ def validate(manifest: dict, root: str, raw_bytes: int = 0) -> list:
             say(f"{label}: reduced is a list of at most 16 names")
         else:
             for k in red:
-                if (k.endswith("_dim") or k.endswith("_rank")
-                        or any(wd in k for wd in WIDTH_WORDS)):
+                counts_layers = k.startswith("num_") and k.endswith("_layers")  # num_hidden_layers
+                if not counts_layers and (k.endswith("_dim") or k.endswith("_rank")
+                                          or any(wd in k for wd in WIDTH_WORDS)):
                     say(f"{label}: reduced names the width {k!r}")
 
     # -- cells ---------------------------------------------------------------
