@@ -9,6 +9,7 @@ framework supports multiple model sizes, not one hardcoded set.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax.numpy as jnp
 
@@ -112,9 +113,52 @@ class ModelConfig:
     query_pre_attn_scalar: float = 0.0
     sliding_window: int = 0
 
+    # DeepSeek-V2 family knobs (all absent elsewhere):
+    #   kv_lora_rank   — > 0: latent attention (MLA). The cache holds per
+    #                    token and layer the normed latent (kv_lora_rank)
+    #                    and ONE rope key shared by all heads
+    #                    (qk_rope_head_dim), nothing per head; keys and
+    #                    values come from the latent through kv_b_proj.
+    #                    head_dim is then the query/key head size,
+    #                    qk_nope_head_dim + qk_rope_head_dim
+    #   v_head_dim     — value head size (o_proj reads num_heads * v_head_dim)
+    #   rope_mscale / rope_mscale_all_dim — yarn: cos/sin are multiplied by
+    #                    yarn_mscale(factor, mscale) / yarn_mscale(factor,
+    #                    mscale_all_dim) and the softmax scale by
+    #                    yarn_mscale(factor, mscale_all_dim)**2 (0 = the
+    #                    one attention factor on cos/sin above)
+    #   n_shared_experts — one always-on SwiGLU of width n_shared_experts *
+    #                    moe_intermediate_size added to the routed output
+    #   routed_scaling_factor — multiplies the routed experts' weights
+    #   first_k_dense_replace — this many leading layers keep the dense MLP
+    #                    (params["dense_layers"], a group of its own)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_mscale: float = 0.0
+    rope_mscale_all_dim: float = 0.0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    first_k_dense_replace: int = 0
+
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def num_dense_layers(self) -> int:
+        """Leading layers with the dense MLP in a model with experts."""
+        return min(self.first_k_dense_replace, self.num_layers) if self.is_moe else 0
+
+    @property
+    def rope_dim(self) -> int:
+        """Dimensions the rotary embedding turns."""
+        return self.qk_rope_head_dim if self.is_mla else self.head_dim
 
     @property
     def q_dim(self) -> int:
@@ -135,10 +179,18 @@ class ModelConfig:
     @property
     def attn_scale(self) -> float:
         base = self.query_pre_attn_scalar or self.head_dim
-        return float(base) ** -0.5
+        scale = float(base) ** -0.5
+        if self.rope_scaling == "yarn" and self.rope_mscale_all_dim:
+            scale *= yarn_mscale(self.rope_scaling_factor, self.rope_mscale_all_dim) ** 2
+        return scale
 
     def with_layers(self, num_layers: int) -> "ModelConfig":
         return dataclasses.replace(self, num_layers=num_layers)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -444,6 +496,46 @@ QWEN3_MOE_30B_A3B = ModelConfig(
     moe_intermediate_size=768,
 )
 
+# DeepSeek-V2-Lite (deepseek-ai/DeepSeek-V2-Lite config.json): latent
+# attention without query compression (q_lora_rank null), one dense layer,
+# then 26 layers of 64 routed experts (softmax over all, greedy top-6, not
+# renormalised) beside two shared ones, YaRN over the 64 rope dimensions.
+# The -8l preset is the same model cut to its first 8 layers (the dense one
+# and 7 sparse ones): what one v5e chip holds at the published widths.
+DEEPSEEK_V2_LITE = ModelConfig(
+    name="deepseek-v2-lite",
+    vocab_size=102400,
+    hidden_size=2048,
+    intermediate_size=10944,
+    num_layers=27,
+    num_heads=16,
+    num_kv_heads=16,
+    head_dim=192,
+    rope_theta=10_000.0,
+    max_position_embeddings=163840,
+    tie_word_embeddings=False,
+    qk_norm=False,
+    rope_scaling="yarn",
+    rope_scaling_factor=40.0,
+    rope_original_max_position=4096,
+    rope_mscale=0.707,
+    rope_mscale_all_dim=0.707,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    num_experts=64,
+    num_experts_per_tok=6,
+    moe_intermediate_size=1408,
+    norm_topk_prob=False,
+    n_shared_experts=2,
+    first_k_dense_replace=1,
+)
+
+DEEPSEEK_V2_LITE_8L = dataclasses.replace(
+    DEEPSEEK_V2_LITE.with_layers(8), name="deepseek-v2-lite-8l"
+)
+
 # Synthetic mid-size config for the default bench's paired pipeline leg
 # (bench.py): big enough that a decode step's compute dominates the
 # inter-stage hop (the regime the north-star ratio grades), small enough
@@ -513,6 +605,16 @@ TINY_GEMMA2 = dataclasses.replace(
     query_pre_attn_scalar=32.0, sliding_window=8,
 )
 
+TINY_DSV2 = dataclasses.replace(
+    TINY, name="tiny-dsv2", qk_norm=False, tie_word_embeddings=False,
+    head_dim=24, num_kv_heads=4, rope_theta=10_000.0,
+    rope_scaling="yarn", rope_scaling_factor=40.0,
+    rope_original_max_position=64, rope_mscale=0.707, rope_mscale_all_dim=0.707,
+    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32,
+    norm_topk_prob=False, n_shared_experts=2, first_k_dense_replace=1,
+)
+
 PRESETS = {
     c.name: c
     for c in [
@@ -534,6 +636,8 @@ PRESETS = {
         GPT_OSS_20B,
         GPT_OSS_120B,
         QWEN3_MOE_30B_A3B,
+        DEEPSEEK_V2_LITE,
+        DEEPSEEK_V2_LITE_8L,
         BENCH_PIPE,
         TINY,
         TINY_MOE,
@@ -541,6 +645,7 @@ PRESETS = {
         TINY_LLAMA,
         TINY_GEMMA2,
         TINY_GPT_OSS,
+        TINY_DSV2,
     ]
 }
 
@@ -564,6 +669,7 @@ HF_REPOS = {
     "mixtral-8x7b": "mistralai/Mixtral-8x7B-v0.1",
     "gpt-oss-20b": "openai/gpt-oss-20b",
     "gpt-oss-120b": "openai/gpt-oss-120b",
+    "deepseek-v2-lite": "deepseek-ai/DeepSeek-V2-Lite",
 }
 
 

@@ -94,6 +94,9 @@ class BatchedEngine:
 
         sc = self.sampling
         L = lanes
+        # the decode program also returns the experts each lane chose (the
+        # uniform dense-lane scan only: models/qwen3.forward_layers_cached)
+        self.routes = routes = cfg.is_moe and block_size == 0 and cfg.sliding_window == 0
 
         from inferd_tpu.core.cache import lane_slice as _lane_slice
         from inferd_tpu.core.cache import lane_write as _lane_write
@@ -197,13 +200,16 @@ class BatchedEngine:
             simply advance nothing host-side; their computed rows are
             discarded by the caller. `ads` (multi-tenant registry): the
             stacked LoRA pools + per-lane slot ids — a mixed-adapter
-            window stays ONE dispatch (ops/lora pool contract)."""
+            window stays ONE dispatch (ops/lora pool contract). The third
+            value is the experts each lane chose in each sparse layer,
+            [Ls, L, K] int32 (the `moe.*` counters of /stats), or None for
+            a model without experts."""
             pos = lengths[:, None]
-            logits, nc = qwen3.forward_cached(
+            logits, nc, *topi = qwen3.forward_cached(
                 params, cfg, toks[:, None], pos, cache, lengths,
-                real_end=lengths + 1, adapters=ads,
+                real_end=lengths + 1, adapters=ads, routing=routes,
             )
-            return nc, logits[:, 0]
+            return nc, logits[:, 0], topi[0][:, :, 0] if routes else None
 
         @partial(jax.jit, donate_argnames=("cache",))
         def _prefill_lane_logits(params, cache: KVCache, tokens, lane, start,
@@ -227,12 +233,9 @@ class BatchedEngine:
             ks = jax.lax.dynamic_slice_in_dim(cache.k, src, 1, axis=1)[:, :, :m]
             vs = jax.lax.dynamic_slice_in_dim(cache.v, src, 1, axis=1)[:, :, :m]
             zero = jnp.int32(0)
-            nk = jax.lax.dynamic_update_slice(
-                cache.k, ks, (zero, dst, zero, zero, zero)
-            )
-            nv = jax.lax.dynamic_update_slice(
-                cache.v, vs, (zero, dst, zero, zero, zero)
-            )
+            at_dst = (zero, dst) + (zero,) * (cache.k.ndim - 2)  # a latent cache has no head axis
+            nk = jax.lax.dynamic_update_slice(cache.k, ks, at_dst)
+            nv = jax.lax.dynamic_update_slice(cache.v, vs, at_dst)
             kl, vl = cache.k_loc, cache.v_loc
             if kl is not None:
                 # rings are fixed-size: the child takes the parent's WHOLE

@@ -63,7 +63,7 @@ def sliding_layer_ids(
 @dataclasses.dataclass
 class KVCache:
     k: jax.Array  # [Lg, B, T, Nkv, D] global (full-length) layers
-    v: jax.Array  # [Lg, B, T, Nkv, D]
+    v: jax.Array  # [Lg, B, T, Nkv, D]; a latent (MLA) cache: k [L, B, T, R], v [L, B, T, Dr]
     length: jax.Array  # int32 scalar: populated positions
     k_loc: Optional[jax.Array] = None  # [Ll, B, R, Nkv, D] sliding-layer rings
     v_loc: Optional[jax.Array] = None
@@ -95,6 +95,14 @@ class KVCache:
         comparison/compat path — also what executors with a TRACED layer
         offset must use)."""
         dt = dtype or cfg.kv_jnp_dtype
+        if cfg.is_mla:
+            # a latent cache: per token and layer the normed latent (`k`)
+            # and the one roped key all heads share (`v`); nothing per head
+            return KVCache(
+                k=jnp.zeros((num_layers, batch, max_len, cfg.kv_lora_rank), dt),
+                v=jnp.zeros((num_layers, batch, max_len, cfg.qk_rope_head_dim), dt),
+                length=jnp.int32(0),
+            )
         use_ring = cfg.sliding_window > 0 if ring is None else (
             ring and cfg.sliding_window > 0
         )
@@ -115,6 +123,12 @@ class KVCache:
             k_loc=jnp.zeros(lshape, dt),
             v_loc=jnp.zeros(lshape, dt),
         )
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes allocated to the cache's buffers."""
+        return sum(int(a.nbytes) for a in (self.k, self.v, self.k_loc, self.v_loc)
+                   if a is not None)
 
     def ensure_room(self, new_tokens: int, owner: Optional[str] = None) -> None:
         """Host-side overflow guard — call before dispatching a jitted step.
@@ -150,6 +164,20 @@ def lane_slice(cache: KVCache, lane) -> KVCache:
         k_loc=None if cache.k_loc is None else sl(cache.k_loc),
         v_loc=None if cache.v_loc is None else sl(cache.v_loc),
     )
+
+
+def layer_slice(cache: KVCache, start: int, end: int) -> KVCache:
+    """Layers [start, end) of a uniform cache (one group of a model whose
+    layer stack comes in groups)."""
+    assert cache.k_loc is None
+    return KVCache(k=cache.k[start:end], v=cache.v[start:end], length=cache.length)
+
+
+def layer_write(cache: KVCache, start: int, part: KVCache) -> KVCache:
+    """Write a layer_slice-shaped cache back at layer `start` (in place
+    under donation, like lane_write)."""
+    up = lambda a, b: jax.lax.dynamic_update_slice_in_dim(a, b, start, axis=0)
+    return KVCache(k=up(cache.k, part.k), v=up(cache.v, part.v), length=cache.length)
 
 
 def lane_write(cache: KVCache, lane, nc: KVCache) -> KVCache:
@@ -310,6 +338,11 @@ class BlockPool:
         dtype=None,
         clock: Optional[Callable[[], float]] = None,
     ):
+        if cfg.is_mla:
+            raise ValueError(
+                f"{cfg.name}: the paged pool has no latent entry "
+                "(a latent cache is served from dense lanes)"
+            )
         if cfg.sliding_window > 0:
             # rings already make sliding layers O(window); paging the
             # uniform layout under them would need a second table per
@@ -689,8 +722,7 @@ def grow(cache: KVCache, new_max_len: int) -> KVCache:
     """
     if new_max_len <= cache.max_len:
         return cache
-    l, b, t, n, d = cache.k.shape
-    pad = [(0, 0), (0, 0), (0, new_max_len - t), (0, 0), (0, 0)]
+    pad = [(0, 0), (0, 0), (0, new_max_len - cache.max_len)] + [(0, 0)] * (cache.k.ndim - 3)
     return KVCache(
         k=jnp.pad(cache.k, pad), v=jnp.pad(cache.v, pad), length=cache.length,
         k_loc=cache.k_loc, v_loc=cache.v_loc,

@@ -72,6 +72,8 @@ def params_from_hf_state_dict(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params
 
     HF stores linear weights [out, in]; we store [in, out] (x @ W).
     """
+    if cfg.is_mla:
+        return _params_from_deepseek_v2(cfg, sd)
     dt = cfg.jnp_dtype
 
     def get_np(name: str, transpose: bool = False) -> np.ndarray:
@@ -202,6 +204,66 @@ def params_from_hf_state_dict(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = get("lm_head.weight", transpose=True)
+    return params
+
+
+def _params_from_deepseek_v2(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:
+    """HF `deepseek_v2` names -> the grouped tree (`dense_layers` for the
+    leading dense layers, `layers` for the sparse ones). The checkpoint
+    stores each rope dimension pair interleaved (x0 y0 x1 y1 ...); the
+    program's `apply_rope` turns the half-split layout (x0 x1 ... y0 y1
+    ...), so the rope columns of q_proj and of kv_a_proj_with_mqa are
+    permuted here, once."""
+    dt = cfg.jnp_dtype
+    dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    halves = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
+
+    def raw(name: str) -> np.ndarray:  # as stored: a norm's vector, the embedding table
+        key = f"{name}.weight"
+        return _to_np(sd[key if key in sd else f"model.{key}"])
+
+    def w(name: str) -> np.ndarray:  # a linear weight as [in, out]
+        return raw(name).T
+
+    def layer(i: int) -> Params:
+        at, mlp = f"layers.{i}.self_attn", f"layers.{i}.mlp"
+        q = w(f"{at}.q_proj").reshape(cfg.hidden_size, cfg.num_heads, dn + dr)
+        q = np.concatenate([q[..., :dn], q[..., dn:][..., halves]], axis=-1)
+        kv_a = w(f"{at}.kv_a_proj_with_mqa")
+        out = {
+            "input_norm": raw(f"layers.{i}.input_layernorm"),
+            "q_proj": q.reshape(cfg.hidden_size, -1),
+            "kv_a_proj": np.concatenate([kv_a[:, :r], kv_a[:, r:][:, halves]], axis=-1),
+            "kv_a_norm": raw(f"{at}.kv_a_layernorm"),
+            "kv_b_proj": w(f"{at}.kv_b_proj"),
+            "o_proj": w(f"{at}.o_proj"),
+            "post_norm": raw(f"layers.{i}.post_attention_layernorm"),
+        }
+        if i < cfg.num_dense_layers:
+            for proj in ("gate_proj", "up_proj", "down_proj"):
+                out[proj] = w(f"{mlp}.{proj}")
+            return out
+        out["router"] = w(f"{mlp}.gate")
+        for proj in ("gate_proj", "up_proj", "down_proj"):
+            out[proj] = np.stack(
+                [w(f"{mlp}.experts.{e}.{proj}") for e in range(cfg.num_experts)])
+            out[f"shared_{proj}"] = w(f"{mlp}.shared_experts.{proj}")
+        return out
+
+    def group(ids) -> Params:
+        per_layer = [layer(i) for i in ids]
+        return {k: jnp.asarray(np.stack([lp[k] for lp in per_layer]), dtype=dt)
+                for k in per_layer[0]}
+
+    nd = cfg.num_dense_layers
+    params: Params = {
+        "embed": jnp.asarray(raw("embed_tokens"), dtype=dt),
+        "layers": group(range(nd, cfg.num_layers)),
+        "final_norm": jnp.asarray(raw("norm"), dtype=dt),
+        "lm_head": jnp.asarray(w("lm_head"), dtype=dt),
+    }
+    if nd:
+        params["dense_layers"] = group(range(nd))
     return params
 
 

@@ -28,7 +28,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from inferd_tpu.config import ModelConfig
+from inferd_tpu.config import ModelConfig, yarn_mscale
 from inferd_tpu.ops import attention as attention_ops
 from inferd_tpu.ops import lora as lora_ops
 from inferd_tpu.ops.quant import qdot, qeinsum
@@ -41,8 +41,12 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 
-def init_layer_params(cfg: ModelConfig, key: jax.Array, num_layers: Optional[int] = None) -> Params:
-    """Stacked decoder-layer params: every leaf has leading dim `num_layers`."""
+def init_layer_params(
+    cfg: ModelConfig, key: jax.Array, num_layers: Optional[int] = None,
+    dense: bool = False,
+) -> Params:
+    """Stacked decoder-layer params: every leaf has leading dim `num_layers`.
+    `dense` gives a model with experts its leading dense-MLP layers."""
     n = cfg.num_layers if num_layers is None else num_layers
     h, q, kv, d, i = cfg.hidden_size, cfg.q_dim, cfg.kv_dim, cfg.head_dim, cfg.intermediate_size
     dt = cfg.jnp_dtype
@@ -76,7 +80,15 @@ def init_layer_params(cfg: ModelConfig, key: jax.Array, num_layers: Optional[int
         p["o_bias"] = jnp.zeros((n, h), dtype=dt)
     if cfg.attn_sinks:  # GPT-OSS: per-q-head sink logits
         p["sinks"] = jnp.zeros((n, cfg.num_heads), dtype=dt)
-    if cfg.is_moe:
+    if cfg.is_mla:  # latent attention: no k/v projections per head
+        del p["k_proj"], p["v_proj"]
+        r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        heads_out = cfg.num_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+        p["kv_a_proj"] = w(jax.random.fold_in(key, 8), h, r + dr)
+        p["kv_a_norm"] = jnp.ones((n, r), dtype=dt)
+        p["kv_b_proj"] = w(jax.random.fold_in(key, 9), r, heads_out)
+        p["o_proj"] = w(ks[3], cfg.num_heads * cfg.v_head_dim, h)
+    if cfg.is_moe and not dense:
         e, mi = cfg.num_experts, cfg.moe_intermediate_size
         p["router"] = w(ks[4], h, e)
         p["gate_proj"] = w(ks[5], e, h, mi)
@@ -88,6 +100,11 @@ def init_layer_params(cfg: ModelConfig, key: jax.Array, num_layers: Optional[int
             p["gate_bias"] = jnp.zeros((n, e, mi), dtype=dt)
             p["up_bias"] = jnp.zeros((n, e, mi), dtype=dt)
             p["down_bias"] = jnp.zeros((n, e, h), dtype=dt)
+        if cfg.n_shared_experts:
+            si = cfg.n_shared_experts * mi
+            p["shared_gate_proj"] = w(jax.random.fold_in(key, 10), h, si)
+            p["shared_up_proj"] = w(jax.random.fold_in(key, 11), h, si)
+            p["shared_down_proj"] = w(jax.random.fold_in(key, 12), si, h)
     else:
         p["gate_proj"] = w(ks[5], h, i)
         p["up_proj"] = w(ks[6], h, i)
@@ -102,9 +119,13 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     norm1 = jnp.zeros if cfg.rms_norm_plus_one else jnp.ones
     params = {
         "embed": (jax.random.normal(k_embed, (cfg.vocab_size, cfg.hidden_size), dtype=jnp.float32) * 0.02).astype(dt),
-        "layers": init_layer_params(cfg, k_layers),
+        "layers": init_layer_params(cfg, k_layers, cfg.num_layers - cfg.num_dense_layers),
         "final_norm": norm1((cfg.hidden_size,), dtype=dt),
     }
+    if cfg.num_dense_layers:  # a leading group with leaves of its own
+        params["dense_layers"] = init_layer_params(
+            cfg, jax.random.fold_in(k_layers, 1), cfg.num_dense_layers, dense=True
+        )
     if not cfg.tie_word_embeddings:
         params["lm_head"] = (
             jax.random.normal(k_head, (cfg.hidden_size, cfg.vocab_size), dtype=jnp.float32) * 0.02
@@ -188,9 +209,12 @@ def rope_cos_sin(
             (inv_freq / cfg.rope_scaling_factor) * (1.0 - extrap_factor)
             + inv_freq * extrap_factor
         )
-        attn_factor = cfg.rope_attention_factor or (
-            0.1 * math.log(cfg.rope_scaling_factor) + 1.0
-        )
+        if cfg.rope_mscale_all_dim:  # DeepSeek: the pair's ratio; the rest scales the softmax
+            attn_factor = yarn_mscale(cfg.rope_scaling_factor, cfg.rope_mscale) / yarn_mscale(
+                cfg.rope_scaling_factor, cfg.rope_mscale_all_dim
+            )
+        else:
+            attn_factor = cfg.rope_attention_factor or yarn_mscale(cfg.rope_scaling_factor)
     if cfg is not None and cfg.rope_scaling == "llama3":
         wavelen = 2.0 * jnp.pi / inv_freq
         low_len = cfg.rope_original_max_position / cfg.rope_low_freq_factor
@@ -358,6 +382,8 @@ def route_topk(cfg: ModelConfig, router_logits: jax.Array) -> Tuple[jax.Array, j
         topw, topi = jax.lax.top_k(probs, k)
         if cfg.norm_topk_prob:
             topw = topw / jnp.sum(topw, axis=-1, keepdims=True)
+    if cfg.routed_scaling_factor != 1.0:
+        topw = topw * cfg.routed_scaling_factor
     return topw, topi
 
 
@@ -399,22 +425,34 @@ def expert_ffn(p: Params, cfg: ModelConfig, xt: jax.Array) -> jax.Array:
     return expert_out
 
 
-def moe_mlp(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+def moe_mlp_routed(p: Params, cfg: ModelConfig, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Mixture-of-experts feed-forward (routing modes + expert flavors in
-    `route` / `expert_ffn`). Dense-dispatch formulation (every token visits
-    every expert, combine weights zero out non-selected) — exact and
-    simple; the expert-parallel sharded dispatch lives in
-    inferd_tpu.parallel and shards the expert axis over the mesh.
+    `route` / `expert_ffn`) -> (output [B, S, H], chosen experts [B, S, K]).
+    Dense-dispatch formulation (every token visits every expert, combine
+    weights zero out non-selected) — exact and simple; the expert-parallel
+    sharded dispatch lives in inferd_tpu.parallel and shards the expert
+    axis over the mesh. With `n_shared_experts` one always-on SwiGLU is
+    added to the routed output, once.
     """
     b, s, h = x.shape
     xt = x.reshape(b * s, h)
-    router_logits = (xt @ p["router"]).astype(jnp.float32)  # [T, E]
-    if cfg.router_bias:
-        router_logits = router_logits + p["router_bias"].astype(jnp.float32)
-    comb, _ = route(cfg, router_logits)
-    expert_out = expert_ffn(p, cfg, xt)
-    out = jnp.einsum("teh,te->th", expert_out, comb.astype(expert_out.dtype))
-    return out.reshape(b, s, h)
+    with jax.named_scope("moe_route"):
+        router_logits = (xt @ p["router"]).astype(jnp.float32)  # [T, E]
+        if cfg.router_bias:
+            router_logits = router_logits + p["router_bias"].astype(jnp.float32)
+        comb, topi = route(cfg, router_logits)
+    with jax.named_scope("moe_experts"):
+        expert_out = expert_ffn(p, cfg, xt)
+        out = jnp.einsum("teh,te->th", expert_out, comb.astype(expert_out.dtype))
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe_shared"):
+            shared = {k: p[f"shared_{k}"] for k in ("gate_proj", "up_proj", "down_proj")}
+            out = out + swiglu_mlp(shared, xt)
+    return out.reshape(b, s, h), topi.reshape(b, s, -1)
+
+
+def moe_mlp(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    return moe_mlp_routed(p, cfg, x)[0]
 
 
 def _attend(
@@ -584,66 +622,16 @@ def _cached_attend(cfg, q, new_k, new_v, q_positions, end, window, sinks, s):
     )
 
 
-def decoder_layer(
-    lp: Params,
-    cfg: ModelConfig,
-    hidden: jax.Array,  # [B, S, H]
-    cos: jax.Array,
-    sin: jax.Array,
-    q_positions: jax.Array,  # [B, S]
-    k_buf: Optional[jax.Array],  # [B, T, nkv(_local), D] or None (no cache: T == S)
-    v_buf: Optional[jax.Array],
-    cache_write_pos: Optional[jax.Array],  # slot where new k/v go: scalar, or [B] per row
-    tp_axis: Optional[str] = None,
-    ep_axis: Optional[str] = None,
-    window=None,  # sliding window: traced scalar (mask-only), or a STATIC
-    #   python int > 0 — then the cached KV READ narrows to a
-    #   window-covering slice (_windowed_slice); None/<=0 = global
-    ring_window: Optional[int] = None,  # STATIC window with k_buf/v_buf an
-    #   O(window) RING [B, R, Nkv, D] (_ring_attend_update) — the sliding-
-    #   layer storage fast path; requires real_end
-    real_end=None,  # scalar or [B]: first bucket-padding position
-    #   (ring + paged layouts)
-    block_table: Optional[jax.Array] = None,  # [B, MB] int32 — PAGED mode:
-    #   k_buf/v_buf are block POOLS [NB, bs, Nkv, D]; writes scatter
-    #   through the table, reads gather through it (core.cache.PagedKVCache)
-    write_mask: Optional[jax.Array] = None,  # [B] bool (paged only): rows
-    #   whose KV writes commit; False rows compute but write NOTHING — a
-    #   non-participating co-batch lane must never scribble on a block
-    #   another lane or a shared prefix may own
-    adapters=None,  # this layer's per-lane LoRA slice (multi-tenant
-    #   registry): {"layers": {target: (a [B, in, r], b [B, r, out])},
-    #   "scale": [B] f32} — slot-0 (base) lanes carry zero A/B and apply
-    #   nothing (ops.lora.apply_lane_delta)
-) -> Tuple[jax.Array, Optional[jax.Array], Optional[jax.Array]]:
-    """One pre-norm residual decoder block with GQA + per-head q/k RMSNorm
-    (the Qwen3 signature feature — reference qwen3_server_module.py:123-124).
-
-    Returns (hidden', k_buf', v_buf'). When k_buf is None the layer runs
-    cache-free over the full sequence (prefill-style parity testing).
-
-    Shard-polymorphic: head counts come from the projection widths, not the
-    config, so the same code runs full-width (single device / pp stage) or
-    on a tensor-parallel head shard inside shard_map — pass `tp_axis` there
-    and the block psums its two row-parallel outputs (attention o_proj and
-    the MLP down-proj, the Megatron minimum; tp.sharded_decoder_layer is
-    the cache-free training sibling). The KV buffer then holds this rank's
-    local heads only. `ep_axis` (MoE only) additionally shards the expert
-    axis: attention replicates across ep ranks (its weights and KV carry no
-    ep spec, mesh.layer_param_specs) while each rank computes its local
-    experts' contribution and the combine psums over (ep, tp).
-
-    Caller contract: cache_write_pos + S must be <= the buffer length T.
-    dynamic_update_slice clamps out-of-range starts (it would silently
-    overwrite the newest slots), so overflow must be prevented host-side —
-    the runtime's session registry enforces this before dispatch
-    (inferd_tpu.core.cache.KVCache.ensure_room).
-    """
-    b, s, h = hidden.shape
+def _gqa_attend_update(
+    lp, cfg, x, cos, sin, q_positions, k_buf, v_buf, cache_write_pos,
+    window, ring_window, real_end, block_table, write_mask, adapters,
+):
+    """Per-head q/k/v from the normed input `x`, the chunk's keys and values
+    written to the cache in whichever layout `k_buf`/`v_buf` have (none,
+    paged, ring, dense lanes), and attention over it -> (attn [B, S, Nq*D],
+    k_buf', v_buf'). The argument contract is decoder_layer's."""
+    b, s, _h = x.shape
     d = cfg.head_dim
-    p1 = cfg.rms_norm_plus_one
-
-    x = rms_norm(hidden, lp["input_norm"], cfg.rms_norm_eps, p1)
     q = lora_ops.apply_lane_delta(qdot(x, lp["q_proj"]), x, "q_proj", adapters)
     k = lora_ops.apply_lane_delta(qdot(x, lp["k_proj"]), x, "k_proj", adapters)
     v = lora_ops.apply_lane_delta(qdot(x, lp["v_proj"]), x, "v_proj", adapters)
@@ -737,6 +725,186 @@ def decoder_layer(
             window, sinks, s,
         )
 
+    return attn, new_k, new_v
+
+
+def _causal_mask(t: int, kv_valid_len, kv_positions, q_positions) -> jax.Array:
+    """[B, S, T]: slot j is attended iff j < kv_valid_len and its absolute
+    position (slot index where `kv_positions` is None) <= the query's."""
+    slots = jnp.arange(t)
+    valid = jnp.asarray(kv_valid_len)
+    if valid.ndim == 0:
+        valid = valid[None]
+    kpos = slots if kv_positions is None else kv_positions
+    if kpos.ndim == 1:
+        kpos = kpos[None, :]
+    return (slots[None, None, :] < valid[:, None, None]) & (
+        kpos[:, None, :] <= q_positions[:, :, None]
+    )
+
+
+def mla_attend(
+    cfg: ModelConfig,
+    q_nope: jax.Array,  # [B, S, N, Dn]
+    q_pe: jax.Array,  # [B, S, N, Dr], roped
+    c: jax.Array,  # [B, T, R] normed latents (a cache buffer or the chunk's own)
+    k_pe: jax.Array,  # [B, T, Dr] the roped key all heads share
+    w_kvb: jax.Array,  # [R, N * (Dn + Dv)]
+    q_positions: jax.Array,  # [B, S]
+    kv_valid_len,  # scalar or [B]
+    kv_positions: Optional[jax.Array] = None,
+    absorbed: bool = False,
+) -> jax.Array:
+    """Latent attention -> [B, S, N * Dv]; softmax in float32.
+
+    Expanded: keys and values per head are made from the latents
+    (k_nope_i, v_i = c W_kvb,i) and attended as ordinary heads. Absorbed:
+    W_kvb's key half is folded into the query and its value half applied
+    after the weighted sum of LATENTS, so nothing per head exists over T:
+    score_i = (q_nope_i W_UK,i^T) . c + q_pe_i . k_pe. The two are the same
+    mathematics; decode (S == 1) runs absorbed over the cache."""
+    b, s, n, dn = q_nope.shape
+    dv = cfg.v_head_dim
+    if c.dtype != q_nope.dtype:  # compressed cache storage: upcast at the read
+        c, k_pe = c.astype(q_nope.dtype), k_pe.astype(q_nope.dtype)
+    w = w_kvb.reshape(w_kvb.shape[0], n, dn + dv)
+    w_uk, w_uv = w[..., :dn], w[..., dn:]
+    mask = _causal_mask(c.shape[1], kv_valid_len, kv_positions, q_positions)
+    rope_scores = jnp.einsum("bsnd,btd->bnst", q_pe, k_pe)
+    if absorbed:
+        q_lat = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_uk)
+        scores = jnp.einsum("bsnr,btr->bnst", q_lat, c)
+    else:
+        k_nope = jnp.einsum("btr,rnd->btnd", c, w_uk)
+        v = jnp.einsum("btr,rnd->btnd", c, w_uv)
+        scores = jnp.einsum("bsnd,btnd->bnst", q_nope, k_nope)
+    scores = (scores.astype(jnp.float32) + rope_scores.astype(jnp.float32)) * cfg.attn_scale
+    scores = jnp.where(mask[:, None], scores, jnp.float32(-1e30))
+    probs = jax.nn.softmax(scores, axis=-1).astype(q_nope.dtype)
+    if absorbed:
+        o_lat = jnp.einsum("bnst,btr->bsnr", probs, c)
+        out = jnp.einsum("bsnr,rnd->bsnd", o_lat, w_uv)
+    else:
+        out = jnp.einsum("bnst,btnd->bsnd", probs, v)
+    return out.reshape(b, s, n * dv)
+
+
+def _mla_attend_update(lp, cfg, x, cos, sin, q_positions, c_buf, r_buf, cache_write_pos):
+    """Latent attention's side of decoder_layer: queries per head, ONE
+    latent and ONE roped key per token written to the cache (`c_buf`
+    [B, T, R], `r_buf` [B, T, Dr]; None = no cache, the chunk attends to
+    itself), attention over it -> (attn [B, S, N * Dv], c_buf', r_buf')."""
+    b, s, _h = x.shape
+    dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    q = qdot(x, lp["q_proj"]).reshape(b, s, -1, dn + dr)
+    q_nope, q_pe = q[..., :dn], apply_rope(q[..., dn:], cos, sin)
+    kv_a = x @ lp["kv_a_proj"]
+    c = rms_norm(kv_a[..., :r], lp["kv_a_norm"], cfg.rms_norm_eps)
+    k_pe = apply_rope(kv_a[..., None, r:], cos, sin)[:, :, 0]
+    if c_buf is None:
+        with jax.named_scope("mla_attend"):
+            attn = mla_attend(
+                cfg, q_nope, q_pe, c, k_pe, lp["kv_b_proj"], q_positions,
+                jnp.int32(s), kv_positions=q_positions,
+            )
+        return attn, None, None
+    c, k_pe = _to_cache_dtype(c, c_buf.dtype), _to_cache_dtype(k_pe, r_buf.dtype)
+    if jnp.ndim(cache_write_pos) == 1:  # per-lane write positions (decode)
+        upd = jax.vmap(lambda buf, chunk, p: jax.lax.dynamic_update_slice(buf, chunk, (p, 0)))
+        new_c, new_r = upd(c_buf, c, cache_write_pos), upd(r_buf, k_pe, cache_write_pos)
+    else:
+        new_c = jax.lax.dynamic_update_slice(c_buf, c, (0, cache_write_pos, 0))
+        new_r = jax.lax.dynamic_update_slice(r_buf, k_pe, (0, cache_write_pos, 0))
+    with jax.named_scope("mla_attend"):
+        attn = mla_attend(
+            cfg, q_nope, q_pe, new_c, new_r, lp["kv_b_proj"], q_positions,
+            cache_write_pos + s, absorbed=s == 1,
+        )
+    return attn, new_c, new_r
+
+
+def decoder_layer(*args, **kwargs):
+    """`decoder_layer_routed` without the chosen experts."""
+    return decoder_layer_routed(*args, **kwargs)[:3]
+
+
+def decoder_layer_routed(
+    lp: Params,
+    cfg: ModelConfig,
+    hidden: jax.Array,  # [B, S, H]
+    cos: jax.Array,
+    sin: jax.Array,
+    q_positions: jax.Array,  # [B, S]
+    k_buf: Optional[jax.Array],  # [B, T, nkv(_local), D] or None (no cache: T == S)
+    v_buf: Optional[jax.Array],
+    cache_write_pos: Optional[jax.Array],  # slot where new k/v go: scalar, or [B] per row
+    tp_axis: Optional[str] = None,
+    ep_axis: Optional[str] = None,
+    window=None,  # sliding window: traced scalar (mask-only), or a STATIC
+    #   python int > 0 — then the cached KV READ narrows to a
+    #   window-covering slice (_windowed_slice); None/<=0 = global
+    ring_window: Optional[int] = None,  # STATIC window with k_buf/v_buf an
+    #   O(window) RING [B, R, Nkv, D] (_ring_attend_update) — the sliding-
+    #   layer storage fast path; requires real_end
+    real_end=None,  # scalar or [B]: first bucket-padding position
+    #   (ring + paged layouts)
+    block_table: Optional[jax.Array] = None,  # [B, MB] int32 — PAGED mode:
+    #   k_buf/v_buf are block POOLS [NB, bs, Nkv, D]; writes scatter
+    #   through the table, reads gather through it (core.cache.PagedKVCache)
+    write_mask: Optional[jax.Array] = None,  # [B] bool (paged only): rows
+    #   whose KV writes commit; False rows compute but write NOTHING — a
+    #   non-participating co-batch lane must never scribble on a block
+    #   another lane or a shared prefix may own
+    adapters=None,  # this layer's per-lane LoRA slice (multi-tenant
+    #   registry): {"layers": {target: (a [B, in, r], b [B, r, out])},
+    #   "scale": [B] f32} — slot-0 (base) lanes carry zero A/B and apply
+    #   nothing (ops.lora.apply_lane_delta)
+) -> Tuple[jax.Array, Optional[jax.Array], Optional[jax.Array], Optional[jax.Array]]:
+    """One pre-norm residual decoder block with GQA + per-head q/k RMSNorm
+    (the Qwen3 signature feature — reference qwen3_server_module.py:123-124),
+    or with latent attention (cfg.is_mla: k_buf/v_buf are then the latent
+    and rope-key buffers [B, T, R] / [B, T, Dr]).
+
+    Returns (hidden', k_buf', v_buf', chosen experts [B, S, K] or None for
+    a dense MLP). When k_buf is None the layer runs cache-free over the
+    full sequence (prefill-style parity testing).
+
+    Shard-polymorphic: head counts come from the projection widths, not the
+    config, so the same code runs full-width (single device / pp stage) or
+    on a tensor-parallel head shard inside shard_map — pass `tp_axis` there
+    and the block psums its two row-parallel outputs (attention o_proj and
+    the MLP down-proj, the Megatron minimum; tp.sharded_decoder_layer is
+    the cache-free training sibling). The KV buffer then holds this rank's
+    local heads only. `ep_axis` (MoE only) additionally shards the expert
+    axis: attention replicates across ep ranks (its weights and KV carry no
+    ep spec, mesh.layer_param_specs) while each rank computes its local
+    experts' contribution and the combine psums over (ep, tp).
+
+    Caller contract: cache_write_pos + S must be <= the buffer length T.
+    dynamic_update_slice clamps out-of-range starts (it would silently
+    overwrite the newest slots), so overflow must be prevented host-side —
+    the runtime's session registry enforces this before dispatch
+    (inferd_tpu.core.cache.KVCache.ensure_room).
+    """
+    p1 = cfg.rms_norm_plus_one
+
+    x = rms_norm(hidden, lp["input_norm"], cfg.rms_norm_eps, p1)
+    if cfg.is_mla:
+        if (tp_axis or ep_axis or block_table is not None or ring_window is not None
+                or window is not None or adapters is not None):
+            raise ValueError(
+                f"{cfg.name}: latent attention runs on the dense lane layout only "
+                "(no tensor/expert parallel shard, paged pool, ring, window or adapter)"
+            )
+        attn, new_k, new_v = _mla_attend_update(
+            lp, cfg, x, cos, sin, q_positions, k_buf, v_buf, cache_write_pos
+        )
+    else:
+        attn, new_k, new_v = _gqa_attend_update(
+            lp, cfg, x, cos, sin, q_positions, k_buf, v_buf, cache_write_pos,
+            window, ring_window, real_end, block_table, write_mask, adapters,
+        )
+
     attn_out = lora_ops.apply_lane_delta(
         qdot(attn, lp["o_proj"]), attn, "o_proj", adapters
     )
@@ -751,7 +919,8 @@ def decoder_layer(
     pre_ffn = lp["pre_ffn_norm"] if cfg.sandwich_norm else lp["post_norm"]
     x = rms_norm(hidden, pre_ffn, cfg.rms_norm_eps, p1)
     expert_axes = tuple(a for a in (ep_axis, tp_axis) if a is not None)
-    if cfg.is_moe:
+    topi = None
+    if cfg.is_moe and "router" in lp:  # a leading dense layer has no router
         if adapters is not None:
             raise ValueError(
                 "the adapter registry targets dense decoder projections — "
@@ -759,20 +928,24 @@ def decoder_layer(
                 "rejects them for the same reason)"
             )
         if expert_axes:
+            if cfg.n_shared_experts:
+                raise ValueError(
+                    f"{cfg.name}: the sharded expert layer has no shared expert"
+                )
             # expert weights shard over (ep, tp) on the EXPERT axis
             # (mesh.layer_param_specs); local dispatch + psum combine
             from inferd_tpu.parallel import tp as tplib  # lazy: tp imports us
 
             mlp_out = tplib.moe_mlp_sharded(lp, cfg, x, expert_axes)
         else:
-            mlp_out = moe_mlp(lp, cfg, x)
+            mlp_out, topi = moe_mlp_routed(lp, cfg, x)
     else:
         mlp_out = swiglu_mlp(lp, x, act_fn(cfg), lane_adapters=adapters)
         if tp_axis is not None:  # row-parallel down-proj
             mlp_out = jax.lax.psum(mlp_out, tp_axis)
     if cfg.sandwich_norm:
         mlp_out = rms_norm(mlp_out, lp["post_ffn_norm"], cfg.rms_norm_eps, p1)
-    return hidden + mlp_out.astype(hidden.dtype), new_k, new_v
+    return hidden + mlp_out.astype(hidden.dtype), new_k, new_v, topi
 
 
 # ---------------------------------------------------------------------------
@@ -801,6 +974,14 @@ def _stack_len(layers: Params) -> int:
     return jax.tree.leaves(layers)[0].shape[0]
 
 
+def layer_groups(params: Params) -> list:
+    """The model's homogeneous layer stacks in order: the leading dense
+    group of a model with experts (`dense_layers`, if it has one), then
+    `layers`. Every other model is the one group it always was."""
+    head = [params["dense_layers"]] if "dense_layers" in params else []
+    return head + [params["layers"]]
+
+
 def forward_layers(
     layers: Params,
     cfg: ModelConfig,
@@ -819,7 +1000,10 @@ def forward_layers(
     adapters=None,  # multi-tenant LoRA pools + per-lane ids (the ops.lora
     #   pool pytree: {"a", "b", "scale", "ids"}); gathered ONCE here, the
     #   per-layer slices ride the scan like the KV buffers
-) -> Tuple[jax.Array, Optional[jax.Array], Optional[jax.Array]]:
+    routing: bool = False,  # also return the experts each row chose in
+    #   each layer, [L, B, S, K] int32 (None for a stack of dense MLPs);
+    #   the uniform cached scan only
+):
     """Run a stack of decoder layers via lax.scan.
 
     The scan carries the hidden states and threads each layer's KV buffer
@@ -840,7 +1024,7 @@ def forward_layers(
     the uniform scan (mask-only windows) whenever the pattern can't be
     proven static.
     """
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta, cfg)
+    cos, sin = rope_cos_sin(positions, cfg.rope_dim, cfg.rope_theta, cfg)
     n_layers = _stack_len(layers)
 
     # multi-tenant LoRA: one per-lane gather of the stacked pools, then
@@ -943,15 +1127,17 @@ def forward_layers(
 
     def body(h, xs):
         lp, kb, vb, w, ad_sl = xs
-        h, nk, nv = decoder_layer(
+        h, nk, nv, topi = decoder_layer_routed(
             lp, cfg, h, cos, sin, positions, kb, vb, cache_write_pos,
             tp_axis, ep_axis, window=w, adapters=_ad(ad_sl),
         )
-        return h, (nk, nv)
+        return h, (nk, nv, topi)
 
-    hidden, (new_k, new_v) = jax.lax.scan(
+    hidden, (new_k, new_v, topi) = jax.lax.scan(
         body, hidden, (layers, k_cache, v_cache, wins, ad_per)
     )
+    if routing:
+        return hidden, new_k, new_v, topi
     return hidden, new_k, new_v
 
 
@@ -986,7 +1172,7 @@ def forward_layers_split(
     Returns (hidden, nk_glob, nv_glob, nk_loc, nv_loc).
     """
     assert cfg.sliding_window > 0 and isinstance(layer_offset, int)
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta, cfg)
+    cos, sin = rope_cos_sin(positions, cfg.rope_dim, cfg.rope_theta, cfg)
     n = _stack_len(layers)
     win = int(cfg.sliding_window)
 
@@ -1071,16 +1257,22 @@ def forward_layers_cached(
     layer_offset: int = 0,
     write_mask=None,  # [B] bool, paged caches only (see decoder_layer)
     adapters=None,  # multi-tenant LoRA pool pytree + per-lane ids
+    routing: bool = False,  # uniform layout only: also the chosen experts
 ):
     """Cached stage/model forward over a KVCache, dispatching on its
     storage layout: paged block pools (core.cache.PagedKVCache — writes
     scatter and reads gather through the lanes' block table), ring-split
     (k_loc present — sliding layers O(window)), or uniform full-length
-    buffers (classic path incl. the windowed-read pair scan). Returns
-    (hidden, new cache with the INPUT length — the caller advances it).
+    buffers (classic path incl. the windowed-read pair scan; a latent
+    cache is always uniform). Returns (hidden, new cache with the INPUT
+    length — the caller advances it); with `routing` also the experts each
+    row chose, [L, B, S, K] (None for dense MLPs).
     """
     from inferd_tpu.core.cache import KVCache, PagedKVCache
 
+    if routing and (isinstance(cache, PagedKVCache) or cache.k_loc is not None
+                    or cfg.sliding_window > 0):
+        raise ValueError("the chosen experts come out of the uniform cached scan only")
     if isinstance(cache, PagedKVCache):
         if real_end is None:
             real_end = cache_write_pos + hidden.shape[1]
@@ -1110,11 +1302,11 @@ def forward_layers_cached(
             cache.k_loc, cache.v_loc, cache_write_pos, real_end, layer_offset,
         )
         return h, KVCache(k=nk, v=nv, length=cache.length, k_loc=nkl, v_loc=nvl)
-    h, nk, nv = forward_layers(
+    h, nk, nv, *topi = forward_layers(
         layers, cfg, hidden, positions, cache.k, cache.v, cache_write_pos,
-        layer_offset=layer_offset, adapters=adapters,
+        layer_offset=layer_offset, adapters=adapters, routing=routing,
     )
-    return h, KVCache(k=nk, v=nv, length=cache.length)
+    return (h, KVCache(k=nk, v=nv, length=cache.length), *topi)
 
 
 def forward_cached(
@@ -1127,11 +1319,16 @@ def forward_cached(
     real_end=None,
     write_mask=None,  # [B] bool, paged caches only
     adapters=None,  # multi-tenant LoRA pool pytree + per-lane ids
+    routing: bool = False,  # uniform dense-lane layout only
 ):
     """Whole-model cached forward -> (logits [B, S, V], new cache with
     the INPUT length — the caller advances it). Ring-aware: sliding-window
     models with split caches store O(window) per sliding layer; paged
-    caches write/read through their block table."""
+    caches write/read through their block table. A model with leading
+    dense layers runs its groups one after the other, each over its own
+    layers of the cache. With `routing` a third value: the experts each
+    row chose in each sparse layer, [Ls, B, S, K] int32, or None for a
+    model without experts."""
     if positions is None:
         start = cache_write_pos
         if jnp.ndim(start) == 1:
@@ -1139,12 +1336,28 @@ def forward_cached(
         positions = start + jnp.broadcast_to(
             jnp.arange(tokens.shape[1]), tokens.shape
         )
+    from inferd_tpu.core import cache as cachelib
+
     hidden = embed(params, tokens, cfg)
-    hidden, new_cache = forward_layers_cached(
-        params["layers"], cfg, hidden, positions, cache, cache_write_pos,
-        real_end, write_mask=write_mask, adapters=adapters,
-    )
-    return unembed(params, cfg, hidden), new_cache
+    want = routing and cfg.is_moe
+    topi, offset = None, 0
+    groups = layer_groups(params)
+    new_cache = cache
+    for layers in groups:
+        n = _stack_len(layers)
+        sub = cache if len(groups) == 1 else cachelib.layer_slice(cache, offset, offset + n)
+        out = forward_layers_cached(
+            layers, cfg, hidden, positions, sub, cache_write_pos,
+            real_end, layer_offset=offset, write_mask=write_mask,
+            adapters=adapters, routing=want,
+        )
+        hidden = out[0]
+        new_cache = out[1] if len(groups) == 1 else cachelib.layer_write(new_cache, offset, out[1])
+        if want and out[2] is not None:
+            topi = out[2]  # the one group with routers
+        offset += n
+    logits = unembed(params, cfg, hidden)
+    return (logits, new_cache, topi) if routing else (logits, new_cache)
 
 
 def decode_k(
@@ -1338,6 +1551,13 @@ def forward(
             start = start[:, None]
         positions = start + jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
     hidden = embed(params, tokens, cfg)
+    groups = layer_groups(params)
+    if len(groups) > 1:
+        if k_cache is not None:
+            raise ValueError(f"{cfg.name}: grouped layers run cached through forward_cached")
+        for layers in groups:
+            hidden, _, _ = forward_layers(layers, cfg, hidden, positions)
+        return unembed(params, cfg, hidden), None, None
     hidden, nk, nv = forward_layers(
         params["layers"], cfg, hidden, positions, k_cache, v_cache, cache_write_pos
     )

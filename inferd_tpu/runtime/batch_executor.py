@@ -99,6 +99,13 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         # windows interleave instead of stalling behind a long admission
         self.prefill_chunk = int(prefill_chunk)
         self.prefill_tokens = 0  # tokens actually computed by prefill
+        # cumulative expert-routing counters over decode steps (/stats
+        # `executor` `moe.*`); None where the decode program returns no
+        # routing (no experts, or a paged / windowed layout)
+        self._moe = (
+            dict(steps=0, assignments=0, assignments_hottest=0, experts_touched=0)
+            if self.engine.routes else None
+        )
         self.max_len = max_len
         self.ttl_s = session_ttl_s
 
@@ -785,6 +792,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                         lane, token, _ks = e.payload
                         toks[lane] = token
                         active[lane] = True
+                    routed = None  # the paged program returns no routing
                     if self.pool is not None:
                         self.engine.cache, logits = (
                             self.engine._decode_logits_paged(
@@ -802,7 +810,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                             tokens=len(legacy), cobatch=len(legacy),
                             program=program_name(fn),
                         ):
-                            self.engine.cache, logits = fn(
+                            self.engine.cache, logits, routed = fn(
                                 self.engine.params, self.engine.cache,
                                 jnp.asarray(toks, jnp.int32),
                                 jnp.asarray(lens, jnp.int32),
@@ -812,6 +820,10 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                     with tracelib.region(self.tracer, "copy_out") as at:
                         out = np.asarray(logits, np.float32)
                         at["bytes"] = out.nbytes
+                    if routed is not None:  # came out with the logits: no wait
+                        self._count_routing(
+                            np.asarray(routed)[:, [e.payload[0] for e in legacy]]
+                        )
                     with self._mu:
                         for e in legacy:
                             self.engine.lengths[e.payload[0]] += 1
@@ -887,6 +899,26 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                     self._batcher.n_served -= len(grp)  # see legacy note
                     if not cache_intact(self.engine.cache):
                         poisoned = exc
+
+    def _count_routing(self, chosen: np.ndarray) -> None:
+        """The `moe.*` counters of one decode step from the experts its
+        live rows chose, `chosen` [sparse layers, live rows, K]: the
+        assignments made, those that fell on each layer's most loaded
+        expert, and the distinct experts hit, each summed over the layers."""
+        per_layer = [np.bincount(layer.ravel(), minlength=self.cfg.num_experts)
+                     for layer in chosen]
+        with self._mu:
+            self._moe["steps"] += 1
+            self._moe["assignments"] += int(chosen.size)
+            self._moe["assignments_hottest"] += int(sum(c.max() for c in per_layer))
+            self._moe["experts_touched"] += int(sum((c > 0).sum() for c in per_layer))
+
+    def _refuse_latent(self, what: str) -> None:
+        if self.cfg.is_mla:
+            raise ValueError(
+                f"{self.cfg.name}: {what} of a latent cache is not supported "
+                "(the handoff schema carries keys and values per head)"
+            )
 
     def end_session(self, session_id: str) -> None:
         with self._mu:
@@ -998,6 +1030,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         _export_and_handoff and /import_session work unchanged for
         --batch-lanes replicas. `only` exports a single session (the
         deliberate prefill->decode handoff path)."""
+        self._refuse_latent("handoff export")
         out = []
         with self._dev_lock:  # quiesce the device first
             if self.pool is not None:
@@ -1086,6 +1119,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         from inferd_tpu.runtime import handoff
         from inferd_tpu.runtime.repl import START_KEY
 
+        self._refuse_latent("standby export")
         since = max(0, int(since))
         # cheap nothing-to-ship early-out under _mu alone: the common
         # replication tick (every resident session, every interval) must
@@ -1159,6 +1193,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         from inferd_tpu.core.cache import KVCache
         from inferd_tpu.runtime import handoff
 
+        self._refuse_latent("import")
         ring = self.engine.cache.k_loc is not None
         # validate against the spec-capped capacity: an imported session
         # longer than cap would break the verify-chunk headroom contract
@@ -1388,6 +1423,12 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             )
             if self.pool is not None:
                 out["paged"] = self.pool.block_stats()
+            else:  # what the lanes' cache really allocates
+                nbytes = self.engine.cache.nbytes
+                out["kv_cache_bytes"] = nbytes
+                out["kv_bytes_per_token"] = nbytes // (self.engine.lanes * self.max_len)
+            if self._moe is not None:
+                out["moe"] = dict(self._moe, experts=self.cfg.num_experts)
             if self.adapters is not None:
                 out["adapters"] = self.adapters.stats()
             return out

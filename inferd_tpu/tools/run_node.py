@@ -410,6 +410,31 @@ def parse_mesh(value: str):
     return plan
 
 
+def check_servable(cfg, args) -> None:
+    """The one place that refuses, by the model's name, every serving path
+    that cannot run a model with latent attention or with a leading dense
+    group: such a model is served whole from the dense lanes of
+    --batch-lanes, in its own dtype or --kv-dtype, and by nothing else."""
+    if not (cfg.is_mla or cfg.num_dense_layers):
+        return
+    refused = {
+        "--mesh (no latent cache or layer groups under a mesh)": args.mesh,
+        "--stage-lanes (a stage holds one group of layers)": args.stage_lanes > 0,
+        "--paged-kv (the paged pool has no latent entry)": args.paged_kv > 0,
+        "--quant (the latent and shared-expert projections have no quantized form)":
+            args.quant != "none",
+        "--spec-draft-layers (no self-draft over layer groups)": args.spec_draft_layers > 0,
+        "--lora": bool(args.lora),
+        "--adapters (the registry targets per-head dense projections)": bool(args.adapters),
+        "--standby-repl (no handoff or standby export of a latent cache)": args.standby_repl,
+        "serving without --batch-lanes (only the lane executor runs its layer groups)":
+            args.backend == "qwen3" and args.batch_lanes <= 0,
+    }
+    hit = [what for what, on in refused.items() if on]
+    if hit:
+        raise SystemExit(f"run_node: {cfg.name} cannot be served with " + "; ".join(hit))
+
+
 async def _run(args, cache_stats=None) -> None:
     # heavyweight imports AFTER main() pinned the platform
     from inferd_tpu.control.dht import SwarmDHT
@@ -442,6 +467,13 @@ async def _run(args, cache_stats=None) -> None:
         else:
             stage = 0
 
+    cfg = manifest.config
+    check_servable(cfg, args)
+    if args.kv_dtype != "model":
+        import dataclasses
+
+        cfg = dataclasses.replace(cfg, kv_dtype=args.kv_dtype)
+
     host = args.host or get_own_ip()
     info = NodeInfo(
         name=name,
@@ -458,11 +490,6 @@ async def _run(args, cache_stats=None) -> None:
         bootstrap=parse_bootstrap(args.bootstrap),
         host="0.0.0.0",
     )
-    cfg = manifest.config
-    if args.kv_dtype != "model":
-        import dataclasses
-
-        cfg = dataclasses.replace(cfg, kv_dtype=args.kv_dtype)
     node = Node(
         info,
         cfg,

@@ -245,3 +245,41 @@ def test_batched_decode_step_compiles(one_chip, no_compile_cache, block_size):
     mem = compiled.memory_analysis()
     # weights + cache + logits of this one program fit a 16 GB chip
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_dsv2l_lane_programs_compile_and_decode_expands_no_head_over_the_cache(
+    one_chip, no_compile_cache
+):
+    """The two programs a `--model deepseek-v2-lite-8l --batch-lanes 16
+    --max-len 4096` node runs, at the published widths: both fit the chip
+    with the weights (9.19 GB) and the 16 lanes of latents, and the decode
+    program is absorbed — of the arrays it holds over the cache's 4096
+    slots none has a head axis of 192 or 128 values (a key or a value per
+    head); the cache itself is [layers, 16, 4096, 512] and [.., 64]."""
+    import re
+
+    from inferd_tpu.core.batch import BatchedEngine
+    from inferd_tpu.core.cache import KVCache
+    from inferd_tpu.models import qwen3
+
+    cfg = get_config("deepseek-v2-lite-8l")
+    lanes, max_len = 16, 4096
+    params = _on(jax.eval_shape(lambda: qwen3.init_params(cfg, jax.random.PRNGKey(0))), one_chip)
+    eng = BatchedEngine(cfg, None, lanes=lanes, max_len=64)
+    cache = _on(jax.eval_shape(lambda: KVCache.create(cfg, cfg.num_layers, lanes, max_len)),
+                one_chip)
+    toks = _sds((lanes,), jnp.int32, one_chip)
+    decode = eng._decode_logits.lower(params, cache, toks, toks).compile()
+    mem = decode.memory_analysis()
+    assert 9.7e9 < mem.argument_size_in_bytes < 9.9e9  # weights + 0.60 GB of latents
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
+    over_cache = set(re.findall(r"(?:bf16|f32)\[[0-9,]*4096[0-9,]*\]", decode.as_text()))
+    assert "bf16[8,16,4096,512]" in over_cache and "bf16[8,16,4096,64]" in over_cache
+    per_head = [s for s in over_cache
+                if re.search(r"\b16,(192|128)\]|,16,4096,(192|128)\]", s)]
+    assert not per_head, per_head
+    i32 = _sds((), jnp.int32, one_chip)
+    chunk = _sds((1, 512), jnp.int32, one_chip)
+    prefill = eng._prefill_lane_logits.lower(params, cache, chunk, i32, i32, i32).compile()
+    mem = prefill.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
