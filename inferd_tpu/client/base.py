@@ -161,6 +161,44 @@ def _softmax(x: np.ndarray) -> np.ndarray:
     return e / s
 
 
+def unpack_reply(where: str, status: int, raw: bytes, headers) -> Dict[str, Any]:
+    """A node's reply bytes -> the reply dict, or the ServerError its
+    status/code/retry_after/resume_from describe. The ONE reply contract
+    for every transport: the HTTP clients hand it what they read off the
+    socket, the node's in-process generation loop what a relay brought
+    back."""
+    try:
+        data = wire.unpack(raw)
+    except Exception:
+        snippet = raw[:200].decode("utf-8", "replace")
+        # ValueError: transport-level garbage (error page, truncated
+        # stream) — callers with multiple endpoints treat it as
+        # "this endpoint is bad" and fail over
+        raise ValueError(f"{where} returned non-wire body (HTTP {status}): {snippet!r}")
+    if status == 200:
+        return data
+    detail = data.get("error", data) if isinstance(data, dict) else data
+    code = data.get("code") if isinstance(data, dict) else None
+    ra = data.get("retry_after") if isinstance(data, dict) else None
+    if ra is None:
+        # busy 503s also carry the standard header — parse it
+        # so a plain-HTTP shed (no wire body) still paces us
+        ra = headers.get("Retry-After")
+    try:
+        ra = None if ra is None else float(ra)
+    except (TypeError, ValueError):
+        ra = None
+    rf = data.get("resume_from") if isinstance(data, dict) else None
+    try:
+        rf = None if rf is None else int(rf)
+    except (TypeError, ValueError):
+        rf = None
+    raise ServerError(
+        f"{where} error {status}: {detail}", status, code,
+        retry_after=ra, resume_from=rf,
+    )
+
+
 class GenerationClient:
     """Base: the sampling/EOS/session loop over an abstract transport.
 
@@ -197,7 +235,7 @@ class GenerationClient:
         # pinned prefix FORK it instead of re-prefilling those tokens.
         # LRU-capped: each pin holds a [V] logits array here and a pinned
         # KV session per stage server-side — unbounded pins on a long-lived
-        # client (e.g. the node's /generate self-client) would grow RSS and
+        # client (e.g. the node's /generate loop) would grow RSS and
         # crowd the servers' session stores.
         self._pins: "OrderedDict[tuple, tuple]" = OrderedDict()
         self.max_pins = 8
@@ -207,7 +245,7 @@ class GenerationClient:
         # sample spans under it; the trace context rides the /forward
         # envelope and the X-Inferd-Trace header so node-side spans merge
         # into the same end-to-end timeline. A co-located serving layer
-        # (the node's /generate self-client) swaps in its own recorder so
+        # (the node's /generate loop, client.local_client) swaps in its own recorder so
         # all of a node's spans land in one JSONL file.
         self.tracer = tracelib.SpanRecorder(service="client")
 
@@ -267,6 +305,19 @@ class GenerationClient:
 
     # -- shared helpers ------------------------------------------------------
 
+    def _hop_timeout_s(self, where: str) -> float:
+        """One hop's time limit: the static client timeout, or what is
+        left of the active end-to-end deadline where that is less (plus a
+        beat for the node's own typed 408 to make it back). A spent
+        budget fails HERE instead of shipping a request every hop would
+        only fast-fail anyway."""
+        rem = retrylib.remaining_s(_DEADLINE_MS.get())
+        if rem is None:
+            return self.timeout_s
+        if rem <= 0:
+            raise _deadline_error(f"before POST {where}")
+        return min(self.timeout_s, rem + 0.25)
+
     async def _post_url(self, url: str, body: Dict[str, Any]) -> Dict[str, Any]:
         """POST a wire envelope; unpack defensively (a plain-HTTP error page
         or truncated body must surface the status, not a msgpack error).
@@ -274,54 +325,11 @@ class GenerationClient:
         header — the propagation surface for endpoints whose envelope has
         no `trace` key (/generate)."""
         assert self._http is not None, "use `async with <client>(...)`"
-        headers = tracelib.header_ctx()
-        kw: Dict[str, Any] = {}
-        rem = retrylib.remaining_s(_DEADLINE_MS.get())
-        if rem is not None:
-            if rem <= 0:
-                # the budget is gone: fail locally instead of shipping a
-                # request every hop would only fast-fail anyway
-                raise _deadline_error(f"before POST {url}")
-            # per-request timeout = the smaller of the static client
-            # timeout and what's left of the end-to-end budget (plus a
-            # beat for the node's own typed 408 to make it back)
-            kw["timeout"] = ClientTimeout(
-                total=min(self.timeout_s, rem + 0.25)
-            )
         async with self._http.post(
-            url, data=wire.pack(body), headers=headers, **kw
+            url, data=wire.pack(body), headers=tracelib.header_ctx(),
+            timeout=ClientTimeout(total=self._hop_timeout_s(url)),
         ) as r:
-            raw = await r.read()
-            try:
-                data = wire.unpack(raw)
-            except Exception:
-                snippet = raw[:200].decode("utf-8", "replace")
-                # ValueError: transport-level garbage (error page, truncated
-                # stream) — callers with multiple endpoints treat it as
-                # "this endpoint is bad" and fail over
-                raise ValueError(f"{url} returned non-wire body (HTTP {r.status}): {snippet!r}")
-            if r.status != 200:
-                detail = data.get("error", data) if isinstance(data, dict) else data
-                code = data.get("code") if isinstance(data, dict) else None
-                ra = data.get("retry_after") if isinstance(data, dict) else None
-                if ra is None:
-                    # busy 503s also carry the standard header — parse it
-                    # so a plain-HTTP shed (no wire body) still paces us
-                    ra = r.headers.get("Retry-After")
-                try:
-                    ra = None if ra is None else float(ra)
-                except (TypeError, ValueError):
-                    ra = None
-                rf = data.get("resume_from") if isinstance(data, dict) else None
-                try:
-                    rf = None if rf is None else int(rf)
-                except (TypeError, ValueError):
-                    rf = None
-                raise ServerError(
-                    f"{url} error {r.status}: {detail}", r.status, code,
-                    retry_after=ra, resume_from=rf,
-                )
-            return data
+            return unpack_reply(url, r.status, await r.read(), r.headers)
 
     # -- public API ----------------------------------------------------------
 
@@ -355,7 +363,10 @@ class GenerationClient:
                 logits = await self._traced_step(sid, chunk, pos)
                 pos += len(chunk)
             assert logits is not None
-            self._pins[ids] = (sid, logits)
+            # a copy of the pin's own row: what a transport hands back may
+            # be a view into a whole co-batch's [L, V] array, and a pin
+            # outlives the step that made it
+            self._pins[ids] = (sid, np.array(logits))
             while len(self._pins) > self.max_pins:
                 _, (old_sid, _l) = self._pins.popitem(last=False)
                 try:

@@ -30,7 +30,7 @@ import time
 import uuid
 from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple, Union
 
 import aiohttp
 import numpy as np
@@ -125,6 +125,14 @@ from inferd_tpu.control.dht import sess_hash  # noqa: E402,F401
 class _ClientGone(Exception):
     """The streaming client disconnected mid-write: abort the stream
     quietly (no restart re-run for a dead socket)."""
+
+
+def _as_response(out: Union[web.Response, Dict[str, Any]]) -> web.Response:
+    """What a handler core returned, for the socket: a reply dict is
+    packed here (the core's local caller takes it unpacked)."""
+    if isinstance(out, dict):
+        return web.Response(body=wire.pack(out))
+    return out
 
 
 def _is_decode_step(payload) -> bool:
@@ -274,8 +282,12 @@ class Node:
         # swarm-wide request tracing (obs.trace): spans recorded host-side
         # into this ring, periodically appended to
         # <trace_dir>/<node_id>.spans.jsonl when --trace-dir is set (the
-        # merge CLI's per-node input), always served live at /spans
-        self.tracer = tracelib.SpanRecorder(service=info.node_id)
+        # merge CLI's per-node input), always served live at /spans. A
+        # decoded token leaves seven spans a session; the ring has to hold
+        # what a poller reads every few seconds (the benchmark's traced run:
+        # five) at some hundreds of tokens a second, or the `capture` span
+        # and a share of every window are gone before they are read
+        self.tracer = tracelib.SpanRecorder(service=info.node_id, cap=32768)
         # fleet flight recorder (obs.events): typed events (migrations,
         # rescues, dead peers, lane evictions, compiles, ...) with the
         # active trace_id attached; flushed next to the span file as
@@ -538,8 +550,9 @@ class Node:
         self._runner: Optional[web.AppRunner] = None
         self._stopped = asyncio.Event()
         self._sweep_task: Optional[asyncio.Task] = None
-        # lazy self-pointed swarm client for /generate (server-driven loop);
-        # persistent so its pinned prefix sessions survive across requests
+        # lazy generation loop of /generate (client.local_client: its hops
+        # are calls into _serve_local); persistent so its pinned prefix
+        # sessions survive across requests
         self._generate_client = None
         self._generate_client_lock = asyncio.Lock()
         # session affinity: (session_id, stage) -> (node_id, ts). A session's
@@ -945,7 +958,7 @@ class Node:
         await self.balancer.stop()
         if self._generate_client is not None:
             try:
-                # drops its pinned prefix sessions, then closes its session
+                # drops its pinned prefix sessions
                 await self._generate_client.__aexit__(None, None, None)
             except Exception:
                 pass
@@ -1470,7 +1483,7 @@ class Node:
             return self._error_response(400, f"bad envelope: {e}")
         if isinstance(env, dict) and env.get(wire.MULTI_KEY) is not None:
             return await self._handle_multi_forward(env, t0)
-        return await self._forward_one(env, t0)
+        return _as_response(await self._forward_one(env, t0))
 
     async def _handle_multi_forward(self, env, t0: float) -> web.Response:
         """A coalesced relay envelope: N sessions' decode activations in
@@ -1486,17 +1499,27 @@ class Node:
             return self._error_response(400, f"bad multi envelope: {e}")
         self.metrics.inc("forward.multi_envelopes")
         self.metrics.inc("forward.multi_frames", len(frames))
-        resps = await asyncio.gather(
-            *(self._forward_one(f, t0) for f in frames)
-        )
+        resps = [
+            _as_response(r) for r in await asyncio.gather(
+                *(self._forward_one(f, t0) for f in frames)
+            )
+        ]
         multi = [
             {"status": r.status, "body": bytes(r.body or b"")} for r in resps
         ]
         return web.Response(body=wire.pack({wire.MULTI_KEY: multi}))
 
-    async def _forward_one(self, env, t0: float) -> web.Response:
+    async def _forward_one(
+        self, env, t0: float, via: str = "http",
+    ) -> Union[web.Response, Dict[str, Any]]:
+        """The ONE forward path, entered by the /forward route (`via`
+        "http") and by the node's own generation loop ("local",
+        _serve_local) with the same envelope dict. A hop this node
+        finishes comes back as the reply DICT — the route packs it, the
+        local caller reads it as it is; every other outcome (an error, a
+        relayed hop's downstream reply) is a Response holding wire bytes."""
         if not tracelib.enabled():
-            return await self._forward_inner(env, t0, None)
+            return await self._forward_inner(env, t0, None, via)
         # server umbrella span for this hop: parented to the `trace` key
         # riding the envelope (a client step span or an upstream relay
         # span — its send/recv pair brackets this span for the merge
@@ -1508,7 +1531,7 @@ class Node:
         )
         t_wall = tracelib.now()
         try:
-            return await self._forward_inner(env, t0, tin)
+            return await self._forward_inner(env, t0, tin, via)
         finally:
             try:
                 stage_attr = int(env.get("stage", 0))
@@ -1516,13 +1539,14 @@ class Node:
                 stage_attr = -1
             self.tracer.record_span(
                 "forward", "server", t_wall, tracelib.now(),
-                parent=parent, ctx=tin, attrs={"stage": stage_attr},
+                parent=parent, ctx=tin,
+                attrs={"stage": stage_attr, "via": via},
             )
 
     async def _forward_inner(
         self, env: Dict[str, Any], t0: float,
-        tin: Optional[tracelib.SpanContext],
-    ) -> web.Response:
+        tin: Optional[tracelib.SpanContext], via: str,
+    ) -> Union[web.Response, Dict[str, Any]]:
         stage = int(env.get("stage", 0))
         session_id = env.get("session_id") or str(uuid.uuid4())
         task_id = env.get("task_id") or str(uuid.uuid4())
@@ -1764,6 +1788,10 @@ class Node:
                 return promo
 
         self.metrics.inc("forward.requests")
+        if via == "local":
+            # the hops /generate served in process: local / requests is
+            # the share of this node's forwards that touched no socket
+            self.metrics.inc("forward.local")
         if self.chaos is not None:
             try:
                 await self.chaos.before_forward()
@@ -1942,13 +1970,14 @@ class Node:
                 # prompt skipped on this replica (key absent on cold
                 # prefills and old builds — additive wire change)
                 result["tokens_saved"] = saved
-            resp = {
+            # the reply as a dict: handle_forward packs it for the socket,
+            # the node's own generation loop samples from it unpacked
+            return {
                 "task_id": task_id,
                 "session_id": session_id,
                 "result_for_user": result,
                 "served_by": self.info.node_id,
             }
-            return web.Response(body=wire.pack(resp))
 
         rem = retrylib.remaining_s(deadline_ms)
         if rem is not None and rem <= 0:
@@ -3509,6 +3538,14 @@ class Node:
         the client falls back to a full prefill."""
         try:
             env = wire.unpack(await request.read())
+        except Exception as e:
+            return self._error_response(400, f"bad fork_session: {e}")
+        return await self._fork_session(env)
+
+    async def _fork_session(self, env: Dict[str, Any]) -> web.Response:
+        """handle_fork_session past the socket: the route and the node's
+        own generation loop (_serve_local) both enter here."""
+        try:
             new_sid = env["session_id"]
             parent_sid = env["parent_session_id"]
             prefix_len = int(env["prefix_len"])
@@ -3626,7 +3663,7 @@ class Node:
         trace surface of this endpoint — there is no per-hop envelope on
         the outer request) parents a `server`-phase umbrella span, and the
         contextvar makes every span of the node's self-driven token loop
-        (its swarm client's steps, the /forward hops they trigger) nest
+        (its generation loop's steps, the forward hops they make) nest
         under it. NOT phase "sample": the merge CLI counts sample-phase
         spans as emitted tokens, and an umbrella would inflate every
         server-driven generation by one. With tracing disabled this is a
@@ -3704,9 +3741,13 @@ class Node:
 
         The client-side token loop (client.base) costs a network round trip
         per token — fine on a LAN, ruinous for a high-latency client. Here
-        the NODE runs that same loop against itself (the swarm client
-        pointed at this node's own /forward; wrong-stage entry relays to
-        stage 0 as usual), so the caller pays one round trip total. POST
+        the NODE runs that same loop against itself, in process: each hop
+        is a call into the forward path the /forward route enters
+        (_serve_local), and a hop this node finishes hands the sampler
+        the executor's logits with no bytes packed and no socket touched.
+        A hop that leaves the node (a later stage of a chain; wrong-stage
+        entry relays to stage 0 as usual) is relayed as ever and only its
+        reply is unpacked. The caller pays one round trip total. POST
         {"prompt_ids": [...], "max_new_tokens", "sampling": {temperature,
         top_k, top_p, min_p}, "seed", "eos_token_id", "pin_prefix_len",
         "stream"} -> {"ids": [...]}.  pin_prefix_len > 0 marks the first N
@@ -3906,23 +3947,38 @@ class Node:
         return web.Response(body=wire.pack(payload))
 
     async def _get_generate_client(self):
-        """Lazy self-pointed swarm client shared by all /generate requests
-        (persistent so node-held prefix pins survive across requests)."""
-        from inferd_tpu.client.swarm_client import SwarmClient
+        """Lazy generation loop shared by all /generate requests
+        (persistent so node-held prefix pins survive across requests):
+        the swarm client's loop with its hops served in process
+        (_serve_local) instead of posted to this node's own port."""
+        from inferd_tpu.client.local_client import LocalClient
 
         async with self._generate_client_lock:
             if self._generate_client is None:
-                c = SwarmClient(
-                    [(self.info.host, self.info.port)],
+                c = LocalClient(
+                    self._serve_local, (self.info.host, self.info.port),
                     timeout_s=self.hop_timeout_s,
                 )
-                # share the NODE's span ring: the self-client's step/sample
+                # share the NODE's span ring: the loop's step/sample
                 # spans belong in this node's JSONL file, not a parallel
                 # "client" buffer nobody exports
                 c.tracer = self.tracer
                 await c.__aenter__()
                 self._generate_client = c
         return self._generate_client
+
+    async def _serve_local(
+        self, path: str, env: Dict[str, Any],
+    ) -> Union[web.Response, Dict[str, Any]]:
+        """The generation loop's transport: each hop enters the handler
+        core its route would have entered, with the envelope as built."""
+        if path == FORWARD_PATH:
+            return await self._forward_one(env, time.perf_counter(), "local")
+        if path == END_SESSION_PATH:
+            return await self._end_session(env)
+        if path == FORK_SESSION_PATH:
+            return await self._fork_session(env)
+        raise ValueError(f"no in-process handler for {path}")
 
     @staticmethod
     def _spec_key(sampling):
@@ -4557,6 +4613,13 @@ class Node:
         """Drop a session's KV cache here and on downstream stages."""
         try:
             env = wire.unpack(await request.read())
+        except Exception as e:
+            return self._error_response(400, f"bad end_session: {e}")
+        return await self._end_session(env)
+
+    async def _end_session(self, env: Dict[str, Any]) -> web.Response:
+        """handle_end_session past the socket (see _fork_session)."""
+        try:
             session_id = env["session_id"]
         except Exception as e:
             return self._error_response(400, f"bad end_session: {e}")
@@ -4798,10 +4861,14 @@ class Node:
     async def handle_spans(self, request: web.Request) -> web.Response:
         """GET /spans — the live span ring as newline-delimited JSON
         (non-draining; the merge CLI's ad-hoc input for a running node)."""
-        body = "\n".join(self.tracer.jsonl_lines()) + "\n"
+        # off the event loop: a full ring is a fifth of a second of
+        # json.dumps, and every session's token loop runs on this loop
+        body = await asyncio.get_running_loop().run_in_executor(
+            None,
+            lambda: ("\n".join(self.tracer.jsonl_lines()) + "\n").encode(),
+        )
         return web.Response(
-            body=body.encode(),
-            headers={"Content-Type": "application/x-ndjson"},
+            body=body, headers={"Content-Type": "application/x-ndjson"},
         )
 
     async def handle_events(self, request: web.Request) -> web.Response:
