@@ -151,6 +151,15 @@ class ModelConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def layer_pattern(self) -> tuple:
+        """The kinds of attention layer, one period of them: GLOBAL layer i
+        has kind layer_pattern[i % len(layer_pattern)]. "sliding" attends
+        within `sliding_window`, "global" over everything before it. The
+        one place that says which layers are which (the scan of
+        models/qwen3.forward_layers, the ring storage of core/cache)."""
+        return ("sliding", "global") if self.sliding_window else ("global",)
+
+    @property
     def num_dense_layers(self) -> int:
         """Leading layers with the dense MLP in a model with experts."""
         return min(self.first_k_dense_replace, self.num_layers) if self.is_moe else 0

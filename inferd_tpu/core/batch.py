@@ -94,8 +94,8 @@ class BatchedEngine:
 
         sc = self.sampling
         L = lanes
-        # the decode program also returns the experts each lane chose (the
-        # uniform dense-lane scan only: models/qwen3.forward_layers_cached)
+        # the dense-lane decode program also returns the experts each lane
+        # chose (the third value of models/qwen3.forward_cached)
         self.routes = routes = cfg.is_moe and block_size == 0 and cfg.sliding_window == 0
 
         from inferd_tpu.core.cache import lane_slice as _lane_slice
@@ -109,7 +109,7 @@ class BatchedEngine:
             lane's cache rows, return the sampled/greedy next token (+ its
             model logprob and top-N alternatives)."""
             lc = _lane_slice(cache, lane)
-            logits, nc = qwen3.forward_cached(
+            logits, nc, _ = qwen3.forward_cached(
                 params, cfg, tokens, None, lc, jnp.int32(0), real_end=n
             )
             cache = _lane_write(cache, lane, nc)
@@ -139,7 +139,7 @@ class BatchedEngine:
             prefix; inactive lanes compute at position 0 and are ignored.
             """
             pos = lengths[:, None]  # [L, 1] absolute position per lane
-            logits, nc = qwen3.forward_cached(
+            logits, nc, _ = qwen3.forward_cached(
                 params, cfg, toks[:, None], pos, cache, lengths,
                 real_end=lengths + 1,
             )
@@ -205,11 +205,11 @@ class BatchedEngine:
             [Ls, L, K] int32 (the `moe.*` counters of /stats), or None for
             a model without experts."""
             pos = lengths[:, None]
-            logits, nc, *topi = qwen3.forward_cached(
+            logits, nc, topi = qwen3.forward_cached(
                 params, cfg, toks[:, None], pos, cache, lengths,
-                real_end=lengths + 1, adapters=ads, routing=routes,
+                real_end=lengths + 1, adapters=ads,
             )
-            return nc, logits[:, 0], topi[0][:, :, 0] if routes else None
+            return nc, logits[:, 0], topi[:, :, 0] if routes else None
 
         @partial(jax.jit, donate_argnames=("cache",))
         def _prefill_lane_logits(params, cache: KVCache, tokens, lane, start,
@@ -219,7 +219,7 @@ class BatchedEngine:
             chunked prefill at any start_pos). `ads` carries a single-row
             "ids" for this lane's adapter slot."""
             lc = _lane_slice(cache, lane)
-            logits, nc = qwen3.forward_cached(
+            logits, nc, _ = qwen3.forward_cached(
                 params, cfg, tokens, None, lc, start, real_end=start + n,
                 adapters=ads,
             )
@@ -258,7 +258,7 @@ class BatchedEngine:
             False) drop their garbage writes — pool blocks are shared
             property, unlike the dense layout's lane-private rows."""
             pos = lengths[:, None]
-            logits, nc = qwen3.forward_cached(
+            logits, nc, _ = qwen3.forward_cached(
                 params, cfg, toks[:, None], pos, cache, lengths,
                 real_end=lengths + 1, write_mask=active, adapters=ads,
             )
@@ -272,7 +272,7 @@ class BatchedEngine:
             lc = PagedKVCache(
                 k=cache.k, v=cache.v, table=table_row, length=cache.length
             )
-            logits, nc = qwen3.forward_cached(
+            logits, nc, _ = qwen3.forward_cached(
                 params, cfg, tokens, None, lc, start, real_end=start + n,
                 adapters=ads,
             )

@@ -12,6 +12,15 @@ Layout: k/v are [num_global_layers, batch, max_len, num_kv_heads, head_dim];
 (`ensure_room`) because in-jit dynamic_update_slice clamps silently (see
 models/qwen3.decoder_layer contract).
 
+This module owns the LAYOUTS. For one layer a cache entry is one value: none
+(cache-free), `DenseEntry`, `LatentEntry`, `RingEntry` or `PagedEntry`;
+stacked over layers they are what the one layer scan of
+models/qwen3.forward_layers scans. `KVCache` and `PagedKVCache` turn
+themselves into stacked entries (`entries`) and back (`with_entries`), and
+`ctx` gives what is the same for every layer (`CacheCtx`). The model file
+holds one write-then-read function per entry type and never takes a cache's
+arrays apart; a new layout is a new entry type here and one function there.
+
 Sliding-window models (Gemma-2, GPT-OSS) additionally carry RING buffers
 `k_loc`/`v_loc` [num_sliding_layers, batch, ring, kv, d] for their sliding
 (even-global-index) layers: a sliding layer never attends past its window,
@@ -28,7 +37,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import OrderedDict
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,12 +60,68 @@ def ring_slots(cfg: ModelConfig) -> int:
 def sliding_layer_ids(
     cfg: ModelConfig, num_layers: int, layer_offset: int
 ) -> List[int]:
-    """Stack-local indices of the SLIDING layers (static python): global
-    layer index (layer_offset + i) even — the Gemma-2/GPT-OSS alternation
-    (models/qwen3.layer_windows)."""
-    if not cfg.sliding_window:
-        return []
-    return [i for i in range(num_layers) if (layer_offset + i) % 2 == 0]
+    """Stack-local indices of the SLIDING layers (static python): those
+    whose global layer index (layer_offset + i) is "sliding" in
+    cfg.layer_pattern — the Gemma-2/GPT-OSS alternation."""
+    kinds = cfg.layer_pattern
+    return [
+        i for i in range(num_layers)
+        if kinds[(layer_offset + i) % len(kinds)] == "sliding"
+    ]
+
+
+# ---------------------------------------------------------------------------
+# One layer's cache entry, by layout. The same types with a leading layer
+# axis on every array are the stacked entries the layer scan scans.
+# ---------------------------------------------------------------------------
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class DenseEntry:
+    """Dense lanes: slot index == absolute position."""
+
+    k: jax.Array  # [B, T, Nkv, D]
+    v: jax.Array
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class LatentEntry:
+    """Dense lanes of a latent (MLA) cache: nothing per head."""
+
+    c: jax.Array  # [B, T, R] normed latents
+    r: jax.Array  # [B, T, Dr] the roped key all heads share
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class RingEntry:
+    """A sliding layer's O(window) ring: position p lives at slot p % R."""
+
+    k: jax.Array  # [B, R, Nkv, D]
+    v: jax.Array
+    window: int = dataclasses.field(metadata=dict(static=True))
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class PagedEntry:
+    """A layer's block pool, read and written through CacheCtx.table."""
+
+    k: jax.Array  # [NB, bs, Nkv, D]
+    v: jax.Array
+
+
+class CacheCtx(NamedTuple):
+    """What a cached forward needs beside the entries, the same for every
+    layer: where the chunk is written and, for a paged pool, through what."""
+
+    write_pos: Any  # slot where the chunk's first token goes: scalar, or [B] per row
+    real_end: Any = None  # scalar or [B]: first bucket-padding position
+    #   (ring and paged writes skip the padding; None = write_pos + S)
+    table: Optional[jax.Array] = None  # [B, MB] int32 block table (paged)
+    write_mask: Optional[jax.Array] = None  # [B] bool (paged): rows whose writes commit
 
 
 @jax.tree_util.register_dataclass
@@ -144,6 +209,35 @@ class KVCache:
                 f"KV cache overflow{who}: {used} used + {new_tokens} new > "
                 f"{self.max_len}"
             )
+
+    def entries(self, cfg: ModelConfig) -> tuple:
+        """The layers' entries, stacked: one stack per kind of
+        cfg.layer_pattern when the storage is ring-split, else one stack
+        holding every layer in layer order."""
+        if cfg.is_mla:
+            return (LatentEntry(c=self.k, r=self.v),)
+        glob = DenseEntry(k=self.k, v=self.v)
+        if self.k_loc is None:
+            return (glob,)
+        ring = RingEntry(k=self.k_loc, v=self.v_loc, window=int(cfg.sliding_window))
+        return tuple(ring if kind == "sliding" else glob for kind in cfg.layer_pattern)
+
+    def with_entries(self, entries: tuple) -> "KVCache":
+        """Inverse of `entries`: the same cache (and length) over new buffers."""
+        if isinstance(entries[0], LatentEntry):
+            return KVCache(k=entries[0].c, v=entries[0].r, length=self.length)
+        by_type = {type(e): e for e in entries}
+        glob, ring = by_type[DenseEntry], by_type.get(RingEntry)
+        return KVCache(
+            k=glob.k, v=glob.v, length=self.length,
+            k_loc=None if ring is None else ring.k,
+            v_loc=None if ring is None else ring.v,
+        )
+
+    @staticmethod
+    def ctx(write_pos, real_end=None, write_mask=None) -> CacheCtx:
+        """Dense lanes are lane-private: no table, and no write is masked."""
+        return CacheCtx(write_pos, real_end)
 
     def updated(self, k: jax.Array, v: jax.Array, new_tokens) -> "KVCache":
         """New cache with written buffers and advanced length (pure)."""
@@ -275,6 +369,17 @@ class PagedKVCache:
         return None
 
     v_loc = k_loc
+
+    def entries(self, cfg: ModelConfig) -> tuple:
+        """One stack of pools for every layer (paged storage is one layout
+        by construction)."""
+        return (PagedEntry(k=self.k, v=self.v),)
+
+    def with_entries(self, entries: tuple) -> "PagedKVCache":
+        return dataclasses.replace(self, k=entries[0].k, v=entries[0].v)
+
+    def ctx(self, write_pos, real_end=None, write_mask=None) -> CacheCtx:
+        return CacheCtx(write_pos, real_end, self.table, write_mask)
 
     @staticmethod
     def create(

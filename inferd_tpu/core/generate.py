@@ -69,7 +69,7 @@ class Engine:
             # prompt_len hold garbage but are never attended: cache.length is
             # reset to prompt_len and decode overwrites them sequentially
             # (rings drop padded rows at write time via real_end).
-            logits, nc = qwen3.forward_cached(
+            logits, nc, _ = qwen3.forward_cached(
                 params, cfg, tokens, None, cache, jnp.int32(0),
                 real_end=prompt_len,
             )
@@ -83,7 +83,7 @@ class Engine:
             # the first start_pos positions are already in the cache)
             b, s = tokens.shape
             pos = start_pos + jnp.broadcast_to(jnp.arange(s), (b, s))
-            logits, nc = qwen3.forward_cached(
+            logits, nc, _ = qwen3.forward_cached(
                 params, cfg, tokens, pos, cache, cache.length,
                 real_end=cache.length + real_len,
             )
@@ -94,7 +94,7 @@ class Engine:
         @partial(jax.jit, donate_argnames=("cache",))
         def _decode(params, tok, cache: KVCache, key):
             pos = jnp.broadcast_to(cache.length, (tok.shape[0], 1))
-            logits, nc = qwen3.forward_cached(
+            logits, nc, _ = qwen3.forward_cached(
                 params, cfg, tok, pos, cache, cache.length,
                 real_end=cache.length + 1,
             )
@@ -116,7 +116,7 @@ class Engine:
             # token's model log-probability + top-N alternatives, computed
             # on device (no [B, V] host transfer per step)
             pos = jnp.broadcast_to(cache.length, (tok.shape[0], 1))
-            logits, nc = qwen3.forward_cached(
+            logits, nc, _ = qwen3.forward_cached(
                 params, cfg, tok, pos, cache, cache.length,
                 real_end=cache.length + 1,
             )
@@ -146,7 +146,7 @@ class Engine:
                 tok, cache, key = carry
                 key, sub = jax.random.split(key)
                 pos = jnp.broadcast_to(cache.length, (tok.shape[0], 1))
-                logits, nc = qwen3.forward_cached(
+                logits, nc, _ = qwen3.forward_cached(
                     params, cfg, tok, pos, cache, cache.length,
                     real_end=cache.length + 1,
                 )

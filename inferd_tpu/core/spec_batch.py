@@ -142,7 +142,7 @@ def draft_step(dp, dcfg: ModelConfig, dcache: KVCache, toks, dlens, advance):
     write)."""
     from inferd_tpu.models import qwen3
 
-    lg, nc = qwen3.forward_cached(
+    lg, nc, _ = qwen3.forward_cached(
         dp, dcfg, toks[:, None], dlens[:, None], dcache, dlens,
         real_end=dlens + 1,
     )
@@ -304,7 +304,7 @@ class LaneSpecRunner:
             logits consumer — the first draft proposal starts from the
             target's first emitted token)."""
             lc = lane_slice(dcache, lane)
-            _, nc = qwen3.forward_cached(
+            _, nc, _ = qwen3.forward_cached(
                 dp, draft_cfg, tokens, None, lc, start, real_end=start + n
             )
             return lane_write(dcache, lane, nc)
@@ -317,7 +317,7 @@ class LaneSpecRunner:
             pos = tlens[:, None] + jnp.arange(K + 1)[None, :]
             return qwen3.forward_cached(
                 tp, cfg, chunk, pos, tcache, tlens, real_end=tlens + K + 1
-            )
+            )[:2]
 
         @partial(jax.jit, donate_argnames=("tcache", "dcache"),
                  static_argnames=("want_lp",))

@@ -130,8 +130,8 @@ class SpeculativeEngine:
                      want_lp: bool = False):
             """Prefill BOTH models on the prompt; returns the target's next
             token (greedy, or sampled when temperature > 0) + caches."""
-            tl, tc = qwen3.forward_cached(tp, tcfg, tokens, None, tc, jnp.int32(0), real_end=n)
-            _, dc = qwen3.forward_cached(dp, dcfg, tokens, None, dc, jnp.int32(0), real_end=n)
+            tl, tc, _ = qwen3.forward_cached(tp, tcfg, tokens, None, tc, jnp.int32(0), real_end=n)
+            _, dc, _ = qwen3.forward_cached(dp, dcfg, tokens, None, dc, jnp.int32(0), real_end=n)
             tc = dataclasses.replace(tc, length=n)
             dc = dataclasses.replace(dc, length=n)
             last = tl[jnp.arange(tokens.shape[0]), n - 1]
@@ -153,7 +153,7 @@ class SpeculativeEngine:
         def _draft_ingest(dp, tok, dc: KVCache):
             """Cache catch-up: feed one already-emitted token through the
             draft (used after a fully-accepted round)."""
-            _, nc = qwen3.forward_cached(dp, dcfg, tok[:, None], None, dc, dc.length)
+            _, nc, _ = qwen3.forward_cached(dp, dcfg, tok[:, None], None, dc, dc.length)
             return dataclasses.replace(nc, length=dc.length + 1)
 
         @partial(jax.jit, donate_argnames=("tc", "dc"),
@@ -169,7 +169,7 @@ class SpeculativeEngine:
             # -- draft: ingest x_n then K-1 self-fed greedy steps -----------
             def draft_body(carry, _):
                 tok, c = carry
-                lg, nc = qwen3.forward_cached(
+                lg, nc, _ = qwen3.forward_cached(
                     dp, dcfg, tok[:, None], None, c, c.length
                 )
                 c = dataclasses.replace(nc, length=c.length + 1)
@@ -182,7 +182,7 @@ class SpeculativeEngine:
 
             # -- target: verify the whole chunk in one forward --------------
             chunk = jnp.concatenate([last_tok[None], drafts], axis=0).T  # [B, K+1]
-            tl, tc2 = qwen3.forward_cached(tp, tcfg, chunk, None, tc, n)
+            tl, tc2, _ = qwen3.forward_cached(tp, tcfg, chunk, None, tc, n)
             greedy = jnp.argmax(tl, axis=-1).astype(jnp.int32)  # [B, K+1]
 
             # -- target logprobs for the whole chunk: the TARGET model's
@@ -228,7 +228,7 @@ class SpeculativeEngine:
 
             def draft_body(carry, key):
                 tok, c = carry
-                lg, nc = qwen3.forward_cached(
+                lg, nc, _ = qwen3.forward_cached(
                     dp, dcfg, tok[:, None], None, c, c.length
                 )
                 c = dataclasses.replace(nc, length=c.length + 1)
@@ -246,7 +246,7 @@ class SpeculativeEngine:
             )  # drafts [K, B]; dprobs [K, V]
 
             chunk = jnp.concatenate([last_tok[None], drafts], axis=0).T  # [B, K+1]
-            tl, tc2 = qwen3.forward_cached(tp, tcfg, chunk, None, tc, n)
+            tl, tc2, _ = qwen3.forward_cached(tp, tcfg, chunk, None, tc, n)
             tprobs = _warped_probs(tl[0])  # [K+1, V]
 
             d = drafts[:, 0]  # [K]

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from inferd_tpu.config import ModelConfig, yarn_mscale
+from inferd_tpu.core import cache as cachelib
 from inferd_tpu.ops import attention as attention_ops
 from inferd_tpu.ops import lora as lora_ops
 from inferd_tpu.ops.quant import qdot, qeinsum
@@ -606,30 +607,115 @@ def _ring_attend_update(
     return attn, upd(k_ring, slot, kc), upd(v_ring, slot, vc)
 
 
-def _cached_attend(cfg, q, new_k, new_v, q_positions, end, window, sinks, s):
-    """Attention over a just-updated cache buffer. A STATIC int window
-    narrows the KV read to a window-covering slice (_windowed_slice — the
-    sliding-layer fast path the pair scan in forward_layers enables); a
-    traced window (or None) attends the whole buffer, mask-only."""
+def _mask_only(window):
+    """A window that only masks: a STATIC int narrows the READ on dense
+    lanes alone (_attend_update_lanes); every other layout takes it traced."""
+    return None if window is None else jnp.asarray(window, jnp.int32)
+
+
+def _lanes_write(buf, chunk, write_pos):
+    """A chunk [B, S, ...] written into dense lanes [B, T, ...] at
+    `write_pos`: a scalar, or [B] per row (continuous batching: lanes at
+    ragged fill levels advance in one step; the vmapped row updates lower
+    to a scatter)."""
+    chunk = _to_cache_dtype(chunk, buf.dtype)
+    if jnp.ndim(write_pos) == 1:
+        upd = jax.vmap(
+            lambda row, ch, p: jax.lax.dynamic_update_slice(row, ch, (p,) + (0,) * (row.ndim - 1))
+        )
+        return upd(buf, chunk, write_pos)
+    return jax.lax.dynamic_update_slice(buf, chunk, (0, write_pos) + (0,) * (buf.ndim - 2))
+
+
+def _attend_chunk(cfg, q, k, v, q_positions, entry, ctx, window, sinks):
+    """No cache: the chunk attends to itself (prefill-style parity)."""
+    attn = _attend(
+        cfg, q, k, v, q_positions, jnp.int32(q.shape[1]),
+        kv_positions=q_positions, window=_mask_only(window), sinks=sinks,
+    )
+    return attn, None
+
+
+def _attend_update_lanes(cfg, q, k, v, q_positions, entry, ctx, window, sinks):
+    """Dense lanes: write at ctx.write_pos, attend over the buffer. A
+    STATIC int window narrows the KV read to a window-covering slice
+    (_windowed_slice, the sliding-layer read fast path); a traced window
+    (or None) attends the whole buffer, mask-only; attention masks per
+    row through the valid length where write_pos is per row."""
+    s = q.shape[1]
+    new = cachelib.DenseEntry(
+        k=_lanes_write(entry.k, k, ctx.write_pos), v=_lanes_write(entry.v, v, ctx.write_pos)
+    )
+    end = ctx.write_pos + s
     if isinstance(window, int) and window > 0:
-        k_att, v_att, kvpos, valid = _windowed_slice(new_k, new_v, end, window, s)
+        k_att, v_att, kvpos, valid = _windowed_slice(new.k, new.v, end, window, s)
         return _attend(
             cfg, q, k_att, v_att, q_positions, valid,
             kv_positions=kvpos, window=jnp.int32(window), sinks=sinks,
-        )
-    return _attend(
-        cfg, q, new_k, new_v, q_positions, end, window=window, sinks=sinks
+        ), new
+    return _attend(cfg, q, new.k, new.v, q_positions, end, window=window, sinks=sinks), new
+
+
+def _attend_update_ring(cfg, q, k, v, q_positions, entry, ctx, window, sinks):
+    """A sliding layer's O(window) ring (_ring_attend_update); the window
+    is the entry's own."""
+    real_end = ctx.write_pos + q.shape[1] if ctx.real_end is None else ctx.real_end
+    attn, nk, nv = _ring_attend_update(
+        cfg, q, k, v, q_positions, entry.k, entry.v, ctx.write_pos, real_end,
+        entry.window, sinks,
     )
+    return attn, cachelib.RingEntry(k=nk, v=nv, window=entry.window)
 
 
-def _gqa_attend_update(
-    lp, cfg, x, cos, sin, q_positions, k_buf, v_buf, cache_write_pos,
-    window, ring_window, real_end, block_table, write_mask, adapters,
-):
+def _attend_update_paged(cfg, q, k, v, q_positions, entry, ctx, window, sinks):
+    """Paged pool: scatter the chunk's K/V through the block table, then
+    attend over the table-gathered view. Write target for row b, chunk
+    offset i at absolute position p = wp[b] + i is pool slot
+    (table[b, p // bs], p % bs); rows past real_end (bucket padding) and
+    rows with write_mask False scatter to index NB, which mode="drop"
+    discards — on dense lanes garbage writes were lane-private and safe,
+    here a dropped write is the ONLY safe garbage (blocks are shared
+    property). Windows stay mask-only: paged storage is one layout for
+    every layer by construction (core.cache)."""
+    b, s = q.shape[0], q.shape[1]
+    nb_, bs_ = entry.k.shape[0], entry.k.shape[1]
+    wp = jnp.asarray(ctx.write_pos)
+    col = lambda a: a[:, None] if a.ndim == 1 else jnp.broadcast_to(a, (b, 1))
+    pos = col(wp) + jnp.arange(s)[None, :]  # [B, S]
+    real_end = ctx.write_pos + s if ctx.real_end is None else ctx.real_end
+    ok = pos < col(jnp.asarray(real_end))
+    if ctx.write_mask is not None:
+        ok &= ctx.write_mask[:, None]
+    chain = jnp.clip(pos // bs_, 0, ctx.table.shape[1] - 1)
+    blk = jnp.take_along_axis(ctx.table, chain, axis=1)  # [B, S]
+    blk = jnp.where(ok, blk, nb_)  # NB = out of range -> dropped
+    off = pos % bs_
+    new = cachelib.PagedEntry(
+        k=entry.k.at[blk, off].set(_to_cache_dtype(k, entry.k.dtype), mode="drop"),
+        v=entry.v.at[blk, off].set(_to_cache_dtype(v, entry.v.dtype), mode="drop"),
+    )
+    attn = gqa_attention(
+        q, new.k, new.v, q_positions, ctx.write_pos + s,
+        scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap,
+        window=_mask_only(window), sinks=sinks, block_table=ctx.table,
+    )
+    return attn, new
+
+
+# the write-then-read of each cache layout (core.cache owns the layouts):
+# (cfg, q, k, v, q_positions, entry, ctx, window, sinks) -> (attn, entry')
+_ATTEND_UPDATE = {
+    type(None): _attend_chunk,
+    cachelib.DenseEntry: _attend_update_lanes,
+    cachelib.RingEntry: _attend_update_ring,
+    cachelib.PagedEntry: _attend_update_paged,
+}
+
+
+def _gqa_attend_update(lp, cfg, x, cos, sin, q_positions, entry, ctx, window, adapters):
     """Per-head q/k/v from the normed input `x`, the chunk's keys and values
-    written to the cache in whichever layout `k_buf`/`v_buf` have (none,
-    paged, ring, dense lanes), and attention over it -> (attn [B, S, Nq*D],
-    k_buf', v_buf'). The argument contract is decoder_layer's."""
+    written to the layer's cache entry in whichever layout it has, and
+    attention over it -> (attn [B, S, Nq*D], entry')."""
     b, s, _h = x.shape
     d = cfg.head_dim
     q = lora_ops.apply_lane_delta(qdot(x, lp["q_proj"]), x, "q_proj", adapters)
@@ -647,85 +733,8 @@ def _gqa_attend_update(
         k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
-
     sinks = lp["sinks"] if cfg.attn_sinks else None
-    if k_buf is None:
-        attn = _attend(
-            cfg, q, k, v, q_positions, jnp.int32(s),
-            kv_positions=q_positions, window=window, sinks=sinks,
-        )
-        new_k = new_v = None
-    elif block_table is not None:
-        # PAGED path: scatter the chunk's K/V through the block table,
-        # then attend over the table-gathered view. Write target for row
-        # b, chunk offset i at absolute position p = wp[b] + i is pool
-        # slot (table[b, p // bs], p % bs); rows past real_end (bucket
-        # padding) and rows with write_mask False scatter to index NB,
-        # which mode="drop" discards — in the dense layout garbage writes
-        # were lane-private and safe, here a dropped write is the ONLY
-        # safe garbage (blocks are shared property).
-        nb_, bs_ = k_buf.shape[0], k_buf.shape[1]
-        wp = jnp.asarray(cache_write_pos)
-        wp_col = wp[:, None] if wp.ndim == 1 else jnp.broadcast_to(
-            wp, (b, 1)
-        )
-        pos = wp_col + jnp.arange(s)[None, :]  # [B, S]
-        ok = jnp.ones(pos.shape, bool)
-        if real_end is not None:
-            re = jnp.asarray(real_end)
-            re_col = re[:, None] if re.ndim == 1 else jnp.broadcast_to(
-                re, (b, 1)
-            )
-            ok &= pos < re_col
-        if write_mask is not None:
-            ok &= write_mask[:, None]
-        chain = jnp.clip(pos // bs_, 0, block_table.shape[1] - 1)
-        blk = jnp.take_along_axis(block_table, chain, axis=1)  # [B, S]
-        blk = jnp.where(ok, blk, nb_)  # NB = out of range -> dropped
-        off = pos % bs_
-        new_k = k_buf.at[blk, off].set(
-            _to_cache_dtype(k, k_buf.dtype), mode="drop"
-        )
-        new_v = v_buf.at[blk, off].set(
-            _to_cache_dtype(v, v_buf.dtype), mode="drop"
-        )
-        attn = gqa_attention(
-            q, new_k, new_v, q_positions,
-            cache_write_pos + s,
-            scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap,
-            window=window, sinks=sinks, block_table=block_table,
-        )
-    elif ring_window is not None:
-        attn, new_k, new_v = _ring_attend_update(
-            cfg, q, k, v, q_positions, k_buf, v_buf, cache_write_pos,
-            real_end, ring_window, sinks,
-        )
-    elif jnp.ndim(cache_write_pos) == 1:
-        # per-batch-row write position ([B] — continuous batching: lanes at
-        # ragged fill levels decode in one step); vmapped row updates lower
-        # to a scatter, and attention masks per-row via kv_len [B]
-        upd = jax.vmap(
-            lambda buf, chunk, p: jax.lax.dynamic_update_slice(buf, chunk, (p, 0, 0))
-        )
-        new_k = upd(k_buf, _to_cache_dtype(k, k_buf.dtype), cache_write_pos)
-        new_v = upd(v_buf, _to_cache_dtype(v, v_buf.dtype), cache_write_pos)
-        attn = _cached_attend(
-            cfg, q, new_k, new_v, q_positions, cache_write_pos + s,
-            window, sinks, s,
-        )
-    else:
-        new_k = jax.lax.dynamic_update_slice(
-            k_buf, _to_cache_dtype(k, k_buf.dtype), (0, cache_write_pos, 0, 0)
-        )
-        new_v = jax.lax.dynamic_update_slice(
-            v_buf, _to_cache_dtype(v, v_buf.dtype), (0, cache_write_pos, 0, 0)
-        )
-        attn = _cached_attend(
-            cfg, q, new_k, new_v, q_positions, cache_write_pos + s,
-            window, sinks, s,
-        )
-
-    return attn, new_k, new_v
+    return _ATTEND_UPDATE[type(entry)](cfg, q, k, v, q_positions, entry, ctx, window, sinks)
 
 
 def _causal_mask(t: int, kv_valid_len, kv_positions, q_positions) -> jax.Array:
@@ -789,11 +798,11 @@ def mla_attend(
     return out.reshape(b, s, n * dv)
 
 
-def _mla_attend_update(lp, cfg, x, cos, sin, q_positions, c_buf, r_buf, cache_write_pos):
+def _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, ctx):
     """Latent attention's side of decoder_layer: queries per head, ONE
-    latent and ONE roped key per token written to the cache (`c_buf`
-    [B, T, R], `r_buf` [B, T, Dr]; None = no cache, the chunk attends to
-    itself), attention over it -> (attn [B, S, N * Dv], c_buf', r_buf')."""
+    latent and ONE roped key per token written to the layer's entry
+    (core.cache.LatentEntry; None = no cache, the chunk attends to itself),
+    attention over it -> (attn [B, S, N * Dv], entry')."""
     b, s, _h = x.shape
     dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     q = qdot(x, lp["q_proj"]).reshape(b, s, -1, dn + dr)
@@ -801,86 +810,66 @@ def _mla_attend_update(lp, cfg, x, cos, sin, q_positions, c_buf, r_buf, cache_wr
     kv_a = x @ lp["kv_a_proj"]
     c = rms_norm(kv_a[..., :r], lp["kv_a_norm"], cfg.rms_norm_eps)
     k_pe = apply_rope(kv_a[..., None, r:], cos, sin)[:, :, 0]
-    if c_buf is None:
+    if entry is None:
         with jax.named_scope("mla_attend"):
             attn = mla_attend(
                 cfg, q_nope, q_pe, c, k_pe, lp["kv_b_proj"], q_positions,
                 jnp.int32(s), kv_positions=q_positions,
             )
-        return attn, None, None
-    c, k_pe = _to_cache_dtype(c, c_buf.dtype), _to_cache_dtype(k_pe, r_buf.dtype)
-    if jnp.ndim(cache_write_pos) == 1:  # per-lane write positions (decode)
-        upd = jax.vmap(lambda buf, chunk, p: jax.lax.dynamic_update_slice(buf, chunk, (p, 0)))
-        new_c, new_r = upd(c_buf, c, cache_write_pos), upd(r_buf, k_pe, cache_write_pos)
-    else:
-        new_c = jax.lax.dynamic_update_slice(c_buf, c, (0, cache_write_pos, 0))
-        new_r = jax.lax.dynamic_update_slice(r_buf, k_pe, (0, cache_write_pos, 0))
+        return attn, None
+    new = cachelib.LatentEntry(
+        c=_lanes_write(entry.c, c, ctx.write_pos), r=_lanes_write(entry.r, k_pe, ctx.write_pos)
+    )
     with jax.named_scope("mla_attend"):
         attn = mla_attend(
-            cfg, q_nope, q_pe, new_c, new_r, lp["kv_b_proj"], q_positions,
-            cache_write_pos + s, absorbed=s == 1,
+            cfg, q_nope, q_pe, new.c, new.r, lp["kv_b_proj"], q_positions,
+            ctx.write_pos + s, absorbed=s == 1,
         )
-    return attn, new_c, new_r
+    return attn, new
 
 
-def decoder_layer(*args, **kwargs):
-    """`decoder_layer_routed` without the chosen experts."""
-    return decoder_layer_routed(*args, **kwargs)[:3]
-
-
-def decoder_layer_routed(
+def decoder_layer(
     lp: Params,
     cfg: ModelConfig,
     hidden: jax.Array,  # [B, S, H]
     cos: jax.Array,
     sin: jax.Array,
     q_positions: jax.Array,  # [B, S]
-    k_buf: Optional[jax.Array],  # [B, T, nkv(_local), D] or None (no cache: T == S)
-    v_buf: Optional[jax.Array],
-    cache_write_pos: Optional[jax.Array],  # slot where new k/v go: scalar, or [B] per row
+    entry=None,  # this layer's cache entry (core.cache: dense lanes, latent,
+    #   ring or paged pool, the kv axis a tp rank's local heads); None = no
+    #   cache, the chunk attends to itself
+    ctx=None,  # core.cache.CacheCtx: where the chunk is written, the same
+    #   for every layer
+    window=None,  # this layer's sliding window: None (global), a STATIC
+    #   int (a sliding layer whose place in the stack is known) or a traced
+    #   scalar (mask-only; <= 0 = global)
     tp_axis: Optional[str] = None,
     ep_axis: Optional[str] = None,
-    window=None,  # sliding window: traced scalar (mask-only), or a STATIC
-    #   python int > 0 — then the cached KV READ narrows to a
-    #   window-covering slice (_windowed_slice); None/<=0 = global
-    ring_window: Optional[int] = None,  # STATIC window with k_buf/v_buf an
-    #   O(window) RING [B, R, Nkv, D] (_ring_attend_update) — the sliding-
-    #   layer storage fast path; requires real_end
-    real_end=None,  # scalar or [B]: first bucket-padding position
-    #   (ring + paged layouts)
-    block_table: Optional[jax.Array] = None,  # [B, MB] int32 — PAGED mode:
-    #   k_buf/v_buf are block POOLS [NB, bs, Nkv, D]; writes scatter
-    #   through the table, reads gather through it (core.cache.PagedKVCache)
-    write_mask: Optional[jax.Array] = None,  # [B] bool (paged only): rows
-    #   whose KV writes commit; False rows compute but write NOTHING — a
-    #   non-participating co-batch lane must never scribble on a block
-    #   another lane or a shared prefix may own
     adapters=None,  # this layer's per-lane LoRA slice (multi-tenant
     #   registry): {"layers": {target: (a [B, in, r], b [B, r, out])},
     #   "scale": [B] f32} — slot-0 (base) lanes carry zero A/B and apply
     #   nothing (ops.lora.apply_lane_delta)
-) -> Tuple[jax.Array, Optional[jax.Array], Optional[jax.Array], Optional[jax.Array]]:
+):
     """One pre-norm residual decoder block with GQA + per-head q/k RMSNorm
     (the Qwen3 signature feature — reference qwen3_server_module.py:123-124),
-    or with latent attention (cfg.is_mla: k_buf/v_buf are then the latent
-    and rope-key buffers [B, T, R] / [B, T, Dr]).
+    or with latent attention (cfg.is_mla).
 
-    Returns (hidden', k_buf', v_buf', chosen experts [B, S, K] or None for
-    a dense MLP). When k_buf is None the layer runs cache-free over the
-    full sequence (prefill-style parity testing).
+    Returns (hidden', entry', chosen experts [B, S, K] or None for a dense
+    MLP). With no entry the layer runs cache-free over the full sequence
+    (prefill-style parity testing).
 
     Shard-polymorphic: head counts come from the projection widths, not the
     config, so the same code runs full-width (single device / pp stage) or
     on a tensor-parallel head shard inside shard_map — pass `tp_axis` there
     and the block psums its two row-parallel outputs (attention o_proj and
     the MLP down-proj, the Megatron minimum; tp.sharded_decoder_layer is
-    the cache-free training sibling). The KV buffer then holds this rank's
+    the cache-free training sibling). The entry then holds this rank's
     local heads only. `ep_axis` (MoE only) additionally shards the expert
     axis: attention replicates across ep ranks (its weights and KV carry no
     ep spec, mesh.layer_param_specs) while each rank computes its local
     experts' contribution and the combine psums over (ep, tp).
 
-    Caller contract: cache_write_pos + S must be <= the buffer length T.
+    Caller contract: ctx.write_pos + S must be <= a dense entry's length T.
     dynamic_update_slice clamps out-of-range starts (it would silently
     overwrite the newest slots), so overflow must be prevented host-side —
     the runtime's session registry enforces this before dispatch
@@ -890,19 +879,16 @@ def decoder_layer_routed(
 
     x = rms_norm(hidden, lp["input_norm"], cfg.rms_norm_eps, p1)
     if cfg.is_mla:
-        if (tp_axis or ep_axis or block_table is not None or ring_window is not None
-                or window is not None or adapters is not None):
+        if (tp_axis or ep_axis or window is not None or adapters is not None
+                or not isinstance(entry, (type(None), cachelib.LatentEntry))):
             raise ValueError(
                 f"{cfg.name}: latent attention runs on the dense lane layout only "
                 "(no tensor/expert parallel shard, paged pool, ring, window or adapter)"
             )
-        attn, new_k, new_v = _mla_attend_update(
-            lp, cfg, x, cos, sin, q_positions, k_buf, v_buf, cache_write_pos
-        )
+        attn, entry = _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, ctx)
     else:
-        attn, new_k, new_v = _gqa_attend_update(
-            lp, cfg, x, cos, sin, q_positions, k_buf, v_buf, cache_write_pos,
-            window, ring_window, real_end, block_table, write_mask, adapters,
+        attn, entry = _gqa_attend_update(
+            lp, cfg, x, cos, sin, q_positions, entry, ctx, window, adapters
         )
 
     attn_out = lora_ops.apply_lane_delta(
@@ -945,7 +931,7 @@ def decoder_layer_routed(
             mlp_out = jax.lax.psum(mlp_out, tp_axis)
     if cfg.sandwich_norm:
         mlp_out = rms_norm(mlp_out, lp["post_ffn_norm"], cfg.rms_norm_eps, p1)
-    return hidden + mlp_out.astype(hidden.dtype), new_k, new_v, topi
+    return hidden + mlp_out.astype(hidden.dtype), entry, topi
 
 
 # ---------------------------------------------------------------------------
@@ -959,15 +945,19 @@ def slice_layers(layers: Params, start: int, end: int) -> Params:
 
 
 def layer_windows(cfg: ModelConfig, n_layers: int, layer_offset) -> Optional[jax.Array]:
-    """Per-layer sliding windows [n_layers] int32, or None when the config
-    has no sliding window. GLOBAL layer index (layer_offset + i) selects the
-    pattern — Gemma-2 alternates local (even) / global (odd) — so a pipeline
-    stage's slice applies the same windows the full model would.
-    layer_offset may be a traced scalar (pp rank inside shard_map)."""
+    """Per-layer sliding windows [n_layers] int32 (0 = global), or None when
+    the config has no sliding window. GLOBAL layer index (layer_offset + i)
+    selects the kind from cfg.layer_pattern, so a pipeline stage's slice
+    applies the same windows the full model would. layer_offset may be a
+    traced scalar (pp rank inside shard_map): the windows then only mask."""
     if not cfg.sliding_window:
         return None
+    kinds = cfg.layer_pattern
+    per_kind = jnp.asarray(
+        [cfg.sliding_window if kind == "sliding" else 0 for kind in kinds], jnp.int32
+    )
     idx = jnp.asarray(layer_offset, jnp.int32) + jnp.arange(n_layers, dtype=jnp.int32)
-    return jnp.where(idx % 2 == 0, jnp.int32(cfg.sliding_window), jnp.int32(0))
+    return per_kind[idx % len(kinds)]
 
 
 def _stack_len(layers: Params) -> int:
@@ -987,58 +977,51 @@ def forward_layers(
     cfg: ModelConfig,
     hidden: jax.Array,  # [B, S, H]
     positions: jax.Array,  # [B, S]
-    k_cache: Optional[jax.Array] = None,  # [L, B, T, Nkv(_local), D]
-    v_cache: Optional[jax.Array] = None,
-    cache_write_pos: Optional[jax.Array] = None,
+    entries: tuple = (),  # the stack's cache entries stacked over layers
+    #   (core.cache `entries()`): one stack per kind of cfg.layer_pattern, or
+    #   one stack for every layer in layer order; () = no cache
+    ctx=None,  # core.cache.CacheCtx, layer-invariant
     tp_axis: Optional[str] = None,
     ep_axis: Optional[str] = None,
-    layer_offset=0,  # global index of layers[0] (sliding-window pattern)
-    block_table: Optional[jax.Array] = None,  # paged KV: k_cache/v_cache
-    #   are per-layer block POOLS [L, NB, bs, Nkv, D] (core.cache)
-    write_mask: Optional[jax.Array] = None,  # [B] bool, paged only
-    real_end=None,  # scalar or [B], paged only: first padding position
+    layer_offset=0,  # global index of layers[0] (the layer pattern)
     adapters=None,  # multi-tenant LoRA pools + per-lane ids (the ops.lora
     #   pool pytree: {"a", "b", "scale", "ids"}); gathered ONCE here, the
-    #   per-layer slices ride the scan like the KV buffers
-    routing: bool = False,  # also return the experts each row chose in
-    #   each layer, [L, B, S, K] int32 (None for a stack of dense MLPs);
-    #   the uniform cached scan only
+    #   per-layer slices ride the scan like the cache entries
 ):
-    """Run a stack of decoder layers via lax.scan.
+    """Run a stack of decoder layers via ONE lax.scan over periods of
+    cfg.layer_pattern -> (hidden, entries', chosen experts [L, B, S, K] or
+    None for a stack without routers).
 
-    The scan carries the hidden states and threads each layer's KV buffer
-    through as scanned inputs/outputs — one compiled layer body regardless
-    of stage depth. `tp_axis`/`ep_axis` (inside shard_map only) run each
-    block on its tensor-/expert-parallel shard — see decoder_layer.
-    Per-layer sliding windows (Gemma-2, GPT-OSS) ride the scan as a scanned
-    input; stage slices pass `layer_offset` so the alternating pattern
-    stays aligned to GLOBAL layer indices.
+    The scan carries the hidden states and threads each layer's cache
+    entry through as scanned inputs/outputs — one compiled body per period
+    regardless of stage depth. `tp_axis`/`ep_axis` (inside shard_map only)
+    run each block on its tensor-/expert-parallel shard — see decoder_layer.
 
-    Sliding-window FAST PATH: when the window pattern is statically known
-    (static even layer_offset, even stack length, no tp/ep) the cached
-    forward runs a PAIR scan — one compiled body per (sliding, global)
-    layer pair — which makes each sliding layer's window a static int, so
-    its attention reads only a window-covering KV slice from HBM
-    (_windowed_slice) instead of the whole buffer. At long context this
-    nearly halves the per-token KV read for window models. Falls back to
-    the uniform scan (mask-only windows) whenever the pattern can't be
-    proven static.
+    With a Python-int `layer_offset` every layer's kind is static: a
+    sliding layer's window is a static int, so on dense lanes its attention
+    reads only a window-covering KV slice from HBM (_windowed_slice), and a
+    ring entry is met by the layer it belongs to. A stack that does not
+    start or end on a period boundary (odd offset, odd length) unrolls the
+    layers before the first and after the last whole period through the
+    same body. With a traced offset (a pp rank inside shard_map) the kinds
+    are unknown until run time: every layer is its own period and the
+    windows ride the scan as a traced, mask-only input.
     """
     cos, sin = rope_cos_sin(positions, cfg.rope_dim, cfg.rope_theta, cfg)
-    n_layers = _stack_len(layers)
+    n = _stack_len(layers)
 
     # multi-tenant LoRA: one per-lane gather of the stacked pools, then
-    # the layer-leading slices ride every scan below as ordinary xs (None
-    # = no adapters = every branch traces exactly as before). When the
+    # the layer-leading slices ride the scan as ordinary xs (None = no
+    # adapters = the scan traces exactly as without them). When the
     # fused kernel is measured faster (ops.lora.fused_delta_enabled), the
-    # gather never happens: the stacked pools close over the scan bodies
+    # gather never happens: the stacked pools close over the scan body
     # (layer-invariant, like the paged block table), only the int32 layer
     # index rides the xs, and fused_lane_delta picks each lane's slot
     # in-kernel at every projection.
     ad_per = ad_scale = None
     fused_ad = adapters is not None and lora_ops.fused_delta_enabled()
     if fused_ad:
-        ad_per = jnp.arange(n_layers, dtype=jnp.int32)
+        ad_per = jnp.arange(n, dtype=jnp.int32)
     elif adapters is not None:
         ad_per, ad_scale = lora_ops.gather_lanes(adapters)
 
@@ -1049,201 +1032,96 @@ def forward_layers(
             return {"pools": adapters, "layer": ad_sl}
         return {"layers": ad_sl, "scale": ad_scale}
 
-    if block_table is not None:
-        # PAGED scan: per-layer block pools ride the scan as xs; the table
-        # is layer-invariant (one chain per lane covers every layer) and
-        # closes over the body. Sliding windows stay mask-only here —
-        # paged storage is uniform-layout by construction (core.cache).
-        pwins = layer_windows(cfg, n_layers, layer_offset)
+    static = isinstance(layer_offset, int)
+    kinds = cfg.layer_pattern if static else (None,)
+    period = len(kinds)
+    head = min(-layer_offset % period, n) if period > 1 else 0
+    nper, tail = divmod(n - head, period)
+    per_layer = (layers, None if static else layer_windows(cfg, n, layer_offset), ad_per)
+    by_kind = len(entries) == period > 1  # a stack per kind; else ONE, in layer order
 
-        def pbody(h, xs):
-            lp, kb, vb, w, ad_sl = xs
-            h, nk, nv = decoder_layer(
-                lp, cfg, h, cos, sin, positions, kb, vb, cache_write_pos,
-                window=w, real_end=real_end, block_table=block_table,
-                write_mask=write_mask, adapters=_ad(ad_sl),
-            )
-            return h, (nk, nv)
+    def home(i):  # (stack, index in it) of layer i's entry
+        return ((layer_offset + i) % period, i // period) if by_kind else (0, i)
 
-        hidden, (new_k, new_v) = jax.lax.scan(
-            pbody, hidden, (layers, k_cache, v_cache, pwins, ad_per)
+    def layer(h, i, per_i, entry):
+        lp, win, ad_sl = per_i
+        if static:
+            sliding = kinds[(layer_offset + i) % period] == "sliding"
+            win = int(cfg.sliding_window) if sliding else None
+        return decoder_layer(
+            lp, cfg, h, cos, sin, positions, entry, ctx, win, tp_axis, ep_axis, _ad(ad_sl)
         )
-        return hidden, new_k, new_v
 
-    use_pairs = (
-        cfg.sliding_window > 0
-        and k_cache is not None
-        and isinstance(layer_offset, int)
-        and layer_offset % 2 == 0
-        and n_layers % 2 == 0
-        and tp_axis is None
-        and ep_axis is None
-        # adapter windows take the uniform scan (mask-only windows): the
-        # pair body would need its own slice plumbing for a layout the
-        # registry doesn't serve (ring-split stages reject adapters)
-        and adapters is None
-    )
-    if use_pairs:
-        n2 = n_layers // 2
-
-        def pair(tree):
-            return jax.tree.map(lambda a: a.reshape(n2, 2, *a.shape[1:]), tree)
-
-        def pbody(h, xs):
-            lp2, kb2, vb2 = xs
-            lp_e = jax.tree.map(lambda a: a[0], lp2)
-            lp_o = jax.tree.map(lambda a: a[1], lp2)
-            h, nk_e, nv_e = decoder_layer(
-                lp_e, cfg, h, cos, sin, positions, kb2[0], vb2[0],
-                cache_write_pos, window=int(cfg.sliding_window),
-            )
-            h, nk_o, nv_o = decoder_layer(
-                lp_o, cfg, h, cos, sin, positions, kb2[1], vb2[1],
-                cache_write_pos, window=None,
-            )
-            return h, (jnp.stack([nk_e, nk_o]), jnp.stack([nv_e, nv_o]))
-
-        hidden, (nk, nv) = jax.lax.scan(
-            pbody, hidden, (pair(layers), pair(k_cache), pair(v_cache))
+    # a period of one layer is the layer: no reshape enters its program
+    def fold(tree, lo, count):  # leaves [n, ...] -> `count` periods [count, period, ...]
+        if period == 1:
+            return tree
+        return jax.tree.map(
+            lambda a: a[lo : lo + count * period].reshape(count, period, *a.shape[1:]), tree
         )
-        new_k = nk.reshape(n_layers, *nk.shape[2:])
-        new_v = nv.reshape(n_layers, *nv.shape[2:])
-        return hidden, new_k, new_v
 
-    wins = layer_windows(cfg, n_layers, layer_offset)
+    def pick(tree, j):  # one period's leaves [period, ...] -> layer j's
+        return tree if period == 1 else jax.tree.map(lambda a: a[j], tree)
 
-    if k_cache is None:
+    def pack(vals):  # the period's layers' values -> leaves [period, ...]
+        return vals[0] if period == 1 else jax.tree.map(lambda *a: jnp.stack(a), *vals)
+
+    def unfold(tree):  # scan outputs [nper, period, ...] -> [nper * period, ...]
+        if period == 1:
+            return tree
+        return jax.tree.map(lambda a: a.reshape(nper * period, *a.shape[2:]), tree)
+
+    pieces = [[] for _ in entries]  # each stack's new entries, in layer order
+    chosen = []
+
+    def single(h, i):  # a layer outside the whole periods, through the same body
+        s, at = home(i)
+        entry = jax.tree.map(lambda a: a[at], entries[s]) if entries else None
+        h, entry, topi = layer(h, i, jax.tree.map(lambda a: a[i], per_layer), entry)
+        if entries:
+            pieces[s].append(jax.tree.map(lambda a: a[None], entry))
+        chosen.append(None if topi is None else topi[None])
+        return h
+
+    for i in range(head):
+        hidden = single(hidden, i)
+    if nper:
+        if by_kind:
+            firsts = [home(head + j)[1] for j in range(period)]
+            ent_xs = tuple(
+                jax.tree.map(lambda a: a[lo : lo + nper], e) for lo, e in zip(firsts, entries)
+            )
+        else:
+            ent_xs = tuple(fold(e, head, nper) for e in entries)
 
         def body(h, xs):
-            lp, w, ad_sl = xs
-            h, _, _ = decoder_layer(
-                lp, cfg, h, cos, sin, positions, None, None, None,
-                tp_axis, ep_axis, window=w, adapters=_ad(ad_sl),
-            )
-            return h, None
+            per_p, ent_p = xs
+            ents, tops = [], []
+            for j in range(period):
+                entry = None if not entries else ent_p[j] if by_kind else pick(ent_p[0], j)
+                h, entry, topi = layer(h, head + j, pick(per_p, j), entry)
+                ents.append(entry)
+                tops.append(topi)
+            ents = () if not entries else tuple(ents) if by_kind else (pack(ents),)
+            return h, (ents, pack(tops))
 
-        hidden, _ = jax.lax.scan(body, hidden, (layers, wins, ad_per))
-        return hidden, None, None
-
-    def body(h, xs):
-        lp, kb, vb, w, ad_sl = xs
-        h, nk, nv, topi = decoder_layer_routed(
-            lp, cfg, h, cos, sin, positions, kb, vb, cache_write_pos,
-            tp_axis, ep_axis, window=w, adapters=_ad(ad_sl),
+        hidden, (ents, tops) = jax.lax.scan(
+            body, hidden, (fold(per_layer, head, nper), ent_xs)
         )
-        return h, (nk, nv, topi)
+        for s, e in enumerate(ents):
+            pieces[s].append(e if by_kind else unfold(e))
+        chosen.append(unfold(tops))
+    for i in range(n - tail, n):
+        hidden = single(hidden, i)
 
-    hidden, (new_k, new_v, topi) = jax.lax.scan(
-        body, hidden, (layers, k_cache, v_cache, wins, ad_per)
-    )
-    if routing:
-        return hidden, new_k, new_v, topi
-    return hidden, new_k, new_v
+    def cat(parts):
+        if len(parts) == 1:
+            return parts[0]
+        return jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *parts)
 
-
-def forward_layers_split(
-    layers: Params,
-    cfg: ModelConfig,
-    hidden: jax.Array,  # [B, S, H]
-    positions: jax.Array,  # [B, S]
-    k_glob: jax.Array,  # [Lg, B, T, Nkv, D] global layers, storage order
-    v_glob: jax.Array,
-    k_loc: jax.Array,  # [Ll, B, R, Nkv, D] sliding-layer rings, storage order
-    v_loc: jax.Array,
-    cache_write_pos,  # scalar or [B]
-    real_end,  # scalar or [B]: first bucket-padding position
-    layer_offset: int = 0,  # STATIC global index of layers[0]
-    tp_axis: Optional[str] = None,
-    ep_axis: Optional[str] = None,
-):
-    """Cached forward over a sliding-window model with SPLIT KV storage:
-    sliding (even-global-index) layers read/write O(window) ring buffers
-    (_ring_attend_update), global layers full-length buffers. The statically
-    known alternation compiles as head (<=1 unpaired global layer when
-    layer_offset is odd) + a scan over (sliding, global) pairs + tail (<=1
-    unpaired sliding layer) — so ANY static layer_offset and stack length
-    gets ring storage, not just even-aligned even-length stages.
-
-    `tp_axis`/`ep_axis` (inside shard_map only) run each block on its
-    tensor-/expert-parallel shard exactly as in forward_layers — the ring
-    buffers then hold this rank's local kv heads (the in-mesh pipelined
-    serving path, runtime/mesh_executor.py).
-
-    Returns (hidden, nk_glob, nv_glob, nk_loc, nv_loc).
-    """
-    assert cfg.sliding_window > 0 and isinstance(layer_offset, int)
-    cos, sin = rope_cos_sin(positions, cfg.rope_dim, cfg.rope_theta, cfg)
-    n = _stack_len(layers)
-    win = int(cfg.sliding_window)
-
-    def lp_at(i):
-        return jax.tree.map(lambda a: a[i], layers)
-
-    h = hidden
-    head_g = None
-    i0 = g0 = 0
-    if layer_offset % 2 == 1:  # stack starts on a GLOBAL layer
-        h, nk, nv = decoder_layer(
-            lp_at(0), cfg, h, cos, sin, positions, k_glob[0], v_glob[0],
-            cache_write_pos, tp_axis, ep_axis, window=None,
-        )
-        head_g = (nk, nv)
-        i0 = g0 = 1
-    npairs = (n - i0) // 2
-    pair_out = None
-    if npairs:
-        lp2 = jax.tree.map(
-            lambda a: a[i0 : i0 + 2 * npairs].reshape(npairs, 2, *a.shape[1:]),
-            layers,
-        )
-
-        def pbody(hh, xs):
-            lp_pair, kl_i, vl_i, kg_i, vg_i = xs
-            lp_s = jax.tree.map(lambda a: a[0], lp_pair)
-            lp_g = jax.tree.map(lambda a: a[1], lp_pair)
-            hh, nkl, nvl = decoder_layer(
-                lp_s, cfg, hh, cos, sin, positions, kl_i, vl_i,
-                cache_write_pos, tp_axis, ep_axis,
-                ring_window=win, real_end=real_end,
-            )
-            hh, nkg, nvg = decoder_layer(
-                lp_g, cfg, hh, cos, sin, positions, kg_i, vg_i,
-                cache_write_pos, tp_axis, ep_axis, window=None,
-            )
-            return hh, (nkl, nvl, nkg, nvg)
-
-        h, pair_out = jax.lax.scan(
-            pbody, h,
-            (lp2, k_loc[:npairs], v_loc[:npairs],
-             k_glob[g0 : g0 + npairs], v_glob[g0 : g0 + npairs]),
-        )
-    tail_l = None
-    if (n - i0) % 2:  # leftover single layer is sliding by construction
-        h, nk, nv = decoder_layer(
-            lp_at(n - 1), cfg, h, cos, sin, positions, k_loc[-1], v_loc[-1],
-            cache_write_pos, tp_axis, ep_axis,
-            ring_window=win, real_end=real_end,
-        )
-        tail_l = (nk, nv)
-
-    gks, gvs, lks, lvs = [], [], [], []
-    if head_g is not None:
-        gks.append(head_g[0][None])
-        gvs.append(head_g[1][None])
-    if pair_out is not None:
-        nkl, nvl, nkg, nvg = pair_out
-        lks.append(nkl)
-        lvs.append(nvl)
-        gks.append(nkg)
-        gvs.append(nvg)
-    if tail_l is not None:
-        lks.append(tail_l[0][None])
-        lvs.append(tail_l[1][None])
-    nk_glob = jnp.concatenate(gks, axis=0) if gks else k_glob
-    nv_glob = jnp.concatenate(gvs, axis=0) if gvs else v_glob
-    nk_loc = jnp.concatenate(lks, axis=0) if lks else k_loc
-    nv_loc = jnp.concatenate(lvs, axis=0) if lvs else v_loc
-    return h, nk_glob, nv_glob, nk_loc, nv_loc
+    new = tuple(cat(p) if p else e for p, e in zip(pieces, entries))
+    chosen = [t for t in chosen if t is not None]
+    return hidden, new, cat(chosen) if chosen else None
 
 
 def forward_layers_cached(
@@ -1251,62 +1129,30 @@ def forward_layers_cached(
     cfg: ModelConfig,
     hidden: jax.Array,
     positions: jax.Array,
-    cache,  # core.cache.KVCache (ring-split or uniform) or PagedKVCache
-    cache_write_pos,
-    real_end=None,
-    layer_offset: int = 0,
-    write_mask=None,  # [B] bool, paged caches only (see decoder_layer)
+    cache,  # core.cache.KVCache (ring-split, uniform or latent) or PagedKVCache
+    cache_write_pos,  # slot where the chunk's keys and values go: scalar, or [B] per row
+    real_end=None,  # scalar or [B]: first bucket-padding position (ring and
+    #   paged layouts; default cache_write_pos + S)
+    layer_offset=0,
+    write_mask=None,  # [B] bool, paged caches only: rows whose KV writes
+    #   commit; False rows compute but write NOTHING — a non-participating
+    #   co-batch lane must never scribble on a block another lane or a
+    #   shared prefix may own
     adapters=None,  # multi-tenant LoRA pool pytree + per-lane ids
-    routing: bool = False,  # uniform layout only: also the chosen experts
+    tp_axis: Optional[str] = None,
+    ep_axis: Optional[str] = None,
 ):
-    """Cached stage/model forward over a KVCache, dispatching on its
-    storage layout: paged block pools (core.cache.PagedKVCache — writes
-    scatter and reads gather through the lanes' block table), ring-split
-    (k_loc present — sliding layers O(window)), or uniform full-length
-    buffers (classic path incl. the windowed-read pair scan; a latent
-    cache is always uniform). Returns (hidden, new cache with the INPUT
-    length — the caller advances it); with `routing` also the experts each
-    row chose, [L, B, S, K] (None for dense MLPs).
-    """
-    from inferd_tpu.core.cache import KVCache, PagedKVCache
-
-    if routing and (isinstance(cache, PagedKVCache) or cache.k_loc is not None
-                    or cfg.sliding_window > 0):
-        raise ValueError("the chosen experts come out of the uniform cached scan only")
-    if isinstance(cache, PagedKVCache):
-        if real_end is None:
-            real_end = cache_write_pos + hidden.shape[1]
-        h, nk, nv = forward_layers(
-            layers, cfg, hidden, positions, cache.k, cache.v,
-            cache_write_pos, layer_offset=layer_offset,
-            block_table=cache.table, write_mask=write_mask,
-            real_end=real_end, adapters=adapters,
-        )
-        return h, PagedKVCache(
-            k=nk, v=nv, table=cache.table, length=cache.length
-        )
-    if cache.k_loc is not None:
-        if adapters is not None:
-            # loud, not silent: serving a tenant the BASE model because
-            # the storage layout skipped the delta would be a correctness
-            # bug wearing a perf hat
-            raise ValueError(
-                "the adapter registry does not support ring-split KV "
-                "storage (sliding-window models) yet — serve --adapters "
-                "on a uniform or paged layout"
-            )
-        if real_end is None:
-            real_end = cache_write_pos + hidden.shape[1]
-        h, nk, nv, nkl, nvl = forward_layers_split(
-            layers, cfg, hidden, positions, cache.k, cache.v,
-            cache.k_loc, cache.v_loc, cache_write_pos, real_end, layer_offset,
-        )
-        return h, KVCache(k=nk, v=nv, length=cache.length, k_loc=nkl, v_loc=nvl)
-    h, nk, nv, *topi = forward_layers(
-        layers, cfg, hidden, positions, cache.k, cache.v, cache_write_pos,
-        layer_offset=layer_offset, adapters=adapters, routing=routing,
+    """THE cached stage/model forward: every layout of core.cache (dense
+    lanes, latent, ring-split, paged pool) goes through here and through
+    the one scan of forward_layers. Returns (hidden, new cache with the
+    INPUT length — the caller advances it —, chosen experts [L, B, S, K] or
+    None for a stack without routers)."""
+    hidden, entries, topi = forward_layers(
+        layers, cfg, hidden, positions, cache.entries(cfg),
+        cache.ctx(cache_write_pos, real_end, write_mask),
+        tp_axis, ep_axis, layer_offset, adapters,
     )
-    return (h, KVCache(k=nk, v=nv, length=cache.length), *topi)
+    return hidden, cache.with_entries(entries), topi
 
 
 def forward_cached(
@@ -1319,16 +1165,12 @@ def forward_cached(
     real_end=None,
     write_mask=None,  # [B] bool, paged caches only
     adapters=None,  # multi-tenant LoRA pool pytree + per-lane ids
-    routing: bool = False,  # uniform dense-lane layout only
 ):
     """Whole-model cached forward -> (logits [B, S, V], new cache with
-    the INPUT length — the caller advances it). Ring-aware: sliding-window
-    models with split caches store O(window) per sliding layer; paged
-    caches write/read through their block table. A model with leading
-    dense layers runs its groups one after the other, each over its own
-    layers of the cache. With `routing` a third value: the experts each
-    row chose in each sparse layer, [Ls, B, S, K] int32, or None for a
-    model without experts."""
+    the INPUT length — the caller advances it —, the experts each row chose
+    in each sparse layer [Ls, B, S, K] int32, or None for a model without
+    routers). A model with leading dense layers runs its groups one after
+    the other, each over its own layers of the cache."""
     if positions is None:
         start = cache_write_pos
         if jnp.ndim(start) == 1:
@@ -1336,28 +1178,23 @@ def forward_cached(
         positions = start + jnp.broadcast_to(
             jnp.arange(tokens.shape[1]), tokens.shape
         )
-    from inferd_tpu.core import cache as cachelib
-
     hidden = embed(params, tokens, cfg)
-    want = routing and cfg.is_moe
     topi, offset = None, 0
     groups = layer_groups(params)
     new_cache = cache
     for layers in groups:
         n = _stack_len(layers)
         sub = cache if len(groups) == 1 else cachelib.layer_slice(cache, offset, offset + n)
-        out = forward_layers_cached(
+        hidden, part, chosen = forward_layers_cached(
             layers, cfg, hidden, positions, sub, cache_write_pos,
             real_end, layer_offset=offset, write_mask=write_mask,
-            adapters=adapters, routing=want,
+            adapters=adapters,
         )
-        hidden = out[0]
-        new_cache = out[1] if len(groups) == 1 else cachelib.layer_write(new_cache, offset, out[1])
-        if want and out[2] is not None:
-            topi = out[2]  # the one group with routers
+        new_cache = part if len(groups) == 1 else cachelib.layer_write(new_cache, offset, part)
+        if chosen is not None:
+            topi = chosen  # the one group with routers
         offset += n
-    logits = unembed(params, cfg, hidden)
-    return (logits, new_cache, topi) if routing else (logits, new_cache)
+    return unembed(params, cfg, hidden), new_cache, topi
 
 
 def decode_k(
@@ -1424,7 +1261,7 @@ def decode_k(
     def body(carry, _):
         cache, toks, lengths, act, keys, n_new = carry
         pos = lengths[:, None]  # [B, 1] absolute per row
-        logits, nc = forward_cached(
+        logits, nc, _ = forward_cached(
             params, cfg, toks[:, None], pos, cache, lengths,
             real_end=lengths + 1,
             # paged caches: a frozen row's tail-step garbage write must be
@@ -1536,29 +1373,24 @@ def forward(
     cfg: ModelConfig,
     tokens: jax.Array,  # [B, S]
     positions: Optional[jax.Array] = None,
-    k_cache: Optional[jax.Array] = None,
+    k_cache: Optional[jax.Array] = None,  # a uniform KVCache's buffers
     v_cache: Optional[jax.Array] = None,
     cache_write_pos: Optional[jax.Array] = None,
 ):
-    """Whole-model forward -> (logits [B, S, V], new_k, new_v).
+    """Whole-model forward -> (logits [B, S, V], new_k, new_v); cache-free
+    (the one scan with no entry) unless the buffers of a uniform
+    core.cache.KVCache are passed, which forward_cached then serves.
 
     When `positions` is omitted it is derived from `cache_write_pos` (or 0),
     so cached decode steps get correct RoPE angles and causal masking.
     """
+    if k_cache is not None:
+        cache = cachelib.KVCache(k=k_cache, v=v_cache, length=cache_write_pos)
+        logits, nc, _ = forward_cached(params, cfg, tokens, positions, cache, cache_write_pos)
+        return logits, nc.k, nc.v
     if positions is None:
-        start = jnp.int32(0) if cache_write_pos is None else cache_write_pos
-        if jnp.ndim(start) == 1:  # per-batch-row start (continuous batching)
-            start = start[:, None]
-        positions = start + jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
+        positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
     hidden = embed(params, tokens, cfg)
-    groups = layer_groups(params)
-    if len(groups) > 1:
-        if k_cache is not None:
-            raise ValueError(f"{cfg.name}: grouped layers run cached through forward_cached")
-        for layers in groups:
-            hidden, _, _ = forward_layers(layers, cfg, hidden, positions)
-        return unembed(params, cfg, hidden), None, None
-    hidden, nk, nv = forward_layers(
-        params["layers"], cfg, hidden, positions, k_cache, v_cache, cache_write_pos
-    )
-    return unembed(params, cfg, hidden), nk, nv
+    for layers in layer_groups(params):
+        hidden, _, _ = forward_layers(layers, cfg, hidden, positions)
+    return unembed(params, cfg, hidden), None, None

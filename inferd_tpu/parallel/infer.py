@@ -53,6 +53,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from inferd_tpu.config import ModelConfig, SamplingConfig
 from inferd_tpu.core import sampling as samplib
+from inferd_tpu.core.cache import KVCache
 from inferd_tpu.core.generate import bucket_len
 from inferd_tpu.models import qwen3
 from inferd_tpu.obs import trace as tracelib
@@ -95,7 +96,7 @@ def ring_split_ok(cfg: ModelConfig, pp: int) -> bool:
     layers? Requires every rank's slice to start on an EVEN global layer
     index — then the sliding/global alternation is the SAME static pattern
     on all ranks and the one SPMD program stays rank-independent. True for
-    pp == 1 (any length; forward_layers_split handles an odd tail) and for
+    pp == 1 (any length; the layer scan unrolls an odd tail) and for
     even layers-per-rank; odd layers-per-rank (e.g. Gemma-2's 26 layers at
     pp=2) keeps the uniform mask-only fallback, observable via stats()."""
     if not cfg.sliding_window:
@@ -165,13 +166,12 @@ def _pipeline_pass(
     cfg: ModelConfig,
     tp_axis: Optional[str] = None,
     ep_axis: Optional[str] = None,
-    split: bool = False,
     full_logits: bool = False,
 ):
     """One interleaved pass: N microbatches move through every stage, each
     reading/writing cache slot slots[i] at start offset lengths[slots[i]].
     Returns (new_k, new_v, last-real-token logits [N, B, V] — replicated),
-    plus (new_k_loc, new_v_loc) before the logits when `split`. With
+    plus (new_k_loc, new_v_loc) before the logits when the rings are passed. With
     `full_logits`, the logits buffer is [N, B, S, V] — every chunk
     position unembedded (the speculative VERIFY shape: the accept frontier
     needs the target's distribution at all K+1 positions; S is the small
@@ -183,13 +183,14 @@ def _pipeline_pass(
     only, and embed/norm/lm_head stay replicated so the hop/logits logic is
     unchanged — pp x tp serving in one SPMD program.
 
-    With `split` (sliding-window configs passing ring_split_ok), each
-    rank's slice runs forward_layers_split with a STATIC layer offset of 0:
+    With rings (`split`: sliding-window configs passing ring_split_ok), each
+    rank's slice runs with a STATIC layer offset of 0:
     every rank's slice starts on an even global index, so the rank-local
     sliding/global alternation is identical across ranks and sliding layers
     read/write O(window) rings — the same program on every rank, which is
     what shard_map requires. The traced-offset design this replaces could
     never make the pattern static (mesh_executor r03 fallback)."""
+    split = k_loc is not None
     pp = lax.axis_size("pp")
     idx = lax.axis_index("pp")
     perm = [(i, (i + 1) % pp) for i in range(pp)]
@@ -203,7 +204,7 @@ def _pipeline_pass(
         logits_buf = jnp.zeros((n, b, cfg.vocab_size), jnp.float32)
 
     def tick(carry, t):
-        state, k, v, k_loc, v_loc, logits_buf = carry
+        state, bufs, logits_buf = carry
         # which in-flight microbatch is resident on this rank at tick t
         m = t - idx
         valid = (m >= 0) & (m < n)
@@ -216,35 +217,31 @@ def _pipeline_pass(
 
         start = lengths[slot]
         positions = start + jnp.broadcast_to(jnp.arange(s), (b, s))
-        km = lax.dynamic_index_in_dim(k, slot, axis=1, keepdims=False)
-        vm = lax.dynamic_index_in_dim(v, slot, axis=1, keepdims=False)
-        if split:
-            klm = lax.dynamic_index_in_dim(k_loc, slot, axis=1, keepdims=False)
-            vlm = lax.dynamic_index_in_dim(v_loc, slot, axis=1, keepdims=False)
-            # real_end is ABSOLUTE (first bucket-padding position in the
-            # stream): the chunk's real rows are start..start+last_idx
-            y, nk, nv, nkl, nvl = qwen3.forward_layers_split(
-                params["layers"], cfg, inp, positions, km, vm, klm, vlm,
-                start, real_end=start + last_idx + 1, layer_offset=0,
-                tp_axis=tp_axis, ep_axis=ep_axis,
-            )
-            k_loc = lax.dynamic_update_index_in_dim(
-                k_loc, jnp.where(valid, nkl, klm), slot, axis=1
-            )
-            v_loc = lax.dynamic_update_index_in_dim(
-                v_loc, jnp.where(valid, nvl, vlm), slot, axis=1
-            )
-        else:
-            y, nk, nv = qwen3.forward_layers(
-                params["layers"], cfg, inp, positions, km, vm, start,
-                tp_axis=tp_axis, ep_axis=ep_axis,
-                layer_offset=idx * (cfg.num_layers // pp),
-            )
+        # the resident slot's cache view (None rings are no leaves)
+        old = jax.tree.map(
+            lambda a: lax.dynamic_index_in_dim(a, slot, axis=1, keepdims=False), bufs
+        )
+        # split: every rank's slice starts on an even global index, so the
+        # STATIC offset 0 gives all ranks one pattern; real_end is ABSOLUTE
+        # (first bucket-padding position in the stream): the chunk's real
+        # rows are start..start+last_idx. Uniform: the rank's own (traced)
+        # offset, windows mask-only
+        y, nc, _ = qwen3.forward_layers_cached(
+            params["layers"], cfg, inp, positions,
+            KVCache(k=old[0], v=old[1], length=start, k_loc=old[2], v_loc=old[3]),
+            start, real_end=start + last_idx + 1 if split else None,
+            layer_offset=0 if split else idx * (cfg.num_layers // pp),
+            tp_axis=tp_axis, ep_axis=ep_axis,
+        )
         # cache writeback for the resident slot: on bubble ticks write the
         # ORIGINAL slice back (no-op) — the select stays slice-sized
         # instead of cache-sized
-        k = lax.dynamic_update_index_in_dim(k, jnp.where(valid, nk, km), slot, axis=1)
-        v = lax.dynamic_update_index_in_dim(v, jnp.where(valid, nv, vm), slot, axis=1)
+        bufs = jax.tree.map(
+            lambda a, new, was: lax.dynamic_update_index_in_dim(
+                a, jnp.where(valid, new, was), slot, axis=1
+            ),
+            bufs, (nc.k, nc.v, nc.k_loc, nc.v_loc), old,
+        )
 
         # last rank: unembed the last REAL token into the output slot
         # (or, for the speculative verify shape, the WHOLE chunk)
@@ -262,21 +259,17 @@ def _pipeline_pass(
         )
 
         state = lax.ppermute(y, "pp", perm)
-        return (state, k, v, k_loc, v_loc, logits_buf), None
+        return (state, bufs, logits_buf), None
 
-    carry0 = (state, k, v, k_loc, v_loc, logits_buf)
-    if not split:  # keep None rings out of the scan carry
-        carry0 = (state, k, v, (), (), logits_buf)
-    (_, k, v, k_loc, v_loc, logits_buf), _ = lax.scan(
-        tick, carry0, jnp.arange(n + pp - 1)
+    (_, (k, v, k_loc, v_loc), logits_buf), _ = lax.scan(
+        tick, (state, (k, v, k_loc, v_loc), logits_buf), jnp.arange(n + pp - 1)
     )
     # only the last rank filled the buffer; psum replicates it
     logits_buf = lax.psum(
         jnp.where(idx == pp - 1, logits_buf, jnp.zeros_like(logits_buf)), "pp"
     )
-    if split:
-        return k, v, k_loc, v_loc, logits_buf
-    return k, v, logits_buf
+    rings = (k_loc, v_loc) if split else ()
+    return (k, v, *rings, logits_buf)
 
 
 def cache_spec(mesh: Mesh) -> P:
@@ -384,27 +377,33 @@ def make_pipeline_pass(
     split = ring_split_ok(cfg, mesh.shape["pp"]) if ring is None else (
         ring and ring_split_ok(cfg, mesh.shape["pp"])
     )
-    if split:
-        return jax.shard_map(
-            partial(
-                _pipeline_pass, cfg=cfg, tp_axis=tp_axis, ep_axis=ep_axis,
-                split=True, full_logits=full_logits,
-            ),
-            mesh=mesh,
-            in_specs=(pspecs, P(), P(), P(), kv, kv, P(), kv, kv),
-            out_specs=(kv, kv, kv, kv, P()),
-            check_vma=False,
-        )
+    rings = (kv, kv) if split else ()  # the sliding layers' k_loc, v_loc
     return jax.shard_map(
         partial(
             _pipeline_pass, cfg=cfg, tp_axis=tp_axis, ep_axis=ep_axis,
             full_logits=full_logits,
         ),
         mesh=mesh,
-        in_specs=(pspecs, P(), P(), P(), kv, kv, P()),
-        out_specs=(kv, kv, P()),
+        in_specs=(pspecs, P(), P(), P(), kv, kv, P(), *rings),
+        out_specs=(kv, kv, *rings, P()),
         check_vma=False,
     )
+
+
+def _over_caches(raw_pass):
+    """A make_pipeline_pass program as a function of PipelinedCaches ->
+    (k', v', k_loc', v_loc', logits), the rings None where the caches have
+    none."""
+
+    def passfn(params, x, slots, last_idx, caches, lengths):
+        rings = () if caches.k_loc is None else (caches.k_loc, caches.v_loc)
+        *bufs, logits = raw_pass(
+            params, x, slots, last_idx, caches.k, caches.v, lengths, *rings
+        )
+        nk, nv, nkl, nvl = (*bufs, None, None)[:4]
+        return nk, nv, nkl, nvl, logits
+
+    return passfn
 
 
 class PipelinedEngine:
@@ -473,20 +472,7 @@ class PipelinedEngine:
         # on it at trace time
         self.ring_active = self.caches.k_loc is not None
 
-        raw_passfn = make_pipeline_pass(cfg, mesh, params=params, ring=ring)
-        if self.ring_active:
-            def passfn(params, x, slots, last_idx, caches, lengths):
-                nk, nv, nkl, nvl, logits = raw_passfn(
-                    params, x, slots, last_idx, caches.k, caches.v, lengths,
-                    caches.k_loc, caches.v_loc,
-                )
-                return nk, nv, nkl, nvl, logits
-        else:
-            def passfn(params, x, slots, last_idx, caches, lengths):
-                nk, nv, logits = raw_passfn(
-                    params, x, slots, last_idx, caches.k, caches.v, lengths
-                )
-                return nk, nv, None, None, logits
+        passfn = _over_caches(make_pipeline_pass(cfg, mesh, params=params, ring=ring))
         sampling = self.sampling
 
         def _sample_lanes(logits, keys, done, prev, eos, top_n=0,
@@ -748,22 +734,10 @@ class PipelinedEngine:
             KVCache.create(dcfg, dcfg.num_layers, self.mb, self.max_len), repl
         )
         self.spec_k = k
-        raw_full = make_pipeline_pass(
+        passfn_full = _over_caches(make_pipeline_pass(
             self.cfg, self.mesh, params=raw_params, ring=self._ring_arg,
             full_logits=True,
-        )
-        if self.ring_active:
-            def passfn_full(params, x, slots, last_idx, caches, lengths):
-                return raw_full(
-                    params, x, slots, last_idx, caches.k, caches.v, lengths,
-                    caches.k_loc, caches.v_loc,
-                )
-        else:
-            def passfn_full(params, x, slots, last_idx, caches, lengths):
-                nk, nv, logits = raw_full(
-                    params, x, slots, last_idx, caches.k, caches.v, lengths
-                )
-                return nk, nv, None, None, logits
+        ))
         self._passfn_full = passfn_full
 
 
@@ -1179,7 +1153,7 @@ class MeshSpecRunner:
         @partial(jax.jit, donate_argnames=("dcache",))
         def _draft_prefill(dp, dcache: KVCache, tokens, slot, start, n):
             lc = lane_slice(dcache, slot)
-            _, nc = qwen3.forward_cached(
+            _, nc, _ = qwen3.forward_cached(
                 dp, dcfg, tokens, None, lc, start, real_end=start + n
             )
             return lane_write(dcache, slot, nc)

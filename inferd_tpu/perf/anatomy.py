@@ -174,7 +174,7 @@ def _build_suite(
         tok, cache, key = carry
         key, sub = jax.random.split(key)
         pos = jnp.broadcast_to(cache.length, (batch, 1))
-        logits, nc = qwen3.forward_cached(
+        logits, nc, _ = qwen3.forward_cached(
             params, cfg, tok, pos, cache, cache.length,
             real_end=cache.length + 1,
         )

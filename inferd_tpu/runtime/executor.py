@@ -278,7 +278,7 @@ class Qwen3StageExecutor:
                 hidden = x
             s = hidden.shape[1]
             positions = start_pos + jnp.broadcast_to(jnp.arange(s), hidden.shape[:2])
-            hidden, nc = qwen3.forward_layers_cached(
+            hidden, nc, _ = qwen3.forward_layers_cached(
                 params["layers"], cfg_, hidden, positions, cache, cache.length,
                 real_end=cache.length + real_len,
                 layer_offset=spec_.start_layer,

@@ -192,7 +192,7 @@ class BatchedStageExecutor(AdapterBindingMixin):
             else:
                 hidden = x
             positions = lengths[:, None]  # [L, 1] absolute per lane
-            hidden, nc = qwen3.forward_layers_cached(
+            hidden, nc, _ = qwen3.forward_layers_cached(
                 params["layers"], cfg_, hidden, positions, cache, lengths,
                 real_end=lengths + 1, layer_offset=spec_.start_layer,
                 adapters=ads,
@@ -218,7 +218,7 @@ class BatchedStageExecutor(AdapterBindingMixin):
                 jnp.arange(s), hidden.shape[:2]
             )
             lc = _lane_slice(cache, lane)
-            hidden, nc = qwen3.forward_layers_cached(
+            hidden, nc, _ = qwen3.forward_layers_cached(
                 params["layers"], cfg_, hidden, positions, lc, start,
                 real_end=start + n, layer_offset=spec_.start_layer,
                 adapters=ads,
@@ -243,7 +243,7 @@ class BatchedStageExecutor(AdapterBindingMixin):
             else:
                 hidden = x
             positions = lengths[:, None]
-            hidden, nc = qwen3.forward_layers_cached(
+            hidden, nc, _ = qwen3.forward_layers_cached(
                 params["layers"], cfg_, hidden, positions, cache, lengths,
                 real_end=lengths + 1, layer_offset=spec_.start_layer,
                 write_mask=active, adapters=ads,
@@ -270,7 +270,7 @@ class BatchedStageExecutor(AdapterBindingMixin):
             lc = PagedKVCache(
                 k=cache.k, v=cache.v, table=table_row, length=cache.length
             )
-            hidden, nc = qwen3.forward_layers_cached(
+            hidden, nc, _ = qwen3.forward_layers_cached(
                 params["layers"], cfg_, hidden, positions, lc, start,
                 real_end=start + n, layer_offset=spec_.start_layer,
                 adapters=ads,

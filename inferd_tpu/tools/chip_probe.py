@@ -138,20 +138,19 @@ def probe_decode_components(cfg_name: str) -> dict:
     hidden0 = jnp.ones((1, 1, cfg.hidden_size), cfg.jnp_dtype)
 
     def layers_step(carry):
-        h, k, v = carry
-        out, new_k, new_v = qwen3.forward_layers(
-            params["layers"], cfg, h, pos, k, v,
-            cache_write_pos=jnp.int32(64),
+        h, c = carry
+        out, nc, _ = qwen3.forward_layers_cached(
+            params["layers"], cfg, h, pos, c, jnp.int32(64)
         )
-        # thread the returned KV buffers through the scan carry: when they
-        # were returned-and-dropped, the cache write was dead code, XLA
+        # thread the returned cache through the scan carry: when it was
+        # returned-and-dropped, the cache write was dead code, XLA
         # DCE'd it out of the loop, and layers_ms/layers_eff_gbps timed a
         # write-free pseudo-step (undercounting a real decode step). As
         # carry, iteration i+1's attention reads what iteration i wrote,
         # so the write is live — the same dependency a real decode has.
-        return (out, new_k, new_v)
+        return (out, nc)
 
-    layers_t = _scan_pair(layers_step, (hidden0, cache.k, cache.v), 4, 12)
+    layers_t = _scan_pair(layers_step, (hidden0, cache), 4, 12)
 
     def head_step(h):
         logits = qwen3.unembed(params, cfg, h)

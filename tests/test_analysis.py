@@ -727,9 +727,11 @@ def test_chip_probe_layers_step_kv_write_survives_dce():
     h0 = jnp.ones((1, 1, cfg.hidden_size), cfg.jnp_dtype)
 
     def fwd(h, k, v):
-        return qwen3.forward_layers(
-            params["layers"], cfg, h, pos, k, v, cache_write_pos=jnp.int32(3)
+        h, nc, _ = qwen3.forward_layers_cached(
+            params["layers"], cfg, h, pos, KVCache(k=k, v=v, length=cache.length),
+            jnp.int32(3),
         )
+        return h, nc.k, nc.v
 
     @jax.jit
     def dead(x):  # the pre-fix shape: KV returned and dropped

@@ -410,7 +410,7 @@ def test_decode_k_paged_token_exact_kernel_forced(all_kernels_forced,
         paged = dataclasses.replace(pool.cache, table=pool.device_table())
         pos = jnp.broadcast_to(jnp.arange(n), (b, n))
         cache = paged if forced else dense
-        logits, cache = qwen3.forward_cached(
+        logits, cache, _ = qwen3.forward_cached(
             tiny_params, TINY, jnp.asarray(toks), pos, cache,
             jnp.int32(0), real_end=jnp.int32(n))
         tok = jnp.argmax(logits[:, n - 1], -1).astype(jnp.int32)

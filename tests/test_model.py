@@ -577,10 +577,11 @@ def test_gemma2_stage_split_matches_full():
 
 @pytest.mark.parametrize("family", ["gemma2", "gptoss"])
 def test_windowed_read_fast_path_matches_uniform(family):
-    """The sliding-window pair-scan fast path (static window -> KV read
-    narrowed to a window-covering slice) must produce bit-comparable
-    logits AND identical cache writes to the uniform scan (traced window,
-    full-buffer mask-only read) — prefill chunk and decode steps."""
+    """A static layer offset (a period of the scan is a (sliding, global)
+    pair; static window -> KV read narrowed to a window-covering slice)
+    must produce bit-comparable logits AND identical cache writes to a
+    traced one (every layer its own period, traced window, full-buffer
+    mask-only read) — prefill chunk and decode steps."""
     from inferd_tpu.config import TINY_GEMMA2, TINY_GPT_OSS
     from inferd_tpu.core.cache import KVCache
 
@@ -589,15 +590,15 @@ def test_windowed_read_fast_path_matches_uniform(family):
     toks = jax.random.randint(jax.random.PRNGKey(18), (2, 6), 0, cfg.vocab_size, jnp.int32)
 
     def run(layer_offset):
-        # static int offset 0 -> pair fast path; traced offset -> uniform
+        # static int offset 0 -> static windows; traced offset -> mask-only
         # (ring=False: this test pins the UNIFORM-layout windowed-READ fast
         # path; ring STORAGE has its own suite, tests/test_ringkv.py)
         cache = KVCache.create(cfg, cfg.num_layers, 2, 32, ring=False)
         pos = jnp.broadcast_to(jnp.arange(6), (2, 6))
         hidden = qwen3.embed(params, toks, cfg)
-        h, nk, nv = qwen3.forward_layers(
-            params["layers"], cfg, hidden, pos, cache.k, cache.v,
-            jnp.int32(0), layer_offset=layer_offset,
+        h, cache, _ = qwen3.forward_layers_cached(
+            params["layers"], cfg, hidden, pos, cache, jnp.int32(0),
+            layer_offset=layer_offset,
         )
         outs = [qwen3.unembed(params, cfg, h)]
         length = jnp.int32(6)
@@ -605,17 +606,17 @@ def test_windowed_read_fast_path_matches_uniform(family):
         for i in range(6, 14):  # decode walks past the window of 8
             pos = jnp.full((2, 1), i, jnp.int32)
             hidden = qwen3.embed(params, tok, cfg)
-            h, nk, nv = qwen3.forward_layers(
-                params["layers"], cfg, hidden, pos, nk, nv, length,
+            h, cache, _ = qwen3.forward_layers_cached(
+                params["layers"], cfg, hidden, pos, cache, length,
                 layer_offset=layer_offset,
             )
             length = length + 1
             outs.append(qwen3.unembed(params, cfg, h))
             tok = jnp.argmax(outs[-1][:, -1], -1)[:, None]
-        return jnp.concatenate(outs, axis=1), nk, nv
+        return jnp.concatenate(outs, axis=1), cache.k, cache.v
 
-    # both jitted: layer_offset a static closure int (pair fast path) vs a
-    # traced argument (uniform scan) — same compilation regime otherwise
+    # both jitted: layer_offset a static closure int (read fast path) vs a
+    # traced argument (mask-only) — same compilation regime otherwise
     fast_logits, fast_k, fast_v = jax.jit(lambda: run(0))()
     uni_logits, uni_k, uni_v = jax.jit(run)(jnp.int32(0))
     np.testing.assert_allclose(
@@ -633,7 +634,7 @@ def test_windowed_slice_fuzz():
     """Randomized shapes/fills: attention over the window-covering slice ==
     attention over the full buffer with the window mask, for scalar and
     per-row ends, prefill chunks and decode steps, tiny and buffer-sized
-    windows (the invariant the pair-scan fast path rests on)."""
+    windows (the invariant the windowed-read fast path rests on)."""
     from inferd_tpu.models.qwen3 import _windowed_slice, gqa_attention
 
     rng = np.random.RandomState(41)
@@ -728,3 +729,133 @@ def test_fp8_kv_write_saturates_no_nan():
     f = np.asarray(out, np.float32)
     assert not np.isnan(f).any()
     assert f[0, 0] > 400 and f[0, 1] < -400
+
+
+# ---------------------------------------------------------------------------
+# every cache layout through the one layer scan
+# ---------------------------------------------------------------------------
+
+# layout -> (preset, stage [start, end) or None for the whole model, the cache
+# the seam is given, per-row write positions in decode)
+LAYOUTS = {
+    "none": ("tiny", None, None, False),
+    "dense-scalar": ("tiny", None, "lanes", False),
+    "dense-per-row": ("tiny", None, "lanes", True),
+    "ring-even-offset": ("tiny-gemma2", None, "lanes", True),
+    "ring-odd-offset-odd-length": ("tiny-gptoss", (1, 4), "lanes", True),
+    "paged": ("tiny", None, "paged", True),
+    "latent-after-a-dense-group": ("tiny-dsv2", None, "lanes", True),
+}
+
+
+def _scan_lengths(jaxpr):
+    """The length of every lax.scan in a jaxpr, nested ones included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            out.append(eqn.params["length"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _scan_lengths(sub)
+    return out
+
+
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_every_layout_runs_through_the_one_scan(layout):
+    """For each layout of core/cache: cache -> stacked entries -> cache is
+    the identity; the cached forward holds exactly ONE scan per layer group,
+    of length (layers - head - tail) / period; and prefill then decode
+    through the seam (forward_cached for a whole model, forward_layers_cached
+    for a stage) equals the cache-free forward. Two rows at ragged fills
+    (6 and 4 real tokens, the shorter padded to the bucket) except where the
+    write position is one scalar."""
+    from inferd_tpu.config import get_config
+    from inferd_tpu.core import cache as cachelib
+
+    preset, stage, kind, per_row = LAYOUTS[layout]
+    cfg = get_config(preset)
+    params = qwen3.init_params(cfg, jax.random.PRNGKey(23))
+    if stage is None:  # (layers, global index of the first) for each layer group
+        stacks = qwen3.layer_groups(params)
+        offs = np.cumsum([0] + [qwen3._stack_len(g) for g in stacks])
+        groups = [(g, int(o)) for g, o in zip(stacks, offs)]
+    else:
+        groups = [(qwen3.slice_layers(params["layers"], *stage), stage[0])]
+    n_layers = sum(qwen3._stack_len(g) for g, _ in groups)
+
+    def free(tokens):  # the cache-free forward over whole sequences
+        h = qwen3.embed(params, tokens, cfg)
+        pos = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
+        for layers, off in groups:
+            h, ents, _ = qwen3.forward_layers(layers, cfg, h, pos, layer_offset=off)
+            assert ents == ()
+        return qwen3.unembed(params, cfg, h)
+
+    def cached(tokens, pos, cache, write_pos, real_end):
+        if stage is None:
+            return qwen3.forward_cached(params, cfg, tokens, pos, cache, write_pos, real_end)[:2]
+        h, nc, _ = qwen3.forward_layers_cached(
+            groups[0][0], cfg, qwen3.embed(params, tokens, cfg), pos, cache,
+            write_pos, real_end, layer_offset=stage[0],
+        )
+        return qwen3.unembed(params, cfg, h), nc
+
+    # one scan per group, as long as its whole periods
+    period = len(cfg.layer_pattern)
+    want_scans = [(qwen3._stack_len(g) - (-off % period)) // period for g, off in groups]
+    toks = jax.random.randint(jax.random.PRNGKey(24), (2, 9), 0, cfg.vocab_size, jnp.int32)
+    if kind is None:
+        assert _scan_lengths(jax.make_jaxpr(free)(toks).jaxpr) == want_scans
+        # against the layers run one by one, outside any scan
+        h = qwen3.embed(params, toks, cfg)
+        pos = jnp.broadcast_to(jnp.arange(9), (2, 9))
+        cos, sin = qwen3.rope_cos_sin(pos, cfg.rope_dim, cfg.rope_theta, cfg)
+        for i in range(n_layers):
+            lp = jax.tree.map(lambda a: a[i], params["layers"])
+            h, entry, _ = qwen3.decoder_layer(lp, cfg, h, cos, sin, pos)
+            assert entry is None
+        np.testing.assert_allclose(
+            free(toks), qwen3.unembed(params, cfg, h), rtol=1e-4, atol=1e-4
+        )
+        return
+
+    lens = [6, 4] if per_row else [6, 6]  # real tokens a row after prefill
+    if kind == "paged":
+        cache = cachelib.PagedKVCache.create(cfg, n_layers, 2, 32, block_size=8)
+        table = jnp.arange(1, 9, dtype=jnp.int32).reshape(2, 4)  # block 0 is scratch
+        cache = dataclasses.replace(cache, table=table)
+        want_types = [cachelib.PagedEntry]
+    else:
+        cache = cachelib.KVCache.create(
+            cfg, n_layers, 2, 32, layer_offset=0 if stage is None else stage[0]
+        )
+        want_types = (
+            [cachelib.LatentEntry] if cfg.is_mla
+            else [cachelib.RingEntry, cachelib.DenseEntry] if cfg.sliding_window
+            else [cachelib.DenseEntry]
+        )
+    entries = cache.entries(cfg)
+    assert [type(e) for e in entries] == want_types
+    back = cache.with_entries(entries)
+    assert jax.tree.structure(back) == jax.tree.structure(cache)
+    assert all(a is b for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(cache)))
+
+    # row r's whole sequence: its prefill tokens, then its decoded ones
+    seqs = [np.concatenate([toks[r, : lens[r]], toks[r, 6:9]]) for r in range(2)]
+    want = [np.asarray(free(jnp.asarray(s)[None]))[0] for s in seqs]
+    pos = jnp.broadcast_to(jnp.arange(6), (2, 6))
+    logits, cache = cached(toks[:, :6], pos, cache, jnp.int32(0), jnp.asarray(lens))
+    for r in range(2):
+        np.testing.assert_allclose(
+            logits[r, : lens[r]], want[r][: lens[r]], rtol=1e-4, atol=1e-4
+        )
+    for i in range(3):
+        at = jnp.asarray(lens) + i if per_row else jnp.int32(6 + i)
+        pos = jnp.broadcast_to(jnp.asarray(lens)[:, None] + i, (2, 1))
+        step = lambda c: cached(toks[:, 6 + i : 7 + i], pos, c, at, at + 1)
+        if i == 0:
+            assert _scan_lengths(jax.make_jaxpr(step)(cache).jaxpr) == want_scans
+        logits, cache = step(cache)
+        for r in range(2):
+            np.testing.assert_allclose(
+                logits[r, 0], want[r][lens[r] + i], rtol=1e-4, atol=1e-4
+            )

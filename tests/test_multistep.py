@@ -428,7 +428,7 @@ def test_decode_k_counts_eos_token_then_freezes(solo_setup):
     cache = KVCache.create(cfg, cfg.num_layers, 1, 32)
     # prefill via the model forward to establish a frontier
     toks = jnp.asarray([PROMPT], jnp.int32)
-    _logits, nc = qwen3.forward_cached(
+    _logits, nc, _ = qwen3.forward_cached(
         params, cfg, toks, None, cache, jnp.int32(0), real_end=4
     )
     import dataclasses

@@ -217,9 +217,9 @@ def _prefill_both(params, pool, toks):
         pool.ensure(lane, n, owner=f"lane {lane}")
     paged = dataclasses.replace(pool.cache, table=pool.device_table())
     pos = jnp.broadcast_to(jnp.arange(n), (b, n))
-    ld, dc = qwen3.forward_cached(params, TINY, jnp.asarray(toks), pos,
+    ld, dc, _ = qwen3.forward_cached(params, TINY, jnp.asarray(toks), pos,
                                   dense, jnp.int32(0), real_end=jnp.int32(n))
-    lp, pc = qwen3.forward_cached(params, TINY, jnp.asarray(toks), pos,
+    lp, pc, _ = qwen3.forward_cached(params, TINY, jnp.asarray(toks), pos,
                                   paged, jnp.int32(0), real_end=jnp.int32(n))
     assert_same_logits(ld, lp)
     return ld, dc, pc
@@ -242,11 +242,11 @@ def test_forward_cached_paged_parity_prefill_decode(tiny_params):
             pool.ensure(lane, cur + 1, owner=f"lane {lane}")
         cur += 1
         pc = dataclasses.replace(pc, table=pool.device_table())
-        ld, dc = qwen3.forward_cached(
+        ld, dc, _ = qwen3.forward_cached(
             tiny_params, TINY, tok[:, None], jnp.asarray(lens)[:, None],
             dc, jnp.asarray(lens), real_end=jnp.asarray(lens) + 1,
         )
-        lp, pc = qwen3.forward_cached(
+        lp, pc, _ = qwen3.forward_cached(
             tiny_params, TINY, tok[:, None], jnp.asarray(lens)[:, None],
             pc, jnp.asarray(lens), real_end=jnp.asarray(lens) + 1,
             write_mask=jnp.ones((2,), bool),
@@ -741,7 +741,7 @@ def _decode_tokens(cfg, params, cache, logits, n, steps):
     toks = [int(np.argmax(np.asarray(logits)[0, n - 1]))]
     lens = n
     for _ in range(steps):
-        l, cache = qwen3.forward_cached(
+        l, cache, _ = qwen3.forward_cached(
             params, cfg, jnp.asarray([[toks[-1]]], jnp.int32),
             jnp.asarray([[lens]], jnp.int32), cache, jnp.int32(lens),
             real_end=jnp.int32(lens + 1),
@@ -763,9 +763,9 @@ def test_grow_then_decode_token_exact(preset):
 
     small = KVCache.create(cfg, cfg.num_layers, 1, 32)
     big = KVCache.create(cfg, cfg.num_layers, 1, 64)
-    ls, cs = qwen3.forward_cached(params, cfg, jnp.asarray(prompt), pos,
+    ls, cs, _ = qwen3.forward_cached(params, cfg, jnp.asarray(prompt), pos,
                                   small, jnp.int32(0), real_end=jnp.int32(n))
-    lb, cb = qwen3.forward_cached(params, cfg, jnp.asarray(prompt), pos,
+    lb, cb, _ = qwen3.forward_cached(params, cfg, jnp.asarray(prompt), pos,
                                   big, jnp.int32(0), real_end=jnp.int32(n))
     toks_small, cs = _decode_tokens(cfg, params, cs, ls, n, 8)
     # grow mid-stream, decode past the old 32-slot bucket
@@ -777,12 +777,12 @@ def test_grow_then_decode_token_exact(preset):
     lens = n + 8
     tok = toks_big[-1]
     for _ in range(16):
-        l1, cs = qwen3.forward_cached(
+        l1, cs, _ = qwen3.forward_cached(
             params, cfg, jnp.asarray([[tok]], jnp.int32),
             jnp.asarray([[lens]], jnp.int32), cs, jnp.int32(lens),
             real_end=jnp.int32(lens + 1),
         )
-        l2, cb = qwen3.forward_cached(
+        l2, cb, _ = qwen3.forward_cached(
             params, cfg, jnp.asarray([[tok]], jnp.int32),
             jnp.asarray([[lens]], jnp.int32), cb, jnp.int32(lens),
             real_end=jnp.int32(lens + 1),

@@ -317,11 +317,11 @@ def test_ring_fuzz_random_chunks_and_rollbacks():
             s = min(s, max_len - pos)
             chunk = rng.randint(0, cfg.vocab_size, size=(1, s)).astype(np.int32)
             pos_arr = pos + jnp.arange(s)[None, :]
-            lr, ring = qwen3.forward_cached(
+            lr, ring, _ = qwen3.forward_cached(
                 params, cfg, jnp.asarray(chunk), pos_arr, ring,
                 jnp.int32(pos), real_end=jnp.int32(pos + s),
             )
-            lf, flat = qwen3.forward_cached(
+            lf, flat, _ = qwen3.forward_cached(
                 params, cfg, jnp.asarray(chunk), pos_arr, flat,
                 jnp.int32(pos), real_end=jnp.int32(pos + s),
             )
