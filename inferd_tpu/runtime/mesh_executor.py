@@ -15,9 +15,13 @@ caches (one slot = one session's cache lane), with idle-TTL sweep and
 slot refill on end_session — the per-session server-side cache story
 (qwen3_server_module.py:220) carried over to the mesh.
 
-process() is called from the node's worker thread pool; an internal lock
-serializes device steps (the engine's donated caches admit one step at a
-time). Different sessions interleave at step granularity.
+process() is called from the node's worker thread pool (a thread per slot
+and one more); an internal lock serializes device steps (the engine's
+donated caches admit one step at a time) and guards the session table, so
+a call is admitted under it too. Prefills run one at a time; decode steps
+go through the arrival window (runtime/window.py, formation), whose
+flusher takes every entry that is pending once it holds the lock: ONE
+pipeline pass advances every session that was waiting when the mesh freed.
 """
 
 from __future__ import annotations
@@ -180,15 +184,23 @@ class MeshExecutor(SpecServing):
         self._ring_hi: Dict[str, int] = {}
         self._inflight: Dict[str, int] = {}  # session -> active request count
         self._dying: Dict[int, str] = {}  # slot -> ended session awaiting drain
-        # windowed decode coalescing: the pipeline pass natively interleaves
-        # all MB slots, so decode steps of sessions co-arriving within the
-        # window share ONE pass instead of one traversal each
+        # decode coalescing: the pipeline pass natively interleaves all MB
+        # slots and costs the same whatever rides it, so a pass takes every
+        # session that is waiting when the mesh frees
         from inferd_tpu.runtime.window import WindowedBatcher
 
         self._batcher = WindowedBatcher(
-            window_s=window_ms / 1e3,
-            run_batch=self._run_decode_batch,
+            # only the start value of the batcher's own estimate of a
+            # session's turn (result out -> next submit); see `expect`
+            window_ms / 1e3,
+            self._run_decode_batch,
             co_possible=lambda: len(self.sessions) > 1,
+            # formation, as on the lanes (runtime/batch_executor.py): the
+            # batch is drained under _lock, and the flusher waits for the
+            # slots the last two passes served. end_session, _spec_drop and
+            # a prefill take a slot out of that expectation at once.
+            swap_in_run=True,
+            expect=lambda payload: payload[0],
         )
         self._spec_window_s = window_ms / 1e3
         # in-mesh lane... slot speculation (parallel.infer.MeshSpecRunner):
@@ -203,9 +215,10 @@ class MeshExecutor(SpecServing):
 
     @property
     def tracer(self):
-        """Span recorder (the node wires its own): the decode flush and the
-        prefill step stamp lock_wait here, the engine's raw steps device /
-        copy_out, the batcher batch_wait — all under the call's `compute`."""
+        """Span recorder (the node wires its own): the prefill step stamps
+        lock_wait here, the engine's raw steps device / copy_out, the
+        batcher a decode entry's lock_wait and batch_wait — all under the
+        call's `compute`."""
         return self._batcher.tracer
 
     @tracer.setter
@@ -414,6 +427,7 @@ class MeshExecutor(SpecServing):
             raise ValueError(f"mesh stage expects tokens [1, S], got {toks.shape}")
         start_pos = int(payload.get("start_pos", 0))
         real_len = int(payload.get("real_len", toks.shape[1]))
+        decode = real_len == 1 and start_pos > 0
 
         with self._lock:
             if self._inflight.get(session_id):
@@ -494,9 +508,13 @@ class MeshExecutor(SpecServing):
                     f"({start_pos}+{real_len} > {self.cap})"
                 )
             self._inflight[session_id] = 1
+            if not decode:
+                # inside a prefill: no decode pass waits for this slot
+                # until one has served it again
+                self._batcher.unexpect(lambda p, _s=slot: p[0] == _s)
 
         try:
-            if real_len == 1 and start_pos > 0:
+            if decode:
                 row = self._batcher.submit((slot, int(toks[0, 0]), session_id))
                 logits = row[None, :]
             elif (
@@ -637,15 +655,25 @@ class MeshExecutor(SpecServing):
             **self.spec_stats(),
         }
 
-    def _run_decode_batch(self, entries) -> None:
-        """Flush callback (runtime/window.py): ONE pipeline pass advances
-        every waiting slot together."""
-        # one lock_wait per entry, under each entry's own `compute`
-        waiting = [e.ctx for e in entries] if self.tracer is not None else None
-        with tracelib.holding(self._lock, self.tracer, waiting, kind="decode"):
-            out = self.engine.step_slots(
-                {e.payload[0]: e.payload[1] for e in entries}
-            )
+    def _run_decode_batch(self, _entries) -> None:
+        """Flush callback (runtime/window.py, formation): ONE pipeline pass
+        advances every slot whose entry is pending once the mesh is ours."""
+        with self._lock:
+            # the batcher stamps each entry's lock_wait and batch_wait
+            entries = self._batcher.drain_pending()
+            if not entries:
+                return  # every waiting entry was invalidated: no pass
+            try:
+                out = self.engine.step_slots(
+                    {e.payload[0]: e.payload[1] for e in entries}
+                )
+            except Exception as exc:
+                for e in entries:
+                    e.error = exc
+                # the drain counted every live entry as served; net failed
+                # entries to zero so /stats batched_tokens stays token-true
+                self._batcher.n_served -= len(entries)
+                return
             for e in entries:
                 slot, _tok, sid = e.payload
                 if self._dying.get(slot) != sid:  # ended-mid-flush: the

@@ -527,9 +527,10 @@ class Node:
         # (utils.platform.CompileCacheStats; None = cache not enabled)
         self.compile_cache = compile_cache
         # continuous batching coalesces decode steps of CONCURRENT requests:
-        # the worker pool must admit at least one thread per lane (plus the
-        # flusher's) or the batch window can never fill past the pool size
-        lanes = batch_lanes or stage_lanes
+        # the worker pool must admit at least one thread per lane or mesh
+        # slot (plus one, so a prefill never starves the decode flusher) or
+        # the batch window can never fill past the pool size
+        lanes = mesh_slots if mesh_plan is not None else batch_lanes or stage_lanes
         self.scheduler = TaskScheduler(
             self._announce_load,
             workers=max(2, lanes + 1) if lanes else 2,

@@ -1,12 +1,10 @@
 """First-arrival-flushes micro-batch window (thread-safe, executor-agnostic).
 
-Shared by the continuous-batching executor (runtime/batch_executor.py) and
-the in-mesh pipelined executor (runtime/mesh_executor.py): decode requests
-from concurrent sessions that arrive within a short window run as ONE
-device step. The first arriving thread becomes the flusher — it waits
-`window_s` for co-arrivals (skipped when none are possible), then calls the
-executor's `run_batch` callback with every pending entry; co-arrived
-threads block on their entry until the flusher distributes results.
+Decode requests from concurrent sessions run as ONE device step. The first
+arriving thread becomes the flusher: it waits for co-arrivals (skipped when
+none are possible), then the executor's `run_batch` callback runs every
+pending entry; co-arrived threads block on their entry until the flusher
+distributes results.
 
 The executor's `run_batch(entries)` must:
   * acquire its own device lock (the batcher holds no locks while calling);
@@ -17,25 +15,28 @@ errors raised by run_batch are propagated to every entry in the batch.
 still waiting in the window (never started), so a freed lane/slot can be
 reused without a stale write racing its new owner.
 
-Opt-in modes power CONTINUOUS batching (runtime/node + runtime/stage_batch
-for stages, runtime/batch_executor for lanes — see docs/SERVING.md):
-  * `swap_in_run`: the flusher passes run_batch an EMPTY list and the
-    callback pulls the batch itself via `drain_pending()` once it holds
-    the device — entries arriving mid-step join the next step instead of
-    fragmenting into mini-batches queued on the device lock;
-  * `gang_target`: the window wait ends early once every live idle
-    session's entry is pending, which merges phase-offset session
-    cohorts into one lockstep co-batch and lets the window be sized
-    generously without charging steady-state latency;
-  * `expect` (with `swap_in_run`): FORMATION. The batcher watches whom
-    its steps serve. A flusher first waits out a step that is still
-    running, then — the device free, no lock held — for the entry of
-    every session served by the last step or the one before it, or until
-    a cap since the device freed runs out; only then does the callback
-    take the device and drain. The flusher keeps its slot until that
-    drain, so no second flusher ever queues on the device lock behind it.
+Three modes, and who runs each (docs/SERVING.md):
+  * FORMATION (`swap_in_run` + `expect`): both serving executors, keyed by
+    lane (runtime/batch_executor.py) or by mesh slot
+    (runtime/mesh_executor.py). The batcher watches whom its steps serve.
+    A flusher first waits out a step that is still running, then, the
+    device free and no lock held, for the entry of every session served
+    by the last step or the one before it, or until a cap since the
+    device freed runs out; only then does the callback take the device
+    and pull the batch itself via `drain_pending()`. The flusher keeps its
+    slot until that drain, so no second flusher ever queues on the device
+    lock behind it, and entries arriving mid-step join the next step.
     The cap is derived: three times a running mean of a served session's
-    result -> next submit, never above the last measured step.
+    result -> next submit, never above the last measured step; `window_s`
+    is only that mean's start value.
+  * `swap_in_run` + `gang_target`: `--stage-lanes` (the window lives on
+    the node, runtime/node.py `_attach_window` + runtime/stage_batch.py).
+    The flusher polls until `gang_target()` entries are pending or
+    `window_s` runs out, gives up its slot and calls run_batch with an
+    EMPTY list; the callback drains once it holds the device.
+  * the plain wake-up swap (neither): the speculative windows only
+    (runtime/spec_serving.py). The flusher sleeps `window_s` and takes
+    what is pending then.
 """
 
 from __future__ import annotations

@@ -5,11 +5,13 @@ waits, the mesh engine counts its pipeline's stage-ticks and journals its
 compiles, and a capture keeps its clock anchor from the moment it starts.
 
 Each executor is built once (module fixtures): two sessions prefill one
-after the other, then their decode steps co-arrive behind a barrier, so
-that the step is a co-batch of 2 by construction: inside a wide window on
-the mesh, which takes its batch when the flusher wakes; while the test
-holds the device lock (as a prefill would) on the lanes, which take theirs
-when they get the device. The tests read what that left behind."""
+after the other, then their decode steps co-arrive behind a barrier while
+the test keeps the flusher from the device (as a prefill would), so that
+the step is a co-batch of 2 by construction: both executors take their
+batch when they get the device. On the lanes the test holds the device
+lock; the mesh admits a call under the lock its passes run under, so there
+the flusher is stopped on its way to that lock. The tests read what that
+left behind."""
 
 import asyncio
 import glob
@@ -27,6 +29,7 @@ from inferd_tpu.obs.devtel import CompileWatch
 from inferd_tpu.parallel.mesh import MeshPlan
 from inferd_tpu.runtime.node import Node
 from inferd_tpu.runtime.window import WindowedBatcher
+from test_mesh_node import hold_flusher
 
 PROMPTS = {"a": [3, 7, 11], "b": [5, 13, 17]}
 PARTS = ("batch_wait", "lock_wait", "device", "copy_out")
@@ -54,10 +57,15 @@ def timed(ex, rec, sid, payload):
     return result
 
 
-def drive(ex, prefix="", hold=False):
-    """Prefill both sessions, then one co-arriving decode step of each
-    (`hold`: both arrive under a held device lock, released HELD_S after
-    the second). Returns {(sid, "prefill"|"decode"): logits}."""
+def hold_dev_lock(ex):
+    ex._dev_lock.acquire()
+    return ex._dev_lock.release
+
+
+def drive(ex, hold, prefix=""):
+    """Prefill both sessions, then one co-arriving decode step of each:
+    both arrive while `hold(ex)` keeps the flusher from the device, let go
+    HELD_S after the second. Returns {(sid, "prefill"|"decode"): logits}."""
     rec = tracelib.SpanRecorder("test")
     ex.tracer = rec
     out = {}
@@ -73,15 +81,13 @@ def drive(ex, prefix="", hold=False):
         out[s, "decode"] = np.asarray(r["logits"])
 
     threads = [threading.Thread(target=step, args=(s,)) for s in PROMPTS]
-    if hold:
-        ex._dev_lock.acquire()
+    release = hold(ex)
     for t in threads:
         t.start()
-    if hold:
-        while len(ex._batcher._pending) < len(PROMPTS):
-            time.sleep(0.001)
-        time.sleep(HELD_S)
-        ex._dev_lock.release()
+    while len(ex._batcher._pending) < len(PROMPTS):
+        time.sleep(0.001)
+    time.sleep(HELD_S)
+    release()
     for t in threads:
         t.join(timeout=120)
     assert len(out) == 4
@@ -98,9 +104,9 @@ def lanes(params):
     from inferd_tpu.runtime.batch_executor import BatchedExecutor
 
     ex = BatchedExecutor(TINY, params, lanes=4, max_len=64, window_ms=400.0)
-    rec, out = drive(ex, hold=True)
+    rec, out = drive(ex, hold_dev_lock)
     return {"ex": ex, "spans": rec.spans(), "out": out, "stats": ex.stats(),
-            "hold": True, "waits": ["lock_wait", "batch_wait"],
+            "hold": hold_dev_lock,
             "programs": {"prefill": "jit__prefill_lane_logits",
                          "decode": "jit__decode_logits"}}
 
@@ -113,10 +119,9 @@ def mesh(params):
                       devices=jax.devices()[:PP], window_ms=400.0)
     journal = Journal()
     CompileWatch(journal=journal).instrument_executor(ex)
-    rec, out = drive(ex)
+    rec, out = drive(ex, hold_flusher)
     return {"ex": ex, "spans": rec.spans(), "out": out, "stats": ex.stats(),
-            "events": journal.events,
-            "hold": False, "waits": ["batch_wait", "lock_wait"],
+            "events": journal.events, "hold": hold_flusher,
             "programs": {"prefill": "jit__step_raw", "decode": "jit__step_raw_multi"}}
 
 
@@ -161,10 +166,11 @@ def test_decode_step_is_one_device_step_and_a_wait_per_entry(driven):
     assert len(calls) == 2
     names = [[k["name"] for k in children(spans, c)] for c in calls]
     # the flusher's call holds the step; the co-arrival's only its waits
-    # (mesh: the window, then the lock; lanes: the time the device was
-    # somebody else's, then the wait for expected sessions)
+    # (the time the device was somebody else's, then the wait for expected
+    # sessions)
     assert sorted(names, key=len) == [
-        driven["waits"], driven["waits"] + ["device", "copy_out"],
+        ["lock_wait", "batch_wait"],
+        ["lock_wait", "batch_wait", "device", "copy_out"],
     ]
     dev = [s for s in spans if s["name"] == "device" and s["attrs"]["kind"] == "decode"]
     assert len(dev) == 1
@@ -172,10 +178,8 @@ def test_decode_step_is_one_device_step_and_a_wait_per_entry(driven):
                                "program": driven["programs"]["decode"]}
     locks = [s for s in spans if s["name"] == "lock_wait" and s["attrs"]["kind"] == "decode"]
     assert len(locks) == 2
-    if driven["hold"]:  # each from its own submit, for as long as the lock was held
-        assert all(HELD_S <= lk["t1"] - lk["t0"] < HELD_S + 0.5 for lk in locks)
-    else:  # one wait of the flusher, stamped for both
-        assert locks[0]["t0"] == locks[1]["t0"]
+    # each from its own submit, for as long as the device was held
+    assert all(HELD_S <= lk["t1"] - lk["t0"] < HELD_S + 0.5 for lk in locks)
     waits = [s for s in spans if s["name"] == "batch_wait"]
     assert sorted(w["attrs"]["flusher"] for w in waits) == [0, 1]
     copies = [k for c in calls for k in children(spans, c) if k["name"] == "copy_out"]
@@ -199,10 +203,9 @@ def test_window_counters_are_fed_by_the_same_stamps(driven):
     waits = [s for s in driven["spans"] if s["name"] == "batch_wait"]
     assert st["queue_wait_ms_sum"] == pytest.approx(
         sum((w["t1"] - w["t0"]) * 1e3 for w in waits), abs=0.01)
-    if driven["hold"]:  # nobody was expected: the whole wait was for the lock
-        assert st["queue_wait_ms_sum"] < 50
-    else:
-        assert st["queue_wait_ms_sum"] >= 300  # the flusher sat out its 400 ms window
+    # nobody was expected: the whole wait was for the lock, none of it the
+    # 400 ms the window starts its turn estimate from
+    assert st["queue_wait_ms_sum"] < 50
 
 
 def test_pipeline_counters_count_ticks_and_live_slots(mesh):
@@ -235,7 +238,7 @@ def test_mesh_engine_compiles_are_journaled(mesh):
 
 def test_trace_off_records_nothing_and_changes_no_result(driven, monkeypatch):
     monkeypatch.setenv("INFERD_TRACE", "0")
-    rec, out = drive(driven["ex"], prefix="off-", hold=driven["hold"])
+    rec, out = drive(driven["ex"], driven["hold"], prefix="off-")
     assert rec.spans() == []
     for key, logits in driven["out"].items():
         np.testing.assert_array_equal(out[key], logits)

@@ -6,6 +6,8 @@ single-process engine token for token, sessions must map to cache slots
 with eviction, and the protocol guards must hold."""
 
 import asyncio
+import threading
+import time
 
 import jax
 import numpy as np
@@ -238,14 +240,55 @@ def test_boundary_chunk_fills_cache_exactly(mesh_parts, devices8):
     np.testing.assert_allclose(out_b["logits"], ref["logits"], rtol=2e-5, atol=2e-5)
 
 
+def hold_flusher(ex):
+    """Keep the decode flusher from the mesh, as a prefill holding `_lock`
+    would, until the returned function is called. The executor admits a
+    call under the lock its passes run under, so a held `_lock` would keep
+    the entries out of the window as well: the flusher is stopped on its
+    way to the lock instead, and whoever arrives meanwhile is pending."""
+    gate = threading.Event()
+    run = ex._batcher._run_batch
+
+    def gated(entries):
+        gate.wait(timeout=60)
+        run(entries)
+
+    def release():
+        ex._batcher._run_batch = run
+        gate.set()
+
+    ex._batcher._run_batch = gated
+    return release
+
+
+def _prefill(ex, sid, ids):
+    r = ex.process(sid, {"tokens": [ids], "start_pos": 0, "real_len": len(ids)})
+    return np.asarray(r["logits"])[0]
+
+
+def _decode(ex, sid, tok, pos):
+    r = ex.process(sid, {"tokens": [[tok]], "start_pos": pos, "real_len": 1})
+    return np.asarray(r["logits"])[0]
+
+
+def _together(ex, fns):
+    """Run `fns` on a thread each; their first decode steps are all pending
+    before the flusher gets the mesh."""
+    threads = [threading.Thread(target=fn) for fn in fns]
+    release = hold_flusher(ex)
+    for t in threads:
+        t.start()
+    while len(ex._batcher._pending) < len(fns):
+        time.sleep(0.001)
+    release()
+    for t in threads:
+        t.join(timeout=120)
+
+
 def test_mesh_decode_steps_coalesce(mesh_parts):
     """Co-arriving sessions' decode steps must share ONE pipeline pass
-    (engine.step_slots) — driven directly with threads + barrier so
-    co-arrival is guaranteed, and results must match solo slot steps."""
-    import threading
-
-    import numpy as np
-
+    (engine.step_slots): all three are pending when the flusher gets the
+    mesh, and results must match solo slot steps."""
     from inferd_tpu.runtime.mesh_executor import MeshExecutor
 
     parts, params = mesh_parts
@@ -253,40 +296,145 @@ def test_mesh_decode_steps_coalesce(mesh_parts):
         TINY, params, MeshPlan(pp=2), num_slots=4, max_len=64,
         devices=jax.devices()[:2],
     )
-    ex._batcher.window_s = 0.1  # plenty for barrier-released peers
-
-    sessions = [f"ms{i}" for i in range(3)]
-    last = {}
-    for i, s in enumerate(sessions):
-        r = ex.process(s, {"tokens": [[3 + i, 7, 11]], "start_pos": 0, "real_len": 3})
-        last[s] = int(np.asarray(r["logits"])[0].argmax())
-
-    hwm = {"n": 0}
-
-    class TrackingList(list):
-        def append(self, item):
-            super().append(item)
-            hwm["n"] = max(hwm["n"], len(self))
-
-    ex._batcher._pending = TrackingList(ex._batcher._pending)
-
-    barrier = threading.Barrier(len(sessions))
+    prompts = {f"ms{i}": [3 + i, 7, 11] for i in range(3)}
+    solo = {}
+    for s, ids in prompts.items():  # one session at a time: passes of one
+        tok = int(_prefill(ex, "solo", ids).argmax())
+        solo[s] = (tok, _decode(ex, "solo", tok, 3))
+        ex.end_session("solo")
+    for s, ids in prompts.items():
+        assert int(_prefill(ex, s, ids).argmax()) == solo[s][0]
+    before = ex.stats()
     results = {}
-
-    def step(s):
-        barrier.wait()
-        results[s] = ex.process(
-            s, {"tokens": [[last[s]]], "start_pos": 3, "real_len": 1}
-        )
-
-    threads = [threading.Thread(target=step, args=(s,)) for s in sessions]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(timeout=120)
+    _together(ex, [
+        lambda s=s: results.update({s: _decode(ex, s, solo[s][0], 3)})
+        for s in prompts
+    ])
     assert len(results) == 3
-    assert hwm["n"] >= 2, "no decode step ever coalesced >1 session"
-    assert ex.stats()["batched_tokens"] >= 3
+    after = ex.stats()
+    assert after["batched_steps"] - before["batched_steps"] == 1
+    assert after["batched_tokens"] - before["batched_tokens"] == 3
+    for s in prompts:
+        np.testing.assert_allclose(results[s], solo[s][1], rtol=2e-5, atol=2e-5)
+
+
+@pytest.fixture(scope="module")
+def mesh8(mesh_parts):
+    """Eight slots over pp=2, as the four-chip cell runs them over pp=4."""
+    from inferd_tpu.runtime.mesh_executor import MeshExecutor
+
+    parts, params = mesh_parts
+    return MeshExecutor(
+        TINY, params, MeshPlan(pp=2), num_slots=8, max_len=64,
+        devices=jax.devices()[:2], window_ms=400.0,
+    )
+
+
+def test_mesh_pass_takes_every_live_slot(mesh8):
+    """Eight threads on eight slots, four tokens each: the pass formed
+    while the mesh was held carries all eight, the passes after it wait for
+    whom the last ones served, and every session reads what it reads alone."""
+    ex, steps = mesh8, 4
+    prompts = {f"e{i}": [3 + i, 7, 11 + i] for i in range(8)}
+    solo = {}
+    for s, ids in prompts.items():
+        toks, rows = [int(_prefill(ex, "solo", ids).argmax())], []
+        for k in range(steps):
+            rows.append(_decode(ex, "solo", toks[-1], 3 + k))
+            toks.append(int(rows[-1].argmax()))
+        solo[s] = (toks, rows)
+        ex.end_session("solo")
+    for s, ids in prompts.items():
+        _prefill(ex, s, ids)
+    before = ex.stats()
+    rows = {s: [] for s in prompts}
+
+    def session(s, ks):
+        for k in ks:
+            rows[s].append(_decode(ex, s, solo[s][0][k], 3 + k))
+
+    try:
+        _together(ex, [lambda s=s: session(s, [0]) for s in prompts])
+        one = ex.stats()
+        threads = [threading.Thread(target=session, args=(s, range(1, steps)))
+                   for s in prompts]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        after = ex.stats()
+    finally:
+        for s in prompts:
+            ex.end_session(s)
+    assert one["batched_steps"] - before["batched_steps"] == 1
+    assert one["batched_tokens"] - before["batched_tokens"] == 8
+    assert after["batched_tokens"] - before["batched_tokens"] == 8 * steps
+    # the later passes form on their own: a wake-up swap behind a running
+    # pass gives passes of one or two
+    passes = after["batched_steps"] - before["batched_steps"]
+    assert 8 * steps / passes >= 4
+    assert after["gang_full"] > before["gang_full"]
+    for s in prompts:
+        for got, want in zip(rows[s], solo[s][1]):
+            np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("leaves", ["ends", "restarts", "replays_a_chunk"])
+def test_mesh_pass_does_not_wait_for_a_slot_that_left(mesh8, leaves):
+    """A session that a pass served and that then ends, or goes back into
+    a prefill, is not waited for: the next pass forms with whoever is left
+    and no formation runs into the cap. (`idle` only keeps a co-arrival
+    possible; no pass has served it, so nobody waits for it either.)"""
+    ex = mesh8
+    prompts = {f"{leaves}{i}": [5 + i, 13, 17 + i, 2] for i in range(3)}
+    stays, goes, idle = prompts
+    tok = {s: int(_prefill(ex, s, ids).argmax()) for s, ids in prompts.items()}
+    try:
+        rows = {}
+        _together(ex, [
+            lambda s=s: rows.update({s: _decode(ex, s, tok[s], 4)})
+            for s in (stays, goes)
+        ])
+        assert len(rows) == 2
+        before = ex.stats()
+        if leaves == "ends":
+            ex.end_session(goes)
+        elif leaves == "restarts":
+            _prefill(ex, goes, prompts[goes])
+        else:  # the client lost a reply and sends the chunk's tail again
+            ex.process(goes, {"tokens": [prompts[goes][2:]], "start_pos": 2, "real_len": 2})
+        _decode(ex, stays, int(rows[stays].argmax()), 5)
+        after = ex.stats()
+    finally:
+        for s in prompts:
+            ex.end_session(s)
+    assert after["gang_timeout"] == before["gang_timeout"]
+    assert after["gang_full"] - before["gang_full"] == 1
+    assert after["batched_steps"] - before["batched_steps"] == 1
+    assert after["batched_tokens"] - before["batched_tokens"] == 1
+
+
+@pytest.mark.parametrize("flags,workers", [
+    ({"mesh_plan": MeshPlan(pp=2), "mesh_slots": 8}, 9),
+    ({"mesh_plan": MeshPlan(pp=2), "mesh_slots": 3}, 4),
+    ({"batch_lanes": 4}, 5),
+    ({}, 2),
+])
+def test_worker_pool_admits_a_thread_per_session(mesh_parts, devices8, flags, workers):
+    """The node's pool has a thread for every session its executor can have
+    in a step, and one for a prefill: slots under --mesh, lanes under
+    --batch-lanes, two for a plain stage."""
+    parts, _params = mesh_parts
+    info = NodeInfo(
+        name="pool", host="127.0.0.1", port=BASE + 90,
+        stage=0, num_stages=1, model_name="tiny",
+    )
+    dht = SwarmDHT(info.node_id, BASE + 190, bootstrap=[], host="127.0.0.1")
+    node = Node(info, TINY, parts, dht, backend="qwen3", max_len=64, **flags)
+    try:
+        assert node.scheduler._pool._max_workers == workers
+    finally:
+        node.scheduler.shutdown()
 
 
 def test_mesh_executor_handoff_roundtrip(mesh_parts, devices8):
