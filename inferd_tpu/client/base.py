@@ -248,6 +248,9 @@ class GenerationClient:
         # (the node's /generate loop, client.local_client) swaps in its own recorder so
         # all of a node's spans land in one JSONL file.
         self.tracer = tracelib.SpanRecorder(service="client")
+        # the served model's block length: 1 generates a token a step, > 1
+        # by blocks (_generate_blocks). None = not yet asked of the node
+        self._block: Optional[int] = None
 
     async def __aenter__(self):
         self._http = ClientSession(timeout=ClientTimeout(total=self.timeout_s))
@@ -270,6 +273,18 @@ class GenerationClient:
     ) -> np.ndarray:
         """One pipeline pass; returns last-token logits [V]."""
         raise NotImplementedError
+
+    async def _forward(
+        self, session_id: str, tokens: List[int], start_pos: int, **extra
+    ) -> Dict[str, Any]:
+        """One pipeline pass with further payload keys (`block`,
+        `want_logits`); returns the last stage's result as it is."""
+        raise NotImplementedError
+
+    async def _block_length(self) -> int:
+        """The served model's block length as its node reports it (asked
+        once); a transport that cannot ask serves token by token."""
+        return self._block or 1
 
     async def _end_session(self, session_id: str) -> None:
         raise NotImplementedError
@@ -346,6 +361,8 @@ class GenerationClient:
         re-prefilling the prefix (the shared-system-prompt serving win).
         Pinned sessions are dropped on client exit."""
         ids = prefixlib.normalize_ids(prefix_ids)
+        if await self._block_length() > 1:
+            raise ValueError("a model generated by blocks serves no pinned prefix")
         if ids in self._pins:
             self._pins.move_to_end(ids)
             return
@@ -543,6 +560,12 @@ class GenerationClient:
         top_n: int = 0,
         top_sink: Optional[List] = None,
     ) -> List[int]:
+        blk = await self._block_length()
+        if blk > 1:
+            return await self._generate_blocks(
+                blk, prompt_ids, max_new_tokens, eos_token_id, seed,
+                sampling or self.sampling, on_token, logprob_sink, top_n, top_sink,
+            )
         session_id = str(uuid.uuid4())
         rng = np.random.default_rng(seed)
         s = sampling or self.sampling
@@ -616,6 +639,70 @@ class GenerationClient:
                     top_sink.append(top_logprobs_np(logits, top_n))
                 if on_token is not None:
                     await _emit(on_token, tok)
+        finally:
+            try:
+                await self._end_session(session_id)
+            except Exception:
+                pass  # best effort: nodes TTL-sweep orphaned sessions
+        return out
+
+    async def _generate_blocks(
+        self, blk: int, prompt_ids: List[int], max_new_tokens: int,
+        eos_token_id: Optional[int], seed: int, s: SamplingConfig,
+        on_token, logprob_sink, top_n: int, top_sink,
+    ) -> List[int]:
+        """The loop for a model generated by blocks of `blk`: the prompt's
+        whole blocks are ingested in chunks (whose logits nobody reads: none
+        is asked for), the tokens left over open the first block, and every
+        further hop carries one block and is answered with its tokens,
+        chosen on the device under `s` and the session's key chain, with
+        the log-probabilities of the pass that made each known. Tokens are
+        emitted in order; the loop stops at `max_new_tokens` (the last
+        block's surplus is dropped) and at `eos_token_id` inside a block
+        (what follows it is dropped)."""
+        session_id = str(uuid.uuid4())
+        out: List[int] = []
+        for sink in (logprob_sink, top_sink):
+            if sink is not None:
+                sink.clear()  # deterministic restarts re-fill
+        want = {
+            "sampling": {"temperature": s.temperature, "top_k": s.top_k,
+                         "top_p": s.top_p, "min_p": s.min_p},
+            "logprobs": logprob_sink is not None,
+            "top_logprobs": top_n if top_sink is not None else 0,
+        }
+        try:
+            whole = len(prompt_ids) // blk * blk
+            chunk = max(blk, self.prefill_chunk // blk * blk)
+            for pos in range(0, whole, chunk):
+                toks = prompt_ids[pos : min(pos + chunk, whole)]
+                with self.tracer.span(
+                    "step", "wire", attrs={"start_pos": pos, "n": len(toks)}
+                ):
+                    await self._forward(session_id, toks, pos, want_logits=False)
+            pos, head, key = whole, prompt_ids[whole:], None
+            while len(out) < max_new_tokens and (not out or out[-1] != eos_token_id):
+                call = dict(want, known=len(head),
+                            **({"seed": seed} if key is None else {"key": key}))
+                with self.tracer.span("step", "wire", attrs={"start_pos": pos, "n": blk}):
+                    res = await self._forward(
+                        session_id, head + [0] * (blk - len(head)), pos, block=call
+                    )
+                for j in range(len(head), blk):
+                    tok = int(res["tokens"][0][j])
+                    out.append(tok)
+                    if logprob_sink is not None:
+                        logprob_sink.append(float(res["logprobs"][j]))
+                    if top_sink is not None:
+                        top_sink.append((
+                            [int(i) for i in res["top_ids"][j][:top_n]],
+                            [float(x) for x in res["top_lps"][j][:top_n]],
+                        ))
+                    if on_token is not None:
+                        await _emit(on_token, tok)
+                    if len(out) >= max_new_tokens or tok == eos_token_id:
+                        break
+                pos, head, key = pos + blk, [], res["key"]
         finally:
             try:
                 await self._end_session(session_id)

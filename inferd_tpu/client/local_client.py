@@ -28,10 +28,11 @@ class LocalClient(SwarmClient):
 
     def __init__(
         self, serve: Callable[[str, Dict[str, Any]], Awaitable[Any]],
-        addr: Tuple[str, int], timeout_s: float,
+        addr: Tuple[str, int], timeout_s: float, block_length: int = 1,
     ):
         super().__init__([addr], timeout_s=timeout_s)
         self._serve = serve
+        self._block = block_length  # the node loaded the model: nothing to ask
 
     async def __aenter__(self):
         return self  # no HTTP session: nothing here opens a connection

@@ -108,14 +108,34 @@ class SwarmClient(GenerationClient):
             **deadline_wire(),
         })
 
+    async def _forward(
+        self, session_id: str, tokens: List[int], start_pos: int, **extra
+    ) -> Dict[str, Any]:
+        env = self._forward_env(session_id, tokens, start_pos)
+        env["payload"].update(extra)
+        return (await self._post("/forward", env))["result_for_user"]
+
     async def _step(
         self, session_id: str, tokens: List[int], start_pos: int
     ) -> np.ndarray:
-        resp = await self._post(
-            "/forward", self._forward_env(session_id, tokens, start_pos)
-        )
-        result = resp["result_for_user"]
+        result = await self._forward(session_id, tokens, start_pos)
         return np.asarray(result["logits"])[0]
+
+    async def _block_length(self) -> int:
+        """/stats `model.block_length` of the first entry node that answers;
+        a node that says nothing of its model serves token by token."""
+        if self._block is not None:
+            return self._block
+        assert self._http is not None, "use `async with <client>(...)`"
+        for host, port in self.entry_nodes:
+            try:
+                async with self._http.get(f"http://{host}:{port}/stats") as r:
+                    model = (await r.json()).get("model") or {}
+                self._block = int(model.get("block_length", 1))
+                return self._block
+            except (OSError, asyncio.TimeoutError, aiohttp.ClientError, ValueError):
+                continue  # the generation's own hop reports an entry that is down
+        return 1
 
     async def _end_session(self, session_id: str) -> None:
         await self._post("/end_session", {"session_id": session_id, "stage": 0})

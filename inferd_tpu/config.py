@@ -142,9 +142,47 @@ class ModelConfig:
     routed_scaling_factor: float = 1.0
     first_k_dense_replace: int = 0
 
+    # Generation by diffusion over blocks (the SDAR family; all absent
+    # elsewhere):
+    #   block_length   — > 1: positions are grouped in blocks of this many.
+    #                    Attention is bidirectional inside a block and
+    #                    causal across blocks, the logits row at position p
+    #                    speaks of the token AT p, and a block is generated
+    #                    as a whole: `denoising_steps` passes over its
+    #                    places, each making block_length / denoising_steps
+    #                    of the masked ones known, then one pass over the
+    #                    known block that writes its keys and values. 1 is
+    #                    the autoregressive decoder
+    #   remask         — which masked places a pass makes known:
+    #                    "sequential" (the leftmost) or "low_confidence"
+    #                    (those whose chosen token is most probable)
+    #   mask_token_id  — what a place not yet known holds
+    block_length: int = 1
+    denoising_steps: int = 1
+    remask: str = "sequential"
+    mask_token_id: int = 0
+
+    def __post_init__(self):
+        if self.block_length > 1:
+            if self.block_length % self.denoising_steps:
+                raise ValueError(
+                    f"{self.name}: denoising_steps {self.denoising_steps} must "
+                    f"divide block_length {self.block_length}"
+                )
+            if self.remask not in ("sequential", "low_confidence"):
+                raise ValueError(f"{self.name}: unknown remask {self.remask!r}")
+            if self.is_mla or self.sliding_window:
+                raise ValueError(
+                    f"{self.name}: block generation runs on global GQA layers only"
+                )
+
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_block_diffusion(self) -> bool:
+        return self.block_length > 1
 
     @property
     def is_mla(self) -> bool:
@@ -505,6 +543,19 @@ QWEN3_MOE_30B_A3B = ModelConfig(
     moe_intermediate_size=768,
 )
 
+# SDAR-30B-A3B-Chat (JetLM/SDAR-30B-A3B-Chat config.json, `sdar_moe`): the
+# Qwen3-MoE decoder layer at the widths above under a block-causal mask,
+# generated block by block. Block length, schedule, order and mask token are
+# not in the published config (benchmark/configs/sdar-30b-a3b-1chip.json
+# lists them as assumed). The -7l preset is the same model cut to its first
+# 7 layers: what one v5e chip holds at the published widths.
+SDAR_30B_A3B = dataclasses.replace(
+    QWEN3_MOE_30B_A3B, name="sdar-30b-a3b", max_position_embeddings=32768,
+    block_length=4, denoising_steps=2, mask_token_id=151669,
+)
+
+SDAR_30B_A3B_7L = dataclasses.replace(SDAR_30B_A3B.with_layers(7), name="sdar-30b-a3b-7l")
+
 # DeepSeek-V2-Lite (deepseek-ai/DeepSeek-V2-Lite config.json): latent
 # attention without query compression (q_lora_rank null), one dense layer,
 # then 26 layers of 64 routed experts (softmax over all, greedy top-6, not
@@ -585,6 +636,11 @@ TINY_MOE = dataclasses.replace(
     moe_intermediate_size=32,
 )
 
+TINY_SDAR = dataclasses.replace(
+    TINY_MOE, name="tiny-sdar", tie_word_embeddings=False,
+    block_length=4, denoising_steps=2, mask_token_id=255,
+)
+
 TINY_QWEN2 = dataclasses.replace(
     TINY, name="tiny-qwen2", qk_norm=False, attn_bias=True
 )
@@ -645,11 +701,14 @@ PRESETS = {
         GPT_OSS_20B,
         GPT_OSS_120B,
         QWEN3_MOE_30B_A3B,
+        SDAR_30B_A3B,
+        SDAR_30B_A3B_7L,
         DEEPSEEK_V2_LITE,
         DEEPSEEK_V2_LITE_8L,
         BENCH_PIPE,
         TINY,
         TINY_MOE,
+        TINY_SDAR,
         TINY_QWEN2,
         TINY_LLAMA,
         TINY_GEMMA2,

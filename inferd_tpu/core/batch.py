@@ -94,6 +94,7 @@ class BatchedEngine:
 
         sc = self.sampling
         L = lanes
+        B = cfg.block_length
         # the dense-lane decode program also returns the experts each lane
         # chose (the third value of models/qwen3.forward_cached)
         self.routes = routes = cfg.is_moe and block_size == 0 and cfg.sliding_window == 0
@@ -225,6 +226,93 @@ class BatchedEngine:
             )
             return _lane_write(cache, lane, nc), logits[0, n - 1]
 
+        @partial(jax.jit, donate_argnames=("cache",),
+                 static_argnames=("temperature", "top_k", "top_p", "min_p", "top_n"))
+        def _block_step(params, cache: KVCache, toks, known, lengths, live, keys,
+                        temperature: float = 0.0, top_k: int = 0, top_p: float = 1.0,
+                        min_p: float = 0.0, top_n: int = 0):
+            """One block of every lane in ONE dispatch (a model generated by
+            blocks, cfg.is_block_diffusion): `cfg.denoising_steps` passes
+            over the block's B places, then the commit pass over the known
+            block, each a forward_cached of [L, B] rows at `lengths` +
+            arange(B). A denoising pass writes its rows' keys and values
+            beyond the frontier like any chunk; the commit overwrites them;
+            nothing reads beyond a lane's frontier + B.
+
+            toks [L, B] and known [L, B] bool: the places the caller filled
+            (a first block opened by prompt tokens); what `toks` holds
+            elsewhere is not read. A pass picks a token at every place
+            still masked (argmax at temperature 0, else core.sampling.sample
+            under the lane's key) and makes B / steps of them known: the
+            leftmost (`remask` "sequential") or those whose token is most
+            probable ("low_confidence"). A pass with nothing left to make
+            known changes nothing. With `top_n` > 0 each place's
+            log-probability and top-n are those of the pass that made it
+            known. Lanes not `live` compute and are discarded by the caller;
+            theirs are written at most B short of the buffer's end (a full
+            lane's frontier would clamp the write back over rows of another
+            position).
+
+            Returns (cache, tokens [L, B], made-known-at pass [L, B] (-1: by
+            the caller), keys' [L, 2], log-probabilities [L, B], top ids
+            [L, B, n], top log-probabilities [L, B, n], the experts every
+            row chose in every pass [passes, Ls, L, B, K])."""
+            steps = cfg.denoising_steps
+            per = B // steps
+            lengths = jnp.where(live, lengths, jnp.minimum(lengths, cache.max_len - B))
+            pos = lengths[:, None] + jnp.arange(B)[None, :]
+            end = lengths + B
+            x = jnp.where(known, toks, jnp.int32(cfg.mask_token_id))
+            at = jnp.where(known, -1, steps).astype(jnp.int32)
+            lps = jnp.zeros((L, B), jnp.float32)
+            tis = jnp.zeros((L, B, top_n), jnp.int32)
+            tls = jnp.zeros((L, B, top_n), jnp.float32)
+            chosen = []
+            for step in range(steps):
+                with jax.named_scope("block_denoise"):
+                    logits, cache, topi = qwen3.forward_cached(
+                        params, cfg, x, pos, cache, lengths, real_end=end
+                    )
+                chosen.append(topi)
+                with jax.named_scope("block_select"):
+                    rows = logits.reshape(L * B, -1)
+                    if temperature == 0.0:
+                        tok = jnp.argmax(rows, axis=-1)
+                    else:
+                        pairs = jax.vmap(jax.random.split)(keys)  # [L, 2, 2]
+                        keys = pairs[:, 0]
+                        subs = jax.vmap(lambda k: jax.random.split(k, B))(pairs[:, 1])
+                        tok = jax.vmap(
+                            lambda l, k: samplib.sample(
+                                l[None], k, temperature, top_k, top_p, min_p)[0]
+                        )(rows, subs.reshape(L * B, 2))
+                    tok = tok.astype(jnp.int32)
+                    confident = cfg.remask == "low_confidence"
+                    if top_n or confident:
+                        lp, ti, tl = samplib.logprob_topn(rows, tok, top_n)
+                        lp = lp.reshape(L, B)
+                    # the B / steps best of the places still masked: the
+                    # leftmost, or the surest of their token
+                    score = lp if confident else -jnp.arange(B, dtype=jnp.float32)[None, :]
+                    score = jnp.where(known, -jnp.inf, jnp.broadcast_to(score, (L, B)))
+                    _, idx = jax.lax.top_k(score, per)
+                    newly = jnp.zeros((L, B), bool).at[jnp.arange(L)[:, None], idx].set(True)
+                    newly &= ~known
+                    x = jnp.where(newly, tok.reshape(L, B), x)
+                    at = jnp.where(newly, step, at)
+                    if top_n:
+                        lps = jnp.where(newly, lp, lps)
+                        tis = jnp.where(newly[..., None], ti.reshape(L, B, top_n), tis)
+                        tls = jnp.where(newly[..., None], tl.reshape(L, B, top_n), tls)
+                    known = known | newly
+            with jax.named_scope("block_commit"):
+                _, cache, topi = qwen3.forward_cached(
+                    params, cfg, x, pos, cache, lengths, real_end=end
+                )
+            chosen.append(topi)
+            return (cache, x, at, keys, lps, tis, tls,
+                    jnp.stack(chosen) if routes else None)
+
         @partial(jax.jit, donate_argnames=("cache",), static_argnames=("m",))
         def _fork_lane(cache: KVCache, src, dst, m: int):
             """Copy the first m KV slots of lane `src` into lane `dst`
@@ -298,6 +386,7 @@ class BatchedEngine:
         self._decode_k_serve = _decode_k_serve
         self._decode_logits = _decode_logits
         self._prefill_lane_logits = _prefill_lane_logits
+        self._block_step = _block_step
         self._decode_logits_paged = _decode_logits_paged
         self._prefill_lane_logits_paged = _prefill_lane_logits_paged
         self._copy_blocks = _copy_blocks

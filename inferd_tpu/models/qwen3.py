@@ -647,6 +647,10 @@ def _attend_update_lanes(cfg, q, k, v, q_positions, entry, ctx, window, sinks):
         k=_lanes_write(entry.k, k, ctx.write_pos), v=_lanes_write(entry.v, v, ctx.write_pos)
     )
     end = ctx.write_pos + s
+    if cfg.is_block_diffusion and ctx.real_end is not None:
+        # a query sees to the end of its block, so the bucket's padding is
+        # kept out by the valid length (causality does it elsewhere)
+        end = ctx.real_end
     if isinstance(window, int) and window > 0:
         k_att, v_att, kvpos, valid = _windowed_slice(new.k, new.v, end, window, s)
         return _attend(
@@ -734,7 +738,23 @@ def _gqa_attend_update(lp, cfg, x, cos, sin, q_positions, entry, ctx, window, ad
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     sinks = lp["sinks"] if cfg.attn_sinks else None
+    # RoPE above took the true positions; the mask of every layout below
+    # compares a slot's position with the last one the query sees
+    q_positions = visible_until(cfg, q_positions)
     return _ATTEND_UPDATE[type(entry)](cfg, q, k, v, q_positions, entry, ctx, window, sinks)
+
+
+def visible_until(cfg: ModelConfig, q_positions: jax.Array) -> jax.Array:
+    """The last position a query at `q_positions` attends to: its own for a
+    causal decoder, the last of its block for a model generated by blocks
+    (bidirectional inside a block, causal across: slot j is seen iff
+    j // B <= p // B). Every mask is `slot position <= this` under the valid
+    length, so with block_length 1 nothing is traced and the programs are
+    the causal ones."""
+    b = cfg.block_length
+    if b == 1:
+        return q_positions
+    return (q_positions // b + 1) * b - 1
 
 
 def _causal_mask(t: int, kv_valid_len, kv_positions, q_positions) -> jax.Array:

@@ -808,6 +808,11 @@ def flash_enabled(
     the upcast fuses into the score einsum) and the kernel route is the
     explicit impls / FORCE_FLASH only.
     """
+    if getattr(cfg, "is_block_diffusion", False):
+        # flash_gqa builds its mask from a causal diagonal (q_start + row);
+        # a model generated by blocks attends to the end of the query's
+        # block (models/qwen3.visible_until), which only the XLA path masks
+        return False
     if FORCE_FLASH is not None:
         return FORCE_FLASH
     impl = getattr(cfg, "attn_impl", "auto")

@@ -19,6 +19,11 @@ Concurrency design (process() runs on the node's worker thread pool):
     logits to its waiting thread (runtime/window.py, formation);
   * prefill chunks (multi-token or unknown session) run solo under the
     same device lock (per-lane cache writes, other lanes untouched);
+  * a model generated by blocks (cfg.is_block_diffusion) has a third kind
+    of call instead of the decode step: `block`, a whole block at a
+    frontier that is a multiple of the block length. Block calls of
+    different lanes ride ONE BatchedEngine._block_step through the same
+    window as decode steps do; the frontier advances by a block a call;
   * whole-model executor: is_first and is_last (tokens in, last-token
     logits out) — like MeshExecutor it hosts a 1-stage swarm topology.
 """
@@ -41,6 +46,7 @@ from inferd_tpu.obs import trace as tracelib
 from inferd_tpu.obs.devtel import program_name
 from inferd_tpu.obs.events import emit_safely
 from inferd_tpu.runtime.adapters import AdapterBindingMixin
+from inferd_tpu.runtime.executor import BlockCall, cache_intact, call_kind, parse_block
 from inferd_tpu.runtime.spec_serving import SpecForkMiss, SpecServing
 from inferd_tpu.runtime.window import WindowedBatcher
 from inferd_tpu.utils import lockwatch
@@ -99,13 +105,26 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         # windows interleave instead of stalling behind a long admission
         self.prefill_chunk = int(prefill_chunk)
         self.prefill_tokens = 0  # tokens actually computed by prefill
-        # cumulative expert-routing counters over decode steps (/stats
-        # `executor` `moe.*`); None where the decode program returns no
-        # routing (no experts, or a paged / windowed layout)
+        # cumulative expert-routing counters over decode steps, or over
+        # the passes of block steps (/stats `executor` `moe.*`); None where
+        # the program returns no routing (no experts, or a paged / windowed
+        # layout)
         self._moe = (
             dict(steps=0, assignments=0, assignments_hottest=0, experts_touched=0)
             if self.engine.routes else None
         )
+        # a model generated by blocks (/stats `executor` `diffusion.*`):
+        # dispatches, live lanes x passes, places made known (those the
+        # caller filled are not counted), rows computed
+        self._diffusion = (
+            dict(block_steps=0, lane_passes=0, tokens=0, rows=0)
+            if cfg.is_block_diffusion else None
+        )
+        if cfg.is_block_diffusion and (block_size or max_len % cfg.block_length):
+            raise ValueError(
+                f"{cfg.name}: block generation serves from dense lanes whose "
+                f"length is a multiple of the block ({cfg.block_length})"
+            )
         self.max_len = max_len
         self.ttl_s = session_ttl_s
 
@@ -138,6 +157,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             # of that expectation at once.
             swap_in_run=True,
             expect=lambda payload: payload[0],
+            kind="block" if cfg.is_block_diffusion else "decode",
         )
         self._spec_window_s = window_ms / 1e3
         # lane-batched speculation (enable_spec): None until enabled
@@ -493,8 +513,22 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
 
     def _process_inner(self, session_id: str, payload: Dict[str, Any],
                        toks, start_pos: int, real_len: int, acquired):
-        # one token at an established frontier; anything else is a prefill
-        decode = real_len == 1 and start_pos > 0
+        kind = call_kind(payload)
+        decode = kind == "decode"
+        blk = self.cfg.block_length
+        if self.cfg.is_block_diffusion:
+            if decode or start_pos % blk or real_len % blk or (
+                kind == "block" and real_len != blk
+            ):
+                raise ValueError(
+                    f"{self.cfg.name} is generated by blocks of {blk}: a prefill "
+                    f"chunk ingests whole blocks and a block call carries one, "
+                    f"at a multiple of {blk} (got {kind}, start_pos {start_pos}, "
+                    f"{real_len} tokens)"
+                )
+        elif kind == "block":
+            raise ValueError(f"{self.cfg.name} is not generated by blocks")
+        call = parse_block(payload, blk) if kind == "block" else None
         with self._mu:
             if self._inflight.get(session_id):
                 # a duplicate/replayed request racing the original would
@@ -571,12 +605,15 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 self.pool.ensure(lane, start_pos + k_req, owner=owner)
             self._bind_adapter_locked(session_id, lane, start_pos, acquired)
             self._inflight[session_id] = 1
-            if not decode:
+            if kind == "prefill":
                 # inside a prefill: no decode step waits for this lane
                 # until one has served it again
                 self._batcher.unexpect(lambda p, _lane=lane: p[0] == _lane)
 
         try:
+            if call is not None:
+                res = self._batcher.submit((lane, toks[0, :blk], call))
+                return {**res, "real_len": blk, "start_pos": start_pos}
             if decode:
                 from inferd_tpu.runtime.executor import parse_kstep
 
@@ -593,7 +630,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 saved = 0
             else:
                 logits, saved = self._prefill_solo(
-                    session_id, lane, toks, start_pos, real_len
+                    session_id, lane, toks, start_pos, real_len,
+                    want_logits=payload.get("want_logits", True),
                 )
         finally:
             with self._mu:
@@ -602,7 +640,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                     del self._dying[lane]
                     self._free_lane(lane)
         return {
-            "logits": logits[None, :],
+            # None where the caller asked for none (`want_logits` false: a
+            # chunk of a prompt whose last row nobody samples from)
+            "logits": None if logits is None else logits[None, :],
             "real_len": real_len,
             "start_pos": start_pos,
             # per-request shared-prefix saving (stage_batch contract):
@@ -621,7 +661,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         return self.engine.cache
 
     def _prefill_solo(self, session_id: str, lane: int, toks: np.ndarray,
-                      start: int, n: int):
+                      start: int, n: int, want_logits: bool = True):
         """Prompt ingestion: shared-prefix skip (paged — full blocks whose
         chained token hash is cached/pinned map read-only, zero prefill
         FLOPs for the shared region), then `prefill_chunk`-token
@@ -727,6 +767,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             # device lock, as it always was: the next step of another
             # session is dispatched while this chunk still runs
             logits.block_until_ready()
+        if not want_logits:
+            return None, saved
         # ONE boundary transfer: only the LAST chunk's logits are the
         # response — mid-chunk logits never leave the device
         with tracelib.region(self.tracer, "copy_out") as at:
@@ -770,14 +812,16 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         still stand."""
         import jax.numpy as jnp
 
-        from inferd_tpu.runtime.executor import (
-            cache_intact, fuse_kstep_group, kstep_hi,
-        )
+        from inferd_tpu.runtime.executor import fuse_kstep_group, kstep_hi
 
         poisoned: Optional[Exception] = None
         with self._dev_lock:
             # the batcher stamps each entry's lock_wait and batch_wait
             entries = self._batcher.drain_pending()
+            blocks = [e for e in entries if isinstance(e.payload[2], BlockCall)]
+            if blocks:  # a model generated by blocks has no other batched call
+                self._run_block_steps(blocks)
+                return
             legacy = [e for e in entries if e.payload[2] is None]
             kstep = [e for e in entries if e.payload[2] is not None]
             if legacy:
@@ -900,8 +944,96 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                     if not cache_intact(self.engine.cache):
                         poisoned = exc
 
+    def _run_block_steps(self, entries) -> None:
+        """The block calls of one drain (under _dev_lock): ONE
+        BatchedEngine._block_step for the lanes that share a sampling
+        config (static in the program; greedy callers are one group). A
+        failed dispatch fails its own entries (the isolation of
+        _run_decode_batch)."""
+        groups: Dict[tuple, list] = {}
+        for e in entries:
+            groups.setdefault(e.payload[2].sampling, []).append(e)
+        # the drain counted one step: every further group is one more
+        self._batcher.n_steps += len(groups) - 1
+        poisoned: Optional[Exception] = None
+        for sampling, grp in groups.items():
+            try:
+                if poisoned is not None:
+                    raise RuntimeError(
+                        "KV cache invalidated by an earlier dispatch failure "
+                        f"in this window: {poisoned}"
+                    )
+                self._block_dispatch(sampling, grp)
+            except Exception as exc:
+                for e in grp:
+                    e.error = exc
+                self._batcher.n_served -= len(grp)  # see _run_decode_batch
+                if not cache_intact(self.engine.cache):
+                    poisoned = exc
+
+    def _block_dispatch(self, sampling: tuple, grp) -> None:
+        """One block step for the lanes of `grp`, at the widest top-n any
+        of them asked: a lane's frontier advances by a block, its entry gets
+        the block's tokens, the pass that made each place known, the
+        session's next key and (where asked) the log-probabilities."""
+        import jax.numpy as jnp
+
+        eng, blk = self.engine, self.cfg.block_length
+        passes = self.cfg.denoising_steps + 1
+        with self._mu:
+            lens = list(eng.lengths)
+        toks = np.zeros((eng.lanes, blk), np.int32)
+        known = np.zeros((eng.lanes, blk), bool)
+        live = np.zeros((eng.lanes,), bool)
+        keys = np.zeros((eng.lanes, 2), np.uint32)
+        for e in grp:
+            lane, tokens, call = e.payload
+            toks[lane], live[lane], keys[lane] = tokens, True, call.key
+            known[lane, :call.known] = True
+        lanes = [e.payload[0] for e in grp]
+        made = int((~known[lanes]).sum())
+        t, tk, tp, mp = sampling
+        with tracelib.region(
+            self.tracer, "device", kind="block", tokens=made,
+            cobatch=len(grp), rows=len(grp) * blk * passes, passes=passes,
+            program=program_name(eng._block_step),
+        ):
+            eng.cache, *out, chosen = eng._block_step(
+                eng.params, eng.cache, jnp.asarray(toks), jnp.asarray(known),
+                jnp.asarray(lens, jnp.int32), jnp.asarray(live), jnp.asarray(keys),
+                temperature=t, top_k=tk, top_p=tp, min_p=mp,
+                top_n=max(e.payload[2].top_n for e in grp),
+            )
+            out[0].block_until_ready()
+        with tracelib.region(self.tracer, "copy_out") as at:
+            host = [np.asarray(a) for a in out]
+            at["bytes"] = sum(a.nbytes for a in host)
+        x, order, nkeys, lps, tis, tls = (a.tolist() for a in host)
+        if chosen is not None:  # came out with the tokens: no wait
+            routed = np.asarray(chosen)[:, :, lanes]  # [passes, layers, live, block, k]
+            for per_pass in routed.reshape(*routed.shape[:2], -1, routed.shape[-1]):
+                self._count_routing(per_pass)
+        with self._mu:
+            for lane in lanes:
+                eng.lengths[lane] += blk
+            d = self._diffusion
+            d["block_steps"] += 1
+            d["lane_passes"] += len(grp) * passes
+            d["tokens"] += made
+            d["rows"] += len(grp) * blk * passes
+        for e in grp:
+            lane = e.payload[0]
+            e.result = {
+                "tokens": [x[lane]],
+                "order": order[lane],  # the pass that made each place known (-1: the caller)
+                "key": nkeys[lane],
+                **({"logprobs": lps[lane], "top_ids": tis[lane], "top_lps": tls[lane]}
+                   if e.payload[2].top_n else {}),
+            }
+
     def _count_routing(self, chosen: np.ndarray) -> None:
-        """The `moe.*` counters of one decode step from the experts its
+        """The `moe.*` counters of one decode step (or one pass of a block
+        step) from the experts its
         live rows chose, `chosen` [sparse layers, live rows, K]: the
         assignments made, those that fell on each layer's most loaded
         expert, and the distinct experts hit, each summed over the layers."""
@@ -936,8 +1068,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         (refcounted, CoW on divergence) and queues a private copy of only
         the partial tail block — O(1) device work instead of a prefix-
         sized buffer copy."""
-        if prefix_len <= 0:
-            return False
+        if prefix_len <= 0 or self.cfg.is_block_diffusion:
+            return False  # a model generated by blocks forks nothing yet
         with self._mu:
             if self._session_adapter.get(parent_session_id):
                 # the fork flow admits the child WITHOUT an adapter key:
@@ -1429,6 +1561,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 out["kv_bytes_per_token"] = nbytes // (self.engine.lanes * self.max_len)
             if self._moe is not None:
                 out["moe"] = dict(self._moe, experts=self.cfg.num_experts)
+            if self._diffusion is not None:
+                out["diffusion"] = dict(self._diffusion)
             if self.adapters is not None:
                 out["adapters"] = self.adapters.stats()
             return out

@@ -25,7 +25,7 @@ import dataclasses
 import threading
 import time
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -158,6 +158,72 @@ def parse_kstep(payload: Dict[str, Any], budget: int):
         "eos": -1 if eos is None else int(eos),
         "key": np.asarray(key, np.uint32),
     }
+
+
+def call_kind(payload: Dict[str, Any]) -> str:
+    """What a /forward payload asks of a whole-model executor, by name:
+    "block" (a block of a model generated by blocks: its `block` key),
+    "decode" (one token at an established frontier) or "prefill" (anything
+    else). The name rides the `compute` and `device` spans, so no reader
+    infers a kind from sizes."""
+    if not isinstance(payload, dict):
+        return "prefill"
+    if payload.get("block") is not None:
+        return "block"
+    try:
+        x = payload.get("tokens")
+        if x is None:
+            x = payload.get("hidden")
+        n = payload.get("real_len")
+        n = np.shape(x)[1] if n is None else int(n)
+        decode = n == 1 and int(payload.get("start_pos", 0)) > 0
+    except Exception:
+        return "prefill"  # a malformed payload fails in the guarded compute
+    return "decode" if decode else "prefill"
+
+
+#: top log-probabilities a block step computes: none, or one of these widths
+#: (static in the program, so every width is one compiled variant; the node's
+#: warm-up compiles none and the first, what the benchmark's probe asks)
+BLOCK_TOP_WIDTHS = (8, 64)
+
+
+class BlockCall(NamedTuple):
+    """One lane's part of a block step (BatchedEngine._block_step)."""
+
+    known: int  # leading places of the block the caller filled
+    sampling: tuple  # (temperature, top_k, top_p, min_p): static in the program
+    top_n: int  # 0 = no log-probabilities, else a width of BLOCK_TOP_WIDTHS
+    key: Any  # uint32 [2]: the session's PRNG chain
+
+
+def parse_block(payload: Dict[str, Any], block_length: int) -> BlockCall:
+    """The `block` key of a /forward payload: {"known": leading places
+    filled, optional "sampling" / "key" / "seed" as a K-step call has them,
+    optional "top_logprobs": n (with "logprobs": true alone, the token's
+    own)}."""
+    b = payload["block"]
+    known = int(b.get("known", 0))
+    if not 0 <= known < block_length:
+        raise ValueError(f"block call: known {known} outside [0, {block_length})")
+    s = b.get("sampling") or {}
+    sampling = (
+        float(s.get("temperature", 0.0)), int(s.get("top_k", 0)),
+        float(s.get("top_p", 1.0)), float(s.get("min_p", 0.0)),
+    )
+    if sampling[0] == 0.0:
+        sampling = (0.0, 0, 1.0, 0.0)  # greedy reads no filter: one variant
+    want = max(int(b.get("top_logprobs", 0)), 1 if b.get("logprobs") else 0)
+    if want > BLOCK_TOP_WIDTHS[-1]:
+        raise ValueError(f"block call: top_logprobs {want} over {BLOCK_TOP_WIDTHS[-1]}")
+    key = b.get("key")
+    if key is None:
+        key = jax.random.PRNGKey(int(b.get("seed", 0) or 0))
+    return BlockCall(
+        known=known, sampling=sampling,
+        top_n=next((w for w in BLOCK_TOP_WIDTHS if w >= want), 0) if want else 0,
+        key=np.asarray(key, np.uint32),
+    )
 
 
 def cache_intact(cache) -> bool:

@@ -52,7 +52,7 @@ from inferd_tpu.parallel import stages as stagelib
 from inferd_tpu.parallel.mesh import MeshPlan
 from inferd_tpu.runtime import repl as repllib
 from inferd_tpu.runtime import wire
-from inferd_tpu.runtime.executor import make_executor
+from inferd_tpu.runtime.executor import BLOCK_TOP_WIDTHS, call_kind, make_executor
 from inferd_tpu.runtime.window import WindowedBatcher
 from inferd_tpu.utils import lockwatch
 from inferd_tpu.utils import retry as retrylib
@@ -89,7 +89,19 @@ def _warmup_executor(executor, journal=None) -> None:
             }
         else:
             payload = {"tokens": [[1]], "start_pos": 0, "real_len": 1}
-        executor.process(sid, payload)
+        blk = getattr(cfg, "block_length", 1)
+        if blk > 1:
+            # a model generated by blocks: a whole block in, then a block
+            # step without log-probabilities (what traffic asks) and one at
+            # the first top-n width (a variant each: runtime/executor.py)
+            executor.process(sid, {"tokens": [[1] * blk], "start_pos": 0, "real_len": blk})
+            for i, top in enumerate((0, BLOCK_TOP_WIDTHS[0])):
+                executor.process(sid, {
+                    "tokens": [[0] * blk], "start_pos": blk * (i + 1), "real_len": blk,
+                    "block": {"known": 0, "top_logprobs": top},
+                })
+        else:
+            executor.process(sid, payload)
         if hasattr(executor, "process_batch"):
             # stage-batch executors serve decode through a SEPARATE
             # co-batched jit — compile it too (it is the serving hot path)
@@ -1929,8 +1941,7 @@ class Node:
                     # how many tokens it SKIPPED — per-request memory-
                     # plane attribution in merged timelines
                     attrs={"stage": stage, "ms": round(pure_ms, 3),
-                           "kind": "decode" if _is_decode_step(_pl)
-                           else "prefill",
+                           "kind": call_kind(_pl),
                            "tokens": _committed_tokens(result),
                            **({"tokens_saved": saved} if saved else {})},
                 )
@@ -3817,6 +3828,9 @@ class Node:
             return self._error_response(400, f"bad generate request: {e}")
         if pin_len < 0 or pin_len > len(ids):
             return self._error_response(400, f"pin_prefix_len {pin_len} out of range")
+        if pin_len and self._block_length() > 1:
+            return self._error_response(
+                400, "pin_prefix_len: a model generated by blocks serves no pinned prefix")
         # optional end-to-end deadline on the WHOLE server-driven
         # generation (epoch ms, same key as the /forward envelopes): an
         # already-expired budget sheds here, and the regular token loop
@@ -3947,6 +3961,11 @@ class Node:
             payload["ignored_sampling_keys"] = ignored_keys
         return web.Response(body=wire.pack(payload))
 
+    def _block_length(self) -> int:
+        """The block length of the model this node loaded (1: a token a
+        step)."""
+        return int(getattr(getattr(self.executor, "cfg", None), "block_length", 1))
+
     async def _get_generate_client(self):
         """Lazy generation loop shared by all /generate requests
         (persistent so node-held prefix pins survive across requests):
@@ -3958,7 +3977,7 @@ class Node:
             if self._generate_client is None:
                 c = LocalClient(
                     self._serve_local, (self.info.host, self.info.port),
-                    timeout_s=self.hop_timeout_s,
+                    timeout_s=self.hop_timeout_s, block_length=self._block_length(),
                 )
                 # share the NODE's span ring: the loop's step/sample
                 # spans belong in this node's JSONL file, not a parallel
@@ -4891,6 +4910,9 @@ class Node:
             self.device, memory=devtellib.device_memory_stats()
         )
         snap["wire_codec"] = self.wire_codec
+        # what a client has to know of the model to drive it: a model
+        # generated by blocks is asked for blocks (client/base.py)
+        snap["model"] = {"name": self.info.model_name, "block_length": self._block_length()}
         if self.compile_cache is not None:
             snap["compile_cache"] = self.compile_cache.as_dict()
         snap["trace"] = self.tracer.stats()

@@ -82,10 +82,14 @@ class WindowedBatcher:
         swap_in_run: bool = False,
         gang_target: Optional[Callable[[], int]] = None,
         expect: Optional[Callable[[Any], Hashable]] = None,
+        kind: str = "decode",
     ):
         if expect is not None and not swap_in_run:
             raise ValueError("expect needs swap_in_run (the callback drains)")
         self.window_s = window_s
+        # what a formed step is called on its entries' `lock_wait` spans:
+        # "decode", or "block" for a model generated by blocks
+        self.kind = kind
         self._run_batch = run_batch
         self._co_possible = co_possible
         self._wait_timeout_s = wait_timeout_s
@@ -199,7 +203,7 @@ class WindowedBatcher:
                     t_wait = entry.t_lock
                     self.tracer.record_span(
                         "lock_wait", "lock_wait", entry.t_submit, t_wait,
-                        parent=entry.ctx, attrs={"kind": "decode"},
+                        parent=entry.ctx, attrs={"kind": self.kind},
                     )
                 self.tracer.record_span(
                     "batch_wait", "batch_wait", t_wait, entry.t_taken,

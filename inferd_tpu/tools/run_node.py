@@ -410,14 +410,30 @@ def parse_mesh(value: str):
     return plan
 
 
-def check_servable(cfg, args) -> None:
-    """The one place that refuses, by the model's name, every serving path
-    that cannot run a model with latent attention or with a leading dense
-    group: such a model is served whole from the dense lanes of
-    --batch-lanes, in its own dtype or --kv-dtype, and by nothing else."""
+def check_servable(cfg, args, num_stages: int = 1) -> None:
+    """The one place that refuses every serving path that cannot run the
+    model, with a sentence that names the path. A model generated by blocks
+    (cfg.is_block_diffusion) is served whole from the dense lanes of
+    --batch-lanes, in its own dtype, --kv-dtype or --quant, and by nothing
+    else: every other path steps a token a call. So is, by its name, a
+    model with latent attention or with a leading dense group."""
+    if cfg.is_block_diffusion:
+        _refuse(cfg, {
+            "--mesh (a pipeline pass steps one token a slot)": args.mesh,
+            "--stage-lanes (the relay's hop carries one token)": args.stage_lanes > 0,
+            "--paged-kv (a denoising pass writes beyond the frontier, which no "
+            "block chain covers)": args.paged_kv > 0,
+            "--spec-draft-layers (a draft proposes token by token)": args.spec_draft_layers > 0,
+            "--lora": bool(args.lora),
+            "--adapters (the block program takes no adapter)": bool(args.adapters),
+            "--standby-repl (the standby resumes token by token)": args.standby_repl,
+            "serving without --batch-lanes (only the lane executor runs a block step)":
+                args.backend == "qwen3" and args.batch_lanes <= 0,
+            "a manifest of several stages (a block step runs the whole model)": num_stages > 1,
+        })
     if not (cfg.is_mla or cfg.num_dense_layers):
         return
-    refused = {
+    _refuse(cfg, {
         "--mesh (no latent cache or layer groups under a mesh)": args.mesh,
         "--stage-lanes (a stage holds one group of layers)": args.stage_lanes > 0,
         "--paged-kv (the paged pool has no latent entry)": args.paged_kv > 0,
@@ -429,7 +445,10 @@ def check_servable(cfg, args) -> None:
         "--standby-repl (no handoff or standby export of a latent cache)": args.standby_repl,
         "serving without --batch-lanes (only the lane executor runs its layer groups)":
             args.backend == "qwen3" and args.batch_lanes <= 0,
-    }
+    })
+
+
+def _refuse(cfg, refused: dict) -> None:
     hit = [what for what, on in refused.items() if on]
     if hit:
         raise SystemExit(f"run_node: {cfg.name} cannot be served with " + "; ".join(hit))
@@ -468,7 +487,7 @@ async def _run(args, cache_stats=None) -> None:
             stage = 0
 
     cfg = manifest.config
-    check_servable(cfg, args)
+    check_servable(cfg, args, manifest.num_stages)
     if args.kv_dtype != "model":
         import dataclasses
 

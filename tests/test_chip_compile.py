@@ -283,3 +283,41 @@ def test_dsv2l_lane_programs_compile_and_decode_expands_no_head_over_the_cache(
     prefill = eng._prefill_lane_logits.lower(params, cache, chunk, i32, i32, i32).compile()
     mem = prefill.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
+
+
+def test_sdar_block_program_compiles_at_16_lanes(one_chip, no_compile_cache):
+    """The two programs a `--model sdar-30b-a3b-7l --batch-lanes 16 --max-len
+    4096` node runs, at the published widths: the block step (two denoising
+    passes and the commit over [16, 4] rows in one dispatch, with and
+    without the top log-probabilities) and a 512-token prefill chunk fit the
+    chip with the weights (9.97 GB) and 16 lanes x 4096 of keys and values
+    (0.94 GB); the numbers are the ones in the configuration's `deployment`
+    (benchmark/configs/sdar-30b-a3b-1chip.json)."""
+    from inferd_tpu.core.batch import BatchedEngine
+    from inferd_tpu.core.cache import KVCache
+    from inferd_tpu.models import qwen3
+
+    cfg = get_config("sdar-30b-a3b-7l")
+    lanes, max_len, blk = 16, 4096, cfg.block_length
+    params = _on(jax.eval_shape(lambda: qwen3.init_params(cfg, jax.random.PRNGKey(0))), one_chip)
+    eng = BatchedEngine(cfg, None, lanes=lanes, max_len=64)
+    cache = _on(jax.eval_shape(lambda: KVCache.create(cfg, cfg.num_layers, lanes, max_len)),
+                one_chip)
+    toks = _sds((lanes, blk), jnp.int32, one_chip)
+    known = _sds((lanes, blk), jnp.bool_, one_chip)
+    lens = _sds((lanes,), jnp.int32, one_chip)
+    live = _sds((lanes,), jnp.bool_, one_chip)
+    keys = _sds((lanes, 2), jnp.uint32, one_chip)
+    for top_n in (0, 8):
+        step = eng._block_step.lower(params, cache, toks, known, lens, live, keys,
+                                     top_n=top_n).compile()
+        mem = step.memory_analysis()
+        assert 10.8e9 < mem.argument_size_in_bytes < 11.0e9  # 9.97 GB of weights + 0.94 of cache
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+        # the cache is donated: its lanes are written in place
+        assert mem.alias_size_in_bytes > 0.9e9
+    i32 = _sds((), jnp.int32, one_chip)
+    chunk = _sds((1, 512), jnp.int32, one_chip)
+    prefill = eng._prefill_lane_logits.lower(params, cache, chunk, i32, i32, i32).compile()
+    mem = prefill.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
