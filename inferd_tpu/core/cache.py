@@ -15,11 +15,15 @@ models/qwen3.decoder_layer contract).
 This module owns the LAYOUTS. For one layer a cache entry is one value: none
 (cache-free), `DenseEntry`, `LatentEntry`, `RingEntry` or `PagedEntry`;
 stacked over layers they are what the one layer scan of
-models/qwen3.forward_layers scans. `KVCache` and `PagedKVCache` turn
-themselves into stacked entries (`entries`) and back (`with_entries`), and
-`ctx` gives what is the same for every layer (`CacheCtx`). The model file
-holds one write-then-read function per entry type and never takes a cache's
-arrays apart; a new layout is a new entry type here and one function there.
+models/qwen3.forward_layers CARRIES: a layer writes its chunk's rows into
+the stack at its own index and reads its slab as a view of the stack, so a
+donated cache is updated where it lies and no layer's slab is copied out or
+back. `KVCache` and `PagedKVCache` turn themselves into stacked entries
+(`entries`) and back (`with_entries`), and `ctx` gives what is the same for
+every layer (`CacheCtx`). The model file holds one write-then-read function
+per entry type, taking (the stacked entries, the layer's index), and never
+takes a cache's arrays apart; a new layout is a new entry type here and one
+function there.
 
 Sliding-window models (Gemma-2, GPT-OSS) additionally carry RING buffers
 `k_loc`/`v_loc` [num_sliding_layers, batch, ring, kv, d] for their sliding
@@ -71,8 +75,9 @@ def sliding_layer_ids(
 
 
 # ---------------------------------------------------------------------------
-# One layer's cache entry, by layout. The same types with a leading layer
-# axis on every array are the stacked entries the layer scan scans.
+# One layer's cache entry, by layout (the shapes in the comments). The same
+# types with a leading layer axis on every array are the stacked entries the
+# layer scan carries and the write-then-read functions take, with an index.
 # ---------------------------------------------------------------------------
 
 
@@ -258,20 +263,6 @@ def lane_slice(cache: KVCache, lane) -> KVCache:
         k_loc=None if cache.k_loc is None else sl(cache.k_loc),
         v_loc=None if cache.v_loc is None else sl(cache.v_loc),
     )
-
-
-def layer_slice(cache: KVCache, start: int, end: int) -> KVCache:
-    """Layers [start, end) of a uniform cache (one group of a model whose
-    layer stack comes in groups)."""
-    assert cache.k_loc is None
-    return KVCache(k=cache.k[start:end], v=cache.v[start:end], length=cache.length)
-
-
-def layer_write(cache: KVCache, start: int, part: KVCache) -> KVCache:
-    """Write a layer_slice-shaped cache back at layer `start` (in place
-    under donation, like lane_write)."""
-    up = lambda a, b: jax.lax.dynamic_update_slice_in_dim(a, b, start, axis=0)
-    return KVCache(k=up(cache.k, part.k), v=up(cache.v, part.v), length=cache.length)
 
 
 def lane_write(cache: KVCache, lane, nc: KVCache) -> KVCache:

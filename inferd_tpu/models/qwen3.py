@@ -538,7 +538,7 @@ _FAR_FUTURE = 1 << 30
 
 
 def _ring_attend_update(
-    cfg, q, k_new, v_new, q_positions, k_ring, v_ring, write_pos, real_end,
+    cfg, q, k_new, v_new, q_positions, k_rings, v_rings, at, write_pos, real_end,
     window: int, sinks,
 ):
     """Sliding-layer attention + update over an O(window) RING buffer.
@@ -566,11 +566,16 @@ def _ring_attend_update(
     slots by construction); rows at positions >= real_end (bucket padding)
     scatter to index R, which `mode="drop"` discards.
 
+    The rings are layer `at` of the sliding layers' stacks
+    [Ll, B, R, Nkv, D]: read as a view of the stack before the write, and
+    the kept rows scattered into the stack where it lies.
+
     write_pos/real_end: scalar or per-batch-row [B]. Returns
-    (attn [B, S, Nq*D], new_k_ring, new_v_ring).
+    (attn [B, S, Nq*D], new_k_rings, new_v_rings).
     """
     b, s = q.shape[0], q.shape[1]
-    r = k_ring.shape[1]  # k_ring: [B, R, Nkv, D]
+    k_ring, v_ring = _slab(k_rings, at), _slab(v_rings, at)  # [B, R, Nkv, D]
+    r = k_ring.shape[1]
     per_row = jnp.ndim(write_pos) == 1
     wp = write_pos if per_row else jnp.broadcast_to(jnp.asarray(write_pos), (b,))
     re = real_end if jnp.ndim(real_end) == 1 else jnp.broadcast_to(
@@ -601,10 +606,9 @@ def _ring_attend_update(
     slot = jnp.where(keep, pos % r, r)  # r = out of bounds -> dropped
     kc = _to_cache_dtype(k_new, k_ring.dtype)
     vc = _to_cache_dtype(v_new, v_ring.dtype)
-    upd = jax.vmap(
-        lambda buf, sl, ch: buf.at[sl].set(ch, mode="drop")
-    )
-    return attn, upd(k_ring, slot, kc), upd(v_ring, slot, vc)
+    rows = jnp.arange(b)[:, None]
+    upd = lambda rings, ch: rings.at[at, rows, slot].set(ch, mode="drop")
+    return attn, upd(k_rings, kc), upd(v_rings, vc)
 
 
 def _mask_only(window):
@@ -613,21 +617,39 @@ def _mask_only(window):
     return None if window is None else jnp.asarray(window, jnp.int32)
 
 
-def _lanes_write(buf, chunk, write_pos):
-    """A chunk [B, S, ...] written into dense lanes [B, T, ...] at
-    `write_pos`: a scalar, or [B] per row (continuous batching: lanes at
-    ragged fill levels advance in one step; the vmapped row updates lower
-    to a scatter)."""
-    chunk = _to_cache_dtype(chunk, buf.dtype)
-    if jnp.ndim(write_pos) == 1:
-        upd = jax.vmap(
-            lambda row, ch, p: jax.lax.dynamic_update_slice(row, ch, (p,) + (0,) * (row.ndim - 1))
+def _slab(stack, at):
+    """Layer `at` of a stacked cache array, as a view of the stack."""
+    return jax.lax.dynamic_index_in_dim(stack, at, 0, keepdims=False)
+
+
+def _lanes_write(stack, at, chunk, write_pos):
+    """A chunk [B, S, ...] written into layer `at` of stacked dense lanes
+    [L, B, T, ...] at `write_pos`: a scalar, or [B] per row (continuous
+    batching: lanes at ragged fill levels advance in one step: one scatter
+    of B windows [S, ...] at (at, b, write_pos[b]), its starts clamped as
+    dynamic_update_slice clamps them). Only the chunk's rows are written:
+    under donation the stack is updated where it lies."""
+    chunk = _to_cache_dtype(chunk, stack.dtype)
+    if jnp.ndim(write_pos) == 0:
+        return jax.lax.dynamic_update_slice(
+            stack, chunk[None], (at, 0, write_pos) + (0,) * (stack.ndim - 3)
         )
-        return upd(buf, chunk, write_pos)
-    return jax.lax.dynamic_update_slice(buf, chunk, (0, write_pos) + (0,) * (buf.ndim - 2))
+    b = chunk.shape[0]
+    rows = jnp.arange(b, dtype=jnp.int32)
+    starts = jnp.stack([jnp.full_like(rows, at), rows, write_pos.astype(jnp.int32)], axis=-1)
+    return jax.lax.scatter(
+        stack, starts, chunk,
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=tuple(range(1, chunk.ndim)),
+            inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1, 2),
+        ),
+        indices_are_sorted=True, unique_indices=True,
+        mode=jax.lax.GatherScatterMode.CLIP,
+    )
 
 
-def _attend_chunk(cfg, q, k, v, q_positions, entry, ctx, window, sinks):
+def _attend_chunk(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks):
     """No cache: the chunk attends to itself (prefill-style parity)."""
     attn = _attend(
         cfg, q, k, v, q_positions, jnp.int32(q.shape[1]),
@@ -636,53 +658,56 @@ def _attend_chunk(cfg, q, k, v, q_positions, entry, ctx, window, sinks):
     return attn, None
 
 
-def _attend_update_lanes(cfg, q, k, v, q_positions, entry, ctx, window, sinks):
-    """Dense lanes: write at ctx.write_pos, attend over the buffer. A
+def _attend_update_lanes(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks):
+    """Dense lanes: write at ctx.write_pos, attend over the layer's slab. A
     STATIC int window narrows the KV read to a window-covering slice
     (_windowed_slice, the sliding-layer read fast path); a traced window
     (or None) attends the whole buffer, mask-only; attention masks per
     row through the valid length where write_pos is per row."""
     s = q.shape[1]
     new = cachelib.DenseEntry(
-        k=_lanes_write(entry.k, k, ctx.write_pos), v=_lanes_write(entry.v, v, ctx.write_pos)
+        k=_lanes_write(entry.k, at, k, ctx.write_pos),
+        v=_lanes_write(entry.v, at, v, ctx.write_pos),
     )
+    new_k, new_v = _slab(new.k, at), _slab(new.v, at)
     end = ctx.write_pos + s
     if cfg.is_block_diffusion and ctx.real_end is not None:
         # a query sees to the end of its block, so the bucket's padding is
         # kept out by the valid length (causality does it elsewhere)
         end = ctx.real_end
     if isinstance(window, int) and window > 0:
-        k_att, v_att, kvpos, valid = _windowed_slice(new.k, new.v, end, window, s)
+        k_att, v_att, kvpos, valid = _windowed_slice(new_k, new_v, end, window, s)
         return _attend(
             cfg, q, k_att, v_att, q_positions, valid,
             kv_positions=kvpos, window=jnp.int32(window), sinks=sinks,
         ), new
-    return _attend(cfg, q, new.k, new.v, q_positions, end, window=window, sinks=sinks), new
+    return _attend(cfg, q, new_k, new_v, q_positions, end, window=window, sinks=sinks), new
 
 
-def _attend_update_ring(cfg, q, k, v, q_positions, entry, ctx, window, sinks):
+def _attend_update_ring(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks):
     """A sliding layer's O(window) ring (_ring_attend_update); the window
     is the entry's own."""
     real_end = ctx.write_pos + q.shape[1] if ctx.real_end is None else ctx.real_end
     attn, nk, nv = _ring_attend_update(
-        cfg, q, k, v, q_positions, entry.k, entry.v, ctx.write_pos, real_end,
+        cfg, q, k, v, q_positions, entry.k, entry.v, at, ctx.write_pos, real_end,
         entry.window, sinks,
     )
     return attn, cachelib.RingEntry(k=nk, v=nv, window=entry.window)
 
 
-def _attend_update_paged(cfg, q, k, v, q_positions, entry, ctx, window, sinks):
+def _attend_update_paged(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks):
     """Paged pool: scatter the chunk's K/V through the block table, then
     attend over the table-gathered view. Write target for row b, chunk
-    offset i at absolute position p = wp[b] + i is pool slot
-    (table[b, p // bs], p % bs); rows past real_end (bucket padding) and
-    rows with write_mask False scatter to index NB, which mode="drop"
-    discards — on dense lanes garbage writes were lane-private and safe,
-    here a dropped write is the ONLY safe garbage (blocks are shared
-    property). Windows stay mask-only: paged storage is one layout for
-    every layer by construction (core.cache)."""
+    offset i at absolute position p = wp[b] + i is slot
+    (at, table[b, p // bs], p % bs) of the stacked pools; rows past
+    real_end (bucket padding) and rows with write_mask False scatter to
+    block index NB, which mode="drop" discards — on dense lanes garbage
+    writes were lane-private and safe, here a dropped write is the ONLY
+    safe garbage (blocks are shared property). Windows stay mask-only:
+    paged storage is one layout for every layer by construction
+    (core.cache)."""
     b, s = q.shape[0], q.shape[1]
-    nb_, bs_ = entry.k.shape[0], entry.k.shape[1]
+    nb_, bs_ = entry.k.shape[1], entry.k.shape[2]
     wp = jnp.asarray(ctx.write_pos)
     col = lambda a: a[:, None] if a.ndim == 1 else jnp.broadcast_to(a, (b, 1))
     pos = col(wp) + jnp.arange(s)[None, :]  # [B, S]
@@ -695,11 +720,11 @@ def _attend_update_paged(cfg, q, k, v, q_positions, entry, ctx, window, sinks):
     blk = jnp.where(ok, blk, nb_)  # NB = out of range -> dropped
     off = pos % bs_
     new = cachelib.PagedEntry(
-        k=entry.k.at[blk, off].set(_to_cache_dtype(k, entry.k.dtype), mode="drop"),
-        v=entry.v.at[blk, off].set(_to_cache_dtype(v, entry.v.dtype), mode="drop"),
+        k=entry.k.at[at, blk, off].set(_to_cache_dtype(k, entry.k.dtype), mode="drop"),
+        v=entry.v.at[at, blk, off].set(_to_cache_dtype(v, entry.v.dtype), mode="drop"),
     )
     attn = gqa_attention(
-        q, new.k, new.v, q_positions, ctx.write_pos + s,
+        q, _slab(new.k, at), _slab(new.v, at), q_positions, ctx.write_pos + s,
         scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap,
         window=_mask_only(window), sinks=sinks, block_table=ctx.table,
     )
@@ -707,7 +732,10 @@ def _attend_update_paged(cfg, q, k, v, q_positions, entry, ctx, window, sinks):
 
 
 # the write-then-read of each cache layout (core.cache owns the layouts):
-# (cfg, q, k, v, q_positions, entry, ctx, window, sinks) -> (attn, entry')
+# (cfg, q, k, v, q_positions, entry, at, ctx, window, sinks) -> (attn, entry')
+# where `entry` is the layers' STACKED entries and `at` this layer's index
+# in them: the chunk's rows are written into the stack, the layer's slab
+# is read as a view of it, and the whole stack comes back
 _ATTEND_UPDATE = {
     type(None): _attend_chunk,
     cachelib.DenseEntry: _attend_update_lanes,
@@ -716,10 +744,10 @@ _ATTEND_UPDATE = {
 }
 
 
-def _gqa_attend_update(lp, cfg, x, cos, sin, q_positions, entry, ctx, window, adapters):
+def _gqa_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx, window, adapters):
     """Per-head q/k/v from the normed input `x`, the chunk's keys and values
-    written to the layer's cache entry in whichever layout it has, and
-    attention over it -> (attn [B, S, Nq*D], entry')."""
+    written at layer `at` of the stacked entries in whichever layout they
+    have, and attention over that layer -> (attn [B, S, Nq*D], entry')."""
     b, s, _h = x.shape
     d = cfg.head_dim
     q = lora_ops.apply_lane_delta(qdot(x, lp["q_proj"]), x, "q_proj", adapters)
@@ -741,7 +769,7 @@ def _gqa_attend_update(lp, cfg, x, cos, sin, q_positions, entry, ctx, window, ad
     # RoPE above took the true positions; the mask of every layout below
     # compares a slot's position with the last one the query sees
     q_positions = visible_until(cfg, q_positions)
-    return _ATTEND_UPDATE[type(entry)](cfg, q, k, v, q_positions, entry, ctx, window, sinks)
+    return _ATTEND_UPDATE[type(entry)](cfg, q, k, v, q_positions, entry, at, ctx, window, sinks)
 
 
 def visible_until(cfg: ModelConfig, q_positions: jax.Array) -> jax.Array:
@@ -818,11 +846,11 @@ def mla_attend(
     return out.reshape(b, s, n * dv)
 
 
-def _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, ctx):
+def _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx):
     """Latent attention's side of decoder_layer: queries per head, ONE
-    latent and ONE roped key per token written to the layer's entry
-    (core.cache.LatentEntry; None = no cache, the chunk attends to itself),
-    attention over it -> (attn [B, S, N * Dv], entry')."""
+    latent and ONE roped key per token written at layer `at` of the stacked
+    entries (core.cache.LatentEntry; None = no cache, the chunk attends to
+    itself), attention over that layer -> (attn [B, S, N * Dv], entry')."""
     b, s, _h = x.shape
     dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     q = qdot(x, lp["q_proj"]).reshape(b, s, -1, dn + dr)
@@ -838,12 +866,13 @@ def _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, ctx):
             )
         return attn, None
     new = cachelib.LatentEntry(
-        c=_lanes_write(entry.c, c, ctx.write_pos), r=_lanes_write(entry.r, k_pe, ctx.write_pos)
+        c=_lanes_write(entry.c, at, c, ctx.write_pos),
+        r=_lanes_write(entry.r, at, k_pe, ctx.write_pos),
     )
     with jax.named_scope("mla_attend"):
         attn = mla_attend(
-            cfg, q_nope, q_pe, new.c, new.r, lp["kv_b_proj"], q_positions,
-            ctx.write_pos + s, absorbed=s == 1,
+            cfg, q_nope, q_pe, _slab(new.c, at), _slab(new.r, at), lp["kv_b_proj"],
+            q_positions, ctx.write_pos + s, absorbed=s == 1,
         )
     return attn, new
 
@@ -855,9 +884,10 @@ def decoder_layer(
     cos: jax.Array,
     sin: jax.Array,
     q_positions: jax.Array,  # [B, S]
-    entry=None,  # this layer's cache entry (core.cache: dense lanes, latent,
-    #   ring or paged pool, the kv axis a tp rank's local heads); None = no
-    #   cache, the chunk attends to itself
+    entry=None,  # the STACKED cache entries this layer's lives in (core.cache:
+    #   dense lanes, latent, ring or paged pool, the kv axis a tp rank's
+    #   local heads); None = no cache, the chunk attends to itself
+    at=None,  # this layer's index in `entry`: a python int or a traced scalar
     ctx=None,  # core.cache.CacheCtx: where the chunk is written, the same
     #   for every layer
     window=None,  # this layer's sliding window: None (global), a STATIC
@@ -875,8 +905,9 @@ def decoder_layer(
     or with latent attention (cfg.is_mla).
 
     Returns (hidden', entry', chosen experts [B, S, K] or None for a dense
-    MLP). With no entry the layer runs cache-free over the full sequence
-    (prefill-style parity testing).
+    MLP); entry' is the whole stack with this layer's rows of the chunk
+    written into it. With no entry the layer runs cache-free over the full
+    sequence (prefill-style parity testing).
 
     Shard-polymorphic: head counts come from the projection widths, not the
     config, so the same code runs full-width (single device / pp stage) or
@@ -905,10 +936,10 @@ def decoder_layer(
                 f"{cfg.name}: latent attention runs on the dense lane layout only "
                 "(no tensor/expert parallel shard, paged pool, ring, window or adapter)"
             )
-        attn, entry = _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, ctx)
+        attn, entry = _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx)
     else:
         attn, entry = _gqa_attend_update(
-            lp, cfg, x, cos, sin, q_positions, entry, ctx, window, adapters
+            lp, cfg, x, cos, sin, q_positions, entry, at, ctx, window, adapters
         )
 
     attn_out = lora_ops.apply_lane_delta(
@@ -1004,6 +1035,8 @@ def forward_layers(
     tp_axis: Optional[str] = None,
     ep_axis: Optional[str] = None,
     layer_offset=0,  # global index of layers[0] (the layer pattern)
+    cache_offset: int = 0,  # the layer of `entries` that layers[0] writes: a
+    #   model whose layers come in groups hands every group the whole cache
     adapters=None,  # multi-tenant LoRA pools + per-lane ids (the ops.lora
     #   pool pytree: {"a", "b", "scale", "ids"}); gathered ONCE here, the
     #   per-layer slices ride the scan like the cache entries
@@ -1012,10 +1045,13 @@ def forward_layers(
     cfg.layer_pattern -> (hidden, entries', chosen experts [L, B, S, K] or
     None for a stack without routers).
 
-    The scan carries the hidden states and threads each layer's cache
-    entry through as scanned inputs/outputs — one compiled body per period
-    regardless of stage depth. `tp_axis`/`ep_axis` (inside shard_map only)
-    run each block on its tensor-/expert-parallel shard — see decoder_layer.
+    The scan carries the hidden states AND the stacked cache entries — one
+    compiled body per period regardless of stage depth. A layer writes only
+    its chunk's rows into the carried stack, at (its own index, row,
+    write_pos), and reads its slab as a view of that stack: no layer's slab
+    is taken out, rewritten and stacked again, so a donated cache is updated
+    where it lies. `tp_axis`/`ep_axis` (inside shard_map only) run each
+    block on its tensor-/expert-parallel shard — see decoder_layer.
 
     With a Python-int `layer_offset` every layer's kind is static: a
     sliding layer's window is a static int, so on dense lanes its attention
@@ -1023,9 +1059,10 @@ def forward_layers(
     ring entry is met by the layer it belongs to. A stack that does not
     start or end on a period boundary (odd offset, odd length) unrolls the
     layers before the first and after the last whole period through the
-    same body. With a traced offset (a pp rank inside shard_map) the kinds
-    are unknown until run time: every layer is its own period and the
-    windows ride the scan as a traced, mask-only input.
+    same body, writing into the same stacks. With a traced offset (a pp
+    rank inside shard_map) the kinds are unknown until run time: every
+    layer is its own period and the windows ride the scan as a traced,
+    mask-only input.
     """
     cos, sin = rope_cos_sin(positions, cfg.rope_dim, cfg.rope_theta, cfg)
     n = _stack_len(layers)
@@ -1060,17 +1097,23 @@ def forward_layers(
     per_layer = (layers, None if static else layer_windows(cfg, n, layer_offset), ad_per)
     by_kind = len(entries) == period > 1  # a stack per kind; else ONE, in layer order
 
-    def home(i):  # (stack, index in it) of layer i's entry
-        return ((layer_offset + i) % period, i // period) if by_kind else (0, i)
+    def home(i, p=0):  # (stack, index in it) of the entry of layer i + p periods
+        m = cache_offset + i
+        return ((layer_offset + i) % period, m // period + p) if by_kind else (0, m + p * period)
 
-    def layer(h, i, per_i, entry):
+    def layer(h, ents, i, per_i, p=0):
         lp, win, ad_sl = per_i
         if static:
             sliding = kinds[(layer_offset + i) % period] == "sliding"
             win = int(cfg.sliding_window) if sliding else None
-        return decoder_layer(
-            lp, cfg, h, cos, sin, positions, entry, ctx, win, tp_axis, ep_axis, _ad(ad_sl)
+        s, at = home(i, p) if ents else (0, None)
+        h, stack, topi = decoder_layer(
+            lp, cfg, h, cos, sin, positions, ents[s] if ents else None, at, ctx, win,
+            tp_axis, ep_axis, _ad(ad_sl),
         )
+        if ents:
+            ents = ents[:s] + (stack,) + ents[s + 1 :]
+        return h, ents, topi
 
     # a period of one layer is the layer: no reshape enters its program
     def fold(tree, lo, count):  # leaves [n, ...] -> `count` periods [count, period, ...]
@@ -1086,62 +1129,39 @@ def forward_layers(
     def pack(vals):  # the period's layers' values -> leaves [period, ...]
         return vals[0] if period == 1 else jax.tree.map(lambda *a: jnp.stack(a), *vals)
 
-    def unfold(tree):  # scan outputs [nper, period, ...] -> [nper * period, ...]
-        if period == 1:
-            return tree
-        return jax.tree.map(lambda a: a.reshape(nper * period, *a.shape[2:]), tree)
-
-    pieces = [[] for _ in entries]  # each stack's new entries, in layer order
     chosen = []
 
-    def single(h, i):  # a layer outside the whole periods, through the same body
-        s, at = home(i)
-        entry = jax.tree.map(lambda a: a[at], entries[s]) if entries else None
-        h, entry, topi = layer(h, i, jax.tree.map(lambda a: a[i], per_layer), entry)
-        if entries:
-            pieces[s].append(jax.tree.map(lambda a: a[None], entry))
+    def single(h, ents, i):  # a layer outside the whole periods, through the same body
+        h, ents, topi = layer(h, ents, i, jax.tree.map(lambda a: a[i], per_layer))
         chosen.append(None if topi is None else topi[None])
-        return h
+        return h, ents
 
     for i in range(head):
-        hidden = single(hidden, i)
+        hidden, entries = single(hidden, entries, i)
     if nper:
-        if by_kind:
-            firsts = [home(head + j)[1] for j in range(period)]
-            ent_xs = tuple(
-                jax.tree.map(lambda a: a[lo : lo + nper], e) for lo, e in zip(firsts, entries)
-            )
-        else:
-            ent_xs = tuple(fold(e, head, nper) for e in entries)
-
-        def body(h, xs):
-            per_p, ent_p = xs
-            ents, tops = [], []
+        def body(carry, xs):
+            h, ents = carry
+            per_p, p = xs
+            tops = []
             for j in range(period):
-                entry = None if not entries else ent_p[j] if by_kind else pick(ent_p[0], j)
-                h, entry, topi = layer(h, head + j, pick(per_p, j), entry)
-                ents.append(entry)
+                h, ents, topi = layer(h, ents, head + j, pick(per_p, j), p)
                 tops.append(topi)
-            ents = () if not entries else tuple(ents) if by_kind else (pack(ents),)
-            return h, (ents, pack(tops))
+            return (h, ents), pack(tops)
 
-        hidden, (ents, tops) = jax.lax.scan(
-            body, hidden, (fold(per_layer, head, nper), ent_xs)
+        periods = jnp.arange(nper, dtype=jnp.int32) if entries else None
+        (hidden, entries), tops = jax.lax.scan(
+            body, (hidden, entries), (fold(per_layer, head, nper), periods)
         )
-        for s, e in enumerate(ents):
-            pieces[s].append(e if by_kind else unfold(e))
-        chosen.append(unfold(tops))
+        if period > 1:  # [nper, period, ...] -> [nper * period, ...]
+            tops = jax.tree.map(lambda a: a.reshape(nper * period, *a.shape[2:]), tops)
+        chosen.append(tops)
     for i in range(n - tail, n):
-        hidden = single(hidden, i)
+        hidden, entries = single(hidden, entries, i)
 
-    def cat(parts):
-        if len(parts) == 1:
-            return parts[0]
-        return jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *parts)
-
-    new = tuple(cat(p) if p else e for p, e in zip(pieces, entries))
     chosen = [t for t in chosen if t is not None]
-    return hidden, new, cat(chosen) if chosen else None
+    if len(chosen) > 1:  # routers outside the whole periods too
+        chosen = [jnp.concatenate(chosen, axis=0)]
+    return hidden, entries, chosen[0] if chosen else None
 
 
 def forward_layers_cached(
@@ -1154,6 +1174,8 @@ def forward_layers_cached(
     real_end=None,  # scalar or [B]: first bucket-padding position (ring and
     #   paged layouts; default cache_write_pos + S)
     layer_offset=0,
+    cache_offset: int = 0,  # the cache's layer that layers[0] writes (a
+    #   model in groups hands each group the whole cache and its first layer)
     write_mask=None,  # [B] bool, paged caches only: rows whose KV writes
     #   commit; False rows compute but write NOTHING — a non-participating
     #   co-batch lane must never scribble on a block another lane or a
@@ -1170,7 +1192,7 @@ def forward_layers_cached(
     hidden, entries, topi = forward_layers(
         layers, cfg, hidden, positions, cache.entries(cfg),
         cache.ctx(cache_write_pos, real_end, write_mask),
-        tp_axis, ep_axis, layer_offset, adapters,
+        tp_axis, ep_axis, layer_offset, cache_offset, adapters,
     )
     return hidden, cache.with_entries(entries), topi
 
@@ -1190,7 +1212,7 @@ def forward_cached(
     the INPUT length — the caller advances it —, the experts each row chose
     in each sparse layer [Ls, B, S, K] int32, or None for a model without
     routers). A model with leading dense layers runs its groups one after
-    the other, each over its own layers of the cache."""
+    the other, each writing its own layers of the one cache."""
     if positions is None:
         start = cache_write_pos
         if jnp.ndim(start) == 1:
@@ -1200,21 +1222,16 @@ def forward_cached(
         )
     hidden = embed(params, tokens, cfg)
     topi, offset = None, 0
-    groups = layer_groups(params)
-    new_cache = cache
-    for layers in groups:
-        n = _stack_len(layers)
-        sub = cache if len(groups) == 1 else cachelib.layer_slice(cache, offset, offset + n)
-        hidden, part, chosen = forward_layers_cached(
-            layers, cfg, hidden, positions, sub, cache_write_pos,
-            real_end, layer_offset=offset, write_mask=write_mask,
-            adapters=adapters,
+    for layers in layer_groups(params):
+        hidden, cache, chosen = forward_layers_cached(
+            layers, cfg, hidden, positions, cache, cache_write_pos,
+            real_end, layer_offset=offset, cache_offset=offset,
+            write_mask=write_mask, adapters=adapters,
         )
-        new_cache = part if len(groups) == 1 else cachelib.layer_write(new_cache, offset, part)
         if chosen is not None:
             topi = chosen  # the one group with routers
-        offset += n
-    return unembed(params, cfg, hidden), new_cache, topi
+        offset += _stack_len(layers)
+    return unembed(params, cfg, hidden), cache, topi
 
 
 def decode_k(

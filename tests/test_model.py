@@ -859,3 +859,122 @@ def test_every_layout_runs_through_the_one_scan(layout):
             np.testing.assert_allclose(
                 logits[r, 0], want[r][lens[r] + i], rtol=1e-4, atol=1e-4
             )
+
+
+# (preset, stage (start, end) or None = the whole model, storage, write_pos
+# per row, a write_mask, a traced layer offset)
+IN_PLACE = {
+    "dense-scalar": ("tiny", None, "lanes", False, False, False),
+    "dense-per-row": ("tiny", None, "lanes", True, False, False),
+    "latent-two-layer-groups": ("tiny-dsv2", None, "lanes", True, False, False),
+    "ring-by-kind-off-both-period-boundaries": ("tiny-gptoss", (1, 4), "lanes", True, False, False),
+    "paged-write-mask": ("tiny", None, "paged", True, True, False),
+    "pp-rank-traced-offset": ("tiny-gemma2", (2, 4), "uniform", True, False, True),
+}
+
+
+@pytest.mark.parametrize("layout", list(IN_PLACE))
+def test_the_carried_cache_equals_slabs_threaded_layer_by_layer(layout):
+    """The one scan carries the stacked entries and a layer writes its rows
+    where the stack lies. For every layout the logits and the resulting
+    cache of a prefill chunk and three decode steps equal, bit for bit,
+    those of this test's own threading in plain jnp: each layer's slab is
+    taken out of its stack (a stack of ONE), the layer runs over it, the
+    slab is put back; the layer's place in its stack is counted here, not
+    taken from forward_layers. Rows the mask leaves out write nothing.
+    (Against the cache-free forward, to a tolerance: the test above.)"""
+    from inferd_tpu.config import get_config
+    from inferd_tpu.core import cache as cachelib
+
+    preset, stage, kind, per_row, masked, traced = IN_PLACE[layout]
+    cfg = get_config(preset)
+    params = qwen3.init_params(cfg, jax.random.PRNGKey(31))
+    if stage is None:
+        stacks = qwen3.layer_groups(params)
+        offs = np.cumsum([0] + [qwen3._stack_len(g) for g in stacks])
+        groups = [(g, int(o)) for g, o in zip(stacks, offs)]
+    else:
+        groups = [(qwen3.slice_layers(params["layers"], *stage), stage[0])]
+    n_layers = sum(qwen3._stack_len(g) for g, _ in groups)
+    first = groups[0][1]
+    if kind == "paged":
+        cache = cachelib.PagedKVCache.create(cfg, n_layers, 2, 32, block_size=8)
+        cache = dataclasses.replace(cache, table=jnp.arange(1, 9, dtype=jnp.int32).reshape(2, 4))
+    else:
+        cache = cachelib.KVCache.create(
+            cfg, n_layers, 2, 32, layer_offset=first, ring=None if kind == "lanes" else False
+        )
+    # a cache that is not all zeros: what a layer must leave alone shows
+    cache = jax.tree.map(
+        lambda a: (jnp.arange(a.size, dtype=jnp.float32).reshape(a.shape) % 7 / 8).astype(a.dtype)
+        if a.ndim > 2 else a, cache,
+    )
+
+    def carried(h, pos, cache, write_pos, real_end, mask, offset):
+        topi = None
+        for layers, off in groups:
+            h, cache, chosen = qwen3.forward_layers_cached(
+                layers, cfg, h, pos, cache, write_pos, real_end,
+                layer_offset=off - first + offset, cache_offset=off - first, write_mask=mask,
+            )
+            topi = chosen if chosen is not None else topi
+        return h, cache, topi
+
+    def threaded(h, pos, cache, write_pos, real_end, mask, offset):
+        entries = list(cache.entries(cfg))
+        ctx = cache.ctx(write_pos, real_end, mask)
+        cos, sin = qwen3.rope_cos_sin(pos, cfg.rope_dim, cfg.rope_theta, cfg)
+        kinds = cfg.layer_pattern
+        seen, at_all = {}, 0
+        for layers, off in groups:
+            n = qwen3._stack_len(layers)
+            wins = qwen3.layer_windows(cfg, n, off - first + offset) if traced else None
+            for i in range(n):
+                s = (offset + off - first + i) % len(kinds) if len(entries) > 1 else 0
+                at = seen[s] = seen.get(s, -1) + 1
+                if traced:
+                    win = wins[i]
+                else:
+                    sliding = kinds[(offset + off - first + i) % len(kinds)] == "sliding"
+                    win = int(cfg.sliding_window) if sliding else None
+                one = jax.tree.map(lambda a: a[at : at + 1], entries[s])
+                h, new, _ = qwen3.decoder_layer(
+                    jax.tree.map(lambda a: a[i], layers), cfg, h, cos, sin, pos, one, 0, ctx, win
+                )
+                entries[s] = jax.tree.map(lambda a, b: a.at[at].set(b[0]), entries[s], new)
+                at_all += 1
+        assert at_all == n_layers
+        return h, cache.with_entries(tuple(entries)), None
+
+    # a pp rank's offset is no python int: every layer is its own period
+    offset = jnp.int32(first) if traced else first
+    toks = jax.random.randint(jax.random.PRNGKey(32), (2, 9), 0, cfg.vocab_size, jnp.int32)
+    lens = jnp.asarray([6, 4] if per_row else [6, 6])
+    mask = jnp.asarray([True, False]) if masked else None
+    steps = [(toks[:, :6], jnp.broadcast_to(jnp.arange(6), (2, 6)), jnp.int32(0), lens)]
+    for i in range(3):
+        at = lens + i if per_row else jnp.int32(6 + i)
+        steps.append((toks[:, 6 + i : 7 + i], jnp.broadcast_to(lens[:, None] + i, (2, 1)), at, at + 1))
+    got_c = want_c = cache
+    for tokens, pos, write_pos, real_end in steps:
+        h = qwen3.embed(params, tokens, cfg)
+        # operation by operation on both sides (the scan too): a compiled
+        # body may fuse, and round, otherwise than the same operations alone
+        with jax.disable_jit():
+            got_h, got_c, topi = carried(h, pos, got_c, write_pos, real_end, mask, offset)
+            want_h, want_c, _ = threaded(h, pos, want_c, write_pos, real_end, mask, offset)
+        np.testing.assert_array_equal(
+            qwen3.unembed(params, cfg, got_h), qwen3.unembed(params, cfg, want_h)
+        )
+        assert jax.tree.structure(got_c) == jax.tree.structure(want_c)
+        for a, b in zip(jax.tree.leaves(got_c), jax.tree.leaves(want_c)):
+            np.testing.assert_array_equal(a, b)
+        if cfg.is_moe:  # every layer with a router reports, in layer order
+            assert topi.shape[0] == n_layers - cfg.num_dense_layers
+    # the steps wrote something, and only where they should
+    changed = [not np.array_equal(a, b) for a, b in
+               zip(jax.tree.leaves(got_c), jax.tree.leaves(cache)) if a.ndim > 2]
+    assert all(changed)
+    if masked:  # row 1 wrote nothing: its blocks (5..8) hold what they held
+        np.testing.assert_array_equal(got_c.k[:, 5:], cache.k[:, 5:])
+        np.testing.assert_array_equal(got_c.v[:, 5:], cache.v[:, 5:])
