@@ -126,7 +126,8 @@ class CacheCtx(NamedTuple):
     real_end: Any = None  # scalar or [B]: first bucket-padding position
     #   (ring and paged writes skip the padding; None = write_pos + S)
     table: Optional[jax.Array] = None  # [B, MB] int32 block table (paged)
-    write_mask: Optional[jax.Array] = None  # [B] bool (paged): rows whose writes commit
+    write_mask: Optional[jax.Array] = None  # [B] bool: rows whose writes commit
+    #   (a False row writes nothing, in any layout; None = every row's do)
 
 
 @jax.tree_util.register_dataclass
@@ -241,8 +242,8 @@ class KVCache:
 
     @staticmethod
     def ctx(write_pos, real_end=None, write_mask=None) -> CacheCtx:
-        """Dense lanes are lane-private: no table, and no write is masked."""
-        return CacheCtx(write_pos, real_end)
+        """Dense lanes are lane-private: no table."""
+        return CacheCtx(write_pos, real_end, write_mask=write_mask)
 
     def updated(self, k: jax.Array, v: jax.Array, new_tokens) -> "KVCache":
         """New cache with written buffers and advanced length (pure)."""

@@ -539,7 +539,7 @@ _FAR_FUTURE = 1 << 30
 
 def _ring_attend_update(
     cfg, q, k_new, v_new, q_positions, k_rings, v_rings, at, write_pos, real_end,
-    window: int, sinks,
+    window: int, sinks, write_mask=None,
 ):
     """Sliding-layer attention + update over an O(window) RING buffer.
 
@@ -564,7 +564,8 @@ def _ring_attend_update(
 
     The update scatters only the chunk's LAST min(S, R) real rows (unique
     slots by construction); rows at positions >= real_end (bucket padding)
-    scatter to index R, which `mode="drop"` discards.
+    and the rows of a batch row whose `write_mask` [B] is False scatter to
+    index R, which `mode="drop"` discards.
 
     The rings are layer `at` of the sliding layers' stacks
     [Ll, B, R, Nkv, D]: read as a view of the stack before the write, and
@@ -603,6 +604,8 @@ def _ring_attend_update(
     # -- update: scatter the last min(S, R) real rows into their slots ------
     pos = wp[:, None] + jnp.arange(s)[None, :]  # [B, S]
     keep = (pos < re[:, None]) & (pos >= re[:, None] - r)
+    if write_mask is not None:
+        keep &= write_mask[:, None]
     slot = jnp.where(keep, pos % r, r)  # r = out of bounds -> dropped
     kc = _to_cache_dtype(k_new, k_ring.dtype)
     vc = _to_cache_dtype(v_new, v_ring.dtype)
@@ -622,21 +625,29 @@ def _slab(stack, at):
     return jax.lax.dynamic_index_in_dim(stack, at, 0, keepdims=False)
 
 
-def _lanes_write(stack, at, chunk, write_pos):
+def _lanes_write(stack, at, chunk, write_pos, write_mask=None):
     """A chunk [B, S, ...] written into layer `at` of stacked dense lanes
     [L, B, T, ...] at `write_pos`: a scalar, or [B] per row (continuous
     batching: lanes at ragged fill levels advance in one step: one scatter
     of B windows [S, ...] at (at, b, write_pos[b]), its starts clamped as
     dynamic_update_slice clamps them). Only the chunk's rows are written:
-    under donation the stack is updated where it lies."""
+    under donation the stack is updated where it lies. With `write_mask`
+    [B] bool a False row writes nothing: its window starts past the end of
+    its lane and the scatter drops it whole (as it then drops, not clamps,
+    a window the caller let run past the end)."""
     chunk = _to_cache_dtype(chunk, stack.dtype)
-    if jnp.ndim(write_pos) == 0:
+    if write_mask is None and jnp.ndim(write_pos) == 0:
         return jax.lax.dynamic_update_slice(
             stack, chunk[None], (at, 0, write_pos) + (0,) * (stack.ndim - 3)
         )
     b = chunk.shape[0]
     rows = jnp.arange(b, dtype=jnp.int32)
-    starts = jnp.stack([jnp.full_like(rows, at), rows, write_pos.astype(jnp.int32)], axis=-1)
+    pos = jnp.broadcast_to(jnp.asarray(write_pos, jnp.int32), (b,))
+    mode = jax.lax.GatherScatterMode.CLIP
+    if write_mask is not None:
+        pos = jnp.where(write_mask, pos, stack.shape[2])
+        mode = jax.lax.GatherScatterMode.FILL_OR_DROP
+    starts = jnp.stack([jnp.full_like(rows, at), rows, pos], axis=-1)
     return jax.lax.scatter(
         stack, starts, chunk,
         jax.lax.ScatterDimensionNumbers(
@@ -644,8 +655,7 @@ def _lanes_write(stack, at, chunk, write_pos):
             inserted_window_dims=(0, 1),
             scatter_dims_to_operand_dims=(0, 1, 2),
         ),
-        indices_are_sorted=True, unique_indices=True,
-        mode=jax.lax.GatherScatterMode.CLIP,
+        indices_are_sorted=True, unique_indices=True, mode=mode,
     )
 
 
@@ -666,8 +676,8 @@ def _attend_update_lanes(cfg, q, k, v, q_positions, entry, at, ctx, window, sink
     row through the valid length where write_pos is per row."""
     s = q.shape[1]
     new = cachelib.DenseEntry(
-        k=_lanes_write(entry.k, at, k, ctx.write_pos),
-        v=_lanes_write(entry.v, at, v, ctx.write_pos),
+        k=_lanes_write(entry.k, at, k, ctx.write_pos, ctx.write_mask),
+        v=_lanes_write(entry.v, at, v, ctx.write_pos, ctx.write_mask),
     )
     new_k, new_v = _slab(new.k, at), _slab(new.v, at)
     end = ctx.write_pos + s
@@ -690,7 +700,7 @@ def _attend_update_ring(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks
     real_end = ctx.write_pos + q.shape[1] if ctx.real_end is None else ctx.real_end
     attn, nk, nv = _ring_attend_update(
         cfg, q, k, v, q_positions, entry.k, entry.v, at, ctx.write_pos, real_end,
-        entry.window, sinks,
+        entry.window, sinks, ctx.write_mask,
     )
     return attn, cachelib.RingEntry(k=nk, v=nv, window=entry.window)
 
@@ -866,8 +876,8 @@ def _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx):
             )
         return attn, None
     new = cachelib.LatentEntry(
-        c=_lanes_write(entry.c, at, c, ctx.write_pos),
-        r=_lanes_write(entry.r, at, k_pe, ctx.write_pos),
+        c=_lanes_write(entry.c, at, c, ctx.write_pos, ctx.write_mask),
+        r=_lanes_write(entry.r, at, k_pe, ctx.write_pos, ctx.write_mask),
     )
     with jax.named_scope("mla_attend"):
         attn = mla_attend(
@@ -1176,10 +1186,12 @@ def forward_layers_cached(
     layer_offset=0,
     cache_offset: int = 0,  # the cache's layer that layers[0] writes (a
     #   model in groups hands each group the whole cache and its first layer)
-    write_mask=None,  # [B] bool, paged caches only: rows whose KV writes
-    #   commit; False rows compute but write NOTHING — a non-participating
+    write_mask=None,  # [B] bool: rows whose KV writes commit; False rows
+    #   compute but write NOTHING, in any layout — a non-participating
     #   co-batch lane must never scribble on a block another lane or a
-    #   shared prefix may own
+    #   shared prefix may own, and the mesh decode pass must leave an
+    #   inactive slot's rows as they are (parallel/infer._rows_pass); None
+    #   = every row writes
     adapters=None,  # multi-tenant LoRA pool pytree + per-lane ids
     tp_axis: Optional[str] = None,
     ep_axis: Optional[str] = None,
@@ -1205,7 +1217,7 @@ def forward_cached(
     cache,  # core.cache.KVCache or PagedKVCache
     cache_write_pos,
     real_end=None,
-    write_mask=None,  # [B] bool, paged caches only
+    write_mask=None,  # [B] bool: rows whose KV writes commit (None = all)
     adapters=None,  # multi-tenant LoRA pool pytree + per-lane ids
 ):
     """Whole-model cached forward -> (logits [B, S, V], new cache with
@@ -1265,8 +1277,8 @@ def decode_k(
 
     Per-row semantics (the core/batch lane invariants, unchanged):
       * positions/masking come from `lengths`, not cache.length — inactive
-        rows compute garbage at their frozen frontier slot, which the
-        row's next real step overwrites before its position can be read;
+        rows compute garbage at their frozen frontier and write nothing
+        (`write_mask`);
       * `lengths` advances only for rows active at step entry; `n_new`
         counts exactly those advances;
       * with `eos` >= 0, a row DEACTIVATES the step after it emits its
@@ -1301,9 +1313,9 @@ def decode_k(
         logits, nc, _ = forward_cached(
             params, cfg, toks[:, None], pos, cache, lengths,
             real_end=lengths + 1,
-            # paged caches: a frozen row's tail-step garbage write must be
-            # DROPPED, not parked at its frontier slot — blocks are shared
-            # property (dense caches ignore the mask; bit-identical)
+            # a frozen row's tail-step garbage write is DROPPED, not
+            # parked at its frontier slot — a paged pool's blocks are
+            # shared property (on dense lanes nothing would read it)
             write_mask=act,
             adapters=adapters,
         )

@@ -14,7 +14,11 @@ reading and writing that microbatch's slice of the rank-local KV cache,
 then rotates activations one stage forward. A decode step costs MB + PP - 1
 ticks and advances MB*B sequences by one token — the bubble amortizes away
 as MB grows (the reference's swarm has exactly one activation in flight per
-request, SURVEY §2.1 'no microbatching').
+request, SURVEY §2.1 'no microbatching'). The SERVING decode pass
+(`_rows_pass`, behind `step_slots`) is lock-step with its callers (every
+reply goes through the host before the next pass), so there a tick's cost
+is the stage's weight read whatever it carries: it runs ONE microbatch
+whose rows are the slots, PP ticks, the head once.
 
 `PipelinedEngine` is a real generation engine, not a demo:
   * temperature/top-k/top-p sampling + EOS stop (core.sampling), fused into
@@ -272,6 +276,79 @@ def _pipeline_pass(
     return (k, v, *rings, logits_buf)
 
 
+def _rows_pass(
+    params: Params,  # rank-local layer slice; embed/norm/head replicated
+    toks: jax.Array,  # [MB] int32: each slot's next token
+    active: jax.Array,  # [MB] bool: the slots this pass advances
+    k: jax.Array,  # [L_local, MB, B=1, T, kv, d] (split: global layers only)
+    v: jax.Array,
+    lengths: jax.Array,  # [MB]
+    k_loc: Optional[jax.Array] = None,  # split: [Ll_local, MB, B=1, R, kv, d]
+    v_loc: Optional[jax.Array] = None,
+    *,
+    cfg: ModelConfig,
+    tp_axis: Optional[str] = None,
+    ep_axis: Optional[str] = None,
+):
+    """The serving DECODE pass: ONE microbatch whose rows are the slots, so
+    a pass is pp ticks and in its own tick (t == rank) a stage reads its
+    weights once for every live session. The stage's stacks with MB and B
+    merged ARE dense lanes [L_local, MB, T, kv, d]: the tick scan carries
+    them and the layer scan writes each row at (layer, row, lengths[row])
+    where it lies, exactly the lane executor's decode step
+    (core/batch._decode_logits), rings included. A stage runs its layers,
+    and so commits keys and values, only in its own tick (a lax.cond: in
+    every other tick it only hands on what the hop brought, which also
+    keeps a profiler capture of a pass to one stage's operations a chip),
+    and there only for an active row (write_mask): an inactive slot
+    (mid-prefill, free, or full at max_len) is not touched. The head runs
+    once, after the last tick, on the last rank's output. Returns
+    (k', v', [k_loc', v_loc',] logits [MB, V] float32, replicated).
+
+    Shares forward_layers_cached and the hop with _pipeline_pass and
+    nothing else: that one wants a scalar start and N microbatches, this
+    one a vector of starts and one."""
+    split = k_loc is not None
+    pp = lax.axis_size("pp")
+    idx = lax.axis_index("pp")
+    perm = [(i, (i + 1) % pp) for i in range(pp)]
+    stacks = (k, v, k_loc, v_loc)
+    # [L, MB, B, ...] -> [L, MB * B, ...]: the slots as the lanes' rows
+    lanes = jax.tree.map(lambda a: a.reshape(a.shape[0], -1, *a.shape[3:]), stacks)
+    emb = qwen3.embed(params, toks[:, None], cfg)  # [MB, 1, H]
+    positions = lengths[:, None]
+
+    def own_tick(inp, lanes):
+        y, nc, _ = qwen3.forward_layers_cached(
+            params["layers"], cfg, inp, positions,
+            KVCache(k=lanes[0], v=lanes[1], length=lengths, k_loc=lanes[2], v_loc=lanes[3]),
+            lengths, real_end=lengths + 1,
+            # as _pipeline_pass: a static 0 under the split, else the rank's own
+            layer_offset=0 if split else idx * (cfg.num_layers // pp),
+            write_mask=active, tp_axis=tp_axis, ep_axis=ep_axis,
+        )
+        return y, (nc.k, nc.v, nc.k_loc, nc.v_loc)
+
+    def tick(carry, t):
+        state, lanes = carry
+        # the microbatch enters stage 0 in tick 0; a stage runs its layers
+        # in its own tick and in no other (the hop is every rank's)
+        y, lanes = lax.cond(
+            t == idx, own_tick, lambda inp, lanes: (inp, lanes),
+            jnp.where(idx == 0, emb, state), lanes,
+        )
+        return (lax.ppermute(y, "pp", perm), lanes), None
+
+    (state, lanes), _ = lax.scan(tick, (jnp.zeros_like(emb), lanes), jnp.arange(pp))
+    # the last hop brought the last rank's output to rank 0; every rank
+    # takes it and unembeds its replicated head: [MB, H] rides, not [MB, V]
+    last_h = lax.psum(jnp.where(idx == 0, state, jnp.zeros_like(state)), "pp")
+    logits = qwen3.unembed(params, cfg, last_h)[:, 0].astype(jnp.float32)
+    k, v, k_loc, v_loc = jax.tree.map(lambda a, was: a.reshape(was.shape), lanes, stacks)
+    rings = (k_loc, v_loc) if split else ()
+    return (k, v, *rings, logits)
+
+
 def cache_spec(mesh: Mesh) -> P:
     """PipelinedCaches k/v spec: layers shard over pp; with tp in the mesh
     the kv-head axis (4 of [L, MB, B, T, n_kv, d]) shards over tp too."""
@@ -358,13 +435,17 @@ def make_pipeline_pass(
     params: Optional[Params] = None,
     ring: Optional[bool] = None,
     full_logits: bool = False,
+    rows: bool = False,
 ):
     """shard_map'd pipeline pass: (params, x[N,B,S], slots[N], last_idx,
     k, v, lengths) -> (k', v', logits[N,B,V]) — or, in the split ring
     layout (ring_split_ok; `ring` mirrors make_caches), (params, x, slots,
     last_idx, k, v, lengths, k_loc, v_loc) -> (k', v', k_loc', v_loc',
-    logits). Layers and caches shard over pp — and over tp (head/expert
-    axes, mesh.layer_param_specs) when the mesh has one; everything else
+    logits). With `rows` it is the serving decode pass over every slot as
+    a row (_rows_pass): (params, toks[MB], active[MB], k, v, lengths[,
+    k_loc, v_loc]) -> (k', v'[, k_loc', v_loc'], logits[MB,V]). Layers and
+    caches shard over pp — and over tp (head/expert axes,
+    mesh.layer_param_specs) when the mesh has one; everything else
     replicates. Pass `params` so the spec tree matches structurally
     (quantized leaves expand to q/scale pairs)."""
     if params is not None:
@@ -378,27 +459,31 @@ def make_pipeline_pass(
         ring and ring_split_ok(cfg, mesh.shape["pp"])
     )
     rings = (kv, kv) if split else ()  # the sliding layers' k_loc, v_loc
+    axes = dict(cfg=cfg, tp_axis=tp_axis, ep_axis=ep_axis)
+    if rows:
+        fn, lead = partial(_rows_pass, **axes), (P(), P())
+    else:
+        fn = partial(_pipeline_pass, full_logits=full_logits, **axes)
+        lead = (P(), P(), P())
     return jax.shard_map(
-        partial(
-            _pipeline_pass, cfg=cfg, tp_axis=tp_axis, ep_axis=ep_axis,
-            full_logits=full_logits,
-        ),
+        fn,
         mesh=mesh,
-        in_specs=(pspecs, P(), P(), P(), kv, kv, P(), *rings),
+        in_specs=(pspecs, *lead, kv, kv, P(), *rings),
         out_specs=(kv, kv, *rings, P()),
         check_vma=False,
     )
 
 
 def _over_caches(raw_pass):
-    """A make_pipeline_pass program as a function of PipelinedCaches ->
-    (k', v', k_loc', v_loc', logits), the rings None where the caches have
-    none."""
+    """A make_pipeline_pass program as a function of (params, what leads
+    its caches, PipelinedCaches, lengths) -> (k', v', k_loc', v_loc',
+    logits), the rings None where the caches have none."""
 
-    def passfn(params, x, slots, last_idx, caches, lengths):
+    def passfn(params, *args):
+        *lead, caches, lengths = args
         rings = () if caches.k_loc is None else (caches.k_loc, caches.v_loc)
         *bufs, logits = raw_pass(
-            params, x, slots, last_idx, caches.k, caches.v, lengths, *rings
+            params, *lead, caches.k, caches.v, lengths, *rings
         )
         nk, nv, nkl, nvl = (*bufs, None, None)[:4]
         return nk, nv, nkl, nvl, logits
@@ -473,6 +558,9 @@ class PipelinedEngine:
         self.ring_active = self.caches.k_loc is not None
 
         passfn = _over_caches(make_pipeline_pass(cfg, mesh, params=params, ring=ring))
+        rows_passfn = _over_caches(
+            make_pipeline_pass(cfg, mesh, params=params, ring=ring, rows=True)
+        )
         sampling = self.sampling
 
         def _sample_lanes(logits, keys, done, prev, eos, top_n=0,
@@ -571,21 +659,20 @@ class PipelinedEngine:
 
         @partial(jax.jit, donate_argnames=("caches",))
         def _step_raw_multi(params, caches: PipelinedCaches, toks, active):
-            # server-side MULTI-slot decode: co-arriving sessions share one
-            # pipeline pass (the pass natively interleaves all MB slots, so
-            # W sessions cost one traversal, not W). toks [MB] int32,
-            # active [MB] bool; inactive slots compute at their frontier but
-            # neither advance nor surface (garbage rows are overwritten by
-            # their own next real step). Returns logits [MB, V].
-            nk, nv, nkl, nvl, logits = passfn(
-                params, toks[:, None, None], jnp.arange(num_microbatches),
-                jnp.int32(0), caches, caches.lengths,
+            # server-side MULTI-slot decode: co-arriving sessions ride one
+            # pass as the ROWS of its one microbatch (_rows_pass: pp ticks,
+            # each stage reads its weights once for all of them). toks [MB]
+            # int32, active [MB] bool; inactive slots compute at their
+            # frontier but write nothing, do not advance and do not
+            # surface. Returns logits [MB, V].
+            nk, nv, nkl, nvl, logits = rows_passfn(
+                params, toks, active, caches, caches.lengths
             )
             new_lengths = jnp.where(active, caches.lengths + 1, caches.lengths)
             new = PipelinedCaches(
                 k=nk, v=nv, lengths=new_lengths, k_loc=nkl, v_loc=nvl
             )
-            return new, logits[:, 0]
+            return new, logits
 
         @partial(jax.jit, donate_argnames=("caches",), static_argnames=("m",))
         def _fork_slot(caches: PipelinedCaches, src, dst, prefix_len, m: int):
@@ -637,9 +724,11 @@ class PipelinedEngine:
         # the raw serving steps stamp `device` and `copy_out` with it
         self.tracer = None
         # pipeline-pass counters of the raw serving steps, host arithmetic
-        # from each pass's shape and active mask: a pass over n in-flight
-        # microbatches is a scan of n + pp - 1 ticks on pp stages, of
-        # which a live slot uses pp (one tick on every stage)
+        # from each pass's shape and active mask: a serving pass is one
+        # microbatch of `rows` rows (a prefill step's one slot, a decode
+        # pass's every slot) through pp ticks on pp stages; a stage-tick
+        # counts the row places it carries, of which a live slot uses pp
+        # (its row in its own tick on every stage)
         self.passes = 0
         self.stage_ticks = 0
         self.stage_ticks_useful = 0
@@ -940,12 +1029,12 @@ class PipelinedEngine:
             at["bytes"] = out.nbytes
         return {slot: out[slot] for slot in tokens_by_slot}
 
-    def _count_pass(self, n: int, live: int) -> None:
-        """One pipeline pass over n in-flight microbatches, `live` of them
+    def _count_pass(self, rows: int, live: int) -> None:
+        """One serving pass of one microbatch of `rows` rows, `live` of them
         doing a session's work (see the counters in __init__)."""
         pp = self.mesh.shape["pp"]
         self.passes += 1
-        self.stage_ticks += (n + pp - 1) * pp
+        self.stage_ticks += pp * pp * rows
         self.stage_ticks_useful += live * pp
 
     def slot_length(self, slot: int) -> int:

@@ -358,3 +358,71 @@ def test_sdar_block_program_compiles_at_16_lanes(one_chip, no_compile_cache):
     chunk = _sds((1, 512), jnp.int32, one_chip)
     prefill = eng._prefill_lane_logits.lower(params, cache, chunk, i32, i32, i32).compile()
     assert prefill.memory_analysis().temp_size_in_bytes < 0.4e9  # 0.25 GB
+
+
+# ---------------------------------------------------------------------------
+# the --mesh pipeline's decode pass, as run_node --mesh pp=4 --mesh-slots 8 builds it
+# ---------------------------------------------------------------------------
+
+
+def test_q8b_pp4_decode_pass_carries_its_slots_as_rows_in_place(topo, no_compile_cache, monkeypatch):
+    """`PipelinedEngine._step_raw_multi` of `--model qwen3-8b --mesh pp=4
+    --mesh-slots 8 --max-len 4096` (the cell q8b-pp4-sat-chat) for the
+    described 2x2: a loop of four ticks that carries a stage's two stacks
+    with the slots as rows and writes them where they lie. No `copy` (nor
+    any other operation but the loops' own updates) makes an array of a
+    stack's shape, both donated stacks are aliased to the output, and the
+    program fits a chip: 7.17 GB of arguments a device. Its 0.454 GB of
+    temporaries are the q, k and v projection weights, which this
+    compiler re-lays once a pass (302 + 75.5 + 75.5 MB); nothing of the
+    cache's size is among them (a slot's view of a stage is 75.5 MB, the
+    rows' slab of one layer 67 MB). The engine is built over the
+    described devices with its parameters and caches as shapes: placing
+    arrays there is what the test has to keep it from."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from inferd_tpu.models import qwen3
+    from inferd_tpu.parallel import infer, mesh as meshlib
+
+    cfg = get_config("qwen3-8b")
+    slots, max_len, pp = 8, 4096, 4
+    mesh = meshlib.make_mesh(meshlib.MeshPlan(pp=pp), topo.devices)
+
+    def on_mesh(shape, dtype, spec):
+        return _sds(shape, dtype, NamedSharding(mesh, spec))
+
+    def shard_shapes(params, cfg, mesh, layer_axis=None):
+        specs = meshlib.param_specs_for(params, cfg, layer_axis)
+        return jax.tree.map(lambda a, s: on_mesh(a.shape, a.dtype, s), params, specs,
+                            is_leaf=lambda x: isinstance(x, P))
+
+    def cache_shapes(cfg, mesh, mb, batch, max_len, ring=None):
+        kv = on_mesh((cfg.num_layers, mb, batch, max_len, cfg.num_kv_heads, cfg.head_dim),
+                     cfg.kv_jnp_dtype, infer.cache_spec(mesh))
+        return infer.PipelinedCaches(k=kv, v=kv, lengths=on_mesh((mb,), jnp.int32, P()))
+
+    monkeypatch.setattr(meshlib, "shard_params", shard_shapes)
+    monkeypatch.setattr(infer, "make_caches", cache_shapes)
+    params = jax.eval_shape(lambda: qwen3.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = infer.PipelinedEngine(cfg, params, mesh, num_microbatches=slots, max_len=max_len)
+    step = eng._step_raw_multi.lower(
+        eng.params, eng.caches, on_mesh((slots,), jnp.int32, P()), on_mesh((slots,), jnp.bool_, P())
+    ).compile()
+    mem = step.memory_analysis()
+    stack_bytes = cfg.num_layers // pp * slots * max_len * cfg.num_kv_heads * cfg.head_dim * 2
+    assert mem.alias_size_in_bytes >= 2 * stack_bytes  # K and V, a stage's share
+    assert 7.1e9 < mem.argument_size_in_bytes < 7.3e9
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    text = step.as_text()
+    stack = re.escape(f"bf16[{cfg.num_layers // pp},{slots},{max_len},{cfg.num_kv_heads},{cfg.head_dim}]")
+    made = set(re.findall(rf"= {stack}\S* ([\w\-]+)\(", text))
+    # the stack appears as the loops' carry and their in-place row updates only
+    assert made and made <= {"parameter", "get-tuple-element", "bitcast", "dynamic-update-slice",
+                             "fusion", "while"}, made
+    assert not re.search(rf"= {stack}\S* (copy|select)\(", text)
+    # one hop a tick inside the loop, the last rank's hidden state once after it
+    assert len(re.findall(r" collective-permute-start\(", text)) == 1
+    assert len(re.findall(r" all-reduce(-start)?\(", text)) == 1

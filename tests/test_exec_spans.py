@@ -209,12 +209,12 @@ def test_window_counters_are_fed_by_the_same_stamps(driven):
 
 
 def test_pipeline_counters_count_ticks_and_live_slots(mesh):
-    """Two one-slot prefill passes (n = 1) and one decode pass with 2 of
-    the 4 slots live (n = MB): (n + PP - 1) * PP stage-ticks each, a live
-    slot using PP of them."""
+    """Two one-slot prefill passes (one row) and one decode pass with 2 of
+    the 4 slots live (every slot a row): PP ticks on PP stages, a stage-tick
+    counting the rows it carries, a live slot using PP of them."""
     assert mesh["stats"]["pipeline"] == {
         "passes": 3,
-        "stage_ticks": 2 * (1 + PP - 1) * PP + (SLOTS + PP - 1) * PP,
+        "stage_ticks": 2 * PP * PP * 1 + PP * PP * SLOTS,
         "stage_ticks_useful": 2 * PP + 2 * PP,
     }
 
@@ -225,7 +225,7 @@ def test_one_more_decode_pass_adds_its_ticks(mesh):
     ex.process("a", {"tokens": [[1]], "start_pos": 4, "real_len": 1})
     after = ex.stats()["pipeline"]
     assert after["passes"] - before["passes"] == 1
-    assert after["stage_ticks"] - before["stage_ticks"] == (SLOTS + PP - 1) * PP
+    assert after["stage_ticks"] - before["stage_ticks"] == PP * PP * SLOTS
     assert after["stage_ticks_useful"] - before["stage_ticks_useful"] == 1 * PP
 
 

@@ -292,3 +292,62 @@ def test_elastic_reshard_carries_live_session(devices8):
         eng2.import_slot(0, k[:, :, :, :1], v[:, :, :, :1], ln)
     with pytest.raises(BufferError):
         eng2.import_slot(0, k, v, 999)
+
+
+@pytest.mark.parametrize(
+    "cfg,pp,ring",
+    [
+        (TINY, 4, False),         # uniform dense lanes, three bubble ticks a stage
+        (TINY_GEMMA2, 2, True),   # ring-split: sliding layers in O(window) rings
+        (TINY_GEMMA2, 4, False),  # uniform under a traced layer offset (mask-only windows)
+    ],
+    ids=["uniform-pp4", "ring-pp2", "gemma2-uniform-pp4"],
+)
+def test_rows_pass_matches_the_per_slot_pass_and_masks_its_writes(cfg, pp, ring, devices8):
+    """The serving decode pass carries its slots as rows (step_slots): over
+    slots at ragged lengths, with a free slot, one full at max_len and one
+    that sits passes out, several passes in a row give each active slot the
+    logits of the per-slot pass (step_slot, one slot at a time), advance
+    the active slots' lengths only, and leave an inactive slot's keys and
+    values bit for bit as they were: a stage that wrote a row in a tick
+    other than its own would undo its own tick's write, or scribble on a
+    slot that is mid-prefill, free or full."""
+    mb, max_len = 6, 32
+    rows, _ = make_engine(cfg, pp, mb, devices8, max_len=max_len)
+    solo, _ = make_engine(cfg, pp, mb, devices8, max_len=max_len)
+    assert rows.ring_active == ring == solo.ring_active
+    rng = np.random.default_rng(7)
+    # slot 4 stays free; slot 5 is full: its prompt fills the whole buffer
+    prompt_len = {0: 3, 1: 9, 2: 17, 3: 5, 5: max_len}
+    nxt, lens = {}, dict(prompt_len)
+    for slot, n in prompt_len.items():
+        prompt = rng.integers(0, cfg.vocab_size, (1, n), dtype=np.int32)
+        got = rows.step_slot(slot, prompt, n, reset=True)
+        want = solo.step_slot(slot, prompt, n, reset=True)
+        np.testing.assert_array_equal(got, want)  # the same program on both
+        nxt[slot] = int(np.argmax(want[0]))
+
+    def kv_of(eng, slot):
+        c = eng.caches
+        return [np.asarray(a[:, slot]) for a in (c.k, c.v, c.k_loc, c.v_loc) if a is not None]
+
+    # slot 3 sits the first two passes out (mid-stream, inactive), slot 0 the third
+    for active in ([0, 1, 2], [0, 1, 2], [1, 2, 3], [0, 1, 2, 3], [0, 3]):
+        idle = [s for s in range(mb) if s not in active]
+        before = {s: kv_of(rows, s) for s in idle}
+        out = rows.step_slots({s: nxt[s] for s in active})
+        assert sorted(out) == active
+        for s in active:
+            want = solo.step_slot(s, np.asarray([[nxt[s]]], np.int32), 1, False,
+                                  start_pos=lens[s])[0]
+            np.testing.assert_allclose(out[s], want, rtol=2e-4, atol=2e-4)
+            assert int(np.argmax(out[s])) == int(np.argmax(want))
+            nxt[s], lens[s] = int(np.argmax(want)), lens[s] + 1
+        assert [rows.slot_length(s) for s in range(mb)] == [lens.get(s, 0) for s in range(mb)]
+        for s in idle:
+            for was, now in zip(before[s], kv_of(rows, s)):
+                np.testing.assert_array_equal(was, now)
+    # what the passes wrote is what the per-slot passes wrote, everywhere
+    for s in range(mb):
+        for a, b in zip(kv_of(rows, s), kv_of(solo, s)):
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)

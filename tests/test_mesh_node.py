@@ -330,11 +330,23 @@ def mesh8(mesh_parts):
     )
 
 
-def test_mesh_pass_takes_every_live_slot(mesh8):
+def test_mesh_pass_takes_every_live_slot(mesh8, monkeypatch):
     """Eight threads on eight slots, four tokens each: the pass formed
     while the mesh was held carries all eight, the passes after it wait for
-    whom the last ones served, and every session reads what it reads alone."""
+    whom the last ones served, and every session reads what it reads alone.
+    A formation waits at most a step for a session's turn (window.py,
+    `_cap_s`), and on the chip a pass (37 ms) outlasts a turn (6 ms); the
+    tiny model's pass of rows on a loaded CPU does not, so the test holds
+    the pass at 50 ms: the regime the wait is made for."""
     ex, steps = mesh8, 4
+    step_slots = ex.engine.step_slots
+
+    def chip_long_pass(tokens_by_slot):
+        out = step_slots(tokens_by_slot)
+        time.sleep(0.05)
+        return out
+
+    monkeypatch.setattr(ex.engine, "step_slots", chip_long_pass)
     prompts = {f"e{i}": [3 + i, 7, 11 + i] for i in range(8)}
     solo = {}
     for s, ids in prompts.items():
