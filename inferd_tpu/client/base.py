@@ -318,6 +318,21 @@ class GenerationClient:
         )
         return tok
 
+    def _record_emit(self, t0: float, tokens: int) -> None:
+        """The `emit`-phase span of what the caller's `on_token` took for
+        one hop's tokens (a token; a block's): with `sample` it fills the
+        stretch between two `step`s, and a callback that yields to the
+        event loop (a stream's write) shows here."""
+        self.tracer.record_span(
+            "emit", "emit", t0, tracelib.now(), parent=tracelib.current(),
+            attrs={"tokens": tokens},
+        )
+
+    async def _emit_traced(self, on_token, tok: int) -> None:
+        t0 = tracelib.now()
+        await _emit(on_token, tok)
+        self._record_emit(t0, 1)
+
     # -- shared helpers ------------------------------------------------------
 
     def _hop_timeout_s(self, where: str) -> float:
@@ -624,7 +639,7 @@ class GenerationClient:
             if top_sink is not None:
                 top_sink.append(top_logprobs_np(logits, top_n))
             if on_token is not None:
-                await _emit(on_token, tok)
+                await self._emit_traced(on_token, tok)
             while len(out) < max_new_tokens and tok != eos_token_id:
                 logits = await self._step_resuming(
                     session_id, [tok], pos, known, resumes
@@ -638,7 +653,7 @@ class GenerationClient:
                 if top_sink is not None:
                     top_sink.append(top_logprobs_np(logits, top_n))
                 if on_token is not None:
-                    await _emit(on_token, tok)
+                    await self._emit_traced(on_token, tok)
         finally:
             try:
                 await self._end_session(session_id)
@@ -688,6 +703,7 @@ class GenerationClient:
                     res = await self._forward(
                         session_id, head + [0] * (blk - len(head)), pos, block=call
                     )
+                t_emit, had = tracelib.now(), len(out)
                 for j in range(len(head), blk):
                     tok = int(res["tokens"][0][j])
                     out.append(tok)
@@ -702,6 +718,8 @@ class GenerationClient:
                         await _emit(on_token, tok)
                     if len(out) >= max_new_tokens or tok == eos_token_id:
                         break
+                if on_token is not None:
+                    self._record_emit(t_emit, len(out) - had)  # one a block
                 pos, head, key = pos + blk, [], res["key"]
         finally:
             try:

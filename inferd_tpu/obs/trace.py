@@ -25,6 +25,12 @@ Inside an executor call (`compute`) the executors stamp four child
 phases through `region`/`holding`: `batch_wait` (the arrival window of
 runtime/window.py), `lock_wait` (the executor's device lock), `device`
 (jitted call -> block_until_ready) and `copy_out` (device -> host).
+Between two steps (docs/OBSERVABILITY.md "Between two steps"): `deliver`
+(the flusher's `copy_out` returned -> the entry's worker is back from the
+executor), `resume` (-> its coroutine runs again on the event loop),
+`emit` (the generation loop's `on_token` callback) and, once a step and
+parentless, `turn` (runtime/window.py: the device freed -> the next
+drain).
 Disabled-by-config tracing
 (INFERD_TRACE=0, read per call) records nothing and leaves the wire
 envelope byte-identical to the untraced format.
@@ -36,9 +42,9 @@ import contextvars
 import dataclasses
 import json
 import os
+import random
 import threading
 import time
-import uuid
 from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, Iterable, List, Optional
@@ -47,6 +53,7 @@ PHASES = (
     "queue", "compute", "wire", "relay", "rescue", "handoff", "sample",
     "window",
     "batch_wait", "lock_wait", "device", "copy_out",
+    "deliver", "resume", "emit", "turn",
     "client", "server",
 )
 
@@ -82,8 +89,19 @@ def now() -> float:
     return _EPOCH_WALL + (time.perf_counter() - _EPOCH_PERF)
 
 
+# Span and trace ids: 64 random bits from a generator of this process's
+# own, seeded from the system's entropy at import (and again in a forked
+# child). An id needs no secrecy, and `uuid.uuid4()` is an `os.urandom`
+# system call an id: the call gives up the GIL, so every span recorded on
+# a node's event loop handed the loop's thread to whichever worker was
+# awake and waited to get it back: 94 us a span against 5 (PERF.md section 6,
+# PR 40), 4 % of `q4b-sat-chat`'s tokens a second.
+_ids = random.Random()
+os.register_at_fork(after_in_child=_ids.seed)
+
+
 def new_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return "%016x" % _ids.getrandbits(64)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -134,6 +152,32 @@ def set_current(ctx: Optional[SpanContext]):
 
 def reset_current(token) -> None:
     _current.reset(token)
+
+
+# Stamps a callee hands up to the owner of the span it runs under: the
+# owner `open_marks()` a dict in its own thread or task, whatever runs
+# beneath `mark(name, t)`s into it (a no-op where nobody opened one), and
+# the owner reads it once the call is back. `deliver`'s t0 travels this
+# way from the window's submit to Node._timed_process.
+_marks: "contextvars.ContextVar[Optional[Dict[str, float]]]" = contextvars.ContextVar(
+    "inferd_trace_marks", default=None
+)
+
+
+def open_marks():
+    """(the dict, a token for close_marks)."""
+    marks: Dict[str, float] = {}
+    return marks, _marks.set(marks)
+
+
+def close_marks(token) -> None:
+    _marks.reset(token)
+
+
+def mark(name: str, t: float) -> None:
+    marks = _marks.get()
+    if marks is not None:
+        marks[name] = t
 
 
 def wire_ctx() -> Optional[Dict[str, str]]:

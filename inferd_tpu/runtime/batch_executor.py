@@ -864,6 +864,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                     with tracelib.region(self.tracer, "copy_out") as at:
                         out = np.asarray(logits, np.float32)
                         at["bytes"] = out.nbytes
+                    self._batcher.stamp_out(legacy)  # `deliver` starts
                     if routed is not None:  # came out with the logits: no wait
                         self._count_routing(
                             np.asarray(routed)[:, [e.payload[0] for e in legacy]]
@@ -1008,6 +1009,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         with tracelib.region(self.tracer, "copy_out") as at:
             host = [np.asarray(a) for a in out]
             at["bytes"] = sum(a.nbytes for a in host)
+        self._batcher.stamp_out(grp)  # `deliver` starts
         x, order, nkeys, lps, tis, tls = (a.tolist() for a in host)
         if chosen is not None:  # came out with the tokens: no wait
             routed = np.asarray(chosen)[:, :, lanes]  # [passes, layers, live, block, k]

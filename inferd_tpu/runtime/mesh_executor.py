@@ -674,6 +674,7 @@ class MeshExecutor(SpecServing):
                 # entries to zero so /stats batched_tokens stays token-true
                 self._batcher.n_served -= len(entries)
                 return
+            self._batcher.stamp_out(entries)  # copy_out is over: `deliver` starts
             for e in entries:
                 slot, _tok, sid = e.payload
                 if self._dying.get(slot) != sid:  # ended-mid-flush: the

@@ -225,12 +225,19 @@ class TaskScheduler:
         self._lock = asyncio.Lock()
 
     async def run(self, fn, *args):
+        return (await self.run_stamped(fn, *args))[0]
+
+    async def run_stamped(self, fn, *args):
+        """run(), and beside the result the moment this coroutine ran
+        again on the event loop once the worker was done: where the hop's
+        `resume` span ends."""
         loop = asyncio.get_running_loop()
         async with self._lock:
             self.inflight += 1
             self._on_load_change()
         try:
-            return await loop.run_in_executor(self._pool, fn, *args)
+            out = await loop.run_in_executor(self._pool, fn, *args)
+            return out, tracelib.now()
         finally:
             async with self._lock:
                 self.inflight -= 1
@@ -1846,9 +1853,11 @@ class Node:
                     executor.window.submit, (session_id, env, tin, t_q)
                 )
             else:
-                result, pure_ms, w0, w1, cctx = await self.scheduler.run(
-                    self._timed_process, executor, session_id,
-                    env.get("payload", {}), tin,
+                (result, pure_ms, w0, w1, cctx), t_res = (
+                    await self.scheduler.run_stamped(
+                        self._timed_process, executor, session_id,
+                        env.get("payload", {}), tin,
+                    )
                 )
         except BufferError as e:  # KV budget exceeded: deterministic
             # the executors' BufferError now names the session AND lane
@@ -1944,6 +1953,11 @@ class Node:
                            "kind": call_kind(_pl),
                            "tokens": _committed_tokens(result),
                            **({"tokens_saved": saved} if saved else {})},
+                )
+                # the worker was done at w1; this coroutine ran again at
+                # t_res: the hop's wait for the event loop
+                self.tracer.record_span(
+                    "resume", "resume", w1, t_res, parent=tin,
                 )
             # service-time EWMA: announced as svc_ms, feeding every
             # planner's measured-latency edge-cost term (carried by the 1 s
@@ -2521,9 +2535,11 @@ class Node:
         # (obs.trace.region: batch_wait, lock_wait, device, copy_out)
         # parents to the span the caller records afterwards
         ctx = token = None
+        marks: Dict[str, float] = {}
         if tin is not None and tracelib.enabled():
             ctx = tracelib.SpanContext(tin.trace_id, tracelib.new_id())
             token = tracelib.set_current(ctx)
+            marks, mtoken = tracelib.open_marks()
         try:
             w0 = tracelib.now()
             t = time.perf_counter()
@@ -2531,8 +2547,17 @@ class Node:
             pure_ms = (time.perf_counter() - t) * 1e3
         finally:
             if token is not None:
+                tracelib.close_marks(mtoken)
                 tracelib.reset_current(token)
-        return result, pure_ms, w0, w0 + pure_ms / 1e3, ctx
+        w1 = w0 + pure_ms / 1e3
+        if "t_out" in marks:
+            # a windowed step served this call: from its copy_out's end
+            # (the flusher's stamp, on the recorder the node handed the
+            # executor) to this worker being back
+            executor.tracer.record_span(
+                "deliver", "deliver", marks["t_out"], w1, parent=ctx,
+            )
+        return result, pure_ms, w0, w1, ctx
 
     def _is_final(self, result: Dict[str, Any]) -> bool:
         # "tokens": a multi-step fused decode result (single-stage

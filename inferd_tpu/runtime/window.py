@@ -28,7 +28,11 @@ Three modes, and who runs each (docs/SERVING.md):
     lock behind it, and entries arriving mid-step join the next step.
     The cap is derived: three times a running mean of a served session's
     result -> next submit, never above the last measured step; `window_s`
-    is only that mean's start value.
+    is only that mean's start value. Each drain ends a TURN (the device
+    freed -> the drain): counted in `stats()` where a session was owed
+    the step, and recorded as a parentless `turn` span by the flusher
+    once its step is delivered (docs/OBSERVABILITY.md "Between two
+    steps"); `stamp_out` is where each served entry's `deliver` begins.
   * `swap_in_run` + `gang_target`: `--stage-lanes` (the window lives on
     the node, runtime/node.py `_attach_window` + runtime/stage_batch.py).
     The flusher polls until `gang_target()` entries are pending or
@@ -51,7 +55,7 @@ from inferd_tpu.utils import lockwatch
 
 class Entry:
     __slots__ = ("payload", "event", "result", "error",
-                 "t_submit", "t_lock", "t_taken", "ctx")
+                 "t_submit", "t_lock", "t_taken", "t_out", "ctx")
 
     def __init__(self, payload: Any):
         self.payload = payload
@@ -69,6 +73,9 @@ class Entry:
         # entry (`lock_wait`), t_lock -> t_taken the rest (`batch_wait`)
         self.t_lock: Optional[float] = None
         self.t_taken: Optional[float] = None
+        # when the copy_out of the step that served this entry returned
+        # (`stamp_out`): the t0 of the entry's `deliver` span
+        self.t_out: Optional[float] = None
         self.ctx = tracelib.current()
 
 
@@ -141,6 +148,16 @@ class WindowedBatcher:
         self.n_served = 0  # entries served across those batches
         self.queue_waits = 0  # entries a flush took into a batch
         self.queue_wait_ms_sum = 0.0  # their summed arrival-window waits
+        # formation, the stretch between two steps (docs/OBSERVABILITY.md
+        # "Between two steps"): a TURN runs from a step's return to the
+        # next drain, counted where the formation was owed a session's
+        # entry (the `turn` span's own stamps); a SESSION's turn from its
+        # result out to its next submit, before `_returned` clamps it
+        self.turns = 0
+        self.turn_ms_sum = 0.0
+        self.session_turns = 0
+        self.session_turn_ms_sum = 0.0
+        self._formed = ("solo", 0)  # how the last formation ended, whom it was owed
         # the node's span recorder (obs.trace.SpanRecorder), handed to
         # the executor that owns this batcher; None records no span
         self.tracer: Optional[tracelib.SpanRecorder] = None
@@ -209,6 +226,9 @@ class WindowedBatcher:
                     "batch_wait", "batch_wait", t_wait, entry.t_taken,
                     parent=entry.ctx, attrs={"flusher": int(i_flush)},
                 )
+                if entry.t_out is not None:
+                    # handed up to whoever records this call's `compute`
+                    tracelib.mark("t_out", entry.t_out)
 
     def _await(self, entry: Entry, where: str) -> Any:
         """Block until another thread's step delivers `entry`."""
@@ -299,6 +319,8 @@ class WindowedBatcher:
         out = self._delivered.pop(self._expect(entry.payload), None)
         if out is not None:
             turn = max(0.0, entry.t_submit - out)
+            self.session_turns += 1
+            self.session_turn_ms_sum += turn * 1e3
             if self._step_s is not None:
                 # the cap never passes a step, so neither need a sample
                 turn = min(turn, self._step_s)
@@ -328,6 +350,8 @@ class WindowedBatcher:
                 self._stall("formation")
                 raise TimeoutError("batched decode step never completed")
             self._cv.wait(timeout=left)
+        how = "solo"
+        owed = len({k for served in self._served for k in served})
         while wait:
             here = {
                 self._expect(e.payload) for e in self._pending if e.error is None
@@ -339,23 +363,27 @@ class WindowedBatcher:
             ]
             if not missing:
                 self.gang_full += 1
+                how = "full"
                 break
             left = self._t_freed + self._cap_s() - tracelib.now()
             if left <= 0:
                 # whoever is not back by now is not waited for again
                 # until a step has served it
                 self.gang_timeout += 1
+                how = "timeout"
                 for served in self._served:
                     for k in missing:
                         served.pop(k, None)
                 break
             self._cv.wait(timeout=left)
         self._t_formed = tracelib.now()
+        self._formed = (how, owed)
 
     def _flush_formed(self, entry: Entry, wait: bool) -> Any:
         """Form, let the callback take the device and drain, then note
         whom the step served and when, and deliver."""
-        ticket: list = [None]  # drain_pending() leaves the step's entries
+        # drain_pending() leaves the step's entries and the turn it ended
+        ticket: list = [None, None]
         failed: Optional[Exception] = None
         try:
             with self._cv:
@@ -391,7 +419,40 @@ class WindowedBatcher:
             self._cv.notify_all()
         for e in batch:
             e.event.set()
+        if drained and ticket[1] is not None:
+            self._record_turn(batch, *ticket[1])
         return self._await(entry, "formation")
+
+    def _record_turn(
+        self, batch: List[Entry], t_freed: float, t_drain: float,
+        t_formed: float, how: str, owed: int,
+    ) -> None:
+        """The `turn` span of the stretch the drain of `batch` ended (the
+        flusher, once its step is delivered): parentless, one a step."""
+        if self.tracer is None or not tracelib.enabled():
+            return
+        last = max(batch, key=lambda e: e.t_submit)
+        self.tracer.record_span(
+            "turn", "turn", t_freed, t_drain,
+            attrs={
+                "kind": self.kind, "cobatch": len(batch), "expected": owed,
+                "how": how,
+                "formed_ms": round((t_formed - t_freed) * 1e3, 3),
+                "first_ms": round(
+                    (min(e.t_submit for e in batch) - t_freed) * 1e3, 3
+                ),
+                "last": last.ctx.span_id if last.ctx is not None else None,
+            },
+        )
+
+    def stamp_out(self, entries: List[Entry]) -> None:
+        """The flusher, as its step's copy_out returns: ONE stamp for
+        every entry the step served, where each one's `deliver` span
+        starts. Nothing without a recorder or with INFERD_TRACE=0."""
+        if self.tracer is not None and tracelib.enabled():
+            t_out = tracelib.now()
+            for e in entries:
+                e.t_out = t_out
 
     def unexpect(self, pred: Callable[[Any], bool]) -> None:
         """Stop waiting for the sessions whose last served payload matches
@@ -413,6 +474,16 @@ class WindowedBatcher:
             "gang_full": self.gang_full,
             "gang_timeout": self.gang_timeout,
             "empty_drains": self.empty_drains,
+            # between two steps: the device free and a session owed a
+            # step (sum / count: the mean turn; against batched_steps and
+            # the uptime, the share of time the chip waited on the host),
+            # a session's own result -> next submit, and the estimate the
+            # formation's cap is made of
+            "turns": self.turns,
+            "turn_ms_sum": round(self.turn_ms_sum, 3),
+            "session_turns": self.session_turns,
+            "session_turn_ms_sum": round(self.session_turn_ms_sum, 3),
+            "turn_est_ms": round(self._turn_s * 1e3, 3),
         }
         return {
             **formation,
@@ -447,6 +518,17 @@ class WindowedBatcher:
                 # whoever submits from here on forms the NEXT step and
                 # first waits this one out
                 self._ticket[0] = live
+                if live and self._t_freed:
+                    # the turn this drain ends: from the last step's
+                    # return; _flush_formed records its span once this
+                    # step is delivered
+                    how, owed = self._formed
+                    if owed:
+                        self.turns += 1
+                        self.turn_ms_sum += (self._t_drain - self._t_freed) * 1e3
+                    self._ticket[1] = (
+                        self._t_freed, self._t_drain, self._t_formed, how, owed
+                    )
                 self._ticket = None
                 self._flusher_active = False
                 self._running = True

@@ -32,7 +32,7 @@ from inferd_tpu.runtime.window import WindowedBatcher
 from test_mesh_node import hold_flusher
 
 PROMPTS = {"a": [3, 7, 11], "b": [5, 13, 17]}
-PARTS = ("batch_wait", "lock_wait", "device", "copy_out")
+PARTS = ("batch_wait", "lock_wait", "device", "copy_out", "deliver")
 PP, SLOTS = 4, 4
 HELD_S = 0.1  # how long the lanes' device lock is held under both entries
 
@@ -167,10 +167,11 @@ def test_decode_step_is_one_device_step_and_a_wait_per_entry(driven):
     names = [[k["name"] for k in children(spans, c)] for c in calls]
     # the flusher's call holds the step; the co-arrival's only its waits
     # (the time the device was somebody else's, then the wait for expected
-    # sessions)
+    # sessions); each ends with the way back from the step's copy_out to
+    # its own worker (tests/test_host_turn.py)
     assert sorted(names, key=len) == [
-        ["lock_wait", "batch_wait"],
-        ["lock_wait", "batch_wait", "device", "copy_out"],
+        ["lock_wait", "batch_wait", "deliver"],
+        ["lock_wait", "batch_wait", "device", "copy_out", "deliver"],
     ]
     dev = [s for s in spans if s["name"] == "device" and s["attrs"]["kind"] == "decode"]
     assert len(dev) == 1
