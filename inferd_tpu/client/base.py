@@ -6,6 +6,16 @@ routes hop-to-hop, reference petals/send_message.py:27-60) and ChainClient
 client.py:204-287) — run the exact same outer loop: tokenize, prefill, then
 sample-append-step until EOS/budget, then drop the session's server-side KV.
 That loop lives here once; subclasses provide only the transport step.
+
+Who samples: the first token is sampled here, from the last prefill
+chunk's logits. Every DECODE hop then carries an ask (`_decode_ask`: the
+sampling config, the session's PRNG chain, the log-probabilities wanted):
+an executor that chooses tokens on the device (`--batch-lanes`, `--mesh`)
+answers with `tokens` and the chain's next `key`, and no logits leave it;
+any other (a stage executor behind a relay, a node of an older tree, a
+transport that cannot carry the ask) answers with `logits` and the loop
+samples as it always did (`sample_np`). Which case applies is read off
+the reply: there is no switch.
 """
 
 from __future__ import annotations
@@ -281,6 +291,17 @@ class GenerationClient:
         `want_logits`); returns the last stage's result as it is."""
         raise NotImplementedError
 
+    async def _asking_step(
+        self, session_id: str, tokens: List[int], start_pos: int,
+        ask: Dict[str, Any],
+    ) -> Dict[str, Any]:
+        """One DECODE pass that carries a sampling ask (`_decode_ask`);
+        returns the last stage's result as it is: "tokens" / "key" (/
+        "logprobs", "top_ids", "top_lps") from an executor that chose the
+        token, "logits" [1, V] from one that did not. A transport that
+        carries no ask (this default) is answered with logits."""
+        return {"logits": (await self._step(session_id, tokens, start_pos))[None]}
+
     async def _block_length(self) -> int:
         """The served model's block length as its node reports it (asked
         once); a transport that cannot ask serves token by token."""
@@ -297,14 +318,19 @@ class GenerationClient:
         return False
 
     async def _traced_step(
-        self, session_id: str, tokens: List[int], start_pos: int
-    ) -> np.ndarray:
+        self, session_id: str, tokens: List[int], start_pos: int,
+        ask: Optional[Dict[str, Any]] = None,
+    ):
         """One pipeline pass wrapped in a `wire`-phase span: the envelope
         the subclass transport builds inside parents to this span (the
-        contextvar carries it), so node-side spans nest under the step."""
+        contextvar carries it), so node-side spans nest under the step.
+        Returns last-token logits [V]; with an `ask` (a decode hop), the
+        reply dict of `_asking_step`."""
         with self.tracer.span(
             "step", "wire", attrs={"start_pos": start_pos, "n": len(tokens)}
         ):
+            if ask is not None:
+                return await self._asking_step(session_id, tokens, start_pos, ask)
             return await self._step(session_id, tokens, start_pos)
 
     def _sample_traced(self, logits: np.ndarray, rng, s: SamplingConfig) -> int:
@@ -317,6 +343,18 @@ class GenerationClient:
             "sample", "sample", t0, tracelib.now(), parent=tracelib.current()
         )
         return tok
+
+    @staticmethod
+    def _decode_ask(s: SamplingConfig, want_lp: bool, top_n: int) -> Dict[str, Any]:
+        """What every decode hop and block hop asks of an executor that can
+        choose tokens on the device (the keys runtime/executor.parse_ask
+        reads; the PRNG chain's "seed" / "key" ride beside it)."""
+        return {
+            "sampling": {"temperature": s.temperature, "top_k": s.top_k,
+                         "top_p": s.top_p, "min_p": s.min_p},
+            "logprobs": want_lp,
+            "top_logprobs": top_n,
+        }
 
     def _record_emit(self, t0: float, tokens: int) -> None:
         """The `emit`-phase span of what the caller's `on_token` took for
@@ -433,15 +471,18 @@ class GenerationClient:
         log-probability (log-softmax of the raw logits), in step with the
         returned ids; cleared at the start of every attempt so restarts
         stay consistent. `top_sink` with `top_n > 0` likewise collects the
-        top-N (ids, logprobs) alternatives per step, computed client-side
-        from the same logits.
+        top-N (ids, logprobs) alternatives per step: float32 on the device
+        where the hop was answered with its token, else computed here
+        from the logits.
 
         A mid-generation failure (a node died — its KV cache with it)
         restarts the WHOLE generation under a fresh session, up to
         `session_retries` times: the swarm needs a beat to detect the death
         (record TTL) and adopt the orphaned stage, after which the full
         prompt re-prefills on the adopting replica. Deterministic given the
-        same seed, so a restart yields the same tokens.
+        same seed, so a restart yields the same tokens (the first from
+        numpy's generator under `seed`, the rest, where the executor
+        chooses them, under the jax key chain `seed` roots).
 
         `on_token` (optional async or sync callable) is invoked with each
         new token id as it is sampled — the streaming hook. On a retried
@@ -527,7 +568,8 @@ class GenerationClient:
     async def _step_resuming(
         self, session_id: str, toks: List[int], pos: int,
         known: List[int], resumes: List[int],
-    ) -> np.ndarray:
+        ask: Optional[Dict[str, Any]] = None,
+    ):
         """_traced_step with standby-promotion resume: a session_state
         409 carrying `resume_from` F means the answering replica holds
         the session's REPLICATED KV up to F (async standby replication,
@@ -538,9 +580,12 @@ class GenerationClient:
         (prompt + generated so far), `resumes` a one-element mutable
         budget shared across the generation so a flapping fleet can't
         loop us; exhausted/ineligible errors propagate into the ordinary
-        full-restart retry loop — exactly the pre-replication behavior."""
+        full-restart retry loop — exactly the pre-replication behavior.
+        A decode hop's `ask` rides the step and its retry unchanged (the
+        same key: the retried hop draws the same token); the replayed
+        chunks between are prefill and carry none."""
         try:
-            return await self._traced_step(session_id, toks, pos)
+            return await self._traced_step(session_id, toks, pos, ask)
         except ServerError as e:
             f = e.resume_from
             if f is None or not 0 <= int(f) < pos or resumes[0] <= 0:
@@ -560,7 +605,7 @@ class GenerationClient:
                 )
                 p += len(chunk)
             return await self._step_resuming(
-                session_id, toks, pos, known, resumes
+                session_id, toks, pos, known, resumes, ask
             )
 
     async def _generate_once(
@@ -640,18 +685,45 @@ class GenerationClient:
                 top_sink.append(top_logprobs_np(logits, top_n))
             if on_token is not None:
                 await self._emit_traced(on_token, tok)
+            # every decode hop asks for its token: an executor that samples
+            # on the device answers with it (and the session's next key:
+            # `seed` roots the chain on the first such hop), any other with
+            # logits, sampled here as the first token was. Which case
+            # applies is read off the reply.
+            ask = self._decode_ask(
+                s, logprob_sink is not None, top_n if top_sink is not None else 0
+            )
+            chain: Dict[str, Any] = {"seed": seed}
             while len(out) < max_new_tokens and tok != eos_token_id:
-                logits = await self._step_resuming(
-                    session_id, [tok], pos, known, resumes
+                res = await self._step_resuming(
+                    session_id, [tok], pos, known, resumes, {**ask, **chain}
                 )
                 pos += 1
-                tok = self._sample_traced(logits, rng, s)
+                if res.get("logits") is not None:
+                    logits = np.asarray(res["logits"])[0]
+                    tok = self._sample_traced(logits, rng, s)
+                    lp = logprob_np(logits, tok) if logprob_sink is not None else None
+                    top = top_logprobs_np(logits, top_n) if top_sink is not None else None
+                else:
+                    t0 = tracelib.now()
+                    tok, chain = int(res["tokens"][0][0]), {"key": res["key"]}
+                    lp = float(res["logprobs"][0]) if logprob_sink is not None else None
+                    top = (
+                        [int(i) for i in res["top_ids"][0][:top_n]],
+                        [float(x) for x in res["top_lps"][0][:top_n]],
+                    ) if top_sink is not None else None
+                    # the token was chosen inside the step: the span only
+                    # closes the per-token timeline (obs.merge counts them)
+                    self.tracer.record_span(
+                        "sample", "sample", t0, tracelib.now(),
+                        parent=tracelib.current(), attrs={"on": "device"},
+                    )
                 out.append(tok)
                 known.append(tok)
                 if logprob_sink is not None:
-                    logprob_sink.append(logprob_np(logits, tok))
+                    logprob_sink.append(lp)
                 if top_sink is not None:
-                    top_sink.append(top_logprobs_np(logits, top_n))
+                    top_sink.append(top)
                 if on_token is not None:
                     await self._emit_traced(on_token, tok)
         finally:
@@ -680,12 +752,9 @@ class GenerationClient:
         for sink in (logprob_sink, top_sink):
             if sink is not None:
                 sink.clear()  # deterministic restarts re-fill
-        want = {
-            "sampling": {"temperature": s.temperature, "top_k": s.top_k,
-                         "top_p": s.top_p, "min_p": s.min_p},
-            "logprobs": logprob_sink is not None,
-            "top_logprobs": top_n if top_sink is not None else 0,
-        }
+        want = self._decode_ask(
+            s, logprob_sink is not None, top_n if top_sink is not None else 0
+        )
         try:
             whole = len(prompt_ids) // blk * blk
             chunk = max(blk, self.prefill_chunk // blk * blk)

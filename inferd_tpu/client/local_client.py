@@ -4,7 +4,10 @@
 resume) on the node itself. Its hops enter the node's handler cores as
 Python calls: the envelope dict goes in as built, and a hop this node
 finishes comes back as the dict the executor's result was put in — no
-bytes packed, no socket, the logits row a view of what copy_out made. A
+bytes packed, no socket. Every decode hop asks for its token (base.
+_decode_ask) and a whole-model executor answers with it: the row's logits
+never leave the device; a prefill chunk's logits row is a view of what
+copy_out made. A
 hop that had to leave the node (a multi-stage chain, a wrong-stage entry,
 a rescue) comes back as the downstream reply's bytes and is unpacked here
 exactly as the HTTP client unpacks them. Which case applies is read off

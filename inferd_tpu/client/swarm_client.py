@@ -1,12 +1,13 @@
-"""Swarm generation client: drives the pipeline with client-side sampling.
+"""Swarm generation client: drives the pipeline, sampling where the last
+stage does not (client.base: every decode hop asks for its token).
 
 Capability parity with both reference clients — the swarm token loop
 (/root/reference/petals/send_message.py:27-60) and the gRPC generation
 client (/root/reference/models/qwen3/client/client.py:204-287) — unified:
-the client sends tokens to any stage-0 node and receives last-token logits
-from the last stage (relay unwind), samples locally (temperature/top-k/
-top-p, the reference's warper chain), and keeps per-session KV on the
-nodes. Pure numpy — importing this never initializes JAX (a TPU client
+the client sends tokens to any stage-0 node and receives from the last
+stage (relay unwind) the token it chose, or last-token logits, which it
+samples locally (temperature/top-k/top-p, the reference's warper chain),
+and keeps per-session KV on the nodes. Pure numpy — importing this never initializes JAX (a TPU client
 machine shouldn't claim a chip to sample 20 logits).
 
 The outer generation loop lives in client.base.GenerationClient (shared
@@ -120,6 +121,14 @@ class SwarmClient(GenerationClient):
     ) -> np.ndarray:
         result = await self._forward(session_id, tokens, start_pos)
         return np.asarray(result["logits"])[0]
+
+    async def _asking_step(
+        self, session_id: str, tokens: List[int], start_pos: int,
+        ask: Dict[str, Any],
+    ) -> Dict[str, Any]:
+        """The ask rides the payload's top level (stages that do not read
+        it relay hidden states on and the last answers with logits)."""
+        return await self._forward(session_id, tokens, start_pos, **ask)
 
     async def _block_length(self) -> int:
         """/stats `model.block_length` of the first entry node that answers;

@@ -193,24 +193,40 @@ class BatchedEngine:
         # static-sampling recompile-surface rationale
         _decode_k_serve = qwen3.make_decode_k_serve(cfg)
 
-        @partial(jax.jit, donate_argnames=("cache",))
-        def _decode_logits(params, cache: KVCache, toks, lengths, ads=None):
-            """One batched decode step returning last-token LOGITS [L, V]
-            (the serving path: sampling stays client-side — the reference
-            contract, client.py:204-287). Lanes not being served this step
-            simply advance nothing host-side; their computed rows are
-            discarded by the caller. `ads` (multi-tenant registry): the
-            stacked LoRA pools + per-lane slot ids — a mixed-adapter
-            window stays ONE dispatch (ops/lora pool contract). The third
-            value is the experts each lane chose in each sparse layer,
-            [Ls, L, K] int32 (the `moe.*` counters of /stats), or None for
-            a model without experts."""
+        @partial(jax.jit, donate_argnames=("cache",), static_argnames=("top_n",))
+        def _decode_logits(params, cache: KVCache, toks, lengths, ads=None,
+                           ask=None, top_n: int = 0):
+            """One batched decode step: last-token LOGITS [L, V] and, with
+            an `ask` (core.sampling.RowAsk: per-lane keys, temperature,
+            top-k, top-p, min-p, all traced), every lane's TOKEN chosen
+            here, after the head (samplib.choose_rows). The serving path
+            hands every step an ask (a lane that asked nothing is a greedy
+            row nobody reads), so any mix of sampling configs is this ONE
+            program; what a hop is answered with is the caller's choice
+            (runtime/batch_executor: the token, or the lane's logits row
+            where the hop carried no ask or one the sampler does not
+            cover). Lanes not being served this step simply advance nothing
+            host-side; their computed rows are discarded by the caller.
+            `ads` (multi-tenant registry): the stacked LoRA pools +
+            per-lane slot ids — a mixed-adapter window stays ONE dispatch
+            (ops/lora pool contract).
+
+            Without an ask the third value is the experts each lane chose
+            in each sparse layer, [Ls, L, K] int32 (the `moe.*` counters
+            of /stats), or None for a model without experts. With one it
+            is samplib.pack_rows' int32 [L, W]: token, next key, with
+            `top_n` > 0 (static: a width of BLOCK_TOP_WIDTHS) the
+            log-probabilities, and those experts: ONE small transfer."""
             pos = lengths[:, None]
             logits, nc, topi = qwen3.forward_cached(
                 params, cfg, toks[:, None], pos, cache, lengths,
                 real_end=lengths + 1, adapters=ads,
             )
-            return nc, logits[:, 0], topi[:, :, 0] if routes else None
+            last = logits[:, 0]
+            chosen = topi[:, :, 0] if routes else None
+            if ask is None:
+                return nc, last, chosen
+            return nc, last, samplib.choose_rows(last, ask, top_n, chosen)
 
         @partial(jax.jit, donate_argnames=("cache",))
         def _prefill_lane_logits(params, cache: KVCache, tokens, lane, start,
@@ -253,10 +269,11 @@ class BatchedEngine:
             lane's frontier would clamp the write back over rows of another
             position).
 
-            Returns (cache, tokens [L, B], made-known-at pass [L, B] (-1: by
-            the caller), keys' [L, 2], log-probabilities [L, B], top ids
-            [L, B, n], top log-probabilities [L, B, n], the experts every
-            row chose in every pass [passes, Ls, L, B, K])."""
+            Returns (cache, samplib.pack_bits of (tokens [L, B],
+            made-known-at pass [L, B] (-1: by the caller), keys' [L, 2],
+            log-probabilities [L, B], top ids [L, B, n], top
+            log-probabilities [L, B, n]): ONE array for one transfer; the
+            experts every row chose in every pass [passes, Ls, L, B, K])."""
             steps = cfg.denoising_steps
             per = B // steps
             lengths = jnp.where(live, lengths, jnp.minimum(lengths, cache.max_len - B))
@@ -310,7 +327,7 @@ class BatchedEngine:
                     params, cfg, x, pos, cache, lengths, real_end=end
                 )
             chosen.append(topi)
-            return (cache, x, at, keys, lps, tis, tls,
+            return (cache, samplib.pack_bits(x, at, keys, lps, tis, tls),
                     jnp.stack(chosen) if routes else None)
 
         @partial(jax.jit, donate_argnames=("cache",), static_argnames=("m",))
@@ -338,19 +355,24 @@ class BatchedEngine:
                 )
             return KVCache(k=nk, v=nv, length=cache.length, k_loc=kl, v_loc=vl)
 
-        @partial(jax.jit, donate_argnames=("cache",))
+        @partial(jax.jit, donate_argnames=("cache",), static_argnames=("top_n",))
         def _decode_logits_paged(params, cache: PagedKVCache, toks, lengths,
-                                 active, ads=None):
+                                 active, ads=None, ask=None, top_n: int = 0):
             """Paged sibling of _decode_logits: reads/writes go through
             the block table, and lanes NOT in this window (`active`
             False) drop their garbage writes — pool blocks are shared
-            property, unlike the dense layout's lane-private rows."""
+            property, unlike the dense layout's lane-private rows. With
+            an `ask` the third value is the packed rows (no experts: the
+            paged program returns no routing)."""
             pos = lengths[:, None]
             logits, nc, _ = qwen3.forward_cached(
                 params, cfg, toks[:, None], pos, cache, lengths,
                 real_end=lengths + 1, write_mask=active, adapters=ads,
             )
-            return nc, logits[:, 0]
+            last = logits[:, 0]
+            if ask is None:
+                return nc, last
+            return nc, last, samplib.choose_rows(last, ask, top_n)
 
         @partial(jax.jit, donate_argnames=("cache",))
         def _prefill_lane_logits_paged(params, cache: PagedKVCache, tokens,

@@ -10,10 +10,11 @@ running on host between steps.
 from __future__ import annotations
 
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from inferd_tpu.config import SamplingConfig
 from inferd_tpu.ops.attention import NEG_INF  # shared masking sentinel
@@ -178,3 +179,232 @@ def logprob_topn(
         return lp_tok, jnp.zeros((b, 0), jnp.int32), jnp.zeros((b, 0), jnp.float32)
     top_lps, top_ids = jax.lax.top_k(lps, n)
     return lp_tok, top_ids.astype(jnp.int32), top_lps
+
+
+# -- per-row sampling inside a decode step ------------------------------------
+#
+# The serving decode programs (core.batch `_decode_logits`, parallel.infer
+# `_step_raw_multi`) choose every row's token after the head, under that
+# row's OWN sampling parameters: traced arrays, so a step is one dispatch
+# whatever mix its rows ask and a config never seen before compiles nothing.
+
+#: candidates a row with top-k keeps (the static width of `lax.top_k`);
+#: `SamplingConfig`'s default 0.6 / 20 / 0.95 fits
+ROW_CANDIDATES = 64
+
+
+def rows_cover(temperature: float, top_k: int, top_p: float, min_p: float) -> bool:
+    """Whether `sample_rows` draws from this config's distribution: greedy;
+    a top-k within ROW_CANDIDATES (then top-p and min-p over the sorted
+    candidates); or no top-k and no top-p (temperature and min-p over the
+    whole row). Top-p alone needs the whole row sorted and a wider top-k
+    more candidates: the caller samples those rows from their logits."""
+    if temperature == 0.0 or 0 < top_k <= ROW_CANDIDATES:
+        return True
+    return top_k <= 0 and top_p >= 1.0
+
+
+def sample_rows(
+    logits: jax.Array,  # [L, V] float32
+    keys: jax.Array,  # [L, 2] uint32: each row's PRNG chain
+    temperature: jax.Array,  # [L] float32; 0 = greedy
+    top_k: jax.Array,  # [L] int32; <= 0 = none
+    top_p: jax.Array,  # [L] float32; >= 1 = none
+    min_p: jax.Array,  # [L] float32; <= 0 = none
+):
+    """One token a row, each under its own config (see `rows_cover`), the
+    warp chain of `sample` in its order: temperature, top-k, top-p, min-p,
+    a categorical draw. Returns (tokens [L] int32, keys' [L, 2]): a sampled
+    row draws under `split(key)[1]` and hands back `split(key)[0]`, a
+    greedy row's key comes back as it went in.
+
+    Greedy rows do not pay for the others: the candidates (`lax.top_k` at
+    ROW_CANDIDATES) and the whole-row draw each sit under a `lax.cond` on
+    "some row needs me", so a step of greedy rows runs one argmax."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    hot = temperature > 0.0
+    narrow = top_k > 0
+
+    def draw():
+        pairs = jax.vmap(jax.random.split)(keys)  # [L, 2, 2]
+        subs = pairs[:, 1]
+        t = jnp.where(hot, temperature, 1.0)[:, None]
+        # min-p in its logit form (min_p_filter): keep iff l >= l_max + ln(min_p)
+        floor = jnp.where(
+            min_p > 0.0, jnp.log(jnp.maximum(min_p, 1e-30)), -jnp.inf
+        )[:, None]
+
+        def among_candidates():
+            vals, idx = jax.lax.top_k(logits, ROW_CANDIDATES)  # sorted descending
+            vals = vals / t
+            rank = jnp.arange(ROW_CANDIDATES)[None, :]
+            vals = jnp.where(rank < top_k[:, None], vals, NEG_INF)
+            # top_p_filter over a sorted row: kept iff the mass before it is < p
+            probs = jax.nn.softmax(vals, axis=-1)
+            kept = (jnp.cumsum(probs, axis=-1) - probs) < top_p[:, None]
+            thresh = jnp.min(jnp.where(kept, vals, jnp.inf), axis=-1, keepdims=True)
+            vals = jnp.where(vals < thresh, NEG_INF, vals)
+            vals = jnp.where(vals < vals[:, :1] + floor, NEG_INF, vals)
+            choice = jax.vmap(jax.random.categorical)(subs, vals)
+            return jnp.take_along_axis(idx, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
+
+        def over_the_row():
+            vals = logits / t
+            lmax = jnp.max(vals, axis=-1, keepdims=True)
+            vals = jnp.where(vals < lmax + floor, NEG_INF, vals)
+            return jax.vmap(jax.random.categorical)(subs, vals).astype(jnp.int32)
+
+        few = jax.lax.cond(jnp.any(hot & narrow), among_candidates, lambda: greedy)
+        whole = jax.lax.cond(jnp.any(hot & ~narrow), over_the_row, lambda: greedy)
+        tok = jnp.where(hot, jnp.where(narrow, few, whole), greedy)
+        return tok, jnp.where(hot[:, None], pairs[:, 0], keys)
+
+    return jax.lax.cond(jnp.any(hot), draw, lambda: (greedy, keys))
+
+
+class RowAsk(NamedTuple):
+    """What the rows of one decode step ask of `sample_rows`: two arrays
+    over the rows (a pytree: it goes into the jitted step as is, two small
+    transfers a step)."""
+
+    keys: jax.Array  # [L, 2] uint32: each row's PRNG chain
+    warp: jax.Array  # [L, 4] float32: temperature, top_k, top_p, min_p
+
+    @classmethod
+    def greedy(cls, rows: int) -> "RowAsk":
+        """Host arrays for `rows` rows that ask nothing: greedy, zero keys.
+        The caller writes the asking rows' own values in (`put`)."""
+        warp = np.zeros((rows, 4), np.float32)
+        warp[:, 2] = 1.0
+        return cls(np.zeros((rows, 2), np.uint32), warp)
+
+    def put(self, row: int, sampling, key) -> None:
+        """Write one row's (temperature, top_k, top_p, min_p) and key."""
+        self.keys[row] = key
+        self.warp[row] = sampling
+
+    @classmethod
+    def of(cls, rows: int, asks) -> "tuple[RowAsk, int]":
+        """The host arrays of a step of `rows` rows from `asks`, {row: an
+        ask with `.sampling`, `.key`, `.top_n`
+        (runtime/executor.SampleAsk)}, and the widest top-n any asked: the
+        step's static log-probability width. Rows that asked nothing are
+        greedy rows nobody reads."""
+        ask = cls.greedy(rows)
+        for row, want in asks.items():
+            ask.put(row, want.sampling, want.key)
+        return ask, max((want.top_n for want in asks.values()), default=0)
+
+
+def choose_rows(logits: jax.Array, ask: RowAsk, top_n: int = 0, routed=None) -> jax.Array:
+    """The tail of a serving decode step, after the head: every row's token
+    under its own ask (`sample_rows`), with `top_n` > 0 (static) its log-
+    probability and top-n under the UNWARPED logits (`logprob_topn`), and
+    the experts the step routed to, packed for one transfer (`pack_rows`)."""
+    w = ask.warp
+    tok, keys = sample_rows(
+        logits, ask.keys, w[:, 0], w[:, 1].astype(jnp.int32), w[:, 2], w[:, 3]
+    )
+    lp = ti = tl = None
+    if top_n:
+        lp, ti, tl = logprob_topn(logits, tok, top_n)
+    return pack_rows(tok, keys, lp, ti, tl, routed)
+
+
+@jax.jit
+def logits_row(logits: jax.Array, row) -> jax.Array:
+    """One row of a step's logits (the row is traced: one program for
+    every row)."""
+    return jax.lax.dynamic_index_in_dim(logits, row, 0, keepdims=False)
+
+
+def logits_out(logits: jax.Array, rows):
+    """The float32 logits of the hops a step answers with logits, copied to
+    the host: ({row: [V]} or the whole [L, V], indexed by row either way;
+    the bytes that moved). One such hop: its [V] floats leave the device,
+    not the step's [L, V]; several: the whole array in ONE transfer (a
+    transfer costs the same whatever its size); none: nothing."""
+    if not rows:
+        return {}, 0
+    if len(rows) == 1:
+        out = np.asarray(logits_row(logits, rows[0]), np.float32)
+        return {rows[0]: out}, out.nbytes
+    out = np.asarray(logits, np.float32)
+    return out, out.nbytes
+
+
+def row_replies(packed, top_n: int, asks, k: int = 0):
+    """What a step answers its asking hops with, from the ONE array it
+    handed the host (`pack_rows`): ({row: {"tokens": [[id]], "key": [2],
+    and where the row asked for log-probabilities "logprobs": [lp],
+    "top_ids": [[n]], "top_lps": [[n]]}} for the rows of `asks`, as plain
+    lists; the experts [sparse layers, L, K] | None)."""
+    tok, keys, lps, tis, tls, routed = unpack_rows(packed, top_n, k)
+    tok, keys = tok.tolist(), keys.tolist()
+    if top_n:
+        lps, tis, tls = lps.tolist(), tis.tolist(), tls.tolist()
+    out = {}
+    for row, want in asks.items():
+        out[row] = {"tokens": [[tok[row]]], "key": keys[row]}
+        if want.want:
+            out[row].update(
+                logprobs=[lps[row]], top_ids=[tis[row][:want.want]],
+                top_lps=[tls[row][:want.want]],
+            )
+    return out, routed
+
+
+def pack_bits(*arrays: jax.Array) -> jax.Array:
+    """Arrays of one leading axis [L, ...] and 32-bit elements as ONE int32
+    array [L, W], each flattened a row and laid side by side, floats and
+    unsigned as their bits: what a step hands the host leaves the device in
+    one transfer (a transfer costs the same whatever its size, so five
+    small ones cost five times one). `unpack_bits` is the inverse."""
+    cols = [
+        (a if a.dtype == jnp.int32 else jax.lax.bitcast_convert_type(a, jnp.int32))
+        .reshape(a.shape[0], -1)
+        for a in arrays
+    ]
+    return jnp.concatenate(cols, axis=1)
+
+
+def unpack_bits(packed, *parts):
+    """`pack_bits` undone on the host, over the numpy array the transfer
+    made: one array a part, each part (dtype, the shape after the leading
+    axis); the last part's shape may hold one -1 (it takes what is left)."""
+    out, at = [], 0
+    for dtype, tail in parts:
+        n = packed.shape[1] - at if -1 in tail else int(np.prod(tail, dtype=int))
+        out.append(packed[:, at:at + n].view(dtype).reshape(len(packed), *tail))
+        at += n
+    return out
+
+
+def pack_rows(tok, keys, lp=None, top_ids=None, top_lps=None, routed=None) -> jax.Array:
+    """What a decode step hands the host (`pack_bits`): the token, the
+    row's next key, then with log-probabilities the token's own, the top
+    ids and theirs, then the experts the row chose, [sparse layers, L, K]
+    with the rows brought to the front."""
+    parts = [tok.astype(jnp.int32), keys]
+    if lp is not None:
+        parts += [lp, top_ids, top_lps]
+    if routed is not None:
+        parts.append(jnp.moveaxis(routed, 1, 0))
+    return pack_bits(*parts)
+
+
+def unpack_rows(packed, top_n: int, k: int = 0):
+    """`pack_rows` undone on the host: (tokens [L], keys [L, 2] uint32,
+    lp [L] | None, top ids [L, n] | None, top log-probabilities [L, n] |
+    None, experts [sparse layers, L, K] | None). `top_n` 0 = the step
+    computed no log-probabilities; `k` > 0 = it returned the experts each
+    row chose, `k` a layer."""
+    parts = [(np.int32, ()), (np.uint32, (2,))]
+    if top_n:
+        parts += [(np.float32, ()), (np.int32, (top_n,)), (np.float32, (top_n,))]
+    if k:
+        parts.append((np.int32, (-1, k)))
+    out = unpack_bits(packed, *parts)
+    tok, keys = out[:2]
+    lp, ti, tl = out[2:5] if top_n else (None, None, None)
+    return tok, keys, lp, ti, tl, np.moveaxis(out[-1], 0, 1) if k else None

@@ -642,9 +642,8 @@ class PipelinedEngine:
 
         @partial(jax.jit, donate_argnames=("caches",))
         def _step_raw(params, caches: PipelinedCaches, tokens, slot, real_len, reset):
-            # server-side raw step: one slot, no sampling — the node serving
-            # path keeps the reference's client-side-sampling contract
-            # (client.py:204-287), so the last stage ships logits
+            # server-side raw step: one slot (a prefill chunk), no sampling:
+            # the caller samples the first token from the logits it ships
             lengths0 = jnp.where(
                 reset, caches.lengths.at[slot].set(0), caches.lengths
             )
@@ -657,14 +656,20 @@ class PipelinedEngine:
             )
             return new, logits[0]
 
-        @partial(jax.jit, donate_argnames=("caches",))
-        def _step_raw_multi(params, caches: PipelinedCaches, toks, active):
+        @partial(jax.jit, donate_argnames=("caches",), static_argnames=("top_n",))
+        def _step_raw_multi(params, caches: PipelinedCaches, toks, active,
+                            ask=None, top_n: int = 0):
             # server-side MULTI-slot decode: co-arriving sessions ride one
             # pass as the ROWS of its one microbatch (_rows_pass: pp ticks,
             # each stage reads its weights once for all of them). toks [MB]
             # int32, active [MB] bool; inactive slots compute at their
             # frontier but write nothing, do not advance and do not
-            # surface. Returns logits [MB, V].
+            # surface. Returns logits [MB, V] and, with an `ask`
+            # (core.sampling.RowAsk: per-slot keys and sampling, traced),
+            # every slot's token chosen here, after the head, packed with
+            # its next key and (`top_n` > 0, static) its log-probabilities
+            # for one transfer (core.sampling.choose_rows, the lane
+            # executor's sampler).
             nk, nv, nkl, nvl, logits = rows_passfn(
                 params, toks, active, caches, caches.lengths
             )
@@ -672,7 +677,9 @@ class PipelinedEngine:
             new = PipelinedCaches(
                 k=nk, v=nv, lengths=new_lengths, k_loc=nkl, v_loc=nvl
             )
-            return new, logits
+            if ask is None:
+                return new, logits
+            return new, logits, samplib.choose_rows(logits, ask, top_n)
 
         @partial(jax.jit, donate_argnames=("caches",), static_argnames=("m",))
         def _fork_slot(caches: PipelinedCaches, src, dst, prefix_len, m: int):
@@ -1003,31 +1010,44 @@ class PipelinedEngine:
             at["bytes"] = out.nbytes
         return out
 
-    def step_slots(self, tokens_by_slot) -> dict:
+    def step_slots(self, tokens_by_slot, asks=None) -> dict:
         """Decode ONE token for several slots in a single pipeline pass
         (requires batch == 1 per slot — the serving shape). tokens_by_slot:
-        {slot: token}; returns {slot: logits [V] float32}."""
+        {slot: token}; `asks`: {slot: an ask with `.sampling`, `.key`,
+        `.want`, `.top_n` (runtime/executor.SampleAsk)} for the slots whose
+        token the pass chooses. Returns, a slot, its reply
+        (core.sampling.row_replies: {"tokens": [[id]], "key", ...}) where
+        it asked, else its logits [V] float32: ONE small transfer for all
+        the asked; a slot without an ask gets its logits row (that row
+        alone where it is the only one, else the pass's whole [MB, V])."""
         if self.batch != 1:
             raise ValueError("step_slots supports batch=1 slots only")
+        asks = asks or {}
         toks = np.zeros((self.mb,), np.int32)
         active = np.zeros((self.mb,), bool)
         for slot, tok in tokens_by_slot.items():
             toks[slot] = tok
             active[slot] = True
+        ask, top_n = samplib.RowAsk.of(self.mb, asks)
+        plain = [slot for slot in tokens_by_slot if slot not in asks]
         live = len(tokens_by_slot)
         with tracelib.region(
             self.tracer, "device", kind="decode", tokens=live, cobatch=live,
             program=program_name(self._step_raw_multi),
         ):
-            self.caches, logits = self._step_raw_multi(
-                self.params, self.caches, jnp.asarray(toks), jnp.asarray(active)
+            self.caches, logits, packed = self._step_raw_multi(
+                self.params, self.caches, toks, active, ask=ask, top_n=top_n,
             )
-            logits.block_until_ready()
+            packed.copy_to_host_async()  # queued behind the pass: on its way when it is done
+            packed.block_until_ready()
         self._count_pass(self.mb, live)
         with tracelib.region(self.tracer, "copy_out") as at:
-            out = np.asarray(logits, np.float32)  # [MB, V]
-            at["bytes"] = out.nbytes
-        return {slot: out[slot] for slot in tokens_by_slot}
+            host = np.asarray(packed)
+            rows, moved = samplib.logits_out(logits, plain)
+            at["bytes"] = host.nbytes + moved
+        replies, _ = samplib.row_replies(host, top_n, asks)
+        return {slot: replies[slot] if slot in replies else rows[slot]
+                for slot in tokens_by_slot}
 
     def _count_pass(self, rows: int, live: int) -> None:
         """One serving pass of one microbatch of `rows` rows, `live` of them

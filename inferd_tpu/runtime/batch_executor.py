@@ -4,7 +4,8 @@ coalesce into ONE device step.
 The reference serves strictly one request at a time per node (a lone
 pipeline pass per token, /root/reference/petals/send_message.py:27-49 /
 server.py:25-54); every session re-reads all the weights per token. This
-executor keeps the node's `/forward` + client-side-sampling contract but
+executor keeps the node's `/forward` contract (a hop that asks for no
+token is answered with logits, to be sampled by its caller) but
 maps sessions to lanes of core.batch.BatchedEngine and batches the
 single-token decode steps of whichever sessions arrive within a short
 window — aggregate tok/s then scales with concurrency instead of dividing
@@ -15,8 +16,11 @@ Concurrency design (process() runs on the node's worker thread pool):
     pending batch; the FIRST arrival becomes the flusher — it waits out a
     step that is still running, then (no lock held) for the lanes the
     last two steps served, takes the device lock, runs one batched step
-    for every lane pending AT THAT MOMENT, and distributes each lane's
-    logits to its waiting thread (runtime/window.py, formation);
+    for every lane pending AT THAT MOMENT, and hands each waiting thread
+    what its hop asked for: the token the step chose under the hop's own
+    sampling (a decode hop's ask, runtime/executor.parse_decode_ask: what
+    the generation loop sends with every decode hop), or the lane's logits
+    (runtime/window.py, formation; docs/SERVING.md "A decode hop's ask");
   * prefill chunks (multi-token or unknown session) run solo under the
     same device lock (per-lane cache writes, other lanes untouched);
   * a model generated by blocks (cfg.is_block_diffusion) has a third kind
@@ -24,8 +28,8 @@ Concurrency design (process() runs on the node's worker thread pool):
     frontier that is a multiple of the block length. Block calls of
     different lanes ride ONE BatchedEngine._block_step through the same
     window as decode steps do; the frontier advances by a block a call;
-  * whole-model executor: is_first and is_last (tokens in, last-token
-    logits out) — like MeshExecutor it hosts a 1-stage swarm topology.
+  * whole-model executor: is_first and is_last (tokens in, a token or
+    last-token logits out) — like MeshExecutor it hosts a 1-stage swarm topology.
 """
 
 from __future__ import annotations
@@ -41,12 +45,15 @@ from inferd_tpu.config import ModelConfig
 from inferd_tpu.core.batch import BatchedEngine
 from inferd_tpu.core.cache import RING_MARGIN, sync_paged
 from inferd_tpu.core import prefix as prefixlib
+from inferd_tpu.core import sampling as samplib
 from inferd_tpu.core.generate import bucket_len
 from inferd_tpu.obs import trace as tracelib
 from inferd_tpu.obs.devtel import program_name
 from inferd_tpu.obs.events import emit_safely
 from inferd_tpu.runtime.adapters import AdapterBindingMixin
-from inferd_tpu.runtime.executor import BlockCall, cache_intact, call_kind, parse_block
+from inferd_tpu.runtime.executor import (
+    BlockCall, cache_intact, call_kind, parse_block, parse_decode_ask, parse_kstep,
+)
 from inferd_tpu.runtime.spec_serving import SpecForkMiss, SpecServing
 from inferd_tpu.runtime.window import WindowedBatcher
 from inferd_tpu.utils import lockwatch
@@ -64,7 +71,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
     """Whole-model, lane-per-session executor with windowed decode batching.
 
     Node executor contract (runtime/node.py): process(session_id, payload)
-    -> {"logits": [1, V], ...}; end_session(session_id).
+    -> {"logits": [1, V], ...} or, for a one-token decode hop that asks
+    for its token (runtime/executor.parse_decode_ask), {"tokens": [[id]],
+    "key", ...}; end_session(session_id).
     """
 
     is_first = True
@@ -105,6 +114,10 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         # windows interleave instead of stalling behind a long admission
         self.prefill_chunk = int(prefill_chunk)
         self.prefill_tokens = 0  # tokens actually computed by prefill
+        # one-token decode hops answered with a token chosen on the device,
+        # and those answered with their [V] logits row (/stats `executor`)
+        self.sampled_rows = 0
+        self.logit_rows = 0
         # cumulative expert-routing counters over decode steps, or over
         # the passes of block steps (/stats `executor` `moe.*`); None where
         # the program returns no routing (no experts, or a paged / windowed
@@ -615,8 +628,6 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 res = self._batcher.submit((lane, toks[0, :blk], call))
                 return {**res, "real_len": blk, "start_pos": start_pos}
             if decode:
-                from inferd_tpu.runtime.executor import parse_kstep
-
                 ks = parse_kstep(payload, self.cap - start_pos)
                 if ks is not None:
                     # multi-step fused decode: K on-device-sampled tokens
@@ -626,8 +637,14 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                         session_id, lane, int(toks[0, 0]), ks
                     )
                     return {**res, "start_pos": start_pos}
-                logits = self._decode_batched(session_id, lane, int(toks[0, 0]))
-                saved = 0
+                # a hop that asks for its token (parse_decode_ask) is
+                # answered with it; any other with its logits row
+                res = self._decode_batched(
+                    session_id, lane, int(toks[0, 0]), parse_decode_ask(payload)
+                )
+                if isinstance(res, dict):
+                    return {**res, "real_len": 1, "start_pos": start_pos}
+                logits, saved = res, 0
             else:
                 logits, saved = self._prefill_solo(
                     session_id, lane, toks, start_pos, real_len,
@@ -788,18 +805,19 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         with the rest). A drain that finds nothing (every waiting entry
         was invalidated meanwhile) runs no program and counts no step.
 
-        Entries partition into the classic logits contract (client-side
-        sampling, one token per dispatch) and multi-step fused decode
-        (`ks` payload from parse_kstep: K on-device-sampled tokens per
-        dispatch). K-step entries sharing a sampling config fuse into ONE
-        K-step scan (models/qwen3.decode_k via the engine's
+        Entries partition into one-token hops (ONE _decode_dispatch for
+        all of them, whatever each asks: a token chosen on the device
+        under its own sampling, or its logits row) and multi-step fused
+        decode (`ks` payload from parse_kstep: K on-device-sampled tokens
+        per dispatch). K-step entries sharing a sampling config fuse into
+        ONE K-step scan (models/qwen3.decode_k via the engine's
         _decode_k_serve) with K = the group's minimum budget-clamped
         request — co-batched lanes decode K steps per window when every
         lane has >= K budget, degrading toward K=1 at boundaries. A lane
         whose `eos` fires mid-window deactivates in-graph; its result
         carries only the really-committed tokens.
 
-        Failure isolation is per DISPATCH: a window can run one legacy
+        Failure isolation is per DISPATCH: a window can run one single
         step plus several K-step group scans, and a raising dispatch must
         not clobber results another dispatch already committed (lengths
         advanced, e.result set) — each dispatch marks only ITS entries
@@ -810,8 +828,6 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         so the window stops dispatching and fails the remaining entries
         with a clear error (executor.cache_intact) — committed results
         still stand."""
-        import jax.numpy as jnp
-
         from inferd_tpu.runtime.executor import fuse_kstep_group, kstep_hi
 
         poisoned: Optional[Exception] = None
@@ -822,65 +838,20 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             if blocks:  # a model generated by blocks has no other batched call
                 self._run_block_steps(blocks)
                 return
-            legacy = [e for e in entries if e.payload[2] is None]
-            kstep = [e for e in entries if e.payload[2] is not None]
-            if legacy:
+            # one-token hops, with an ask (SampleAsk) or without (None),
+            # ride ONE program; only K-step calls dispatch apart
+            single = [e for e in entries if not isinstance(e.payload[2], dict)]
+            kstep = [e for e in entries if isinstance(e.payload[2], dict)]
+            if single:
                 try:
-                    with self._mu:
-                        lens = list(self.engine.lengths)  # snapshot under _mu
-                        ids = list(self._lane_slot)
-                    ads = self._ads(ids)
-                    toks = [0] * self.engine.lanes
-                    active = [False] * self.engine.lanes
-                    for e in legacy:
-                        lane, token, _ks = e.payload
-                        toks[lane] = token
-                        active[lane] = True
-                    routed = None  # the paged program returns no routing
-                    if self.pool is not None:
-                        self.engine.cache, logits = (
-                            self.engine._decode_logits_paged(
-                                self.engine.params, self._sync_paged(),
-                                jnp.asarray(toks, jnp.int32),
-                                jnp.asarray(lens, jnp.int32),
-                                jnp.asarray(active),
-                                ads=ads,
-                            )
-                        )
-                    else:
-                        fn = self.engine._decode_logits
-                        with tracelib.region(
-                            self.tracer, "device", kind="decode",
-                            tokens=len(legacy), cobatch=len(legacy),
-                            program=program_name(fn),
-                        ):
-                            self.engine.cache, logits, routed = fn(
-                                self.engine.params, self.engine.cache,
-                                jnp.asarray(toks, jnp.int32),
-                                jnp.asarray(lens, jnp.int32),
-                                ads=ads,
-                            )
-                            logits.block_until_ready()
-                    with tracelib.region(self.tracer, "copy_out") as at:
-                        out = np.asarray(logits, np.float32)
-                        at["bytes"] = out.nbytes
-                    self._batcher.stamp_out(legacy)  # `deliver` starts
-                    if routed is not None:  # came out with the logits: no wait
-                        self._count_routing(
-                            np.asarray(routed)[:, [e.payload[0] for e in legacy]]
-                        )
-                    with self._mu:
-                        for e in legacy:
-                            self.engine.lengths[e.payload[0]] += 1
-                    for e in legacy:
-                        e.result = out[e.payload[0]]
+                    self._decode_dispatch(single)
                 except Exception as exc:
-                    for e in legacy:
+                    for e in single:
                         e.error = exc
                     # the drain counted every live entry as served; net
                     # failed entries to zero so /stats batched_tokens
                     # stays token-true
-                    self._batcher.n_served -= len(legacy)
+                    self._batcher.n_served -= len(single)
                     if not cache_intact(self.engine.cache):
                         poisoned = exc
             groups: Dict[tuple, list] = {}
@@ -896,7 +867,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                             "KV cache invalidated by an earlier dispatch "
                             f"failure in this window: {poisoned}"
                         )
-                    self._batcher.n_served -= len(grp)  # see legacy note
+                    self._batcher.n_served -= len(grp)  # see the single step's note
                     continue
                 try:
                     with self._mu:
@@ -915,7 +886,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                     with self._mu:
                         for e in grp:
                             lane = e.payload[0]
-                            n = int(n_new[lane])  # jaxlint: disable=J003 -- n_new is a HOST array (fuse_kstep_group materialized it)
+                            n = int(n_new[lane])
                             old = self.engine.lengths[lane]
                             self.engine.lengths[lane] = old + n
                             self._lane_hi[lane] = max(
@@ -925,13 +896,13 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                     served_tokens = 0
                     for e in grp:
                         lane = e.payload[0]
-                        n = int(n_new[lane])  # jaxlint: disable=J003 -- host array
+                        n = int(n_new[lane])
                         served_tokens += n
                         e.result = {
-                            "tokens": [seq[:n, lane].tolist()],  # jaxlint: disable=J003 -- host array row unpack, no device sync
+                            "tokens": [seq[:n, lane].tolist()],
                             "real_len": n,
                             "decode_steps": kg,
-                            "key": nkeys[lane].tolist(),  # jaxlint: disable=J003 -- host array row unpack, no device sync
+                            "key": nkeys[lane].tolist(),
                         }
                     # token-true stats: the drain counts one
                     # served unit per ENTRY; a K-step entry really served
@@ -941,9 +912,71 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 except Exception as exc:
                     for e in grp:
                         e.error = exc
-                    self._batcher.n_served -= len(grp)  # see legacy note
+                    self._batcher.n_served -= len(grp)  # see the single step's note
                     if not cache_intact(self.engine.cache):
                         poisoned = exc
+
+    def _decode_dispatch(self, entries) -> None:
+        """ONE decode step (under _dev_lock) for the one-token hops of a
+        drain, whatever each asks: the program chooses every lane's token
+        under the lane's own sampling (core.sampling.choose_rows: traced
+        per lane, so a mix of configs is one program and a new one compiles
+        nothing), and ONE small array leaves the device: tokens, next keys,
+        the log-probabilities at the widest top-n a lane asked, the routed
+        experts. An entry with an ask is answered with its token; one
+        without (a raw /forward, an ask the sampler does not cover) with
+        its logits row: that row alone where it is the only one, else the
+        step's whole [L, V]."""
+        eng = self.engine
+        with self._mu:
+            # snapshot under _mu; host arrays in: the call places them all at once
+            lens = np.asarray(eng.lengths, np.int32)
+            ids = list(self._lane_slot)
+        ads = self._ads(ids)
+        toks = np.zeros((eng.lanes,), np.int32)
+        active = np.zeros((eng.lanes,), bool)
+        for e in entries:
+            toks[e.payload[0]], active[e.payload[0]] = e.payload[1], True
+        asks = {e.payload[0]: e.payload[2] for e in entries if e.payload[2] is not None}
+        plain = [e.payload[0] for e in entries if e.payload[2] is None]
+        ask, top_n = samplib.RowAsk.of(eng.lanes, asks)
+        if self.pool is not None:
+            eng.cache, logits, packed = eng._decode_logits_paged(
+                eng.params, self._sync_paged(), toks, lens, active,
+                ads=ads, ask=ask, top_n=top_n,
+            )
+            packed.copy_to_host_async()
+        else:
+            fn = eng._decode_logits
+            with tracelib.region(
+                self.tracer, "device", kind="decode", tokens=len(entries),
+                cobatch=len(entries), program=program_name(fn),
+            ):
+                eng.cache, logits, packed = fn(
+                    eng.params, eng.cache, toks, lens, ads=ads, ask=ask, top_n=top_n,
+                )
+                # the transfer is queued behind the step: the small array
+                # is on its way when the device is done
+                packed.copy_to_host_async()
+                packed.block_until_ready()
+        with tracelib.region(self.tracer, "copy_out") as at:
+            host = np.asarray(packed)
+            rows, moved = samplib.logits_out(logits, plain)
+            at["bytes"] = host.nbytes + moved
+        self._batcher.stamp_out(entries)  # `deliver` starts
+        replies, routed = samplib.row_replies(
+            host, top_n, asks, self.cfg.num_experts_per_tok if eng.routes else 0
+        )
+        if routed is not None:
+            self._count_routing(routed[:, [e.payload[0] for e in entries]])
+        with self._mu:
+            for e in entries:
+                eng.lengths[e.payload[0]] += 1
+            self.sampled_rows += len(asks)
+            self.logit_rows += len(plain)
+        for e in entries:
+            lane = e.payload[0]
+            e.result = replies[lane] if lane in replies else rows[lane]
 
     def _run_block_steps(self, entries) -> None:
         """The block calls of one drain (under _dev_lock): ONE
@@ -999,18 +1032,22 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             cobatch=len(grp), rows=len(grp) * blk * passes, passes=passes,
             program=program_name(eng._block_step),
         ):
-            eng.cache, *out, chosen = eng._block_step(
+            top_n = max(e.payload[2].top_n for e in grp)
+            eng.cache, packed, chosen = eng._block_step(
                 eng.params, eng.cache, jnp.asarray(toks), jnp.asarray(known),
                 jnp.asarray(lens, jnp.int32), jnp.asarray(live), jnp.asarray(keys),
-                temperature=t, top_k=tk, top_p=tp, min_p=mp,
-                top_n=max(e.payload[2].top_n for e in grp),
+                temperature=t, top_k=tk, top_p=tp, min_p=mp, top_n=top_n,
             )
-            out[0].block_until_ready()
+            packed.copy_to_host_async()  # queued behind the step (see _decode_dispatch)
+            packed.block_until_ready()
         with tracelib.region(self.tracer, "copy_out") as at:
-            host = [np.asarray(a) for a in out]
-            at["bytes"] = sum(a.nbytes for a in host)
+            host = np.asarray(packed)  # ONE transfer (samplib.pack_bits)
+            at["bytes"] = host.nbytes
         self._batcher.stamp_out(grp)  # `deliver` starts
-        x, order, nkeys, lps, tis, tls = (a.tolist() for a in host)
+        x, order, nkeys, lps, tis, tls = (a.tolist() for a in samplib.unpack_bits(
+            host, (np.int32, (blk,)), (np.int32, (blk,)), (np.uint32, (2,)),
+            (np.float32, (blk,)), (np.int32, (blk, top_n)), (np.float32, (blk, top_n)),
+        ))
         if chosen is not None:  # came out with the tokens: no wait
             routed = np.asarray(chosen)[:, :, lanes]  # [passes, layers, live, block, k]
             for per_pass in routed.reshape(*routed.shape[:2], -1, routed.shape[-1]):
@@ -1553,6 +1590,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 lanes=self.engine.lanes,
                 lanes_busy=self.engine.lanes - len(self.engine.free),
                 prefill_tokens=self.prefill_tokens,
+                sampled_rows=self.sampled_rows,
+                logit_rows=self.logit_rows,
                 **self._batcher.stats(),
             )
             if self.pool is not None:

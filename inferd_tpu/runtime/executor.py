@@ -34,6 +34,7 @@ import numpy as np
 from inferd_tpu.config import ModelConfig
 from inferd_tpu.core.cache import RING_MARGIN, KVCache, grow
 from inferd_tpu.core.generate import bucket_len
+from inferd_tpu.core.sampling import rows_cover
 from inferd_tpu.models import qwen3
 from inferd_tpu.parallel.stages import StageSpec
 
@@ -121,15 +122,72 @@ class SessionStore:
             self._last_used.pop(oldest, None)
 
 
+#: top log-probabilities a step that samples on the device computes: none,
+#: or one of these widths (static in the program, so every width is one
+#: compiled variant; the node's warm-up compiles none and the first, what
+#: the benchmark's probe asks)
+BLOCK_TOP_WIDTHS = (8, 64)
+
+
+class SampleAsk(NamedTuple):
+    """How a hop asks for its tokens to be chosen on the device."""
+
+    sampling: tuple  # (temperature, top_k, top_p, min_p)
+    want: int  # log-probabilities: 0 none, else the top-n asked (the token's own: 1)
+    key: Any  # uint32 [2]: the session's PRNG chain
+
+    @property
+    def top_n(self) -> int:
+        """The width of BLOCK_TOP_WIDTHS that serves `want` (0: none is
+        asked; None: none is wide enough)."""
+        if not self.want:
+            return 0
+        return next((w for w in BLOCK_TOP_WIDTHS if w >= self.want), None)
+
+
+def root_key(seed: int) -> np.ndarray:
+    """jax.random.PRNGKey(seed) as host uint32 [2]. For the seeds a 32-bit
+    PRNGKey takes it is [0, seed] (threefry), written here: the first
+    decode hop of every generation carries a seed, and its parse should
+    dispatch nothing to the device."""
+    if -2**31 <= seed < 2**31:
+        return np.array([0, seed & 0xFFFFFFFF], np.uint32)
+    return np.asarray(jax.random.PRNGKey(seed), np.uint32)
+
+
+def parse_ask(d: Dict[str, Any]) -> SampleAsk:
+    """The ONE reader of a sampling ask, whichever call carries it (a
+    K-step call and a decode hop at the payload's top level, a block call
+    inside its `block` key): optional "sampling" ({temperature, top_k,
+    top_p, min_p}: greedy default), optional "key" ([2] uint32, the
+    session's PRNG chain as the last reply returned it) / "seed" (derives
+    the chain's root when no key rides yet), optional "top_logprobs": n
+    (with "logprobs": true alone, the token's own)."""
+    s = d.get("sampling") or {}
+    sampling = (
+        float(s.get("temperature", 0.0)), int(s.get("top_k", 0)),
+        float(s.get("top_p", 1.0)), float(s.get("min_p", 0.0)),
+    )
+    if not 0.0 <= sampling[3] < 1.0:
+        raise ValueError(f"min_p must be in [0, 1), got {sampling[3]}")
+    key = d.get("key")
+    if key is None:
+        key = root_key(int(d.get("seed", 0) or 0))
+    return SampleAsk(
+        sampling=sampling,
+        want=max(int(d.get("top_logprobs", 0) or 0), 1 if d.get("logprobs") else 0),
+        key=np.asarray(key, np.uint32),
+    )
+
+
 def parse_kstep(payload: Dict[str, Any], budget: int):
     """Parse a multi-step fused-decode request out of a /forward payload,
     shared by all three executors (solo/batched/stage-batch) so the wire
     contract cannot drift.
 
-    Payload keys: "decode_steps" (requested K), optional "sampling"
-    ({temperature, top_k, top_p, min_p} — greedy default), optional "eos"
-    (stop token id; absent = none), optional "key" ([2] uint32 per-session
-    PRNG chain) / "seed" (derives the chain's root when no key rides yet).
+    Payload keys: "decode_steps" (requested K), optional "eos" (stop token
+    id; absent = none), and the sampling ask as parse_ask reads it
+    ("sampling", "key" / "seed").
 
     Returns None when the payload requests no multi-step decode, else
     {"k": K clamped into [1, budget] (falling back toward K=1 at budget
@@ -141,23 +199,28 @@ def parse_kstep(payload: Dict[str, Any], budget: int):
         return None
     if budget < 1:
         raise BufferError(f"KV overflow: no budget for a decode step ({budget})")
-    s = payload.get("sampling") or {}
-    sampling = (
-        float(s.get("temperature", 0.0)),
-        int(s.get("top_k", 0)),
-        float(s.get("top_p", 1.0)),
-        float(s.get("min_p", 0.0)),
-    )
-    key = payload.get("key")
-    if key is None:
-        key = jax.random.PRNGKey(int(payload.get("seed", 0) or 0))
+    ask = parse_ask(payload)
     eos = payload.get("eos")
     return {
         "k": max(1, min(k_req, int(budget))),
-        "sampling": sampling,
+        "sampling": ask.sampling,
         "eos": -1 if eos is None else int(eos),
-        "key": np.asarray(key, np.uint32),
+        "key": ask.key,
     }
+
+
+def parse_decode_ask(payload: Dict[str, Any]) -> Optional[SampleAsk]:
+    """The ask of a one-token decode hop that a step's sampler can honour
+    (core.sampling.sample_rows), or None: the hop carries none (no
+    "sampling" key: a raw /forward), or one outside the device form (top-p
+    with no top-k, a top-k over the candidates, more top log-probabilities
+    than the widest variant): that hop is answered with its logits."""
+    if payload.get("sampling") is None:
+        return None
+    ask = parse_ask(payload)
+    if ask.top_n is None or not rows_cover(*ask.sampling):
+        return None
+    return ask
 
 
 def call_kind(payload: Dict[str, Any]) -> str:
@@ -182,12 +245,6 @@ def call_kind(payload: Dict[str, Any]) -> str:
     return "decode" if decode else "prefill"
 
 
-#: top log-probabilities a block step computes: none, or one of these widths
-#: (static in the program, so every width is one compiled variant; the node's
-#: warm-up compiles none and the first, what the benchmark's probe asks)
-BLOCK_TOP_WIDTHS = (8, 64)
-
-
 class BlockCall(NamedTuple):
     """One lane's part of a block step (BatchedEngine._block_step)."""
 
@@ -199,31 +256,20 @@ class BlockCall(NamedTuple):
 
 def parse_block(payload: Dict[str, Any], block_length: int) -> BlockCall:
     """The `block` key of a /forward payload: {"known": leading places
-    filled, optional "sampling" / "key" / "seed" as a K-step call has them,
-    optional "top_logprobs": n (with "logprobs": true alone, the token's
-    own)}."""
+    filled, and the sampling ask as parse_ask reads it}."""
     b = payload["block"]
     known = int(b.get("known", 0))
     if not 0 <= known < block_length:
         raise ValueError(f"block call: known {known} outside [0, {block_length})")
-    s = b.get("sampling") or {}
-    sampling = (
-        float(s.get("temperature", 0.0)), int(s.get("top_k", 0)),
-        float(s.get("top_p", 1.0)), float(s.get("min_p", 0.0)),
-    )
+    ask = parse_ask(b)
+    sampling = ask.sampling
     if sampling[0] == 0.0:
         sampling = (0.0, 0, 1.0, 0.0)  # greedy reads no filter: one variant
-    want = max(int(b.get("top_logprobs", 0)), 1 if b.get("logprobs") else 0)
-    if want > BLOCK_TOP_WIDTHS[-1]:
-        raise ValueError(f"block call: top_logprobs {want} over {BLOCK_TOP_WIDTHS[-1]}")
-    key = b.get("key")
-    if key is None:
-        key = jax.random.PRNGKey(int(b.get("seed", 0) or 0))
-    return BlockCall(
-        known=known, sampling=sampling,
-        top_n=next((w for w in BLOCK_TOP_WIDTHS if w >= want), 0) if want else 0,
-        key=np.asarray(key, np.uint32),
-    )
+    if ask.top_n is None:
+        raise ValueError(
+            f"block call: top_logprobs {ask.want} over {BLOCK_TOP_WIDTHS[-1]}"
+        )
+    return BlockCall(known=known, sampling=sampling, top_n=ask.top_n, key=ask.key)
 
 
 def cache_intact(cache) -> bool:

@@ -7,8 +7,10 @@ a node that owns N chips hosts the WHOLE model pipelined over an in-mesh
 `pp` axis (parallel/infer.py) behind the SAME `/forward` surface — the
 inter-stage hop becomes a `lax.ppermute` over ICI inside one jitted SPMD
 program instead of a network round trip, and the swarm sees a single-stage
-pipeline (is_first and is_last both true: tokens in, last-token logits out,
-client-side sampling — the reference contract, client.py:204-287).
+pipeline (is_first and is_last both true: tokens in; out, the token the
+pass chose where the decode hop asked for it (docs/SERVING.md "A decode
+hop's ask"), else last-token logits for the caller to sample: the
+reference contract, client.py:204-287).
 
 Sessions map to microbatch slots of the engine's persistent sharded KV
 caches (one slot = one session's cache lane), with idle-TTL sweep and
@@ -21,7 +23,8 @@ donated caches admit one step at a time) and guards the session table, so
 a call is admitted under it too. Prefills run one at a time; decode steps
 go through the arrival window (runtime/window.py, formation), whose
 flusher takes every entry that is pending once it holds the lock: ONE
-pipeline pass advances every session that was waiting when the mesh freed.
+pipeline pass advances every session that was waiting when the mesh freed,
+and chooses the token of every one whose hop asked for it.
 """
 
 from __future__ import annotations
@@ -37,6 +40,7 @@ from inferd_tpu.config import ModelConfig
 from inferd_tpu.obs import trace as tracelib
 from inferd_tpu.parallel import mesh as meshlib
 from inferd_tpu.parallel.infer import PipelinedEngine
+from inferd_tpu.runtime.executor import parse_decode_ask
 from inferd_tpu.runtime.spec_serving import SpecForkMiss, SpecServing
 
 log = logging.getLogger(__name__)
@@ -184,6 +188,10 @@ class MeshExecutor(SpecServing):
         self._ring_hi: Dict[str, int] = {}
         self._inflight: Dict[str, int] = {}  # session -> active request count
         self._dying: Dict[int, str] = {}  # slot -> ended session awaiting drain
+        # one-token decode hops answered with a token chosen on the device,
+        # and those answered with their [V] logits row (/stats `executor`)
+        self.sampled_rows = 0
+        self.logit_rows = 0
         # decode coalescing: the pipeline pass natively interleaves all MB
         # slots and costs the same whatever rides it, so a pass takes every
         # session that is waiting when the mesh frees
@@ -273,7 +281,7 @@ class MeshExecutor(SpecServing):
         return MeshSpecRunner(self.engine, sampling)
 
     def _spec_plain_submit(self, slot, last_tok, session_id):
-        return self._batcher.submit((slot, last_tok, session_id))
+        return self._batcher.submit((slot, last_tok, session_id, None))
 
     def enable_spec(self, draft_layers: int, k: int, raw_params) -> None:
         self.engine.enable_spec(draft_layers, k, raw_params)
@@ -420,8 +428,10 @@ class MeshExecutor(SpecServing):
 
     def process(self, session_id: str, payload: Dict[str, Any]) -> Dict[str, Any]:
         """payload: {"tokens": int32 [1, S], "start_pos": int, "real_len"}.
-        The mesh node is first AND last stage, so the reply always carries
-        last-real-token logits [1, V]."""
+        The mesh node is first AND last stage, so the reply carries
+        last-real-token logits [1, V] or, for a one-token decode hop that
+        asks for its token (runtime/executor.parse_decode_ask), {"tokens":
+        [[id]], "key", ...}."""
         toks = np.asarray(payload["tokens"], dtype=np.int32)
         if toks.ndim != 2 or toks.shape[0] != 1:
             raise ValueError(f"mesh stage expects tokens [1, S], got {toks.shape}")
@@ -515,8 +525,14 @@ class MeshExecutor(SpecServing):
 
         try:
             if decode:
-                row = self._batcher.submit((slot, int(toks[0, 0]), session_id))
-                logits = row[None, :]
+                # a hop that asks for its token (parse_decode_ask) is
+                # answered with it; any other with its logits row
+                res = self._batcher.submit(
+                    (slot, int(toks[0, 0]), session_id, parse_decode_ask(payload))
+                )
+                if isinstance(res, dict):
+                    return {**res, "real_len": 1, "start_pos": start_pos}
+                logits = res[None, :]
             elif (
                 start_pos == 0 and real_len > 1 and self.engine.sp_active
             ):
@@ -643,6 +659,8 @@ class MeshExecutor(SpecServing):
             "slots": self.engine.mb,
             "sessions": len(self.sessions),
             "kv_window_fallback": self.kv_window_fallback,
+            "sampled_rows": self.sampled_rows,
+            "logit_rows": self.logit_rows,
             **self._batcher.stats(),
             # pipeline passes of the raw serving steps and how many of
             # their stage-ticks did a live session's work (the rest are
@@ -663,9 +681,10 @@ class MeshExecutor(SpecServing):
             entries = self._batcher.drain_pending()
             if not entries:
                 return  # every waiting entry was invalidated: no pass
+            asks = {e.payload[0]: e.payload[3] for e in entries if e.payload[3] is not None}
             try:
                 out = self.engine.step_slots(
-                    {e.payload[0]: e.payload[1] for e in entries}
+                    {e.payload[0]: e.payload[1] for e in entries}, asks
                 )
             except Exception as exc:
                 for e in entries:
@@ -675,8 +694,10 @@ class MeshExecutor(SpecServing):
                 self._batcher.n_served -= len(entries)
                 return
             self._batcher.stamp_out(entries)  # copy_out is over: `deliver` starts
+            self.sampled_rows += len(asks)
+            self.logit_rows += len(entries) - len(asks)
             for e in entries:
-                slot, _tok, sid = e.payload
+                slot, _tok, sid, _ask = e.payload
                 if self._dying.get(slot) != sid:  # ended-mid-flush: the
                     # _dying drain discards the mirror anyway; everyone else
                     # advances in lockstep with the device-side length
@@ -685,7 +706,7 @@ class MeshExecutor(SpecServing):
                         self._ring_hi[sid] = max(
                             self._ring_hi.get(sid, 0), self._session_len[sid]
                         )
-                e.result = out[slot]
+                e.result = out[slot]  # its reply where it asked, else its logits
 
     def fork_session(
         self, new_session_id: str, parent_session_id: str, prefix_len: int

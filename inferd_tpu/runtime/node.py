@@ -102,6 +102,18 @@ def _warmup_executor(executor, journal=None) -> None:
                 })
         else:
             executor.process(sid, payload)
+            if hasattr(executor, "sampled_rows"):
+                # an executor whose decode step chooses the tokens: the
+                # step without log-probabilities (what traffic asks) and at
+                # the first top-n width (a variant each), then a hop that
+                # asks nothing (its logits row is copied out alone)
+                for i, ask in enumerate((
+                    {"sampling": {}},
+                    {"sampling": {}, "top_logprobs": BLOCK_TOP_WIDTHS[0]}, {},
+                )):
+                    executor.process(sid, {
+                        "tokens": [[1]], "start_pos": 1 + i, "real_len": 1, **ask,
+                    })
         if hasattr(executor, "process_batch"):
             # stage-batch executors serve decode through a SEPARATE
             # co-batched jit — compile it too (it is the serving hot path)

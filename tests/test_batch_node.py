@@ -295,26 +295,28 @@ async def test_batched_replica_graceful_death_failover(whole_parts):
         engine = Engine(TINY, params, max_len=64,
                         sampling_cfg=SamplingConfig(temperature=0.0))
         prompt = [3, 7, 11, 19, 5]
-        want = engine.generate(prompt, max_new_tokens=8)
+        # long enough that the poll below finds the session mid-generation
+        # (the warm-up has compiled the decode step: a hop takes a few ms)
+        want = engine.generate(prompt, max_new_tokens=48)
 
         killed = {}
 
         async def kill_serving_entry():
-            for _ in range(1200):
+            for _ in range(12000):
                 for n in nodes:
                     if len(n.executor.sessions):
                         await n.stop()
                         stopped.append(n)
                         killed["node"] = n
                         return
-                await asyncio.sleep(0.05)
+                await asyncio.sleep(0.005)
 
         async with SwarmClient(
             [("127.0.0.1", BASE + 30), ("127.0.0.1", BASE + 31)],
             sampling=SamplingConfig(temperature=0.0), timeout_s=60.0,
         ) as c:
             task = asyncio.create_task(kill_serving_entry())
-            got = await c.generate_ids(prompt, max_new_tokens=8,
+            got = await c.generate_ids(prompt, max_new_tokens=48,
                                        session_retries=0)
             await task
         assert killed.get("node") is not None

@@ -321,6 +321,46 @@ def test_dsv2l_lane_programs_compile_and_decode_expands_no_head_over_the_cache(
     assert prefill.memory_analysis().temp_size_in_bytes < 0.25e9  # 0.15 GB
 
 
+@pytest.mark.parametrize("model, lanes", [("qwen3-4b", 5), ("deepseek-v2-lite-8l", 16)],
+                         ids=["q4b-sat-chat", "dsv2l-long-chat"])
+@pytest.mark.parametrize("top_n", [0, 8])
+def test_decode_step_with_its_sampler_still_writes_the_cache_in_place(
+    one_chip, no_compile_cache, model, lanes, top_n
+):
+    """The decode step the cells run since the step chooses the tokens
+    (`_decode_logits` with an ask: core.sampling.choose_rows after the head,
+    per-lane sampling traced; `top_n` 8: the probe's variant), against the
+    same program without an ask (the parent's, byte for byte): the donated
+    cache is aliased to the output to the same byte, and the sampler's
+    temporaries are a megabyte (0.68 -> 1.65 MB at qwen3-4b, 135.9 -> 136.9
+    MB at deepseek-v2-lite-8l: the candidates' `top_k` and the packed rows),
+    under 1 % of the parent's and 2 MB."""
+    from inferd_tpu.core import sampling as samplib
+    from inferd_tpu.core.batch import BatchedEngine
+    from inferd_tpu.core.cache import KVCache
+    from inferd_tpu.models import qwen3
+
+    cfg = get_config(model)
+    params = _on(jax.eval_shape(lambda: qwen3.init_params(cfg, jax.random.PRNGKey(0))), one_chip)
+    eng = BatchedEngine(cfg, None, lanes=lanes, max_len=64)
+    shapes = jax.eval_shape(lambda: KVCache.create(cfg, cfg.num_layers, lanes, 4096))
+    cache = _on(shapes, one_chip)
+    toks = _sds((lanes,), jnp.int32, one_chip)
+    ask = samplib.RowAsk(_sds((lanes, 2), jnp.uint32, one_chip),
+                         _sds((lanes, 4), jnp.float32, one_chip))
+    parent = eng._decode_logits.lower(params, cache, toks, toks).compile().memory_analysis()
+    step = eng._decode_logits.lower(params, cache, toks, toks, ask=ask, top_n=top_n).compile()
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes == parent.alias_size_in_bytes
+    assert mem.alias_size_in_bytes >= (shapes.k.size + shapes.v.size) * 2  # two bytes a value
+    assert mem.temp_size_in_bytes <= parent.temp_size_in_bytes * 1.01 + 2e6
+    # the step hands the host ONE int32 array beside the logits that stay
+    packed = 3 + (1 + 2 * top_n if top_n else 0)
+    if cfg.is_moe:
+        packed += (cfg.num_layers - cfg.first_k_dense_replace) * cfg.num_experts_per_tok
+    assert f"s32[{lanes},{packed}]" in step.as_text()
+
+
 def test_sdar_block_program_compiles_at_16_lanes(one_chip, no_compile_cache):
     """The two programs a `--model sdar-30b-a3b-7l --batch-lanes 16 --max-len
     4096` node runs, at the published widths: the block step (two denoising
@@ -365,7 +405,10 @@ def test_sdar_block_program_compiles_at_16_lanes(one_chip, no_compile_cache):
 # ---------------------------------------------------------------------------
 
 
-def test_q8b_pp4_decode_pass_carries_its_slots_as_rows_in_place(topo, no_compile_cache, monkeypatch):
+@pytest.mark.parametrize("sampled", [False, True], ids=["logits", "tokens"])
+def test_q8b_pp4_decode_pass_carries_its_slots_as_rows_in_place(
+    topo, no_compile_cache, monkeypatch, sampled
+):
     """`PipelinedEngine._step_raw_multi` of `--model qwen3-8b --mesh pp=4
     --mesh-slots 8 --max-len 4096` (the cell q8b-pp4-sat-chat) for the
     described 2x2: a loop of four ticks that carries a stage's two stacks
@@ -378,11 +421,15 @@ def test_q8b_pp4_decode_pass_carries_its_slots_as_rows_in_place(topo, no_compile
     cache's size is among them (a slot's view of a stage is 75.5 MB, the
     rows' slab of one layer 67 MB). The engine is built over the
     described devices with its parameters and caches as shapes: placing
-    arrays there is what the test has to keep it from."""
+    arrays there is what the test has to keep it from. `tokens`: the pass
+    as the cell runs it since it chooses its slots' tokens after the head
+    (an ask a slot, the probe's top-8 variant): the same stacks in place,
+    the same two collectives, one s32[8, 20] more for the host."""
     import re
 
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from inferd_tpu.core import sampling as samplib
     from inferd_tpu.models import qwen3
     from inferd_tpu.parallel import infer, mesh as meshlib
 
@@ -407,8 +454,12 @@ def test_q8b_pp4_decode_pass_carries_its_slots_as_rows_in_place(topo, no_compile
     monkeypatch.setattr(infer, "make_caches", cache_shapes)
     params = jax.eval_shape(lambda: qwen3.init_params(cfg, jax.random.PRNGKey(0)))
     eng = infer.PipelinedEngine(cfg, params, mesh, num_microbatches=slots, max_len=max_len)
+    ask = {"ask": samplib.RowAsk(on_mesh((slots, 2), jnp.uint32, P()),
+                                 on_mesh((slots, 4), jnp.float32, P())),
+           "top_n": 8} if sampled else {}
     step = eng._step_raw_multi.lower(
-        eng.params, eng.caches, on_mesh((slots,), jnp.int32, P()), on_mesh((slots,), jnp.bool_, P())
+        eng.params, eng.caches, on_mesh((slots,), jnp.int32, P()),
+        on_mesh((slots,), jnp.bool_, P()), **ask,
     ).compile()
     mem = step.memory_analysis()
     stack_bytes = cfg.num_layers // pp * slots * max_len * cfg.num_kv_heads * cfg.head_dim * 2
@@ -426,3 +477,4 @@ def test_q8b_pp4_decode_pass_carries_its_slots_as_rows_in_place(topo, no_compile
     # one hop a tick inside the loop, the last rank's hidden state once after it
     assert len(re.findall(r" collective-permute-start\(", text)) == 1
     assert len(re.findall(r" all-reduce(-start)?\(", text)) == 1
+    assert (f"s32[{slots},20]" in text) is sampled

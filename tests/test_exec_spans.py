@@ -184,7 +184,9 @@ def test_decode_step_is_one_device_step_and_a_wait_per_entry(driven):
     waits = [s for s in spans if s["name"] == "batch_wait"]
     assert sorted(w["attrs"]["flusher"] for w in waits) == [0, 1]
     copies = [k for c in calls for k in children(spans, c) if k["name"] == "copy_out"]
-    assert [k["attrs"]["bytes"] for k in copies] == [4 * TINY.vocab_size * 4]  # every lane's / slot's row
+    # two raw /forward steps: every lane's / slot's row, and the step's own
+    # small array (a token and a key a lane: tests/test_device_sampling.py)
+    assert [k["attrs"]["bytes"] for k in copies] == [4 * TINY.vocab_size * 4 + 4 * 3 * 4]
 
 
 def test_parts_lie_inside_their_compute_and_do_not_overlap(driven):

@@ -341,8 +341,8 @@ def test_mesh_pass_takes_every_live_slot(mesh8, monkeypatch):
     ex, steps = mesh8, 4
     step_slots = ex.engine.step_slots
 
-    def chip_long_pass(tokens_by_slot):
-        out = step_slots(tokens_by_slot)
+    def chip_long_pass(*args):
+        out = step_slots(*args)
         time.sleep(0.05)
         return out
 

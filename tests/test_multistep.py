@@ -390,7 +390,7 @@ def test_batched_kstep_device_failure_poisons_window_clearly(solo_setup):
     rb = bx.process("b", {"tokens": [pb], "start_pos": 0, "real_len": 2})
     ta, tb = int(np.argmax(ra["logits"][0])), int(np.argmax(rb["logits"][0]))
 
-    def boom(params, cache, toks, lens, ads=None):
+    def boom(params, cache, toks, lens, ads=None, **_ask):
         cache.k.delete()  # what a failed donating jit leaves behind
         raise RuntimeError("injected device failure")
 
