@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from typing import Optional
 
 import jax.numpy as jnp
 
@@ -162,7 +163,59 @@ class ModelConfig:
     remask: str = "sequential"
     mask_token_id: int = 0
 
+    # State-space layers beside attention (the Granite-4.0-H family; all
+    # absent elsewhere):
+    #   layer_types    — one PERIOD of layer kinds, "mamba" or "attention":
+    #                    global layer i has kind layer_types[i % len]; () =
+    #                    every layer attends (the rule of `layer_pattern`)
+    #   mamba_*        — a Mamba-2 mixer: `mamba_heads` heads of
+    #                    `mamba_head_dim` (= mamba_expand * hidden_size in
+    #                    all), a state of `mamba_state` per head and channel,
+    #                    B and C shared by the heads of each of
+    #                    `mamba_groups` groups, a depthwise causal
+    #                    convolution of `mamba_conv` taps, the chunked form
+    #                    tiled by `mamba_chunk_size`
+    #   state_dtype    — what the recurrent state is held in between steps
+    #   embedding_multiplier / residual_multiplier / logits_scaling —
+    #                    Granite's scalars: the embedding is multiplied, each
+    #                    sublayer's output is multiplied before it joins the
+    #                    residual, the logits are DIVIDED (1.0 = absent).
+    #                    Its attention_multiplier is `attn_scale`:
+    #                    query_pre_attn_scalar 4096 gives the published 1/64
+    #   position_embedding — "rope", or "nope": no rotation at all
+    layer_types: tuple = ()
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_state: int = 0
+    mamba_groups: int = 1
+    mamba_conv: int = 4
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
+    state_dtype: str = "float32"
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    position_embedding: str = "rope"
+
     def __post_init__(self):
+        if self.layer_types:
+            odd = set(self.layer_types) - {"mamba", "attention"}
+            if odd or self.num_layers % len(self.layer_types):
+                raise ValueError(
+                    f"{self.name}: layer_types is one period of 'mamba' / 'attention' "
+                    f"that divides num_layers {self.num_layers} (got {self.layer_types})"
+                )
+            if self.has_state_layers and (
+                self.mamba_heads * self.mamba_head_dim != self.mamba_expand * self.hidden_size
+                or self.mamba_heads % self.mamba_groups or self.mamba_state <= 0
+                or self.is_mla or self.sliding_window or self.is_moe or self.is_block_diffusion
+            ):
+                raise ValueError(
+                    f"{self.name}: a Mamba-2 layer has mamba_heads x mamba_head_dim = "
+                    "mamba_expand x hidden_size, beside global GQA layers and a dense MLP"
+                )
+        if self.position_embedding not in ("rope", "nope"):
+            raise ValueError(f"{self.name}: unknown position_embedding {self.position_embedding!r}")
         if self.block_length > 1:
             if self.block_length % self.denoising_steps:
                 raise ValueError(
@@ -189,13 +242,42 @@ class ModelConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def has_state_layers(self) -> bool:
+        """Some layer holds a recurrent state and not keys and values."""
+        return "mamba" in self.layer_types
+
+    @property
     def layer_pattern(self) -> tuple:
-        """The kinds of attention layer, one period of them: GLOBAL layer i
-        has kind layer_pattern[i % len(layer_pattern)]. "sliding" attends
-        within `sliding_window`, "global" over everything before it. The
-        one place that says which layers are which (the scan of
-        models/qwen3.forward_layers, the ring storage of core/cache)."""
+        """The kinds of layer, one period of them: GLOBAL layer i has kind
+        layer_pattern[i % len(layer_pattern)]. "sliding" attends within
+        `sliding_window`, "global" (or "attention") over everything before
+        it, "mamba" carries a recurrent state (`layer_types`, where given).
+        The one place that says which layers are which (the scan of
+        models/qwen3.forward_layers, the storage of core/cache)."""
+        if self.layer_types:
+            return self.layer_types
         return ("sliding", "global") if self.sliding_window else ("global",)
+
+    def layers_of(self, kind: str, num_layers: Optional[int] = None) -> int:
+        """How many of the first `num_layers` layers (default: all) are `kind`."""
+        kinds = self.layer_pattern
+        n = self.num_layers if num_layers is None else num_layers
+        return sum(kinds[i % len(kinds)] == kind for i in range(n))
+
+    @property
+    def layer_type_names(self) -> list:
+        """Every layer's kind in layer order, as a published config lists them."""
+        kinds = self.layer_pattern
+        return [kinds[i % len(kinds)] for i in range(self.num_layers)]
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels through the convolution: x, then B and C of every group."""
+        return self.mamba_inner + 2 * self.mamba_groups * self.mamba_state
 
     @property
     def num_dense_layers(self) -> int:
@@ -596,6 +678,40 @@ DEEPSEEK_V2_LITE_8L = dataclasses.replace(
     DEEPSEEK_V2_LITE.with_layers(8), name="deepseek-v2-lite-8l"
 )
 
+# Granite-4.0-H-Micro (ibm-granite/granite-4.0-h-micro config.json,
+# `granitemoehybrid` with no experts): 36 Mamba-2 layers and 4 GQA layers
+# without any position embedding, one period of ten (attention at 5, 15, 25,
+# 35), a SwiGLU MLP in every layer, four scalars. As published: nothing cut.
+GRANITE_4_H_MICRO = ModelConfig(
+    name="granite-4.0-h-micro",
+    vocab_size=100352,
+    hidden_size=2048,
+    intermediate_size=8192,
+    num_layers=40,
+    num_heads=32,
+    num_kv_heads=8,
+    head_dim=64,
+    rms_norm_eps=1e-5,
+    rope_theta=10_000.0,
+    max_position_embeddings=131072,
+    tie_word_embeddings=True,
+    qk_norm=False,
+    attn_bias=False,
+    query_pre_attn_scalar=4096.0,  # attention_multiplier 1/64, not 1/sqrt(64)
+    layer_types=("mamba",) * 5 + ("attention",) + ("mamba",) * 4,
+    mamba_heads=64,
+    mamba_head_dim=64,
+    mamba_state=128,
+    mamba_groups=1,
+    mamba_conv=4,
+    mamba_expand=2,
+    mamba_chunk_size=256,
+    embedding_multiplier=12.0,
+    residual_multiplier=0.22,
+    logits_scaling=8.0,
+    position_embedding="nope",
+)
+
 # Synthetic mid-size config for the default bench's paired pipeline leg
 # (bench.py): big enough that a decode step's compute dominates the
 # inter-stage hop (the regime the north-star ratio grades), small enough
@@ -680,6 +796,16 @@ TINY_DSV2 = dataclasses.replace(
     norm_topk_prob=False, n_shared_experts=2, first_k_dense_replace=1,
 )
 
+TINY_GRANITE_H = dataclasses.replace(
+    TINY, name="tiny-granite-h", qk_norm=False, num_layers=8, rms_norm_eps=1e-5,
+    query_pre_attn_scalar=256.0,  # 1/16 where head_dim 16 would give 1/4
+    layer_types=("mamba", "mamba", "attention", "mamba"),
+    mamba_heads=8, mamba_head_dim=16, mamba_state=16, mamba_groups=1, mamba_conv=4,
+    mamba_expand=2, mamba_chunk_size=8,
+    embedding_multiplier=12.0, residual_multiplier=0.22, logits_scaling=8.0,
+    position_embedding="nope",
+)
+
 PRESETS = {
     c.name: c
     for c in [
@@ -705,6 +831,7 @@ PRESETS = {
         SDAR_30B_A3B_7L,
         DEEPSEEK_V2_LITE,
         DEEPSEEK_V2_LITE_8L,
+        GRANITE_4_H_MICRO,
         BENCH_PIPE,
         TINY,
         TINY_MOE,
@@ -714,6 +841,7 @@ PRESETS = {
         TINY_GEMMA2,
         TINY_GPT_OSS,
         TINY_DSV2,
+        TINY_GRANITE_H,
     ]
 }
 
@@ -738,6 +866,7 @@ HF_REPOS = {
     "gpt-oss-20b": "openai/gpt-oss-20b",
     "gpt-oss-120b": "openai/gpt-oss-120b",
     "deepseek-v2-lite": "deepseek-ai/DeepSeek-V2-Lite",
+    "granite-4.0-h-micro": "ibm-granite/granite-4.0-h-micro",
 }
 
 

@@ -195,7 +195,7 @@ class BatchedEngine:
 
         @partial(jax.jit, donate_argnames=("cache",), static_argnames=("top_n",))
         def _decode_logits(params, cache: KVCache, toks, lengths, ads=None,
-                           ask=None, top_n: int = 0):
+                           ask=None, top_n: int = 0, active=None):
             """One batched decode step: last-token LOGITS [L, V] and, with
             an `ask` (core.sampling.RowAsk: per-lane keys, temperature,
             top-k, top-p, min-p, all traced), every lane's TOKEN chosen
@@ -209,7 +209,10 @@ class BatchedEngine:
             host-side; their computed rows are discarded by the caller.
             `ads` (multi-tenant registry): the stacked LoRA pools +
             per-lane slot ids — a mixed-adapter window stays ONE dispatch
-            (ops/lora pool contract).
+            (ops/lora pool contract). `active` [L] bool (a model with
+            state-space layers hands it): a lane not served this step must
+            keep its recurrent state as it is, where a dense lane's garbage
+            row beyond the frontier is simply never read.
 
             Without an ask the third value is the experts each lane chose
             in each sparse layer, [Ls, L, K] int32 (the `moe.*` counters
@@ -220,7 +223,7 @@ class BatchedEngine:
             pos = lengths[:, None]
             logits, nc, topi = qwen3.forward_cached(
                 params, cfg, toks[:, None], pos, cache, lengths,
-                real_end=lengths + 1, adapters=ads,
+                real_end=lengths + 1, adapters=ads, write_mask=active,
             )
             last = logits[:, 0]
             chosen = topi[:, :, 0] if routes else None
@@ -335,6 +338,11 @@ class BatchedEngine:
             """Copy the first m KV slots of lane `src` into lane `dst`
             (prefix-cache fork). Donated + dynamic_update_slice so XLA
             updates the cache in place — never a whole-cache copy."""
+            if cache.s is not None:
+                raise ValueError(
+                    f"{cfg.name}: a recurrent state is the state after ALL of the "
+                    "parent's tokens; no prefix of it can seed another lane"
+                )
             ks = jax.lax.dynamic_slice_in_dim(cache.k, src, 1, axis=1)[:, :, :m]
             vs = jax.lax.dynamic_slice_in_dim(cache.v, src, 1, axis=1)[:, :, :m]
             zero = jnp.int32(0)

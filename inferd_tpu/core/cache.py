@@ -13,7 +13,8 @@ Layout: k/v are [num_global_layers, batch, max_len, num_kv_heads, head_dim];
 models/qwen3.decoder_layer contract).
 
 This module owns the LAYOUTS. For one layer a cache entry is one value: none
-(cache-free), `DenseEntry`, `LatentEntry`, `RingEntry` or `PagedEntry`;
+(cache-free), `DenseEntry`, `LatentEntry`, `RingEntry`, `PagedEntry` or
+`StateEntry` (a recurrent state, the one entry that does not grow with tokens);
 stacked over layers they are what the one layer scan of
 models/qwen3.forward_layers CARRIES: a layer writes its chunk's rows into
 the stack at its own index and reads its slab as a view of the stack, so a
@@ -118,6 +119,17 @@ class PagedEntry:
     v: jax.Array
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class StateEntry:
+    """A state-space layer's whole memory of a session, the same size
+    whatever the session's length and not indexed by position: it cannot be
+    truncated, rolled back or cut at a prefix."""
+
+    s: jax.Array  # [B, heads, P, N] in cfg.state_dtype: the recurrent state
+    conv: jax.Array  # [B, K-1, conv_dim]: the last inputs of the causal convolution
+
+
 class CacheCtx(NamedTuple):
     """What a cached forward needs beside the entries, the same for every
     layer: where the chunk is written and, for a paged pool, through what."""
@@ -130,6 +142,11 @@ class CacheCtx(NamedTuple):
     #   (a False row writes nothing, in any layout; None = every row's do)
 
 
+def _nbytes(*arrays) -> int:
+    """Bytes of the arrays that are there (of their shapes, where abstract)."""
+    return sum(int(a.size) * a.dtype.itemsize for a in arrays if a is not None)
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class KVCache:
@@ -138,6 +155,10 @@ class KVCache:
     length: jax.Array  # int32 scalar: populated positions
     k_loc: Optional[jax.Array] = None  # [Ll, B, R, Nkv, D] sliding-layer rings
     v_loc: Optional[jax.Array] = None
+    # a model with state-space layers (cfg.has_state_layers): k and v hold
+    # its ATTENTION layers only, these its Mamba layers' StateEntry stack
+    s: Optional[jax.Array] = None  # [Lm, B, heads, P, N] in cfg.state_dtype
+    conv: Optional[jax.Array] = None  # [Lm, B, K-1, conv_dim] in the model's dtype
 
     @property
     def max_len(self) -> int:
@@ -166,6 +187,22 @@ class KVCache:
         comparison/compat path — also what executors with a TRACED layer
         offset must use)."""
         dt = dtype or cfg.kv_jnp_dtype
+        if cfg.has_state_layers:
+            # keys and values (in the kv dtype) for the attention layers, a
+            # state and the convolution's last inputs for the others
+            la = cfg.layers_of("attention", num_layers)
+            lm = num_layers - la
+            shape = (la, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
+            return KVCache(
+                k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt), length=jnp.int32(0),
+                s=jnp.zeros(
+                    (lm, batch, cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state),
+                    jnp.dtype(cfg.state_dtype),
+                ),
+                conv=jnp.zeros(
+                    (lm, batch, cfg.mamba_conv - 1, cfg.mamba_conv_dim), cfg.jnp_dtype
+                ),
+            )
         if cfg.is_mla:
             # a latent cache: per token and layer the normed latent (`k`)
             # and the one roped key all heads share (`v`); nothing per head
@@ -198,8 +235,12 @@ class KVCache:
     @property
     def nbytes(self) -> int:
         """Bytes allocated to the cache's buffers."""
-        return sum(int(a.nbytes) for a in (self.k, self.v, self.k_loc, self.v_loc)
-                   if a is not None)
+        return self.state_bytes + _nbytes(self.k, self.v, self.k_loc, self.v_loc)
+
+    @property
+    def state_bytes(self) -> int:
+        """Bytes of the buffers that do not grow with tokens (StateEntry)."""
+        return _nbytes(self.s, self.conv)
 
     def ensure_room(self, new_tokens: int, owner: Optional[str] = None) -> None:
         """Host-side overflow guard — call before dispatching a jitted step.
@@ -218,11 +259,16 @@ class KVCache:
 
     def entries(self, cfg: ModelConfig) -> tuple:
         """The layers' entries, stacked: one stack per kind of
-        cfg.layer_pattern when the storage is ring-split, else one stack
-        holding every layer in layer order."""
+        cfg.layer_pattern (in the order the kinds first appear in it) when
+        the storage is split by kind (rings, states), else one stack holding
+        every layer in layer order."""
         if cfg.is_mla:
             return (LatentEntry(c=self.k, r=self.v),)
         glob = DenseEntry(k=self.k, v=self.v)
+        if self.s is not None:
+            state = StateEntry(s=self.s, conv=self.conv)
+            return tuple(state if kind == "mamba" else glob
+                         for kind in dict.fromkeys(cfg.layer_pattern))
         if self.k_loc is None:
             return (glob,)
         ring = RingEntry(k=self.k_loc, v=self.v_loc, window=int(cfg.sliding_window))
@@ -233,11 +279,13 @@ class KVCache:
         if isinstance(entries[0], LatentEntry):
             return KVCache(k=entries[0].c, v=entries[0].r, length=self.length)
         by_type = {type(e): e for e in entries}
-        glob, ring = by_type[DenseEntry], by_type.get(RingEntry)
+        glob, ring, state = by_type[DenseEntry], by_type.get(RingEntry), by_type.get(StateEntry)
         return KVCache(
             k=glob.k, v=glob.v, length=self.length,
             k_loc=None if ring is None else ring.k,
             v_loc=None if ring is None else ring.v,
+            s=None if state is None else state.s,
+            conv=None if state is None else state.conv,
         )
 
     @staticmethod
@@ -247,33 +295,30 @@ class KVCache:
 
     def updated(self, k: jax.Array, v: jax.Array, new_tokens) -> "KVCache":
         """New cache with written buffers and advanced length (pure)."""
-        return KVCache(
-            k=k, v=v, length=self.length + new_tokens,
-            k_loc=self.k_loc, v_loc=self.v_loc,
-        )
+        return dataclasses.replace(self, k=k, v=v, length=self.length + new_tokens)
 
 
 def lane_slice(cache: KVCache, lane) -> KVCache:
-    """One lane's KVCache view, [.., 1, ..] on the batch axis (global +
-    ring buffers). Shared by the lane-indexed engines (core.batch prefill,
-    core.spec_batch draft prefill) so the ring-buffer field handling lives
-    in exactly one place."""
-    sl = lambda a: jax.lax.dynamic_slice_in_dim(a, lane, 1, axis=1)
+    """One lane's KVCache view, [.., 1, ..] on the batch axis (global,
+    ring and state buffers). Shared by the lane-indexed engines (core.batch
+    prefill, core.spec_batch draft prefill) so the handling of the optional
+    fields lives in exactly one place."""
+    sl = lambda a: None if a is None else jax.lax.dynamic_slice_in_dim(a, lane, 1, axis=1)
     return KVCache(
         k=sl(cache.k), v=sl(cache.v), length=cache.length,
-        k_loc=None if cache.k_loc is None else sl(cache.k_loc),
-        v_loc=None if cache.v_loc is None else sl(cache.v_loc),
+        k_loc=sl(cache.k_loc), v_loc=sl(cache.v_loc), s=sl(cache.s), conv=sl(cache.conv),
     )
 
 
 def lane_write(cache: KVCache, lane, nc: KVCache) -> KVCache:
     """Write a lane_slice-shaped cache back into `lane` (inverse of
     lane_slice; in-place under donation)."""
-    up = lambda a, b: jax.lax.dynamic_update_slice_in_dim(a, b, lane, axis=1)
+    up = lambda a, b: None if a is None else jax.lax.dynamic_update_slice_in_dim(
+        a, b, lane, axis=1)
     return KVCache(
         k=up(cache.k, nc.k), v=up(cache.v, nc.v), length=cache.length,
-        k_loc=None if cache.k_loc is None else up(cache.k_loc, nc.k_loc),
-        v_loc=None if cache.v_loc is None else up(cache.v_loc, nc.v_loc),
+        k_loc=up(cache.k_loc, nc.k_loc), v_loc=up(cache.v_loc, nc.v_loc),
+        s=up(cache.s, nc.s), conv=up(cache.conv, nc.conv),
     )
 
 
@@ -435,10 +480,10 @@ class BlockPool:
         dtype=None,
         clock: Optional[Callable[[], float]] = None,
     ):
-        if cfg.is_mla:
+        if cfg.is_mla or cfg.has_state_layers:
             raise ValueError(
-                f"{cfg.name}: the paged pool has no latent entry "
-                "(a latent cache is served from dense lanes)"
+                f"{cfg.name}: the paged pool has no latent or state entry "
+                "(such a cache is served from dense lanes)"
             )
         if cfg.sliding_window > 0:
             # rings already make sliding layers O(window); paging the
@@ -820,7 +865,4 @@ def grow(cache: KVCache, new_max_len: int) -> KVCache:
     if new_max_len <= cache.max_len:
         return cache
     pad = [(0, 0), (0, 0), (0, new_max_len - cache.max_len)] + [(0, 0)] * (cache.k.ndim - 3)
-    return KVCache(
-        k=jnp.pad(cache.k, pad), v=jnp.pad(cache.v, pad), length=cache.length,
-        k_loc=cache.k_loc, v_loc=cache.v_loc,
-    )
+    return dataclasses.replace(cache, k=jnp.pad(cache.k, pad), v=jnp.pad(cache.v, pad))

@@ -74,6 +74,8 @@ def params_from_hf_state_dict(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params
     """
     if cfg.is_mla:
         return _params_from_deepseek_v2(cfg, sd)
+    if cfg.has_state_layers:
+        return _params_from_granite_hybrid(cfg, sd)
     dt = cfg.jnp_dtype
 
     def get_np(name: str, transpose: bool = False) -> np.ndarray:
@@ -264,6 +266,57 @@ def _params_from_deepseek_v2(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:
     }
     if nd:
         params["dense_layers"] = group(range(nd))
+    return params
+
+
+def _params_from_granite_hybrid(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:
+    """HF `granitemoehybrid` names (no experts) -> `layers` for the attention
+    kind, `state_layers` for the Mamba kind, each in layer order. The
+    published `shared_mlp.input_linear` [2 I, H] is gate over up and is
+    split here; `mamba.conv1d.weight` [C, 1, K] becomes the taps [K, C]."""
+    dt = cfg.jnp_dtype
+
+    def raw(name: str) -> np.ndarray:
+        return _to_np(sd[name if name in sd else f"model.{name}"])
+
+    def w(name: str) -> np.ndarray:  # a linear weight as [in, out]
+        return raw(f"{name}.weight").T
+
+    def layer(i: int, kind: str) -> Params:
+        gate_up = w(f"layers.{i}.shared_mlp.input_linear")
+        out = {
+            "input_norm": raw(f"layers.{i}.input_layernorm.weight"),
+            "post_norm": raw(f"layers.{i}.post_attention_layernorm.weight"),
+            "gate_proj": gate_up[:, : cfg.intermediate_size],
+            "up_proj": gate_up[:, cfg.intermediate_size:],
+            "down_proj": w(f"layers.{i}.shared_mlp.output_linear"),
+        }
+        if kind == "attention":
+            for proj in ("q_proj", "k_proj", "v_proj", "o_proj"):
+                out[proj] = w(f"layers.{i}.self_attn.{proj}")
+            return out
+        m = f"layers.{i}.mamba"
+        out.update(
+            in_proj=w(f"{m}.in_proj"), out_proj=w(f"{m}.out_proj"),
+            conv_w=raw(f"{m}.conv1d.weight")[:, 0, :].T, conv_b=raw(f"{m}.conv1d.bias"),
+            dt_bias=raw(f"{m}.dt_bias"), A_log=raw(f"{m}.A_log"), D=raw(f"{m}.D"),
+            gate_norm=raw(f"{m}.norm.weight"),
+        )
+        return out
+
+    def group(kind: str) -> Params:
+        per_layer = [layer(i, kind) for i, k in enumerate(cfg.layer_type_names) if k == kind]
+        return {k: jnp.asarray(np.stack([lp[k] for lp in per_layer]), dtype=dt)
+                for k in per_layer[0]}
+
+    params: Params = {
+        "embed": jnp.asarray(raw("embed_tokens.weight"), dtype=dt),
+        "layers": group("attention"),
+        "state_layers": group("mamba"),
+        "final_norm": jnp.asarray(raw("norm.weight"), dtype=dt),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = jnp.asarray(w("lm_head"), dtype=dt)
     return params
 
 

@@ -113,16 +113,59 @@ def init_layer_params(
     return p
 
 
+def init_state_layer_params(cfg: ModelConfig, key: jax.Array, num_layers: int) -> Params:
+    """Stacked Mamba-2 layers (mamba_mixer) with their MLP: every leaf has
+    leading dim `num_layers`. The projections are drawn as every other one
+    (normal, 0.02). What steers the recurrence is drawn so that it is neither
+    dead nor saturated: a target step d log-uniform in [0.01, 0.5] with
+    dt_bias its inverse softplus, A = -exp(A_log) with exp(A_log) uniform in
+    [0.1, 1], so the decay exp(d A) of a step spreads over about 0.5-0.999
+    and the state weighs about what D x does; D uniform in [0.5, 1.5]; the
+    convolution's taps normal with deviation 0.3."""
+    n, h, i = num_layers, cfg.hidden_size, cfg.intermediate_size
+    di, cd, heads = cfg.mamba_inner, cfg.mamba_conv_dim, cfg.mamba_heads
+    dt = cfg.jnp_dtype
+    ks = jax.random.split(key, 10)
+
+    def w(k, *shape, std=0.02):
+        return (jax.random.normal(k, (n, *shape), dtype=jnp.float32) * std).astype(dt)
+
+    def uniform(k, lo, hi):
+        return jax.random.uniform(k, (n, heads), jnp.float32, lo, hi)
+
+    step = jnp.exp(uniform(ks[4], math.log(0.01), math.log(0.5)))
+    return {
+        "input_norm": jnp.ones((n, h), dtype=dt),
+        "in_proj": w(ks[0], h, di + cd + heads),  # [z | xBC | dt], in that order
+        "conv_w": w(ks[1], cfg.mamba_conv, cd, std=0.3),  # tap i meets the input K-1-i back
+        "conv_b": w(ks[2], cd),
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),  # softplus^-1(step)
+        "A_log": jnp.log(uniform(ks[5], 0.1, 1.0)).astype(dt),
+        "D": uniform(ks[6], 0.5, 1.5).astype(dt),
+        "gate_norm": jnp.ones((n, di), dtype=dt),
+        "out_proj": w(ks[3], di, h),
+        "post_norm": jnp.ones((n, h), dtype=dt),
+        "gate_proj": w(ks[7], h, i),
+        "up_proj": w(ks[8], h, i),
+        "down_proj": w(ks[9], i, h),
+    }
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     """Full-model params: embed + stacked layers + final norm (+ lm_head)."""
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     dt = cfg.jnp_dtype
     norm1 = jnp.zeros if cfg.rms_norm_plus_one else jnp.ones
+    n_state = cfg.layers_of("mamba")
     params = {
         "embed": (jax.random.normal(k_embed, (cfg.vocab_size, cfg.hidden_size), dtype=jnp.float32) * 0.02).astype(dt),
-        "layers": init_layer_params(cfg, k_layers, cfg.num_layers - cfg.num_dense_layers),
+        "layers": init_layer_params(
+            cfg, k_layers, cfg.num_layers - cfg.num_dense_layers - n_state),
         "final_norm": norm1((cfg.hidden_size,), dtype=dt),
     }
+    if n_state:  # the Mamba kind's stack, beside the attention kind's `layers`
+        params["state_layers"] = init_state_layer_params(
+            cfg, jax.random.fold_in(k_layers, 2), n_state)
     if cfg.num_dense_layers:  # a leading group with leaves of its own
         params["dense_layers"] = init_layer_params(
             cfg, jax.random.fold_in(k_layers, 1), cfg.num_dense_layers, dense=True
@@ -773,8 +816,9 @@ def _gqa_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx, window
     if cfg.qk_norm:  # Qwen3 signature feature
         q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cos is not None:  # None: a model without position embedding
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     sinks = lp["sinks"] if cfg.attn_sinks else None
     # RoPE above took the true positions; the mask of every layout below
     # compares a slot's position with the last one the query sees
@@ -887,6 +931,143 @@ def _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx):
     return attn, new
 
 
+# ---------------------------------------------------------------------------
+# Mamba-2: a state-space mixer in attention's place (cfg.layer_types)
+# ---------------------------------------------------------------------------
+
+_HI = jax.lax.Precision.HIGHEST  # float32 operands stay float32 on the MXU
+
+
+def ssm_chunked(x, dt, a, bm, cm, s_in, tile: int):
+    """The chunked (SSD) form of  S_t = exp(dt_t a) S_{t-1} + dt_t x_t (x) B_t,
+    y_t = S_t C_t  over a chunk, float32 throughout.
+
+    x [B, S, G, Hg, P], dt [B, S, G, Hg] (0 where a position is padding: the
+    state passes it unchanged), a [G, Hg] (negative), bm / cm [B, S, G, N]
+    (the heads of a group share them), s_in [B, G, Hg, P, N] the state the
+    chunk enters with -> (y [B, S, G, Hg, P], the state it leaves).
+
+    The chunk is cut into tiles of `tile` positions (it has to divide S).
+    Inside a tile position i reads position j <= i through the decay between
+    them (a [tile, tile] matrix a head, as attention without a softmax); a
+    tile's own contribution to the state and the state it entered with go
+    from tile to tile through a sequential scan over the few tiles."""
+    b, s, g, hg, p = x.shape
+    c = s // tile
+    t5 = lambda v: v.reshape(b, c, tile, *v.shape[2:])  # noqa: E731
+    x, dt, bm, cm = t5(x), t5(dt), t5(bm), t5(cm)
+    acum = jnp.cumsum(dt * a, axis=2)  # [b, c, q, g, h]: log decay from the tile's start
+    dtx = dt[..., None] * x  # [b, c, q, g, h, p]
+    # inside a tile
+    diff = acum[:, :, :, None] - acum[:, :, None, :]  # [b, c, i, j, g, h]
+    seen = (jnp.arange(tile)[:, None] >= jnp.arange(tile)[None, :])[None, None, :, :, None, None]
+    decay = jnp.exp(jnp.where(seen, diff, -jnp.inf))
+    cb = jnp.einsum("bcign,bcjgn->bcijg", cm, bm, precision=_HI)
+    y = jnp.einsum("bcijgh,bcjghp->bcighp", cb[..., None] * decay, dtx, precision=_HI)
+    # each tile's own contribution to the state at its end, and its whole decay
+    to_end = jnp.exp(acum[:, :, -1:] - acum)  # [b, c, q, g, h]
+    own = jnp.einsum("bcjghp,bcjgn->bcghpn", to_end[..., None] * dtx, bm, precision=_HI)
+    whole = jnp.exp(acum[:, :, -1])  # [b, c, g, h]
+
+    def carry(state, xs):
+        own_c, whole_c = xs
+        return state * whole_c[..., None, None] + own_c, state
+
+    s_out, entered = jax.lax.scan(
+        carry, s_in, (jnp.moveaxis(own, 1, 0), jnp.moveaxis(whole, 1, 0)))
+    entered = jnp.moveaxis(entered, 0, 1)  # [b, c, g, h, p, n]: the state each tile enters with
+    y = y + jnp.einsum("bcign,bcghpn->bcighp", cm, entered, precision=_HI) * jnp.exp(acum)[..., None]
+    return y.reshape(b, s, g, hg, p), s_out
+
+
+def mamba_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, entry, at, ctx):
+    """A Mamba-2 block over the normed input x [B, S, H] -> (out [B, S, H],
+    entry'). `entry` is the Mamba layers' STACKED core.cache.StateEntry and
+    `at` this layer's index in it (None: no cache, the chunk starts from
+    zeros and nothing is kept).
+
+        [z | xBC | dt] = x W_in
+        xBC_t = silu(sum_i w_conv[i] xBC_{t-(K-1)+i} + b_conv)      causal, depthwise
+        [x_t | B_t | C_t] = xBC_t;  d_t = softplus(dt_t + dt_bias);  A = -exp(A_log)
+        S_t = exp(d_t A) S_{t-1} + d_t x_t (x) B_t;   y_t = S_t C_t + D x_t
+        out = RMSNorm(y_t silu(z_t); w_norm) W_out        the norm over each group
+
+    One recurrence in two forms: S == 1 (a decode row) is one multiply-add
+    over the state; a longer chunk runs `ssm_chunked`, tiled by
+    cfg.mamba_chunk_size where that divides it. The state is float32 while
+    it is computed and is held in cfg.state_dtype between steps.
+
+    What must not move a session's state: a position at or past
+    ctx.real_end (bucket padding) has d_t = 0, so the state passes it
+    bit-unchanged, and the convolution's kept inputs are the last K-1
+    BEFORE real_end; a row whose ctx.write_mask is False keeps its old state
+    and inputs by a select. A row written at position 0 enters with zeros
+    whatever its lane held: that is how a session starts."""
+    f32 = jnp.float32
+    b, s, _ = x.shape
+    heads, p, n, g, k = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state,
+                         cfg.mamba_groups, cfg.mamba_conv)
+    di, cd, hg = cfg.mamba_inner, cfg.mamba_conv_dim, cfg.mamba_heads // cfg.mamba_groups
+    proj = qdot(x, lp["in_proj"])
+    z, xbc, dt = proj[..., :di], proj[..., di:di + cd], proj[..., di + cd:]
+
+    if entry is None:
+        s_in = jnp.zeros((b, heads, p, n), f32)
+        kept = jnp.zeros((b, k - 1, cd), xbc.dtype)
+        real = jnp.full((b,), s, jnp.int32)
+    else:
+        row = lambda v: jnp.broadcast_to(jnp.asarray(v, jnp.int32), (b,))  # noqa: E731
+        start = row(ctx.write_pos)
+        real = jnp.clip(row(start + s if ctx.real_end is None else ctx.real_end) - start, 0, s)
+        fresh = (start == 0)[:, None, None]
+        s_old, kept_old = _slab(entry.s, at), _slab(entry.conv, at)
+        s_in = jnp.where(fresh[..., None], 0.0, s_old.astype(f32))
+        kept = jnp.where(fresh, 0, kept_old).astype(xbc.dtype)
+
+    with jax.named_scope("ssm_conv"):
+        full = jnp.concatenate([kept, xbc], axis=1)  # [B, K-1 + S, C]
+        w = lp["conv_w"].astype(f32)
+        acc = lp["conv_b"].astype(f32) + sum(
+            full[:, i:i + s].astype(f32) * w[i] for i in range(k))
+        xbc = jax.nn.silu(acc)
+        # the K-1 inputs before the first padding position
+        kept = full[:, 1:] if s == 1 else jax.vmap(
+            lambda f, r: jax.lax.dynamic_slice_in_dim(f, r, k - 1, axis=0))(full, real)
+    xs = xbc[..., :di].reshape(b, s, g, hg, p)
+    bm = xbc[..., di:di + g * n].reshape(b, s, g, n)
+    cm = xbc[..., di + g * n:].reshape(b, s, g, n)
+    step = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+    step = jnp.where((jnp.arange(s)[None, :] < real[:, None])[..., None], step, 0.0)
+    step = step.reshape(b, s, g, hg)
+    a = -jnp.exp(lp["A_log"].astype(f32)).reshape(g, hg)
+    s_in = s_in.reshape(b, g, hg, p, n)
+    if s == 1:
+        with jax.named_scope("ssm_update"):
+            d1, x1 = step[:, 0], xs[:, 0]
+            s_new = (s_in * jnp.exp(d1 * a)[..., None, None]
+                     + (d1[..., None] * x1)[..., None] * bm[:, 0, :, None, None, :])
+            y = jnp.sum(s_new * cm[:, 0, :, None, None, :], axis=-1)[:, None]
+    else:
+        with jax.named_scope("ssm_scan"):
+            q = cfg.mamba_chunk_size
+            y, s_new = ssm_chunked(xs, step, a, bm, cm, s_in, q if s % q == 0 else s)
+    y = y + lp["D"].astype(f32).reshape(g, hg)[:, :, None] * xs
+    with jax.named_scope("ssm_gate_norm"):  # the norm is over each group's channels
+        y = (y.reshape(b, s, di) * jax.nn.silu(z.astype(f32))).reshape(b, s, g, di // g)
+        y = rms_norm(y, lp["gate_norm"].reshape(g, di // g), cfg.rms_norm_eps)
+        y = y.reshape(b, s, di).astype(x.dtype)
+    out = qdot(y, lp["out_proj"])
+    if entry is None:
+        return out, None
+    s_new = s_new.reshape(b, heads, p, n).astype(entry.s.dtype)
+    kept = kept.astype(entry.conv.dtype)
+    if ctx.write_mask is not None:
+        s_new = jnp.where(ctx.write_mask[:, None, None, None], s_new, s_old)
+        kept = jnp.where(ctx.write_mask[:, None, None], kept, kept_old)
+    put = jax.lax.dynamic_update_index_in_dim
+    return out, cachelib.StateEntry(s=put(entry.s, s_new, at, 0), conv=put(entry.conv, kept, at, 0))
+
+
 def decoder_layer(
     lp: Params,
     cfg: ModelConfig,
@@ -937,8 +1118,24 @@ def decoder_layer(
     (inferd_tpu.core.cache.KVCache.ensure_room).
     """
     p1 = cfg.rms_norm_plus_one
+    # Granite's residual multiplier: each sublayer's output is scaled before
+    # it joins the residual (1.0: absent, nothing traced)
+    scaled = (lambda y: y) if cfg.residual_multiplier == 1.0 else (
+        lambda y: y * cfg.residual_multiplier)
 
     x = rms_norm(hidden, lp["input_norm"], cfg.rms_norm_eps, p1)
+    if "in_proj" in lp:  # a state-space layer: its stack holds no q / k / v
+        if tp_axis or ep_axis or adapters is not None or not isinstance(
+                entry, (type(None), cachelib.StateEntry)):
+            raise ValueError(
+                f"{cfg.name}: a state-space layer runs whole on its device over a "
+                "StateEntry (no tensor/expert parallel shard, no adapter)"
+            )
+        mixed, entry = mamba_mixer(lp, cfg, x, entry, at, ctx)
+        hidden = hidden + scaled(mixed).astype(hidden.dtype)
+        x = rms_norm(hidden, lp["post_norm"], cfg.rms_norm_eps, p1)
+        mlp_out = swiglu_mlp(lp, x, act_fn(cfg))
+        return hidden + scaled(mlp_out).astype(hidden.dtype), entry, None
     if cfg.is_mla:
         if (tp_axis or ep_axis or window is not None or adapters is not None
                 or not isinstance(entry, (type(None), cachelib.LatentEntry))):
@@ -961,7 +1158,7 @@ def decoder_layer(
         attn_out = attn_out + lp["o_bias"]
     if cfg.sandwich_norm:  # Gemma: post-norm the sublayer output pre-residual
         attn_out = rms_norm(attn_out, lp["post_norm"], cfg.rms_norm_eps, p1)
-    hidden = hidden + attn_out.astype(hidden.dtype)
+    hidden = hidden + scaled(attn_out).astype(hidden.dtype)
 
     pre_ffn = lp["pre_ffn_norm"] if cfg.sandwich_norm else lp["post_norm"]
     x = rms_norm(hidden, pre_ffn, cfg.rms_norm_eps, p1)
@@ -992,7 +1189,7 @@ def decoder_layer(
             mlp_out = jax.lax.psum(mlp_out, tp_axis)
     if cfg.sandwich_norm:
         mlp_out = rms_norm(mlp_out, lp["post_ffn_norm"], cfg.rms_norm_eps, p1)
-    return hidden + mlp_out.astype(hidden.dtype), entry, topi
+    return hidden + scaled(mlp_out).astype(hidden.dtype), entry, topi
 
 
 # ---------------------------------------------------------------------------
@@ -1050,6 +1247,9 @@ def forward_layers(
     adapters=None,  # multi-tenant LoRA pools + per-lane ids (the ops.lora
     #   pool pytree: {"a", "b", "scale", "ids"}); gathered ONCE here, the
     #   per-layer slices ride the scan like the cache entries
+    state_layers: Optional[Params] = None,  # a model with state-space layers:
+    #   the Mamba kind's stack, `layers` then being the attention kind's; the
+    #   two ride the scan by kind, as a cache split by kind does
 ):
     """Run a stack of decoder layers via ONE lax.scan over periods of
     cfg.layer_pattern -> (hidden, entries', chosen experts [L, B, S, K] or
@@ -1074,8 +1274,13 @@ def forward_layers(
     layer is its own period and the windows ride the scan as a traced,
     mask-only input.
     """
-    cos, sin = rope_cos_sin(positions, cfg.rope_dim, cfg.rope_theta, cfg)
+    cos = sin = None
+    if cfg.position_embedding == "rope":
+        cos, sin = rope_cos_sin(positions, cfg.rope_dim, cfg.rope_theta, cfg)
     n = _stack_len(layers)
+    split = state_layers is not None  # a weight stack per kind
+    if split:
+        n += _stack_len(state_layers)
 
     # multi-tenant LoRA: one per-lane gather of the stacked pools, then
     # the layer-leading slices ride the scan as ordinary xs (None = no
@@ -1105,11 +1310,28 @@ def forward_layers(
     head = min(-layer_offset % period, n) if period > 1 else 0
     nper, tail = divmod(n - head, period)
     per_layer = (layers, None if static else layer_windows(cfg, n, layer_offset), ad_per)
-    by_kind = len(entries) == period > 1  # a stack per kind; else ONE, in layer order
+    uniq = tuple(dict.fromkeys(kinds))  # the kinds, in the order a period first meets them
+    by_kind = len(entries) == len(uniq) > 1  # a stack per kind; else ONE, in layer order
+    if cfg.has_state_layers and not (
+            split and static and head == tail == 0 and adapters is None):
+        raise ValueError(
+            f"{cfg.name}: a model with state-space layers runs whole periods of its two "
+            "weight stacks from a static offset (one stage, no adapter)"
+        )
+    if split:
+        per_layer = (tuple(state_layers if kind == "mamba" else layers for kind in uniq),
+                     None, None)
+
+    def place(j):  # of place j in a period: (its kind's stack, its rank among the
+        #   period's layers of that kind, how many of them a period has)
+        return uniq.index(kinds[j]), kinds[:j].count(kinds[j]), kinds.count(kinds[j])
 
     def home(i, p=0):  # (stack, index in it) of the entry of layer i + p periods
         m = cache_offset + i
-        return ((layer_offset + i) % period, m // period + p) if by_kind else (0, m + p * period)
+        if not by_kind:
+            return 0, m + p * period
+        s, rank, count = place((layer_offset + i) % period)
+        return s, m // period * count + rank + (p if count == 1 else p * count)
 
     def layer(h, ents, i, per_i, p=0):
         lp, win, ad_sl = per_i
@@ -1129,11 +1351,19 @@ def forward_layers(
     def fold(tree, lo, count):  # leaves [n, ...] -> `count` periods [count, period, ...]
         if period == 1:
             return tree
+        if split:  # nothing rides as xs: a layer reads its weights where they lie
+            return None
         return jax.tree.map(
             lambda a: a[lo : lo + count * period].reshape(count, period, *a.shape[1:]), tree
         )
 
-    def pick(tree, j):  # one period's leaves [period, ...] -> layer j's
+    def pick(tree, j, p=0):  # one period's leaves [period, ...] -> layer j's
+        if split:
+            # a stack per kind: layer j of period p is a view of its kind's
+            # stack, as its cache entry is (a period's weights folded into
+            # the scan's inputs would be copied out, nine layers at a time)
+            s, rank, count = place(j)
+            return jax.tree.map(lambda a: _slab(a, p * count + rank), per_layer[0][s]), None, None
         return tree if period == 1 else jax.tree.map(lambda a: a[j], tree)
 
     def pack(vals):  # the period's layers' values -> leaves [period, ...]
@@ -1154,11 +1384,11 @@ def forward_layers(
             per_p, p = xs
             tops = []
             for j in range(period):
-                h, ents, topi = layer(h, ents, head + j, pick(per_p, j), p)
+                h, ents, topi = layer(h, ents, head + j, pick(per_p, j, p), p)
                 tops.append(topi)
             return (h, ents), pack(tops)
 
-        periods = jnp.arange(nper, dtype=jnp.int32) if entries else None
+        periods = jnp.arange(nper, dtype=jnp.int32) if entries or split else None
         (hidden, entries), tops = jax.lax.scan(
             body, (hidden, entries), (fold(per_layer, head, nper), periods)
         )
@@ -1195,6 +1425,7 @@ def forward_layers_cached(
     adapters=None,  # multi-tenant LoRA pool pytree + per-lane ids
     tp_axis: Optional[str] = None,
     ep_axis: Optional[str] = None,
+    state_layers: Optional[Params] = None,  # the Mamba kind's stack (forward_layers)
 ):
     """THE cached stage/model forward: every layout of core.cache (dense
     lanes, latent, ring-split, paged pool) goes through here and through
@@ -1204,7 +1435,7 @@ def forward_layers_cached(
     hidden, entries, topi = forward_layers(
         layers, cfg, hidden, positions, cache.entries(cfg),
         cache.ctx(cache_write_pos, real_end, write_mask),
-        tp_axis, ep_axis, layer_offset, cache_offset, adapters,
+        tp_axis, ep_axis, layer_offset, cache_offset, adapters, state_layers,
     )
     return hidden, cache.with_entries(entries), topi
 
@@ -1239,6 +1470,7 @@ def forward_cached(
             layers, cfg, hidden, positions, cache, cache_write_pos,
             real_end, layer_offset=offset, cache_offset=offset,
             write_mask=write_mask, adapters=adapters,
+            state_layers=params.get("state_layers"),
         )
         if chosen is not None:
             topi = chosen  # the one group with routers
@@ -1401,6 +1633,8 @@ def embed(params: Params, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
         # Gemma: scale by sqrt(H), normalizer rounded to the activation
         # dtype first (matches HF's torch.tensor(h**0.5, dtype=...))
         e = e * jnp.asarray(math.sqrt(cfg.hidden_size), e.dtype)
+    if cfg.embedding_multiplier != 1.0:  # Granite
+        e = e * jnp.asarray(cfg.embedding_multiplier, e.dtype)
     return e
 
 
@@ -1414,6 +1648,8 @@ def unembed(params: Params, cfg: ModelConfig, hidden: jax.Array) -> jax.Array:
             z = (x @ params["embed"].T).astype(jnp.float32)
     else:
         z = qdot(x, params["lm_head"]).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:  # Granite DIVIDES its logits
+        z = z / cfg.logits_scaling
     return attention_ops.apply_softcap(z, cfg.final_logit_softcap)
 
 
@@ -1441,5 +1677,6 @@ def forward(
         positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
     hidden = embed(params, tokens, cfg)
     for layers in layer_groups(params):
-        hidden, _, _ = forward_layers(layers, cfg, hidden, positions)
+        hidden, _, _ = forward_layers(
+            layers, cfg, hidden, positions, state_layers=params.get("state_layers"))
     return unembed(params, cfg, hidden), None, None

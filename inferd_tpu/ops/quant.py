@@ -387,6 +387,7 @@ def qeinsum(spec: str, x: jax.Array, w: WeightLike) -> jax.Array:
 # and the matrix is tiny.
 _LAYER_LINEARS = (
     "q_proj", "k_proj", "v_proj", "o_proj", "gate_proj", "up_proj", "down_proj",
+    "in_proj", "out_proj",  # a Mamba-2 layer's two projections (`state_layers`)
 )
 
 
@@ -408,12 +409,14 @@ def quantize_params(
     """
     out = dict(params)
     qtypes = (QuantWeight, Int4Weight)
-    if "layers" in out:
-        layers = dict(out["layers"])
+    for group in ("layers", "state_layers"):
+        if group not in out:
+            continue
+        layers = dict(out[group])
         for name in _LAYER_LINEARS:
             if name in layers and not isinstance(layers[name], qtypes):
                 layers[name] = quantizer(layers[name])
-        out["layers"] = layers
+        out[group] = layers
     if "lm_head" in out and not isinstance(out["lm_head"], qtypes):
         out["lm_head"] = quantizer(out["lm_head"])
     elif (

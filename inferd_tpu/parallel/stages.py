@@ -165,15 +165,17 @@ def extract_stage_params(full: Params, cfg: ModelConfig, spec: StageSpec) -> Par
     """The param subset a stage needs: its layer slice, plus embed on the
     first stage and final-norm/lm-head on the last (reference
     split_model.py:92-102 semantics, as pytree slicing)."""
-    if "dense_layers" in full:
-        # two groups of layers (models/qwen3.layer_groups): kept whole, in
-        # the one stage that the lane path serves
+    groups = [g for g in ("dense_layers", "state_layers") if g in full]
+    if groups:
+        # more than one stack of layers (models/qwen3.layer_groups, or a
+        # stack per kind of layer): kept whole, in the one stage that the
+        # lane path serves
         if spec.num_stages != 1:
             raise ValueError(
-                f"{cfg.name}: a model with leading dense layers is served whole "
+                f"{cfg.name}: a model with {' and '.join(groups)} is served whole "
                 f"(one stage), not split into {spec.num_stages}"
             )
-        out: Params = {"dense_layers": full["dense_layers"], "layers": full["layers"]}
+        out: Params = {g: full[g] for g in groups + ["layers"]}
     else:
         out = {
             "layers": qwen3.slice_layers(full["layers"], spec.start_layer, spec.end_layer + 1)

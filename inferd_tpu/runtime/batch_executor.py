@@ -576,6 +576,13 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                         f"session {session_id}: start_pos {start_pos} != cache "
                         f"length {have} (out-of-order chunk)"
                     )
+                if self.cfg.has_state_layers:
+                    # before any frontier moves: the lane stays as it was
+                    raise ValueError(
+                        f"session {session_id}: replay to {start_pos} of {have} tokens: "
+                        f"{self.cfg.name} holds a recurrent state, which does not roll "
+                        "back; restart the session at 0"
+                    )
                 hi = max(self._lane_hi.get(lane, 0), have)
                 if (
                     self.engine.cache.k_loc is not None
@@ -954,6 +961,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             ):
                 eng.cache, logits, packed = fn(
                     eng.params, eng.cache, toks, lens, ads=ads, ask=ask, top_n=top_n,
+                    # a lane not in this step keeps its recurrent state
+                    **({"active": active} if self.cfg.has_state_layers else {}),
                 )
                 # the transfer is queued behind the step: the small array
                 # is on its way when the device is done
@@ -1090,6 +1099,12 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 f"{self.cfg.name}: {what} of a latent cache is not supported "
                 "(the handoff schema carries keys and values per head)"
             )
+        if self.cfg.has_state_layers:
+            raise ValueError(
+                f"{self.cfg.name}: {what} of a recurrent state is not supported "
+                "(the handoff schema carries keys and values per token, and no "
+                "prefix of a state can be cut from it)"
+            )
 
     def end_session(self, session_id: str) -> None:
         with self._mu:
@@ -1107,8 +1122,10 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         (refcounted, CoW on divergence) and queues a private copy of only
         the partial tail block — O(1) device work instead of a prefix-
         sized buffer copy."""
-        if prefix_len <= 0 or self.cfg.is_block_diffusion:
-            return False  # a model generated by blocks forks nothing yet
+        if prefix_len <= 0 or self.cfg.is_block_diffusion or self.cfg.has_state_layers:
+            # a model generated by blocks forks nothing yet; a recurrent
+            # state is the state after ALL of the parent's tokens
+            return False
         with self._mu:
             if self._session_adapter.get(parent_session_id):
                 # the fork flow admits the child WITHOUT an adapter key:
@@ -1505,6 +1522,11 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         (resident until unpinned; later sessions map the region read-only
         instead of recomputing it) — the Engine pin store generalized to
         refcounted pool blocks. Returns the pinned token coverage."""
+        if self.cfg.has_state_layers:
+            raise ValueError(
+                f"{self.cfg.name}: pin_prefix: a recurrent state cannot be shared "
+                "at a prefix (no snapshot of it is kept)"
+            )
         if self.pool is None:
             raise ValueError("pin_prefix needs paged KV (--paged-kv)")
         ids = [int(t) for t in prefix_ids]
@@ -1597,9 +1619,13 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             if self.pool is not None:
                 out["paged"] = self.pool.block_stats()
             else:  # what the lanes' cache really allocates
-                nbytes = self.engine.cache.nbytes
+                state = self.engine.cache.state_bytes
+                nbytes = self.engine.cache.nbytes - state  # the buffers that grow with tokens
                 out["kv_cache_bytes"] = nbytes
                 out["kv_bytes_per_token"] = nbytes // (self.engine.lanes * self.max_len)
+                if self.cfg.has_state_layers:  # what a session holds whatever its length
+                    out["state_bytes"] = state
+                    out["state_bytes_per_session"] = state // self.engine.lanes
             if self._moe is not None:
                 out["moe"] = dict(self._moe, experts=self.cfg.num_experts)
             if self._diffusion is not None:

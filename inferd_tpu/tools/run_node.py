@@ -416,7 +416,28 @@ def check_servable(cfg, args, num_stages: int = 1) -> None:
     (cfg.is_block_diffusion) is served whole from the dense lanes of
     --batch-lanes, in its own dtype, --kv-dtype or --quant, and by nothing
     else: every other path steps a token a call. So is, by its name, a
-    model with latent attention or with a leading dense group."""
+    model with latent attention or with a leading dense group, and one with
+    state-space layers (cfg.has_state_layers: its recurrent state lives in
+    the dense lanes' StateEntry and nowhere else; --kv-dtype and --quant run
+    it unchanged)."""
+    if cfg.has_state_layers:
+        _refuse(cfg, {
+            "--mesh (no recurrent state in a mesh slot, and its two weight stacks "
+            "are not sharded)": args.mesh,
+            "--stage-lanes (a stage holds one stack of layers)": args.stage_lanes > 0,
+            "--paged-kv (the paged pool has no state entry)": args.paged_kv > 0,
+            "--spec-draft-layers (a rejected draft would need the state back, and a "
+            "recurrent state does not roll back)": args.spec_draft_layers > 0,
+            "--lora": bool(args.lora),
+            "--adapters (the registry targets attention's dense projections)":
+                bool(args.adapters),
+            "--standby-repl (no handoff or standby export of a recurrent state)":
+                args.standby_repl,
+            "serving without --batch-lanes (only the lane executor holds a StateEntry)":
+                args.backend == "qwen3" and args.batch_lanes <= 0,
+            "a manifest of several stages (the two weight stacks are kept whole)":
+                num_stages > 1,
+        })
     if cfg.is_block_diffusion:
         _refuse(cfg, {
             "--mesh (a pipeline pass steps one token a slot)": args.mesh,

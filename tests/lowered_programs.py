@@ -1,0 +1,88 @@
+"""The ten programs the benchmark's five cells run (a decode or block step
+and a prefill, the lane programs with the sampler at both of its widths),
+lowered at the tiny presets: `texts()` gives their StableHLO text by name.
+What the text holds is the traced program; sizes are not the point: a change
+that leaves these configurations alone leaves every byte alone.
+
+    python tests/lowered_programs.py <dir>      writes <dir>/<name>.txt
+    python tests/lowered_programs.py --record   rewrites tests/data/lowered_programs.json
+
+A PR that changes one of these programs on purpose records the digests anew
+and says so; tests/test_model.py holds the tree to them."""
+
+import hashlib
+import json
+import os
+import sys
+
+DIGESTS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data", "lowered_programs.json")
+
+
+def texts() -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from inferd_tpu.config import get_config
+    from inferd_tpu.core import sampling as samplib
+    from inferd_tpu.core.batch import BatchedEngine
+    from inferd_tpu.models import qwen3
+    from inferd_tpu.parallel import mesh as meshlib
+    from inferd_tpu.parallel.infer import PipelinedEngine
+
+    out = {}
+    i32 = jnp.int32(0)
+    for cell, model, lanes in (("q4b", "tiny", 5), ("dsv2l", "tiny-dsv2", 16), ("sdar", "tiny-sdar", 16)):
+        cfg = get_config(model)
+        params = qwen3.init_params(cfg, jax.random.PRNGKey(0))
+        eng = BatchedEngine(cfg, params, lanes=lanes, max_len=64)
+        toks = jnp.zeros((lanes,), jnp.int32)
+        chunk = jnp.zeros((1, 32), jnp.int32)
+        out[f"{cell}.prefill"] = eng._prefill_lane_logits.lower(
+            eng.params, eng.cache, chunk, i32, i32, i32).as_text()
+        if cfg.is_block_diffusion:
+            blk = jnp.zeros((lanes, cfg.block_length), jnp.int32)
+            out[f"{cell}.block"] = eng._block_step.lower(
+                eng.params, eng.cache, blk, blk.astype(bool), toks, toks.astype(bool),
+                jnp.zeros((lanes, 2), jnp.uint32)).as_text()
+            continue
+        ask = samplib.RowAsk(jnp.zeros((lanes, 2), jnp.uint32), jnp.zeros((lanes, 4), jnp.float32))
+        for top_n in (0, 8):
+            out[f"{cell}.decode.top{top_n}"] = eng._decode_logits.lower(
+                eng.params, eng.cache, toks, toks, ask=ask, top_n=top_n).as_text()
+    cfg = get_config("tiny")
+    mesh = meshlib.make_mesh(meshlib.MeshPlan(pp=4), jax.devices()[:4])
+    eng = PipelinedEngine(cfg, qwen3.init_params(cfg, jax.random.PRNGKey(0)), mesh,
+                          num_microbatches=8, max_len=64)
+    slots = jnp.zeros((8,), jnp.int32)
+    ask = samplib.RowAsk(jnp.zeros((8, 2), jnp.uint32), jnp.zeros((8, 4), jnp.float32))
+    out["q8b-pp4.prefill"] = eng._step_raw.lower(
+        eng.params, eng.caches, jnp.zeros((1, 1, 32), jnp.int32), i32, i32, jnp.bool_(False)).as_text()
+    out["q8b-pp4.decode.top0"] = eng._step_raw_multi.lower(
+        eng.params, eng.caches, slots, slots.astype(bool), ask=ask, top_n=0).as_text()
+    return out
+
+
+NAMES = ("q4b.prefill", "q4b.decode.top0", "q4b.decode.top8", "dsv2l.prefill", "dsv2l.decode.top0",
+         "dsv2l.decode.top8", "sdar.prefill", "sdar.block", "q8b-pp4.prefill", "q8b-pp4.decode.top0")
+
+
+def digests(found: dict) -> dict:
+    return {name: hashlib.sha256(text.encode()).hexdigest() for name, text in sorted(found.items())}
+
+
+if __name__ == "__main__":
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=8").strip()
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    found = texts()
+    assert tuple(sorted(found)) == tuple(sorted(NAMES)), sorted(found)
+    if sys.argv[1] == "--record":
+        with open(DIGESTS, "w") as f:
+            json.dump(digests(found), f, indent=1)
+    else:
+        os.makedirs(sys.argv[1], exist_ok=True)
+        for name, text in found.items():
+            with open(os.path.join(sys.argv[1], f"{name}.txt"), "w") as f:
+                f.write(text)
+    print(json.dumps(digests(found), indent=1))

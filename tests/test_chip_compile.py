@@ -400,6 +400,56 @@ def test_sdar_block_program_compiles_at_16_lanes(one_chip, no_compile_cache):
     assert prefill.memory_analysis().temp_size_in_bytes < 0.4e9  # 0.25 GB
 
 
+def test_granite_hybrid_lane_programs_compile_and_the_state_is_updated_where_it_lies(
+    one_chip, no_compile_cache
+):
+    """The two programs a `--model granite-4.0-h-micro --batch-lanes 32
+    --max-len 4096` node runs, at the published widths and depth: 6.38 GB of
+    weights, 2.45 GB of recurrent state and columns, 1.07 GB of keys and
+    values. The decode step (with its sampler and the lanes' `active` mask,
+    as the executor calls it) aliases the whole donated cache to its output
+    and holds no second copy of the state stack `f32[36,32,64,64,128]` among
+    its temporaries, nor of a period's weights (the two weight stacks are
+    read where they lie: folded into the scan's inputs, nine layers of them
+    were copied out a period, 5.8 GB of temporaries). What it does hold,
+    2.19 GB, is the four attention layers' lanes re-laid T-minor around the
+    layer loop (head size 64; PERF.md section 7). A 512-token prefill chunk
+    holds 0.37 GB. The numbers are the configuration's `deployment`."""
+    import re
+
+    from inferd_tpu.core import sampling as samplib
+    from inferd_tpu.core.batch import BatchedEngine
+    from inferd_tpu.core.cache import KVCache
+    from inferd_tpu.models import qwen3
+
+    cfg = get_config("granite-4.0-h-micro")
+    lanes, max_len = 32, 4096
+    params = _on(jax.eval_shape(lambda: qwen3.init_params(cfg, jax.random.PRNGKey(0))), one_chip)
+    eng = BatchedEngine(cfg, None, lanes=lanes, max_len=64)
+    shapes = jax.eval_shape(lambda: KVCache.create(cfg, cfg.num_layers, lanes, max_len))
+    cache = _on(shapes, one_chip)
+    toks = _sds((lanes,), jnp.int32, one_chip)
+    ask = samplib.RowAsk(_sds((lanes, 2), jnp.uint32, one_chip),
+                         _sds((lanes, 4), jnp.float32, one_chip))
+    step = eng._decode_logits.lower(
+        params, cache, toks, toks, ask=ask, top_n=8, active=_sds((lanes,), jnp.bool_, one_chip)
+    ).compile()
+    mem = step.memory_analysis()
+    assert 9.85e9 < mem.argument_size_in_bytes < 9.95e9  # 6.38 GB of weights + 3.52 of cache
+    assert mem.alias_size_in_bytes >= shapes.nbytes  # state, columns, keys and values: all in place
+    assert mem.temp_size_in_bytes < 2.4e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    text = step.as_text()
+    copies = re.findall(r"= (\S+?)\{[^ ]* copy\(", text)
+    assert copies and not [c for c in copies if c.startswith("f32[36,32,64,64,128]")]
+    assert not [c for c in copies if re.match(r"bf16\[(36|4,9|9),(2048|4096|8192),", c)]
+    i32 = _sds((), jnp.int32, one_chip)
+    chunk = _sds((1, 512), jnp.int32, one_chip)
+    prefill = eng._prefill_lane_logits.lower(params, cache, chunk, i32, i32, i32).compile()
+    pm = prefill.memory_analysis()
+    assert pm.temp_size_in_bytes < 0.5e9 and pm.alias_size_in_bytes >= shapes.nbytes
+
+
 # ---------------------------------------------------------------------------
 # the --mesh pipeline's decode pass, as run_node --mesh pp=4 --mesh-slots 8 builds it
 # ---------------------------------------------------------------------------

@@ -978,3 +978,39 @@ def test_the_carried_cache_equals_slabs_threaded_layer_by_layer(layout):
     if masked:  # row 1 wrote nothing: its blocks (5..8) hold what they held
         np.testing.assert_array_equal(got_c.k[:, 5:], cache.k[:, 5:])
         np.testing.assert_array_equal(got_c.v[:, 5:], cache.v[:, 5:])
+
+
+# ---------------------------------------------------------------------------
+# the programs the benchmark's cells run, held to their recorded text
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def lowered_programs():
+    from tests import lowered_programs as lp
+
+    return lp.digests(lp.texts())
+
+
+def _program_names():
+    from tests import lowered_programs as lp
+
+    return lp.NAMES
+
+
+@pytest.mark.parametrize("name", _program_names())
+def test_a_cells_program_lowers_to_its_recorded_text(lowered_programs, name):
+    """The ten programs of the five older cells (tests/lowered_programs.py)
+    lower byte for byte to the text recorded at PR 42, which was the parent's
+    (4f27965): a model's new fields, absent by default, trace nothing into
+    another model's program. A PR that changes one of them on purpose runs
+    `python tests/lowered_programs.py --record` and says so."""
+    import json
+
+    from tests import lowered_programs as lp
+
+    with open(lp.DIGESTS) as f:
+        recorded = json.load(f)
+    assert lowered_programs[name] == recorded[name], (
+        f"{name} no longer lowers to the recorded text: diff `python tests/lowered_programs.py "
+        "<dir>` of this tree against the parent's")
