@@ -7,13 +7,15 @@ the TPU-idiomatic design: XLA sees one static shape per (batch, max_len)
 bucket instead of a shape that grows every token (which would trigger a
 recompile per step).
 
-Layout: k/v are [num_global_layers, batch, max_len, num_kv_heads, head_dim];
+Layout: k/v are [num_global_layers, batch, max_len, num_kv_heads, head_dim],
+or [num_global_layers, batch, max_len, num_kv_heads * head_dim] where a head is
+narrower than the chip's tile (`rows_layout`: one row a token);
 `length` is the number of populated positions. Overflow is checked host-side
 (`ensure_room`) because in-jit dynamic_update_slice clamps silently (see
 models/qwen3.decoder_layer contract).
 
 This module owns the LAYOUTS. For one layer a cache entry is one value: none
-(cache-free), `DenseEntry`, `LatentEntry`, `RingEntry`, `PagedEntry` or
+(cache-free), `DenseEntry`, `RowEntry`, `LatentEntry`, `RingEntry`, `PagedEntry` or
 `StateEntry` (a recurrent state, the one entry that does not grow with tokens);
 stacked over layers they are what the one layer scan of
 models/qwen3.forward_layers CARRIES: a layer writes its chunk's rows into
@@ -57,6 +59,43 @@ from inferd_tpu.config import ModelConfig
 RING_MARGIN = 64
 
 
+# The minor dimension of the chip's (8, 128) tile. A K/V array whose last axis
+# is narrower pads every row to it, and the compiler then re-lays the whole
+# stack between the layout its scatter of rows wants and the one its dots want.
+TILE_LANES = 128
+
+
+def rows_layout(cfg: ModelConfig) -> bool:
+    """Do uniform dense lanes of this model store a token's keys (values) of
+    ALL kv heads as one row [.., Nkv * D] (`RowEntry`) and not as [.., Nkv, D]
+    (`DenseEntry`)? Where a head is narrower than a tile: the row is the
+    shape that is written and read where it lies. Rings and paged pools keep
+    heads; a latent cache has none."""
+    return not cfg.is_mla and cfg.head_dim < TILE_LANES
+
+
+def lane_shape(cfg: ModelConfig) -> Tuple[int, ...]:
+    """What follows [.., B, T] in uniform dense K / V lanes: a head axis, or
+    for a narrow head one row of all kv heads."""
+    if rows_layout(cfg):
+        return (cfg.num_kv_heads * cfg.head_dim,)
+    return (cfg.num_kv_heads, cfg.head_dim)
+
+
+def wire_heads(a: np.ndarray, cfg: ModelConfig) -> np.ndarray:
+    """A host copy of K/V lanes [L, B, T, ...] in the shape every handoff
+    payload has, [L, B, T, Nkv, D], whatever layout it was stored in (a
+    row-major reshape on the host: free)."""
+    return a if a.ndim == 5 else a.reshape(*a.shape[:3], -1, cfg.head_dim)
+
+
+def from_wire(a: np.ndarray, cfg: ModelConfig, uniform: bool) -> np.ndarray:
+    """The inverse of `wire_heads`: wire-shaped lanes [L, B, T, Nkv, D] as
+    `KVCache.create` lays this model's out; `uniform`: the cache has no
+    rings (a payload without them), which is where rows are stored."""
+    return a.reshape(*a.shape[:3], -1) if uniform and rows_layout(cfg) else a
+
+
 def ring_slots(cfg: ModelConfig) -> int:
     """Ring length for sliding layers: 16-rounded window + safety margin."""
     return (int(cfg.sliding_window) + 15) // 16 * 16 + RING_MARGIN
@@ -88,6 +127,16 @@ class DenseEntry:
     """Dense lanes: slot index == absolute position."""
 
     k: jax.Array  # [B, T, Nkv, D]
+    v: jax.Array
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class RowEntry:
+    """Dense lanes of heads narrower than a tile (`rows_layout`): a token's
+    keys of all kv heads are ONE row, kv head n in columns [n*D, (n+1)*D)."""
+
+    k: jax.Array  # [B, T, Nkv * D]
     v: jax.Array
 
 
@@ -151,7 +200,8 @@ def _nbytes(*arrays) -> int:
 @dataclasses.dataclass
 class KVCache:
     k: jax.Array  # [Lg, B, T, Nkv, D] global (full-length) layers
-    v: jax.Array  # [Lg, B, T, Nkv, D]; a latent (MLA) cache: k [L, B, T, R], v [L, B, T, Dr]
+    v: jax.Array  # [Lg, B, T, Nkv, D]; rows (rows_layout): [Lg, B, T, Nkv * D] both;
+    #   a latent (MLA) cache: k [L, B, T, R], v [L, B, T, Dr]
     length: jax.Array  # int32 scalar: populated positions
     k_loc: Optional[jax.Array] = None  # [Ll, B, R, Nkv, D] sliding-layer rings
     v_loc: Optional[jax.Array] = None
@@ -187,12 +237,14 @@ class KVCache:
         comparison/compat path — also what executors with a TRACED layer
         offset must use)."""
         dt = dtype or cfg.kv_jnp_dtype
+        heads = (cfg.num_kv_heads, cfg.head_dim)
+        lane = lane_shape(cfg)  # uniform lanes only: beside rings, heads
         if cfg.has_state_layers:
             # keys and values (in the kv dtype) for the attention layers, a
             # state and the convolution's last inputs for the others
             la = cfg.layers_of("attention", num_layers)
             lm = num_layers - la
-            shape = (la, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
+            shape = (la, batch, max_len, *lane)
             return KVCache(
                 k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt), length=jnp.int32(0),
                 s=jnp.zeros(
@@ -216,14 +268,14 @@ class KVCache:
         )
         loc = sliding_layer_ids(cfg, num_layers, layer_offset) if use_ring else []
         if not loc:  # uniform layout (forced, no window, or global-only slice)
-            shape = (num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
+            shape = (num_layers, batch, max_len, *lane)
             return KVCache(
                 k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt), length=jnp.int32(0)
             )
         lg = num_layers - len(loc)
         r = ring_slots(cfg)
-        gshape = (lg, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
-        lshape = (len(loc), batch, r, cfg.num_kv_heads, cfg.head_dim)
+        gshape = (lg, batch, max_len, *heads)
+        lshape = (len(loc), batch, r, *heads)
         return KVCache(
             k=jnp.zeros(gshape, dt),
             v=jnp.zeros(gshape, dt),
@@ -264,7 +316,7 @@ class KVCache:
         every layer in layer order."""
         if cfg.is_mla:
             return (LatentEntry(c=self.k, r=self.v),)
-        glob = DenseEntry(k=self.k, v=self.v)
+        glob = (RowEntry if self.k.ndim == 4 else DenseEntry)(k=self.k, v=self.v)
         if self.s is not None:
             state = StateEntry(s=self.s, conv=self.conv)
             return tuple(state if kind == "mamba" else glob
@@ -279,7 +331,8 @@ class KVCache:
         if isinstance(entries[0], LatentEntry):
             return KVCache(k=entries[0].c, v=entries[0].r, length=self.length)
         by_type = {type(e): e for e in entries}
-        glob, ring, state = by_type[DenseEntry], by_type.get(RingEntry), by_type.get(StateEntry)
+        glob = by_type.get(RowEntry) or by_type[DenseEntry]
+        ring, state = by_type.get(RingEntry), by_type.get(StateEntry)
         return KVCache(
             k=glob.k, v=glob.v, length=self.length,
             k_loc=None if ring is None else ring.k,
@@ -287,6 +340,12 @@ class KVCache:
             s=None if state is None else state.s,
             conv=None if state is None else state.conv,
         )
+
+    def layout(self, cfg: ModelConfig) -> str:
+        """What `entries` makes of k and v: "latent", "rows" or "heads"."""
+        if cfg.is_mla:
+            return "latent"
+        return "rows" if self.k.ndim == 4 else "heads"
 
     @staticmethod
     def ctx(write_pos, real_end=None, write_mask=None) -> CacheCtx:

@@ -711,18 +711,12 @@ def _attend_chunk(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks):
     return attn, None
 
 
-def _attend_update_lanes(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks):
-    """Dense lanes: write at ctx.write_pos, attend over the layer's slab. A
-    STATIC int window narrows the KV read to a window-covering slice
-    (_windowed_slice, the sliding-layer read fast path); a traced window
-    (or None) attends the whole buffer, mask-only; attention masks per
-    row through the valid length where write_pos is per row."""
-    s = q.shape[1]
-    new = cachelib.DenseEntry(
-        k=_lanes_write(entry.k, at, k, ctx.write_pos, ctx.write_mask),
-        v=_lanes_write(entry.v, at, v, ctx.write_pos, ctx.write_mask),
-    )
-    new_k, new_v = _slab(new.k, at), _slab(new.v, at)
+def _lanes_read(cfg, new_k, new_v, ctx, s: int, window):
+    """What a chunk of `s` queries reads of its layer's dense-lane slab
+    [B, T, ...] (either layout: T is axis 1) -> (k, v, kv_positions, valid
+    length, window). A STATIC int window narrows the read to a
+    window-covering slice (_windowed_slice, the sliding-layer read fast
+    path); a traced window (or None) reads the whole buffer, mask-only."""
     end = ctx.write_pos + s
     if cfg.is_block_diffusion and ctx.real_end is not None:
         # a query sees to the end of its block, so the bucket's padding is
@@ -730,11 +724,75 @@ def _attend_update_lanes(cfg, q, k, v, q_positions, entry, at, ctx, window, sink
         end = ctx.real_end
     if isinstance(window, int) and window > 0:
         k_att, v_att, kvpos, valid = _windowed_slice(new_k, new_v, end, window, s)
+        return k_att, v_att, kvpos, valid, jnp.int32(window)
+    return new_k, new_v, None, end, window
+
+
+def _attend_update_lanes(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks):
+    """Dense lanes: write at ctx.write_pos, attend over the layer's slab
+    (what of it: _lanes_read); attention masks per row through the valid
+    length where write_pos is per row."""
+    new = cachelib.DenseEntry(
+        k=_lanes_write(entry.k, at, k, ctx.write_pos, ctx.write_mask),
+        v=_lanes_write(entry.v, at, v, ctx.write_pos, ctx.write_mask),
+    )
+    k_att, v_att, kvpos, valid, window = _lanes_read(
+        cfg, _slab(new.k, at), _slab(new.v, at), ctx, q.shape[1], window)
+    return _attend(
+        cfg, q, k_att, v_att, q_positions, valid, kv_positions=kvpos, window=window, sinks=sinks,
+    ), new
+
+
+def _rows_query(q, nkv: int):
+    """The block-diagonal query of row-stored keys: head i of kv group n
+    (q [B, S, Nq, D]) laid into an Nkv * D wide row that is zero outside
+    columns [n*D, (n+1)*D), so ONE contraction over the row gives each head
+    the score of its own kv head (every product added has a zero factor)."""
+    b, s, nq, d = q.shape
+    own = jnp.eye(nkv, dtype=bool)[:, None, :, None]  # [n, 1, m, 1]
+    qh = q.reshape(b, s, nkv, nq // nkv, 1, d)
+    return jnp.where(own, qh, jnp.zeros((), q.dtype)).reshape(b, s, nq, nkv * d)
+
+
+def _rows_own(out, nq: int, nkv: int):
+    """What a head keeps of the weighted sum of value ROWS: out
+    [B, S, Nq * Nkv * D] -> its own kv head's D columns, [B, S, Nq * D]."""
+    b, s, _ = out.shape
+    o = out.reshape(b, s, nkv, nq // nkv, nkv, -1)
+    return jnp.stack([o[:, :, n, :, n] for n in range(nkv)], axis=2).reshape(b, s, -1)
+
+
+def _attend_update_rows(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks):
+    """Dense lanes of a head narrower than a tile (core.cache.RowEntry):
+    the chunk's keys (values) of all kv heads written as one row a token,
+    and attention as ONE kv "head" the row's width against the
+    block-diagonal query, for a decode step and a chunk alike: the stack is
+    written and read where it lies, in one layout (taken apart into heads it
+    is re-laid whole around the layer loop). Same mask, scale (the REAL
+    head's), softmax and window as _attend_update_lanes. The flash kernel
+    wants heads: where it is chosen the layer's slab is viewed as heads."""
+    b, s, nq, d = q.shape
+    nkv = k.shape[2]
+    new = cachelib.RowEntry(
+        k=_lanes_write(entry.k, at, k.reshape(b, s, nkv * d), ctx.write_pos, ctx.write_mask),
+        v=_lanes_write(entry.v, at, v.reshape(b, s, nkv * d), ctx.write_pos, ctx.write_mask),
+    )
+    new_k, new_v, kvpos, end, window = _lanes_read(  # [B, T, Nkv * D]
+        cfg, _slab(new.k, at), _slab(new.v, at), ctx, s, window)
+    if attention_ops.flash_enabled(
+        cfg, new_k.shape[1], compressed_kv=new_k.dtype != q.dtype, q_len=s, batch=b,
+    ):
+        heads = lambda a: a.reshape(*a.shape[:2], nkv, d)
         return _attend(
-            cfg, q, k_att, v_att, q_positions, valid,
-            kv_positions=kvpos, window=jnp.int32(window), sinks=sinks,
+            cfg, q, heads(new_k), heads(new_v), q_positions, end,
+            kv_positions=kvpos, window=window, sinks=sinks,
         ), new
-    return _attend(cfg, q, new_k, new_v, q_positions, end, window=window, sinks=sinks), new
+    out = gqa_attention(
+        _rows_query(q, nkv), new_k[:, :, None], new_v[:, :, None], q_positions, end,
+        kv_positions=kvpos, scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap,
+        window=window, sinks=sinks,
+    )
+    return _rows_own(out, nq, nkv), new
 
 
 def _attend_update_ring(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks):
@@ -792,6 +850,7 @@ def _attend_update_paged(cfg, q, k, v, q_positions, entry, at, ctx, window, sink
 _ATTEND_UPDATE = {
     type(None): _attend_chunk,
     cachelib.DenseEntry: _attend_update_lanes,
+    cachelib.RowEntry: _attend_update_rows,
     cachelib.RingEntry: _attend_update_ring,
     cachelib.PagedEntry: _attend_update_paged,
 }

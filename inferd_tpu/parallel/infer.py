@@ -57,7 +57,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from inferd_tpu.config import ModelConfig, SamplingConfig
 from inferd_tpu.core import sampling as samplib
-from inferd_tpu.core.cache import KVCache
+from inferd_tpu.core.cache import KVCache, from_wire, lane_shape, wire_heads
 from inferd_tpu.core.generate import bucket_len
 from inferd_tpu.models import qwen3
 from inferd_tpu.obs import trace as tracelib
@@ -77,8 +77,11 @@ class PipelinedCaches:
     """KV caches for MB microbatch slots, sharded over pp on the layer axis.
 
     Uniform layout: k/v [L, MB, B, T, n_kv, head_dim] (L sharded over pp —
-    each rank holds caches only for its own layers); lengths: [MB] valid
-    prefix per slot (uniform within a slot); k_loc/v_loc None.
+    each rank holds caches only for its own layers), or
+    [L, MB, B, T, n_kv * head_dim] where a head is narrower than a tile
+    (core.cache.rows_layout: one row a token, as KVCache.create lays dense
+    lanes); lengths: [MB] valid prefix per slot (uniform within a slot);
+    k_loc/v_loc None.
 
     Split layout (sliding-window configs where every pp rank's layer slice
     starts on an even global index — see ring_split_ok): k/v hold only the
@@ -93,6 +96,11 @@ class PipelinedCaches:
     lengths: jax.Array
     k_loc: Optional[jax.Array] = None
     v_loc: Optional[jax.Array] = None
+
+    @property
+    def layout(self) -> str:
+        """ "rows" where k and v hold one row a token, else "heads"."""
+        return "rows" if self.k.ndim == 5 else "heads"
 
 
 def ring_split_ok(cfg: ModelConfig, pp: int) -> bool:
@@ -133,10 +141,7 @@ def make_caches(
     )
     sharding = NamedSharding(mesh, cache_spec(mesh))
     if not use_ring:
-        shape = (
-            cfg.num_layers, num_microbatches, batch, max_len,
-            cfg.num_kv_heads, cfg.head_dim,
-        )
+        shape = (cfg.num_layers, num_microbatches, batch, max_len, *lane_shape(cfg))
         zeros = _sharded_zeros_fn(shape, cfg.kv_jnp_dtype, sharding)
         return PipelinedCaches(
             k=zeros(), v=zeros(), lengths=jnp.zeros((num_microbatches,), jnp.int32)
@@ -351,7 +356,9 @@ def _rows_pass(
 
 def cache_spec(mesh: Mesh) -> P:
     """PipelinedCaches k/v spec: layers shard over pp; with tp in the mesh
-    the kv-head axis (4 of [L, MB, B, T, n_kv, d]) shards over tp too."""
+    the kv-head axis (4 of [L, MB, B, T, n_kv, d]) shards over tp too (in
+    the row layout [L, MB, B, T, n_kv * d] the same axis 4: a rank's kv
+    heads are one run of the row's columns)."""
     if mesh.shape.get("tp", 1) > 1:
         return P("pp", None, None, None, "tp")
     return P("pp")
@@ -693,7 +700,7 @@ class PipelinedEngine:
             ks = jax.lax.dynamic_slice_in_dim(caches.k, src, 1, axis=1)[:, :, :, :m]
             vs = jax.lax.dynamic_slice_in_dim(caches.v, src, 1, axis=1)[:, :, :, :m]
             zero = jnp.int32(0)
-            idx = (zero, dst, zero, zero, zero, zero)
+            idx = (zero, dst) + (zero,) * (caches.k.ndim - 2)  # rows have no head axis
             k_loc, v_loc = caches.k_loc, caches.v_loc
             if k_loc is not None:
                 kl = jax.lax.dynamic_slice_in_dim(k_loc, src, 1, axis=1)
@@ -773,14 +780,13 @@ class PipelinedEngine:
                             slot, n):
                 k_full, v_full, logits = sp_pass(params, x, positions, n)
                 zero = jnp.int32(0)
-                idx6 = (zero, slot, zero, zero, zero, zero)
+                at = (zero, slot) + (zero,) * (caches.k.ndim - 2)
+                # [L, B, S, Nkv, D] as the slot's lanes are stored (heads or rows)
+                put = lambda a, full: jax.lax.dynamic_update_slice(
+                    a, full.reshape(*full.shape[:3], *a.shape[4:])[:, None].astype(a.dtype), at
+                )
                 return PipelinedCaches(
-                    k=jax.lax.dynamic_update_slice(
-                        caches.k, k_full[:, None].astype(caches.k.dtype), idx6
-                    ),
-                    v=jax.lax.dynamic_update_slice(
-                        caches.v, v_full[:, None].astype(caches.v.dtype), idx6
-                    ),
+                    k=put(caches.k, k_full), v=put(caches.v, v_full),
                     lengths=caches.lengths.at[slot].set(n),
                     k_loc=caches.k_loc, v_loc=caches.v_loc,
                 ), logits
@@ -865,8 +871,8 @@ class PipelinedEngine:
         sliding-layer rings [Ll, B, R, Nkv, D] (whole) or None for uniform
         layouts. The elastic-reshard/checkpoint surface: an exported slot
         can be imported into an engine with a DIFFERENT mesh split."""
-        k = np.asarray(jax.device_get(self.caches.k[:, slot]))
-        v = np.asarray(jax.device_get(self.caches.v[:, slot]))
+        k = wire_heads(np.asarray(jax.device_get(self.caches.k[:, slot])), self.cfg)
+        v = wire_heads(np.asarray(jax.device_get(self.caches.v[:, slot])), self.cfg)
         if self.caches.k_loc is None:
             return k, v, int(self.caches.lengths[slot]), None, None
         kl = np.asarray(jax.device_get(self.caches.k_loc[:, slot]))
@@ -890,24 +896,24 @@ class PipelinedEngine:
                 f"{'on' if ring else 'off'} but payload rings are "
                 f"{'present' if k_loc is not None else 'absent'}"
             )
-        n_glob = self.caches.k.shape[0]
-        want = (n_glob, self.batch, None, k.shape[3], k.shape[4])
-        got = (k.shape[0], k.shape[1], None,
-               self.caches.k.shape[4], self.caches.k.shape[5])
+        want = (self.caches.k.shape[0], self.batch, self.cfg.num_kv_heads, self.cfg.head_dim)
+        got = (k.shape[0], k.shape[1]) + tuple(k.shape[3:])
         if got != want or v.shape != k.shape:
             raise ValueError(f"slot KV shape {k.shape} does not match this engine")
         if length > self.max_len:
             raise BufferError(f"imported length {length} exceeds max_len")
+        # [L, B, T, Nkv, D] as exported, whatever this engine stores
+        k, v = (from_wire(a, self.cfg, uniform=not ring) for a in (k, v))
         t = k.shape[2]
         if t < self.max_len:
-            pad = [(0, 0), (0, 0), (0, self.max_len - t), (0, 0), (0, 0)]
+            pad = [(0, 0), (0, 0), (0, self.max_len - t)] + [(0, 0)] * (k.ndim - 3)
             k, v = np.pad(k, pad), np.pad(v, pad)
         elif t > self.max_len:
             k, v = k[:, :, : self.max_len], v[:, :, : self.max_len]
         kk = jnp.asarray(k, self.caches.k.dtype)
         vv = jnp.asarray(v, self.caches.v.dtype)
         zero = jnp.int32(0)
-        idx = (zero, jnp.int32(slot), zero, zero, zero, zero)
+        idx = (zero, jnp.int32(slot)) + (zero,) * (self.caches.k.ndim - 2)  # rings: with heads, 6
         new_k_loc, new_v_loc = self.caches.k_loc, self.caches.v_loc
         if ring:
             lshape = (self.caches.k_loc.shape[0], self.batch,

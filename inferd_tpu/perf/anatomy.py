@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from inferd_tpu.config import ModelConfig, SamplingConfig
-from inferd_tpu.core.cache import KVCache
+from inferd_tpu.core.cache import KVCache, from_wire
 from inferd_tpu.core import sampling as samplib
 from inferd_tpu.models import qwen3
 from inferd_tpu.ops.quant import apply_quant_mode, qdot
@@ -184,7 +184,8 @@ def _build_suite(
         )
         return (ntok[:, None], cache, key)
 
-    cache0 = KVCache(k=kc, v=vc, length=jnp.int32(ctx))
+    # the fused step runs the layout serving runs (rows where a head is narrow)
+    cache0 = KVCache(k=from_wire(kc, cfg, True), v=from_wire(vc, cfg, True), length=jnp.int32(ctx))
 
     # ---- embed -----------------------------------------------------------
     def embed_body(tok):

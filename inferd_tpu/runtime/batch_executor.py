@@ -43,7 +43,7 @@ import numpy as np
 
 from inferd_tpu.config import ModelConfig
 from inferd_tpu.core.batch import BatchedEngine
-from inferd_tpu.core.cache import RING_MARGIN, sync_paged
+from inferd_tpu.core.cache import RING_MARGIN, from_wire, sync_paged, wire_heads
 from inferd_tpu.core import prefix as prefixlib
 from inferd_tpu.core import sampling as samplib
 from inferd_tpu.core.generate import bucket_len
@@ -1268,10 +1268,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                     kl = np.asarray(self.engine.cache.k_loc[:, lane : lane + 1])
                     vl = np.asarray(self.engine.cache.v_loc[:, lane : lane + 1])
                     hi = max(self._lane_hi.get(lane, 0), n)
+                lanes = lambda a: wire_heads(np.asarray(a[:, lane : lane + 1, :n]), self.cfg)
                 out.append((sid, self._stamp_adapter(sid, handoff.encode(
-                    np.asarray(self.engine.cache.k[:, lane : lane + 1, :n]),
-                    np.asarray(self.engine.cache.v[:, lane : lane + 1, :n]),
-                    n, kl, vl, hi,
+                    lanes(self.engine.cache.k), lanes(self.engine.cache.v), n, kl, vl, hi,
                 ))))
 
     def _stamp_adapter(self, sid: str, payload: Dict[str, Any]):
@@ -1364,10 +1363,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                     kl = np.asarray(self.engine.cache.k_loc[:, lane: lane + 1])
                     vl = np.asarray(self.engine.cache.v_loc[:, lane: lane + 1])
                     hi = max(self._lane_hi.get(lane, 0), n)
+                lanes = lambda a: wire_heads(np.asarray(a[:, lane: lane + 1, since:n]), self.cfg)
                 payload = self._stamp_adapter(session_id, handoff.encode(
-                    np.asarray(self.engine.cache.k[:, lane: lane + 1, since:n]),
-                    np.asarray(self.engine.cache.v[:, lane: lane + 1, since:n]),
-                    n, kl, vl, hi,
+                    lanes(self.engine.cache.k), lanes(self.engine.cache.v), n, kl, vl, hi,
                 ))
                 payload[START_KEY] = since
                 return payload
@@ -1413,6 +1411,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             # early declines release it, its post-bind rollbacks release
             # through _drop
             return self._import_paged(session_id, k, v, n, ad_name)
+        # the wire carries heads; dense lanes of a narrow head store rows
+        k, v = (from_wire(a, self.cfg, uniform=not ring) for a in (k, v))
         with self._dev_lock, self._mu:
             if session_id in self._sessions:
                 if ad_name is not None:
@@ -1615,6 +1615,11 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 sampled_rows=self.sampled_rows,
                 logit_rows=self.logit_rows,
                 **self._batcher.stats(),
+            )
+            # how a token's keys lie: "rows" (all kv heads in one row: a head
+            # narrower than a tile on dense lanes), "heads" or "latent"
+            out["kv_layout"] = (
+                "heads" if self.pool is not None else self.engine.cache.layout(self.cfg)
             )
             if self.pool is not None:
                 out["paged"] = self.pool.block_stats()

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from inferd_tpu.config import ModelConfig
-from inferd_tpu.core.cache import RING_MARGIN, KVCache, grow
+from inferd_tpu.core.cache import RING_MARGIN, KVCache, from_wire, grow, wire_heads
 from inferd_tpu.core.generate import bucket_len
 from inferd_tpu.core.sampling import rows_cover
 from inferd_tpu.models import qwen3
@@ -669,7 +669,8 @@ class Qwen3StageExecutor:
                         # the importer's replay guard needs the true value
                         hi = max(self._ring_hi.get(sid, 0), n)
                 out.append((sid, handoff.encode(
-                    np.asarray(cur.k[:, :, :n]), np.asarray(cur.v[:, :, :n]),
+                    wire_heads(np.asarray(cur.k[:, :, :n]), self.cfg),
+                    wire_heads(np.asarray(cur.v[:, :, :n]), self.cfg),
                     n, kl, vl, hi,
                 )))
         return out
@@ -710,8 +711,8 @@ class Qwen3StageExecutor:
                 with self._hi_lock:
                     hi = max(self._ring_hi.get(session_id, 0), n)
             payload = handoff.encode(
-                np.asarray(cur.k[:, :, since:n]),
-                np.asarray(cur.v[:, :, since:n]),
+                wire_heads(np.asarray(cur.k[:, :, since:n]), self.cfg),
+                wire_heads(np.asarray(cur.v[:, :, since:n]), self.cfg),
                 n, kl, vl, hi,
             )
             payload[START_KEY] = since
@@ -735,8 +736,11 @@ class Qwen3StageExecutor:
         )
         if dec is None:
             return False
-        k, v, n = dec["k"], dec["v"], dec["n"]
+        n = dec["n"]
         k_loc, v_loc = dec["k_loc"], dec["v_loc"]
+        # the wire carries heads; this model's lanes may be stored as rows
+        k = from_wire(dec["k"], self.cfg, uniform=k_loc is None)
+        v = from_wire(dec["v"], self.cfg, uniform=k_loc is None)
         with self.sessions.lock_for(session_id):
             if self.sessions.get(session_id) is not None:
                 return False
@@ -744,7 +748,7 @@ class Qwen3StageExecutor:
             if buf < k.shape[2]:  # shipped more than the target bucket: trim
                 k, v = k[:, :, :buf], v[:, :, :buf]
             elif buf > k.shape[2]:
-                pad = [(0, 0), (0, 0), (0, buf - k.shape[2]), (0, 0), (0, 0)]
+                pad = [(0, 0), (0, 0), (0, buf - k.shape[2])] + [(0, 0)] * (k.ndim - 3)
                 k = np.pad(k, pad)
                 v = np.pad(v, pad)
             cache = KVCache(

@@ -659,6 +659,7 @@ class MeshExecutor(SpecServing):
             "slots": self.engine.mb,
             "sessions": len(self.sessions),
             "kv_window_fallback": self.kv_window_fallback,
+            "kv_layout": self.engine.caches.layout,
             "sampled_rows": self.sampled_rows,
             "logit_rows": self.logit_rows,
             **self._batcher.stats(),

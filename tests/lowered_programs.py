@@ -1,8 +1,12 @@
-"""The ten programs the benchmark's five cells run (a decode or block step
-and a prefill, the lane programs with the sampler at both of its widths),
-lowered at the tiny presets: `texts()` gives their StableHLO text by name.
-What the text holds is the traced program; sizes are not the point: a change
-that leaves these configurations alone leaves every byte alone.
+"""The ten programs the benchmark's five older cells run (a decode or block
+step and a prefill, the lane programs with the sampler at both of its widths),
+and the two lane programs of `g4hm-many-chat`, lowered at the tiny presets:
+`texts()` gives their StableHLO text by name. What the text holds is the traced
+program; sizes are not the point: a change that leaves these configurations
+alone leaves every byte alone. ONE width is the point: the cells' heads are as
+wide as a tile (128), so the tiny presets of the per-head cells are lowered
+with `head_dim=128` (at their own 16 they would take the row layout of
+core.cache.rows_layout, which no older cell runs); granite's are rows.
 
     python tests/lowered_programs.py <dir>      writes <dir>/<name>.txt
     python tests/lowered_programs.py --record   rewrites tests/data/lowered_programs.json
@@ -10,6 +14,7 @@ that leaves these configurations alone leaves every byte alone.
 A PR that changes one of these programs on purpose records the digests anew
 and says so; tests/test_model.py holds the tree to them."""
 
+import dataclasses
 import hashlib
 import json
 import os
@@ -29,10 +34,17 @@ def texts() -> dict:
     from inferd_tpu.parallel import mesh as meshlib
     from inferd_tpu.parallel.infer import PipelinedEngine
 
+    def cell_config(model):
+        cfg = get_config(model)
+        if cfg.is_mla or cfg.has_state_layers:
+            return cfg
+        return dataclasses.replace(cfg, head_dim=128)  # the width of the cell's heads
+
     out = {}
     i32 = jnp.int32(0)
-    for cell, model, lanes in (("q4b", "tiny", 5), ("dsv2l", "tiny-dsv2", 16), ("sdar", "tiny-sdar", 16)):
-        cfg = get_config(model)
+    for cell, model, lanes in (("q4b", "tiny", 5), ("dsv2l", "tiny-dsv2", 16), ("sdar", "tiny-sdar", 16),
+                               ("g4hm", "tiny-granite-h", 4)):
+        cfg = cell_config(model)
         params = qwen3.init_params(cfg, jax.random.PRNGKey(0))
         eng = BatchedEngine(cfg, params, lanes=lanes, max_len=64)
         toks = jnp.zeros((lanes,), jnp.int32)
@@ -46,10 +58,14 @@ def texts() -> dict:
                 jnp.zeros((lanes, 2), jnp.uint32)).as_text()
             continue
         ask = samplib.RowAsk(jnp.zeros((lanes, 2), jnp.uint32), jnp.zeros((lanes, 4), jnp.float32))
+        if cfg.has_state_layers:  # the step as its executor calls it: the lanes' mask, one width
+            out[f"{cell}.decode.top8"] = eng._decode_logits.lower(
+                eng.params, eng.cache, toks, toks, ask=ask, top_n=8, active=toks.astype(bool)).as_text()
+            continue
         for top_n in (0, 8):
             out[f"{cell}.decode.top{top_n}"] = eng._decode_logits.lower(
                 eng.params, eng.cache, toks, toks, ask=ask, top_n=top_n).as_text()
-    cfg = get_config("tiny")
+    cfg = cell_config("tiny")
     mesh = meshlib.make_mesh(meshlib.MeshPlan(pp=4), jax.devices()[:4])
     eng = PipelinedEngine(cfg, qwen3.init_params(cfg, jax.random.PRNGKey(0)), mesh,
                           num_microbatches=8, max_len=64)
@@ -63,7 +79,8 @@ def texts() -> dict:
 
 
 NAMES = ("q4b.prefill", "q4b.decode.top0", "q4b.decode.top8", "dsv2l.prefill", "dsv2l.decode.top0",
-         "dsv2l.decode.top8", "sdar.prefill", "sdar.block", "q8b-pp4.prefill", "q8b-pp4.decode.top0")
+         "dsv2l.decode.top8", "sdar.prefill", "sdar.block", "q8b-pp4.prefill", "q8b-pp4.decode.top0",
+         "g4hm.prefill", "g4hm.decode.top8")
 
 
 def digests(found: dict) -> dict:

@@ -400,6 +400,45 @@ def test_sdar_block_program_compiles_at_16_lanes(one_chip, no_compile_cache):
     assert prefill.memory_analysis().temp_size_in_bytes < 0.4e9  # 0.25 GB
 
 
+def _lane_programs(cfg, lanes, max_len, one_chip, active):
+    """(cache shapes, the compiled decode step with its sampler, the compiled
+    512-token prefill chunk) of a `--batch-lanes` node, for the described chip."""
+    from inferd_tpu.core import sampling as samplib
+    from inferd_tpu.core.batch import BatchedEngine
+    from inferd_tpu.core.cache import KVCache
+    from inferd_tpu.models import qwen3
+
+    params = _on(jax.eval_shape(lambda: qwen3.init_params(cfg, jax.random.PRNGKey(0))), one_chip)
+    eng = BatchedEngine(cfg, None, lanes=lanes, max_len=64)
+    shapes = jax.eval_shape(lambda: KVCache.create(cfg, cfg.num_layers, lanes, max_len))
+    cache = _on(shapes, one_chip)
+    toks = _sds((lanes,), jnp.int32, one_chip)
+    ask = samplib.RowAsk(_sds((lanes, 2), jnp.uint32, one_chip),
+                         _sds((lanes, 4), jnp.float32, one_chip))
+    step = eng._decode_logits.lower(
+        params, cache, toks, toks, ask=ask, top_n=8,
+        active=_sds((lanes,), jnp.bool_, one_chip) if active else None,
+    ).compile()
+    i32 = _sds((), jnp.int32, one_chip)
+    chunk = _sds((1, 512), jnp.int32, one_chip)
+    prefill = eng._prefill_lane_logits.lower(params, cache, chunk, i32, i32, i32).compile()
+    return shapes, step, prefill
+
+
+def _made_whole(text, *shapes):
+    """The `copy` and `dynamic-slice` fusion operations of a compiled text
+    whose result has one of the shapes (a regular expression each): the
+    operations that MAKE an array of a cache's, a stack's or a slab's size
+    (a `dynamic-slice` fused into the dot that reads it makes none)."""
+    import re
+
+    made = re.findall(r"^ *(?:ROOT )?(%\S+) = (\S+?)\{[^ ]* (copy|fusion)\(", text, re.M)
+    return [
+        (name, shape) for name, shape, op in made
+        if (op == "copy" or "dynamic-slice" in name) and any(re.match(s, shape) for s in shapes)
+    ]
+
+
 def test_granite_hybrid_lane_programs_compile_and_the_state_is_updated_where_it_lies(
     one_chip, no_compile_cache
 ):
@@ -411,43 +450,54 @@ def test_granite_hybrid_lane_programs_compile_and_the_state_is_updated_where_it_
     and holds no second copy of the state stack `f32[36,32,64,64,128]` among
     its temporaries, nor of a period's weights (the two weight stacks are
     read where they lie: folded into the scan's inputs, nine layers of them
-    were copied out a period, 5.8 GB of temporaries). What it does hold,
-    2.19 GB, is the four attention layers' lanes re-laid T-minor around the
-    layer loop (head size 64; PERF.md section 7). A 512-token prefill chunk
-    holds 0.37 GB. The numbers are the configuration's `deployment`."""
+    were copied out a period, 5.8 GB of temporaries). Nor of the four
+    attention layers' keys and values: 8 kv heads of 64 are ONE row of 512 a
+    token (core.cache.rows_layout), written and read where the stack lies:
+    under 0.3 GB of temporaries (0.007; as `[4,32,4096,8,64]` the stacks
+    were re-laid T-minor around the layer loop and each slab again inside
+    it: 2.19 GB, PR 42), no `copy` and no `dynamic-slice` fusion that makes
+    a stack `bf16[4,32,4096,...]` or a layer's slab `bf16[(1,)32,4096,...]`.
+    A 512-token prefill chunk likewise: no copy of the stack or of the
+    lane's view of it. The numbers are the configuration's `deployment`."""
     import re
 
-    from inferd_tpu.core import sampling as samplib
-    from inferd_tpu.core.batch import BatchedEngine
-    from inferd_tpu.core.cache import KVCache
-    from inferd_tpu.models import qwen3
-
     cfg = get_config("granite-4.0-h-micro")
-    lanes, max_len = 32, 4096
-    params = _on(jax.eval_shape(lambda: qwen3.init_params(cfg, jax.random.PRNGKey(0))), one_chip)
-    eng = BatchedEngine(cfg, None, lanes=lanes, max_len=64)
-    shapes = jax.eval_shape(lambda: KVCache.create(cfg, cfg.num_layers, lanes, max_len))
-    cache = _on(shapes, one_chip)
-    toks = _sds((lanes,), jnp.int32, one_chip)
-    ask = samplib.RowAsk(_sds((lanes, 2), jnp.uint32, one_chip),
-                         _sds((lanes, 4), jnp.float32, one_chip))
-    step = eng._decode_logits.lower(
-        params, cache, toks, toks, ask=ask, top_n=8, active=_sds((lanes,), jnp.bool_, one_chip)
-    ).compile()
+    shapes, step, prefill = _lane_programs(cfg, 32, 4096, one_chip, active=True)
+    assert shapes.k.shape == (4, 32, 4096, 512)
     mem = step.memory_analysis()
     assert 9.85e9 < mem.argument_size_in_bytes < 9.95e9  # 6.38 GB of weights + 3.52 of cache
     assert mem.alias_size_in_bytes >= shapes.nbytes  # state, columns, keys and values: all in place
-    assert mem.temp_size_in_bytes < 2.4e9
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    assert mem.temp_size_in_bytes < 0.3e9
     text = step.as_text()
     copies = re.findall(r"= (\S+?)\{[^ ]* copy\(", text)
     assert copies and not [c for c in copies if c.startswith("f32[36,32,64,64,128]")]
     assert not [c for c in copies if re.match(r"bf16\[(36|4,9|9),(2048|4096|8192),", c)]
-    i32 = _sds((), jnp.int32, one_chip)
-    chunk = _sds((1, 512), jnp.int32, one_chip)
-    prefill = eng._prefill_lane_logits.lower(params, cache, chunk, i32, i32, i32).compile()
+    kv = (r"bf16\[4,32,4096,", r"bf16\[1,32,4096,", r"bf16\[32,4096,\d+[,\]]")
+    assert _made_whole(text, *kv) == []
     pm = prefill.memory_analysis()
     assert pm.temp_size_in_bytes < 0.5e9 and pm.alias_size_in_bytes >= shapes.nbytes
+    assert not re.findall(r"= bf16\[4,(?:32|1),4096,[^ ]* copy\(", prefill.as_text())
+
+
+def test_llama32_1b_lanes_keep_their_rows_where_they_lie(one_chip, no_compile_cache):
+    """The other public model of this head size (8 kv heads of 64, 16
+    layers), as `--model llama3.2-1b --batch-lanes 32 --max-len 4096` would
+    run it: the decode step aliases its 2.1 GB cache and makes no copy of a
+    stack `bf16[16,32,4096,512]` or of a layer's slab (as `[.., 8, 64]` it
+    compiled to the same re-laying copies as granite's, two of its whole
+    cache; PERF.md section 7), the prefill chunk none of the stack."""
+    import re
+
+    cfg = get_config("llama3.2-1b")
+    shapes, step, prefill = _lane_programs(cfg, 32, 4096, one_chip, active=False)
+    assert shapes.k.shape == (16, 32, 4096, 512)
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= shapes.nbytes
+    assert mem.temp_size_in_bytes < 0.3e9
+    kv = (r"bf16\[16,32,4096,", r"bf16\[1,32,4096,", r"bf16\[32,4096,\d+[,\]]")
+    assert _made_whole(step.as_text(), *kv) == []
+    assert prefill.memory_analysis().alias_size_in_bytes >= shapes.nbytes
+    assert not re.findall(r"= bf16\[16,(?:32|1),4096,[^ ]* copy\(", prefill.as_text())
 
 
 # ---------------------------------------------------------------------------

@@ -737,10 +737,15 @@ def test_fp8_kv_write_saturates_no_nan():
 
 # layout -> (preset, stage [start, end) or None for the whole model, the cache
 # the seam is given, per-row write positions in decode)
+# "tiny-wide": the tiny preset with heads as wide as a tile, so its dense lanes
+# keep a head axis (DenseEntry); the tiny presets' 16-wide heads are stored as
+# rows (RowEntry, core.cache.rows_layout)
 LAYOUTS = {
     "none": ("tiny", None, None, False),
-    "dense-scalar": ("tiny", None, "lanes", False),
-    "dense-per-row": ("tiny", None, "lanes", True),
+    "dense-scalar": ("tiny-wide", None, "lanes", False),
+    "dense-per-row": ("tiny-wide", None, "lanes", True),
+    "rows-scalar": ("tiny", None, "lanes", False),
+    "rows-per-row": ("tiny", None, "lanes", True),
     "ring-even-offset": ("tiny-gemma2", None, "lanes", True),
     "ring-odd-offset-odd-length": ("tiny-gptoss", (1, 4), "lanes", True),
     "paged": ("tiny", None, "paged", True),
@@ -772,7 +777,10 @@ def test_every_layout_runs_through_the_one_scan(layout):
     from inferd_tpu.core import cache as cachelib
 
     preset, stage, kind, per_row = LAYOUTS[layout]
-    cfg = get_config(preset)
+    if preset == "tiny-wide":
+        cfg = dataclasses.replace(get_config("tiny"), name=preset, head_dim=128)
+    else:
+        cfg = get_config(preset)
     params = qwen3.init_params(cfg, jax.random.PRNGKey(23))
     if stage is None:  # (layers, global index of the first) for each layer group
         stacks = qwen3.layer_groups(params)
@@ -831,8 +839,12 @@ def test_every_layout_runs_through_the_one_scan(layout):
         want_types = (
             [cachelib.LatentEntry] if cfg.is_mla
             else [cachelib.RingEntry, cachelib.DenseEntry] if cfg.sliding_window
+            else [cachelib.RowEntry] if cfg.head_dim < 128
             else [cachelib.DenseEntry]
         )
+        assert cache.layout(cfg) == {
+            cachelib.LatentEntry: "latent", cachelib.RowEntry: "rows"
+        }.get(want_types[-1], "heads")
     entries = cache.entries(cfg)
     assert [type(e) for e in entries] == want_types
     back = cache.with_entries(entries)
@@ -1001,10 +1013,15 @@ def _program_names():
 @pytest.mark.parametrize("name", _program_names())
 def test_a_cells_program_lowers_to_its_recorded_text(lowered_programs, name):
     """The ten programs of the five older cells (tests/lowered_programs.py)
-    lower byte for byte to the text recorded at PR 42, which was the parent's
-    (4f27965): a model's new fields, absent by default, trace nothing into
-    another model's program. A PR that changes one of them on purpose runs
-    `python tests/lowered_programs.py --record` and says so."""
+    lower byte for byte to the recorded text: a model's new fields, absent by
+    default, trace nothing into another model's program. Recorded at PR 42
+    from the parent (4f27965); at PR 43 the seven per-head ones were recorded
+    anew with the tiny presets' heads as wide as the cells' (128), from the
+    parent (787d7c0) under the same script, and PR 43's tree lowered them to
+    the same bytes: a head as wide as a tile keeps its layout. Granite's two
+    lane programs are PR 43's own (one row a token). A PR that changes one
+    of them on purpose runs `python tests/lowered_programs.py --record` and
+    says so."""
     import json
 
     from tests import lowered_programs as lp

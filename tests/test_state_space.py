@@ -299,7 +299,9 @@ def test_the_cache_of_the_published_preset_is_the_arithmetic():
     c = jax.eval_shape(lambda: KVCache.create(cfg, cfg.num_layers, 32, 4096))
     assert c.s.shape == (36, 32, 64, 64, 128) and c.s.dtype == jnp.float32
     assert c.conv.shape == (36, 32, 3, 4352) and c.conv.dtype == jnp.bfloat16
-    assert c.k.shape == (4, 32, 4096, 8, 64) and c.k.dtype == jnp.bfloat16
+    # 8 kv heads of 64, narrower than a tile: ONE row of 512 a token (core.cache.rows_layout)
+    assert c.k.shape == c.v.shape == (4, 32, 4096, 512) and c.k.dtype == jnp.bfloat16
+    assert c.layout(cfg) == "rows"
     per_session = 36 * (64 * 64 * 128 * 4 + 3 * 4352 * 2)
     assert per_session == 76_437_504  # 75.5 MB of state + 0.94 MB of columns
     assert c.state_bytes == 32 * per_session
