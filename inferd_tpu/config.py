@@ -81,6 +81,12 @@ class ModelConfig:
     #   moe_router_mode — "softmax_topk" (Qwen/Mixtral: probs over ALL
     #                     experts, then top-k) or "topk_softmax" (GPT-OSS:
     #                     top-k over LOGITS, softmax over the k values)
+    #                     or "sigmoid_topk" (the afmoe family: a sigmoid
+    #                     score per expert in float32; the top k of score +
+    #                     p["router_select_bias"], a float32 vector that
+    #                     SELECTS and never weighs; the weights are the chosen
+    #                     experts' own scores, with norm_topk_prob divided by
+    #                     their sum + 1e-20, times routed_scaling_factor)
     #   router_bias / moe_bias — biases on the router / expert projections
     #   swiglu_limit  — >0: clamped GLU experts (gate<=limit, |up|<=limit,
     #                   glu = gate*sigmoid(1.702*gate), out = (up+1)*glu)
@@ -103,8 +109,9 @@ class ModelConfig:
     #   attn_logit_softcap / final_logit_softcap — cap*tanh(x/cap), 0 = off
     #   query_pre_attn_scalar — attention scores scale by this**-0.5
     #                    instead of head_dim**-0.5 (0 = use head_dim)
-    #   sliding_window — local attention window on EVEN layer indices
-    #                    (odd layers stay global); 0 = all layers global
+    #   sliding_window — local attention window on the "sliding" layers of
+    #                    layer_pattern (with no layer_types: EVEN layer
+    #                    indices, odd layers global); 0 = all layers global
     sandwich_norm: bool = False
     rms_norm_plus_one: bool = False
     hidden_act: str = "silu"
@@ -165,9 +172,11 @@ class ModelConfig:
 
     # State-space layers beside attention (the Granite-4.0-H family; all
     # absent elsewhere):
-    #   layer_types    — one PERIOD of layer kinds, "mamba" or "attention":
-    #                    global layer i has kind layer_types[i % len]; () =
-    #                    every layer attends (the rule of `layer_pattern`)
+    #   layer_types    — one PERIOD of layer kinds, "mamba", "attention",
+    #                    "sliding" or "global": global layer i has kind
+    #                    layer_types[i % len]; () = the rule of
+    #                    `layer_pattern`. A period with a state layer divides
+    #                    num_layers; any other may end anywhere
     #   mamba_*        — a Mamba-2 mixer: `mamba_heads` heads of
     #                    `mamba_head_dim` (= mamba_expand * hidden_size in
     #                    all), a state of `mamba_state` per head and channel,
@@ -197,13 +206,40 @@ class ModelConfig:
     logits_scaling: float = 1.0
     position_embedding: str = "rope"
 
+    # Windowed and full layers by a list, and one chip's share of the experts
+    # (the afmoe family; all absent elsewhere):
+    #   nope_kinds     — the kinds of layer_pattern whose attention carries
+    #                    NO rotation (("global",): rope on the windowed layers
+    #                    only); () = what position_embedding says, for all
+    #                    layers
+    #   attn_gate      — out = W_o (attn * sigmoid(x W_g)), W_g
+    #                    (p["attn_gate_proj"]) from the layer's normed input
+    #                    to num_heads x head_dim, no bias
+    #   router_experts — the router's width where it is more than the
+    #                    experts whose weights are here (num_experts then
+    #                    counts the HELD ones): an expert-parallel rank's
+    #                    share, served on one chip. The layer routes over the
+    #                    whole width and computes the part of the result that
+    #                    experts expert_offset .. expert_offset + num_experts
+    #                    give; what the absent ones would add is left out.
+    #                    0 = every routed expert is here
+    nope_kinds: tuple = ()
+    attn_gate: bool = False
+    router_experts: int = 0
+    expert_offset: int = 0
+
     def __post_init__(self):
         if self.layer_types:
-            odd = set(self.layer_types) - {"mamba", "attention"}
-            if odd or self.num_layers % len(self.layer_types):
+            odd = set(self.layer_types) - {"mamba", "attention", "sliding", "global"}
+            if odd or (self.has_state_layers and self.num_layers % len(self.layer_types)):
                 raise ValueError(
-                    f"{self.name}: layer_types is one period of 'mamba' / 'attention' "
-                    f"that divides num_layers {self.num_layers} (got {self.layer_types})"
+                    f"{self.name}: layer_types is one period of 'mamba' / 'attention' / "
+                    f"'sliding' / 'global' (with a state layer it divides num_layers "
+                    f"{self.num_layers}); got {self.layer_types}"
+                )
+            if ("sliding" in self.layer_types) != (self.sliding_window > 0):
+                raise ValueError(
+                    f"{self.name}: 'sliding' layers and a sliding_window come together"
                 )
             if self.has_state_layers and (
                 self.mamba_heads * self.mamba_head_dim != self.mamba_expand * self.hidden_size
@@ -216,6 +252,18 @@ class ModelConfig:
                 )
         if self.position_embedding not in ("rope", "nope"):
             raise ValueError(f"{self.name}: unknown position_embedding {self.position_embedding!r}")
+        if set(self.nope_kinds) - set(self.layer_pattern):
+            raise ValueError(
+                f"{self.name}: nope_kinds {self.nope_kinds} names no kind of {self.layer_pattern}"
+            )
+        if self.moe_router_mode not in ("softmax_topk", "topk_softmax", "sigmoid_topk"):
+            raise ValueError(f"{self.name}: unknown moe_router_mode {self.moe_router_mode!r}")
+        if self.router_experts and not (
+                0 <= self.expert_offset <= self.router_experts - self.num_experts):
+            raise ValueError(
+                f"{self.name}: experts {self.expert_offset} .. {self.expert_offset} + "
+                f"{self.num_experts} are no share of a router {self.router_experts} wide"
+            )
         if self.block_length > 1:
             if self.block_length % self.denoising_steps:
                 raise ValueError(
@@ -251,7 +299,8 @@ class ModelConfig:
         """The kinds of layer, one period of them: GLOBAL layer i has kind
         layer_pattern[i % len(layer_pattern)]. "sliding" attends within
         `sliding_window`, "global" (or "attention") over everything before
-        it, "mamba" carries a recurrent state (`layer_types`, where given).
+        it, "mamba" carries a recurrent state (`layer_types`, where given; a
+        model with a `sliding_window` and no list alternates, windowed first).
         The one place that says which layers are which (the scan of
         models/qwen3.forward_layers, the storage of core/cache)."""
         if self.layer_types:
@@ -278,6 +327,11 @@ class ModelConfig:
     def mamba_conv_dim(self) -> int:
         """Channels through the convolution: x, then B and C of every group."""
         return self.mamba_inner + 2 * self.mamba_groups * self.mamba_state
+
+    @property
+    def router_width(self) -> int:
+        """Outputs of the router: every routed expert, held here or not."""
+        return self.router_experts or self.num_experts
 
     @property
     def num_dense_layers(self) -> int:
@@ -712,6 +766,49 @@ GRANITE_4_H_MICRO = ModelConfig(
     position_embedding="nope",
 )
 
+# Trinity-Large-Preview (arcee-ai/Trinity-Large-Preview config.json, `afmoe`,
+# 400B-A13B): 60 layers, three windowed (4096, rope) to one full (no rope),
+# a gated attention output, sandwich norms, 6 dense layers, then 256 sigmoid-
+# routed experts (top 4) beside a shared one. The -ep8-5l preset is ONE chip's
+# share of an eight-chip expert-parallel group over the first five layers
+# (one dense, then a whole period of sparse ones): experts 0..31 of the
+# router's 256, vocabulary ids 0..25 023 of 200 192, every width as published
+# (benchmark/configs/trinity-large-ep8-1chip.json has the arithmetic).
+TRINITY_LARGE = ModelConfig(
+    name="trinity-large-preview",
+    vocab_size=200192,
+    hidden_size=3072,
+    intermediate_size=12288,
+    num_layers=60,
+    num_heads=48,
+    num_kv_heads=8,
+    head_dim=128,
+    rms_norm_eps=1e-5,
+    rope_theta=10_000.0,
+    max_position_embeddings=262144,
+    tie_word_embeddings=False,
+    qk_norm=True,
+    sandwich_norm=True,
+    scale_embedding=True,
+    sliding_window=4096,
+    layer_types=("sliding", "sliding", "sliding", "global"),
+    nope_kinds=("global",),
+    attn_gate=True,
+    num_experts=256,
+    num_experts_per_tok=4,
+    moe_intermediate_size=3072,
+    moe_router_mode="sigmoid_topk",
+    norm_topk_prob=True,
+    routed_scaling_factor=2.448,
+    n_shared_experts=1,
+    first_k_dense_replace=6,
+)
+
+TRINITY_LARGE_EP8_5L = dataclasses.replace(
+    TRINITY_LARGE.with_layers(5), name="trinity-large-ep8-5l", vocab_size=200192 // 8,
+    first_k_dense_replace=1, num_experts=256 // 8, router_experts=256,
+)
+
 # Synthetic mid-size config for the default bench's paired pipeline leg
 # (bench.py): big enough that a decode step's compute dominates the
 # inter-stage hop (the regime the north-star ratio grades), small enough
@@ -806,6 +903,18 @@ TINY_GRANITE_H = dataclasses.replace(
     position_embedding="nope",
 )
 
+# tiny-afmoe: the Trinity layer at toy widths: a dense layer, then two
+# periods of windowed x 3 + full (9 layers: the scan meets a head of three,
+# one whole period and a tail of one), window 8, 16 experts top 2, 1 shared.
+TINY_AFMOE = dataclasses.replace(
+    TINY, name="tiny-afmoe", num_layers=9, tie_word_embeddings=False, rms_norm_eps=1e-5,
+    rope_theta=10_000.0, sandwich_norm=True, scale_embedding=True, sliding_window=8,
+    layer_types=("sliding", "sliding", "sliding", "global"), nope_kinds=("global",),
+    attn_gate=True, num_experts=16, num_experts_per_tok=2, moe_intermediate_size=32,
+    moe_router_mode="sigmoid_topk", norm_topk_prob=True, routed_scaling_factor=2.448,
+    n_shared_experts=1, first_k_dense_replace=1,
+)
+
 PRESETS = {
     c.name: c
     for c in [
@@ -832,6 +941,8 @@ PRESETS = {
         DEEPSEEK_V2_LITE,
         DEEPSEEK_V2_LITE_8L,
         GRANITE_4_H_MICRO,
+        TRINITY_LARGE,
+        TRINITY_LARGE_EP8_5L,
         BENCH_PIPE,
         TINY,
         TINY_MOE,
@@ -842,6 +953,7 @@ PRESETS = {
         TINY_GPT_OSS,
         TINY_DSV2,
         TINY_GRANITE_H,
+        TINY_AFMOE,
     ]
 }
 

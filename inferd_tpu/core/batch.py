@@ -97,7 +97,7 @@ class BatchedEngine:
         B = cfg.block_length
         # the dense-lane decode program also returns the experts each lane
         # chose (the third value of models/qwen3.forward_cached)
-        self.routes = routes = cfg.is_moe and block_size == 0 and cfg.sliding_window == 0
+        self.routes = routes = cfg.is_moe and block_size == 0
 
         from inferd_tpu.core.cache import lane_slice as _lane_slice
         from inferd_tpu.core.cache import lane_write as _lane_write

@@ -28,9 +28,9 @@ per entry type, taking (the stacked entries, the layer's index), and never
 takes a cache's arrays apart; a new layout is a new entry type here and one
 function there.
 
-Sliding-window models (Gemma-2, GPT-OSS) additionally carry RING buffers
-`k_loc`/`v_loc` [num_sliding_layers, batch, ring, kv, d] for their sliding
-(even-global-index) layers: a sliding layer never attends past its window,
+Sliding-window models (Gemma-2, GPT-OSS, afmoe) additionally carry RING buffers
+`k_loc`/`v_loc` [num_sliding_layers, batch, ring, kv, d] for their "sliding"
+layers (cfg.layer_pattern: alternating, or a listed period): a sliding layer never attends past its window,
 so its storage is O(window), not O(context) — position p lives at slot
 p % ring until position p + ring overwrites it. `ring = round16(window) +
 RING_MARGIN`; the margin is what makes speculative rollback and bounded
@@ -106,7 +106,7 @@ def sliding_layer_ids(
 ) -> List[int]:
     """Stack-local indices of the SLIDING layers (static python): those
     whose global layer index (layer_offset + i) is "sliding" in
-    cfg.layer_pattern — the Gemma-2/GPT-OSS alternation."""
+    cfg.layer_pattern (the Gemma-2/GPT-OSS alternation, or a listed period)."""
     kinds = cfg.layer_pattern
     return [
         i for i in range(num_layers)
@@ -317,14 +317,14 @@ class KVCache:
         if cfg.is_mla:
             return (LatentEntry(c=self.k, r=self.v),)
         glob = (RowEntry if self.k.ndim == 4 else DenseEntry)(k=self.k, v=self.v)
-        if self.s is not None:
-            state = StateEntry(s=self.s, conv=self.conv)
-            return tuple(state if kind == "mamba" else glob
-                         for kind in dict.fromkeys(cfg.layer_pattern))
-        if self.k_loc is None:
+        if self.s is None and self.k_loc is None:
             return (glob,)
-        ring = RingEntry(k=self.k_loc, v=self.v_loc, window=int(cfg.sliding_window))
-        return tuple(ring if kind == "sliding" else glob for kind in cfg.layer_pattern)
+        by_kind = {
+            "mamba": None if self.s is None else StateEntry(s=self.s, conv=self.conv),
+            "sliding": None if self.k_loc is None else RingEntry(
+                k=self.k_loc, v=self.v_loc, window=int(cfg.sliding_window)),
+        }
+        return tuple(by_kind.get(kind) or glob for kind in dict.fromkeys(cfg.layer_pattern))
 
     def with_entries(self, entries: tuple) -> "KVCache":
         """Inverse of `entries`: the same cache (and length) over new buffers."""

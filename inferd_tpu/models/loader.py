@@ -76,6 +76,8 @@ def params_from_hf_state_dict(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params
         return _params_from_deepseek_v2(cfg, sd)
     if cfg.has_state_layers:
         return _params_from_granite_hybrid(cfg, sd)
+    if cfg.moe_router_mode == "sigmoid_topk":
+        return _params_from_afmoe(cfg, sd)
     dt = cfg.jnp_dtype
 
     def get_np(name: str, transpose: bool = False) -> np.ndarray:
@@ -263,6 +265,64 @@ def _params_from_deepseek_v2(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:
         "layers": group(range(nd, cfg.num_layers)),
         "final_norm": jnp.asarray(raw("norm"), dtype=dt),
         "lm_head": jnp.asarray(w("lm_head"), dtype=dt),
+    }
+    if nd:
+        params["dense_layers"] = group(range(nd))
+    return params
+
+
+def _params_from_afmoe(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:
+    """HF `afmoe` names -> the grouped tree (`dense_layers`, then `layers`).
+    The attention gate is published as `self_attn.gate_proj` (ours:
+    `attn_gate_proj`; `gate_proj` is the MLP's), the router as
+    `mlp.router.gate`, its selection bias as the float32 buffer
+    `mlp.expert_bias`. Rope is the half-split one `apply_rope` turns: nothing
+    is permuted. A preset that holds a share reads the experts
+    cfg.expert_offset .. + cfg.num_experts of each layer, the router whole,
+    and the first cfg.vocab_size rows of the vocabulary."""
+    dt = cfg.jnp_dtype
+    held = range(cfg.expert_offset, cfg.expert_offset + cfg.num_experts)
+
+    def raw(name: str) -> np.ndarray:
+        return _to_np(sd[name if name in sd else f"model.{name}"])
+
+    def w(name: str) -> np.ndarray:  # a linear weight as [in, out]
+        return raw(f"{name}.weight").T
+
+    def layer(i: int) -> Params:
+        at, mlp = f"layers.{i}.self_attn", f"layers.{i}.mlp"
+        out = {
+            "input_norm": raw(f"layers.{i}.input_layernorm.weight"),
+            "post_norm": raw(f"layers.{i}.post_attention_layernorm.weight"),
+            "pre_ffn_norm": raw(f"layers.{i}.pre_mlp_layernorm.weight"),
+            "post_ffn_norm": raw(f"layers.{i}.post_mlp_layernorm.weight"),
+            "q_norm": raw(f"{at}.q_norm.weight"), "k_norm": raw(f"{at}.k_norm.weight"),
+            "attn_gate_proj": w(f"{at}.gate_proj"),
+            **{proj: w(f"{at}.{proj}") for proj in ("q_proj", "k_proj", "v_proj", "o_proj")},
+        }
+        if i < cfg.num_dense_layers:
+            for proj in ("gate_proj", "up_proj", "down_proj"):
+                out[proj] = w(f"{mlp}.{proj}")
+            return out
+        out["router"] = w(f"{mlp}.router.gate")
+        out["router_select_bias"] = raw(f"{mlp}.expert_bias").astype(np.float32)
+        for proj in ("gate_proj", "up_proj", "down_proj"):
+            out[proj] = np.stack([w(f"{mlp}.experts.{e}.{proj}") for e in held])
+            out[f"shared_{proj}"] = w(f"{mlp}.shared_experts.{proj}")
+        return out
+
+    def group(ids) -> Params:
+        per_layer = [layer(i) for i in ids]
+        return {k: jnp.asarray(np.stack([lp[k] for lp in per_layer]),
+                               dtype=jnp.float32 if k == "router_select_bias" else dt)
+                for k in per_layer[0]}
+
+    nd, v = cfg.num_dense_layers, cfg.vocab_size
+    params: Params = {
+        "embed": jnp.asarray(raw("embed_tokens.weight")[:v], dtype=dt),
+        "layers": group(range(nd, cfg.num_layers)),
+        "final_norm": jnp.asarray(raw("norm.weight"), dtype=dt),
+        "lm_head": jnp.asarray(w("lm_head")[:, :v], dtype=dt),
     }
     if nd:
         params["dense_layers"] = group(range(nd))

@@ -81,6 +81,8 @@ def init_layer_params(
         p["o_bias"] = jnp.zeros((n, h), dtype=dt)
     if cfg.attn_sinks:  # GPT-OSS: per-q-head sink logits
         p["sinks"] = jnp.zeros((n, cfg.num_heads), dtype=dt)
+    if cfg.attn_gate:  # afmoe: the attention output's gate, from the layer's input
+        p["attn_gate_proj"] = w(jax.random.fold_in(key, 13), h, q)
     if cfg.is_mla:  # latent attention: no k/v projections per head
         del p["k_proj"], p["v_proj"]
         r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
@@ -90,8 +92,17 @@ def init_layer_params(
         p["kv_b_proj"] = w(jax.random.fold_in(key, 9), r, heads_out)
         p["o_proj"] = w(ks[3], cfg.num_heads * cfg.v_head_dim, h)
     if cfg.is_moe and not dense:
-        e, mi = cfg.num_experts, cfg.moe_intermediate_size
-        p["router"] = w(ks[4], h, e)
+        e, mi = cfg.num_experts, cfg.moe_intermediate_size  # the experts HELD here
+        p["router"] = w(ks[4], h, cfg.router_width)
+        if cfg.moe_router_mode == "sigmoid_topk":
+            # drawn so that the router's logits have unit variance whatever
+            # the width (the scores spread over (0, 1)), and a selection bias
+            # small and non-zero: of 256 experts' top 4 it changes the choice
+            # of about half the tokens and leaves the load near even (a
+            # deviation of 0.1 sends a third of the rows to one expert)
+            p["router"] = (p["router"].astype(jnp.float32) * (50.0 / math.sqrt(h))).astype(dt)
+            p["router_select_bias"] = 0.01 * jax.random.normal(
+                jax.random.fold_in(key, 14), (n, cfg.router_width), dtype=jnp.float32)
         p["gate_proj"] = w(ks[5], e, h, mi)
         p["up_proj"] = w(ks[6], e, h, mi)
         p["down_proj"] = w(ks[7], e, mi, h)
@@ -408,19 +419,29 @@ def swiglu_mlp(
     )
 
 
-def route_topk(cfg: ModelConfig, router_logits: jax.Array) -> Tuple[jax.Array, jax.Array]:
+def route_topk(
+    cfg: ModelConfig, router_logits: jax.Array, select_bias: Optional[jax.Array] = None
+) -> Tuple[jax.Array, jax.Array]:
     """Router -> (top-k weights [T, K] f32, top-k indices [T, K]) — the
-    single source of both HF-exact routing modes, shared by the
-    single-device moe_mlp and the (ep, tp)-sharded tp.moe_mlp_sharded:
+    single source of the HF-exact routing modes (moe_routed_part):
       softmax_topk (Qwen3-MoE / Mixtral): probabilities over ALL experts,
         top-k selected, optionally renormalized;
       topk_softmax (GPT-OSS): top-k over the raw LOGITS, softmax over just
-        the k selected values.
+        the k selected values;
+      sigmoid_topk (afmoe): a sigmoid score per expert; the top k of
+        score + `select_bias` [E] f32, which chooses and never weighs; the
+        weights are the chosen experts' scores, optionally over their sum.
     """
     k = cfg.num_experts_per_tok
     if cfg.moe_router_mode == "topk_softmax":
         topv, topi = jax.lax.top_k(router_logits, k)
         topw = jax.nn.softmax(topv, axis=-1)
+    elif cfg.moe_router_mode == "sigmoid_topk":
+        scores = jax.nn.sigmoid(router_logits)
+        _, topi = jax.lax.top_k(scores + select_bias, k)
+        topw = jnp.take_along_axis(scores, topi, axis=-1)
+        if cfg.norm_topk_prob:
+            topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-20)
     else:
         probs = jax.nn.softmax(router_logits, axis=-1)
         topw, topi = jax.lax.top_k(probs, k)
@@ -431,12 +452,15 @@ def route_topk(cfg: ModelConfig, router_logits: jax.Array) -> Tuple[jax.Array, j
     return topw, topi
 
 
-def route(cfg: ModelConfig, router_logits: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """route_topk densified to combine weights [T, E] f32 (+ topi)."""
-    topw, topi = route_topk(cfg, router_logits)
-    t = router_logits.shape[0]
+def route(
+    cfg: ModelConfig, router_logits: jax.Array, select_bias: Optional[jax.Array] = None
+) -> Tuple[jax.Array, jax.Array]:
+    """route_topk densified to combine weights [T, E] f32 over the ROUTER's
+    width (+ topi)."""
+    topw, topi = route_topk(cfg, router_logits, select_bias)
+    t, e = router_logits.shape
     comb = (
-        jnp.zeros((t, cfg.num_experts), jnp.float32)
+        jnp.zeros((t, e), jnp.float32)
         .at[jnp.arange(t)[:, None], topi]
         .add(topw)
     )
@@ -469,25 +493,53 @@ def expert_ffn(p: Params, cfg: ModelConfig, xt: jax.Array) -> jax.Array:
     return expert_out
 
 
-def moe_mlp_routed(p: Params, cfg: ModelConfig, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Mixture-of-experts feed-forward (routing modes + expert flavors in
-    `route` / `expert_ffn`) -> (output [B, S, H], chosen experts [B, S, K]).
-    Dense-dispatch formulation (every token visits every expert, combine
-    weights zero out non-selected) — exact and simple; the expert-parallel
-    sharded dispatch lives in inferd_tpu.parallel and shards the expert
-    axis over the mesh. With `n_shared_experts` one always-on SwiGLU is
-    added to the routed output, once.
-    """
-    b, s, h = x.shape
-    xt = x.reshape(b * s, h)
+def router_logits(p: Params, cfg: ModelConfig, xt: jax.Array) -> jax.Array:
+    """Tokens xt [T, H] -> the router's float32 logits [T, E] over its whole width."""
+    logits = (xt @ p["router"]).astype(jnp.float32)
+    if cfg.router_bias:
+        logits = logits + p["router_bias"].astype(jnp.float32)
+    return logits
+
+
+def moe_routed_part(
+    p: Params, cfg: ModelConfig, xt: jax.Array, offset=0
+) -> Tuple[jax.Array, jax.Array]:
+    """THE routed layer, told which experts it holds: tokens xt [T, H] are
+    routed over the router's whole width (p["router"]: every routed expert
+    of the model), and the experts whose weights are here (gate_proj.shape[0]
+    of them, the router's outputs `offset` .. offset + that many) give their
+    part of the result -> (that part [T, H], the experts each token chose
+    [T, K], ids over the router's width, the same in every share).
+
+    Every expert here (moe_mlp_routed) is the whole layer; a rank of an
+    expert-parallel mesh passes its offset and sums the parts
+    (parallel/tp.moe_mlp_sharded); one chip serving a rank's share
+    (cfg.router_experts) computes its part and nothing stands in for the
+    others. Dense dispatch over the HELD experts: every token visits each of
+    them and the combine weights zero what it did not choose."""
     with jax.named_scope("moe_route"):
-        router_logits = (xt @ p["router"]).astype(jnp.float32)  # [T, E]
-        if cfg.router_bias:
-            router_logits = router_logits + p["router_bias"].astype(jnp.float32)
-        comb, topi = route(cfg, router_logits)
+        comb, topi = route(cfg, router_logits(p, cfg, xt), p.get("router_select_bias"))
+    held = p["gate_proj"].shape[0]
+    if held != comb.shape[1]:
+        with jax.named_scope("moe_share"):  # the held experts' columns of the weights
+            comb = jax.lax.dynamic_slice_in_dim(comb, offset, held, axis=1)
     with jax.named_scope("moe_experts"):
         expert_out = expert_ffn(p, cfg, xt)
         out = jnp.einsum("teh,te->th", expert_out, comb.astype(expert_out.dtype))
+    return out, topi
+
+
+def moe_mlp_routed(p: Params, cfg: ModelConfig, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Mixture-of-experts feed-forward (routing modes + expert flavors in
+    `route` / `expert_ffn`) -> (output [B, S, H], chosen experts [B, S, K]):
+    moe_routed_part over the experts this device holds, from
+    cfg.expert_offset (0 and all of them, but for a rank's share served on
+    one chip). With `n_shared_experts` one always-on SwiGLU is added to the
+    routed output, once, outside the share.
+    """
+    b, s, h = x.shape
+    xt = x.reshape(b * s, h)
+    out, topi = moe_routed_part(p, cfg, xt, cfg.expert_offset)
     if cfg.n_shared_experts:
         with jax.named_scope("moe_shared"):
             shared = {k: p[f"shared_{k}"] for k in ("gate_proj", "up_proj", "down_proj")}
@@ -882,7 +934,13 @@ def _gqa_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx, window
     # RoPE above took the true positions; the mask of every layout below
     # compares a slot's position with the last one the query sees
     q_positions = visible_until(cfg, q_positions)
-    return _ATTEND_UPDATE[type(entry)](cfg, q, k, v, q_positions, entry, at, ctx, window, sinks)
+    attn, entry = _ATTEND_UPDATE[type(entry)](
+        cfg, q, k, v, q_positions, entry, at, ctx, window, sinks)
+    if cfg.attn_gate:  # afmoe: each head's output gated from the layer's input
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(qdot(x, lp["attn_gate_proj"]).astype(jnp.float32))
+            attn = (attn.astype(jnp.float32) * gate).astype(attn.dtype)
+    return attn, entry
 
 
 def visible_until(cfg: ModelConfig, q_positions: jax.Array) -> jax.Array:
@@ -1371,6 +1429,11 @@ def forward_layers(
     per_layer = (layers, None if static else layer_windows(cfg, n, layer_offset), ad_per)
     uniq = tuple(dict.fromkeys(kinds))  # the kinds, in the order a period first meets them
     by_kind = len(entries) == len(uniq) > 1  # a stack per kind; else ONE, in layer order
+    if cfg.nope_kinds and not static:
+        raise ValueError(
+            f"{cfg.name}: which layers carry no rope is known by their kind, "
+            "and a traced layer offset (a pp rank) knows no kind"
+        )
     if cfg.has_state_layers and not (
             split and static and head == tail == 0 and adapters is None):
         raise ValueError(
@@ -1394,12 +1457,15 @@ def forward_layers(
 
     def layer(h, ents, i, per_i, p=0):
         lp, win, ad_sl = per_i
+        rope = (cos, sin)
         if static:
-            sliding = kinds[(layer_offset + i) % period] == "sliding"
-            win = int(cfg.sliding_window) if sliding else None
+            kind = kinds[(layer_offset + i) % period]
+            win = int(cfg.sliding_window) if kind == "sliding" else None
+            if kind in cfg.nope_kinds:  # this kind of layer carries no rotation
+                rope = (None, None)
         s, at = home(i, p) if ents else (0, None)
         h, stack, topi = decoder_layer(
-            lp, cfg, h, cos, sin, positions, ents[s] if ents else None, at, ctx, win,
+            lp, cfg, h, *rope, positions, ents[s] if ents else None, at, ctx, win,
             tp_axis, ep_axis, _ad(ad_sl),
         )
         if ents:
@@ -1735,7 +1801,10 @@ def forward(
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
     hidden = embed(params, tokens, cfg)
+    offset = 0
     for layers in layer_groups(params):
         hidden, _, _ = forward_layers(
-            layers, cfg, hidden, positions, state_layers=params.get("state_layers"))
+            layers, cfg, hidden, positions, layer_offset=offset,
+            state_layers=params.get("state_layers"))
+        offset += _stack_len(layers)
     return unembed(params, cfg, hidden), None, None

@@ -105,16 +105,17 @@ class PipelinedCaches:
 
 def ring_split_ok(cfg: ModelConfig, pp: int) -> bool:
     """Can the pipelined cache use O(window) ring storage for sliding
-    layers? Requires every rank's slice to start on an EVEN global layer
-    index — then the sliding/global alternation is the SAME static pattern
-    on all ranks and the one SPMD program stays rank-independent. True for
-    pp == 1 (any length; the layer scan unrolls an odd tail) and for
-    even layers-per-rank; odd layers-per-rank (e.g. Gemma-2's 26 layers at
-    pp=2) keeps the uniform mask-only fallback, observable via stats()."""
+    layers? Requires every rank's slice to start on a PERIOD boundary of
+    cfg.layer_pattern (an even global layer index for the alternation) —
+    then the sliding/global pattern is the SAME static one on all ranks and
+    the one SPMD program stays rank-independent. True for pp == 1 (any
+    length; the layer scan unrolls what lies outside whole periods) and for
+    whole periods per rank; otherwise (e.g. Gemma-2's 26 layers at pp=2: 13
+    a rank) the uniform mask-only fallback, observable via stats()."""
     if not cfg.sliding_window:
         return False
     per = cfg.num_layers // pp
-    return pp == 1 or per % 2 == 0
+    return pp == 1 or per % len(cfg.layer_pattern) == 0
 
 
 @functools.lru_cache(maxsize=64)

@@ -27,14 +27,14 @@ import jax.numpy as jnp
 from jax import lax
 
 from inferd_tpu.config import ModelConfig
-from inferd_tpu.ops.quant import qdot, qeinsum
+from inferd_tpu.ops.quant import qdot
 from inferd_tpu.models.qwen3 import (
     act_fn,
     apply_rope,
-    expert_ffn,
     gqa_attention,
     layer_windows,
-    route_topk,
+    moe_routed_part,
+    router_logits,
     rms_norm,
     rope_cos_sin,
 )
@@ -151,32 +151,21 @@ def moe_mlp_sharded(
     xt = x.reshape(b * s, h)
     # every path from here (router AND experts) is sharded over expert_axes
     xt = enter_sharded(xt, tuple(expert_axes))
-    router_logits = (xt @ lp["router"]).astype(jnp.float32)  # [T, E] full
-    if cfg.router_bias:
-        router_logits = router_logits + lp["router_bias"].astype(jnp.float32)
-    topw, topi = route_topk(cfg, router_logits)  # [T, K] (shared modes)
-
     e_local = lp["gate_proj"].shape[0]
     rank = jnp.int32(0)
     stride = 1
     for ax in reversed(expert_axes):
         rank = rank + lax.axis_index(ax) * stride
         stride *= lax.axis_size(ax)
-    offset = rank * e_local
-    local_ids = offset + jnp.arange(e_local)  # [E_local] global expert ids
-    match = topi[:, :, None] == local_ids[None, None, :]  # [T, K, E_local]
-    comb = jnp.sum(topw[:, :, None] * match, axis=1)  # [T, E_local]
-
-    # shared expert math (models.qwen3.expert_ffn — silu or GPT-OSS clamped
-    # GLU with biases) over the LOCAL expert slice; qeinsum inside lets the
-    # weights be QuantWeight on the serving path (run_node --quant)
-    expert_out = expert_ffn(lp, cfg, xt)
-    out = jnp.einsum("teh,te->th", expert_out, comb.astype(expert_out.dtype))
+    # the one routed layer (models.qwen3.moe_routed_part: routing modes,
+    # silu or GPT-OSS clamped GLU with biases, QuantWeight under --quant)
+    # over the LOCAL expert slice, told where in the router's width it lies
+    out, topi = moe_routed_part(lp, cfg, xt, rank * e_local)
     out = psum_replicated(out, tuple(expert_axes))
     if return_aux:
         # the aux always uses softmax-over-all probabilities (the HF
         # load-balancing formula), independent of the routing mode
-        probs = jax.nn.softmax(router_logits, axis=-1)
+        probs = jax.nn.softmax(router_logits(lp, cfg, xt), axis=-1)
         f, p = _route_fractions(probs, topi, cfg.num_experts)
         n_shards = 1.0
         for ax in aux_token_axes:

@@ -123,7 +123,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         # the program returns no routing (no experts, or a paged / windowed
         # layout)
         self._moe = (
-            dict(steps=0, assignments=0, assignments_hottest=0, experts_touched=0)
+            dict(steps=0, assignments=0, assignments_hottest=0, experts_touched=0,
+                 assignments_here=0, experts_touched_here=0)
             if self.engine.routes else None
         )
         # a model generated by blocks (/stats `executor` `diffusion.*`):
@@ -1084,14 +1085,20 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         step) from the experts its
         live rows chose, `chosen` [sparse layers, live rows, K]: the
         assignments made, those that fell on each layer's most loaded
-        expert, and the distinct experts hit, each summed over the layers."""
-        per_layer = [np.bincount(layer.ravel(), minlength=self.cfg.num_experts)
+        expert, and the distinct experts hit, each summed over the layers
+        and counted over the ROUTER's width; of the assignments and of the
+        experts hit, those held here (all of them, but for a rank's share)."""
+        cfg = self.cfg
+        per_layer = [np.bincount(layer.ravel(), minlength=cfg.router_width)
                      for layer in chosen]
+        here = slice(cfg.expert_offset, cfg.expert_offset + cfg.num_experts)
         with self._mu:
             self._moe["steps"] += 1
             self._moe["assignments"] += int(chosen.size)
             self._moe["assignments_hottest"] += int(sum(c.max() for c in per_layer))
             self._moe["experts_touched"] += int(sum((c > 0).sum() for c in per_layer))
+            self._moe["assignments_here"] += int(sum(c[here].sum() for c in per_layer))
+            self._moe["experts_touched_here"] += int(sum((c[here] > 0).sum() for c in per_layer))
 
     def _refuse_latent(self, what: str) -> None:
         if self.cfg.is_mla:
@@ -1631,8 +1638,16 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 if self.cfg.has_state_layers:  # what a session holds whatever its length
                     out["state_bytes"] = state
                     out["state_bytes_per_session"] = state // self.engine.lanes
+                if self.engine.cache.k_loc is not None:  # what a session's windowed
+                    #   layers hold whatever its length: their rings
+                    out["kv"] = {
+                        "window": int(self.cfg.sliding_window),
+                        "ring_bytes_per_session": 2 * int(self.engine.cache.k_loc.nbytes)
+                        // self.engine.lanes,
+                    }
             if self._moe is not None:
-                out["moe"] = dict(self._moe, experts=self.cfg.num_experts)
+                out["moe"] = dict(self._moe, experts=self.cfg.router_width,
+                                  experts_held=self.cfg.num_experts)
             if self._diffusion is not None:
                 out["diffusion"] = dict(self._diffusion)
             if self.adapters is not None:

@@ -419,7 +419,32 @@ def check_servable(cfg, args, num_stages: int = 1) -> None:
     model with latent attention or with a leading dense group, and one with
     state-space layers (cfg.has_state_layers: its recurrent state lives in
     the dense lanes' StateEntry and nowhere else; --kv-dtype and --quant run
-    it unchanged)."""
+    it unchanged). A model whose full layers carry no rope (cfg.nope_kinds),
+    whose attention output is gated (cfg.attn_gate) or that holds a share of
+    its experts (cfg.router_experts) is served from the lanes too, in its own
+    dtype or --kv-dtype: no sharding rule or stage knows the gate, a stage or
+    a traced rank knows no layer's kind, and a share is already one rank's
+    part."""
+    if cfg.nope_kinds or cfg.attn_gate or cfg.router_experts:
+        _refuse(cfg, {
+            "--mesh (a traced rank knows no layer's kind, no sharding rule names the "
+            "attention gate, and a share of the experts is already one rank's)": args.mesh,
+            "--stage-lanes (a stage's relay hands every layer one rope)": args.stage_lanes > 0,
+            "--paged-kv (the paged pool keeps no ring for the windowed layers)":
+                args.paged_kv > 0,
+            "--quant (ops/quant quantizes the `layers` stack alone: not a leading dense "
+            "group, not a shared expert)":
+                args.quant != "none",
+            "--spec-draft-layers (no self-draft over a share of the experts)":
+                args.spec_draft_layers > 0,
+            "--lora": bool(args.lora),
+            "--adapters (the registry knows no gate projection)": bool(args.adapters),
+            "--standby-repl (a standby resumes through the stage path)": args.standby_repl,
+            "serving without --batch-lanes (only the lane executor hands each layer its "
+            "kind's rope and ring)": args.backend == "qwen3" and args.batch_lanes <= 0,
+            "a manifest of several stages (the rings are laid out from layer 0)":
+                num_stages > 1,
+        })
     if cfg.has_state_layers:
         _refuse(cfg, {
             "--mesh (no recurrent state in a mesh slot, and its two weight stacks "

@@ -1,6 +1,7 @@
 """The ten programs the benchmark's five older cells run (a decode or block
 step and a prefill, the lane programs with the sampler at both of its widths),
-and the two lane programs of `g4hm-many-chat`, lowered at the tiny presets:
+the two lane programs of `g4hm-many-chat` and the two of `trinl-window-docs`
+(the prefill and the step the window runs), lowered at the tiny presets:
 `texts()` gives their StableHLO text by name. What the text holds is the traced
 program; sizes are not the point: a change that leaves these configurations
 alone leaves every byte alone. ONE width is the point: the cells' heads are as
@@ -42,8 +43,10 @@ def texts() -> dict:
 
     out = {}
     i32 = jnp.int32(0)
-    for cell, model, lanes in (("q4b", "tiny", 5), ("dsv2l", "tiny-dsv2", 16), ("sdar", "tiny-sdar", 16),
-                               ("g4hm", "tiny-granite-h", 4)):
+    # cell, preset, lanes, the sampler's widths its decode step is lowered at
+    for cell, model, lanes, widths in (
+            ("q4b", "tiny", 5, (0, 8)), ("dsv2l", "tiny-dsv2", 16, (0, 8)), ("sdar", "tiny-sdar", 16, ()),
+            ("g4hm", "tiny-granite-h", 4, (8,)), ("trinl", "tiny-afmoe", 16, (0,))):
         cfg = cell_config(model)
         params = qwen3.init_params(cfg, jax.random.PRNGKey(0))
         eng = BatchedEngine(cfg, params, lanes=lanes, max_len=64)
@@ -58,13 +61,11 @@ def texts() -> dict:
                 jnp.zeros((lanes, 2), jnp.uint32)).as_text()
             continue
         ask = samplib.RowAsk(jnp.zeros((lanes, 2), jnp.uint32), jnp.zeros((lanes, 4), jnp.float32))
-        if cfg.has_state_layers:  # the step as its executor calls it: the lanes' mask, one width
-            out[f"{cell}.decode.top8"] = eng._decode_logits.lower(
-                eng.params, eng.cache, toks, toks, ask=ask, top_n=8, active=toks.astype(bool)).as_text()
-            continue
-        for top_n in (0, 8):
+        # the step as its executor calls it: with the lanes' mask where a state must not move
+        mask = {"active": toks.astype(bool)} if cfg.has_state_layers else {}
+        for top_n in widths:
             out[f"{cell}.decode.top{top_n}"] = eng._decode_logits.lower(
-                eng.params, eng.cache, toks, toks, ask=ask, top_n=top_n).as_text()
+                eng.params, eng.cache, toks, toks, ask=ask, top_n=top_n, **mask).as_text()
     cfg = cell_config("tiny")
     mesh = meshlib.make_mesh(meshlib.MeshPlan(pp=4), jax.devices()[:4])
     eng = PipelinedEngine(cfg, qwen3.init_params(cfg, jax.random.PRNGKey(0)), mesh,
@@ -80,7 +81,7 @@ def texts() -> dict:
 
 NAMES = ("q4b.prefill", "q4b.decode.top0", "q4b.decode.top8", "dsv2l.prefill", "dsv2l.decode.top0",
          "dsv2l.decode.top8", "sdar.prefill", "sdar.block", "q8b-pp4.prefill", "q8b-pp4.decode.top0",
-         "g4hm.prefill", "g4hm.decode.top8")
+         "g4hm.prefill", "g4hm.decode.top8", "trinl.prefill", "trinl.decode.top0")
 
 
 def digests(found: dict) -> dict:

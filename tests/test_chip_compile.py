@@ -479,6 +479,36 @@ def test_granite_hybrid_lane_programs_compile_and_the_state_is_updated_where_it_
     assert not re.findall(r"= bf16\[4,(?:32|1),4096,[^ ]* copy\(", prefill.as_text())
 
 
+def test_trinity_share_lane_programs_compile_with_rings_and_the_cache_in_place(
+    one_chip, no_compile_cache
+):
+    """The two programs a `--model trinity-large-ep8-5l --batch-lanes 16
+    --max-len 16384` node runs, at the published widths: 8.64 GB of weights
+    (32 held experts a sparse layer under a 256-wide router), four windowed
+    layers as rings of 4 160 slots and one full layer's slab of 16 384:
+    2.164 GB of cache, where full-length slabs for all five would be 5.37.
+    The decode step (with its sampler, as the executor calls it) aliases the
+    whole donated cache and holds under 0.6 GB of temporaries (0.43: the
+    windowed layers' `concatenate` of ring and fresh row, 0.27 GB, is the
+    largest of them), so nothing of the slab's 1.07 GB or of the rings' 1.09
+    is made a second time: the re-laying `copy` of a slab or a ring in the
+    compiled text sits INSIDE the fusion of the dot that reads it. A
+    512-token prefill chunk: 0.96
+    GB of temporaries (every token through the 32 held experts). The numbers
+    are the configuration's `deployment`."""
+    cfg = get_config("trinity-large-ep8-5l")
+    shapes, step, prefill = _lane_programs(cfg, 16, 16384, one_chip, active=False)
+    assert shapes.k.shape == (1, 16, 16384, 8, 128) and shapes.k_loc.shape == (4, 16, 4160, 8, 128)
+    assert shapes.nbytes == 2_164_260_864
+    mem = step.memory_analysis()
+    assert 10.79e9 < mem.argument_size_in_bytes < 10.83e9  # 8.644 GB of weights + 2.164 of cache
+    assert mem.alias_size_in_bytes >= shapes.nbytes
+    assert mem.temp_size_in_bytes < 0.6e9
+    pm = prefill.memory_analysis()
+    assert pm.alias_size_in_bytes >= shapes.nbytes and pm.temp_size_in_bytes < 1.3e9
+    assert mem.argument_size_in_bytes + pm.temp_size_in_bytes < 15.75e9 * 0.8
+
+
 def test_llama32_1b_lanes_keep_their_rows_where_they_lie(one_chip, no_compile_cache):
     """The other public model of this head size (8 kv heads of 64, 16
     layers), as `--model llama3.2-1b --batch-lanes 32 --max-len 4096` would

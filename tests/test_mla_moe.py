@@ -231,10 +231,12 @@ def test_stats_report_the_allocated_cache_and_the_routing(params):
         np.testing.assert_allclose(r["logits"][0], want[9], atol=2e-5)
         st = ex.stats()
         sparse = CFG.num_layers - CFG.num_dense_layers
+        chose = sparse * CFG.num_experts_per_tok  # one row: all distinct
         assert st["moe"] == {
-            "experts": CFG.num_experts, "steps": 1, "assignments": sparse * CFG.num_experts_per_tok,
-            "experts_touched": sparse * CFG.num_experts_per_tok,  # one row: all distinct
-            "assignments_hottest": sparse}
+            "experts": CFG.num_experts, "steps": 1, "assignments": chose, "experts_touched": chose,
+            "assignments_hottest": sparse,
+            # every expert is held here (PR 44: a rank's share would hold fewer)
+            "experts_held": CFG.num_experts, "assignments_here": chose, "experts_touched_here": chose}
         with pytest.raises(ValueError, match="tiny-dsv2"):
             ex.export_sessions()
         with pytest.raises(ValueError, match="tiny-dsv2"):
