@@ -693,10 +693,17 @@ class GenerationClient:
             ask = self._decode_ask(
                 s, logprob_sink is not None, top_n if top_sink is not None else 0
             )
+            if eos_token_id is not None:
+                ask["eos"] = int(eos_token_id)
             chain: Dict[str, Any] = {"seed": seed}
             while len(out) < max_new_tokens and tok != eos_token_id:
+                # `ahead`: the hops that follow this one unless `eos` ends
+                # the generation: an executor that keeps a step ahead of its
+                # sessions runs that many more rows for this one, and none
+                # past them (runtime/executor.parse_decode_ask)
                 res = await self._step_resuming(
-                    session_id, [tok], pos, known, resumes, {**ask, **chain}
+                    session_id, [tok], pos, known, resumes,
+                    {**ask, **chain, "ahead": max_new_tokens - len(out) - 1},
                 )
                 pos += 1
                 if res.get("logits") is not None:

@@ -393,6 +393,20 @@ def pack_rows(tok, keys, lp=None, top_ids=None, top_lps=None, routed=None) -> ja
     return pack_bits(*parts)
 
 
+@jax.jit
+def ahead_rows(packed: jax.Array, toks, keys, ahead):
+    """The inputs of a decode step that runs ahead of its hops: for the
+    rows of `ahead` [L] bool the token and the next key the step before
+    left ON THE DEVICE (`pack_rows`' first three columns of its `packed`),
+    for every other row the host's `toks` [L] int32 and `keys` [L, 2]
+    uint32. What comes back has the shapes and dtypes of the host arrays
+    and goes into the same compiled step; one small program a width of
+    `packed` (the log-probability variants)."""
+    last = jax.lax.bitcast_convert_type(packed[:, 1:3], jnp.uint32)
+    return (jnp.where(ahead, packed[:, 0], toks),
+            jnp.where(ahead[:, None], last, keys))
+
+
 def unpack_rows(packed, top_n: int, k: int = 0):
     """`pack_rows` undone on the host: (tokens [L], keys [L, 2] uint32,
     lp [L] | None, top ids [L, n] | None, top log-probabilities [L, n] |

@@ -14,13 +14,20 @@ by it (weights are read once per BATCHED step).
 Concurrency design (process() runs on the node's worker thread pool):
   * decode steps (real_len == 1 at the session's frontier) enqueue into a
     pending batch; the FIRST arrival becomes the flusher — it waits out a
-    step that is still running, then (no lock held) for the lanes the
+    drain that is still running, then (no lock held) for the lanes the
     last two steps served, takes the device lock, runs one batched step
     for every lane pending AT THAT MOMENT, and hands each waiting thread
     what its hop asked for: the token the step chose under the hop's own
     sampling (a decode hop's ask, runtime/executor.parse_decode_ask: what
     the generation loop sends with every decode hop), or the lane's logits
     (runtime/window.py, formation; docs/SERVING.md "A decode hop's ask");
+  * dense lanes run ONE STEP AHEAD of the hops they answer
+    (`_decode_ahead`, docs/SERVING.md "One step ahead"): a hop whose ask
+    says more hops follow is answered from the row a step ran for it
+    before it arrived, and the drain that answers it first dispatches the
+    row of the hop after it, fed by the token and key the step before
+    left on the device: the sessions take their turn beside a step, not
+    between two;
   * prefill chunks (multi-token or unknown session) run solo under the
     same device lock (per-lane cache writes, other lanes untouched);
   * a model generated by blocks (cfg.is_block_diffusion) has a third kind
@@ -65,6 +72,56 @@ class CapacityError(RuntimeError):
     """All lanes are serving in-flight requests — transient backpressure
     (the node maps this to a retryable 503, unlike deterministic KV
     overflow which is a 409)."""
+
+
+class _Step:
+    """One dispatched decode program of the dense lanes and what it ran
+    for. `finish` (BatchedExecutor._finish: any thread, once) waits for it,
+    copies its one packed array out and fills `toks` / `keys` / `replies` /
+    `rows`; until then only the device holds what it chose."""
+
+    __slots__ = (
+        "packed", "logits", "top_n", "asks", "plain", "lanes", "carry", "prev",
+        "program", "t_call", "t0", "t_done", "t_out", "lock", "done", "error",
+        "toks", "keys", "replies", "rows", "released",
+    )
+
+    def __init__(self, packed, logits, top_n, asks, plain, lanes, carry, prev, program):
+        self.packed, self.logits, self.top_n = packed, logits, top_n
+        self.asks = asks  # {lane: SampleAsk} of the rows that chose a token
+        self.plain = plain  # lanes answered with their logits row
+        self.lanes = lanes  # every row run for a session
+        # {lane: ask} of the rows a waiting hop rode whose ask promised a
+        # hop to follow: the next drain runs that hop's row ahead of it
+        self.carry = carry
+        self.prev = prev  # the step dispatched before: `device` starts at its end
+        self.program = program
+        self.t_call = tracelib.now()
+        self.t0 = self.t_done = self.t_out = 0.0
+        self.lock = threading.Lock()
+        self.done = False
+        # set once the next drain has taken `carry` (and run those rows)
+        self.released = threading.Event()
+        self.error: Optional[Exception] = None
+        self.toks = self.keys = self.replies = self.rows = None
+
+    def reply(self, lane: int):
+        """What the hop of `lane` is answered with, once finished: the
+        token's reply, or the logits row of a hop that asked for none."""
+        return self.replies[lane] if lane in self.replies else self.rows[lane]
+
+
+class _Ahead:
+    """A row run for a lane before its hop arrived (under the executor's
+    `_mu`): the position it was written at (the lane's length), the step
+    whose output row was its input (`src`: the token and key its hop must
+    carry), the step that ran it (`step`: what answers the hop) and the ask
+    it was run under."""
+
+    __slots__ = ("pos", "src", "step", "ask")
+
+    def __init__(self, pos: int, src: _Step, ask):
+        self.pos, self.src, self.step, self.ask = pos, src, None, ask
 
 
 class BatchedExecutor(SpecServing, AdapterBindingMixin):
@@ -118,6 +175,18 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         # and those answered with their [V] logits row (/stats `executor`)
         self.sampled_rows = 0
         self.logit_rows = 0
+        # one step ahead (dense lanes, `_decode_ahead`): lane -> the row a
+        # step ran before its hop arrived, unclaimed; the last step
+        # dispatched (the only one that may still be running when a drain
+        # begins); rows run ahead, those that answered a hop, those dropped
+        self._ahead: Dict[int, _Ahead] = {}
+        self._last_step: Optional[_Step] = None
+        # the logits of the last prefill chunk dispatched on dense lanes:
+        # while they are not ready a chunk is on the device (`_settle`)
+        self._chunk = None
+        self.ahead_rows = 0
+        self.ahead_claimed = 0
+        self.ahead_dropped = 0
         # cumulative expert-routing counters over decode steps, or over
         # the passes of block steps (/stats `executor` `moe.*`); None where
         # the program returns no routing (no experts, or a paged / windowed
@@ -478,6 +547,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         self._release_adapter_locked(session_id)
         if lane is None:
             return
+        self._forget(lane)
         # invalidate decode entries still waiting in the batch window — a
         # later flusher step must never write this lane on the old
         # session's behalf once a new session may own it
@@ -492,6 +562,55 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             self._dying[lane] = session_id
         else:
             self._free_lane(lane)
+
+    # -- one step ahead: the records (call under self._mu) --------------------
+
+    def _forget(self, lane: int) -> None:
+        """Drop what was run ahead for `lane` (its session ended, was
+        evicted, forked from, exported, or sent something else than the hop
+        the row was run for): the row stays where it was written, beyond
+        the lane's length, and whoever writes there next overwrites it."""
+        if self._ahead.pop(lane, None) is not None:
+            self.ahead_dropped += 1
+        if self._last_step is not None:
+            self._last_step.carry.pop(lane, None)
+
+    def _claim(self, lane: int, pos: int, tok, ask) -> bool:
+        """Whether the call at `pos` of `lane` is the hop a row was run
+        ahead for: a one-token hop at the row's position, with the token
+        and key the step before chose and the ask the row was run under.
+        Anything else drops the row and is served as if none had been run;
+        on a lane with a recurrent state, which the row has already moved,
+        it is refused as a replay of such a lane is, and the row stays for
+        the hop it was run for (a restart at 0 resets the state: dropped)."""
+        rec = self._ahead.get(lane)
+        if rec is None:
+            return False
+        src = rec.src
+        if (
+            ask is not None and src.done and src.error is None and rec.pos == pos
+            and ask.sampling == rec.ask.sampling and ask.want == rec.ask.want
+            and src.toks[lane] == tok and src.keys[lane] == ask.key.tolist()
+        ):
+            return True
+        if self.cfg.has_state_layers and pos:
+            raise ValueError(
+                f"lane {lane}: a step already ran position {rec.pos} for the hop "
+                f"this session promised (`ahead`); {self.cfg.name} holds a recurrent "
+                "state, which does not roll back: send that hop, or restart the "
+                "session at 0"
+            )
+        self._forget(lane)
+        return False
+
+    def _write_lens(self) -> np.ndarray:
+        """The lanes' lengths as a program that writes EVERY lane's row
+        takes them: a lane with an unclaimed row run ahead writes one past
+        it, so that the row survives the steps its session sits out."""
+        lens = np.asarray(self.engine.lengths, np.int32)
+        for lane in self._ahead:
+            lens[lane] += 1
+        return lens
 
     def _free_lane(self, lane: int) -> None:
         """Return a lane to the free list (under self._mu). Paged: the
@@ -543,6 +662,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         elif kind == "block":
             raise ValueError(f"{self.cfg.name} is not generated by blocks")
         call = parse_block(payload, blk) if kind == "block" else None
+        # a hop that asks for its token (parse_decode_ask) is answered with
+        # it; any other with its logits row
+        ask = parse_decode_ask(payload) if decode and not payload.get("decode_steps") else None
         with self._mu:
             if self._inflight.get(session_id):
                 # a duplicate/replayed request racing the original would
@@ -554,10 +676,15 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             lane = self._lane_for(session_id, new_ok=start_pos == 0)
             owner = f"session {session_id}, lane {lane}"
             have = self.engine.lengths[lane]
+            if self._last_step is not None:
+                # whatever this call is, the lane is back: the next drain
+                # runs nothing ahead on the strength of the hop before it
+                self._last_step.carry.pop(lane, None)
             if start_pos == 0 and have:
                 # session restart under the same id: reset the lane
                 self.engine.lengths[lane] = 0
                 self._lane_hi[lane] = 0
+                self._forget(lane)
                 if self.pool is not None:
                     self.pool.release_lane(lane)
                 have = 0
@@ -571,6 +698,10 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                     f"session {session_id}: KV overflow "
                     f"({start_pos}+{real_len} > {self.cap})"
                 )
+            if lane in self._ahead:
+                # before any frontier moves: a call that is not the hop the
+                # row was run for drops it (or is refused: `_claim`)
+                self._claim(lane, start_pos, int(toks[0, 0]), ask)
             if start_pos != have:
                 if not 0 < start_pos < have:
                     raise ValueError(
@@ -645,11 +776,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                         session_id, lane, int(toks[0, 0]), ks
                     )
                     return {**res, "start_pos": start_pos}
-                # a hop that asks for its token (parse_decode_ask) is
-                # answered with it; any other with its logits row
-                res = self._decode_batched(
-                    session_id, lane, int(toks[0, 0]), parse_decode_ask(payload)
-                )
+                res = self._decode_batched(session_id, lane, int(toks[0, 0]), ask)
                 if isinstance(res, dict):
                     return {**res, "real_len": 1, "start_pos": start_pos}
                 logits, saved = res, 0
@@ -768,6 +895,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                             jnp.int32(lane), jnp.int32(pos), jnp.int32(c),
                             ads=ads,
                         )
+                        self._chunk = logits
                     # advance the lane BEFORE releasing the device lock: a
                     # flusher snapshots lengths under the same lock order
                     # (_dev_lock, _mu), so it can never scatter a decode write
@@ -802,7 +930,24 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         return out, saved
 
     def _decode_batched(self, session_id: str, lane: int, token: int, ks=None):
-        return self._batcher.submit((lane, token, ks))
+        res = self._batcher.submit((lane, token, ks))
+        if not isinstance(res, _Step):
+            return res
+        # the hop rode a step that other sessions' drains do not wait for
+        # (`_decode_ahead`): this thread waits for it, and the window
+        # expects the lane again once a drain has answered it
+        self._batcher.unexpect(lambda p, _lane=lane: p[0] == _lane)
+        self._finish(res)
+        if res.error is not None:
+            raise res.error
+        tracelib.mark("t_out", res.t_out)  # `deliver` starts (runtime/window.py submit)
+        if lane in res.carry:
+            # the next drain runs this session's next row ahead if its hop
+            # is not back before it: give that drain a step's time to come
+            # (a session that returned at once would ride again, and again)
+            res.released.wait(min(0.1, res.t_done - res.t0))
+        self._batcher.delivered(lane)
+        return res.reply(lane)
 
     def _run_decode_batch(self, _empty) -> None:
         """Flush callback: ONE batched device step for every lane whose
@@ -840,6 +985,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
 
         poisoned: Optional[Exception] = None
         with self._dev_lock:
+            self._settle()
             # the batcher stamps each entry's lock_wait and batch_wait
             entries = self._batcher.drain_pending()
             blocks = [e for e in entries if isinstance(e.payload[2], BlockCall)]
@@ -852,7 +998,10 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             kstep = [e for e in entries if isinstance(e.payload[2], dict)]
             if single:
                 try:
-                    self._decode_dispatch(single)
+                    if self.pool is None:
+                        self._decode_ahead(single)
+                    else:
+                        self._decode_dispatch(single)
                 except Exception as exc:
                     for e in single:
                         e.error = exc
@@ -879,7 +1028,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                     continue
                 try:
                     with self._mu:
-                        lens = list(self.engine.lengths)
+                        lens = self._write_lens().tolist()
                         ids = list(self._lane_slot)
                     kg, seq, n_new, nkeys, self.engine.cache = (
                         fuse_kstep_group(
@@ -926,21 +1075,21 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
 
     def _decode_dispatch(self, entries) -> None:
         """ONE decode step (under _dev_lock) for the one-token hops of a
-        drain, whatever each asks: the program chooses every lane's token
-        under the lane's own sampling (core.sampling.choose_rows: traced
-        per lane, so a mix of configs is one program and a new one compiles
-        nothing), and ONE small array leaves the device: tokens, next keys,
-        the log-probabilities at the widest top-n a lane asked, the routed
-        experts. An entry with an ask is answered with its token; one
-        without (a raw /forward, an ask the sampler does not cover) with
-        its logits row: that row alone where it is the only one, else the
-        step's whole [L, V]."""
+        drain on PAGED lanes, whatever each asks: the program chooses every
+        lane's token under the lane's own sampling (core.sampling.choose_rows:
+        traced per lane, so a mix of configs is one program and a new one
+        compiles nothing), and ONE small array leaves the device: tokens,
+        next keys, the log-probabilities at the widest top-n a lane asked.
+        An entry with an ask is answered with its token; one without (a raw
+        /forward, an ask the sampler does not cover) with its logits row:
+        that row alone where it is the only one, else the step's whole
+        [L, V]. The step is waited for here, inside the drain (dense lanes:
+        `_decode_ahead`)."""
         eng = self.engine
         with self._mu:
             # snapshot under _mu; host arrays in: the call places them all at once
             lens = np.asarray(eng.lengths, np.int32)
             ids = list(self._lane_slot)
-        ads = self._ads(ids)
         toks = np.zeros((eng.lanes,), np.int32)
         active = np.zeros((eng.lanes,), bool)
         for e in entries:
@@ -948,37 +1097,17 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         asks = {e.payload[0]: e.payload[2] for e in entries if e.payload[2] is not None}
         plain = [e.payload[0] for e in entries if e.payload[2] is None]
         ask, top_n = samplib.RowAsk.of(eng.lanes, asks)
-        if self.pool is not None:
-            eng.cache, logits, packed = eng._decode_logits_paged(
-                eng.params, self._sync_paged(), toks, lens, active,
-                ads=ads, ask=ask, top_n=top_n,
-            )
-            packed.copy_to_host_async()
-        else:
-            fn = eng._decode_logits
-            with tracelib.region(
-                self.tracer, "device", kind="decode", tokens=len(entries),
-                cobatch=len(entries), program=program_name(fn),
-            ):
-                eng.cache, logits, packed = fn(
-                    eng.params, eng.cache, toks, lens, ads=ads, ask=ask, top_n=top_n,
-                    # a lane not in this step keeps its recurrent state
-                    **({"active": active} if self.cfg.has_state_layers else {}),
-                )
-                # the transfer is queued behind the step: the small array
-                # is on its way when the device is done
-                packed.copy_to_host_async()
-                packed.block_until_ready()
+        eng.cache, logits, packed = eng._decode_logits_paged(
+            eng.params, self._sync_paged(), toks, lens, active,
+            ads=self._ads(ids), ask=ask, top_n=top_n,
+        )
+        packed.copy_to_host_async()
         with tracelib.region(self.tracer, "copy_out") as at:
             host = np.asarray(packed)
             rows, moved = samplib.logits_out(logits, plain)
             at["bytes"] = host.nbytes + moved
         self._batcher.stamp_out(entries)  # `deliver` starts
-        replies, routed = samplib.row_replies(
-            host, top_n, asks, self.cfg.num_experts_per_tok if eng.routes else 0
-        )
-        if routed is not None:
-            self._count_routing(routed[:, [e.payload[0] for e in entries]])
+        replies, _ = samplib.row_replies(host, top_n, asks)
         with self._mu:
             for e in entries:
                 eng.lengths[e.payload[0]] += 1
@@ -987,6 +1116,260 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         for e in entries:
             lane = e.payload[0]
             e.result = replies[lane] if lane in replies else rows[lane]
+
+    # -- one step ahead (dense lanes; docs/SERVING.md "One step ahead") ------
+
+    def _runs_ahead(self, lane: int, ask, src: _Step) -> bool:
+        """Whether the hop after the one `ask` came with gets its row run
+        before it arrives (under _mu; the lane's length is that row's
+        position): the ask promised the hop, the row and the slot an idle
+        lane writes past it fit, the token `src` chose for it, where the
+        host knows it already, is not the one that ends the generation,
+        and no speculative round reads the lanes' lengths."""
+        return (
+            ask is not None and ask.ahead >= 1 and self._spec is None
+            and self.engine.lengths[lane] + 2 <= self.cap
+            and not (src.done and (src.error is not None or src.toks[lane] == ask.eos))
+        )
+
+    def _settle(self) -> None:
+        """Before a drain (under _dev_lock): while a prefill chunk is on the
+        device the next step cannot start before the chunk ends, so nothing
+        is won by dispatching it now, and a session whose prefill is ending
+        would find two steps queued ahead of its first decode hop. The drain
+        waits out the step dispatched before it instead (it lies behind or
+        before the chunk), then takes whoever has arrived meanwhile, the
+        newcomer among them, and feeds the next step from the host's copy
+        of what that step chose."""
+        prev, chunk = self._last_step, self._chunk
+        if chunk is not None and prev is not None and not prev.done and not chunk.is_ready():
+            self._finish(prev)
+
+    def _decode_ahead(self, entries) -> None:
+        """The one-token hops of a drain on dense lanes (under _dev_lock),
+        one step ahead of the sessions. In this order:
+
+        1. Each entry is CLAIMED (`_claim`: a step already ran the row this
+           hop asks for, before it arrived) or RIDES (no row was run: a
+           session's first decode hop, a hop whose ask promised nothing, a
+           raw /forward). ONE `_decode_logits` is dispatched for the rows
+           to come: for every claimed lane whose ask says a hop follows,
+           and for every lane that rode the last step under such an ask and
+           is not back yet, the NEXT row, its token and key taken from the
+           last step's packed array where the device still holds it alone
+           (core.sampling.ahead_rows: no round trip); for every rider its
+           own row from its host token, as before.
+        2. The step dispatched at the previous drain is waited for and
+           copied out (`_finish`), and each claimed entry is answered from
+           the step that ran its row. The sessions take their turn while
+           the step of (1) runs.
+        3. A rider is answered by its own thread (`_decode_batched` waits
+           for the step it rode), so no drain waits for a newcomer and a
+           newcomer waits for no drain. Where nobody is ahead (no claimed
+           entry, no row run ahead in this step: a lone session, a cohort's
+           first hops, callers that promise nothing) the drain is what it
+           was: the flusher waits for the step and answers; the riders'
+           next rows, where promised, are dispatched right behind it.
+
+        `batched_steps` counts the programs dispatched and `batched_tokens`
+        the rows they ran for a session (the drain counted one step and its
+        entries)."""
+        eng = self.engine
+        prev = self._last_step
+        claimed, riders, answers, conts = [], [], {}, {}
+        with self._mu:
+            for e in entries:
+                lane, tok, ask = e.payload
+                try:
+                    mine = self._claim(lane, eng.lengths[lane], tok, ask)
+                except ValueError as exc:
+                    e.error = exc
+                    continue
+                (claimed if mine else riders).append(e)
+            for e in claimed:  # the hop takes its row: the frontier moves past it
+                lane, _, ask = e.payload
+                answers[lane] = src = self._ahead.pop(lane).step
+                eng.lengths[lane] += 1
+                if self._runs_ahead(lane, ask, src):
+                    conts[lane] = (src, ask)
+            if prev is not None:
+                here = {e.payload[0] for e in entries}
+                for lane, ask in prev.carry.items():
+                    if lane not in here and self._runs_ahead(lane, ask, prev):
+                        conts[lane] = (prev, ask)
+                prev.carry = {}
+            self.ahead_claimed += len(claimed)
+            self.sampled_rows += len(claimed)
+        programs, rows = 0, len(conts)
+        step = None
+        if conts or riders:
+            step = self._dispatch_rows(conts, riders)
+            programs += 1
+        if prev is not None:
+            prev.released.set()
+        # nobody is ahead: the flusher answers its riders itself, and runs
+        # their next rows right behind the step they ride
+        sync = bool(riders) and not claimed and not conts
+        if sync:
+            with self._mu:
+                nxt = {lane: (step, ask) for lane, ask in step.carry.items()
+                       if self._runs_ahead(lane, ask, step)}
+                step.carry = {}
+            if nxt:
+                self._dispatch_rows(nxt, [])
+                programs, rows = programs + 1, rows + len(nxt)
+        if prev is not None:
+            self._finish(prev)
+            if prev.error is None:
+                # the step's own time: what the drain waited for is only
+                # what was left of it (runtime/window.py `step_hint`)
+                self._batcher.step_hint = prev.t_done - prev.t0
+        if sync:
+            self._finish(step)
+            if step.error is None:
+                self._batcher.step_hint = step.t_done - step.t0
+        self._batcher.stamp_out(claimed + riders if sync else claimed)  # `deliver` starts
+        for e in claimed:
+            lane = e.payload[0]
+            if answers[lane].error is not None:
+                e.error = answers[lane].error
+            else:
+                e.result = answers[lane].replies[lane]
+        for e in riders:
+            lane = e.payload[0]
+            if not sync:
+                e.result = step  # `_decode_batched` waits for it
+            elif step.error is not None:
+                e.error = step.error
+            else:
+                e.result = step.reply(lane)
+        failed = len(entries) - len(claimed) - len(riders)
+        self._batcher.n_steps += programs - 1
+        self._batcher.n_served += rows - len(claimed) - failed
+
+    def _dispatch_rows(self, conts, riders) -> _Step:
+        """ONE `_decode_logits` (under _dev_lock) over the rows of `conts`,
+        {lane: (the step whose output is the row's input, the ask it runs
+        under)}: rows run AHEAD of their hops, recorded in `_ahead`; and of
+        `riders`: entries waiting for their row, run from their host token.
+        The program, its shapes and the placement of what it is handed are
+        those of a step fed from the host alone: the tokens and keys a
+        still-running step holds come through core.sampling.ahead_rows as
+        arrays of the host arrays' kind."""
+        eng = self.engine
+        last = self._last_step
+        for src, _ in conts.values():
+            if not src.done and src is not last:
+                self._finish(src)  # only the last step may still hold its output alone
+        toks = np.zeros((eng.lanes,), np.int32)
+        active = np.zeros((eng.lanes,), bool)
+        from_dev = np.zeros((eng.lanes,), bool)
+        asks, plain, carry, made = {}, [], {}, {}
+        with self._mu:
+            ids = list(self._lane_slot)
+            for lane, (src, ask) in conts.items():
+                asks[lane], active[lane] = ask, True
+                made[lane] = self._ahead[lane] = _Ahead(eng.lengths[lane], src, ask)
+                # the row counts as written (a ring has given up its oldest slot)
+                self._lane_hi[lane] = max(self._lane_hi.get(lane, 0), eng.lengths[lane] + 1)
+            lens = self._write_lens()
+            for lane in conts:
+                lens[lane] -= 1  # the row itself lies AT the lane's length
+            for e in riders:
+                lane, tok, ask = e.payload
+                toks[lane], active[lane] = tok, True
+                if ask is None:
+                    plain.append(lane)
+                else:
+                    asks[lane] = ask
+                    if ask.ahead >= 1:
+                        carry[lane] = ask
+        fn = eng._decode_logits
+        try:
+            ask, top_n = samplib.RowAsk.of(eng.lanes, asks)
+            keys = ask.keys
+            for lane, (src, _) in conts.items():
+                if src.done:  # the host has read it (a rider's thread may finish `last` any time)
+                    toks[lane], keys[lane] = src.toks[lane], src.keys[lane]
+                else:
+                    from_dev[lane] = True
+            if from_dev.any():
+                toks, keys = samplib.ahead_rows(last.packed, toks, keys, from_dev)
+                ask = samplib.RowAsk(keys, ask.warp)
+            eng.cache, logits, packed = fn(
+                eng.params, eng.cache, toks, lens, ads=self._ads(ids), ask=ask, top_n=top_n,
+                # a lane not in this step keeps its recurrent state
+                **({"active": active} if self.cfg.has_state_layers else {}),
+            )
+        except Exception:
+            with self._mu:
+                for lane, rec in made.items():
+                    if self._ahead.get(lane) is rec:
+                        del self._ahead[lane]
+            raise
+        # the transfer is queued behind the step: the small array is on
+        # its way when the device is done
+        packed.copy_to_host_async()
+        step = self._last_step = _Step(
+            packed, logits if plain else None, top_n, asks, plain,
+            list(conts) + [e.payload[0] for e in riders], carry, last, program_name(fn),
+        )
+        with self._mu:
+            for rec in made.values():
+                rec.step = step
+            for e in riders:
+                eng.lengths[e.payload[0]] += 1
+            self.ahead_rows += len(conts)
+            self.sampled_rows += len(riders) - len(plain)
+            self.logit_rows += len(plain)
+        return step
+
+    def _finish(self, step: _Step) -> None:
+        """Wait for `step`, copy its one packed array out and keep what the
+        host reads of it on the step: once, by whoever needs it first (the
+        next drain's flusher under _dev_lock; a rider's own thread under no
+        lock). Its `device` span runs from its dispatch, or from the end of
+        the step before it where it queued behind that one, to the moment
+        this thread learnt it was done: where no one waited for it while it
+        ran (a turn longer than the step), that is the next drain, and the
+        span overstates the step by the difference."""
+        with step.lock:
+            if step.done:
+                return
+            prev, step.prev = step.prev, None
+            if prev is not None:
+                self._finish(prev)
+            try:
+                step.packed.block_until_ready()
+                step.t_done = tracelib.now()
+                step.t0 = max(step.t_call, prev.t_done if prev is not None else 0.0)
+                if self.tracer is not None and tracelib.enabled():
+                    self.tracer.record_span(
+                        "device", "device", step.t0, step.t_done, parent=tracelib.current(),
+                        attrs={"kind": "decode", "tokens": len(step.lanes),
+                               "cobatch": len(step.lanes), "program": step.program},
+                    )
+                with tracelib.region(self.tracer, "copy_out") as at:
+                    host = np.asarray(step.packed)
+                    step.rows, moved = samplib.logits_out(step.logits, step.plain)
+                    at["bytes"] = host.nbytes + moved
+                step.t_out = tracelib.now()
+                step.replies, routed = samplib.row_replies(
+                    host, step.top_n, step.asks,
+                    self.cfg.num_experts_per_tok if self.engine.routes else 0,
+                )
+                step.toks = host[:, 0].tolist()
+                step.keys = host[:, 1:3].view(np.uint32).tolist()
+                if routed is not None and step.lanes:
+                    self._count_routing(routed[:, step.lanes])
+            except Exception as exc:
+                step.error = exc
+                step.t_done = step.t_done or tracelib.now()
+            finally:
+                # `packed` stays: a drain that found the step unfinished may
+                # still be about to feed its next step from it
+                step.logits = None
+                step.done = True
 
     def _run_block_steps(self, entries) -> None:
         """The block calls of one drain (under _dev_lock): ONE
@@ -1178,6 +1561,10 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 parent_hi = max(
                     self._lane_hi.get(plane, 0), self.engine.lengths[plane]
                 )
+                # the copy reads the parent's lane: nothing run ahead of its
+                # session is left standing beside it (the mark above counts
+                # the row: a ring has given up its oldest slot to it)
+                self._forget(plane)
                 if (
                     self.engine.cache.k_loc is not None
                     and parent_hi - prefix_len > RING_MARGIN
@@ -1247,6 +1634,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 n = self.engine.lengths[lane]
                 if n == 0:
                     continue
+                self._forget(lane)  # the snapshot ends at the lane's length
                 if self.pool is not None:
                     # dense materialization through the block table, ONE
                     # device gather per session's chain (never a whole-pool
@@ -1621,6 +2009,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 prefill_tokens=self.prefill_tokens,
                 sampled_rows=self.sampled_rows,
                 logit_rows=self.logit_rows,
+                ahead_rows=self.ahead_rows,
+                ahead_claimed=self.ahead_claimed,
+                ahead_dropped=self.ahead_dropped,
                 **self._batcher.stats(),
             )
             # how a token's keys lie: "rows" (all kv heads in one row: a head

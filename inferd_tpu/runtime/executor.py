@@ -135,6 +135,11 @@ class SampleAsk(NamedTuple):
     sampling: tuple  # (temperature, top_k, top_p, min_p)
     want: int  # log-probabilities: 0 none, else the top-n asked (the token's own: 1)
     key: Any  # uint32 [2]: the session's PRNG chain
+    # a decode hop alone (parse_decode_ask): how many hops of this session
+    # will follow this one if no `eos` ends it (0: none is promised), and
+    # the token that would (-1: none). What a lane executor may run ahead.
+    ahead: int = 0
+    eos: int = -1
 
     @property
     def top_n(self) -> int:
@@ -214,13 +219,24 @@ def parse_decode_ask(payload: Dict[str, Any]) -> Optional[SampleAsk]:
     (core.sampling.sample_rows), or None: the hop carries none (no
     "sampling" key: a raw /forward), or one outside the device form (top-p
     with no top-k, a top-k over the candidates, more top log-probabilities
-    than the widest variant): that hop is answered with its logits."""
+    than the widest variant): that hop is answered with its logits.
+
+    Two more optional keys ride a decode hop's ask: "ahead": n, the hops
+    of this session that will follow this one (the generation loop's
+    `max_new_tokens` less what it has; absent or 0: no promise), and "eos":
+    the token id that ends the generation early. An executor that keeps a
+    step ahead of its sessions (runtime/batch_executor.py) runs a lane's
+    next step before its hop arrives only where the ask promised one."""
     if payload.get("sampling") is None:
         return None
     ask = parse_ask(payload)
     if ask.top_n is None or not rows_cover(*ask.sampling):
         return None
-    return ask
+    eos = payload.get("eos")
+    return ask._replace(
+        ahead=max(0, int(payload.get("ahead") or 0)),
+        eos=-1 if eos is None else int(eos),
+    )
 
 
 def call_kind(payload: Dict[str, Any]) -> str:

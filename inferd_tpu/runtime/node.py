@@ -105,15 +105,23 @@ def _warmup_executor(executor, journal=None) -> None:
             if hasattr(executor, "sampled_rows"):
                 # an executor whose decode step chooses the tokens: the
                 # step without log-probabilities (what traffic asks) and at
-                # the first top-n width (a variant each), then a hop that
-                # asks nothing (its logits row is copied out alone)
+                # the first top-n width (a variant each), each followed by
+                # the hop it promised, as a generation sends it (the lane
+                # executor runs that hop's row ahead from what the step left
+                # on the device: the small program that hands it over is
+                # one a width too), then a hop that asks nothing (its
+                # logits row is copied out alone)
+                tok, chain = 1, {}
                 for i, ask in enumerate((
-                    {"sampling": {}},
+                    {"sampling": {}, "ahead": 1}, {"sampling": {}},
+                    {"sampling": {}, "top_logprobs": BLOCK_TOP_WIDTHS[0], "ahead": 1},
                     {"sampling": {}, "top_logprobs": BLOCK_TOP_WIDTHS[0]}, {},
                 )):
-                    executor.process(sid, {
-                        "tokens": [[1]], "start_pos": 1 + i, "real_len": 1, **ask,
+                    res = executor.process(sid, {
+                        "tokens": [[tok]], "start_pos": 1 + i, "real_len": 1, **ask, **chain,
                     })
+                    if res.get("tokens") is not None:
+                        tok, chain = int(res["tokens"][0][0]), {"key": res["key"]}
         if hasattr(executor, "process_batch"):
             # stage-batch executors serve decode through a SEPARATE
             # co-batched jit — compile it too (it is the serving hot path)

@@ -141,6 +141,11 @@ class WindowedBatcher:
         self._delivered: Dict[Hashable, float] = {}  # session -> result out
         self._turn_s = window_s  # running mean: result out -> next submit
         self._step_s: Optional[float] = None  # the last measured step
+        # formation: a callback whose drain does not wait out the step it
+        # dispatched (runtime/batch_executor.py keeps one step ahead of its
+        # sessions) leaves the step's own time here before it returns; it
+        # is taken once, in place of drain -> return
+        self.step_hint: Optional[float] = None
         self.gang_full = 0  # formations that ended with every expected entry
         self.gang_timeout = 0  # ... because the cap ran out
         self.empty_drains = 0  # drains that found no live entry: no step
@@ -412,8 +417,9 @@ class WindowedBatcher:
                     e.error = failed or RuntimeError("entry was not served")
                 if e.error is None:
                     served[self._expect(e.payload)] = e.payload
+            hint, self.step_hint = self.step_hint, None
             if served:
-                self._step_s = now - self._t_drain
+                self._step_s = now - self._t_drain if hint is None else hint
                 self._served = [served, self._served[0]]
                 self._delivered.update(dict.fromkeys(served, now))
             self._cv.notify_all()
@@ -453,6 +459,14 @@ class WindowedBatcher:
             t_out = tracelib.now()
             for e in entries:
                 e.t_out = t_out
+
+    def delivered(self, key: Hashable) -> None:
+        """The result of session `key` is out NOW, handed over by its
+        caller and not by a drain's own delivery (an entry the callback
+        answered with a step still running, which the submitter waited for
+        itself): where its next submit's turn is counted from."""
+        with self._mu:
+            self._delivered[key] = tracelib.now()
 
     def unexpect(self, pred: Callable[[Any], bool]) -> None:
         """Stop waiting for the sessions whose last served payload matches
