@@ -255,8 +255,8 @@ def bench_decode_multistep(
     one dispatch + one host sync per K tokens, and report the steady
     per-token rate per K. The amortization claim this leg gates
     (`perf check` ordering): some K > 1 must be at least as fast as K=1 —
-    per-token dispatch overhead is real (perf anatomy's `dispatch` phase
-    measures it per box) and the fused loop exists to remove it.
+    per-token dispatch overhead is real and the fused loop exists to
+    remove it.
 
     Token-exactness is asserted in-leg: every K's greedy stream must equal
     the K=1 client-style loop (argmax over shipped logits), or the leg

@@ -38,7 +38,6 @@ from inferd_tpu.utils.lockwatch import LOCK_ORDER, LOCK_RANK
 _ATTR_DEFAULT = {
     "_dev_lock": "dev",
     "_mu": "mu",
-    "_capture_lock": "capture",
 }
 _CLASS_ATTR = {
     ("AdapterRegistry", "_mu"): "registry",

@@ -7,8 +7,6 @@
     python -m inferd_tpu.obs postmortem TRACE_ID PATHS... [--json]
         [--out report.json] [--rules rules.json]
     python -m inferd_tpu.obs fleet [--check] [--json] PATHS...
-    python -m inferd_tpu.obs prof [--check] [--json] [--priors FILE]
-        PATHS...
 
 `merge` consumes per-node span JSONL files (or directories of them — the
 node's --trace-dir output, or /spans endpoint dumps), corrects clock
@@ -40,15 +38,6 @@ output / GET /metrics/history), which assemble into one fresh sample.
 `--check` is the CI smoke: exit 1 unless at least one sample exists,
 carries the schema fields, and resolved at least one real SLI series —
 run.sh step 0e runs it over the committed tests/data/fleet fixture.
-
-`prof` re-runs the continuous-profiling sentinel (obs.prof) offline:
-each `*.history.json` node dump is judged against the `priors.json`
-per-token-cost table (matched on its meta (chip, preset, quant, stage)
-key), the published anatomy./roofline. series are listed, and journaled
-`perf.regression` events from `*.events.jsonl` are counted. `--check`
-is the CI smoke: exit 1 unless at least one valid history exists and at
-least one was actually evaluated — run.sh step 0f runs it over the
-committed tests/data/prof fixture (one fresh history, one regressed).
 """
 
 from __future__ import annotations
@@ -202,31 +191,6 @@ def cmd_fleet(args) -> int:
     return 0
 
 
-def cmd_prof(args) -> int:
-    from inferd_tpu.obs import prof as proflib
-
-    report = proflib.check_paths(args.paths, priors_path=args.priors)
-    if args.json:
-        print(json.dumps(report))
-    else:
-        print(proflib.format_report(report))
-    if args.check:
-        problems = proflib.check_report(report)
-        ok = not problems
-        fired = sum(
-            1 for r in report["histories"]
-            if (r.get("verdict") or {}).get("fired")
-        )
-        print(
-            f"obs prof check: {'OK' if ok else 'FAIL'} "
-            f"({len(report['histories'])} history(ies), {fired} firing"
-            + (f"; {'; '.join(problems)}" if problems else "")
-            + ")"
-        )
-        return 0 if ok else 1
-    return 0
-
-
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(prog="python -m inferd_tpu.obs")
     sub = ap.add_subparsers(dest="cmd", required=True)
@@ -301,29 +265,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         "series exists",
     )
     fl.set_defaults(fn=cmd_fleet)
-
-    pf = sub.add_parser(
-        "prof",
-        help="re-run the perf-regression sentinel over committed "
-        "node histories",
-    )
-    pf.add_argument(
-        "paths", nargs="+",
-        help="per-node *.history.json dumps, *.events.jsonl journals, "
-        "and a priors.json (or directories of them)",
-    )
-    pf.add_argument(
-        "--priors", default="",
-        help="per-token-cost priors JSON (default: priors.json found "
-        "in the scanned directories)",
-    )
-    pf.add_argument("--json", action="store_true", help="machine output")
-    pf.add_argument(
-        "--check", action="store_true",
-        help="CI smoke: exit 1 unless a valid history exists and the "
-        "sentinel evaluated at least one",
-    )
-    pf.set_defaults(fn=cmd_prof)
 
     args = ap.parse_args(argv)
     return args.fn(args)

@@ -26,17 +26,6 @@ module makes health a computation:
     `event:TYPE/min` = trailing-minute rate), and against gossiped peer
     records (`peer:FIELD` — fires when ANY peer breaches, so one node
     can flag fleet-wide trouble);
-  * `roofline:` / `phase:` rules judge the live-anatomy gauges the
-    continuous profiling plane publishes (obs.prof), stating — like
-    every metric rule — the HEALTHY condition:
-    `"roofline:frac > 0.02"` resolves the `roofline.<field>` gauges
-    (frac, live_frac) and fires when the achieved fraction COLLAPSES
-    below the floor; `"phase:attn/frac > 0.1"` resolves
-    `anatomy.<phase>_<field>` (aliases: attn -> attention,
-    head -> lm_head; field defaults to ms) and fires when the attention
-    phase falls that far off its roofline — so a kernel PR's win, or
-    its regression, is a health rule over LIVE traffic, not only a
-    bench-battery assertion;
   * a signal that doesn't exist SKIPS its rule (a CPU node has no
     hbm.frac; skipping is not passing and not firing — the verdict
     reports how many rules actually evaluated);
@@ -89,10 +78,6 @@ _BURN_RE = re.compile(
 )
 
 _WINDOW_UNITS = {"s": 1.0, "m": 60.0, "h": 3600.0}
-
-#: `phase:` rule-name aliases onto perf.anatomy's PHASES vocabulary.
-PHASE_ALIASES = {"attn": "attention", "head": "lm_head"}
-
 
 def parse_window(text: str) -> float:
     """'5m' / '1h' / '90s' -> seconds."""
@@ -248,11 +233,6 @@ DEFAULT_RULES: Tuple[Rule, ...] = (
     Rule.parse("burn:availability[5m,1h] > 14", severity="failing"),
     Rule.parse("burn:availability[30m,4h] > 3"),
     Rule.parse("burn:canary[5m,1h] > 14", severity="failing"),
-    # perf regression sentinel (obs.prof): trailing live per-token cost
-    # degraded > 20% vs the committed (chip, config) prior in both
-    # sentinel windows. The gauge only exists on prof-enabled nodes —
-    # everywhere else the rule SKIPS, like hbm.frac on CPU.
-    Rule.parse("perf.regression == 0"),
 )
 
 #: Postmortem defaults (evaluated over ONE trace's window): count-based
@@ -270,21 +250,6 @@ POSTMORTEM_RULES: Tuple[Rule, ...] = (
 
 
 # ------------------------------------------------------------- resolution
-
-
-def _prof_gauge_path(signal: str) -> Optional[str]:
-    """Translate a `roofline:` / `phase:` rule signal into the gauge
-    name the continuous profiling plane publishes (obs.prof), or None
-    when the signal isn't prof-shaped. `roofline:frac` ->
-    `roofline.frac`; `phase:attn/frac` -> `anatomy.attention_frac`
-    (field defaults to ms)."""
-    if signal.startswith("roofline:"):
-        return "roofline." + signal[len("roofline:"):]
-    if signal.startswith("phase:"):
-        name, _, field = signal[len("phase:"):].partition("/")
-        name = PHASE_ALIASES.get(name, name)
-        return f"anatomy.{name}_{field or 'ms'}"
-    return None
 
 
 def _resolve_metric(snapshot: Dict[str, Any], path: str) -> Optional[float]:
@@ -409,14 +374,6 @@ def evaluate_rule(
         fired = all(_OPS[rule.op](b, rule.threshold) for b in burns)
         limiting = min(burns) if rule.op in (">", ">=") else max(burns)
         return fired, limiting, None
-    prof_path = _prof_gauge_path(sig)
-    if prof_path is not None:
-        # live-anatomy gauges (obs.prof): plain metric lookup behind the
-        # rule-facing prefix — a node without the prof plane SKIPS
-        val = _resolve_metric(snapshot, prof_path)
-        if val is None:
-            return None, None, None
-        return (not _OPS[rule.op](val, rule.threshold)), val, None
     if sig.startswith("event:"):
         val = _resolve_event(sig[len("event:"):], events, now, window_s)
         if val is None:

@@ -9,11 +9,6 @@ measurement is consistent with it:
     chip-spec table: floor ms/step, ceiling tok/s, and the one audited
     definition of `hbm_roofline_frac` (bench.py's ad-hoc arithmetic
     re-derives from here — docs/PERF.md).
-  * anatomy  — step-anatomy profiler: times jitted sub-graphs of a decode
-    step (embed / attention / mlp / lm_head / sampling / kv_write) with
-    interleaved paired differencing scans, attributing ms and
-    %-of-roofline per phase. CPU-runnable for tests; on TPU via the
-    bench_battery `anatomy` leg.
   * autotune — persistent per-(chip, shape, dtype) measurement registry
     consulted by the `auto` dispatches in ops/attention.py (kernel vs
     XLA) and ops/quant.py (int4 contraction scheme) when populated;
@@ -23,7 +18,7 @@ measurement is consistent with it:
     artifacts: steady/e2e ordering, roofline-fraction regressions vs a
     prior artifact, and physical-impossibility (frac > 1) checks.
 
-CLI: `python -m inferd_tpu.perf {report,check,anatomy}` (see __main__).
+CLI: `python -m inferd_tpu.perf {report,check}` (see __main__).
 
 No module in this package may initialize a JAX backend at import time
 (tests/test_cli.py test_package_import_initializes_no_jax_backend).

@@ -4,13 +4,10 @@
         [--ctx N] [--batch B] [--artifact BENCH.jsonl]
     python -m inferd_tpu.perf check --artifact BENCH.jsonl
         [--prior OLD.jsonl] [--chip v5e] [--json]
-    python -m inferd_tpu.perf anatomy --preset qwen3-0.6b [--ctx N]
-        [--quant int8] [--device cpu|tpu|auto] [--pairs K]
 
 `report` and `check` are pure host-side arithmetic — they run on a
 CPU-only box without initializing any JAX backend beyond importing
-jax.numpy for dtype sizes. `anatomy` runs jitted sub-graphs on the pinned
-device and prints ONE JSON line last (the bench_battery stdout contract).
+jax.numpy for dtype sizes.
 
 Exit codes: `check` exits 1 when any ERROR-severity finding exists
 (warnings never fail the gate); everything else exits 0 on success.
@@ -83,27 +80,6 @@ def cmd_check(args) -> int:
     return 0 if ok else 1
 
 
-def cmd_anatomy(args) -> int:
-    # pin BEFORE the first backend use
-    from inferd_tpu.utils.platform import force_platform
-
-    force_platform(args.device)
-    from inferd_tpu.config import get_config
-    from inferd_tpu.perf import anatomy
-
-    cfg = get_config(args.preset)
-    phases = None
-    if args.phases:
-        phases = tuple(p.strip() for p in args.phases.split(",") if p.strip())
-    out = anatomy.profile_step(
-        cfg, quant=args.quant, ctx=args.ctx, batch=args.batch,
-        pairs=args.pairs, phases=phases,
-        paged_block_size=args.paged_block,
-    )
-    print(json.dumps(out))
-    return 0
-
-
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(prog="python -m inferd_tpu.perf")
     sub = ap.add_subparsers(dest="cmd", required=True)
@@ -132,29 +108,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         "overhead against stage compute (warning only)",
     )
     ck.set_defaults(fn=cmd_check)
-
-    an = sub.add_parser("anatomy", help="step-anatomy profile on the "
-                        "attached device (one JSON line)")
-    an.add_argument("--preset", required=True)
-    an.add_argument("--quant", default="none")
-    an.add_argument("--ctx", type=int, default=256)
-    an.add_argument("--batch", type=int, default=1)
-    an.add_argument("--pairs", type=int, default=3)
-    an.add_argument("--device", default="auto")
-    an.add_argument(
-        "--phases", default="",
-        help="comma-separated subset of anatomy phases to time (default "
-        "all; e.g. --phases dispatch isolates the host-loop dispatch "
-        "overhead the K-step fused decode amortizes)",
-    )
-    an.add_argument(
-        "--paged-block", type=int, default=0,
-        help="time the attention phase through the PAGED read path "
-        "(block-table gather, ops.attention.gather_block_kv) with this "
-        "block size in tokens (0 = dense) — matches a --paged-kv "
-        "executor's live anatomy",
-    )
-    an.set_defaults(fn=cmd_anatomy)
 
     args = ap.parse_args(argv)
     return args.fn(args)

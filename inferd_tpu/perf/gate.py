@@ -601,7 +601,6 @@ def check_span_overhead(stats: Dict[str, Any]) -> List[Finding]:
     — or any of its always-on siblings: the event journal's
     `events.overhead_ms`, the windowed tsdb's `tsdb.overhead_ms`
     sampling cost, the canary prober's `canary.overhead_ms` bookkeeping,
-    the live-anatomy tick's `prof.overhead_ms` scan time (obs.prof),
     the lock-order sanitizer's `lockwatch.overhead_ms` checking cost
     — exceeds 1% of cumulative stage compute (stage.compute_ms histogram
     mean x count). The whole telemetry plane is only defensible while
@@ -630,8 +629,6 @@ def check_span_overhead(stats: Dict[str, Any]) -> List[Finding]:
          "lengthen the tick or shrink the level ladder"),
         ("canary.overhead_ms", "canary-probing",
          "lengthen --canary-interval"),
-        ("prof.overhead_ms", "live-anatomy",
-         "lengthen --prof-interval or shrink the scan windows"),
         ("lockwatch.overhead_ms", "lock-order-sanitizer",
          "watch fewer locks or disable INFERD_LOCKWATCH in production"),
     ):

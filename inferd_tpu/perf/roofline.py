@@ -72,8 +72,8 @@ CHIP_SPECS: Dict[str, ChipSpec] = {
         ChipSpec("v5p", "TPU v5p", 2765.0, 459.0, 918.0, 95.0),
         ChipSpec("v4", "TPU v4", 1228.0, 275.0, 275.0, 32.0),
         ChipSpec("v6e", "TPU v6e (Trillium)", 1640.0, 918.0, 1836.0, 32.0),
-        # Order-of-magnitude placeholder so CPU smoke runs of the report /
-        # anatomy tooling have a denominator; never used for real claims.
+        # Order-of-magnitude placeholder so CPU smoke runs of the report
+        # have a denominator; never used for real claims.
         ChipSpec("cpu", "host CPU (nominal)", 20.0, 0.2, 0.4, 64.0),
     ]
 }

@@ -1976,35 +1976,6 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             return None
         return prefixlib.make_digest(keys, bs)
 
-    def anatomy_target(self) -> Dict[str, Any]:
-        """Live step-anatomy inputs for the continuous profiling plane
-        (obs.prof.LiveAnatomy): this executor's REAL serving weights
-        (already quantized/LoRA-merged at load) and paged/dense cache
-        config, with ctx tracking the current decode frontier — rounded
-        UP to a 64-token bucket so the scan shapes (and their XLA
-        compilations) stay stable as the frontier drifts token by token.
-        Whole-model executor: every device phase applies."""
-        with self._mu:
-            ctx = max(self.engine.lengths, default=0)
-        ctx = -(-max(ctx, 32) // 64) * 64  # 64-token shape bucket
-        return {
-            "cfg": self.cfg,
-            "params": self.engine.params,
-            "phases": (
-                "embed", "attention", "mlp", "lm_head", "sampling",
-                "kv_write",
-            ),
-            "ctx": min(ctx, max(self.max_len - 64, 32)),
-            "batch": 1,
-            "paged_block_size": (
-                self.pool.block_size if self.pool is not None else 0
-            ),
-            # full-co-batch ceiling basis for roofline.live_frac: the
-            # replica's aggregate tok/s is judged against what the chip
-            # allows at ALL lanes, not one (obs.prof.AnatomyTarget)
-            "ceiling_batch": self.engine.lanes,
-        }
-
     def stats(self) -> Dict[str, Any]:
         """Batching effectiveness for /stats: lane occupancy + how many
         decode steps actually coalesced (tok-per-weight-read is the whole

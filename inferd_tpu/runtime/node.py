@@ -45,7 +45,6 @@ from inferd_tpu.obs import devtel as devtellib
 from inferd_tpu.obs import events as eventslib
 from inferd_tpu.obs import export as obs_export
 from inferd_tpu.obs import health as healthlib
-from inferd_tpu.obs import prof as proflib
 from inferd_tpu.obs import trace as tracelib
 from inferd_tpu.obs import tsdb as tsdblib
 from inferd_tpu.parallel import stages as stagelib
@@ -299,8 +298,6 @@ class Node:
         adapter_slots: int = 0,
         trace_dir: Optional[str] = None,
         canary_interval_s: float = 0.0,
-        prof_interval_s: float = 0.0,
-        prof_priors: Optional[str] = None,
         hedge_delay_ms: float = 0.0,
         hedge_mode: str = "advertised",
         admission_reserve: float = 0.05,
@@ -362,19 +359,6 @@ class Node:
         # bounded rate, recording ONLY canary.* series
         self.canary_interval_s = canary_interval_s
         self.canary: Optional[canarylib.CanaryProber] = None
-        # continuous profiling plane (obs.prof): off unless run_node
-        # --prof-interval > 0; a low-duty-cycle tick scans ONE anatomy
-        # phase against the live executor's weights when the device is
-        # quiet, publishes anatomy.*/roofline.* gauges, and runs the
-        # perf-regression sentinel against the committed priors file
-        self.prof_interval_s = prof_interval_s
-        self.prof_priors = prof_priors
-        self.prof: Optional[proflib.LiveAnatomy] = None
-        self._prof_task: Optional[asyncio.Task] = None
-        # capture lock shared by the manual /profile window and the
-        # live-anatomy tick: held for a whole capture so tick micro-scans
-        # never pollute the device timeline (and vice versa)
-        self._capture_lock = lockwatch.make_lock("capture")
         self._capture_task: Optional[asyncio.Task] = None
         # event-loop stall watchdog (J009's dynamic twin) — started by
         # start() when lockwatch + events are on, journals `loop.stall`
@@ -507,9 +491,7 @@ class Node:
         from inferd_tpu.core.spec_batch import SPEC_TOP_N
 
         self._spec_top_n = SPEC_TOP_N
-        self.profiler = Profiler(
-            device_lock=self._capture_lock, recorder=self.tracer
-        )
+        self.profiler = Profiler(recorder=self.tracer)
         if mesh_plan is not None and batch_lanes > 0:
             raise ValueError(
                 "--mesh and --batch-lanes are mutually exclusive executor "
@@ -927,8 +909,6 @@ class Node:
                 timeout_s=min(self.hop_timeout_s, 30.0),
             )
             self.canary.start()
-        if self.prof_interval_s > 0:
-            self._setup_prof()
         if self.spec_draft_layers > 0:
             # compile the greedy speculative engine off the critical path;
             # the first request then hits a warm engine (or waits briefly
@@ -970,18 +950,16 @@ class Node:
         if self.canary is not None:
             await self.canary.stop()
             self.canary = None
-        for task_attr in ("_prof_task", "_capture_task"):
-            task = getattr(self, task_attr, None)
-            if task is not None:
-                task.cancel()
-                try:
-                    await task
-                except asyncio.CancelledError:
-                    pass
-                setattr(self, task_attr, None)
+        if self._capture_task is not None:
+            self._capture_task.cancel()
+            try:
+                await self._capture_task
+            except asyncio.CancelledError:
+                pass
+            self._capture_task = None
         if self.profiler.active_dir is not None:
             # a capture window still open at shutdown: close it so the
-            # trace flushes (and the capture lock releases)
+            # trace flushes
             try:
                 await asyncio.get_running_loop().run_in_executor(
                     None, self.profiler.stop
@@ -1109,91 +1087,6 @@ class Node:
             if v.get("host") and v.get("port")
         )
 
-    def _prof_target(self) -> Optional[proflib.AnatomyTarget]:
-        """Live AnatomyTarget from the CURRENT executor (rebinding per
-        call, so a stage migration's swapped-in executor profiles its own
-        weights), or None when the executor can't express one."""
-        fn = getattr(self.executor, "anatomy_target", None)
-        if not callable(fn):
-            return None
-        try:
-            return proflib.AnatomyTarget(quant=self.quant, **fn())
-        except Exception:
-            log.debug("anatomy target unavailable", exc_info=True)
-            return None
-
-    def _setup_prof(self) -> None:
-        """Build the live-anatomy plane (obs.prof) over the current
-        executor. Priors (--prof-priors) key on (chip, preset, quant,
-        stage) — a replica without a matching prior still publishes the
-        anatomy/roofline series; only the sentinel skips."""
-        if self._prof_target() is None:
-            log.info(
-                "live anatomy disabled: executor %s has no anatomy_target",
-                type(self.executor).__name__,
-            )
-            return
-        priors = {}
-        if self.prof_priors:
-            try:
-                priors = proflib.load_priors(self.prof_priors)
-            except (OSError, ValueError) as e:
-                log.warning("prof priors %s unusable: %s", self.prof_priors, e)
-        # detect the chip EAGERLY (the executor already initialized the
-        # backend): a history flushed before the first idle tick must not
-        # stamp chip="cpu" on a TPU node — the offline sentinel would
-        # judge TPU per-token cost against a CPU prior
-        from inferd_tpu.perf import roofline as rl
-
-        chip = rl.detect_chip()
-        self.prof = proflib.LiveAnatomy(
-            self.metrics,
-            self._prof_target,
-            # no history_fn: the tick thread must not serialize the live
-            # rings itself — _prof_loop snapshots on the loop thread and
-            # passes the snapshot into tick_once
-            journal=self.journal,
-            device_lock=self._capture_lock,
-            executor_lock_fn=lambda: getattr(self.executor, "_dev_lock", None),
-            busy_fn=lambda: self.scheduler.inflight > 0,
-            priors=priors,
-            chip=chip,
-            key_fn=lambda: proflib.prior_key(
-                chip.key, self.cfg.name, self.quant, self.info.stage,
-            ),
-        )
-        # stamp the sentinel's identity into the history meta so the
-        # OFFLINE check (obs prof --check over --trace-dir dumps) can
-        # match each node's history against the same priors table
-        self.tsdb.meta.update(
-            preset=self.cfg.name, quant=self.quant, chip=chip.key,
-        )
-        self._prof_task = asyncio.create_task(self._prof_loop())
-
-    async def _prof_loop(self) -> None:
-        """Low-duty-cycle live-anatomy tick (obs.prof): one phase scan
-        per interval, off the event loop, only when the node is idle and
-        no capture holds the device. A sentinel transition re-announces
-        urgently so the gossiped `perf` flag propagates within a gossip
-        period, mirroring the outlier flag."""
-        loop = asyncio.get_running_loop()
-        while True:
-            await asyncio.sleep(self.prof_interval_s)
-            try:
-                # serialize the history snapshot HERE, on the loop thread
-                # where sample() also runs — the tick thread must never
-                # iterate the live rings concurrently with a sample
-                self.tsdb.sample()
-                h = self.tsdb.history()
-                out = await loop.run_in_executor(
-                    None, self.prof.tick_once, h
-                )
-                if out.get("sentinel_changed"):
-                    self._health_cache = (0.0, None)
-                    self.announce()
-            except Exception:
-                log.exception("live-anatomy tick failed")
-
     async def _tsdb_loop(self) -> None:
         """Fixed-cadence telemetry tick: fold the registry into the
         windowed rings every `tsdb_period_s` (so idle periods age the
@@ -1305,15 +1198,6 @@ class Node:
             # self-detected replica outlier: peers' routing applies
             # OUTLIER_PENALTY to this record (control/path_finder, dstar)
             gossip["outlier"] = 1
-        if self.prof is not None:
-            # continuous profiling plane (obs.prof): the live roofline
-            # fraction + the sentinel flag — old peers pass the unknown
-            # keys through untouched (mixed-version contract), old
-            # dashboards/collectors render the cells blank
-            if self.prof.last_live_frac is not None:
-                gossip["roofline"] = round(self.prof.last_live_frac, 4)
-            if self.prof.sentinel_fired:
-                gossip["perf"] = 1
         frac = snap["gauges"].get("hbm.frac")
         if frac is not None:
             gossip["hbm"] = round(float(frac), 3)
@@ -4885,12 +4769,6 @@ class Node:
                 m.set_gauge(
                     "canary.overhead_ms", round(self.canary.overhead_ms, 3)
                 )
-            if self.prof is not None:
-                # live-anatomy scan cost, budgeted by perf.gate next to
-                # trace/events/tsdb/canary (<=1% of stage compute)
-                m.set_gauge(
-                    "prof.overhead_ms", round(self.prof.overhead_ms, 3)
-                )
             lw = lockwatch.stats()
             if lw["checks"]:
                 # lock-order sanitizer cost, same perf.gate 1% budget;
@@ -5006,8 +4884,6 @@ class Node:
         on-device trace; `capture_close` times the write), journaled,
         and the obs artifacts flush when it closes so the collector can
         assemble the bundle immediately.
-        Start/stop/window all hold the shared capture lock for the whole
-        trace, so live-anatomy ticks (obs.prof) never interleave.
 
         Opt-in only (--enable-profiling): an open profiler endpoint lets any
         peer degrade the node and fill its disk with traces (ADVICE r1)."""
@@ -5194,10 +5070,6 @@ class Node:
         self.path_finder.planner = None  # planned from the OLD stage's view
         self.info.set_stage(target)
         self.tsdb.meta["stage"] = target  # fleet SLIs group by stage
-        if self.prof is not None:
-            # the swapped-in executor is a new anatomy target: old phase
-            # scans (and the old stage's prior key) must not bleed over
-            self.prof.reset_target()
         self.announce()
         self.metrics.inc("migrations")
         seconds = time.perf_counter() - t0

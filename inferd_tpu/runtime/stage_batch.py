@@ -1062,41 +1062,6 @@ class BatchedStageExecutor(AdapterBindingMixin):
         with self._mu:
             return session_id in self._sessions
 
-    def anatomy_target(self) -> Dict[str, Any]:
-        """Live step-anatomy inputs for the continuous profiling plane
-        (obs.prof.LiveAnatomy): the stage's REAL weight slice and paged/
-        dense cache config. The cfg is re-shaped to the slice's layer
-        count (profile_step scans params["layers"], which holds exactly
-        this stage's layers) and the phase set is restricted to what the
-        slice can express: embed only on the first stage, lm_head +
-        sampling only on the last. ctx rounds UP to a 64-token bucket so
-        the scan shapes (and their XLA compilations) stay stable as the
-        decode frontier drifts."""
-        import dataclasses as _dc
-
-        phases = ["attention", "mlp", "kv_write"]
-        if self.spec.is_first:
-            phases.insert(0, "embed")
-        if self.spec.is_last:
-            phases.extend(["lm_head", "sampling"])
-        with self._mu:
-            ctx = max(self.lengths, default=0)
-        ctx = -(-max(ctx, 32) // 64) * 64  # 64-token shape bucket
-        return {
-            "cfg": _dc.replace(self.cfg, num_layers=self.spec.num_layers),
-            "params": self.params,
-            "phases": tuple(phases),
-            "ctx": min(ctx, max(self.max_len - 64, 32)),
-            "batch": 1,
-            "paged_block_size": (
-                self.pool.block_size if self.pool is not None else 0
-            ),
-            # full-co-batch ceiling basis for roofline.live_frac: the
-            # replica's aggregate tok/s is judged against what the chip
-            # allows at ALL lanes, not one (obs.prof.AnatomyTarget)
-            "ceiling_batch": self.lanes,
-        }
-
     def stats(self) -> Dict[str, Any]:
         with self._mu:
             steps, toks = self._batched_steps, self._batched_tokens

@@ -1,8 +1,7 @@
 """On-chip benchmark battery -> committed, driver-auditable artifacts.
 
-Each leg shells out to bench.py (or `python -m inferd_tpu.perf`) with
-`--device tpu`: one process after another, each alone on the chip, this
-parent never touching JAX. The result JSON — plus timestamp, argv, and wall
+Each leg shells out to bench.py with `--device tpu`: one process after
+another, each alone on the chip, this parent never touching JAX. The result JSON — plus timestamp, argv, and wall
 time — is appended to `bench_artifacts/BENCH_tpu_<utc-stamp>.jsonl`, one
 line per leg, ready to `git add`. A leg that finds no chip fails; nothing
 here measures the CPU under a chip leg's name.
@@ -32,10 +31,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 BENCH = os.path.join(REPO, "bench.py")
 ARTIFACT_DIR = os.path.join(REPO, "bench_artifacts")
 
-# each leg: (name, argv tail, per-leg timeout seconds). A tail starting
-# with the "@perf" marker runs `python -m inferd_tpu.perf <rest>` instead
-# of bench.py (the step-anatomy profiler rides the same battery/artifact
-# machinery as the bench legs).
+# each leg: (name, argv tail, per-leg timeout seconds).
 # --no-extras everywhere: only the on-chip leg matters here (the CPU
 # pipeline-ratio/batched proxy legs ride the default --device cpu run).
 DEFAULT_LEGS = [
@@ -59,17 +55,11 @@ DEFAULT_LEGS = [
     # warm/cold witness where the delta is tens of seconds, not two
     ("spec", ["--config", "spec"], 1500),
     ("compile_cache", ["--config", "compile-cache"], 1500),
-    # round-6 legs: the north-star model's
-    # single-chip denominator — qwen3-8b int8 fits v5e's 16 GB HBM where
-    # bf16 (~16.4 GB) does not — and the step-anatomy profile that says
-    # where the decode milliseconds actually go (perf/anatomy)
+    # round-6 leg: the north-star model's single-chip denominator —
+    # qwen3-8b int8 fits v5e's 16 GB HBM where bf16 (~16.4 GB) does not
     ("decode_8b_int8",
      ["--config", "decode", "--model", "qwen3-8b", "--quant", "int8",
       "--no-extras"], 2400),
-    ("anatomy",
-     ["@perf", "anatomy", "--preset", "qwen3-0.6b", "--ctx", "256"], 1500),
-    ("anatomy_ctx8k",
-     ["@perf", "anatomy", "--preset", "qwen3-0.6b", "--ctx", "8192"], 1500),
     ("decode_multistep", ["--config", "decode-multistep"], 1800),
     # round-19 leg (on-chip roofline gap): the three Pallas decode
     # kernels (paged attention, dequant GEMV, fused LoRA lane-delta)
@@ -78,9 +68,6 @@ DEFAULT_LEGS = [
     # on a TPU host pair this with `sweep_attn --kernels --populate` so
     # the wall-clock verdicts land in the autotune registry
     ("kernels", ["--config", "kernels"], 1800),
-    ("anatomy_dispatch",
-     ["@perf", "anatomy", "--preset", "qwen3-0.6b", "--ctx", "256",
-      "--phases", "dispatch"], 1200),
 ]
 
 SMOKE_LEGS = [
@@ -94,9 +81,6 @@ SMOKE_LEGS = [
       "--steps", "8", "--reps", "1"], 600),
     ("prefill_tiny", ["--config", "prefill", "--tiny", "--device", "cpu",
                       "--reps", "1"], 600),
-    ("anatomy_tiny",
-     ["@perf", "anatomy", "--preset", "tiny", "--ctx", "64", "--pairs", "2",
-      "--device", "cpu"], 600),
     # CPU stand-in for the swarm aggregate-throughput leg: 4 concurrent
     # sessions through a 2-stage --stage-lanes chain vs the serial swarm
     # baseline (stage-level continuous batching, runtime/stage_batch) —
@@ -110,15 +94,12 @@ SMOKE_LEGS = [
     ("swarm_agg_tiny",
      ["--config", "swarm-agg", "--tiny", "--lanes", "4", "--steps", "6",
       "--device", "cpu"], 900),
-    # round-7 smoke siblings: same argv shapes as decode_multistep /
-    # anatomy_dispatch so the K-step evidence machinery is dryrun-tested
-    # on every offline battery run
+    # round-7 smoke sibling: same argv shape as decode_multistep so the
+    # K-step evidence machinery is dryrun-tested on every offline
+    # battery run
     ("decode_multistep_tiny",
      ["--config", "decode-multistep", "--tiny", "--device", "cpu",
       "--steps", "6", "--reps", "2", "--k-sweep", "1,4,8"], 900),
-    ("anatomy_dispatch_tiny",
-     ["@perf", "anatomy", "--preset", "tiny", "--ctx", "64", "--pairs", "2",
-      "--device", "cpu", "--phases", "dispatch"], 600),
     # canary-prober dryrun: a real 2-stage chain with --canary-interval,
     # asserting probes complete end to end AND never leak into the user
     # SLI series (obs.canary; docs/OBSERVABILITY.md)
@@ -155,10 +136,7 @@ SMOKE_LEGS = [
 
 
 def run_leg(name: str, tail, timeout_s: int, device_args):
-    if tail and tail[0] == "@perf":
-        argv = [sys.executable, "-m", "inferd_tpu.perf", *tail[1:], *device_args]
-    else:
-        argv = [sys.executable, BENCH, *tail, *device_args]
+    argv = [sys.executable, BENCH, *tail, *device_args]
     t0 = time.time()
     entry = {
         "leg": name,

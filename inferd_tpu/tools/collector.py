@@ -63,12 +63,6 @@ FIELDS = [
     "health",
     # replicas currently gossiping the `outlier` self-flag (obs.canary)
     "outliers",
-    # continuous profiling plane (obs.prof): the stage's WORST replica's
-    # live roofline fraction (gossiped `roofline`), and the replicas
-    # whose perf-regression sentinel is firing (gossiped `perf`) — old
-    # peers gossip neither key and simply leave the cells blank
-    "roofline_worst",
-    "perf",
     # fleet capacity signals (PR 12): tightest replica's paged-KV
     # block-pool free fraction (gossiped `kvfree`) and the worst
     # replica's short-window availability burn (gossiped `burn`) — the
@@ -127,13 +121,6 @@ def stage_rows(swarm_map: SwarmMap, ts: Optional[float] = None) -> list:
         outliers = sorted(
             nid for nid, v in nodes.items() if v.get("outlier")
         )
-        rooflines = [
-            float(v["roofline"]) for v in nodes.values()
-            if isinstance(v.get("roofline"), (int, float))
-        ]
-        perf_firing = sorted(
-            nid for nid, v in nodes.items() if v.get("perf")
-        )
         kvfrees = [
             float(v["kvfree"]) for v in nodes.values()
             if isinstance(v.get("kvfree"), (int, float))
@@ -175,10 +162,6 @@ def stage_rows(swarm_map: SwarmMap, ts: Optional[float] = None) -> list:
                     if healths else ""
                 ),
                 "outliers": " ".join(outliers),
-                # the WORST (lowest) live roofline fraction: the replica
-                # furthest from what the hardware allows sets the cell
-                "roofline_worst": round(min(rooflines), 4) if rooflines else "",
-                "perf": " ".join(perf_firing),
                 # tightest pool / worst burn set the cell: autoscaling
                 # (and a human) reacts to the constrained replica
                 "kvfree_min": round(min(kvfrees), 4) if kvfrees else "",

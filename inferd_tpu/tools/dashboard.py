@@ -56,22 +56,6 @@ def _cobatch_cell(v: Dict[str, Any]) -> str:
     return f"{float(cb):.1f}"
 
 
-def _roofline_cell(v: Dict[str, Any]) -> str:
-    """Live roofline fraction as a percentage (gossiped as `roofline` by
-    prof-enabled nodes — obs.prof), or "-" (old peers / prof off)."""
-    r = v.get("roofline")
-    if not isinstance(r, (int, float)):
-        return "-"
-    return f"{float(r) * 100:.1f}%"
-
-
-def _perf_cell(v: Dict[str, Any]) -> str:
-    """"!perf" when the replica's perf-regression sentinel is firing
-    (gossiped as `perf` — obs.prof: trailing live per-token cost
-    degraded >20% vs the committed prior), else ""."""
-    return "!perf" if v.get("perf") else ""
-
-
 def _kvfree_cell(v: Dict[str, Any]) -> str:
     """Paged-KV block-pool free fraction as a percentage (gossiped as
     `kvfree` by paged replicas, runtime/node.announce — the admission /
@@ -138,7 +122,6 @@ def render_table(swarm_map: SwarmMap, ts: Optional[float] = None) -> str:
         f"{'stage':>5}  {'node':<21} {'name':<12} {'load':>4}/{'cap':<4} "
         f"{'hop p50':>8} {'hop p99':>8} {'out':>3} "
         f"{'cobatch':>7} {'kvfree':>6} {'cache%':>6} {'ada':>3} {'hbm%':>5} "
-        f"{'roof%':>6} {'perf':>5} "
         f"{'compiles':>8} {'health':<8} {'model':<16}"
     )
     rule = "-" * len(header)
@@ -162,8 +145,6 @@ def render_table(swarm_map: SwarmMap, ts: Optional[float] = None) -> str:
                 f"{_cachehit_cell(v):>6} "
                 f"{_ada_cell(v):>3} "
                 f"{_hbm_cell(v):>5} "
-                f"{_roofline_cell(v):>6} "
-                f"{_perf_cell(v):>5} "
                 f"{_compiles_cell(v):>8} "
                 f"{_health_cell(v):<8} "
                 f"{str(v.get('model', '')):<16}"

@@ -296,24 +296,6 @@ def build_parser() -> argparse.ArgumentParser:
         "(docs/OBSERVABILITY.md)",
     )
     ap.add_argument(
-        "--prof-interval", type=float,
-        default=float(os.environ.get("INFERD_PROF_INTERVAL", "0")),
-        help="seconds between live step-anatomy ticks (env "
-        "INFERD_PROF_INTERVAL; 0 = off). Each tick scans ONE anatomy "
-        "phase against the live executor's weights when the device is "
-        "quiet, publishing anatomy.*/roofline.* series and running the "
-        "perf-regression sentinel; cost rides the same 1%%-of-compute "
-        "budget as trace/events/tsdb/canary (docs/OBSERVABILITY.md)",
-    )
-    ap.add_argument(
-        "--prof-priors",
-        default=os.environ.get("INFERD_PROF_PRIORS", ""),
-        help="committed per-token-cost priors JSON for the perf "
-        "regression sentinel (env INFERD_PROF_PRIORS), keyed by "
-        "(chip, preset, quant, stage) — see obs.prof.prior_key. Without "
-        "it the sentinel skips; the anatomy series still publish",
-    )
-    ap.add_argument(
         "--hop-timeout", type=float,
         default=float(os.environ.get("INFERD_HOP_TIMEOUT", "120")),
         help="per-hop relay/HTTP timeout in seconds (env "
@@ -582,8 +564,6 @@ async def _run(args, cache_stats=None) -> None:
         adapter_slots=args.adapter_slots,
         trace_dir=args.trace_dir or None,
         canary_interval_s=args.canary_interval,
-        prof_interval_s=args.prof_interval,
-        prof_priors=args.prof_priors or None,
         hedge_delay_ms=args.hedge_delay_ms,
         hedge_mode=args.hedge_mode,
         admission_reserve=args.admission_reserve,

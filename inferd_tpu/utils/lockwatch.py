@@ -12,10 +12,10 @@ specific interleaving):
     J007 rule imports it, so the lint and the sanitizer can never
     disagree about which nesting is an inversion.
   * `make_lock(name)` is the constructor seam the runtime threads its
-    named locks through (executor device lock / `_mu`, the node's
-    capture lock, the adapter registry, the standby store, the arrival
-    window). Disabled — the default outside tests — it returns a plain
-    `threading.Lock` and costs NOTHING. Watching (INFERD_LOCKWATCH env,
+    named locks through (executor device lock / `_mu`, the adapter
+    registry, the standby store, the arrival window). Disabled — the
+    default outside tests — it returns a plain `threading.Lock` and
+    costs NOTHING. Watching (INFERD_LOCKWATCH env,
     or `instrument()`), it returns an order-recording `WatchedLock`
     proxy that keeps a per-thread stack of held ranks and, on a BLOCKING
     acquisition that violates `LOCK_ORDER`, raises `LockOrderError`
@@ -57,7 +57,6 @@ from typing import Any, Callable, List, Optional
 #: they are too hot for per-acquire bookkeeping; the static J007 rule
 #: still checks their lexical nesting.
 LOCK_ORDER = (
-    "capture",   # node profiler/anatomy capture exclusion
     "dev",       # executor device lock (serializes device steps)
     "mu",        # executor session/lane bookkeeping
     "registry",  # AdapterRegistry._mu (slot + refcount state)

@@ -59,9 +59,8 @@ def interleaved_pair_times(time_short, time_long, pairs: int):
     runs one SHORT and one LONG window back to back, ALTERNATING which
     goes first, so a linear host-load drift biases half the pairs
     up and half down and a median over per-pair quantities cancels it.
-    This is the round-4 pipeline-leg discipline, factored out so the
-    decode bench (bench.py) and the step-anatomy profiler (perf/anatomy)
-    share ONE definition. Returns (t_shorts, t_longs), seconds."""
+    This is the round-4 pipeline-leg discipline, factored out of the
+    decode bench (bench.py). Returns (t_shorts, t_longs), seconds."""
     ts, tl = [], []
     for i in range(pairs):
         if i % 2 == 0:
@@ -121,29 +120,18 @@ def paired_delta_stats(ts, tl, n_short: int, n_long: int):
 
 
 class Profiler:
-    """Serialized start/stop wrapper around jax.profiler tracing.
+    """Serialized start/stop wrapper around jax.profiler tracing: one
+    capture at a time (a second start raises), start and stop may arrive
+    on different threads."""
 
-    `device_lock` (optional, shared with the live-anatomy tick —
-    obs.prof.LiveAnatomy) is HELD for the whole start..stop window: a
-    manual /profile capture must never interleave with a tick's
-    micro-scans (the tick's extra jits would pollute the device timeline,
-    and the tick's paired differencing would eat the capture's
-    congestion). The tick try-acquires and skips; start() waits briefly
-    (a tick's scan windows are short) and fails loudly if the device
-    never frees up. threading.Lock release-from-another-thread is legal,
-    which is exactly what stop() relies on (start and stop arrive on
-    different executor threads)."""
-
-    def __init__(self, base_dir: str = "profiles", device_lock=None,
+    def __init__(self, base_dir: str = "profiles",
                  recorder: Optional[tracelib.SpanRecorder] = None):
         self.base_dir = base_dir
-        self.device_lock = device_lock
         # the node's span recorder: `annotating` while a capture runs, so
         # the program's regions (obs.trace.region) show in the trace
         self.recorder = recorder
         self._lock = threading.Lock()
         self._active_dir: Optional[str] = None
-        self._holds_device = False
         # obs.trace.now() as the trace's anchor event ended (see start)
         self.started_at: Optional[float] = None
 
@@ -167,26 +155,14 @@ class Profiler:
             base = os.path.normpath(self.base_dir)
             if os.path.isabs(label) or not (d == base or d.startswith(base + os.sep)):
                 raise ValueError(f"trace name {label!r} escapes profile dir")
-            if self.device_lock is not None:
-                if not self.device_lock.acquire(timeout=10.0):
-                    raise RuntimeError(
-                        "device busy (live-anatomy tick held the capture "
-                        "lock for >10 s) — retry the profile start"
-                    )
-                self._holds_device = True
-            try:
-                os.makedirs(d, exist_ok=True)
-                # no python call stacks: the tracer that records them
-                # slows the host threads the capture is there to time,
-                # and the program's own `inferd.*` regions (obs.trace)
-                # say what the host was doing. Host level 2 keeps
-                # TraceAnnotations.
-                opts = jax.profiler.ProfileOptions()
-                opts.python_tracer_level = 0
-                jax.profiler.start_trace(d, profiler_options=opts)
-            except BaseException:
-                self._release_device()
-                raise
+            os.makedirs(d, exist_ok=True)
+            # no python call stacks: the tracer that records them slows
+            # the host threads the capture is there to time, and the
+            # program's own `inferd.*` regions (obs.trace) say what the
+            # host was doing. Host level 2 keeps TraceAnnotations.
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(d, profiler_options=opts)
             # the clock anchor, an event INSIDE the trace: its end and
             # `started_at` name the same instant on the profiler's clock
             # and on the spans', so a reader can put the two together
@@ -197,11 +173,6 @@ class Profiler:
                 self.recorder.annotating = True
             self._active_dir = d
             return d
-
-    def _release_device(self) -> None:
-        if self._holds_device:
-            self._holds_device = False
-            self.device_lock.release()
 
     def stop(self) -> str:
         """End the trace; returns the directory containing it."""
@@ -220,5 +191,4 @@ class Profiler:
                 # as "running" forever (every later /profile start would
                 # 409 with no way to recover short of a node restart)
                 self._active_dir = None
-                self._release_device()
             return d

@@ -152,13 +152,6 @@ echo "== 0e/4 fleet SLI smoke over the committed collector artifacts (advisory â
 python -m inferd_tpu.obs fleet --check tests/data/fleet \
     || echo "obs fleet: ADVISORY failure (non-blocking in run.sh; tier-1 gates it)"
 
-echo "== 0f/4 perf-regression sentinel smoke over the committed prof fixture (advisory â€” docs/OBSERVABILITY.md)"
-# one fresh and one regressed live-anatomy history vs the committed
-# per-token-cost prior: the fresh one must stay quiet, the regressed one
-# must fire â€” the offline half of the continuous profiling plane
-python -m inferd_tpu.obs prof --check tests/data/prof \
-    || echo "obs prof: ADVISORY failure (non-blocking in run.sh; tier-1 gates it)"
-
 echo "== 0g/4 fleet-simulator scenario replay over committed fixtures (advisory â€” docs/CONTROL.md Â§5)"
 # deterministic 1000-node-class control-plane rehearsal: replays every
 # committed non-slow scenario fixture (adoption race, drain wave,

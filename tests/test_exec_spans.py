@@ -15,6 +15,8 @@ left behind."""
 
 import asyncio
 import glob
+import json
+import os
 import threading
 import time
 
@@ -341,6 +343,67 @@ def test_region_records_one_span_per_parent():
     assert got[0]["t0"] == got[1]["t0"] and got[0]["attrs"] == {"kind": "decode"}
 
 
+# ----------------------------------------------- sessions resident, /stats
+
+# what `kv.sessions_resident_mean` polls each second: `executor.lanes_busy`
+# on the lane paths, `executor.sessions` under --mesh
+RESIDENT = {
+    "dense": ("tiny", "lanes"),
+    "latent": ("tiny-dsv2", "lanes"),
+    "state": ("tiny-granite-h", "lanes"),
+    "ring": ("tiny-gemma2", "lanes"),
+    "stage_lanes": ("tiny", "stage"),
+    "mesh_pp2": ("tiny", "mesh"),
+}
+
+
+def _resident_executor(model, path):
+    from inferd_tpu.config import get_config
+
+    cfg = get_config(model)
+    weights = qwen3.init_params(cfg, jax.random.PRNGKey(0))
+    if path == "lanes":
+        from inferd_tpu.runtime.batch_executor import BatchedExecutor
+
+        return BatchedExecutor(cfg, weights, lanes=3, max_len=64), "lanes_busy"
+    if path == "stage":
+        from inferd_tpu.parallel.stages import Manifest, extract_stage_params
+        from inferd_tpu.runtime.stage_batch import BatchedStageExecutor
+
+        (spec,) = Manifest.even_split(model, 1).stage_specs()
+        sp = extract_stage_params(weights, cfg, spec)
+        return BatchedStageExecutor(cfg, spec, sp, lanes=3, max_len=64), "lanes_busy"
+    from inferd_tpu.runtime.mesh_executor import MeshExecutor
+
+    return MeshExecutor(cfg, weights, MeshPlan(pp=2), num_slots=3, max_len=64,
+                        devices=jax.devices()[:2]), "sessions"
+
+
+@pytest.mark.parametrize("layout", list(RESIDENT))
+def test_stats_count_the_sessions_resident(layout):
+    """0 at rest, n with n sessions holding a lane, unmoved by a decode
+    step, one fewer after `end_session`, 0 after the node's sweep took the
+    stale ones, and a lane given back is taken again."""
+    ex, key = _resident_executor(*RESIDENT[layout])
+
+    def resident():
+        return ex.stats()[key]
+
+    assert resident() == 0
+    for n, sid in enumerate(("a", "b", "c"), start=1):
+        ex.process(sid, {"tokens": [[3, 7, 11]], "start_pos": 0, "real_len": 3})
+        assert resident() == n
+    ex.process("b", {"tokens": [[5]], "start_pos": 3, "real_len": 1})
+    assert resident() == 3
+    ex.end_session("a")
+    assert resident() == 2
+    store = ex.sessions  # what Node._sweep_loop sweeps
+    store.ttl_s = -1.0
+    assert store.sweep() == 2 and resident() == 0
+    ex.process("d", {"tokens": [[3, 7, 11]], "start_pos": 0, "real_len": 3})
+    assert resident() == 1
+
+
 # ------------------------------------------------------------ the capture
 
 
@@ -387,3 +450,155 @@ async def test_capture_span_is_there_before_the_capture_closes(tmp_path):
     anchors = [n for n in names if "start_trace" in n]
     assert anchors == ["inferd.start_trace.anchor"]
     assert not any(n.startswith("$") for n in names)  # the python tracer's events
+
+
+def test_profiler_refuses_a_second_start(tmp_path):
+    """One capture at a time: `stop` hands back the directory `start`
+    named, and a second `start` while one runs raises (the endpoint's 409)
+    and leaves the first running."""
+    from inferd_tpu.utils.profiling import Profiler
+
+    prof = Profiler(base_dir=str(tmp_path / "profiles"))
+    d = prof.start("cap1")
+    assert prof.stop() == d
+    d2 = prof.start("cap2")
+    with pytest.raises(RuntimeError, match="already running"):
+        prof.start("cap3")
+    assert prof.active_dir == d2
+    assert prof.stop() == d2
+
+
+@pytest.mark.asyncio
+async def test_collector_capture_fleet(tmp_path):
+    """Fleet-coordinated capture: the collector triggers one bounded
+    capture_id-tagged /profile window on every node simultaneously, then
+    merges the per-node spans into a Chrome-trace bundle + manifest. A
+    node without --enable-profiling degrades to a recorded error instead
+    of aborting the capture (mixed-fleet contract); the capturing node's
+    `capture` span (bracketing the device trace) rides the bundle."""
+    from inferd_tpu.tools.collector import capture_fleet
+    from test_node_e2e import _mk_node, _start_all, _stop_all
+
+    nodes = [
+        _mk_node(170, 0, 2, bootstrap_idx=170),
+        _mk_node(171, 1, 2, bootstrap_idx=170),
+    ]
+    cap, no_cap = nodes[0], nodes[1]
+    cap.enable_profiling = True
+    cap.profiler.base_dir = str(tmp_path / "profiles")
+    await _start_all(nodes)
+    try:
+        swarm_map = cap.dht.get_all(2)
+        out_dir = str(tmp_path / "bundle")
+        manifest = await capture_fleet(
+            swarm_map, "cap-test", seconds=0.4, out_dir=out_dir
+        )
+        assert manifest["capture_id"] == "cap-test"
+        rec_cap = manifest["nodes"][cap.info.node_id]
+        rec_no = manifest["nodes"][no_cap.info.node_id]
+        assert "cap-test" in rec_cap["dir"]
+        assert "disabled" in rec_no["error"]
+        # the device-trace artifacts landed under the tagged dir
+        assert os.path.isdir(rec_cap["dir"])
+        # the bundle: chrome trace with the capture span in it
+        with open(os.path.join(out_dir, "cap-test.trace.json")) as f:
+            chrome = json.load(f)
+        cap_events = [
+            ev for ev in chrome["traceEvents"]
+            if ev["name"] == "capture"
+            and ev["args"].get("capture_id") == "cap-test"
+        ]
+        assert len(cap_events) == 1
+        assert cap_events[0]["dur"] >= 0.4 * 1e6 * 0.5
+        # writing the trace takes as long as the host lets it: wait for
+        # the close itself, not for a guess at when it will have happened
+        await asyncio.wait_for(cap._capture_task, timeout=120)
+        # the capture journaled open AND close on the capturing node
+        types = [ev["type"] for ev in cap.journal.events()]
+        assert "profile.capture" in types
+        assert "profile.capture_done" in types
+        # profiler closed itself after the bounded window
+        assert cap.profiler.active_dir is None
+    finally:
+        await _stop_all(nodes)
+
+
+@pytest.mark.asyncio
+async def test_capture_fleet_empty_swarm(tmp_path):
+    """A capture against an empty swarm map yields an empty manifest —
+    the CLI turns that into a nonzero exit (an empty bundle must not
+    read as a working capture)."""
+    from inferd_tpu.tools.collector import capture_fleet
+
+    manifest = await capture_fleet({}, "none", 0.1, str(tmp_path / "b"))
+    assert manifest["nodes"] == {} and manifest["spans"] == 0
+
+
+async def _post_profile(http, port, env):
+    from inferd_tpu.runtime import wire
+
+    async with http.post(f"http://127.0.0.1:{port}/profile", data=wire.pack(env)) as r:
+        return r.status, wire.unpack(await r.read())
+
+
+@pytest.mark.parametrize(
+    "case", ["bad_seconds", "clamped_high", "clamped_low", "second_window", "two_threads"]
+)
+@pytest.mark.asyncio
+async def test_profile_endpoint_edges(case, tmp_path):
+    """What /profile answers at its edges: `seconds` that is no number is
+    refused before anything starts, a window is held to [0.1, 60] s and
+    says so, a second window while one is open is a 409 that leaves the
+    first to close, and `start` / `stop` need not share a thread."""
+    import aiohttp
+
+    from test_node_e2e import BASE, _mk_node
+
+    node = _mk_node(172, 0, 1, bootstrap_idx=172)
+    node.enable_profiling = True
+    node.profiler.base_dir = str(tmp_path / "profiles")
+    port = BASE + 172
+    await node.start()
+    try:
+        async with aiohttp.ClientSession() as http:
+            if case == "bad_seconds":
+                status, obj = await _post_profile(
+                    http, port, {"action": "window", "seconds": "soon"})
+                assert status == 400 and "bad seconds" in obj["error"]
+                assert node.profiler.active_dir is None
+                assert not [s for s in node.tracer.spans() if s["name"] == "capture"]
+            elif case in ("clamped_high", "clamped_low"):
+                asked, held = (1000, 60.0) if case == "clamped_high" else (0, 0.1)
+                status, obj = await _post_profile(
+                    http, port, {"action": "window", "seconds": asked, "capture_id": "c"})
+                assert status == 200 and obj["seconds"] == held
+                (cap,) = [s for s in node.tracer.spans() if s["name"] == "capture"]
+                assert cap["t1"] - cap["t0"] == pytest.approx(held)
+            elif case == "second_window":
+                status, first = await _post_profile(
+                    http, port, {"action": "window", "seconds": 0.5, "capture_id": "one"})
+                assert status == 200
+                task = node._capture_task
+                status, obj = await _post_profile(
+                    http, port, {"action": "window", "seconds": 0.5, "capture_id": "two"})
+                assert status == 409 and "already running" in obj["error"]
+                assert node._capture_task is task and node.profiler.active_dir == first["dir"]
+                await asyncio.wait_for(task, timeout=120)
+                closes = [s for s in node.tracer.spans() if s["name"] == "capture_close"]
+                assert [s["attrs"] for s in closes] == [{"capture_id": "one"}]
+                assert node.profiler.active_dir is None
+            else:
+                status, started = await _post_profile(
+                    http, port, {"action": "start", "name": "t"})
+                assert status == 200
+                got = {}
+                stopper = threading.Thread(
+                    target=lambda: got.update(dir=node.profiler.stop(), by=threading.get_ident()))
+                stopper.start()
+                await asyncio.get_running_loop().run_in_executor(None, stopper.join)
+                assert got["dir"] == started["dir"] and got["by"] != threading.get_ident()
+                assert node.profiler.active_dir is None
+                status, _ = await _post_profile(http, port, {"action": "start", "name": "u"})
+                assert status == 200  # nothing was left held
+    finally:
+        await node.stop()

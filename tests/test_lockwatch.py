@@ -327,3 +327,93 @@ def test_suite_runs_instrumented_with_zero_inversions():
         pytest.skip("lockwatch killed via INFERD_LOCKWATCH")
     assert lockwatch.watching() and lockwatch.strict()
     assert lockwatch.stats()["inversions"] == 0
+
+
+# ------------------------------------- the order as it stands, both halves
+
+
+def test_every_named_lock_is_ranked_and_the_package_nests_none_backwards():
+    """The lint's half: the node's capture lock left the order with its
+    one other holder, so no name the lint resolves and no lock the
+    runtime constructs may still point outside `LOCK_ORDER` (an unranked
+    name is silently unwatched), and J007 over the package finds nothing
+    with no baseline to lean on."""
+    import pathlib
+    import re
+
+    from inferd_tpu.analysis import concurrency
+    from inferd_tpu.analysis.engine import check_paths
+    from inferd_tpu.analysis.rules import ALL_RULES
+
+    assert "capture" not in LOCK_ORDER
+    resolved = set(concurrency._ATTR_DEFAULT.values()) | set(concurrency._CLASS_ATTR.values())
+    assert resolved <= set(LOCK_ORDER)
+    pkg = pathlib.Path(lockwatch.__file__).resolve().parents[1]
+    built = set()
+    for f in pkg.rglob("*.py"):
+        if f.name != "lockwatch.py":
+            built |= set(re.findall(r"make_lock\(\s*\"(\w+)\"", f.read_text()))
+    assert built and built <= set(LOCK_ORDER)
+    j007 = [r for r in ALL_RULES if r.id == "J007"]
+    assert len(j007) == 1
+    assert check_paths([str(pkg)], rules=j007, rel_to=str(pkg.parent)) == []
+
+
+@pytest.mark.asyncio
+async def test_profile_window_under_traffic_takes_no_lock_out_of_order(tmp_path):
+    """The sanitizer's half, strict as the suite runs it: a lane node
+    serves two generations side by side inside an open /profile window
+    (30 s, so no host is too slow for it; the shutdown closes it). Every
+    named lock it took was watched; an acquisition against the order
+    would have failed its request."""
+    import os
+
+    import aiohttp
+    import jax
+
+    from inferd_tpu.client.swarm_client import SwarmClient
+    from inferd_tpu.config import TINY, SamplingConfig
+    from inferd_tpu.control.dht import SwarmDHT
+    from inferd_tpu.models import qwen3
+    from inferd_tpu.parallel.stages import Manifest, split_and_save
+    from inferd_tpu.runtime import wire
+    from inferd_tpu.runtime.node import Node, NodeInfo
+
+    if os.environ.get("INFERD_LOCKWATCH", "").strip().lower() in (
+        "0", "off", "false", "no"
+    ):
+        pytest.skip("lockwatch killed via INFERD_LOCKWATCH")
+    assert lockwatch.watching() and lockwatch.strict()
+    host, port = "127.0.0.1", 20700
+    split_and_save(qwen3.init_params(TINY, jax.random.PRNGKey(0)), TINY,
+                   Manifest.even_split("tiny", 1), str(tmp_path / "parts"))
+    info = NodeInfo(name="lw", host=host, port=port, stage=0, num_stages=1,
+                    capacity=8, model_name="tiny")
+    dht = SwarmDHT(info.node_id, port + 200, bootstrap=[], host=host,
+                   gossip_period_s=0.05, ttl_s=5.0)
+    node = Node(info, TINY, str(tmp_path / "parts"), dht, backend="qwen3", max_len=64,
+                rebalance_period_s=600.0, batch_lanes=2, enable_profiling=True)
+    node.profiler.base_dir = str(tmp_path / "profiles")
+    before = lockwatch.stats()
+    await node.start()
+    try:
+        ex = node.executor
+        assert isinstance(ex._dev_lock, WatchedLock) and isinstance(ex._mu, WatchedLock)
+        async with aiohttp.ClientSession() as http:
+            body = wire.pack({"action": "window", "seconds": 30.0, "capture_id": "lw"})
+            async with http.post(f"http://{host}:{port}/profile", data=body) as r:
+                assert r.status == 200
+        assert node.profiler.active_dir is not None  # the window is open
+        async with SwarmClient([(host, port)], sampling=SamplingConfig(temperature=0.0)) as c:
+            out = await asyncio.gather(*(
+                c.generate_server_side_stream(p, lambda t: None, 12)
+                for p in ([3, 7, 11, 19], [5, 13, 17, 41])
+            ))
+        assert all(len(o) == 12 for o in out)
+        assert node.profiler.active_dir is not None  # and stayed open throughout
+    finally:
+        await node.stop()
+    assert node.profiler.active_dir is None
+    after = lockwatch.stats()
+    assert after["checks"] > before["checks"]
+    assert after["inversions"] == before["inversions"]

@@ -382,6 +382,26 @@ def test_health_cli_check_fails_on_breach(tmp_path):
     assert main(["health", "--check", str(tmp_path)]) == 0
 
 
+def test_prefixed_signals_no_engine_knows_are_skipped_never_green(tmp_path, capsys):
+    """A rules file written for an older build may still name `roofline:`
+    / `phase:` signals. They resolve as every unknown metric does: the
+    rule is skipped and counted as skipped, whatever gauges the scrape
+    carries under similar names, and a file of nothing else fails
+    `--check` (zero evaluated is not a pass)."""
+    from inferd_tpu.obs.__main__ import main
+
+    (tmp_path / "n.stats.json").write_text(json.dumps({"gauges": {
+        "roofline.frac": 0.5, "roofline.live_frac": 0.4, "queue.depth": 0,
+    }}))
+    gone = ["roofline:frac > 0.02", "phase:attn/frac > 0.1", "roofline:live_frac > 0.1"]
+    (tmp_path / "rules.json").write_text(json.dumps(gone + ["queue.depth < 16"]))
+    assert main(["health", "--check", str(tmp_path)]) == 0
+    assert "(0 firing, 1 evaluated, 3 skipped)" in capsys.readouterr().out
+    (tmp_path / "rules.json").write_text(json.dumps(gone))
+    assert main(["health", "--check", str(tmp_path)]) == 1
+    assert "(0 firing, 0 evaluated, 3 skipped)" in capsys.readouterr().out
+
+
 # ---------------------------------------------------- /metrics byte parity
 
 
@@ -664,8 +684,7 @@ def test_default_rules_survive_event_kill_switch():
     )
     assert v["evaluated"] == 3  # queue.depth, trace.dropped, hop p99
     # every event rule (events=None), every burn rule (histories=None),
-    # every peer rule (no peers passed), plus the absent hbm.frac and
-    # perf.regression gauges
-    assert v["skipped"] == n_event + n_burn + n_peer + 2
+    # every peer rule (no peers passed), plus the absent hbm.frac gauge
+    assert v["skipped"] == n_event + n_burn + n_peer + 1
     assert {f["rule"] for f in v["firing"]} == {"queue.depth < 16"}
     assert v["status"] == "degraded"

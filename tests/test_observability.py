@@ -206,3 +206,25 @@ def test_dashboard_independent_hop_cells_and_outlier_marker():
     # non-flagged rows collapse the empty out cell (next token is the
     # cobatch "-"), never a stray marker
     assert "!" not in rows["oldpeer"]
+
+
+# a peer of an older build still gossips `roofline` / `perf`: both are
+# keys this build does not know, and are shown as any unknown key is
+_OLDER = {"name": "older", "load": 1, "cap": 4, "hop_p50_ms": 4.0,
+          "hop_p99_ms": 40.0, "health": "ok"}
+_OLDER_GOSSIPS = dict(_OLDER, roofline=0.05, perf=1)
+
+
+def test_collector_ignores_an_older_peers_roofline_and_perf():
+    with_keys = stage_rows({0: {"10.0.0.2:6050": _OLDER_GOSSIPS}}, ts=1.0)
+    without = stage_rows({0: {"10.0.0.2:6050": _OLDER}}, ts=1.0)
+    assert with_keys == without
+    assert set(with_keys[0]) == set(FIELDS)
+    assert not {"roofline_worst", "perf"} & set(FIELDS)
+
+
+def test_dashboard_ignores_an_older_peers_roofline_and_perf():
+    with_keys = render_table({0: {"10.0.0.2:6050": _OLDER_GOSSIPS}}, ts=0.0)
+    without = render_table({0: {"10.0.0.2:6050": _OLDER}}, ts=0.0)
+    assert with_keys == without
+    assert "roof%" not in with_keys and "!perf" not in with_keys

@@ -1,5 +1,5 @@
 """The perf subsystem (inferd_tpu/perf/): roofline cost model, autotune
-registry + dispatch integration, step-anatomy profiler, regression gate,
+registry + dispatch integration, regression gate,
 and the round-6 sampling fast path.
 
 Hand-computed roofline expectations are derived INDEPENDENTLY here (byte
@@ -18,7 +18,7 @@ import pytest
 
 from inferd_tpu.config import PRESETS, SamplingConfig, get_config
 from inferd_tpu.core import sampling as samplib
-from inferd_tpu.perf import anatomy, autotune, gate as gatelib, roofline as rl
+from inferd_tpu.perf import autotune, gate as gatelib, roofline as rl
 from inferd_tpu.perf.__main__ import main as perf_main
 
 R05 = gatelib.DEFAULT_ARTIFACT
@@ -324,45 +324,6 @@ def test_sweep_attn_populates_registry(reg_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# anatomy
-# ---------------------------------------------------------------------------
-
-
-def test_anatomy_phases_sum_to_whole_step():
-    out = anatomy.profile_step(
-        get_config("tiny"), ctx=64, pairs=2, short=3, long_=9
-    )
-    assert set(out["phases"]) == set(anatomy.PHASES)
-    for name, p in out["phases"].items():
-        if name == "dispatch":
-            # host-loop overhead delta: clamped at 0 (can measure ~0 on a
-            # fast local device), with the raw host-loop rate alongside
-            assert p["ms"] >= 0 and p["hostloop_step_ms"] > 0
-            continue
-        assert p["ms"] > 0, name
-        assert p["roofline_ms"] <= p["ms"] * 50  # sane attribution scale
-    assert out["step_ms"] > 0
-    # separately-jitted phases lose cross-phase fusion, so demand the sum
-    # lands within a loose band of the fused step, not equality
-    ratio = out["phase_sum_ms"] / out["step_ms"]
-    assert 0.2 <= ratio <= 5.0, out
-    assert out["unattributed_ms"] == pytest.approx(
-        out["step_ms"] - out["phase_sum_ms"], abs=1e-6
-    )
-
-
-def test_anatomy_cli_emits_one_json_line(capsys):
-    rc = perf_main([
-        "anatomy", "--preset", "tiny", "--ctx", "32", "--pairs", "2",
-        "--device", "cpu",
-    ])
-    assert rc == 0
-    last = capsys.readouterr().out.strip().splitlines()[-1]
-    obj = json.loads(last)
-    assert obj["preset"] == "tiny" and "phases" in obj
-
-
-# ---------------------------------------------------------------------------
 # gate
 # ---------------------------------------------------------------------------
 
@@ -657,33 +618,30 @@ def test_battery_has_round6_legs():
     from inferd_tpu.tools.bench_battery import DEFAULT_LEGS, SMOKE_LEGS
 
     names = {n for n, _, _ in DEFAULT_LEGS}
-    assert {"decode_8b_int8", "anatomy", "anatomy_ctx8k"} <= names
+    assert "decode_8b_int8" in names
     tail = dict((n, t) for n, t, _ in DEFAULT_LEGS)["decode_8b_int8"]
     assert "--model" in tail and "qwen3-8b" in tail and "int8" in tail
-    assert {"decode_tiny_int8", "anatomy_tiny"} <= {n for n, _, _ in SMOKE_LEGS}
+    assert "decode_tiny_int8" in {n for n, _, _ in SMOKE_LEGS}
 
 
 @pytest.mark.slow
-def test_battery_smoke_runs_int8_and_anatomy_legs(tmp_path):
-    """Dryrun the two new battery legs end to end on CPU: the artifact
-    lines must carry an int8 decode result and an anatomy phase table."""
+def test_battery_smoke_runs_int8_leg(tmp_path):
+    """Dryrun the int8 battery leg end to end on CPU: the artifact line
+    must carry an int8 decode result."""
     from inferd_tpu.tools.bench_battery import main
 
     out = tmp_path / "smoke.jsonl"
-    rc = main(["--smoke", "--legs", "decode_tiny_int8,anatomy_tiny",
-               "--out", str(out)])
+    rc = main(["--smoke", "--legs", "decode_tiny_int8", "--out", str(out)])
     assert rc == 0
     lines = [json.loads(l) for l in out.read_text().splitlines()]
     by_leg = {l["leg"]: l for l in lines}
     dec = by_leg["decode_tiny_int8"]["result"]
     assert dec["metric"].endswith("_int8") and dec["quant"] == "int8"
     assert dec["timing_methodology"] == "interleaved-paired"
-    ana = by_leg["anatomy_tiny"]["result"]
-    assert set(ana["phases"]) == set(anatomy.PHASES)
 
 
 # ---------------------------------------------------------------------------
-# round 7: multi-step fused decode evidence (gate + anatomy + battery)
+# round 7: multi-step fused decode evidence (gate + battery)
 # ---------------------------------------------------------------------------
 
 MULTISTEP_ARTIFACT = os.path.join(
@@ -813,37 +771,15 @@ def test_gate_passes_committed_multistep_artifact():
     )
 
 
-def test_anatomy_dispatch_phase_subset():
-    """--phases dispatch isolates the host-loop overhead phase: the fused
-    step is still timed (it anchors the delta), device phases are
-    skipped, and the dispatch entry carries the host-loop rate."""
-    out = anatomy.profile_step(
-        get_config("tiny"), ctx=32, pairs=2, short=3, long_=6,
-        phases=("dispatch",),
-    )
-    assert set(out["phases"]) == {"dispatch"}
-    d = out["phases"]["dispatch"]
-    assert d["ms"] >= 0 and d["hostloop_step_ms"] > 0 and d["bytes"] == 0
-    assert out["step_ms"] > 0
-    # an incomplete device-phase set must not misreport the whole step as
-    # unattributed residual: the reconciliation fields go null
-    assert out["phase_sum_ms"] is None
-    assert out["unattributed_ms"] is None
-    with pytest.raises(ValueError, match="unknown anatomy phases"):
-        anatomy.profile_step(get_config("tiny"), phases=("nope",))
-
-
 def test_battery_has_round7_legs():
     from inferd_tpu.tools.bench_battery import DEFAULT_LEGS, SMOKE_LEGS
 
     names = {n for n, _, _ in DEFAULT_LEGS}
-    assert {"decode_multistep", "anatomy_dispatch"} <= names
+    assert "decode_multistep" in names
     smoke = dict((n, t) for n, t, _ in SMOKE_LEGS)
     assert "decode_multistep_tiny" in smoke
     assert "--config" in smoke["decode_multistep_tiny"]
     assert "decode-multistep" in smoke["decode_multistep_tiny"]
-    assert "anatomy_dispatch_tiny" in smoke
-    assert "dispatch" in smoke["anatomy_dispatch_tiny"]
 
 
 # ---------------------------------------------------------------------------
