@@ -15,6 +15,10 @@ from typing import Optional
 import jax.numpy as jnp
 
 
+# the kinds of cfg.layer_types that hold a recurrent state and no keys and values
+STATE_KINDS = ("mamba", "delta")
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters for a Qwen3-family causal LM."""
@@ -192,6 +196,16 @@ class ModelConfig:
     #                    Its attention_multiplier is `attn_scale`:
     #                    query_pre_attn_scalar 4096 gives the published 1/64
     #   position_embedding — "rope", or "nope": no rotation at all
+    #   linear_*       — the other state kind, "delta": a Gated-DeltaNet mixer
+    #                    (the Qwen3-Next family). `linear_key_heads` key heads
+    #                    and `linear_value_heads` value heads (value head h
+    #                    reads key head h // their ratio) of
+    #                    `linear_key_head_dim` / `linear_value_head_dim`, a
+    #                    float state of key x value a value head, q | k | v
+    #                    through a depthwise causal convolution of
+    #                    `linear_conv` taps without bias, the chunked form
+    #                    tiled by `linear_chunk_size`. A model has ONE state
+    #                    kind (`state_kind`)
     layer_types: tuple = ()
     mamba_heads: int = 0
     mamba_head_dim: int = 0
@@ -205,6 +219,12 @@ class ModelConfig:
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
     position_embedding: str = "rope"
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv: int = 4
+    linear_chunk_size: int = 64
 
     # Windowed and full layers by a list, and one chip's share of the experts
     # (the afmoe family; all absent elsewhere):
@@ -228,13 +248,22 @@ class ModelConfig:
     router_experts: int = 0
     expert_offset: int = 0
 
+    # The Qwen3-Next family's two further knobs (absent elsewhere):
+    #   partial_rotary_factor — rope turns the first head_dim x this many
+    #                    dimensions of a head (`rope_dim`), the rest pass
+    #   shared_expert_gate — the shared expert's output is multiplied by
+    #                    sigmoid(x . w), w (p["shared_expert_gate"]) a vector
+    #                    of hidden_size
+    partial_rotary_factor: float = 1.0
+    shared_expert_gate: bool = False
+
     def __post_init__(self):
         if self.layer_types:
-            odd = set(self.layer_types) - {"mamba", "attention", "sliding", "global"}
+            odd = set(self.layer_types) - set(STATE_KINDS) - {"attention", "sliding", "global"}
             if odd or (self.has_state_layers and self.num_layers % len(self.layer_types)):
                 raise ValueError(
-                    f"{self.name}: layer_types is one period of 'mamba' / 'attention' / "
-                    f"'sliding' / 'global' (with a state layer it divides num_layers "
+                    f"{self.name}: layer_types is one period of 'mamba' / 'delta' / 'attention' "
+                    f"/ 'sliding' / 'global' (with a state layer it divides num_layers "
                     f"{self.num_layers}); got {self.layer_types}"
                 )
             if ("sliding" in self.layer_types) != (self.sliding_window > 0):
@@ -242,13 +271,30 @@ class ModelConfig:
                     f"{self.name}: 'sliding' layers and a sliding_window come together"
                 )
             if self.has_state_layers and (
+                len(set(self.layer_types) & set(STATE_KINDS)) > 1 or self.is_mla
+                or self.sliding_window or self.is_block_diffusion or self.sandwich_norm
+                or self.first_k_dense_replace
+            ):
+                raise ValueError(
+                    f"{self.name}: state layers are of ONE kind, beside global GQA layers, "
+                    "every layer with the same feed-forward (dense, or routed experts)"
+                )
+            if self.state_kind == "mamba" and (
                 self.mamba_heads * self.mamba_head_dim != self.mamba_expand * self.hidden_size
                 or self.mamba_heads % self.mamba_groups or self.mamba_state <= 0
-                or self.is_mla or self.sliding_window or self.is_moe or self.is_block_diffusion
             ):
                 raise ValueError(
                     f"{self.name}: a Mamba-2 layer has mamba_heads x mamba_head_dim = "
-                    "mamba_expand x hidden_size, beside global GQA layers and a dense MLP"
+                    "mamba_expand x hidden_size, its heads in whole groups, and a state"
+                )
+            if self.state_kind == "delta" and (
+                min(self.linear_key_heads, self.linear_key_head_dim,
+                    self.linear_value_head_dim) <= 0
+                or self.linear_value_heads % self.linear_key_heads
+            ):
+                raise ValueError(
+                    f"{self.name}: a Gated-DeltaNet layer has linear_value_heads a whole "
+                    "multiple of linear_key_heads, and both head sizes"
                 )
         if self.position_embedding not in ("rope", "nope"):
             raise ValueError(f"{self.name}: unknown position_embedding {self.position_embedding!r}")
@@ -292,14 +338,36 @@ class ModelConfig:
     @property
     def has_state_layers(self) -> bool:
         """Some layer holds a recurrent state and not keys and values."""
-        return "mamba" in self.layer_types
+        return self.state_kind is not None
+
+    @property
+    def state_kind(self) -> Optional[str]:
+        """The kind of the model's state layers, "mamba" or "delta"; None: it has none."""
+        return next((k for k in STATE_KINDS if k in self.layer_types), None)
+
+    @property
+    def state_shape(self) -> tuple:
+        """What a session's recurrent state is in ONE state layer, after the
+        lane axis: Mamba-2 [heads, head_dim, state]; the delta rule
+        [value heads, key_dim, value_dim]."""
+        if self.state_kind == "delta":
+            return (self.linear_value_heads, self.linear_key_head_dim, self.linear_value_head_dim)
+        return (self.mamba_heads, self.mamba_head_dim, self.mamba_state)
+
+    @property
+    def state_conv_shape(self) -> tuple:
+        """The convolution's kept inputs of one state layer, after the lane
+        axis: its last taps - 1 inputs of every channel."""
+        if self.state_kind == "delta":
+            return (self.linear_conv - 1, self.linear_conv_dim)
+        return (self.mamba_conv - 1, self.mamba_conv_dim)
 
     @property
     def layer_pattern(self) -> tuple:
         """The kinds of layer, one period of them: GLOBAL layer i has kind
         layer_pattern[i % len(layer_pattern)]. "sliding" attends within
         `sliding_window`, "global" (or "attention") over everything before
-        it, "mamba" carries a recurrent state (`layer_types`, where given; a
+        it, "mamba" or "delta" carries a recurrent state (`layer_types`, where given; a
         model with a `sliding_window` and no list alternates, windowed first).
         The one place that says which layers are which (the scan of
         models/qwen3.forward_layers, the storage of core/cache)."""
@@ -329,6 +397,30 @@ class ModelConfig:
         return self.mamba_inner + 2 * self.mamba_groups * self.mamba_state
 
     @property
+    def full_attention_interval(self) -> int:
+        """As a `qwen3_next` config names the period: layer i is full
+        attention iff (i + 1) % this == 0, every other a state layer."""
+        return len(self.layer_pattern)
+
+    @property
+    def shared_expert_intermediate_size(self) -> int:
+        """The shared expert's width, as a published config names it."""
+        return self.n_shared_experts * self.moe_intermediate_size
+
+    @property
+    def linear_key_dim(self) -> int:
+        return self.linear_key_heads * self.linear_key_head_dim
+
+    @property
+    def linear_value_dim(self) -> int:
+        return self.linear_value_heads * self.linear_value_head_dim
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels through the delta rule's convolution: q, k, then v."""
+        return 2 * self.linear_key_dim + self.linear_value_dim
+
+    @property
     def router_width(self) -> int:
         """Outputs of the router: every routed expert, held here or not."""
         return self.router_experts or self.num_experts
@@ -340,8 +432,10 @@ class ModelConfig:
 
     @property
     def rope_dim(self) -> int:
-        """Dimensions the rotary embedding turns."""
-        return self.qk_rope_head_dim if self.is_mla else self.head_dim
+        """Dimensions the rotary embedding turns: the first of a head's."""
+        if self.is_mla:
+            return self.qk_rope_head_dim
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def q_dim(self) -> int:
@@ -809,6 +903,55 @@ TRINITY_LARGE_EP8_5L = dataclasses.replace(
     first_k_dense_replace=1, num_experts=256 // 8, router_experts=256,
 )
 
+# Qwen3-Next-80B-A3B-Instruct (Qwen/Qwen3-Next-80B-A3B-Instruct config.json,
+# `qwen3_next`): 48 layers, three Gated-DeltaNet layers (16 key / 32 value
+# heads of 128, a convolution of 4 taps) to one gated full-attention layer
+# (16 query / 2 kv heads of 256, rope on the first 64 dimensions), every
+# RMSNorm but the delta rule's gated one scaling by 1 + w, every layer with
+# 512 softmax-routed experts (top 10, width 512) beside one gated shared
+# expert. Its multi-token-prediction module is left out (generation does not
+# run it). The -ep4-8l preset is ONE chip's share of a four-chip
+# expert-parallel group over the first eight layers (two whole periods):
+# experts 0..127 of the router's 512, vocabulary ids 0..37 983 of 151 936,
+# every width as published (benchmark/configs/qwen3-next-80b-ep4-1chip.json
+# has the arithmetic).
+QWEN3_NEXT_80B_A3B = ModelConfig(
+    name="qwen3-next-80b-a3b",
+    vocab_size=151936,
+    hidden_size=2048,
+    intermediate_size=5120,
+    num_layers=48,
+    num_heads=16,
+    num_kv_heads=2,
+    head_dim=256,
+    rms_norm_eps=1e-6,
+    rope_theta=10_000_000.0,
+    max_position_embeddings=262144,
+    tie_word_embeddings=False,
+    qk_norm=True,
+    rms_norm_plus_one=True,
+    partial_rotary_factor=0.25,
+    attn_gate=True,
+    layer_types=("delta", "delta", "delta", "attention"),
+    linear_key_heads=16,
+    linear_value_heads=32,
+    linear_key_head_dim=128,
+    linear_value_head_dim=128,
+    linear_conv=4,
+    linear_chunk_size=64,
+    num_experts=512,
+    num_experts_per_tok=10,
+    moe_intermediate_size=512,
+    norm_topk_prob=True,
+    n_shared_experts=1,
+    shared_expert_gate=True,
+)
+
+QWEN3_NEXT_80B_EP4_8L = dataclasses.replace(
+    QWEN3_NEXT_80B_A3B.with_layers(8), name="qwen3-next-80b-ep4-8l", vocab_size=151936 // 4,
+    num_experts=512 // 4, router_experts=512,
+)
+
 # Synthetic mid-size config for the default bench's paired pipeline leg
 # (bench.py): big enough that a decode step's compute dominates the
 # inter-stage hop (the regime the north-star ratio grades), small enough
@@ -915,6 +1058,19 @@ TINY_AFMOE = dataclasses.replace(
     n_shared_experts=1, first_k_dense_replace=1,
 )
 
+# tiny-qwen3-next: the Qwen3-Next layer at toy widths: two periods of three
+# delta-rule layers and a gated full one whose rope turns 8 of its 32
+# dimensions, 16 experts top 4 beside a gated shared one.
+TINY_QWEN3_NEXT = dataclasses.replace(
+    TINY, name="tiny-qwen3-next", num_layers=8, tie_word_embeddings=False, head_dim=32,
+    num_kv_heads=2, rope_theta=10_000.0, rms_norm_plus_one=True, partial_rotary_factor=0.25,
+    attn_gate=True, layer_types=("delta", "delta", "delta", "attention"),
+    linear_key_heads=2, linear_value_heads=4, linear_key_head_dim=16, linear_value_head_dim=16,
+    linear_conv=4, linear_chunk_size=8,
+    num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32, norm_topk_prob=True,
+    n_shared_experts=1, shared_expert_gate=True,
+)
+
 PRESETS = {
     c.name: c
     for c in [
@@ -943,6 +1099,8 @@ PRESETS = {
         GRANITE_4_H_MICRO,
         TRINITY_LARGE,
         TRINITY_LARGE_EP8_5L,
+        QWEN3_NEXT_80B_A3B,
+        QWEN3_NEXT_80B_EP4_8L,
         BENCH_PIPE,
         TINY,
         TINY_MOE,
@@ -954,6 +1112,7 @@ PRESETS = {
         TINY_DSV2,
         TINY_GRANITE_H,
         TINY_AFMOE,
+        TINY_QWEN3_NEXT,
     ]
 }
 
@@ -979,6 +1138,7 @@ HF_REPOS = {
     "gpt-oss-120b": "openai/gpt-oss-120b",
     "deepseek-v2-lite": "deepseek-ai/DeepSeek-V2-Lite",
     "granite-4.0-h-micro": "ibm-granite/granite-4.0-h-micro",
+    "qwen3-next-80b-a3b": "Qwen/Qwen3-Next-80B-A3B-Instruct",
 }
 
 

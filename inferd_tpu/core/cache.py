@@ -59,19 +59,29 @@ from inferd_tpu.config import ModelConfig
 RING_MARGIN = 64
 
 
-# The minor dimension of the chip's (8, 128) tile. A K/V array whose last axis
-# is narrower pads every row to it, and the compiler then re-lays the whole
+# The chip's (8, 128) tile. A K/V array whose last axis is narrower than its
+# minor dimension pads every row to it, and the compiler then re-lays the whole
 # stack between the layout its scatter of rows wants and the one its dots want.
+# A head WIDER than that dimension among fewer kv heads than the tile has
+# sublanes is stored unpadded ([.., 2, 256] compiles to T(2,128) tiles), but
+# the step's dots then read each layer's slab through a T-minor copy of it (for
+# a described v5e: 0.54 GB of temporaries at 16 x 32 768 x 2 x 256, a slab
+# written once more a layer and step; as rows 0.013 GB).
 TILE_LANES = 128
+TILE_SUBLANES = 8
 
 
 def rows_layout(cfg: ModelConfig) -> bool:
     """Do uniform dense lanes of this model store a token's keys (values) of
     ALL kv heads as one row [.., Nkv * D] (`RowEntry`) and not as [.., Nkv, D]
-    (`DenseEntry`)? Where a head is narrower than a tile: the row is the
-    shape that is written and read where it lies. Rings and paged pools keep
-    heads; a latent cache has none."""
-    return not cfg.is_mla and cfg.head_dim < TILE_LANES
+    (`DenseEntry`)? Where a head is narrower than a tile, and where it is
+    wider than one among fewer kv heads than a tile has sublanes: the row is
+    the shape that is written and read where it lies. Rings and paged pools
+    keep heads; a latent cache has none."""
+    if cfg.is_mla:
+        return False
+    return cfg.head_dim < TILE_LANES or (
+        cfg.head_dim > TILE_LANES and cfg.num_kv_heads < TILE_SUBLANES)
 
 
 def lane_shape(cfg: ModelConfig) -> Tuple[int, ...]:
@@ -175,7 +185,8 @@ class StateEntry:
     whatever the session's length and not indexed by position: it cannot be
     truncated, rolled back or cut at a prefix."""
 
-    s: jax.Array  # [B, heads, P, N] in cfg.state_dtype: the recurrent state
+    s: jax.Array  # [B, *cfg.state_shape] in cfg.state_dtype: the recurrent state (Mamba-2
+    #   [heads, P, N]; the delta rule [value heads, Dk, Dv])
     conv: jax.Array  # [B, K-1, conv_dim]: the last inputs of the causal convolution
 
 
@@ -206,8 +217,8 @@ class KVCache:
     k_loc: Optional[jax.Array] = None  # [Ll, B, R, Nkv, D] sliding-layer rings
     v_loc: Optional[jax.Array] = None
     # a model with state-space layers (cfg.has_state_layers): k and v hold
-    # its ATTENTION layers only, these its Mamba layers' StateEntry stack
-    s: Optional[jax.Array] = None  # [Lm, B, heads, P, N] in cfg.state_dtype
+    # its ATTENTION layers only, these its state layers' StateEntry stack
+    s: Optional[jax.Array] = None  # [Lm, B, *cfg.state_shape] in cfg.state_dtype
     conv: Optional[jax.Array] = None  # [Lm, B, K-1, conv_dim] in the model's dtype
 
     @property
@@ -247,13 +258,8 @@ class KVCache:
             shape = (la, batch, max_len, *lane)
             return KVCache(
                 k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt), length=jnp.int32(0),
-                s=jnp.zeros(
-                    (lm, batch, cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state),
-                    jnp.dtype(cfg.state_dtype),
-                ),
-                conv=jnp.zeros(
-                    (lm, batch, cfg.mamba_conv - 1, cfg.mamba_conv_dim), cfg.jnp_dtype
-                ),
+                s=jnp.zeros((lm, batch, *cfg.state_shape), jnp.dtype(cfg.state_dtype)),
+                conv=jnp.zeros((lm, batch, *cfg.state_conv_shape), cfg.jnp_dtype),
             )
         if cfg.is_mla:
             # a latent cache: per token and layer the normed latent (`k`)
@@ -320,7 +326,7 @@ class KVCache:
         if self.s is None and self.k_loc is None:
             return (glob,)
         by_kind = {
-            "mamba": None if self.s is None else StateEntry(s=self.s, conv=self.conv),
+            cfg.state_kind: None if self.s is None else StateEntry(s=self.s, conv=self.conv),
             "sliding": None if self.k_loc is None else RingEntry(
                 k=self.k_loc, v=self.v_loc, window=int(cfg.sliding_window)),
         }

@@ -74,6 +74,8 @@ def params_from_hf_state_dict(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params
     """
     if cfg.is_mla:
         return _params_from_deepseek_v2(cfg, sd)
+    if cfg.state_kind == "delta":
+        return _params_from_qwen3_next(cfg, sd)
     if cfg.has_state_layers:
         return _params_from_granite_hybrid(cfg, sd)
     if cfg.moe_router_mode == "sigmoid_topk":
@@ -378,6 +380,88 @@ def _params_from_granite_hybrid(cfg: ModelConfig, sd: Mapping[str, Any]) -> Para
     if not cfg.tie_word_embeddings:
         params["lm_head"] = jnp.asarray(w("lm_head"), dtype=dt)
     return params
+
+
+def _params_from_qwen3_next(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:
+    """HF `qwen3_next` names -> `layers` for the full-attention kind,
+    `state_layers` for the Gated-DeltaNet kind, each in layer order, every
+    layer with its router, experts, shared expert and that one's gate.
+
+    What the checkpoint fuses is taken apart here. `linear_attn.in_proj_qkvz`
+    [2 Kd + 2 Vd, H] lies key head after key head, each as [q | k | v | z] of
+    its own value heads: it becomes `in_proj` [H, q | k | v | z], every part
+    head after head (value head h reads key head h // (Hv / Hk) in both).
+    `linear_attn.in_proj_ba` likewise becomes `ba_proj` [H, b | a].
+    `self_attn.q_proj` [2 Nq D, H] lies head after head as [query | gate]: it
+    becomes `q_proj` and `attn_gate_proj`. `linear_attn.conv1d.weight`
+    [C, 1, K] becomes the taps [K, C]; `mlp.shared_expert_gate.weight` [1, H]
+    the vector `shared_expert_gate`. A preset that holds a share reads the
+    experts cfg.expert_offset .. + cfg.num_experts of each layer, the router
+    whole, and the first cfg.vocab_size rows of the vocabulary. The
+    multi-token-prediction module (`mtp.*`) is not read."""
+    dt = cfg.jnp_dtype
+    held = range(cfg.expert_offset, cfg.expert_offset + cfg.num_experts)
+    hk, hv, dk, dv = (cfg.linear_key_heads, cfg.linear_value_heads, cfg.linear_key_head_dim,
+                      cfg.linear_value_head_dim)
+    r = hv // hk
+
+    def raw(name: str) -> np.ndarray:
+        return _to_np(sd[name if name in sd else f"model.{name}"])
+
+    def w(name: str) -> np.ndarray:  # a linear weight as [in, out]
+        return raw(f"{name}.weight").T
+
+    def by_group(fused: np.ndarray, widths) -> list:
+        """[H, Hk x sum(widths)], key head after key head -> one [H, Hk x width] a part."""
+        per = fused.reshape(fused.shape[0], hk, sum(widths))
+        cuts = np.cumsum([0, *widths])
+        return [per[:, :, lo:hi].reshape(fused.shape[0], -1) for lo, hi in zip(cuts, cuts[1:])]
+
+    def layer(i: int, kind: str) -> Params:
+        mlp = f"layers.{i}.mlp"
+        out = {
+            "input_norm": raw(f"layers.{i}.input_layernorm.weight"),
+            "post_norm": raw(f"layers.{i}.post_attention_layernorm.weight"),
+            "router": w(f"{mlp}.gate"),
+            "shared_expert_gate": raw(f"{mlp}.shared_expert_gate.weight")[0],
+        }
+        for proj in ("gate_proj", "up_proj", "down_proj"):
+            out[proj] = np.stack([w(f"{mlp}.experts.{e}.{proj}") for e in held])
+            out[f"shared_{proj}"] = w(f"{mlp}.shared_expert.{proj}")
+        if kind == "attention":
+            at = f"layers.{i}.self_attn"
+            q_gate = w(f"{at}.q_proj").reshape(cfg.hidden_size, cfg.num_heads, 2, cfg.head_dim)
+            out.update(
+                q_proj=q_gate[:, :, 0].reshape(cfg.hidden_size, -1),
+                attn_gate_proj=q_gate[:, :, 1].reshape(cfg.hidden_size, -1),
+                q_norm=raw(f"{at}.q_norm.weight"), k_norm=raw(f"{at}.k_norm.weight"),
+                **{proj: w(f"{at}.{proj}") for proj in ("k_proj", "v_proj", "o_proj")},
+            )
+            return out
+        m = f"layers.{i}.linear_attn"
+        out.update(
+            in_proj=np.concatenate(
+                by_group(w(f"{m}.in_proj_qkvz"), (dk, dk, r * dv, r * dv)), axis=1),
+            ba_proj=np.concatenate(by_group(w(f"{m}.in_proj_ba"), (r, r)), axis=1),
+            conv_w=raw(f"{m}.conv1d.weight")[:, 0, :].T,
+            dt_bias=raw(f"{m}.dt_bias"), A_log=raw(f"{m}.A_log"),
+            gate_norm=raw(f"{m}.norm.weight"), out_proj=w(f"{m}.out_proj"),
+        )
+        return out
+
+    def group(kind: str) -> Params:
+        per_layer = [layer(i, kind) for i, k in enumerate(cfg.layer_type_names) if k == kind]
+        return {k: jnp.asarray(np.stack([lp[k] for lp in per_layer]), dtype=dt)
+                for k in per_layer[0]}
+
+    v = cfg.vocab_size
+    return {
+        "embed": jnp.asarray(raw("embed_tokens.weight")[:v], dtype=dt),
+        "layers": group("attention"),
+        "state_layers": group("delta"),
+        "final_norm": jnp.asarray(raw("norm.weight"), dtype=dt),
+        "lm_head": jnp.asarray(w("lm_head")[:, :v], dtype=dt),
+    }
 
 
 # ---------------------------------------------------------------------------

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-from inferd_tpu.config import ModelConfig, yarn_mscale
+from inferd_tpu.config import STATE_KINDS, ModelConfig, yarn_mscale
 from inferd_tpu.core import cache as cachelib
 from inferd_tpu.ops import attention as attention_ops
 from inferd_tpu.ops import lora as lora_ops
@@ -45,6 +45,46 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 
+def _init_ffn_params(cfg: ModelConfig, w, n: int, dense: bool, ks, key) -> Params:
+    """A layer stack's feed-forward leaves: the dense MLP, or (a model with
+    experts, not `dense`) the router, the held experts and the shared one.
+    `w(key, *shape)` draws a stacked projection, `ks` four keys (router, gate,
+    up, down); what else is drawn folds `key`."""
+    h, i, dt = cfg.hidden_size, cfg.intermediate_size, cfg.jnp_dtype
+    if not (cfg.is_moe and not dense):
+        return {"gate_proj": w(ks[1], h, i), "up_proj": w(ks[2], h, i),
+                "down_proj": w(ks[3], i, h)}
+    p = {}
+    e, mi = cfg.num_experts, cfg.moe_intermediate_size  # the experts HELD here
+    p["router"] = w(ks[0], h, cfg.router_width)
+    if cfg.moe_router_mode == "sigmoid_topk":
+        # drawn so that the router's logits have unit variance whatever
+        # the width (the scores spread over (0, 1)), and a selection bias
+        # small and non-zero: of 256 experts' top 4 it changes the choice
+        # of about half the tokens and leaves the load near even (a
+        # deviation of 0.1 sends a third of the rows to one expert)
+        p["router"] = (p["router"].astype(jnp.float32) * (50.0 / math.sqrt(h))).astype(dt)
+        p["router_select_bias"] = 0.01 * jax.random.normal(
+            jax.random.fold_in(key, 14), (n, cfg.router_width), dtype=jnp.float32)
+    p["gate_proj"] = w(ks[1], e, h, mi)
+    p["up_proj"] = w(ks[2], e, h, mi)
+    p["down_proj"] = w(ks[3], e, mi, h)
+    if cfg.router_bias:
+        p["router_bias"] = jnp.zeros((n, e), dtype=dt)
+    if cfg.moe_bias:
+        p["gate_bias"] = jnp.zeros((n, e, mi), dtype=dt)
+        p["up_bias"] = jnp.zeros((n, e, mi), dtype=dt)
+        p["down_bias"] = jnp.zeros((n, e, h), dtype=dt)
+    if cfg.n_shared_experts:
+        si = cfg.n_shared_experts * mi
+        p["shared_gate_proj"] = w(jax.random.fold_in(key, 10), h, si)
+        p["shared_up_proj"] = w(jax.random.fold_in(key, 11), h, si)
+        p["shared_down_proj"] = w(jax.random.fold_in(key, 12), si, h)
+        if cfg.shared_expert_gate:  # Qwen3-Next: the shared expert's own gate, a vector
+            p["shared_expert_gate"] = w(jax.random.fold_in(key, 15), h)
+    return p
+
+
 def init_layer_params(
     cfg: ModelConfig, key: jax.Array, num_layers: Optional[int] = None,
     dense: bool = False,
@@ -52,7 +92,7 @@ def init_layer_params(
     """Stacked decoder-layer params: every leaf has leading dim `num_layers`.
     `dense` gives a model with experts its leading dense-MLP layers."""
     n = cfg.num_layers if num_layers is None else num_layers
-    h, q, kv, d, i = cfg.hidden_size, cfg.q_dim, cfg.kv_dim, cfg.head_dim, cfg.intermediate_size
+    h, q, kv, d = cfg.hidden_size, cfg.q_dim, cfg.kv_dim, cfg.head_dim
     dt = cfg.jnp_dtype
     ks = jax.random.split(key, 8)
 
@@ -74,8 +114,8 @@ def init_layer_params(
         p["pre_ffn_norm"] = norm1((n, h), dtype=dt)
         p["post_ffn_norm"] = norm1((n, h), dtype=dt)
     if cfg.qk_norm:  # Qwen3's per-head q/k RMSNorm
-        p["q_norm"] = jnp.ones((n, d), dtype=dt)
-        p["k_norm"] = jnp.ones((n, d), dtype=dt)
+        p["q_norm"] = norm1((n, d), dtype=dt)
+        p["k_norm"] = norm1((n, d), dtype=dt)
     if cfg.attn_bias:  # Qwen2's q/k/v projection biases
         p["q_bias"] = jnp.zeros((n, q), dtype=dt)
         p["k_bias"] = jnp.zeros((n, kv), dtype=dt)
@@ -94,52 +134,26 @@ def init_layer_params(
         p["kv_a_norm"] = jnp.ones((n, r), dtype=dt)
         p["kv_b_proj"] = w(jax.random.fold_in(key, 9), r, heads_out)
         p["o_proj"] = w(ks[3], cfg.num_heads * cfg.v_head_dim, h)
-    if cfg.is_moe and not dense:
-        e, mi = cfg.num_experts, cfg.moe_intermediate_size  # the experts HELD here
-        p["router"] = w(ks[4], h, cfg.router_width)
-        if cfg.moe_router_mode == "sigmoid_topk":
-            # drawn so that the router's logits have unit variance whatever
-            # the width (the scores spread over (0, 1)), and a selection bias
-            # small and non-zero: of 256 experts' top 4 it changes the choice
-            # of about half the tokens and leaves the load near even (a
-            # deviation of 0.1 sends a third of the rows to one expert)
-            p["router"] = (p["router"].astype(jnp.float32) * (50.0 / math.sqrt(h))).astype(dt)
-            p["router_select_bias"] = 0.01 * jax.random.normal(
-                jax.random.fold_in(key, 14), (n, cfg.router_width), dtype=jnp.float32)
-        p["gate_proj"] = w(ks[5], e, h, mi)
-        p["up_proj"] = w(ks[6], e, h, mi)
-        p["down_proj"] = w(ks[7], e, mi, h)
-        if cfg.router_bias:
-            p["router_bias"] = jnp.zeros((n, e), dtype=dt)
-        if cfg.moe_bias:
-            p["gate_bias"] = jnp.zeros((n, e, mi), dtype=dt)
-            p["up_bias"] = jnp.zeros((n, e, mi), dtype=dt)
-            p["down_bias"] = jnp.zeros((n, e, h), dtype=dt)
-        if cfg.n_shared_experts:
-            si = cfg.n_shared_experts * mi
-            p["shared_gate_proj"] = w(jax.random.fold_in(key, 10), h, si)
-            p["shared_up_proj"] = w(jax.random.fold_in(key, 11), h, si)
-            p["shared_down_proj"] = w(jax.random.fold_in(key, 12), si, h)
-    else:
-        p["gate_proj"] = w(ks[5], h, i)
-        p["up_proj"] = w(ks[6], h, i)
-        p["down_proj"] = w(ks[7], i, h)
+    p.update(_init_ffn_params(cfg, w, n, dense, ks[4:8], key))
     return p
 
 
 def init_state_layer_params(cfg: ModelConfig, key: jax.Array, num_layers: int) -> Params:
-    """Stacked Mamba-2 layers (mamba_mixer) with their MLP: every leaf has
-    leading dim `num_layers`. The projections are drawn as every other one
-    (normal, 0.02). What steers the recurrence is drawn so that it is neither
-    dead nor saturated: a target step d log-uniform in [0.01, 0.5] with
-    dt_bias its inverse softplus, A = -exp(A_log) with exp(A_log) uniform in
-    [0.1, 1], so the decay exp(d A) of a step spreads over about 0.5-0.999
-    and the state weighs about what D x does; D uniform in [0.5, 1.5]; the
-    convolution's taps normal with deviation 0.3."""
-    n, h, i = num_layers, cfg.hidden_size, cfg.intermediate_size
-    di, cd, heads = cfg.mamba_inner, cfg.mamba_conv_dim, cfg.mamba_heads
+    """Stacked state layers of cfg.state_kind (mamba_mixer or
+    gated_delta_mixer) with their feed-forward: every leaf has leading dim
+    `num_layers`. The projections are drawn as every other one (normal,
+    0.02). What steers the recurrence is drawn so that it is neither dead nor
+    saturated: a target step d log-uniform in [0.01, 0.5] with dt_bias its
+    inverse softplus, A = -exp(A_log) with exp(A_log) uniform in [0.1, 1], so
+    the decay exp(d A) of a step spreads over about 0.5-0.999 and the state
+    weighs about what the newest token does; Mamba-2's D uniform in
+    [0.5, 1.5]; the convolution's taps normal with deviation 0.3."""
+    n, h = num_layers, cfg.hidden_size
+    delta = cfg.state_kind == "delta"
+    heads = cfg.linear_value_heads if delta else cfg.mamba_heads
     dt = cfg.jnp_dtype
     ks = jax.random.split(key, 10)
+    norm1 = jnp.zeros if cfg.rms_norm_plus_one else jnp.ones
 
     def w(k, *shape, std=0.02):
         return (jax.random.normal(k, (n, *shape), dtype=jnp.float32) * std).astype(dt)
@@ -148,21 +162,33 @@ def init_state_layer_params(cfg: ModelConfig, key: jax.Array, num_layers: int) -
         return jax.random.uniform(k, (n, heads), jnp.float32, lo, hi)
 
     step = jnp.exp(uniform(ks[4], math.log(0.01), math.log(0.5)))
-    return {
-        "input_norm": jnp.ones((n, h), dtype=dt),
-        "in_proj": w(ks[0], h, di + cd + heads),  # [z | xBC | dt], in that order
-        "conv_w": w(ks[1], cfg.mamba_conv, cd, std=0.3),  # tap i meets the input K-1-i back
-        "conv_b": w(ks[2], cd),
+    p = {
+        "input_norm": norm1((n, h), dtype=dt),
         "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),  # softplus^-1(step)
         "A_log": jnp.log(uniform(ks[5], 0.1, 1.0)).astype(dt),
-        "D": uniform(ks[6], 0.5, 1.5).astype(dt),
-        "gate_norm": jnp.ones((n, di), dtype=dt),
-        "out_proj": w(ks[3], di, h),
-        "post_norm": jnp.ones((n, h), dtype=dt),
-        "gate_proj": w(ks[7], h, i),
-        "up_proj": w(ks[8], h, i),
-        "down_proj": w(ks[9], i, h),
+        "post_norm": norm1((n, h), dtype=dt),
     }
+    if delta:
+        cd, dv = cfg.linear_conv_dim, cfg.linear_value_dim
+        p.update(
+            in_proj=w(ks[0], h, cd + dv),  # [q | k | v | z], each head after head
+            ba_proj=w(ks[2], h, 2 * heads),  # [b | a], a value head each
+            conv_w=w(ks[1], cfg.linear_conv, cd, std=0.3),  # no bias
+            gate_norm=jnp.ones((n, cfg.linear_value_head_dim), dtype=dt),  # scales by w
+            out_proj=w(ks[3], dv, h),
+        )
+    else:
+        di, cd = cfg.mamba_inner, cfg.mamba_conv_dim
+        p.update(
+            in_proj=w(ks[0], h, di + cd + heads),  # [z | xBC | dt], in that order
+            conv_w=w(ks[1], cfg.mamba_conv, cd, std=0.3),  # tap i meets the input K-1-i back
+            conv_b=w(ks[2], cd),
+            D=uniform(ks[6], 0.5, 1.5).astype(dt),
+            gate_norm=jnp.ones((n, di), dtype=dt),
+            out_proj=w(ks[3], di, h),
+        )
+    p.update(_init_ffn_params(cfg, w, n, False, ks[6:10], key))
+    return p
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
@@ -170,14 +196,14 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     dt = cfg.jnp_dtype
     norm1 = jnp.zeros if cfg.rms_norm_plus_one else jnp.ones
-    n_state = cfg.layers_of("mamba")
+    n_state = cfg.layers_of(cfg.state_kind) if cfg.has_state_layers else 0
     params = {
         "embed": (jax.random.normal(k_embed, (cfg.vocab_size, cfg.hidden_size), dtype=jnp.float32) * 0.02).astype(dt),
         "layers": init_layer_params(
             cfg, k_layers, cfg.num_layers - cfg.num_dense_layers - n_state),
         "final_norm": norm1((cfg.hidden_size,), dtype=dt),
     }
-    if n_state:  # the Mamba kind's stack, beside the attention kind's `layers`
+    if n_state:  # the state kind's stack, beside the attention kind's `layers`
         params["state_layers"] = init_state_layer_params(
             cfg, jax.random.fold_in(k_layers, 2), n_state)
     if cfg.num_dense_layers:  # a leading group with leaves of its own
@@ -314,7 +340,11 @@ def _rotate_half(x: jax.Array) -> jax.Array:
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """x: [B, S, N, D]; cos/sin: [B, S, D] float32."""
+    """x: [B, S, N, D]; cos/sin: [B, S, R] float32, R <= D: the first R
+    dimensions of a head are turned (cfg.rope_dim), the rest pass."""
+    r = cos.shape[-1]
+    if r < x.shape[-1]:
+        return jnp.concatenate([apply_rope(x[..., :r], cos, sin), x[..., r:]], axis=-1)
     c = cos[:, :, None, :]
     s = sin[:, :, None, :]
     xf = x.astype(jnp.float32)
@@ -681,7 +711,8 @@ def moe_mlp_routed(p: Params, cfg: ModelConfig, x: jax.Array) -> Tuple[jax.Array
     moe_routed_part over the experts this device holds, from
     cfg.expert_offset (0 and all of them, but for a rank's share served on
     one chip). With `n_shared_experts` one always-on SwiGLU is added to the
-    routed output, once, outside the share.
+    routed output, once, outside the share, multiplied by its own gate
+    where the layer has one (`shared_expert_gate`).
     """
     b, s, h = x.shape
     xt = x.reshape(b * s, h)
@@ -689,7 +720,15 @@ def moe_mlp_routed(p: Params, cfg: ModelConfig, x: jax.Array) -> Tuple[jax.Array
     if cfg.n_shared_experts:
         with jax.named_scope("moe_shared"):
             shared = {k: p[f"shared_{k}"] for k in ("gate_proj", "up_proj", "down_proj")}
-            out = out + swiglu_mlp(shared, xt)
+            shared_out = swiglu_mlp(shared, xt)
+            if "shared_expert_gate" in p:  # Qwen3-Next: sigmoid(x . w) on the shared expert
+                with jax.named_scope("moe_shared_gate"):
+                    gate = jax.nn.sigmoid(jnp.einsum(
+                        "th,h->t", xt, p["shared_expert_gate"],
+                        preferred_element_type=jnp.float32))
+                    shared_out = (shared_out.astype(jnp.float32) * gate[:, None]).astype(
+                        shared_out.dtype)
+            out = out + shared_out
     return out.reshape(b, s, h), topi.reshape(b, s, -1)
 
 
@@ -1071,8 +1110,8 @@ def _gqa_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx, window
     k = k.reshape(b, s, k.shape[-1] // d, d)
     v = v.reshape(b, s, v.shape[-1] // d, d)
     if cfg.qk_norm:  # Qwen3 signature feature
-        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
-        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps, cfg.rms_norm_plus_one)
+        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps, cfg.rms_norm_plus_one)
     if cos is not None:  # None: a model without position embedding
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
@@ -1195,10 +1234,66 @@ def _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx):
 
 
 # ---------------------------------------------------------------------------
-# Mamba-2: a state-space mixer in attention's place (cfg.layer_types)
+# State layers in attention's place (cfg.layer_types): Mamba-2 and the gated
+# delta rule. One cache entry (core.cache.StateEntry), one convolution, one set
+# of rules for padding, write_mask and position 0; two recurrences
 # ---------------------------------------------------------------------------
 
 _HI = jax.lax.Precision.HIGHEST  # float32 operands stay float32 on the MXU
+
+
+def _state_enter(cfg: ModelConfig, entry, at, ctx, b: int, s: int, dtype):
+    """What a state layer's chunk of `s` positions enters with -> (the state
+    [B, *cfg.state_shape] f32, the convolution's kept inputs [B, K-1, C] in
+    `dtype`, how many of the chunk's positions are real [B], and the stored
+    state and inputs of layer `at` as they lie, None without a cache). A
+    row written at position 0 enters with zeros whatever its lane held: that
+    is how a session starts; with no `entry` every row does."""
+    f32 = jnp.float32
+    if entry is None:
+        return (jnp.zeros((b, *cfg.state_shape), f32),
+                jnp.zeros((b, *cfg.state_conv_shape), dtype),
+                jnp.full((b,), s, jnp.int32), None, None)
+    row = lambda v: jnp.broadcast_to(jnp.asarray(v, jnp.int32), (b,))  # noqa: E731
+    start = row(ctx.write_pos)
+    real = jnp.clip(row(start + s if ctx.real_end is None else ctx.real_end) - start, 0, s)
+    fresh = (start == 0)[:, None, None]
+    s_old, kept_old = _slab(entry.s, at), _slab(entry.conv, at)
+    s_in = jnp.where(fresh[..., None], 0.0, s_old.astype(f32))
+    kept = jnp.where(fresh, 0, kept_old).astype(dtype)
+    return s_in, kept, real, s_old, kept_old
+
+
+def _state_leave(entry, at, ctx, s_new, kept, s_old, kept_old):
+    """The stacked StateEntry with layer `at` holding `s_new` and `kept`; a
+    row whose ctx.write_mask is False keeps what it had, by a select."""
+    s_new = s_new.astype(entry.s.dtype)
+    kept = kept.astype(entry.conv.dtype)
+    if ctx.write_mask is not None:
+        s_new = jnp.where(ctx.write_mask[:, None, None, None], s_new, s_old)
+        kept = jnp.where(ctx.write_mask[:, None, None], kept, kept_old)
+    put = jax.lax.dynamic_update_index_in_dim
+    return cachelib.StateEntry(s=put(entry.s, s_new, at, 0), conv=put(entry.conv, kept, at, 0))
+
+
+def _causal_conv(kept, x, taps, bias, real):
+    """The depthwise causal convolution of a state layer and its SiLU:
+    x [B, S, C] behind the K-1 inputs `kept` [B, K-1, C] that came before it,
+    taps [K, C] (tap i meets the input K-1-i back), `bias` [C] or None ->
+    (silu(conv) [B, S, C] f32, the K-1 inputs before the first padding
+    position, `real` [B] positions into the chunk)."""
+    f32 = jnp.float32
+    s, k = x.shape[1], taps.shape[0]
+    full = jnp.concatenate([kept, x], axis=1)  # [B, K-1 + S, C]
+    w = taps.astype(f32)
+    bias = None if bias is None else bias.astype(f32)
+    acc = sum(full[:, i:i + s].astype(f32) * w[i] for i in range(k))
+    if bias is not None:
+        acc = bias + acc
+    out = jax.nn.silu(acc)
+    kept = full[:, 1:] if s == 1 else jax.vmap(
+        lambda f, r: jax.lax.dynamic_slice_in_dim(f, r, k - 1, axis=0))(full, real)
+    return out, kept
 
 
 def ssm_chunked(x, dt, a, bm, cm, s_in, tile: int):
@@ -1245,7 +1340,7 @@ def ssm_chunked(x, dt, a, bm, cm, s_in, tile: int):
 
 def mamba_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, entry, at, ctx):
     """A Mamba-2 block over the normed input x [B, S, H] -> (out [B, S, H],
-    entry'). `entry` is the Mamba layers' STACKED core.cache.StateEntry and
+    entry'). `entry` is the state layers' STACKED core.cache.StateEntry and
     `at` this layer's index in it (None: no cache, the chunk starts from
     zeros and nothing is kept).
 
@@ -1268,34 +1363,13 @@ def mamba_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, entry, at, ctx):
     whatever its lane held: that is how a session starts."""
     f32 = jnp.float32
     b, s, _ = x.shape
-    heads, p, n, g, k = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state,
-                         cfg.mamba_groups, cfg.mamba_conv)
+    heads, p, n, g = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state, cfg.mamba_groups
     di, cd, hg = cfg.mamba_inner, cfg.mamba_conv_dim, cfg.mamba_heads // cfg.mamba_groups
     proj = qdot(x, lp["in_proj"])
     z, xbc, dt = proj[..., :di], proj[..., di:di + cd], proj[..., di + cd:]
-
-    if entry is None:
-        s_in = jnp.zeros((b, heads, p, n), f32)
-        kept = jnp.zeros((b, k - 1, cd), xbc.dtype)
-        real = jnp.full((b,), s, jnp.int32)
-    else:
-        row = lambda v: jnp.broadcast_to(jnp.asarray(v, jnp.int32), (b,))  # noqa: E731
-        start = row(ctx.write_pos)
-        real = jnp.clip(row(start + s if ctx.real_end is None else ctx.real_end) - start, 0, s)
-        fresh = (start == 0)[:, None, None]
-        s_old, kept_old = _slab(entry.s, at), _slab(entry.conv, at)
-        s_in = jnp.where(fresh[..., None], 0.0, s_old.astype(f32))
-        kept = jnp.where(fresh, 0, kept_old).astype(xbc.dtype)
-
+    s_in, kept, real, s_old, kept_old = _state_enter(cfg, entry, at, ctx, b, s, xbc.dtype)
     with jax.named_scope("ssm_conv"):
-        full = jnp.concatenate([kept, xbc], axis=1)  # [B, K-1 + S, C]
-        w = lp["conv_w"].astype(f32)
-        acc = lp["conv_b"].astype(f32) + sum(
-            full[:, i:i + s].astype(f32) * w[i] for i in range(k))
-        xbc = jax.nn.silu(acc)
-        # the K-1 inputs before the first padding position
-        kept = full[:, 1:] if s == 1 else jax.vmap(
-            lambda f, r: jax.lax.dynamic_slice_in_dim(f, r, k - 1, axis=0))(full, real)
+        xbc, kept = _causal_conv(kept, xbc, lp["conv_w"], lp["conv_b"], real)
     xs = xbc[..., :di].reshape(b, s, g, hg, p)
     bm = xbc[..., di:di + g * n].reshape(b, s, g, n)
     cm = xbc[..., di + g * n:].reshape(b, s, g, n)
@@ -1322,13 +1396,130 @@ def mamba_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, entry, at, ctx):
     out = qdot(y, lp["out_proj"])
     if entry is None:
         return out, None
-    s_new = s_new.reshape(b, heads, p, n).astype(entry.s.dtype)
-    kept = kept.astype(entry.conv.dtype)
-    if ctx.write_mask is not None:
-        s_new = jnp.where(ctx.write_mask[:, None, None, None], s_new, s_old)
-        kept = jnp.where(ctx.write_mask[:, None, None], kept, kept_old)
-    put = jax.lax.dynamic_update_index_in_dim
-    return out, cachelib.StateEntry(s=put(entry.s, s_new, at, 0), conv=put(entry.conv, kept, at, 0))
+    return out, _state_leave(
+        entry, at, ctx, s_new.reshape(b, heads, p, n), kept, s_old, kept_old)
+
+
+def gated_delta_chunked(q, k, v, g, beta, s_in, tile: int):
+    """The chunked (WY / UT) form of the gated delta rule over a chunk,
+    float32 throughout:
+
+        S' = exp(g_t) S_{t-1};  u_t = beta_t (v_t - S'^T k_t);  S_t = S' + k_t u_t^T;  o_t = S_t^T q_t
+
+    q, k [B, S, H, Dk] (normalised, q scaled), v [B, S, H, Dv], g (<= 0; 0
+    with beta 0 where a position is padding: the state passes it unchanged)
+    and beta [B, S, H], s_in [B, H, Dk, Dv] -> (o [B, S, H, Dv], the state the
+    chunk leaves).
+
+    The chunk is cut into tiles of `tile` positions (it has to divide S).
+    With G_t the log decay cumulated from the tile's start and S0 the state
+    the tile enters with, the tile's u solve ONE unit lower-triangular system
+
+        (I + L) U = beta V - (beta K exp(G)) S0,   L[i, j] = beta_i exp(G_i - G_j) k_i . k_j  (j < i)
+
+    whose two right-hand sides do not depend on S0, so every tile's solve
+    runs at once; what depends on S0 goes from tile to tile through a
+    sequential scan over the few tiles. Only DIFFERENCES of the cumulated
+    log decay with i >= j are exponentiated (and G itself, <= 0), as
+    ssm_chunked does: nothing overflows however long the decay."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    c = s // tile
+    t5 = lambda a: jnp.moveaxis(a.reshape(b, c, tile, *a.shape[2:]), 3, 2)  # noqa: E731
+    q, k, v, g, beta = t5(q), t5(k), t5(v), t5(g), t5(beta)  # [b, c, h, tile(, d)]
+    gc = jnp.cumsum(g, axis=-1)
+    at = jnp.arange(tile)
+    seen = at[:, None] >= at[None, :]
+    decay = jnp.exp(jnp.where(seen, gc[..., :, None] - gc[..., None, :], -jnp.inf))  # [.., i, j]
+    kb = k * beta[..., None]
+    lower = jnp.where(at[:, None] > at[None, :],
+                      jnp.einsum("bchid,bchjd->bchij", kb, k, precision=_HI) * decay, 0.0)
+    rhs = jnp.concatenate([v * beta[..., None], kb * jnp.exp(gc)[..., None]], axis=-1)
+    sol = jax.lax.linalg.triangular_solve(
+        lower + jnp.eye(tile, dtype=lower.dtype), rhs, left_side=True, lower=True,
+        unit_diagonal=True)
+    u_own, k_dec = sol[..., :dv], sol[..., dv:]  # U with S0 = 0, and what S0 takes off it
+    qk = jnp.einsum("bchid,bchjd->bchij", q, k, precision=_HI) * decay  # j <= i
+    q_dec = q * jnp.exp(gc)[..., None]
+    k_end = k * jnp.exp(gc[..., -1:] - gc)[..., None]  # each key's decay to the tile's end
+    whole = jnp.exp(gc[..., -1])  # [b, c, h]
+
+    def carry(state, xs):
+        u_c, kd_c, qk_c, qd_c, ke_c, whole_c = xs
+        u = u_c - jnp.einsum("bhtk,bhkv->bhtv", kd_c, state, precision=_HI)
+        o = (jnp.einsum("bhtk,bhkv->bhtv", qd_c, state, precision=_HI)
+             + jnp.einsum("bhij,bhjv->bhiv", qk_c, u, precision=_HI))
+        state = (state * whole_c[..., None, None]
+                 + jnp.einsum("bhtk,bhtv->bhkv", ke_c, u, precision=_HI))
+        return state, o
+
+    s_out, o = jax.lax.scan(
+        carry, s_in, tuple(jnp.moveaxis(a, 1, 0) for a in (u_own, k_dec, qk, q_dec, k_end, whole)))
+    o = jnp.moveaxis(o, (0, 1, 2, 3), (1, 0, 3, 2))  # [c, b, h, t, v] -> [b, c, t, h, v]
+    return o.reshape(b, s, h, dv), s_out
+
+
+def _l2norm(u: jax.Array) -> jax.Array:
+    return u * jax.lax.rsqrt(jnp.sum(u * u, axis=-1, keepdims=True) + 1e-6)
+
+
+def gated_delta_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, entry, at, ctx):
+    """A Gated-DeltaNet block (the Qwen3-Next family) over the normed input
+    x [B, S, H] -> (out [B, S, H], entry'); `entry`, `at` and `ctx` as
+    mamba_mixer takes them, and the same rules for padding, write_mask and
+    position 0 (_state_enter, _causal_conv, _state_leave).
+
+        [q | k | v | z] = x W_in;  [b | a] = x W_ba
+        [q|k|v]_t = silu(sum_i w_conv[i] [q|k|v]_{t-(K-1)+i})        causal, depthwise, no bias
+        value head h reads key head h // (value heads / key heads)
+        q = l2norm(q) / sqrt(Dk);  k = l2norm(k);  beta_t = sigmoid(b_t)
+        g_t = -exp(A_log) softplus(a_t + dt_bias)
+        S' = exp(g_t) S_{t-1};  u_t = beta_t (v_t - S'^T k_t);  S_t = S' + k_t u_t^T;  o_t = S_t^T q_t
+        out = (RMSNorm(o_t; w_norm) silu(z_t)) W_out      per head: the norm first, then the gate
+
+    One recurrence in two forms: S == 1 (a decode row) is the update as
+    written; a longer chunk runs `gated_delta_chunked`, tiled by
+    cfg.linear_chunk_size where that divides it. The state [B, value heads,
+    Dk, Dv] is float32 while it is computed and is held in cfg.state_dtype
+    between steps. A padding position has g_t = 0 and beta_t = 0."""
+    f32 = jnp.float32
+    b, s, _ = x.shape
+    hk, hv, dk, dv = (cfg.linear_key_heads, cfg.linear_value_heads, cfg.linear_key_head_dim,
+                      cfg.linear_value_head_dim)
+    kd, cd = cfg.linear_key_dim, cfg.linear_conv_dim
+    proj = qdot(x, lp["in_proj"])
+    qkv, z = proj[..., :cd], proj[..., cd:]
+    ba = qdot(x, lp["ba_proj"]).astype(f32)
+    s_in, kept, real, s_old, kept_old = _state_enter(cfg, entry, at, ctx, b, s, qkv.dtype)
+    with jax.named_scope("gdn_conv"):
+        qkv, kept = _causal_conv(kept, qkv, lp["conv_w"], None, real)
+    heads = lambda u, n, d: jnp.repeat(u.reshape(b, s, n, d), hv // n, axis=2)  # noqa: E731
+    q = _l2norm(heads(qkv[..., :kd], hk, dk)) * dk ** -0.5
+    k = _l2norm(heads(qkv[..., kd:2 * kd], hk, dk))
+    v = qkv[..., 2 * kd:].reshape(b, s, hv, dv)
+    valid = (jnp.arange(s)[None, :] < real[:, None])[..., None]
+    beta = jnp.where(valid, jax.nn.sigmoid(ba[..., :hv]), 0.0)
+    g = -jnp.exp(lp["A_log"].astype(f32)) * jax.nn.softplus(
+        ba[..., hv:] + lp["dt_bias"].astype(f32))
+    g = jnp.where(valid, g, 0.0)
+    if s == 1:
+        with jax.named_scope("gdn_update"):
+            q1, k1, v1, g1, b1 = q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0]
+            s_new = s_in * jnp.exp(g1)[..., None, None]
+            u = b1[..., None] * (v1 - jnp.sum(s_new * k1[..., None], axis=-2))
+            s_new = s_new + k1[..., None] * u[..., None, :]
+            o = jnp.sum(s_new * q1[..., None], axis=-2)[:, None]
+    else:
+        with jax.named_scope("gdn_scan"):
+            t = cfg.linear_chunk_size
+            o, s_new = gated_delta_chunked(q, k, v, g, beta, s_in, t if s % t == 0 else s)
+    with jax.named_scope("gdn_gate_norm"):  # per head; this one norm scales by w
+        y = rms_norm(o, lp["gate_norm"], cfg.rms_norm_eps)
+        y = (y * jax.nn.silu(z.astype(f32).reshape(b, s, hv, dv))).reshape(b, s, hv * dv)
+    out = qdot(y.astype(x.dtype), lp["out_proj"])
+    if entry is None:
+        return out, None
+    return out, _state_leave(entry, at, ctx, s_new, kept, s_old, kept_old)
 
 
 def decoder_layer(
@@ -1354,9 +1545,12 @@ def decoder_layer(
     #   "scale": [B] f32} — slot-0 (base) lanes carry zero A/B and apply
     #   nothing (ops.lora.apply_lane_delta)
 ):
-    """One pre-norm residual decoder block with GQA + per-head q/k RMSNorm
-    (the Qwen3 signature feature — reference qwen3_server_module.py:123-124),
-    or with latent attention (cfg.is_mla).
+    """One pre-norm residual decoder block: a mixer, then a feed-forward, two
+    independent choices. The mixer is GQA + per-head q/k RMSNorm (the Qwen3
+    signature feature — reference qwen3_server_module.py:123-124), latent
+    attention (cfg.is_mla), or for a layer of the state kind's stack a
+    Mamba-2 block or the gated delta rule; the feed-forward is the dense MLP
+    or routed experts beside a shared one, by what the layer's stack holds.
 
     Returns (hidden', entry', chosen experts [B, S, K] or None for a dense
     MLP); entry' is the whole stack with this layer's rows of the chunk
@@ -1387,42 +1581,42 @@ def decoder_layer(
         lambda y: y * cfg.residual_multiplier)
 
     x = rms_norm(hidden, lp["input_norm"], cfg.rms_norm_eps, p1)
-    if "in_proj" in lp:  # a state-space layer: its stack holds no q / k / v
+    if "in_proj" in lp:  # a state layer: its stack holds no q / k / v
         if tp_axis or ep_axis or adapters is not None or not isinstance(
                 entry, (type(None), cachelib.StateEntry)):
             raise ValueError(
                 f"{cfg.name}: a state-space layer runs whole on its device over a "
                 "StateEntry (no tensor/expert parallel shard, no adapter)"
             )
-        mixed, entry = mamba_mixer(lp, cfg, x, entry, at, ctx)
-        hidden = hidden + scaled(mixed).astype(hidden.dtype)
-        x = rms_norm(hidden, lp["post_norm"], cfg.rms_norm_eps, p1)
-        mlp_out = swiglu_mlp(lp, x, act_fn(cfg))
-        return hidden + scaled(mlp_out).astype(hidden.dtype), entry, None
-    if cfg.is_mla:
-        if (tp_axis or ep_axis or window is not None or adapters is not None
-                or not isinstance(entry, (type(None), cachelib.LatentEntry))):
-            raise ValueError(
-                f"{cfg.name}: latent attention runs on the dense lane layout only "
-                "(no tensor/expert parallel shard, paged pool, ring, window or adapter)"
-            )
-        attn, entry = _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx)
+        mixer = gated_delta_mixer if "ba_proj" in lp else mamba_mixer
+        attn_out, entry = mixer(lp, cfg, x, entry, at, ctx)
     else:
-        attn, entry = _gqa_attend_update(
-            lp, cfg, x, cos, sin, q_positions, entry, at, ctx, window, adapters
-        )
+        if cfg.is_mla:
+            if (tp_axis or ep_axis or window is not None or adapters is not None
+                    or not isinstance(entry, (type(None), cachelib.LatentEntry))):
+                raise ValueError(
+                    f"{cfg.name}: latent attention runs on the dense lane layout only "
+                    "(no tensor/expert parallel shard, paged pool, ring, window or adapter)"
+                )
+            attn, entry = _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx)
+        else:
+            attn, entry = _gqa_attend_update(
+                lp, cfg, x, cos, sin, q_positions, entry, at, ctx, window, adapters
+            )
 
-    attn_out = lora_ops.apply_lane_delta(
-        qdot(attn, lp["o_proj"]), attn, "o_proj", adapters
-    )
-    if tp_axis is not None:  # row-parallel o_proj: partial sums per rank
-        attn_out = jax.lax.psum(attn_out, tp_axis)
-    if cfg.o_bias:  # replicated bias joins AFTER the partial-sum combine
-        attn_out = attn_out + lp["o_bias"]
-    if cfg.sandwich_norm:  # Gemma: post-norm the sublayer output pre-residual
-        attn_out = rms_norm(attn_out, lp["post_norm"], cfg.rms_norm_eps, p1)
+        attn_out = lora_ops.apply_lane_delta(
+            qdot(attn, lp["o_proj"]), attn, "o_proj", adapters
+        )
+        if tp_axis is not None:  # row-parallel o_proj: partial sums per rank
+            attn_out = jax.lax.psum(attn_out, tp_axis)
+        if cfg.o_bias:  # replicated bias joins AFTER the partial-sum combine
+            attn_out = attn_out + lp["o_bias"]
+        if cfg.sandwich_norm:  # Gemma: post-norm the sublayer output pre-residual
+            attn_out = rms_norm(attn_out, lp["post_norm"], cfg.rms_norm_eps, p1)
     hidden = hidden + scaled(attn_out).astype(hidden.dtype)
 
+    # the feed-forward: dense, or routed experts beside a shared one, whatever
+    # the mixer above was
     pre_ffn = lp["pre_ffn_norm"] if cfg.sandwich_norm else lp["post_norm"]
     x = rms_norm(hidden, pre_ffn, cfg.rms_norm_eps, p1)
     expert_axes = tuple(a for a in (ep_axis, tp_axis) if a is not None)
@@ -1510,9 +1704,9 @@ def forward_layers(
     adapters=None,  # multi-tenant LoRA pools + per-lane ids (the ops.lora
     #   pool pytree: {"a", "b", "scale", "ids"}); gathered ONCE here, the
     #   per-layer slices ride the scan like the cache entries
-    state_layers: Optional[Params] = None,  # a model with state-space layers:
-    #   the Mamba kind's stack, `layers` then being the attention kind's; the
-    #   two ride the scan by kind, as a cache split by kind does
+    state_layers: Optional[Params] = None,  # a model with state layers: the
+    #   state kind's stack, `layers` then being the attention kind's; the two
+    #   ride the scan by kind, as a cache split by kind does
 ):
     """Run a stack of decoder layers via ONE lax.scan over periods of
     cfg.layer_pattern -> (hidden, entries', chosen experts [L, B, S, K] or
@@ -1570,12 +1764,18 @@ def forward_layers(
     # a routed layer's expert weights do not ride the scan: a layer finds its
     # experts in the stack where it lies (ExpertStack), as it finds its cache
     # entry, so the grouped product's kernel is handed no copy of them
-    experts = {
-        name: layers[name] for name in ("gate_proj", "up_proj", "down_proj")
-        if "router" in layers and not split and isinstance(layers[name], jax.Array)
-    }
-    if experts:
-        layers = {name: a for name, a in layers.items() if name not in experts}
+    def without_experts(stack):  # -> (the stack's other leaves, its expert weights)
+        held = {
+            name: stack[name] for name in ("gate_proj", "up_proj", "down_proj")
+            if "router" in stack and isinstance(stack[name], jax.Array)
+        }
+        return {name: a for name, a in stack.items() if name not in held}, held
+
+    experts = {}
+    if not split:
+        rest, experts = without_experts(layers)
+        if experts:
+            layers = rest
 
     static = isinstance(layer_offset, int)
     kinds = cfg.layer_pattern if static else (None,)
@@ -1596,9 +1796,10 @@ def forward_layers(
             f"{cfg.name}: a model with state-space layers runs whole periods of its two "
             "weight stacks from a static offset (one stage, no adapter)"
         )
-    if split:
-        per_layer = (tuple(state_layers if kind == "mamba" else layers for kind in uniq),
-                     None, None)
+    if split:  # a weight stack per kind, and beside each its expert weights
+        stacks, experts_of = zip(*(
+            without_experts(state_layers if kind in STATE_KINDS else layers) for kind in uniq))
+        per_layer = (stacks, None, None)
 
     def place(j):  # of place j in a period: (its kind's stack, its rank among the
         #   period's layers of that kind, how many of them a period has)
@@ -1646,7 +1847,10 @@ def forward_layers(
             # stack, as its cache entry is (a period's weights folded into
             # the scan's inputs would be copied out, nine layers at a time)
             s, rank, count = place(j)
-            return jax.tree.map(lambda a: _slab(a, p * count + rank), per_layer[0][s]), None, None
+            lp = jax.tree.map(lambda a: _slab(a, p * count + rank), per_layer[0][s])
+            lp.update(
+                {name: ExpertStack(a, p * count + rank) for name, a in experts_of[s].items()})
+            return lp, None, None
         return tree if period == 1 else jax.tree.map(lambda a: a[j], tree)
 
     def pack(vals):  # the period's layers' values -> leaves [period, ...]
@@ -1708,7 +1912,7 @@ def forward_layers_cached(
     adapters=None,  # multi-tenant LoRA pool pytree + per-lane ids
     tp_axis: Optional[str] = None,
     ep_axis: Optional[str] = None,
-    state_layers: Optional[Params] = None,  # the Mamba kind's stack (forward_layers)
+    state_layers: Optional[Params] = None,  # the state kind's stack (forward_layers)
 ):
     """THE cached stage/model forward: every layout of core.cache (dense
     lanes, latent, ring-split, paged pool) goes through here and through

@@ -389,6 +389,7 @@ _LAYER_LINEARS = (
     "q_proj", "k_proj", "v_proj", "o_proj", "gate_proj", "up_proj", "down_proj",
     "in_proj", "out_proj",  # a Mamba-2 layer's two projections (`state_layers`)
     "attn_gate_proj",  # the attention output's gate (cfg.attn_gate)
+    "shared_gate_proj", "shared_up_proj", "shared_down_proj",  # the shared expert beside routed ones
 )
 
 

@@ -406,7 +406,10 @@ def check_servable(cfg, args, num_stages: int = 1) -> None:
     its experts (cfg.router_experts) is served from the lanes too, in its own
     dtype or --kv-dtype: no sharding rule or stage knows the gate, a stage or
     a traced rank knows no layer's kind, and a share is already one rank's
-    part."""
+    part; --quant runs it too (ops/quant quantizes both weight stacks'
+    projections, the held experts and the shared expert; a quantised expert
+    weight takes the dense expert product), but where a dense group leads,
+    which the last table refuses."""
     if cfg.nope_kinds or cfg.attn_gate or cfg.router_experts:
         _refuse(cfg, {
             "--mesh (a traced rank knows no layer's kind, no sharding rule names the "
@@ -414,9 +417,6 @@ def check_servable(cfg, args, num_stages: int = 1) -> None:
             "--stage-lanes (a stage's relay hands every layer one rope)": args.stage_lanes > 0,
             "--paged-kv (the paged pool keeps no ring for the windowed layers)":
                 args.paged_kv > 0,
-            "--quant (ops/quant quantizes the `layers` stack alone: not a leading dense "
-            "group, not a shared expert)":
-                args.quant != "none",
             "--spec-draft-layers (no self-draft over a share of the experts)":
                 args.spec_draft_layers > 0,
             "--lora": bool(args.lora),
@@ -465,7 +465,7 @@ def check_servable(cfg, args, num_stages: int = 1) -> None:
         "--mesh (no latent cache or layer groups under a mesh)": args.mesh,
         "--stage-lanes (a stage holds one group of layers)": args.stage_lanes > 0,
         "--paged-kv (the paged pool has no latent entry)": args.paged_kv > 0,
-        "--quant (the latent and shared-expert projections have no quantized form)":
+        "--quant (the latent projections and a leading dense group have no quantized form)":
             args.quant != "none",
         "--spec-draft-layers (no self-draft over layer groups)": args.spec_draft_layers > 0,
         "--lora": bool(args.lora),

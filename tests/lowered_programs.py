@@ -1,7 +1,9 @@
 """The ten programs the benchmark's five older cells run (a decode or block
 step and a prefill, the lane programs with the sampler at both of its widths),
-the two lane programs of `g4hm-many-chat` and the two of `trinl-window-docs`
-(the prefill and the step the window runs), lowered at the tiny presets:
+the two lane programs of `g4hm-many-chat`, the two of `trinl-window-docs` and
+the two of `q3n-long-docs` (the prefill and the step the window runs; its
+preset keeps its own heads: 2 kv heads of 32 are one row a token, as the
+cell's 2 of 256 are), lowered at the tiny presets:
 `texts()` gives their StableHLO text by name. What the text holds is the traced
 program; sizes are not the point: a change that leaves these configurations
 alone leaves every byte alone. ONE width is the point: the cells' heads are as
@@ -46,7 +48,8 @@ def texts() -> dict:
     # cell, preset, lanes, the sampler's widths its decode step is lowered at
     for cell, model, lanes, widths in (
             ("q4b", "tiny", 5, (0, 8)), ("dsv2l", "tiny-dsv2", 16, (0, 8)), ("sdar", "tiny-sdar", 16, ()),
-            ("g4hm", "tiny-granite-h", 4, (8,)), ("trinl", "tiny-afmoe", 16, (0,))):
+            ("g4hm", "tiny-granite-h", 4, (8,)), ("trinl", "tiny-afmoe", 16, (0,)),
+            ("q3n", "tiny-qwen3-next", 16, (0,))):
         cfg = cell_config(model)
         params = qwen3.init_params(cfg, jax.random.PRNGKey(0))
         eng = BatchedEngine(cfg, params, lanes=lanes, max_len=64)
@@ -81,7 +84,8 @@ def texts() -> dict:
 
 NAMES = ("q4b.prefill", "q4b.decode.top0", "q4b.decode.top8", "dsv2l.prefill", "dsv2l.decode.top0",
          "dsv2l.decode.top8", "sdar.prefill", "sdar.block", "q8b-pp4.prefill", "q8b-pp4.decode.top0",
-         "g4hm.prefill", "g4hm.decode.top8", "trinl.prefill", "trinl.decode.top0")
+         "g4hm.prefill", "g4hm.decode.top8", "trinl.prefill", "trinl.decode.top0",
+         "q3n.prefill", "q3n.decode.top0")
 
 
 def digests(found: dict) -> dict:

@@ -398,7 +398,7 @@ def test_a_config_that_contradicts_itself_is_refused(bad):
 
 REFUSED = {
     "mesh": dict(mesh="pp=2"), "stage-lanes": dict(stage_lanes=2), "paged-kv": dict(paged_kv=16),
-    "quant": dict(quant="int8"), "spec": dict(spec_draft_layers=1), "adapters": dict(adapters="a"),
+    "spec": dict(spec_draft_layers=1), "adapters": dict(adapters="a"), "lora": dict(lora="x"),
     "standby": dict(standby_repl=True), "no lanes": dict(batch_lanes=0),
 }
 
@@ -415,6 +415,11 @@ def test_run_node_refuses_every_other_path_by_what_the_config_observes(path):
         run_node.check_servable(renamed, argparse.Namespace(**{**base, **REFUSED[path]}))
     with pytest.raises(SystemExit, match="several stages"):
         run_node.check_servable(renamed, argparse.Namespace(**base), num_stages=2)
+    # --quant: refused for the leading dense group alone (PR 48: the shared expert has a
+    # quantized form), so open without one and refused, by the group's table, with it
+    run_node.check_servable(renamed, argparse.Namespace(**{**base, "quant": "int8"}))
+    with pytest.raises(SystemExit, match="--quant"):
+        run_node.check_servable(CFG, argparse.Namespace(**{**base, "quant": "int8"}))
 
 
 def test_a_traced_layer_offset_and_a_split_are_refused_below_too(params):

@@ -519,6 +519,40 @@ def test_trinity_share_lane_programs_compile_with_rings_and_the_cache_in_place(
     assert mem.argument_size_in_bytes + pm.temp_size_in_bytes < 15.75e9 * 0.8
 
 
+def test_qwen3_next_share_lane_programs_compile_with_state_and_rows_in_place(
+    one_chip, no_compile_cache, expert_kernel
+):
+    """The two programs a `--model qwen3-next-80b-ep4-8l --batch-lanes 16
+    --max-len 32768` node runs, at the published widths: 7.33 GB of weights
+    (128 held experts a layer under a 512-wide router, in BOTH weight
+    stacks), six layers' float32 delta-rule state `f32[6,16,32,128,128]` and
+    columns, two full layers' keys and values as ONE row of 512 a token: 2.354
+    GB of cache. The decode step (with its sampler and the lanes' `active`
+    mask, as the executor calls it) aliases the whole donated cache and holds
+    under 0.1 GB of temporaries (0.013): no second copy of the state stack,
+    of a slab or of a layer's experts. As `[.., 2, 256]` the slabs compiled
+    unpadded (tiles of T(2,128)) but each was copied T-minor before its dot:
+    0.54 GB of temporaries (core.cache.rows_layout). A 512-token prefill chunk:
+    0.85 GB of temporaries. The numbers are the configuration's `deployment`."""
+    import re
+
+    cfg = get_config("qwen3-next-80b-ep4-8l")
+    shapes, step, prefill = _lane_programs(cfg, 16, 32768, one_chip, active=True)
+    assert shapes.k.shape == (2, 16, 32768, 512) and shapes.s.shape == (6, 16, 32, 128, 128)
+    assert shapes.nbytes == 2_353_528_832 and shapes.state_bytes == 16 * 12_877_824
+    mem = step.memory_analysis()
+    assert 9.68e9 < mem.argument_size_in_bytes < 9.70e9  # 7.335 GB of weights + 2.354 of cache
+    assert mem.alias_size_in_bytes >= shapes.nbytes
+    assert mem.temp_size_in_bytes < 0.1e9
+    text = step.as_text()
+    copies = re.findall(r"= (\S+?)\{[^ ]* copy\(", text)
+    assert not [c for c in copies if c.startswith(("f32[6,16,32,128,128]", "bf16[6,128,", "bf16[2,128,"))]
+    assert _made_whole(text, r"bf16\[2,16,32768,", r"bf16\[1,16,32768,", r"bf16\[16,32768,\d+[,\]]") == []
+    pm = prefill.memory_analysis()
+    assert pm.alias_size_in_bytes >= shapes.nbytes and pm.temp_size_in_bytes < 1.1e9
+    assert mem.argument_size_in_bytes + pm.temp_size_in_bytes < 15.75e9 * 0.8
+
+
 def test_llama32_1b_lanes_keep_their_rows_where_they_lie(one_chip, no_compile_cache):
     """The other public model of this head size (8 kv heads of 64, 16
     layers), as `--model llama3.2-1b --batch-lanes 32 --max-len 4096` would

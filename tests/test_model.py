@@ -1027,8 +1027,10 @@ def test_a_cells_program_lowers_to_its_recorded_text(lowered_programs, name):
     seven of `dsv2l.*`, `sdar.*` and `trinl.*` anew on purpose (the routed
     layer multiplies grouped by expert, and a layer finds its experts in the
     stack where it lies); the seven without an expert kept the parent's
-    bytes. A PR that changes one of them on purpose runs `python
-    tests/lowered_programs.py --record` and says so."""
+    bytes. PR 48 added the two of `q3n.*` (a second recurrence and state
+    layers that route) and left the fourteen digests as they were. A PR that
+    changes one of them on purpose runs `python tests/lowered_programs.py
+    --record` and says so."""
     import json
 
     from tests import lowered_programs as lp
