@@ -28,6 +28,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas.ops.tpu.megablox import gmm
 
 from inferd_tpu.config import STATE_KINDS, ModelConfig, yarn_mscale
@@ -746,10 +747,12 @@ def _attend(
     kv_positions: Optional[jax.Array] = None,
     window: Optional[jax.Array] = None,
     sinks: Optional[jax.Array] = None,
+    flash: Optional[bool] = None,
 ) -> jax.Array:
     """Hot-op dispatch (the single site for prefill AND cached decode):
-    Pallas flash kernel when enabled for this buffer size, XLA gqa_attention
-    otherwise. Positions from forward_layers/forward are contiguous per batch
+    Pallas flash kernel when enabled for this buffer size (`flash` None; a
+    caller that hands a prefix of its buffer decides by the buffer), XLA
+    gqa_attention otherwise. Positions from forward_layers/forward are contiguous per batch
     row (start + arange) — the flash kernel's layout contract; kv slot j holds
     position kv_positions[:, 0] + j (or j when kv_positions is None).
     Scattered-position callers must use gqa_attention directly.
@@ -762,10 +765,12 @@ def _attend(
     Attention sinks (GPT-OSS) fold into the kernels' online-softmax
     denominator at finalize — the full sink+window+softcap recipe rides
     either path."""
-    if attention_ops.flash_enabled(
-        cfg, k.shape[1], compressed_kv=k.dtype != q.dtype,
-        q_len=q.shape[1], batch=q.shape[0],
-    ):
+    if flash is None:
+        flash = attention_ops.flash_enabled(
+            cfg, k.shape[1], compressed_kv=k.dtype != q.dtype,
+            q_len=q.shape[1], batch=q.shape[0],
+        )
+    if flash:
         kv_start = kv_positions[:, 0] if kv_positions is not None else 0
         return attention_ops.flash_gqa(
             q, k, v,
@@ -948,21 +953,123 @@ def _attend_chunk(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks):
     return attn, None
 
 
-def _lanes_read(cfg, new_k, new_v, ctx, s: int, window):
-    """What a chunk of `s` queries reads of its layer's dense-lane slab
-    [B, T, ...] (either layout: T is axis 1) -> (k, v, kv_positions, valid
-    length, window). A STATIC int window narrows the read to a
-    window-covering slice (_windowed_slice, the sliding-layer read fast
-    path); a traced window (or None) reads the whole buffer, mask-only."""
+# How finely a dense lane's slab is read by its prefix: eighths of its length
+# (at 4096 slots a rung is one 512-token prefill chunk; over a 2816-token
+# prompt eighths read 44 % of the slots where powers of two read 56 %), each a
+# whole number of 128-slot tiles, so a slab under 1024 slots is read whole
+_READ_RUNGS = 8
+_READ_TILE = 128
+# The longest slice worth making before the dots (a pinned read, read_pinned):
+# the chip's compiler keeps one of 64 MiB in fast memory and makes a longer one
+# in HBM (described-v5e compile of `trinl-window-docs`' step: the rung of 2048
+# slots, 67 108 864 B, is placed in S(1), the one of 4096 is not), and a slice
+# made in HBM is read, written and read again (my chip run, PR 49: two slices
+# of 470 MB took 0.17 s of a capture each before their dots, where the parent's
+# program spends 0.093 on each whole slab of 537 MB)
+_READ_SLICE_BYTES = 64 * 2**20
+
+
+def read_rungs(
+    cfg: ModelConfig, t: int, q_len: int, batch: int, compressed_kv: bool, heads: bool
+) -> Tuple[int, ...]:
+    """The static lengths a full layer's `t`-slot dense-lane slab may be read
+    to by `q_len` queries a row over `batch` rows (`compressed_kv`: the slab is
+    stored narrower than the activations; `heads`: with a head axis, not one
+    row a token), the last of them `t` (the whole slab): from static shapes
+    alone, so the program (_lanes_read) and its counter (`kv.slots_read`,
+    runtime/batch_executor) agree and nothing recompiles with the lengths.
+    One rung, `t`, where a rung would not be a whole number of tiles, and
+    where the Pallas kernel is chosen (by the slab's length: its block loop is
+    bounded by the valid length already). Where the slice is made before the
+    dots (read_pinned), no rung whose slice is over _READ_SLICE_BYTES."""
+    if t % (_READ_RUNGS * _READ_TILE) or attention_ops.flash_enabled(
+            cfg, t, compressed_kv=compressed_kv, q_len=q_len, batch=batch):
+        return (t,)
+    step = t // _READ_RUNGS
+    longest = t
+    if read_pinned(heads, q_len):
+        longest = _READ_SLICE_BYTES // (batch * cfg.kv_dim * cfg.kv_jnp_dtype.itemsize)
+    return tuple(r for r in range(step, t, step) if r <= longest) + (t,)
+
+
+def read_pinned(heads: bool, q_len: int) -> bool:
+    """Whether a rung's slice keeps the stack's own row-major layout, and so
+    is made before the dots (_lanes_read). Where a dot asks another layout of
+    its keys (heads before slots of a stack with a head axis; slots innermost
+    for a chunk's weighted sum over rows) that layout otherwise runs back
+    through the slice to the branch's parameter, and every branch copies the
+    WHOLE stack (described-v5e compiles: 1.51 GB of temporaries in
+    `q4b-sat-chat`'s step where pinned has 0.002; a `copy` of
+    `bf16[4,1,4096,512]{2,3,1,0}` in granite's chunk). A decode step over
+    rows takes them as they lie, and unpinned its slice fuses into the dots."""
+    return heads or q_len > 1
+
+
+def read_rung(longest, rungs: Tuple[int, ...]):
+    """Index in `rungs` (read_rungs) of the shortest that covers `longest`
+    valid slots (the last where none does): a Python int on the host, a
+    traced int32 in a program."""
+    xp = jnp if isinstance(longest, jax.Array) else np
+    return xp.sum(longest > xp.asarray(rungs[:-1], xp.int32))
+
+
+def _lanes_read(cfg, k_stack, v_stack, at, ctx, q, window, attend):
+    """What the chunk of queries `q` [B, S, ...] reads of layer `at` of the
+    stacked dense-lane slabs [L, B, T, ...] (either layout: T is axis 2),
+    handed to `attend(k, v, kv_positions, valid length, window, flash)` -> its
+    result; `flash`: whether the Pallas kernel is chosen, by the length of the
+    buffer the read is taken from (the slab's, or the window's slice). A STATIC
+    int window narrows the read to a window-covering slice (_windowed_slice,
+    the sliding-layer read fast path). A traced window (or None) masks only,
+    and the slab is read to the shortest of read_rungs that covers the
+    LONGEST row's valid length (a row whose ctx.write_mask is False left out:
+    nobody reads what it computes), chosen here, in the program: one branch a
+    rung, each slicing [at, :, :rung] out of the STACK (a layer's slab taken
+    out before the conditional would be the copy of it this rule is there to
+    spare) and attending at that length. Slot index stays absolute position and
+    the valid length is what it was, so a slot of the rung beyond it is masked
+    as it was in the whole slab: the same mathematics over fewer masked slots.
+    The kernel bounds its own loop by the valid length: where it is chosen,
+    as where the slab is one rung, the slab is read as a view of the stack.
+    A slice that read_pinned names keeps the stack's row-major layout."""
+    s, t = q.shape[1], k_stack.shape[2]
+    shapes = dict(compressed_kv=k_stack.dtype != q.dtype, q_len=s, batch=q.shape[0])
+    windowed = isinstance(window, int) and window > 0
+    heads = k_stack.ndim == 5
+    rungs = read_rungs(cfg, t, heads=heads, **shapes)
+    by_prefix = not windowed and len(rungs) > 1
+    if not by_prefix:
+        k_slab, v_slab = _slab(k_stack, at), _slab(v_stack, at)
     end = ctx.write_pos + s
     if cfg.is_block_diffusion and ctx.real_end is not None:
         # a query sees to the end of its block, so the bucket's padding is
         # kept out by the valid length (causality does it elsewhere)
         end = ctx.real_end
-    if isinstance(window, int) and window > 0:
-        k_att, v_att, kvpos, valid = _windowed_slice(new_k, new_v, end, window, s)
-        return k_att, v_att, kvpos, valid, jnp.int32(window)
-    return new_k, new_v, None, end, window
+    if windowed:
+        k_att, v_att, kvpos, valid = _windowed_slice(k_slab, v_slab, end, window, s)
+        return attend(k_att, v_att, kvpos, valid, jnp.int32(window),
+                      attention_ops.flash_enabled(cfg, k_att.shape[1], **shapes))
+    if not by_prefix:
+        return attend(k_slab, v_slab, None, end, window,
+                      attention_ops.flash_enabled(cfg, t, **shapes))
+    live = end if ctx.write_mask is None else jnp.where(ctx.write_mask, end, 0)
+
+    def to(rung):
+        def read(ks, vs):
+            head = lambda stack: jax.lax.dynamic_slice(
+                stack, (at,) + (0,) * (stack.ndim - 1),
+                (1, stack.shape[1], rung) + stack.shape[3:])[0]
+            k_att, v_att = head(ks), head(vs)
+            if read_pinned(heads, s):
+                row_major = Layout(major_to_minor=tuple(range(k_att.ndim)))
+                k_att = with_layout_constraint(k_att, row_major)
+                v_att = with_layout_constraint(v_att, row_major)
+            return attend(k_att, v_att, None, end, window, False)
+        return read
+
+    with jax.named_scope("prefix_read"):
+        return jax.lax.switch(
+            read_rung(jnp.max(live), rungs), [to(r) for r in rungs], k_stack, v_stack)
 
 
 def _attend_update_lanes(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks):
@@ -973,11 +1080,10 @@ def _attend_update_lanes(cfg, q, k, v, q_positions, entry, at, ctx, window, sink
         k=_lanes_write(entry.k, at, k, ctx.write_pos, ctx.write_mask),
         v=_lanes_write(entry.v, at, v, ctx.write_pos, ctx.write_mask),
     )
-    k_att, v_att, kvpos, valid, window = _lanes_read(
-        cfg, _slab(new.k, at), _slab(new.v, at), ctx, q.shape[1], window)
-    return _attend(
-        cfg, q, k_att, v_att, q_positions, valid, kv_positions=kvpos, window=window, sinks=sinks,
-    ), new
+    attend = lambda k_att, v_att, kvpos, valid, win, flash: _attend(
+        cfg, q, k_att, v_att, q_positions, valid, kv_positions=kvpos, window=win, sinks=sinks,
+        flash=flash)
+    return _lanes_read(cfg, new.k, new.v, at, ctx, q, window, attend), new
 
 
 def _rows_query(q, nkv: int):
@@ -1014,22 +1120,21 @@ def _attend_update_rows(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks
         k=_lanes_write(entry.k, at, k.reshape(b, s, nkv * d), ctx.write_pos, ctx.write_mask),
         v=_lanes_write(entry.v, at, v.reshape(b, s, nkv * d), ctx.write_pos, ctx.write_mask),
     )
-    new_k, new_v, kvpos, end, window = _lanes_read(  # [B, T, Nkv * D]
-        cfg, _slab(new.k, at), _slab(new.v, at), ctx, s, window)
-    if attention_ops.flash_enabled(
-        cfg, new_k.shape[1], compressed_kv=new_k.dtype != q.dtype, q_len=s, batch=b,
-    ):
-        heads = lambda a: a.reshape(*a.shape[:2], nkv, d)
-        return _attend(
-            cfg, q, heads(new_k), heads(new_v), q_positions, end,
-            kv_positions=kvpos, window=window, sinks=sinks,
-        ), new
-    out = gqa_attention(
-        _rows_query(q, nkv), new_k[:, :, None], new_v[:, :, None], q_positions, end,
-        kv_positions=kvpos, scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap,
-        window=window, sinks=sinks,
-    )
-    return _rows_own(out, nq, nkv), new
+    def attend(new_k, new_v, kvpos, end, win, flash):  # [B, T or less, Nkv * D]
+        if flash:
+            heads = lambda a: a.reshape(*a.shape[:2], nkv, d)
+            return _attend(
+                cfg, q, heads(new_k), heads(new_v), q_positions, end,
+                kv_positions=kvpos, window=win, sinks=sinks, flash=True,
+            )
+        out = gqa_attention(
+            _rows_query(q, nkv), new_k[:, :, None], new_v[:, :, None], q_positions, end,
+            kv_positions=kvpos, scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap,
+            window=win, sinks=sinks,
+        )
+        return _rows_own(out, nq, nkv)
+
+    return _lanes_read(cfg, new.k, new.v, at, ctx, q, window, attend), new
 
 
 def _attend_update_ring(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks):

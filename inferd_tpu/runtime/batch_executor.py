@@ -197,6 +197,13 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                  assignments_here=0, experts_touched_here=0, rows_multiplied=0)
             if self.engine.routes else None
         )
+        # what the programs read of the dense lanes' slabs (/stats `executor`
+        # `kv.slots_read` / `kv.slots_held`, _count_read); None where no
+        # program reads a slab by its prefix (a paged pool, a latent cache)
+        self._read = (
+            dict(slots_read=0, slots_held=0)
+            if self.pool is None and not cfg.is_mla else None
+        )
         # a model generated by blocks (/stats `executor` `diffusion.*`):
         # dispatches, live lanes x passes, places made known (those the
         # caller filled are not counted), rows computed
@@ -904,6 +911,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                     with self._mu:
                         self.engine.lengths[lane] = pos + c  # real tokens only
                         self.prefill_tokens += c
+                        # a block's valid length is its real end, a causal chunk's its bucket's
+                        self._count_read(
+                            1, pos + (c if self.cfg.is_block_diffusion else b), q_len=b)
                 pos += c
                 if self.prefill_chunk > 0 and pos < end:
                     # explicit yield between chunks: threading.Lock is NOT
@@ -1320,6 +1330,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 rec.step = step
             for e in riders:
                 eng.lengths[e.payload[0]] += 1
+            # a row lies AT its lane's length; a lane that keeps its state sits the read out
+            self._count_read(
+                eng.lanes, int((lens[active] if self.cfg.has_state_layers else lens).max()) + 1)
             self.ahead_rows += len(conts)
             self.sampled_rows += len(riders) - len(plain)
             self.logit_rows += len(plain)
@@ -1449,6 +1462,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         with self._mu:
             for lane in lanes:
                 eng.lengths[lane] += blk
+            self._count_read(eng.lanes, max(lens) + blk, q_len=blk, passes=passes)
             d = self._diffusion
             d["block_steps"] += 1
             d["lane_passes"] += len(grp) * passes
@@ -1463,6 +1477,22 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 **({"logprobs": lps[lane], "top_ids": tis[lane], "top_lps": tls[lane]}
                    if e.payload[2].top_n else {}),
             }
+
+    def _count_read(self, rows: int, longest: int, q_len: int = 1, passes: int = 1) -> None:
+        """`kv.slots_read` / `kv.slots_held` of one dispatched program (under
+        self._mu): of the slots `rows` lanes hold in a full layer's slab, those
+        the program read for `q_len` queries a row where the longest row's valid
+        length is `longest`: the rung the program chose from the lengths it was
+        handed, by the function it asks (models.qwen3.read_rungs / read_rung),
+        once a forward pass. A windowed layer on dense lanes reads its window's
+        slice and is not counted; K-step and speculative dispatches are not."""
+        if self._read is None:
+            return
+        rungs = qwen3.read_rungs(
+            self.cfg, self.max_len, q_len, rows, self.cfg.kv_jnp_dtype != self.cfg.jnp_dtype,
+            heads=self.engine.cache.k.ndim == 5)
+        self._read["slots_read"] += passes * rows * rungs[int(qwen3.read_rung(longest, rungs))]
+        self._read["slots_held"] += passes * rows * self.max_len
 
     def _count_routing(self, chosen: np.ndarray, rows: int) -> None:
         """The `moe.*` counters of one decode step (or one pass of a block
@@ -2009,13 +2039,15 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 if self.cfg.has_state_layers:  # what a session holds whatever its length
                     out["state_bytes"] = state
                     out["state_bytes_per_session"] = state // self.engine.lanes
+                if self._read is not None:
+                    out["kv"] = dict(self._read)
                 if self.engine.cache.k_loc is not None:  # what a session's windowed
                     #   layers hold whatever its length: their rings
-                    out["kv"] = {
-                        "window": int(self.cfg.sliding_window),
-                        "ring_bytes_per_session": 2 * int(self.engine.cache.k_loc.nbytes)
+                    out["kv"].update(
+                        window=int(self.cfg.sliding_window),
+                        ring_bytes_per_session=2 * int(self.engine.cache.k_loc.nbytes)
                         // self.engine.lanes,
-                    }
+                    )
             if self._moe is not None:
                 out["moe"] = dict(self._moe, experts=self.cfg.router_width,
                                   experts_held=self.cfg.num_experts)

@@ -3,7 +3,10 @@ step and a prefill, the lane programs with the sampler at both of its widths),
 the two lane programs of `g4hm-many-chat`, the two of `trinl-window-docs` and
 the two of `q3n-long-docs` (the prefill and the step the window runs; its
 preset keeps its own heads: 2 kv heads of 32 are one row a token, as the
-cell's 2 of 256 are), lowered at the tiny presets:
+cell's 2 of 256 are), lowered at the tiny presets, and (PR 49) the decode step
+and the prefill of `q4b-*` once more over lanes of 1024 slots, where a slab
+is read by its prefix (models/qwen3.read_rungs: the 64-slot lanes of the
+sixteen are under that rule's floor and keep the text they had):
 `texts()` gives their StableHLO text by name. What the text holds is the traced
 program; sizes are not the point: a change that leaves these configurations
 alone leaves every byte alone. ONE width is the point: the cells' heads are as
@@ -69,6 +72,15 @@ def texts() -> dict:
         for top_n in widths:
             out[f"{cell}.decode.top{top_n}"] = eng._decode_logits.lower(
                 eng.params, eng.cache, toks, toks, ask=ask, top_n=top_n, **mask).as_text()
+    # the two programs of `q4b-*` over lanes long enough for the read by prefix
+    cfg = cell_config("tiny")
+    eng = BatchedEngine(cfg, qwen3.init_params(cfg, jax.random.PRNGKey(0)), lanes=5, max_len=1024)
+    toks = jnp.zeros((5,), jnp.int32)
+    ask = samplib.RowAsk(jnp.zeros((5, 2), jnp.uint32), jnp.zeros((5, 4), jnp.float32))
+    out["q4b.prefill.t1024"] = eng._prefill_lane_logits.lower(
+        eng.params, eng.cache, jnp.zeros((1, 32), jnp.int32), i32, i32, i32).as_text()
+    out["q4b.decode.top0.t1024"] = eng._decode_logits.lower(
+        eng.params, eng.cache, toks, toks, ask=ask, top_n=0).as_text()
     cfg = cell_config("tiny")
     mesh = meshlib.make_mesh(meshlib.MeshPlan(pp=4), jax.devices()[:4])
     eng = PipelinedEngine(cfg, qwen3.init_params(cfg, jax.random.PRNGKey(0)), mesh,
@@ -85,7 +97,7 @@ def texts() -> dict:
 NAMES = ("q4b.prefill", "q4b.decode.top0", "q4b.decode.top8", "dsv2l.prefill", "dsv2l.decode.top0",
          "dsv2l.decode.top8", "sdar.prefill", "sdar.block", "q8b-pp4.prefill", "q8b-pp4.decode.top0",
          "g4hm.prefill", "g4hm.decode.top8", "trinl.prefill", "trinl.decode.top0",
-         "q3n.prefill", "q3n.decode.top0")
+         "q3n.prefill", "q3n.decode.top0", "q4b.prefill.t1024", "q4b.decode.top0.t1024")
 
 
 def digests(found: dict) -> dict:

@@ -291,6 +291,49 @@ def test_q4b_decode_step_writes_the_donated_cache_where_it_lies(one_chip, no_com
     assert copies and lanes_shape not in copies, [c for c in copies if c == lanes_shape]
 
 
+def test_q4b_step_and_chunk_read_their_slabs_by_prefix_and_copy_none(one_chip, no_compile_cache):
+    """The two programs of the cells `q4b-sat-chat` / `q4b-long-prompt` at their
+    own shapes (5 lanes of 4096 slots; a 512-token chunk): a layer reads its
+    slab through ONE conditional of eight branches (models/qwen3._lanes_read),
+    each slicing [layer, :, :rung] out of the stack where it lies. Held: the
+    slices are there at every rung; no branch copies the stack to the dot's
+    layout (the first form did: 1.51 GB of temporaries a step, a `copy` of
+    `bf16[36,5,4096,8,128]{4,2,3,1,0}` in every branch); the step has no
+    temporary of a slab's size (42 MB a layer; 0.002 GB in all) and the chunk
+    none beyond the lane it is handed (0.91 GB, the parent's); the donated
+    cache is aliased whole (3.02 GB)."""
+    import re
+
+    from inferd_tpu.core import sampling as samplib
+    from inferd_tpu.core.batch import BatchedEngine
+    from inferd_tpu.core.cache import KVCache
+    from inferd_tpu.models import qwen3
+
+    cfg = get_config("qwen3-4b")
+    lanes, max_len = 5, 4096
+    rungs = qwen3.read_rungs(cfg, max_len, 1, lanes, False, heads=True)
+    assert rungs == tuple(range(512, 4097, 512))
+    params = _on(jax.eval_shape(lambda: qwen3.init_params(cfg, jax.random.PRNGKey(0))), one_chip)
+    eng = BatchedEngine(cfg, None, lanes=lanes, max_len=64)
+    cache = jax.eval_shape(lambda: KVCache.create(cfg, cfg.num_layers, lanes, max_len))
+    toks = _sds((lanes,), jnp.int32, one_chip)
+    ask = samplib.RowAsk(_sds((lanes, 2), jnp.uint32, one_chip), _sds((lanes, 4), jnp.float32, one_chip))
+    i32 = _sds((), jnp.int32, one_chip)
+    step = eng._decode_logits.lower(params, _on(cache, one_chip), toks, toks, ask=ask, top_n=0).compile()
+    chunk = eng._prefill_lane_logits.lower(
+        params, _on(cache, one_chip), _sds((1, 512), jnp.int32, one_chip), i32, i32, i32).compile()
+    slab = lanes * max_len * cfg.num_kv_heads * cfg.head_dim * 2
+    for program, rows, temp in ((step, lanes, slab), (chunk, 1, 0.95e9)):
+        mem, text = program.memory_analysis(), program.as_text()
+        assert mem.temp_size_in_bytes < temp
+        assert mem.alias_size_in_bytes >= cache.k.size * 2 * 2
+        for rung in rungs:
+            assert f"dynamic_slice_sizes={{1,{rows},{rung},{cfg.num_kv_heads},{cfg.head_dim}}}" in text
+        stack = f"bf16[{cfg.num_layers},{rows},{max_len},{cfg.num_kv_heads},{cfg.head_dim}]"
+        copies = re.findall(r"= (\S+?)\{[^ ]* copy\(", text)
+        assert stack not in copies
+
+
 def test_dsv2l_lane_programs_compile_and_decode_expands_no_head_over_the_cache(
     one_chip, no_compile_cache, expert_kernel
 ):

@@ -1028,7 +1028,10 @@ def test_a_cells_program_lowers_to_its_recorded_text(lowered_programs, name):
     layer multiplies grouped by expert, and a layer finds its experts in the
     stack where it lies); the seven without an expert kept the parent's
     bytes. PR 48 added the two of `q3n.*` (a second recurrence and state
-    layers that route) and left the fourteen digests as they were. A PR that
+    layers that route) and left the fourteen digests as they were. PR 49 left
+    the sixteen as they were (a 64-slot slab is under the floor of the read
+    by prefix, models/qwen3.read_rungs) and added `q4b.*.t1024`, the step and
+    the prefill over lanes of 1024 slots, where that rule engages. A PR that
     changes one of them on purpose runs `python tests/lowered_programs.py
     --record` and says so."""
     import json

@@ -5,6 +5,7 @@ over the committed fixture, wire trace-key compatibility, the perf-gate
 span-overhead check, and the satellite fixes (Profiler.stop wedge,
 Histogram.summary lock consistency, dashboard/collector hop columns)."""
 
+import glob
 import json
 import os
 import random
@@ -391,29 +392,38 @@ def test_perf_check_cli_stats_flag(tmp_path, capsys):
 
 
 def test_profiler_stop_unwedges_after_failure(monkeypatch, tmp_path):
-    """A raising jax.profiler.stop_trace must not leave the profiler
+    """A capture whose session raises as it stops must not leave the profiler
     stuck 'running' forever (the /profile endpoint would 409 every
     subsequent start with no recovery short of a restart)."""
-    import jax
+    from jax._src.lib import _profiler
 
     from inferd_tpu.utils.profiling import Profiler
 
-    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **kw: None)
+    class Session:
+        ends = [RuntimeError("trace finalization failed"), b"xspace"]
 
-    def boom():
-        raise RuntimeError("trace finalization failed")
+        def __init__(self, options):
+            pass
 
-    monkeypatch.setattr(jax.profiler, "stop_trace", boom)
+        def stop(self):
+            end = self.ends.pop(0)
+            if isinstance(end, Exception):
+                raise end
+            return end
+
+    monkeypatch.setattr(_profiler, "ProfilerSession", Session)
     p = Profiler(base_dir=str(tmp_path))
     p.start("x")
     with pytest.raises(RuntimeError, match="finalization failed"):
         p.stop()
     assert p.active_dir is None  # cleared despite the failure
     # fully recovered: start works again (no "already running" 409)...
-    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     d = p.start("y")
-    # ...and a clean stop returns the new dir
+    # ...and a clean stop returns the new dir, the session's bytes in it
     assert p.stop() == d
+    (written,) = glob.glob(os.path.join(d, "plugins", "profile", "*", "*.xplane.pb"))
+    with open(written, "rb") as f:
+        assert f.read() == b"xspace"
     # a second stop correctly reports nothing running
     with pytest.raises(RuntimeError, match="no profile running"):
         p.stop()
