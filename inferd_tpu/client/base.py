@@ -762,6 +762,8 @@ class GenerationClient:
         want = self._decode_ask(
             s, logprob_sink is not None, top_n if top_sink is not None else 0
         )
+        if eos_token_id is not None:
+            want["eos"] = int(eos_token_id)
         try:
             whole = len(prompt_ids) // blk * blk
             chunk = max(blk, self.prefill_chunk // blk * blk)
@@ -773,7 +775,11 @@ class GenerationClient:
                     await self._forward(session_id, toks, pos, want_logits=False)
             pos, head, key = whole, prompt_ids[whole:], None
             while len(out) < max_new_tokens and (not out or out[-1] != eos_token_id):
-                call = dict(want, known=len(head),
+                # `ahead`: the block hops that follow this one unless `eos`
+                # ends the generation, as a decode hop's ask counts tokens
+                # (runtime/executor.parse_block)
+                left = max_new_tokens - len(out) - (blk - len(head))
+                call = dict(want, known=len(head), ahead=max(0, -(-left // blk)),
                             **({"seed": seed} if key is None else {"key": key}))
                 with self.tracer.span("step", "wire", attrs={"start_pos": pos, "n": blk}):
                     res = await self._forward(

@@ -10,6 +10,7 @@ running on host between steps.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import NamedTuple, Optional
 
 import jax
@@ -405,6 +406,19 @@ def ahead_rows(packed: jax.Array, toks, keys, ahead):
     last = jax.lax.bitcast_convert_type(packed[:, 1:3], jnp.uint32)
     return (jnp.where(ahead, packed[:, 0], toks),
             jnp.where(ahead[:, None], last, keys))
+
+
+@partial(jax.jit, static_argnames=("block",))
+def ahead_block_keys(packed: jax.Array, keys, ahead, block: int):
+    """`ahead_rows` for a block step that runs ahead of its hops (a model
+    generated by blocks of `block`): nothing of the next block is known, so
+    all a row of `ahead` [L] bool takes from the step before is the key it
+    left ON THE DEVICE: in its `packed` (`pack_bits` of tokens [L, block],
+    passes [L, block], keys [L, 2], ...) the two columns after the first
+    2 x `block`. Every other row keeps the host's `keys` [L, 2] uint32, whose
+    shape and dtype come back; one small program a width of `packed`."""
+    last = jax.lax.bitcast_convert_type(packed[:, 2 * block:2 * block + 2], jnp.uint32)
+    return jnp.where(ahead[:, None], last, keys)
 
 
 def unpack_rows(packed, top_n: int, k: int = 0):

@@ -34,7 +34,9 @@ Concurrency design (process() runs on the node's worker thread pool):
     of call instead of the decode step: `block`, a whole block at a
     frontier that is a multiple of the block length. Block calls of
     different lanes ride ONE BatchedEngine._block_step through the same
-    window as decode steps do; the frontier advances by a block a call;
+    window as decode steps do, one step ahead as they are (a block hop's
+    `block` call promises the hops that follow it as a decode hop's ask
+    does); the frontier advances by a block a call;
   * whole-model executor: is_first and is_last (tokens in, a token or
     last-token logits out) — like MeshExecutor it hosts a 1-stage swarm topology.
 """
@@ -79,29 +81,27 @@ class _Step:
     """One dispatched decode program of the dense lanes and what it ran
     for. `finish` (BatchedExecutor._finish: any thread, once) waits for it,
     copies its one packed array out and fills `toks` / `keys` / `replies` /
-    `rows`; until then only the device holds what it chose."""
+    `rows` (`out`); until then only the device holds what it chose."""
 
     __slots__ = (
-        "packed", "logits", "top_n", "asks", "plain", "lanes", "carry", "prev",
+        "packed", "logits", "top_n", "asks", "plain", "lanes", "prev",
         "program", "t_call", "t0", "t_done", "t_out", "lock", "done", "error",
         "toks", "keys", "replies", "rows", "released",
     )
 
-    def __init__(self, packed, logits, top_n, asks, plain, lanes, carry, prev, program):
+    def __init__(self, packed, logits, top_n, asks, plain, lanes, prev, program):
         self.packed, self.logits, self.top_n = packed, logits, top_n
         self.asks = asks  # {lane: SampleAsk} of the rows that chose a token
         self.plain = plain  # lanes answered with their logits row
         self.lanes = lanes  # every row run for a session
-        # {lane: ask} of the rows a waiting hop rode whose ask promised a
-        # hop to follow: the next drain runs that hop's row ahead of it
-        self.carry = carry
         self.prev = prev  # the step dispatched before: `device` starts at its end
         self.program = program
         self.t_call = tracelib.now()
         self.t0 = self.t_done = self.t_out = 0.0
         self.lock = threading.Lock()
         self.done = False
-        # set once the next drain has taken `carry` (and run those rows)
+        # set once the next drain has run the rows this step's riders
+        # promised (BatchedExecutor._carry); the steps of one drain share it
         self.released = threading.Event()
         self.error: Optional[Exception] = None
         self.toks = self.keys = self.replies = self.rows = None
@@ -111,18 +111,102 @@ class _Step:
         token's reply, or the logits row of a hop that asked for none."""
         return self.replies[lane] if lane in self.replies else self.rows[lane]
 
+    def span(self) -> Dict[str, Any]:
+        """The attributes of the step's `device` span."""
+        return {"kind": "decode", "tokens": len(self.lanes),
+                "cobatch": len(self.lanes), "program": self.program}
+
+    def fed(self, lane: int, tok, ask, ran) -> bool:
+        """Whether a hop of `lane` that carries `tok` under `ask` is the one
+        a row was run for under `ran`, from what this step (finished) chose:
+        its token and its key, the same sampling and log-probabilities."""
+        return (
+            ask.sampling == ran.sampling and ask.want == ran.want
+            and self.toks[lane] == tok and self.keys[lane] == ask.key.tolist()
+        )
+
+    def ends(self, lane: int, eos: int) -> bool:
+        """Whether what this step (finished) chose for `lane` ends its
+        generation."""
+        return self.toks[lane] == eos
+
+    def out(self, host: np.ndarray, ex: "BatchedExecutor") -> None:
+        """Read the step's packed array, on the host now (`_finish`)."""
+        self.replies, routed = samplib.row_replies(
+            host, self.top_n, self.asks,
+            ex.cfg.num_experts_per_tok if ex.engine.routes else 0,
+        )
+        self.toks = host[:, 0].tolist()
+        self.keys = host[:, 1:3].view(np.uint32).tolist()
+        if routed is not None and self.lanes:
+            ex._count_routing(routed[:, self.lanes], ex.engine.lanes)
+
+
+class _BlockStep(_Step):
+    """One dispatched BatchedEngine._block_step (a model generated by
+    blocks): a `_Step` whose rows are whole blocks, `asks` {lane: BlockCall}
+    for every lane it ran. `toks[lane]` are the tokens it made known (not
+    those the caller filled)."""
+
+    __slots__ = ("chosen", "made", "passes", "block")
+
+    def __init__(self, packed, chosen, top_n, asks, prev, program, made, passes, block):
+        super().__init__(packed, None, top_n, asks, [], list(asks), prev, program)
+        self.chosen = chosen  # the experts every row chose in every pass | None
+        self.made, self.passes, self.block = made, passes, block
+
+    def span(self) -> Dict[str, Any]:
+        live = len(self.lanes)
+        return {"kind": "block", "tokens": self.made, "cobatch": live,
+                "rows": live * self.block * self.passes, "passes": self.passes,
+                "program": self.program}
+
+    def fed(self, lane: int, tok, ask, ran) -> bool:
+        # a block of which the caller knows nothing: its tokens are not read
+        return (
+            ask.known == 0 and ask.sampling == ran.sampling and ask.top_n == ran.top_n
+            and self.keys[lane] == ask.key.tolist()
+        )
+
+    def ends(self, lane: int, eos: int) -> bool:
+        return eos in self.toks[lane]
+
+    def out(self, host: np.ndarray, ex: "BatchedExecutor") -> None:
+        blk, top_n = self.block, self.top_n
+        x, order, self.keys, lps, tis, tls = (a.tolist() for a in samplib.unpack_bits(
+            host, (np.int32, (blk,)), (np.int32, (blk,)), (np.uint32, (2,)),
+            (np.float32, (blk,)), (np.int32, (blk, top_n)), (np.float32, (blk, top_n)),
+        ))
+        self.toks = {lane: [t for t, at in zip(x[lane], order[lane]) if at >= 0]
+                     for lane in self.lanes}
+        self.replies = {
+            lane: {
+                "tokens": [x[lane]],
+                "order": order[lane],  # the pass that made each place known (-1: the caller)
+                "key": self.keys[lane],
+                **({"logprobs": lps[lane], "top_ids": tis[lane], "top_lps": tls[lane]}
+                   if self.asks[lane].top_n else {}),
+            }
+            for lane in self.lanes
+        }
+        if self.chosen is not None:  # came out with the tokens: no wait
+            routed = np.asarray(self.chosen)[:, :, self.lanes]  # [passes, layers, live, block, k]
+            self.chosen = None
+            for per_pass in routed.reshape(*routed.shape[:2], -1, routed.shape[-1]):
+                ex._count_routing(per_pass, ex.engine.lanes * blk)
+
 
 class _Ahead:
     """A row run for a lane before its hop arrived (under the executor's
-    `_mu`): the position it was written at (the lane's length), the step
-    whose output row was its input (`src`: the token and key its hop must
-    carry), the step that ran it (`step`: what answers the hop) and the ask
-    it was run under."""
+    `_mu`): the position it was written at (the lane's length) and how many
+    positions it covers (1; a block), the step whose output row was its
+    input (`src`: the token and key its hop must carry), the step that ran
+    it (`step`: what answers the hop) and the ask it was run under."""
 
-    __slots__ = ("pos", "src", "step", "ask")
+    __slots__ = ("pos", "width", "src", "step", "ask")
 
-    def __init__(self, pos: int, src: _Step, ask):
-        self.pos, self.src, self.step, self.ask = pos, src, None, ask
+    def __init__(self, pos: int, width: int, src: _Step, ask):
+        self.pos, self.width, self.src, self.step, self.ask = pos, width, src, None, ask
 
 
 class BatchedExecutor(SpecServing, AdapterBindingMixin):
@@ -176,11 +260,16 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         # and those answered with their [V] logits row (/stats `executor`)
         self.sampled_rows = 0
         self.logit_rows = 0
-        # one step ahead (dense lanes, `_decode_ahead`): lane -> the row a
-        # step ran before its hop arrived, unclaimed; the last step
-        # dispatched (the only one that may still be running when a drain
-        # begins); rows run ahead, those that answered a hop, those dropped
+        # one step ahead (dense lanes, `_decode_ahead`; a row is a token's,
+        # or a whole block's for a model generated by blocks): lane -> the
+        # row a step ran before its hop arrived, unclaimed; lane -> (the step
+        # its waiting hop rode, the hop's ask) where that ask promised a hop
+        # to follow: the next drain runs that hop's row ahead of it; the last
+        # step dispatched (the only one whose output a drain may find on the
+        # device alone); rows run ahead, those that answered a hop, those
+        # dropped
         self._ahead: Dict[int, _Ahead] = {}
+        self._carry: Dict[int, tuple] = {}
         self._last_step: Optional[_Step] = None
         # the logits of the last prefill chunk dispatched on dense lanes:
         # while they are not ready a chunk is on the device (`_settle`)
@@ -206,9 +295,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         )
         # a model generated by blocks (/stats `executor` `diffusion.*`):
         # dispatches, live lanes x passes, places made known (those the
-        # caller filled are not counted), rows computed
+        # caller filled are not counted), rows computed, block hops answered
         self._diffusion = (
-            dict(block_steps=0, lane_passes=0, tokens=0, rows=0)
+            dict(block_steps=0, lane_passes=0, tokens=0, rows=0, hops=0)
             if cfg.is_block_diffusion else None
         )
         if cfg.is_block_diffusion and (block_size or max_len % cfg.block_length):
@@ -580,13 +669,14 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         the lane's length, and whoever writes there next overwrites it."""
         if self._ahead.pop(lane, None) is not None:
             self.ahead_dropped += 1
-        if self._last_step is not None:
-            self._last_step.carry.pop(lane, None)
+        self._carry.pop(lane, None)
 
     def _claim(self, lane: int, pos: int, tok, ask) -> bool:
         """Whether the call at `pos` of `lane` is the hop a row was run
         ahead for: a one-token hop at the row's position, with the token
-        and key the step before chose and the ask the row was run under.
+        and key the step before chose and the ask the row was run under (a
+        block hop: nothing known of its block, the key the step before
+        left: `_Step.fed`).
         Anything else drops the row and is served as if none had been run;
         on a lane with a recurrent state, which the row has already moved,
         it is refused as a replay of such a lane is, and the row stays for
@@ -597,8 +687,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         src = rec.src
         if (
             ask is not None and src.done and src.error is None and rec.pos == pos
-            and ask.sampling == rec.ask.sampling and ask.want == rec.ask.want
-            and src.toks[lane] == tok and src.keys[lane] == ask.key.tolist()
+            and src.fed(lane, tok, ask, rec.ask)
         ):
             return True
         if self.cfg.has_state_layers and pos:
@@ -613,11 +702,11 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
 
     def _write_lens(self) -> np.ndarray:
         """The lanes' lengths as a program that writes EVERY lane's row
-        takes them: a lane with an unclaimed row run ahead writes one past
-        it, so that the row survives the steps its session sits out."""
+        takes them: a lane with an unclaimed row run ahead writes past it,
+        so that the row survives the steps its session sits out."""
         lens = np.asarray(self.engine.lengths, np.int32)
-        for lane in self._ahead:
-            lens[lane] += 1
+        for lane, rec in self._ahead.items():
+            lens[lane] += rec.width
         return lens
 
     def _free_lane(self, lane: int) -> None:
@@ -671,8 +760,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             raise ValueError(f"{self.cfg.name} is not generated by blocks")
         call = parse_block(payload, blk) if kind == "block" else None
         # a hop that asks for its token (parse_decode_ask) is answered with
-        # it; any other with its logits row
-        ask = parse_decode_ask(payload) if decode and not payload.get("decode_steps") else None
+        # it; any other with its logits row; a block hop's ask is its call
+        ask = parse_decode_ask(payload) if decode and not payload.get("decode_steps") else call
         with self._mu:
             if self._inflight.get(session_id):
                 # a duplicate/replayed request racing the original would
@@ -684,10 +773,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             lane = self._lane_for(session_id, new_ok=start_pos == 0)
             owner = f"session {session_id}, lane {lane}"
             have = self.engine.lengths[lane]
-            if self._last_step is not None:
-                # whatever this call is, the lane is back: the next drain
-                # runs nothing ahead on the strength of the hop before it
-                self._last_step.carry.pop(lane, None)
+            # whatever this call is, the lane is back: the next drain runs
+            # nothing ahead on the strength of the hop before it
+            self._carry.pop(lane, None)
             if start_pos == 0 and have:
                 # session restart under the same id: reset the lane
                 self.engine.lengths[lane] = 0
@@ -772,7 +860,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
 
         try:
             if call is not None:
-                res = self._batcher.submit((lane, toks[0, :blk], call))
+                res = self._decode_batched(session_id, lane, toks[0, :blk], call)
                 return {**res, "real_len": blk, "start_pos": start_pos}
             if decode:
                 ks = parse_kstep(payload, self.cap - start_pos)
@@ -940,7 +1028,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             at["bytes"] = out.nbytes
         return out, saved
 
-    def _decode_batched(self, session_id: str, lane: int, token: int, ks=None):
+    def _decode_batched(self, session_id: str, lane: int, token, ks=None):
+        """Hand a hop (a decode hop's token, a block hop's tokens) and its
+        ask to the window and return what it is answered with."""
         res = self._batcher.submit((lane, token, ks))
         if not isinstance(res, _Step):
             return res
@@ -952,7 +1042,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         if res.error is not None:
             raise res.error
         tracelib.mark("t_out", res.t_out)  # `deliver` starts (runtime/window.py submit)
-        if lane in res.carry:
+        if lane in self._carry:
             # the next drain runs this session's next row ahead if its hop
             # is not back before it: give that drain a step's time to come
             # (a session that returned at once would ride again, and again)
@@ -1001,7 +1091,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             entries = self._batcher.drain_pending()
             blocks = [e for e in entries if isinstance(e.payload[2], BlockCall)]
             if blocks:  # a model generated by blocks has no other batched call
-                self._run_block_steps(blocks)
+                self._decode_ahead(blocks, self._dispatch_blocks)
                 return
             # one-token hops, with an ask (SampleAsk) or without (None),
             # ride ONE program; only K-step calls dispatch apart
@@ -1010,7 +1100,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             if single:
                 try:
                     if self.pool is None:
-                        self._decode_ahead(single)
+                        self._decode_ahead(single, self._dispatch_rows)
                     else:
                         self._decode_dispatch(single)
                 except Exception as exc:
@@ -1133,15 +1223,25 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
     def _runs_ahead(self, lane: int, ask, src: _Step) -> bool:
         """Whether the hop after the one `ask` came with gets its row run
         before it arrives (under _mu; the lane's length is that row's
-        position): the ask promised the hop, the row and the slot an idle
-        lane writes past it fit, the token `src` chose for it, where the
-        host knows it already, is not the one that ends the generation,
-        and no speculative round reads the lanes' lengths."""
+        position): the ask promised the hop, the row and what an idle lane
+        writes past it fit (two rows of the hop's width: a token, a block),
+        what `src` chose for it, where the host knows it already, does not
+        end the generation (the token; a token IN the block), and no
+        speculative round reads the lanes' lengths."""
         return (
             ask is not None and ask.ahead >= 1 and self._spec is None
-            and self.engine.lengths[lane] + 2 <= self.cap
-            and not (src.done and (src.error is not None or src.toks[lane] == ask.eos))
+            and self.engine.lengths[lane] + 2 * self.cfg.block_length <= self.cap
+            and not (src.done and (src.error is not None or src.ends(lane, ask.eos)))
         )
+
+    def _take_carry(self, conts, here=()) -> None:
+        """The promises of the riders since the last drain (under _mu): the
+        next row of every such lane that is not back (`here`: the lanes of
+        this drain's entries) goes into `conts` where `_runs_ahead` lets it."""
+        carried, self._carry = self._carry, {}
+        for lane, (src, ask) in carried.items():
+            if lane not in here and self._runs_ahead(lane, ask, src):
+                conts[lane] = (src, ask)
 
     def _settle(self) -> None:
         """Before a drain (under _dev_lock): while a prefill chunk is on the
@@ -1156,20 +1256,24 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         if chunk is not None and prev is not None and not prev.done and not chunk.is_ready():
             self._finish(prev)
 
-    def _decode_ahead(self, entries) -> None:
-        """The one-token hops of a drain on dense lanes (under _dev_lock),
-        one step ahead of the sessions. In this order:
+    def _decode_ahead(self, entries, dispatch) -> None:
+        """The hops of a drain on dense lanes (under _dev_lock), one step
+        ahead of the sessions: one-token hops (`dispatch` is
+        `_dispatch_rows`) or the block hops of a model generated by blocks
+        (`_dispatch_blocks`); a ROW below is what a hop asks for, a token's
+        or a block's. In this order:
 
         1. Each entry is CLAIMED (`_claim`: a step already ran the row this
            hop asks for, before it arrived) or RIDES (no row was run: a
-           session's first decode hop, a hop whose ask promised nothing, a
-           raw /forward). ONE `_decode_logits` is dispatched for the rows
-           to come: for every claimed lane whose ask says a hop follows,
-           and for every lane that rode the last step under such an ask and
-           is not back yet, the NEXT row, its token and key taken from the
-           last step's packed array where the device still holds it alone
-           (core.sampling.ahead_rows: no round trip); for every rider its
-           own row from its host token, as before.
+           session's first decode or block hop, a hop whose ask promised
+           nothing, a raw /forward). ONE program (a sampling group, for
+           blocks) is dispatched for the rows to come: for every claimed
+           lane whose ask says a hop follows, and for every lane that rode
+           the last step under such an ask and is not back yet, the NEXT
+           row, fed by what the last step's packed array holds for it where
+           the device still holds it alone (core.sampling.ahead_rows /
+           ahead_block_keys: no round trip); for every rider its own row
+           from what its hop carries, as before.
         2. The step dispatched at the previous drain is waited for and
            copied out (`_finish`), and each claimed entry is answered from
            the step that ran its row. The sessions take their turn while
@@ -1185,7 +1289,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         `batched_steps` counts the programs dispatched and `batched_tokens`
         the rows they ran for a session (the drain counted one step and its
         entries)."""
-        eng = self.engine
+        eng, width = self.engine, self.cfg.block_length
         prev = self._last_step
         claimed, riders, answers, conts = [], [], {}, {}
         with self._mu:
@@ -1200,45 +1304,35 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             for e in claimed:  # the hop takes its row: the frontier moves past it
                 lane, _, ask = e.payload
                 answers[lane] = src = self._ahead.pop(lane).step
-                eng.lengths[lane] += 1
+                eng.lengths[lane] += width
                 if self._runs_ahead(lane, ask, src):
                     conts[lane] = (src, ask)
-            if prev is not None:
-                here = {e.payload[0] for e in entries}
-                for lane, ask in prev.carry.items():
-                    if lane not in here and self._runs_ahead(lane, ask, prev):
-                        conts[lane] = (prev, ask)
-                prev.carry = {}
+            if self._carry:
+                self._take_carry(conts, {e.payload[0] for e in entries})
             self.ahead_claimed += len(claimed)
-            self.sampled_rows += len(claimed)
-        programs, rows = 0, len(conts)
-        step = None
-        if conts or riders:
-            step = self._dispatch_rows(conts, riders)
-            programs += 1
+            if self._diffusion is None:
+                self.sampled_rows += len(claimed)
+            else:
+                self._diffusion["hops"] += len(claimed)
+        rows = len(conts)
+        steps = dispatch(conts, riders) if conts or riders else []
         if prev is not None:
             prev.released.set()
         # nobody is ahead: the flusher answers its riders itself, and runs
-        # their next rows right behind the step they ride
+        # their next rows right behind the steps they ride
         sync = bool(riders) and not claimed and not conts
+        programs = len(steps)
         if sync:
+            nxt = {}
             with self._mu:
-                nxt = {lane: (step, ask) for lane, ask in step.carry.items()
-                       if self._runs_ahead(lane, ask, step)}
-                step.carry = {}
+                self._take_carry(nxt)
             if nxt:
-                self._dispatch_rows(nxt, [])
-                programs, rows = programs + 1, rows + len(nxt)
+                programs, rows = programs + len(dispatch(nxt, [])), rows + len(nxt)
         if prev is not None:
-            self._finish(prev)
-            if prev.error is None:
-                # the step's own time: what the drain waited for is only
-                # what was left of it (runtime/window.py `step_hint`)
-                self._batcher.step_hint = prev.t_done - prev.t0
+            self._wait_out(prev)
         if sync:
-            self._finish(step)
-            if step.error is None:
-                self._batcher.step_hint = step.t_done - step.t0
+            for step in steps:
+                self._wait_out(step)
         self._batcher.stamp_out(claimed + riders if sync else claimed)  # `deliver` starts
         for e in claimed:
             lane = e.payload[0]
@@ -1246,8 +1340,12 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 e.error = answers[lane].error
             else:
                 e.result = answers[lane].replies[lane]
+        ran = {lane: step for step in steps for lane in step.lanes} if riders else {}
         for e in riders:
             lane = e.payload[0]
+            step = ran.get(lane)
+            if step is None:
+                continue  # its dispatch failed: it has its error
             if not sync:
                 e.result = step  # `_decode_batched` waits for it
             elif step.error is not None:
@@ -1258,7 +1356,51 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         self._batcher.n_steps += programs - 1
         self._batcher.n_served += rows - len(claimed) - failed
 
-    def _dispatch_rows(self, conts, riders) -> _Step:
+    def _wait_out(self, step: _Step) -> None:
+        """A drain waits for `step` (`_finish`) and leaves the window the
+        step's own time: what the drain waited for is only what was left of
+        it (runtime/window.py `step_hint`)."""
+        self._finish(step)
+        if step.error is None:
+            self._batcher.step_hint = step.t_done - step.t0
+
+    def _fed_by(self, conts):
+        """Where the rows of `conts` take what the step before left for them
+        (under _dev_lock, before a dispatch): the last step dispatched is
+        the only one whose output the device may still hold alone; an older
+        one is finished here."""
+        last = self._last_step
+        for src, _ in conts.values():
+            if not src.done and src is not last:
+                self._finish(src)
+        return last
+
+    def _record_ahead(self, conts):
+        """Record the rows of `conts` as run ahead (under _mu, before their
+        dispatch; `_recorded` undoes it if that fails): each lies AT its
+        lane's length and counts as written (a ring has given up its oldest
+        slot). Returns the records and the lengths the program is handed
+        (`_write_lens`, a row of `conts` at its own lane's length)."""
+        width, made = self.cfg.block_length, {}
+        for lane, (src, ask) in conts.items():
+            at = self.engine.lengths[lane]
+            made[lane] = self._ahead[lane] = _Ahead(at, width, src, ask)
+            self._lane_hi[lane] = max(self._lane_hi.get(lane, 0), at + width)
+        lens = self._write_lens()
+        for lane in conts:
+            lens[lane] -= width
+        return made, lens
+
+    def _recorded(self, made: Dict[int, _Ahead], step: Optional[_Step]) -> None:
+        """The dispatch of the rows recorded in `made` returned `step`, or
+        failed (None): the records go (under _mu)."""
+        for lane, rec in made.items():
+            if step is not None:
+                rec.step = step
+            elif self._ahead.get(lane) is rec:
+                del self._ahead[lane]
+
+    def _dispatch_rows(self, conts, riders) -> list:
         """ONE `_decode_logits` (under _dev_lock) over the rows of `conts`,
         {lane: (the step whose output is the row's input, the ask it runs
         under)}: rows run AHEAD of their hops, recorded in `_ahead`; and of
@@ -1266,26 +1408,18 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         The program, its shapes and the placement of what it is handed are
         those of a step fed from the host alone: the tokens and keys a
         still-running step holds come through core.sampling.ahead_rows as
-        arrays of the host arrays' kind."""
+        arrays of the host arrays' kind. Returns the step, in a list."""
         eng = self.engine
-        last = self._last_step
-        for src, _ in conts.values():
-            if not src.done and src is not last:
-                self._finish(src)  # only the last step may still hold its output alone
+        last = self._fed_by(conts)
         toks = np.zeros((eng.lanes,), np.int32)
         active = np.zeros((eng.lanes,), bool)
         from_dev = np.zeros((eng.lanes,), bool)
-        asks, plain, carry, made = {}, [], {}, {}
+        asks, plain, carry = {}, [], {}
         with self._mu:
             ids = list(self._lane_slot)
-            for lane, (src, ask) in conts.items():
+            for lane, (_, ask) in conts.items():
                 asks[lane], active[lane] = ask, True
-                made[lane] = self._ahead[lane] = _Ahead(eng.lengths[lane], src, ask)
-                # the row counts as written (a ring has given up its oldest slot)
-                self._lane_hi[lane] = max(self._lane_hi.get(lane, 0), eng.lengths[lane] + 1)
-            lens = self._write_lens()
-            for lane in conts:
-                lens[lane] -= 1  # the row itself lies AT the lane's length
+            made, lens = self._record_ahead(conts)
             for e in riders:
                 lane, tok, ask = e.payload
                 toks[lane], active[lane] = tok, True
@@ -1314,39 +1448,139 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             )
         except Exception:
             with self._mu:
-                for lane, rec in made.items():
-                    if self._ahead.get(lane) is rec:
-                        del self._ahead[lane]
+                self._recorded(made, None)
             raise
         # the transfer is queued behind the step: the small array is on
         # its way when the device is done
         packed.copy_to_host_async()
         step = self._last_step = _Step(
             packed, logits if plain else None, top_n, asks, plain,
-            list(conts) + [e.payload[0] for e in riders], carry, last, program_name(fn),
+            list(conts) + [e.payload[0] for e in riders], last, program_name(fn),
         )
         with self._mu:
-            for rec in made.values():
-                rec.step = step
+            self._recorded(made, step)
             for e in riders:
                 eng.lengths[e.payload[0]] += 1
+            for lane, ask in carry.items():
+                self._carry[lane] = (step, ask)
             # a row lies AT its lane's length; a lane that keeps its state sits the read out
             self._count_read(
                 eng.lanes, int((lens[active] if self.cfg.has_state_layers else lens).max()) + 1)
             self.ahead_rows += len(conts)
             self.sampled_rows += len(riders) - len(plain)
             self.logit_rows += len(plain)
+        return [step]
+
+    def _dispatch_blocks(self, conts, riders) -> list:
+        """`_dispatch_rows` for a model generated by blocks: ONE
+        BatchedEngine._block_step for the lanes of `conts` and `riders` that
+        share a sampling config (static in the program; greedy callers are
+        one group). Returns the steps dispatched. A failed dispatch fails
+        its own riders and leaves no row recorded (the isolation of
+        _run_decode_batch): what was claimed is answered from steps that
+        ran before."""
+        groups: Dict[tuple, tuple] = {}
+        for lane, cont in conts.items():
+            groups.setdefault(cont[1].sampling, ({}, []))[0][lane] = cont
+        for e in riders:
+            groups.setdefault(e.payload[2].sampling, ({}, []))[1].append(e)
+        steps = []
+        poisoned: Optional[Exception] = None
+        for sampling, (ahead, grp) in groups.items():
+            try:
+                if poisoned is not None:
+                    raise RuntimeError(
+                        "KV cache invalidated by an earlier dispatch failure "
+                        f"in this window: {poisoned}"
+                    )
+                steps.append(self._block_dispatch(sampling, ahead, grp))
+            except Exception as exc:
+                for e in grp:
+                    e.error = exc
+                self._batcher.n_served -= len(grp) + len(ahead)  # see _run_decode_batch
+                if not cache_intact(self.engine.cache):
+                    poisoned = exc
+        for step in steps[1:]:  # one drain: its riders wait for the same next drain
+            step.released = steps[0].released
+        return steps
+
+    def _block_dispatch(self, sampling: tuple, conts, grp) -> _BlockStep:
+        """One block step, at the widest top-n any of its lanes asked: the
+        NEXT block of the lanes of `conts` (nothing of it known, the key the
+        block before left: core.sampling.ahead_block_keys where the device
+        holds it alone), the block each entry of `grp` carries. The inputs
+        have the shapes and dtypes of a step fed from the host alone. A
+        rider's frontier advances by a block; one run ahead moves when its
+        hop claims it."""
+        import jax.numpy as jnp
+
+        eng, blk = self.engine, self.cfg.block_length
+        passes = self.cfg.denoising_steps + 1
+        last = self._fed_by(conts)
+        toks = np.zeros((eng.lanes, blk), np.int32)
+        known = np.zeros((eng.lanes, blk), bool)
+        live = np.zeros((eng.lanes,), bool)
+        keys = np.zeros((eng.lanes, 2), np.uint32)
+        from_dev = np.zeros((eng.lanes,), bool)
+        asks = {lane: ask for lane, (_, ask) in conts.items()}
+        for e in grp:
+            lane, tokens, call = e.payload
+            toks[lane], keys[lane], asks[lane] = tokens, call.key, call
+            known[lane, :call.known] = True
+        lanes = list(asks)
+        live[lanes] = True
+        with self._mu:
+            made, lens = self._record_ahead(conts)
+        fn = eng._block_step
+        try:
+            for lane, (src, _) in conts.items():
+                if src.done:  # the host has read it (see _dispatch_rows)
+                    keys[lane] = src.keys[lane]
+                else:
+                    from_dev[lane] = True
+            if from_dev.any():
+                keys = samplib.ahead_block_keys(last.packed, keys, from_dev, block=blk)
+            top_n = max(ask.top_n for ask in asks.values())
+            t, tk, tp, mp = sampling
+            eng.cache, packed, chosen = fn(
+                eng.params, eng.cache, jnp.asarray(toks), jnp.asarray(known),
+                jnp.asarray(lens), jnp.asarray(live), jnp.asarray(keys),
+                temperature=t, top_k=tk, top_p=tp, min_p=mp, top_n=top_n,
+            )
+        except Exception:
+            with self._mu:
+                self._recorded(made, None)
+            raise
+        packed.copy_to_host_async()  # queued behind the step (see _dispatch_rows)
+        tokens = int((~known[lanes]).sum())
+        step = self._last_step = _BlockStep(
+            packed, chosen, top_n, asks, last, program_name(fn), tokens, passes, blk)
+        with self._mu:
+            self._recorded(made, step)
+            for e in grp:
+                lane, _, call = e.payload
+                eng.lengths[lane] += blk
+                if call.ahead >= 1:
+                    self._carry[lane] = (step, call)
+            self._count_read(eng.lanes, int(lens.max()) + blk, q_len=blk, passes=passes)
+            self.ahead_rows += len(conts)
+            d = self._diffusion
+            d["hops"] += len(grp)
+            d["block_steps"] += 1
+            d["lane_passes"] += len(lanes) * passes
+            d["tokens"] += tokens
+            d["rows"] += len(lanes) * blk * passes
         return step
 
     def _finish(self, step: _Step) -> None:
         """Wait for `step`, copy its one packed array out and keep what the
-        host reads of it on the step: once, by whoever needs it first (the
-        next drain's flusher under _dev_lock; a rider's own thread under no
-        lock). Its `device` span runs from its dispatch, or from the end of
-        the step before it where it queued behind that one, to the moment
-        this thread learnt it was done: where no one waited for it while it
-        ran (a turn longer than the step), that is the next drain, and the
-        span overstates the step by the difference."""
+        host reads of it on the step (`_Step.out`): once, by whoever needs
+        it first (the next drain's flusher under _dev_lock; a rider's own
+        thread under no lock). Its `device` span runs from its dispatch, or
+        from the end of the step before it where it queued behind that one,
+        to the moment this thread learnt it was done: where no one waited
+        for it while it ran (a turn longer than the step), that is the next
+        drain, and the span overstates the step by the difference."""
         with step.lock:
             if step.done:
                 return
@@ -1360,22 +1594,14 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 if self.tracer is not None and tracelib.enabled():
                     self.tracer.record_span(
                         "device", "device", step.t0, step.t_done, parent=tracelib.current(),
-                        attrs={"kind": "decode", "tokens": len(step.lanes),
-                               "cobatch": len(step.lanes), "program": step.program},
+                        attrs=step.span(),
                     )
                 with tracelib.region(self.tracer, "copy_out") as at:
                     host = np.asarray(step.packed)
                     step.rows, moved = samplib.logits_out(step.logits, step.plain)
                     at["bytes"] = host.nbytes + moved
                 step.t_out = tracelib.now()
-                step.replies, routed = samplib.row_replies(
-                    host, step.top_n, step.asks,
-                    self.cfg.num_experts_per_tok if self.engine.routes else 0,
-                )
-                step.toks = host[:, 0].tolist()
-                step.keys = host[:, 1:3].view(np.uint32).tolist()
-                if routed is not None and step.lanes:
-                    self._count_routing(routed[:, step.lanes], self.engine.lanes)
+                step.out(host, self)
             except Exception as exc:
                 step.error = exc
                 step.t_done = step.t_done or tracelib.now()
@@ -1384,99 +1610,6 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
                 # still be about to feed its next step from it
                 step.logits = None
                 step.done = True
-
-    def _run_block_steps(self, entries) -> None:
-        """The block calls of one drain (under _dev_lock): ONE
-        BatchedEngine._block_step for the lanes that share a sampling
-        config (static in the program; greedy callers are one group). A
-        failed dispatch fails its own entries (the isolation of
-        _run_decode_batch)."""
-        groups: Dict[tuple, list] = {}
-        for e in entries:
-            groups.setdefault(e.payload[2].sampling, []).append(e)
-        # the drain counted one step: every further group is one more
-        self._batcher.n_steps += len(groups) - 1
-        poisoned: Optional[Exception] = None
-        for sampling, grp in groups.items():
-            try:
-                if poisoned is not None:
-                    raise RuntimeError(
-                        "KV cache invalidated by an earlier dispatch failure "
-                        f"in this window: {poisoned}"
-                    )
-                self._block_dispatch(sampling, grp)
-            except Exception as exc:
-                for e in grp:
-                    e.error = exc
-                self._batcher.n_served -= len(grp)  # see _run_decode_batch
-                if not cache_intact(self.engine.cache):
-                    poisoned = exc
-
-    def _block_dispatch(self, sampling: tuple, grp) -> None:
-        """One block step for the lanes of `grp`, at the widest top-n any
-        of them asked: a lane's frontier advances by a block, its entry gets
-        the block's tokens, the pass that made each place known, the
-        session's next key and (where asked) the log-probabilities."""
-        import jax.numpy as jnp
-
-        eng, blk = self.engine, self.cfg.block_length
-        passes = self.cfg.denoising_steps + 1
-        with self._mu:
-            lens = list(eng.lengths)
-        toks = np.zeros((eng.lanes, blk), np.int32)
-        known = np.zeros((eng.lanes, blk), bool)
-        live = np.zeros((eng.lanes,), bool)
-        keys = np.zeros((eng.lanes, 2), np.uint32)
-        for e in grp:
-            lane, tokens, call = e.payload
-            toks[lane], live[lane], keys[lane] = tokens, True, call.key
-            known[lane, :call.known] = True
-        lanes = [e.payload[0] for e in grp]
-        made = int((~known[lanes]).sum())
-        t, tk, tp, mp = sampling
-        with tracelib.region(
-            self.tracer, "device", kind="block", tokens=made,
-            cobatch=len(grp), rows=len(grp) * blk * passes, passes=passes,
-            program=program_name(eng._block_step),
-        ):
-            top_n = max(e.payload[2].top_n for e in grp)
-            eng.cache, packed, chosen = eng._block_step(
-                eng.params, eng.cache, jnp.asarray(toks), jnp.asarray(known),
-                jnp.asarray(lens, jnp.int32), jnp.asarray(live), jnp.asarray(keys),
-                temperature=t, top_k=tk, top_p=tp, min_p=mp, top_n=top_n,
-            )
-            packed.copy_to_host_async()  # queued behind the step (see _decode_dispatch)
-            packed.block_until_ready()
-        with tracelib.region(self.tracer, "copy_out") as at:
-            host = np.asarray(packed)  # ONE transfer (samplib.pack_bits)
-            at["bytes"] = host.nbytes
-        self._batcher.stamp_out(grp)  # `deliver` starts
-        x, order, nkeys, lps, tis, tls = (a.tolist() for a in samplib.unpack_bits(
-            host, (np.int32, (blk,)), (np.int32, (blk,)), (np.uint32, (2,)),
-            (np.float32, (blk,)), (np.int32, (blk, top_n)), (np.float32, (blk, top_n)),
-        ))
-        if chosen is not None:  # came out with the tokens: no wait
-            routed = np.asarray(chosen)[:, :, lanes]  # [passes, layers, live, block, k]
-            for per_pass in routed.reshape(*routed.shape[:2], -1, routed.shape[-1]):
-                self._count_routing(per_pass, eng.lanes * blk)
-        with self._mu:
-            for lane in lanes:
-                eng.lengths[lane] += blk
-            self._count_read(eng.lanes, max(lens) + blk, q_len=blk, passes=passes)
-            d = self._diffusion
-            d["block_steps"] += 1
-            d["lane_passes"] += len(grp) * passes
-            d["tokens"] += made
-            d["rows"] += len(grp) * blk * passes
-        for e in grp:
-            lane = e.payload[0]
-            e.result = {
-                "tokens": [x[lane]],
-                "order": order[lane],  # the pass that made each place known (-1: the caller)
-                "key": nkeys[lane],
-                **({"logprobs": lps[lane], "top_ids": tis[lane], "top_lps": tls[lane]}
-                   if e.payload[2].top_n else {}),
-            }
 
     def _count_read(self, rows: int, longest: int, q_len: int = 1, passes: int = 1) -> None:
         """`kv.slots_read` / `kv.slots_held` of one dispatched program (under

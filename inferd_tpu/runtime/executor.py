@@ -232,11 +232,15 @@ def parse_decode_ask(payload: Dict[str, Any]) -> Optional[SampleAsk]:
     ask = parse_ask(payload)
     if ask.top_n is None or not rows_cover(*ask.sampling):
         return None
-    eos = payload.get("eos")
-    return ask._replace(
-        ahead=max(0, int(payload.get("ahead") or 0)),
-        eos=-1 if eos is None else int(eos),
-    )
+    return ask._replace(**_promise(payload))
+
+
+def _promise(d: Dict[str, Any]) -> Dict[str, int]:
+    """The two keys by which a decode hop's ask and a block hop's `block`
+    call say what follows: "ahead" (absent, 0 or negative: no promise) and
+    "eos" (absent: -1, no token ends the generation early)."""
+    eos = d.get("eos")
+    return {"ahead": max(0, int(d.get("ahead") or 0)), "eos": -1 if eos is None else int(eos)}
 
 
 def call_kind(payload: Dict[str, Any]) -> str:
@@ -268,11 +272,18 @@ class BlockCall(NamedTuple):
     sampling: tuple  # (temperature, top_k, top_p, min_p): static in the program
     top_n: int  # 0 = no log-probabilities, else a width of BLOCK_TOP_WIDTHS
     key: Any  # uint32 [2]: the session's PRNG chain
+    # as a decode hop's ask has them (SampleAsk): how many BLOCK hops of this
+    # session will follow this one if no `eos` ends it (0: none is promised),
+    # and the token that would (-1: none)
+    ahead: int = 0
+    eos: int = -1
 
 
 def parse_block(payload: Dict[str, Any], block_length: int) -> BlockCall:
     """The `block` key of a /forward payload: {"known": leading places
-    filled, and the sampling ask as parse_ask reads it}."""
+    filled, the sampling ask as parse_ask reads it, and optionally what a
+    decode hop's ask may carry (parse_decode_ask): "ahead": n, the block
+    hops that will follow this one, and "eos"}."""
     b = payload["block"]
     known = int(b.get("known", 0))
     if not 0 <= known < block_length:
@@ -285,7 +296,7 @@ def parse_block(payload: Dict[str, Any], block_length: int) -> BlockCall:
         raise ValueError(
             f"block call: top_logprobs {ask.want} over {BLOCK_TOP_WIDTHS[-1]}"
         )
-    return BlockCall(known=known, sampling=sampling, top_n=ask.top_n, key=ask.key)
+    return BlockCall(known=known, sampling=sampling, top_n=ask.top_n, key=ask.key, **_promise(b))
 
 
 def cache_intact(cache) -> bool:

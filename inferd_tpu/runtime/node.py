@@ -92,13 +92,22 @@ def _warmup_executor(executor, journal=None) -> None:
         if blk > 1:
             # a model generated by blocks: a whole block in, then a block
             # step without log-probabilities (what traffic asks) and one at
-            # the first top-n width (a variant each: runtime/executor.py)
+            # the first top-n width (a variant each: runtime/executor.py),
+            # each followed by the hop it promised, as a generation sends
+            # it (the lane executor runs that hop's block ahead under the key
+            # the step left on the device: the small program that hands it
+            # over is one a width too)
             executor.process(sid, {"tokens": [[1] * blk], "start_pos": 0, "real_len": blk})
-            for i, top in enumerate((0, BLOCK_TOP_WIDTHS[0])):
-                executor.process(sid, {
+            chain: Dict[str, Any] = {}
+            for i, ask in enumerate((
+                {"ahead": 1}, {}, {"top_logprobs": BLOCK_TOP_WIDTHS[0], "ahead": 1},
+                {"top_logprobs": BLOCK_TOP_WIDTHS[0]},
+            )):
+                res = executor.process(sid, {
                     "tokens": [[0] * blk], "start_pos": blk * (i + 1), "real_len": blk,
-                    "block": {"known": 0, "top_logprobs": top},
+                    "block": {"known": 0, **ask, **chain},
                 })
+                chain = {"key": res["key"]}
         else:
             executor.process(sid, payload)
             if hasattr(executor, "sampled_rows"):

@@ -76,7 +76,7 @@ class Direct(GenerationClient):
 
     def __init__(self, ex, prefill_chunk=8):
         super().__init__(sampling=GREEDY, prefill_chunk=prefill_chunk)
-        self.ex, self._block, self.replies = ex, ex.cfg.block_length, []
+        self.ex, self._block, self.replies, self.calls = ex, ex.cfg.block_length, [], []
 
     async def _forward(self, session_id, tokens, start_pos, **extra):
         payload = {"tokens": np.asarray([tokens], np.int32), "start_pos": start_pos,
@@ -84,6 +84,7 @@ class Direct(GenerationClient):
         res = await asyncio.to_thread(self.ex.process, session_id, payload)
         if "block" in extra:
             self.replies.append(res)
+            self.calls.append(extra["block"])
         return res
 
     async def _end_session(self, session_id):
@@ -153,7 +154,9 @@ def test_lane_path_equals_the_reference(params, cfg, left):
     blocks = -(-(left + new) // BLK)
     assert st["diffusion"] == {
         "block_steps": blocks, "lane_passes": 3 * blocks, "tokens": BLK * blocks - left,
-        "rows": 3 * BLK * blocks}
+        "rows": 3 * BLK * blocks, "hops": blocks}
+    # the first block hop rode, every later one found its block run; none in vain
+    assert st["ahead_claimed"] == st["ahead_rows"] == blocks - 1 and st["ahead_dropped"] == 0
     assert st["moe"]["steps"] == 3 * blocks
     assert st["moe"]["assignments"] == 3 * blocks * BLK * CFG.num_layers * CFG.num_experts_per_tok
 
@@ -203,6 +206,29 @@ def test_max_new_tokens_and_eos_cut_inside_a_block(params):
         assert generate(ex, prompt, n)[0] == full[:n]
     stop = next(i for i in range(2, 12) if full[i] not in full[:i] and i % BLK != BLK - 1)
     assert generate(ex, prompt, 12, eos=full[stop])[0] == full[: stop + 1]
+
+
+@pytest.mark.parametrize("n_prompt,new", [(10, 1), (10, 2), (10, 3), (8, 9), (11, 13), (3, 6)])
+def test_the_loop_promises_exactly_the_block_hops_that_follow(params, n_prompt, new):
+    """`ahead` of a block hop: the hops after it unless `eos` ends the answer,
+    from `max_new_tokens`, what is out and the block (an answer that ends
+    inside a block, on its end, in the first one; a prompt shorter than a
+    block): the executor runs those blocks ahead and none past them."""
+    ex = BatchedExecutor(CFG, params, lanes=2, max_len=64)
+
+    async def go():
+        c = Direct(ex)
+        out = await c._generate_once(prompt_of(n_prompt, seed=2), new, 7, 0, GREEDY, None, None, 0, None)
+        return out, c.calls
+
+    out, calls = asyncio.run(go())
+    hops = -(-(n_prompt % BLK + new) // BLK)
+    assert 7 not in out and len(out) == new  # the stream ran to its budget
+    assert [c["ahead"] for c in calls] == list(range(hops - 1, -1, -1))
+    assert all(c["eos"] == 7 for c in calls) and calls[0]["known"] == n_prompt % BLK
+    st = ex.stats()
+    assert st["diffusion"]["hops"] == st["diffusion"]["block_steps"] == hops
+    assert st["ahead_claimed"] == st["ahead_rows"] == hops - 1 and st["ahead_dropped"] == 0
 
 
 def test_the_mask_tokens_id_is_served_as_any_token(params):
@@ -359,6 +385,14 @@ async def test_generate_streams_blocks_and_tells_a_client_of_them(parts, params)
                 break
             await asyncio.sleep(0.05)
         assert any(e["type"] == "executor.warmup_ok" for e in node.journal.events())
+        # the warm-up sent each top-n width's hop with a promise and the hop it
+        # promised: the block step fed from the host and from the device, and
+        # the program that hands the key over, are compiled
+        from inferd_tpu.core import sampling as samplib
+        warm = node.executor.stats()
+        assert warm["ahead_claimed"] == warm["ahead_rows"] == 2 and warm["diffusion"]["hops"] == 4
+        programs = (node.executor.engine._block_step, samplib.ahead_block_keys)
+        compiled = [f._cache_size() for f in programs]
         async with aiohttp.ClientSession() as http:
             async with http.get(f"http://{HOST}:{BASE}/stats") as r:
                 stats = await r.json()
@@ -385,6 +419,12 @@ async def test_generate_streams_blocks_and_tells_a_client_of_them(parts, params)
             assert c._block == BLK
             with pytest.raises(ValueError, match="pinned prefix"):
                 await c.pin_prefix(prompt[:4])
+        assert [f._cache_size() for f in programs] == compiled  # nothing compiled since
+        ran = node.executor.stats()
+        hops = -(-(len(prompt) % BLK + new) // BLK)
+        assert ran["diffusion"]["hops"] - warm["diffusion"]["hops"] == 2 * hops
+        assert ran["ahead_claimed"] - warm["ahead_claimed"] == 2 * (hops - 1)
+        assert ran["ahead_dropped"] == 0
         kinds = {(s["name"], (s.get("attrs") or {}).get("kind")) for s in node.tracer.spans()}
         assert {("compute", "block"), ("compute", "prefill"), ("device", "block"),
                 ("lock_wait", "block")} <= kinds

@@ -160,16 +160,18 @@ def test_every_hop_leaves_its_parts_and_every_step_its_turn(served, topology):
     mine = [s for s in steps if s["t0"] >= turns[0]["t1"]]
     computes = {h["compute"]["span"]: h for h in hops}
     beside = sum(d["t0"] < t["t1"] and t["t0"] < d["t1"] for t in turns for d in steps)
-    if topology == "lanes":
-        # the lane executor keeps one step ahead: a drain dispatches the NEXT
-        # step (two where nobody was ahead yet: the riders' own and the one
-        # behind it), so a step runs BESIDE the turn that follows its drain
-        assert len(mine) >= len(turns) >= NEW - 1 and beside >= NEW // 2
+    ahead = topology != "mesh"
+    if ahead:
+        # the lane executor keeps one step ahead, block steps too: a drain
+        # dispatches the NEXT step (two where nobody was ahead yet: the riders'
+        # own and the one behind it), so a step runs BESIDE the turn that
+        # follows its drain
+        assert len(mine) >= len(turns) >= NEW // per_hop - 1 and beside >= NEW // per_hop // 2
     else:
         assert len(turns) == len(mine) >= NEW // per_hop - 1 and beside == 0
     for t, step in zip(turns, mine):
         a = t["attrs"]
-        if topology == "lanes":
+        if ahead:
             # the step dispatched at the drain that ended the turn (none
             # where every entry was a generation's last hop)
             step = min((s for s in steps if s["t0"] >= t["t1"]), key=lambda s: s["t0"],
@@ -189,7 +191,10 @@ def test_every_hop_leaves_its_parts_and_every_step_its_turn(served, topology):
         assert computes[a["last"]]["submit"] == max(taken)
         assert abs(a["first_ms"] - (min(taken) - t["t0"]) * 1e3) < 2e-3
     # a formation is owed the sessions of the last two steps: both, here
-    assert max(t["attrs"]["expected"] for t in turns) == 2
+    # (three block hops a session, the first of which rides a step that no
+    # drain waits for, may be over before both were owed at once)
+    owed = max(t["attrs"]["expected"] for t in turns)
+    assert owed == 2 or (topology == "block" and owed == 1)
 
 
 @pytest.mark.parametrize("topology", list(TOPOLOGIES))

@@ -9,7 +9,8 @@ the log-probabilities, for every layout a dense lane has; no row is run past a
 request's budget; whatever is not the promised hop drops the row (or, on a
 lane with a recurrent state, is refused) and nobody reads it; a newcomer, a
 lone session and a late one are served; and every other path runs no row
-ahead."""
+ahead. A model generated by blocks (`tiny-sdar`) is held to the same: its hops
+carry a block, its `block` call the promise, and a row run ahead is a block."""
 
 import dataclasses
 import threading
@@ -387,15 +388,18 @@ def test_a_step_somebody_else_finished_still_feeds_the_next(when, monkeypatch):
     assert ex.stats()["ahead_dropped"] == 0 and _at_rest(ex) == 0
 
 
+class OnTheDevice:
+    """A prefill chunk's logits that never come (`_settle` asks `is_ready`)."""
+
+    def is_ready(self):
+        return False
+
+
 def test_behind_a_prefill_chunk_the_drain_waits_out_the_last_step_first():
     """While a prefill chunk is on the device the next step cannot start,
     so the drain first waits for the step dispatched before it (and so takes
     in a newcomer whose prefill is ending) and feeds the next step from the
     host's copy: same stream, every row still run ahead and claimed."""
-
-    class OnTheDevice:
-        def is_ready(self):
-            return False
 
     ex = _executor("tiny")
     s = Session(ex, "s").run(hops=2)
@@ -452,7 +456,7 @@ def _block(ex):
     blk = ex.cfg.block_length
     ex.process("s", {"tokens": [[1] * blk], "start_pos": 0, "real_len": blk})
     res = ex.process("s", {"tokens": [[0] * blk], "start_pos": blk, "real_len": blk,
-                           "block": {"known": 0, "ahead": 8}})
+                           "block": {"known": 0}})  # no `ahead`: served as before PR 50
     assert len(res["tokens"][0]) == blk
 
 
@@ -488,4 +492,366 @@ def test_the_ask_carries_the_budget():
     ask = execlib.parse_decode_ask({"sampling": {}})
     assert (ask.ahead, ask.eos) == (0, -1)  # every caller that says nothing
     assert execlib.parse_decode_ask({"sampling": {}, "ahead": -3}).ahead == 0
-    assert execlib.parse_ask({"ahead": 5}).ahead == 0  # a block call, a K-step call: not theirs
+    assert execlib.parse_ask({"ahead": 5}).ahead == 0  # a K-step call: not its
+    assert execlib.parse_kstep({"decode_steps": 2, "ahead": 5}, 8).get("ahead") is None
+    # a block hop's `block` call carries the same two keys
+    call = execlib.parse_block({"block": {"known": 0, "ahead": 5, "eos": 2}}, 4)
+    assert (call.ahead, call.eos) == (5, 2)
+    call = execlib.parse_block({"block": {"known": 0}}, 4)
+    assert (call.ahead, call.eos) == (0, -1)  # a caller that promises nothing
+    assert execlib.parse_block({"block": {"known": 0, "ahead": -3}}, 4).ahead == 0
+
+
+# ---------------------------------------------------------------------------
+# (f) block hops (a model generated by blocks): the same, a block a row
+# ---------------------------------------------------------------------------
+
+BLK = get_config("tiny-sdar").block_length
+LONG = [3, 7, 11, 19, 23, 29, 31, 37, 41, 43]  # two whole blocks and two tokens that open the third
+
+
+class BlockSession:
+    """The block loop's hops (client/base.py `_generate_blocks`), one call a
+    hop: the prompt's whole blocks go in as one chunk, what is left of it
+    opens the first block."""
+
+    def __init__(self, ex, sid, prompt=LONG, new=14, ahead=True, sampling=GREEDY, seed=0,
+                 top=0, eos=None):
+        self.ex, self.sid, self.new, self.ahead, self.eos = ex, sid, new, ahead, eos
+        self.ask = {"sampling": sampling, "top_logprobs": top}
+        if eos is not None:
+            self.ask["eos"] = eos
+        whole = len(prompt) // BLK * BLK
+        if whole:
+            ex.process(sid, {"tokens": [prompt[:whole]], "start_pos": 0, "real_len": whole,
+                             "want_logits": False})
+        self.pos, self.head, self.chain = whole, list(prompt[whole:]), {"seed": seed}
+        self.out, self.orders, self.keys, self.lps, self.hops = [], [], [], [], 0
+
+    def payload(self, **block):
+        left = self.new - len(self.out) - (BLK - len(self.head))
+        more = {"ahead": max(0, -(-left // BLK))} if self.ahead else {}
+        call = {"known": len(self.head), **self.ask, **self.chain, **more, **block}
+        return {"tokens": [self.head + [0] * (BLK - len(self.head))], "start_pos": self.pos,
+                "real_len": BLK, "block": call}
+
+    @property
+    def over(self):
+        return len(self.out) >= self.new or (bool(self.out) and self.out[-1] == self.eos)
+
+    def hop(self):
+        res = self.ex.process(self.sid, self.payload())
+        for j in range(len(self.head), BLK):
+            self.out.append(int(res["tokens"][0][j]))
+            if self.over:
+                break
+        self.orders.append(res["order"])
+        self.keys.append(list(res["key"]))
+        if self.ask["top_logprobs"]:
+            self.lps.append((res["logprobs"], res["top_ids"], res["top_lps"]))
+        self.pos, self.head, self.chain = self.pos + BLK, [], {"key": res["key"]}
+        self.hops += 1
+
+    run, end = Session.run, Session.end
+
+    @property
+    def read(self):
+        return self.out, self.orders, self.keys, self.lps
+
+
+def _fresh_blocks(prompt=LONG, **kw):
+    return BlockSession(_executor("tiny-sdar"), "fresh", prompt=prompt, ahead=False, **kw).run().read
+
+
+@pytest.mark.parametrize("beside", ["alone", "three_resident"])
+@pytest.mark.parametrize("how", ["greedy", "sampled"])
+def test_a_block_stream_is_the_one_the_hops_give_without_the_promise(how, beside):
+    ex = _executor("tiny-sdar", lanes=4)
+    kw = dict(sampling=GREEDY if how == "greedy" else SAMPLED, seed=7, top=3)
+    want = _fresh_blocks(**kw)
+    others = []
+    if beside == "three_resident":
+        # each holds a block run ahead that nobody claims while the others step
+        others = [BlockSession(ex, f"o{i}", prompt=LONG[i:], new=20, **kw).run(hops=2)
+                  for i in range(1, 4)]
+        assert len(ex._ahead) == 3
+    rows0, claimed0 = ex.stats()["ahead_rows"], ex.stats()["ahead_claimed"]
+    got = BlockSession(ex, "s", **kw).run().end()
+    # bit for bit: the program and its inputs are the same, only WHEN it runs differs
+    assert got.read == want and len(got.out) == 14 and len(got.lps) == got.hops == 4
+    if how == "sampled":
+        assert len(set(map(tuple, got.keys))) == got.hops  # the chain moved
+    s = ex.stats()
+    # every hop but the first found its block run: nothing was run in vain
+    assert s["ahead_claimed"] - claimed0 == s["ahead_rows"] - rows0 == got.hops - 1
+    assert s["ahead_dropped"] == 0
+    for i, o in enumerate(others, 1):
+        assert o.run().end().read == _fresh_blocks(prompt=LONG[i:], new=20, **kw)
+    assert _at_rest(ex) == 0 and ex.stats()["ahead_dropped"] == 0
+
+
+def test_block_sessions_side_by_side_each_read_their_own_stream():
+    ex = _executor("tiny-sdar")
+    jobs = [(LONG, 22), (LONG[1:], 17), (OTHER, 12)]
+    alone = [_fresh_blocks(prompt=p, new=n) for p, n in jobs]
+    sessions = [BlockSession(ex, f"s{i}", prompt=p, new=n) for i, (p, n) in enumerate(jobs)]
+    _side_by_side(*sessions)
+    assert [x.read for x in sessions] == alone
+    s = ex.stats()
+    hops = sum(x.hops for x in sessions)
+    assert hops - 2 * len(sessions) <= s["ahead_claimed"] == s["ahead_rows"] <= hops - len(sessions)
+    assert s["ahead_dropped"] == 0 and s["diffusion"]["hops"] == hops == s["batched_tokens"]
+    assert s["diffusion"]["block_steps"] == s["batched_steps"]
+
+
+def test_block_sessions_of_two_sampling_configs_share_a_drain_not_a_program():
+    """The block step's sampling is static: a drain dispatches one program a
+    config, each running its own lanes' next blocks ahead."""
+    ex = _executor("tiny-sdar")
+    jobs = [(LONG, GREEDY), (LONG[1:], SAMPLED), (OTHER, SAMPLED)]
+    alone = [_fresh_blocks(prompt=p, new=22, sampling=s, seed=4) for p, s in jobs]
+    sessions = [BlockSession(ex, f"s{i}", prompt=p, new=22, sampling=s, seed=4)
+                for i, (p, s) in enumerate(jobs)]
+    _side_by_side(*sessions)
+    assert [x.read for x in sessions] == alone
+    s = ex.stats()
+    hops = sum(x.hops for x in sessions)
+    assert hops - 2 * len(sessions) <= s["ahead_claimed"] == s["ahead_rows"] <= hops - len(sessions)
+    assert s["ahead_dropped"] == 0 and s["diffusion"]["hops"] == hops == s["batched_tokens"]
+    # a sampled lane never rides the greedy lane's program
+    assert s["diffusion"]["block_steps"] == s["batched_steps"] >= sessions[0].hops + sessions[1].hops
+
+
+@pytest.mark.parametrize("new", [1, 2, 5, 6, 7, 13])
+def test_no_block_is_run_past_the_budget(new):
+    """`max_new_tokens` that ends inside a block, on its end, in the first
+    one: the promised hop count is exact."""
+    ex = _executor("tiny-sdar")
+    s = BlockSession(ex, "s", new=new).run()
+    hops = -(-(new + len(LONG) % BLK) // BLK)
+    st = ex.stats()
+    assert len(s.out) == new and s.hops == hops and st["diffusion"]["hops"] == hops
+    assert st["ahead_rows"] == st["ahead_claimed"] == hops - 1 and st["ahead_dropped"] == 0
+    assert not ex._ahead and not ex._carry
+    assert ex.engine.lengths[ex._sessions["s"]] == len(LONG) // BLK * BLK + hops * BLK
+    assert st["diffusion"]["block_steps"] == st["batched_steps"] == hops  # no program in vain
+    assert s.read == _fresh_blocks(new=new)
+    s.end()
+    assert ex.stats()["ahead_dropped"] == 0
+
+
+def test_an_eos_inside_a_block_costs_the_block_run_ahead_of_it():
+    ex = _executor("tiny-sdar", lanes=1)
+    full = _fresh_blocks(new=22, sampling=SAMPLED, seed=3)[0]
+    at = next(i for i in range(4, 22) if full[i] not in full[:i])
+    s = BlockSession(ex, "s", new=22, sampling=SAMPLED, seed=3, eos=full[at]).run()
+    assert s.out == full[:at + 1]
+    lane = ex._sessions["s"]
+    # the block after the one that holds `eos` was run before the host knew
+    assert ex._ahead[lane].pos == s.pos == ex.engine.lengths[lane]
+    s.end()
+    st = ex.stats()
+    assert st["ahead_dropped"] == 1 and _at_rest(ex) == 0 and not ex._ahead
+    assert ex.engine.free == [lane]
+    # the lane's next owner reads what a fresh executor's would: nobody reads the block
+    nxt = BlockSession(ex, "next", prompt=LONG[1:], new=9)
+    assert ex._sessions["next"] == lane
+    assert nxt.run().end().read == _fresh_blocks(prompt=LONG[1:], new=9)
+    # ... and where the host already knows the block when the next would be
+    # dispatched (every drain waited the step before out: `_settle`), `eos`
+    # IN it runs nothing ahead
+    s = BlockSession(ex, "known", new=22, sampling=SAMPLED, seed=3, eos=full[at])
+    ex._chunk = OnTheDevice()
+    assert s.run().end().out == full[:at + 1]
+    assert ex.stats()["ahead_dropped"] == 1 and _at_rest(ex) == 0
+
+
+def _block_end(ex, s):
+    s.end()
+
+
+def _block_replay(ex, s):
+    """The hop before, sent again (its reply was lost): the lane rolls back a block."""
+    res = ex.process(s.sid, {**s.payload(ahead=0, key=s.keys[-2]), "start_pos": s.pos - BLK})
+    assert res["key"] == s.keys[-1] and res["order"] == s.orders[-1]
+    ex.end_session(s.sid)
+
+
+def _block_restart(ex, s):
+    again = BlockSession(ex, s.sid, new=9)  # the same id at 0: the lane is reset
+    assert again.run().end().read == _fresh_blocks(new=9)
+
+
+def _block_known(ex, s):
+    """A block of which the caller fills a place is not the block that was run."""
+    p = s.payload(ahead=0, known=1)
+    p["tokens"] = [[5] + [0] * (BLK - 1)]
+    res = ex.process(s.sid, p)
+    assert res["order"][0] == -1 and res["tokens"][0][0] == 5
+    ex.end_session(s.sid)
+
+
+def _block_other_key(ex, s):
+    res = ex.process(s.sid, s.payload(ahead=0, key=[1, 2]))
+    assert len(res["tokens"][0]) == BLK
+    ex.end_session(s.sid)
+
+
+def _block_prefill(ex, s):
+    ex.process(s.sid, {"tokens": [[9, 8, 7, 6]], "start_pos": s.pos, "real_len": BLK,
+                       "want_logits": False})
+    ex.end_session(s.sid)
+
+
+def _block_export(ex, s):
+    assert ex.export_sessions(only=s.sid)
+    ex.end_session(s.sid)
+
+
+BLOCK_DROPS = {"end_session": _block_end, "replay": _block_replay, "restart": _block_restart,
+               "known": _block_known, "another_key": _block_other_key,
+               "prefill_chunk": _block_prefill, "export": _block_export}
+
+
+@pytest.mark.parametrize("what", list(BLOCK_DROPS))
+def test_what_is_not_the_promised_block_hop_drops_the_block_and_nobody_reads_it(what):
+    ex = _executor("tiny-sdar", lanes=1)
+    s = BlockSession(ex, "s", new=22, sampling=SAMPLED, seed=5).run(hops=3)
+    lane = ex._sessions["s"]
+    rec = ex._ahead[lane]
+    assert rec.pos == s.pos == ex.engine.lengths[lane] and rec.width == BLK
+    BLOCK_DROPS[what](ex, s)
+    st = ex.stats()
+    assert st["ahead_dropped"] == 1 and _at_rest(ex) == 0 and not ex._ahead and not ex._carry
+    nxt = BlockSession(ex, "next", prompt=LONG[1:], new=9)
+    assert ex._sessions["next"] == lane
+    assert nxt.run().end().read == _fresh_blocks(prompt=LONG[1:], new=9)
+
+
+def test_a_block_call_that_promises_runs_the_block_and_end_session_drops_it():
+    """What `OTHER_PATHS` held of the parent: a block call with `ahead`."""
+    ex = _executor("tiny-sdar")
+    ex.process("s", {"tokens": [[1] * BLK], "start_pos": 0, "real_len": BLK})
+    res = ex.process("s", {"tokens": [[0] * BLK], "start_pos": BLK, "real_len": BLK,
+                           "block": {"known": 0, "ahead": 8}})
+    assert len(res["tokens"][0]) == BLK
+    s = ex.stats()
+    assert (s["ahead_rows"], s["ahead_claimed"], s["ahead_dropped"]) == (1, 0, 0)
+    assert ex._ahead[ex._sessions["s"]].pos == 2 * BLK
+    ex.end_session("s")
+    assert ex.stats()["ahead_dropped"] == 1 and not ex._ahead
+
+
+def test_a_fork_of_a_lane_with_a_block_run_ahead_is_refused_and_the_block_stays():
+    ex = _executor("tiny-sdar")
+    s = BlockSession(ex, "s").run(hops=2)
+    assert ex.fork_session("child", "s", 2 * BLK) is False  # no fork of such a model yet
+    assert len(ex._ahead) == 1 and s.run().end().read == _fresh_blocks()
+    assert ex.stats()["ahead_dropped"] == 0 and _at_rest(ex) == 0
+
+
+def test_behind_a_prefill_chunk_a_block_drain_waits_out_the_last_step_first():
+    """`_settle`, for block steps: another lane's chunk is on the device
+    between two block steps, so each drain first finishes the step before
+    and feeds the next from the host's copy of its key."""
+
+    ex = _executor("tiny-sdar")
+    s = BlockSession(ex, "s", sampling=SAMPLED, seed=2).run(hops=2)
+    BlockSession(ex, "other", prompt=OTHER)  # a prefill chunk of another lane cuts in
+    assert ex._chunk is not None
+    ex._chunk = OnTheDevice()
+    seen = []
+    real = ex._batcher.drain_pending
+
+    def drain():
+        seen.append(ex._last_step.done)
+        return real()
+
+    ex._batcher.drain_pending = drain
+    s.run()
+    assert seen and all(seen)
+    assert s.read == _fresh_blocks(sampling=SAMPLED, seed=2)
+    st = ex.stats()
+    assert st["ahead_claimed"] == st["ahead_rows"] == s.hops - 1 and st["ahead_dropped"] == 0
+
+
+@pytest.mark.parametrize("who", ["the_block_run_ahead", "a_rider"])
+def test_a_block_dispatch_that_raises_fails_its_own_entries_only(who):
+    ex = _executor("tiny-sdar")
+    real = ex.engine._block_step
+
+    def boom(*a, **kw):
+        ex.engine._block_step = real  # once
+        raise RuntimeError("injected dispatch failure")
+
+    s = BlockSession(ex, "s").run(hops=2)
+    rows = ex.stats()["ahead_rows"]
+    if who == "a_rider":
+        r = BlockSession(ex, "r", prompt=LONG[1:], new=9)
+        ex.engine._block_step = boom
+        with pytest.raises(RuntimeError, match="injected dispatch failure"):
+            r.hop()
+        # nothing moved on its lane, and nothing is left to answer from
+        lane = ex._sessions["r"]
+        assert ex.engine.lengths[lane] == r.pos and lane not in ex._ahead and lane not in ex._carry
+        assert r.run().end().read == _fresh_blocks(prompt=LONG[1:], new=9)
+    else:
+        ex.engine._block_step = boom
+        s.hop()  # answered from the step that ran its block; the next block's dispatch failed
+        assert not ex._ahead and ex.stats()["ahead_rows"] == rows
+    assert s.run().end().read == _fresh_blocks()  # its next hop rode, and the rest ran ahead
+    assert _at_rest(ex) == 0 and ex.stats()["ahead_dropped"] == 0
+
+
+def test_the_block_counters_add_up_over_a_generation():
+    ex = _executor("tiny-sdar")
+    a = BlockSession(ex, "a", new=22).run()
+    b = BlockSession(ex, "b", prompt=LONG[1:], new=22, eos=a.out[9]).run()
+    a.end(), b.end()
+    s = ex.stats()
+    hops = a.hops + b.hops
+    assert s["diffusion"]["hops"] == hops == s["ahead_claimed"] + 2  # each first hop rode
+    assert s["ahead_rows"] == s["ahead_claimed"] + s["ahead_dropped"] and s["ahead_dropped"] <= 1
+    assert s["diffusion"]["block_steps"] == s["batched_steps"] == s["batched_tokens"]
+    assert s["batched_tokens"] == hops + s["ahead_dropped"]  # the blocks run for a session
+    assert s["diffusion"]["lane_passes"] == 3 * s["batched_tokens"]
+    assert s["sampled_rows"] == s["logit_rows"] == 0  # those count one-token hops
+
+
+def _reader(metric):
+    """A per-layer reader of the benchmark, loaded as its harness loads it."""
+    import importlib.util
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)  # the reader imports `arith` as the harness has it
+    try:
+        spec = importlib.util.spec_from_file_location(
+            metric.replace(".", "_"), os.path.join(bench, "layer_metrics", f"{metric}.py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+    finally:
+        sys.path.remove(bench)
+    return mod.read
+
+
+@pytest.mark.parametrize("program", ["this_tree", "the_parent", "a_token_model", "no_hop"])
+def test_the_benchmark_reads_the_share_of_block_hops_that_found_their_block_run(program):
+    read = _reader("window.block_ahead_claimed_share")
+    ex = _executor("tiny-sdar" if program != "a_token_model" else "tiny")
+    before = {"executor": ex.stats()}
+    if program == "a_token_model":
+        Session(ex, "s").run().end()
+    elif program != "no_hop":
+        a = BlockSession(ex, "a", new=22).run().end()
+        b = BlockSession(ex, "b", prompt=OTHER, new=10, ahead=False).run().end()  # promises nothing
+    after = {"executor": ex.stats()}
+    if program == "the_parent":  # a program without the counter: nothing to read, no error
+        for stats in (before, after):
+            del stats["executor"]["diffusion"]["hops"]
+    got = read({"stats0": before, "stats1": after})
+    if program == "this_tree":
+        assert got == pytest.approx(100.0 * (a.hops - 1) / (a.hops + b.hops)) and 0 < got < 100
+    else:
+        assert got is None
