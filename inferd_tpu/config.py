@@ -57,6 +57,11 @@ class ModelConfig:
     # neither knob and (3.1+) frequency-dependent "llama3" RoPE scaling.
     qk_norm: bool = True
     attn_bias: bool = False
+    # the Olmo family's q/k norm: ONE RMSNorm over the whole projection
+    # (num_heads x head_dim; the keys' over num_kv_heads x head_dim) before it
+    # is split into heads, where Qwen3's is one of head_dim applied to each
+    # head. Says WHICH norm `qk_norm` is, so it comes with qk_norm
+    qk_norm_flat: bool = False
 
     # RoPE scaling: "none", "llama3" (Llama-3.1+ long-context scheme:
     # low-frequency bands divided by `rope_scaling_factor`, high-frequency
@@ -105,8 +110,12 @@ class ModelConfig:
     o_bias: bool = False
 
     # Gemma-2 family knobs (all off for Qwen/Llama):
-    #   sandwich_norm  — norms BOTH before and after each sublayer (the
-    #                    post-norms apply to the sublayer output pre-residual)
+    #   norm_placement — where a sublayer's RMSNorm stands: "before" (the
+    #                    pre-norm block: the sublayer reads its normed input),
+    #                    "both" (Gemma's sandwich: that, and a second norm on
+    #                    the sublayer's output before it joins the residual)
+    #                    or "after" (the Olmo family: the sublayer reads the
+    #                    residual stream as it is, h = x + Norm(Mixer(x)))
     #   rms_norm_plus_one — RMSNorm scales by (1 + w); weights init to zero
     #   hidden_act     — MLP gate activation: "silu" or "gelu_tanh"
     #   scale_embedding — multiply embeddings by sqrt(hidden_size)
@@ -116,7 +125,7 @@ class ModelConfig:
     #   sliding_window — local attention window on the "sliding" layers of
     #                    layer_pattern (with no layer_types: EVEN layer
     #                    indices, odd layers global); 0 = all layers global
-    sandwich_norm: bool = False
+    norm_placement: str = "before"
     rms_norm_plus_one: bool = False
     hidden_act: str = "silu"
     scale_embedding: bool = False
@@ -206,6 +215,10 @@ class ModelConfig:
     #                    `linear_conv` taps without bias, the chunked form
     #                    tiled by `linear_chunk_size`. A model has ONE state
     #                    kind (`state_kind`)
+    #   linear_allow_neg_eigval — the delta rule's beta_t is 2 sigmoid(b_t),
+    #                    in (0, 2), so the transition I - beta k k^T has
+    #                    eigenvalues in (-1, 1) (the Olmo-Hybrid family);
+    #                    False: sigmoid(b_t), eigenvalues in (0, 1)
     layer_types: tuple = ()
     mamba_heads: int = 0
     mamba_head_dim: int = 0
@@ -225,6 +238,7 @@ class ModelConfig:
     linear_value_head_dim: int = 0
     linear_conv: int = 4
     linear_chunk_size: int = 64
+    linear_allow_neg_eigval: bool = False
 
     # Windowed and full layers by a list, and one chip's share of the experts
     # (the afmoe family; all absent elsewhere):
@@ -272,12 +286,13 @@ class ModelConfig:
                 )
             if self.has_state_layers and (
                 len(set(self.layer_types) & set(STATE_KINDS)) > 1 or self.is_mla
-                or self.sliding_window or self.is_block_diffusion or self.sandwich_norm
-                or self.first_k_dense_replace
+                or self.sliding_window or self.is_block_diffusion
+                or self.norm_placement == "both" or self.first_k_dense_replace
             ):
                 raise ValueError(
                     f"{self.name}: state layers are of ONE kind, beside global GQA layers, "
-                    "every layer with the same feed-forward (dense, or routed experts)"
+                    "every layer with the same feed-forward (dense, or routed experts) and "
+                    "ONE norm a sublayer (norm_placement 'before' or 'after', not 'both')"
                 )
             if self.state_kind == "mamba" and (
                 self.mamba_heads * self.mamba_head_dim != self.mamba_expand * self.hidden_size
@@ -296,6 +311,10 @@ class ModelConfig:
                     f"{self.name}: a Gated-DeltaNet layer has linear_value_heads a whole "
                     "multiple of linear_key_heads, and both head sizes"
                 )
+        if self.norm_placement not in ("before", "both", "after"):
+            raise ValueError(f"{self.name}: unknown norm_placement {self.norm_placement!r}")
+        if self.qk_norm_flat and not self.qk_norm:
+            raise ValueError(f"{self.name}: qk_norm_flat says which norm qk_norm is")
         if self.position_embedding not in ("rope", "nope"):
             raise ValueError(f"{self.name}: unknown position_embedding {self.position_embedding!r}")
         if set(self.nope_kinds) - set(self.layer_pattern):
@@ -322,6 +341,22 @@ class ModelConfig:
                 raise ValueError(
                     f"{self.name}: block generation runs on global GQA layers only"
                 )
+
+    @property
+    def norm_before(self) -> bool:
+        """A sublayer reads its normed input (norm_placement)."""
+        return self.norm_placement != "after"
+
+    @property
+    def norm_after(self) -> bool:
+        """A sublayer's output is normed before it joins the residual."""
+        return self.norm_placement != "before"
+
+    @property
+    def qk_norm_kind(self) -> str:
+        """Which q/k norm the attention layers have: "none", "head" (Qwen3:
+        one of head_dim, each head) or "flat" (Olmo: over the whole projection)."""
+        return ("flat" if self.qk_norm_flat else "head") if self.qk_norm else "none"
 
     @property
     def is_moe(self) -> bool:
@@ -658,7 +693,7 @@ GEMMA2_2B = ModelConfig(
     tie_word_embeddings=True,
     qk_norm=False,
     attn_bias=False,
-    sandwich_norm=True,
+    norm_placement="both",
     rms_norm_plus_one=True,
     hidden_act="gelu_tanh",
     scale_embedding=True,
@@ -882,7 +917,7 @@ TRINITY_LARGE = ModelConfig(
     max_position_embeddings=262144,
     tie_word_embeddings=False,
     qk_norm=True,
-    sandwich_norm=True,
+    norm_placement="both",
     scale_embedding=True,
     sliding_window=4096,
     layer_types=("sliding", "sliding", "sliding", "global"),
@@ -951,6 +986,43 @@ QWEN3_NEXT_80B_EP4_8L = dataclasses.replace(
     QWEN3_NEXT_80B_A3B.with_layers(8), name="qwen3-next-80b-ep4-8l", vocab_size=151936 // 4,
     num_experts=512 // 4, router_experts=512,
 )
+
+# Olmo-Hybrid-7B (allenai/Olmo-Hybrid-7B config.json, `olmo_hybrid`): 32
+# layers, three Gated-DeltaNet layers (30 key and 30 value heads, keys of 96,
+# values of 192, beta in (0, 2)) to one full-attention layer of 30 query and 30
+# key/value heads of 128 without rope, a SwiGLU MLP of 11 008 in every layer,
+# NO norm on a sublayer's input: h = x + Norm(Mixer(x)), y = h + Norm(MLP(h)),
+# and the q/k norm over the whole projection. The -16l preset is the first
+# sixteen layers (four whole periods), what one v5e chip holds at the
+# published widths beside sixteen lanes of 4096 slots
+# (benchmark/configs/olmo-hybrid-7b-1chip.json has the arithmetic).
+OLMO_HYBRID_7B = ModelConfig(
+    name="olmo-hybrid-7b",
+    vocab_size=100352,
+    hidden_size=3840,
+    intermediate_size=11008,
+    num_layers=32,
+    num_heads=30,
+    num_kv_heads=30,
+    head_dim=128,
+    rms_norm_eps=1e-6,
+    max_position_embeddings=65536,
+    tie_word_embeddings=False,
+    qk_norm=True,
+    qk_norm_flat=True,
+    norm_placement="after",
+    position_embedding="nope",
+    layer_types=("delta", "delta", "delta", "attention"),
+    linear_key_heads=30,
+    linear_value_heads=30,
+    linear_key_head_dim=96,
+    linear_value_head_dim=192,
+    linear_conv=4,
+    linear_chunk_size=64,
+    linear_allow_neg_eigval=True,
+)
+
+OLMO_HYBRID_7B_16L = dataclasses.replace(OLMO_HYBRID_7B.with_layers(16), name="olmo-hybrid-7b-16l")
 
 # Synthetic mid-size config for the default bench's paired pipeline leg
 # (bench.py): big enough that a decode step's compute dominates the
@@ -1021,7 +1093,7 @@ TINY_GPT_OSS = dataclasses.replace(
 TINY_GEMMA2 = dataclasses.replace(
     TINY, name="tiny-gemma2", qk_norm=False, attn_bias=False,
     rope_theta=10_000.0,
-    sandwich_norm=True, rms_norm_plus_one=True, hidden_act="gelu_tanh",
+    norm_placement="both", rms_norm_plus_one=True, hidden_act="gelu_tanh",
     scale_embedding=True, attn_logit_softcap=50.0, final_logit_softcap=30.0,
     query_pre_attn_scalar=32.0, sliding_window=8,
 )
@@ -1051,7 +1123,7 @@ TINY_GRANITE_H = dataclasses.replace(
 # one whole period and a tail of one), window 8, 16 experts top 2, 1 shared.
 TINY_AFMOE = dataclasses.replace(
     TINY, name="tiny-afmoe", num_layers=9, tie_word_embeddings=False, rms_norm_eps=1e-5,
-    rope_theta=10_000.0, sandwich_norm=True, scale_embedding=True, sliding_window=8,
+    rope_theta=10_000.0, norm_placement="both", scale_embedding=True, sliding_window=8,
     layer_types=("sliding", "sliding", "sliding", "global"), nope_kinds=("global",),
     attn_gate=True, num_experts=16, num_experts_per_tok=2, moe_intermediate_size=32,
     moe_router_mode="sigmoid_topk", norm_topk_prob=True, routed_scaling_factor=2.448,
@@ -1069,6 +1141,19 @@ TINY_QWEN3_NEXT = dataclasses.replace(
     linear_conv=4, linear_chunk_size=8,
     num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32, norm_topk_prob=True,
     n_shared_experts=1, shared_expert_gate=True,
+)
+
+# tiny-olmo-hybrid: the Olmo-Hybrid layer at toy widths but the PUBLISHED head
+# sizes of its state (keys of 96, values of 192: what is off the tile is the
+# point, core.cache.state_fold), four heads of them: two periods of three
+# delta-rule layers and a full one of 4 kv heads without rope, norms on the
+# sublayers' outputs, the q/k norm over the whole projection.
+TINY_OLMO_HYBRID = dataclasses.replace(
+    TINY, name="tiny-olmo-hybrid", num_layers=8, tie_word_embeddings=False, num_kv_heads=4,
+    qk_norm_flat=True, norm_placement="after", position_embedding="nope",
+    layer_types=("delta", "delta", "delta", "attention"),
+    linear_key_heads=4, linear_value_heads=4, linear_key_head_dim=96, linear_value_head_dim=192,
+    linear_conv=4, linear_chunk_size=8, linear_allow_neg_eigval=True,
 )
 
 PRESETS = {
@@ -1101,6 +1186,8 @@ PRESETS = {
         TRINITY_LARGE_EP8_5L,
         QWEN3_NEXT_80B_A3B,
         QWEN3_NEXT_80B_EP4_8L,
+        OLMO_HYBRID_7B,
+        OLMO_HYBRID_7B_16L,
         BENCH_PIPE,
         TINY,
         TINY_MOE,
@@ -1113,6 +1200,7 @@ PRESETS = {
         TINY_GRANITE_H,
         TINY_AFMOE,
         TINY_QWEN3_NEXT,
+        TINY_OLMO_HYBRID,
     ]
 }
 
@@ -1139,6 +1227,7 @@ HF_REPOS = {
     "deepseek-v2-lite": "deepseek-ai/DeepSeek-V2-Lite",
     "granite-4.0-h-micro": "ibm-granite/granite-4.0-h-micro",
     "qwen3-next-80b-a3b": "Qwen/Qwen3-Next-80B-A3B-Instruct",
+    "olmo-hybrid-7b": "allenai/Olmo-Hybrid-7B",
 }
 
 

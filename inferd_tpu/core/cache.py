@@ -42,6 +42,7 @@ the layout is exactly the classic single-buffer one.
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -74,14 +75,68 @@ TILE_SUBLANES = 8
 def rows_layout(cfg: ModelConfig) -> bool:
     """Do uniform dense lanes of this model store a token's keys (values) of
     ALL kv heads as one row [.., Nkv * D] (`RowEntry`) and not as [.., Nkv, D]
-    (`DenseEntry`)? Where a head is narrower than a tile, and where it is
-    wider than one among fewer kv heads than a tile has sublanes: the row is
+    (`DenseEntry`)? Where a head is narrower than a tile, where it is
+    wider than one among fewer kv heads than a tile has sublanes, and where
+    heads as wide as a tile are more than its sublanes and no whole number
+    of them (30 of 128: the head axis pads to 32, and the described-v5e
+    compile of the decode step re-lays both stacks whole, 2 x 2.0 GB of
+    temporaries at 4 x 16 x 4096, over the chip's memory): the row is
     the shape that is written and read where it lies. Rings and paged pools
     keep heads; a latent cache has none."""
     if cfg.is_mla:
         return False
-    return cfg.head_dim < TILE_LANES or (
-        cfg.head_dim > TILE_LANES and cfg.num_kv_heads < TILE_SUBLANES)
+    if cfg.head_dim == TILE_LANES:  # a head axis over one tile's sublanes that pads (30 to 32)
+        return cfg.num_kv_heads > TILE_SUBLANES and cfg.num_kv_heads % TILE_SUBLANES > 0
+    return cfg.head_dim < TILE_LANES or cfg.num_kv_heads < TILE_SUBLANES
+
+
+def state_fold(cfg: ModelConfig) -> int:
+    """How many heads of a delta-rule state lie SIDE BY SIDE on the minor axis
+    of what is held (`state_held_shape`). A value size over one tile's lanes
+    that is no whole number of them (192: one and a half) pads every row of
+    [.., Dk, Dv] to the next tile, a third more bytes held and moved every
+    step; 128 / gcd(Dv, 128) heads beside each other (two of 192: 384, three
+    tiles) are whole tiles. 1: the state is held as it is computed (every
+    value size that is a whole number of tiles, or under one; Mamba-2)."""
+    if cfg.state_kind != "delta":
+        return 1
+    dv = cfg.linear_value_head_dim
+    if dv <= TILE_LANES or dv % TILE_LANES == 0:
+        return 1
+    fold = TILE_LANES // math.gcd(dv, TILE_LANES)
+    return fold if cfg.linear_value_heads % fold == 0 else 1
+
+
+def state_held_shape(cfg: ModelConfig) -> Tuple[int, ...]:
+    """What a session's recurrent state is HELD as in one state layer, after
+    the lane axis: cfg.state_shape, or with `state_fold` heads side by side
+    [heads / fold, Dk, fold * Dv] (head g * fold + i in columns [i * Dv,
+    (i + 1) * Dv) of row-block g)."""
+    fold = state_fold(cfg)
+    if fold == 1:
+        return cfg.state_shape
+    heads, dk, dv = cfg.state_shape
+    return (heads // fold, dk, fold * dv)
+
+
+def state_heads_apart(held, fold: int):
+    """A state as it is held, [.., G, Dk, fold * Dv], head by head:
+    [.., G * fold, Dk, Dv] (numpy or jax; `fold` 1: as it is)."""
+    if fold == 1:
+        return held
+    *lead, g, dk, fdv = held.shape
+    apart = held.reshape(*lead, g, dk, fold, fdv // fold)
+    return apart.swapaxes(-2, -3).reshape(*lead, g * fold, dk, fdv // fold)
+
+
+def state_heads_beside(state, fold: int):
+    """The inverse of `state_heads_apart`: [.., H, Dk, Dv] with `fold` heads
+    side by side, [.., H / fold, Dk, fold * Dv]."""
+    if fold == 1:
+        return state
+    *lead, h, dk, dv = state.shape
+    beside = state.reshape(*lead, h // fold, fold, dk, dv)
+    return beside.swapaxes(-2, -3).reshape(*lead, h // fold, dk, fold * dv)
 
 
 def lane_shape(cfg: ModelConfig) -> Tuple[int, ...]:
@@ -185,8 +240,9 @@ class StateEntry:
     whatever the session's length and not indexed by position: it cannot be
     truncated, rolled back or cut at a prefix."""
 
-    s: jax.Array  # [B, *cfg.state_shape] in cfg.state_dtype: the recurrent state (Mamba-2
-    #   [heads, P, N]; the delta rule [value heads, Dk, Dv])
+    s: jax.Array  # [B, *state_held_shape(cfg)] in cfg.state_dtype: the recurrent state
+    #   (Mamba-2 [heads, P, N]; the delta rule [value heads, Dk, Dv], or with
+    #   `state_fold` heads side by side [value heads / fold, Dk, fold * Dv])
     conv: jax.Array  # [B, K-1, conv_dim]: the last inputs of the causal convolution
 
 
@@ -218,7 +274,7 @@ class KVCache:
     v_loc: Optional[jax.Array] = None
     # a model with state-space layers (cfg.has_state_layers): k and v hold
     # its ATTENTION layers only, these its state layers' StateEntry stack
-    s: Optional[jax.Array] = None  # [Lm, B, *cfg.state_shape] in cfg.state_dtype
+    s: Optional[jax.Array] = None  # [Lm, B, *state_held_shape(cfg)] in cfg.state_dtype
     conv: Optional[jax.Array] = None  # [Lm, B, K-1, conv_dim] in the model's dtype
 
     @property
@@ -258,7 +314,7 @@ class KVCache:
             shape = (la, batch, max_len, *lane)
             return KVCache(
                 k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt), length=jnp.int32(0),
-                s=jnp.zeros((lm, batch, *cfg.state_shape), jnp.dtype(cfg.state_dtype)),
+                s=jnp.zeros((lm, batch, *state_held_shape(cfg)), jnp.dtype(cfg.state_dtype)),
                 conv=jnp.zeros((lm, batch, *cfg.state_conv_shape), cfg.jnp_dtype),
             )
         if cfg.is_mla:

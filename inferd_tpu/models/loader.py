@@ -74,6 +74,8 @@ def params_from_hf_state_dict(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params
     """
     if cfg.is_mla:
         return _params_from_deepseek_v2(cfg, sd)
+    if cfg.state_kind == "delta" and cfg.norm_placement == "after":
+        return _params_from_olmo_hybrid(cfg, sd)
     if cfg.state_kind == "delta":
         return _params_from_qwen3_next(cfg, sd)
     if cfg.has_state_layers:
@@ -105,7 +107,7 @@ def params_from_hf_state_dict(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params
         "o_proj": stack("layers.{i}.self_attn.o_proj.weight", transpose=True),
         "post_norm": stack("layers.{i}.post_attention_layernorm.weight"),
     }
-    if cfg.sandwich_norm:  # Gemma-2's extra MLP norms
+    if cfg.norm_placement == "both":  # Gemma-2's extra MLP norms
         layers["pre_ffn_norm"] = stack("layers.{i}.pre_feedforward_layernorm.weight")
         layers["post_ffn_norm"] = stack("layers.{i}.post_feedforward_layernorm.weight")
     if cfg.qk_norm:  # Qwen3
@@ -461,6 +463,66 @@ def _params_from_qwen3_next(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:
         "state_layers": group("delta"),
         "final_norm": jnp.asarray(raw("norm.weight"), dtype=dt),
         "lm_head": jnp.asarray(w("lm_head")[:, :v], dtype=dt),
+    }
+
+
+def _params_from_olmo_hybrid(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:
+    """HF `olmo_hybrid` names -> `layers` for the full-attention kind,
+    `state_layers` for the Gated-DeltaNet kind, each in layer order, every
+    layer with its dense MLP and its two OUTPUT norms (the Olmo 2 / Olmo 3
+    names: `post_attention_layernorm` on the mixer's output, here `post_norm`,
+    and `post_feedforward_layernorm`, here `post_ffn_norm`; no input norm).
+
+    A `linear_attn` layer keeps its projections apart (the GatedDeltaNet
+    layer's q_proj, k_proj, v_proj, g_proj, b_proj, a_proj, o_proj): the
+    program's `in_proj` is [q | k | v | g] side by side and `ba_proj` [b | a],
+    the same functions of the input. `conv1d.weight` [C, 1, K] over the
+    channels q | k | v becomes the taps [K, C]; `o_norm.weight` the gated
+    norm. A full layer's `q_norm` / `k_norm` are vectors over the whole
+    projection (cfg.qk_norm_flat)."""
+    dt = cfg.jnp_dtype
+
+    def raw(name: str) -> np.ndarray:
+        return _to_np(sd[name if name in sd else f"model.{name}"])
+
+    def w(name: str) -> np.ndarray:  # a linear weight as [in, out]
+        return raw(f"{name}.weight").T
+
+    def layer(i: int, kind: str) -> Params:
+        out = {
+            "post_norm": raw(f"layers.{i}.post_attention_layernorm.weight"),
+            "post_ffn_norm": raw(f"layers.{i}.post_feedforward_layernorm.weight"),
+            **{proj: w(f"layers.{i}.mlp.{proj}") for proj in ("gate_proj", "up_proj", "down_proj")},
+        }
+        if kind == "attention":
+            at = f"layers.{i}.self_attn"
+            out.update(
+                q_norm=raw(f"{at}.q_norm.weight"), k_norm=raw(f"{at}.k_norm.weight"),
+                **{proj: w(f"{at}.{proj}") for proj in ("q_proj", "k_proj", "v_proj", "o_proj")},
+            )
+            return out
+        m = f"layers.{i}.linear_attn"
+        out.update(
+            in_proj=np.concatenate(
+                [w(f"{m}.{proj}") for proj in ("q_proj", "k_proj", "v_proj", "g_proj")], axis=1),
+            ba_proj=np.concatenate([w(f"{m}.b_proj"), w(f"{m}.a_proj")], axis=1),
+            conv_w=raw(f"{m}.conv1d.weight")[:, 0, :].T,
+            dt_bias=raw(f"{m}.dt_bias"), A_log=raw(f"{m}.A_log"),
+            gate_norm=raw(f"{m}.o_norm.weight"), out_proj=w(f"{m}.o_proj"),
+        )
+        return out
+
+    def group(kind: str) -> Params:
+        per_layer = [layer(i, kind) for i, k in enumerate(cfg.layer_type_names) if k == kind]
+        return {k: jnp.asarray(np.stack([lp[k] for lp in per_layer]), dtype=dt)
+                for k in per_layer[0]}
+
+    return {
+        "embed": jnp.asarray(raw("embed_tokens.weight"), dtype=dt),
+        "layers": group("attention"),
+        "state_layers": group("delta"),
+        "final_norm": jnp.asarray(raw("norm.weight"), dtype=dt),
+        "lm_head": jnp.asarray(w("lm_head"), dtype=dt),
     }
 
 

@@ -86,6 +86,19 @@ def _init_ffn_params(cfg: ModelConfig, w, n: int, dense: bool, ks, key) -> Param
     return p
 
 
+def _sublayer_norms(cfg: ModelConfig, norm: jax.Array) -> Params:
+    """A layer stack's RMSNorms around its two sublayers, each drawn as
+    `norm`, by cfg.norm_placement. "before": `input_norm`, and `post_norm`
+    before the feed-forward. "both": `post_norm` stands on the MIXER's output,
+    and the feed-forward has `pre_ffn_norm` and `post_ffn_norm`. "after":
+    `post_norm` on the mixer's output, `post_ffn_norm` on the
+    feed-forward's, and no other."""
+    names = {"before": ("input_norm", "post_norm"),
+             "both": ("input_norm", "post_norm", "pre_ffn_norm", "post_ffn_norm"),
+             "after": ("post_norm", "post_ffn_norm")}[cfg.norm_placement]
+    return {name: norm for name in names}
+
+
 def init_layer_params(
     cfg: ModelConfig, key: jax.Array, num_layers: Optional[int] = None,
     dense: bool = False,
@@ -104,19 +117,15 @@ def init_layer_params(
     norm1 = jnp.zeros if cfg.rms_norm_plus_one else jnp.ones
 
     p = {
-        "input_norm": norm1((n, h), dtype=dt),
+        **_sublayer_norms(cfg, norm1((n, h), dtype=dt)),  # Gemma: around the MLP too; Olmo: outputs only
         "q_proj": w(ks[0], h, q),
         "k_proj": w(ks[1], h, kv),
         "v_proj": w(ks[2], h, kv),
         "o_proj": w(ks[3], q, h),
-        "post_norm": norm1((n, h), dtype=dt),
     }
-    if cfg.sandwich_norm:  # Gemma: pre/post norms around the MLP too
-        p["pre_ffn_norm"] = norm1((n, h), dtype=dt)
-        p["post_ffn_norm"] = norm1((n, h), dtype=dt)
-    if cfg.qk_norm:  # Qwen3's per-head q/k RMSNorm
-        p["q_norm"] = norm1((n, d), dtype=dt)
-        p["k_norm"] = norm1((n, d), dtype=dt)
+    if cfg.qk_norm:  # Qwen3's per-head q/k RMSNorm; Olmo's over the whole projection
+        p["q_norm"] = norm1((n, q if cfg.qk_norm_flat else d), dtype=dt)
+        p["k_norm"] = norm1((n, kv if cfg.qk_norm_flat else d), dtype=dt)
     if cfg.attn_bias:  # Qwen2's q/k/v projection biases
         p["q_bias"] = jnp.zeros((n, q), dtype=dt)
         p["k_bias"] = jnp.zeros((n, kv), dtype=dt)
@@ -148,7 +157,11 @@ def init_state_layer_params(cfg: ModelConfig, key: jax.Array, num_layers: int) -
     inverse softplus, A = -exp(A_log) with exp(A_log) uniform in [0.1, 1], so
     the decay exp(d A) of a step spreads over about 0.5-0.999 and the state
     weighs about what the newest token does; Mamba-2's D uniform in
-    [0.5, 1.5]; the convolution's taps normal with deviation 0.3."""
+    [0.5, 1.5]; the convolution's taps normal with deviation 0.3. Where beta
+    may pass 1 (cfg.linear_allow_neg_eigval) the columns of `b` are drawn
+    with deviation 0.5 / sqrt(hidden): b is then about half the residual
+    stream's RMS wide, and beta = 2 sigmoid(b) spreads over (0, 2) where a
+    deviation of 0.02 would leave every head at 1 +- 0.03."""
     n, h = num_layers, cfg.hidden_size
     delta = cfg.state_kind == "delta"
     heads = cfg.linear_value_heads if delta else cfg.mamba_heads
@@ -164,10 +177,9 @@ def init_state_layer_params(cfg: ModelConfig, key: jax.Array, num_layers: int) -
 
     step = jnp.exp(uniform(ks[4], math.log(0.01), math.log(0.5)))
     p = {
-        "input_norm": norm1((n, h), dtype=dt),
+        **_sublayer_norms(cfg, norm1((n, h), dtype=dt)),
         "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),  # softplus^-1(step)
         "A_log": jnp.log(uniform(ks[5], 0.1, 1.0)).astype(dt),
-        "post_norm": norm1((n, h), dtype=dt),
     }
     if delta:
         cd, dv = cfg.linear_conv_dim, cfg.linear_value_dim
@@ -178,6 +190,9 @@ def init_state_layer_params(cfg: ModelConfig, key: jax.Array, num_layers: int) -
             gate_norm=jnp.ones((n, cfg.linear_value_head_dim), dtype=dt),  # scales by w
             out_proj=w(ks[3], dv, h),
         )
+        if cfg.linear_allow_neg_eigval:
+            wide = w(jax.random.fold_in(ks[2], 1), h, heads, std=0.5 / math.sqrt(h))
+            p["ba_proj"] = p["ba_proj"].at[..., :heads].set(wide)
     else:
         di, cd = cfg.mamba_inner, cfg.mamba_conv_dim
         p.update(
@@ -1120,12 +1135,17 @@ def _attend_update_rows(cfg, q, k, v, q_positions, entry, at, ctx, window, sinks
         k=_lanes_write(entry.k, at, k.reshape(b, s, nkv * d), ctx.write_pos, ctx.write_mask),
         v=_lanes_write(entry.v, at, v.reshape(b, s, nkv * d), ctx.write_pos, ctx.write_mask),
     )
+    # a chunk over rows of heads as wide as a tile attends head by head: the
+    # block-diagonal query costs Nkv times the products, nothing beside a
+    # step's read of the slab, a chunk's whole budget at 30 kv heads
+    by_head = s > 1 and d == cachelib.TILE_LANES
+
     def attend(new_k, new_v, kvpos, end, win, flash):  # [B, T or less, Nkv * D]
-        if flash:
+        if flash or by_head:
             heads = lambda a: a.reshape(*a.shape[:2], nkv, d)
             return _attend(
                 cfg, q, heads(new_k), heads(new_v), q_positions, end,
-                kv_positions=kvpos, window=win, sinks=sinks, flash=True,
+                kv_positions=kvpos, window=win, sinks=sinks, flash=flash,
             )
         out = gqa_attention(
             _rows_query(q, nkv), new_k[:, :, None], new_v[:, :, None], q_positions, end,
@@ -1199,9 +1219,10 @@ _ATTEND_UPDATE = {
 
 
 def _gqa_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx, window, adapters):
-    """Per-head q/k/v from the normed input `x`, the chunk's keys and values
-    written at layer `at` of the stacked entries in whichever layout they
-    have, and attention over that layer -> (attn [B, S, Nq*D], entry')."""
+    """Per-head q/k/v from the layer's input `x` (normed where the block norms
+    it), the chunk's keys and values written at layer `at` of the stacked
+    entries in whichever layout they have, and attention over that layer ->
+    (attn [B, S, Nq*D], entry')."""
     b, s, _h = x.shape
     d = cfg.head_dim
     q = lora_ops.apply_lane_delta(qdot(x, lp["q_proj"]), x, "q_proj", adapters)
@@ -1211,10 +1232,14 @@ def _gqa_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx, window
         q = q + lp["q_bias"]
         k = k + lp["k_bias"]
         v = v + lp["v_bias"]
+    if cfg.qk_norm_flat:  # Olmo: one norm over the whole projection, before the split
+        with jax.named_scope("qk_norm_flat"):
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps, cfg.rms_norm_plus_one)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps, cfg.rms_norm_plus_one)
     q = q.reshape(b, s, q.shape[-1] // d, d)
     k = k.reshape(b, s, k.shape[-1] // d, d)
     v = v.reshape(b, s, v.shape[-1] // d, d)
-    if cfg.qk_norm:  # Qwen3 signature feature
+    if cfg.qk_norm and not cfg.qk_norm_flat:  # Qwen3 signature feature
         q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps, cfg.rms_norm_plus_one)
         k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps, cfg.rms_norm_plus_one)
     if cos is not None:  # None: a model without position embedding
@@ -1349,8 +1374,9 @@ _HI = jax.lax.Precision.HIGHEST  # float32 operands stay float32 on the MXU
 
 def _state_enter(cfg: ModelConfig, entry, at, ctx, b: int, s: int, dtype):
     """What a state layer's chunk of `s` positions enters with -> (the state
-    [B, *cfg.state_shape] f32, the convolution's kept inputs [B, K-1, C] in
-    `dtype`, how many of the chunk's positions are real [B], and the stored
+    [B, *cfg.state_shape] f32 (a decode row of a state held with heads side by
+    side, core.cache.state_fold: as it is held), the convolution's kept inputs
+    [B, K-1, C] in `dtype`, how many of the chunk's positions are real [B], and the stored
     state and inputs of layer `at` as they lie, None without a cache). A
     row written at position 0 enters with zeros whatever its lane held: that
     is how a session starts; with no `entry` every row does."""
@@ -1366,12 +1392,16 @@ def _state_enter(cfg: ModelConfig, entry, at, ctx, b: int, s: int, dtype):
     s_old, kept_old = _slab(entry.s, at), _slab(entry.conv, at)
     s_in = jnp.where(fresh[..., None], 0.0, s_old.astype(f32))
     kept = jnp.where(fresh, 0, kept_old).astype(dtype)
+    if s > 1:  # a chunk computes head by head: heads held side by side are taken apart
+        s_in = cachelib.state_heads_apart(s_in, cachelib.state_fold(cfg))
     return s_in, kept, real, s_old, kept_old
 
 
 def _state_leave(entry, at, ctx, s_new, kept, s_old, kept_old):
     """The stacked StateEntry with layer `at` holding `s_new` and `kept`; a
     row whose ctx.write_mask is False keeps what it had, by a select."""
+    # a chunk's state comes head by head: laid as it is held (core.cache.state_fold)
+    s_new = cachelib.state_heads_beside(s_new, s_new.shape[1] // s_old.shape[1])
     s_new = s_new.astype(entry.s.dtype)
     kept = kept.astype(entry.conv.dtype)
     if ctx.write_mask is not None:
@@ -1564,20 +1594,62 @@ def gated_delta_chunked(q, k, v, g, beta, s_in, tile: int):
     return o.reshape(b, s, h, dv), s_out
 
 
+def _delta_update_folded(q, k, v, g, beta, state, fold: int):
+    """One token of the gated delta rule over a state held with `fold` heads
+    side by side (core.cache.state_held_shape): q, k [B, H, Dk], v [B, H, Dv],
+    g and beta [B, H], state [B, H / fold, Dk, fold * Dv] f32 -> (o [B, H, Dv],
+    the state after the token, as it is held). The update as
+    gated_delta_mixer writes it, in TWO passes over the state where that reads
+    it three times: the first reads the OLD state alone, S^T k and S^T q at
+    once; the second writes S_t = exp(g) S + k u^T where it lies; and
+
+        o_t = S_t^T q_t = exp(g_t) S_{t-1}^T q_t + (k_t . q_t) u_t
+
+    needs no read of the new state. One reader before one writer: nothing of
+    the stack is copied around the update (as written, the described-v5e
+    compile of Dk 96, Dv 192 split it into three fusions of which the second
+    rewrites the stack while the third still reads it, and copied the whole
+    stack around each)."""
+    b, h, dk = k.shape
+    dv = v.shape[-1]
+    grp = h // fold
+    col = jnp.arange(fold * dv) // dv  # the head of a group that a column belongs to
+
+    def per_head(x):  # [B, H] -> [B, H / fold, fold * Dv]: a head's scalar over its columns
+        return jnp.repeat(x.reshape(b, grp, fold), dv, axis=-1)
+
+    def over_columns(x):  # [B, H, Dk] -> [B, H / fold, Dk, fold * Dv]: a head's vector down them
+        parts = x.reshape(b, grp, fold, dk)
+        out = parts[:, :, fold - 1, :, None]
+        for i in reversed(range(fold - 1)):
+            out = jnp.where(col <= i, parts[:, :, i, :, None], out)
+        return out
+
+    decay, kc, qc = per_head(jnp.exp(g)), over_columns(k), over_columns(q)
+    sk = jnp.sum(state * kc, axis=-2) * decay  # S'^T k, S' = exp(g) S
+    sq = jnp.sum(state * qc, axis=-2) * decay
+    u = per_head(beta) * (v.reshape(b, grp, fold * dv) - sk)
+    s_new = state * decay[:, :, None, :] + kc * u[:, :, None, :]
+    o = sq + per_head(jnp.sum(k * q, axis=-1)) * u
+    return o.reshape(b, h, dv), s_new
+
+
 def _l2norm(u: jax.Array) -> jax.Array:
     return u * jax.lax.rsqrt(jnp.sum(u * u, axis=-1, keepdims=True) + 1e-6)
 
 
 def gated_delta_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, entry, at, ctx):
-    """A Gated-DeltaNet block (the Qwen3-Next family) over the normed input
-    x [B, S, H] -> (out [B, S, H], entry'); `entry`, `at` and `ctx` as
-    mamba_mixer takes them, and the same rules for padding, write_mask and
-    position 0 (_state_enter, _causal_conv, _state_leave).
+    """A Gated-DeltaNet block (the Qwen3-Next and Olmo-Hybrid families) over
+    the layer's input x [B, S, H] (normed where the block norms it) -> (out
+    [B, S, H], entry'); `entry`, `at` and `ctx` as mamba_mixer takes them, and
+    the same rules for padding, write_mask and position 0 (_state_enter,
+    _causal_conv, _state_leave).
 
         [q | k | v | z] = x W_in;  [b | a] = x W_ba
         [q|k|v]_t = silu(sum_i w_conv[i] [q|k|v]_{t-(K-1)+i})        causal, depthwise, no bias
         value head h reads key head h // (value heads / key heads)
         q = l2norm(q) / sqrt(Dk);  k = l2norm(k);  beta_t = sigmoid(b_t)
+                                  (cfg.linear_allow_neg_eigval: 2 sigmoid(b_t))
         g_t = -exp(A_log) softplus(a_t + dt_bias)
         S' = exp(g_t) S_{t-1};  u_t = beta_t (v_t - S'^T k_t);  S_t = S' + k_t u_t^T;  o_t = S_t^T q_t
         out = (RMSNorm(o_t; w_norm) silu(z_t)) W_out      per head: the norm first, then the gate
@@ -1586,7 +1658,10 @@ def gated_delta_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, entry, at, ctx
     written; a longer chunk runs `gated_delta_chunked`, tiled by
     cfg.linear_chunk_size where that divides it. The state [B, value heads,
     Dk, Dv] is float32 while it is computed and is held in cfg.state_dtype
-    between steps. A padding position has g_t = 0 and beta_t = 0."""
+    between steps; where it is held with heads side by side
+    (core.cache.state_fold: a value size that would pad its tiles) a decode row
+    updates it as it is held (_delta_update_folded) and a chunk takes the heads
+    apart and lays them back. A padding position has g_t = 0 and beta_t = 0."""
     f32 = jnp.float32
     b, s, _ = x.shape
     hk, hv, dk, dv = (cfg.linear_key_heads, cfg.linear_value_heads, cfg.linear_key_head_dim,
@@ -1603,11 +1678,20 @@ def gated_delta_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, entry, at, ctx
     k = _l2norm(heads(qkv[..., kd:2 * kd], hk, dk))
     v = qkv[..., 2 * kd:].reshape(b, s, hv, dv)
     valid = (jnp.arange(s)[None, :] < real[:, None])[..., None]
-    beta = jnp.where(valid, jax.nn.sigmoid(ba[..., :hv]), 0.0)
+    beta = jax.nn.sigmoid(ba[..., :hv])
+    if cfg.linear_allow_neg_eigval:  # Olmo-Hybrid: I - beta k k^T may turn a key's direction round
+        beta = 2.0 * beta
+    beta = jnp.where(valid, beta, 0.0)
     g = -jnp.exp(lp["A_log"].astype(f32)) * jax.nn.softplus(
         ba[..., hv:] + lp["dt_bias"].astype(f32))
     g = jnp.where(valid, g, 0.0)
-    if s == 1:
+    fold = cachelib.state_fold(cfg) if entry is not None else 1
+    if s == 1 and fold > 1:
+        with jax.named_scope("gdn_update"):
+            o, s_new = _delta_update_folded(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s_in, fold)
+            o = o[:, None]
+    elif s == 1:
         with jax.named_scope("gdn_update"):
             q1, k1, v1, g1, b1 = q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0]
             s_new = s_in * jnp.exp(g1)[..., None, None]
@@ -1650,12 +1734,17 @@ def decoder_layer(
     #   "scale": [B] f32} — slot-0 (base) lanes carry zero A/B and apply
     #   nothing (ops.lora.apply_lane_delta)
 ):
-    """One pre-norm residual decoder block: a mixer, then a feed-forward, two
-    independent choices. The mixer is GQA + per-head q/k RMSNorm (the Qwen3
-    signature feature — reference qwen3_server_module.py:123-124), latent
-    attention (cfg.is_mla), or for a layer of the state kind's stack a
-    Mamba-2 block or the gated delta rule; the feed-forward is the dense MLP
-    or routed experts beside a shared one, by what the layer's stack holds.
+    """One residual decoder block: a mixer, then a feed-forward, two
+    independent choices, each sublayer with its RMSNorm where
+    cfg.norm_placement puts it: on its input (the pre-norm block), on its
+    input and its output (Gemma's sandwich), or on its output alone (Olmo:
+    h = x + Norm(Mixer(x)), y = h + Norm(MLP(h)), both reading the residual
+    stream as it is). The mixer is GQA + per-head q/k RMSNorm (the Qwen3
+    signature feature — reference qwen3_server_module.py:123-124; Olmo's norm
+    over the whole projection), latent attention (cfg.is_mla), or for a layer
+    of the state kind's stack a Mamba-2 block or the gated delta rule; the
+    feed-forward is the dense MLP or routed experts beside a shared one, by
+    what the layer's stack holds.
 
     Returns (hidden', entry', chosen experts [B, S, K] or None for a dense
     MLP); entry' is the whole stack with this layer's rows of the chunk
@@ -1685,7 +1774,11 @@ def decoder_layer(
     scaled = (lambda y: y) if cfg.residual_multiplier == 1.0 else (
         lambda y: y * cfg.residual_multiplier)
 
-    x = rms_norm(hidden, lp["input_norm"], cfg.rms_norm_eps, p1)
+    def out_norm(y, w):  # a sublayer's output, before it joins the residual
+        with jax.named_scope("out_norm"):
+            return rms_norm(y, w, cfg.rms_norm_eps, p1)
+
+    x = rms_norm(hidden, lp["input_norm"], cfg.rms_norm_eps, p1) if cfg.norm_before else hidden
     if "in_proj" in lp:  # a state layer: its stack holds no q / k / v
         if tp_axis or ep_axis or adapters is not None or not isinstance(
                 entry, (type(None), cachelib.StateEntry)):
@@ -1716,14 +1809,16 @@ def decoder_layer(
             attn_out = jax.lax.psum(attn_out, tp_axis)
         if cfg.o_bias:  # replicated bias joins AFTER the partial-sum combine
             attn_out = attn_out + lp["o_bias"]
-        if cfg.sandwich_norm:  # Gemma: post-norm the sublayer output pre-residual
-            attn_out = rms_norm(attn_out, lp["post_norm"], cfg.rms_norm_eps, p1)
+    if cfg.norm_after:  # Gemma, Olmo: the mixer's output normed pre-residual
+        attn_out = out_norm(attn_out, lp["post_norm"])
     hidden = hidden + scaled(attn_out).astype(hidden.dtype)
 
     # the feed-forward: dense, or routed experts beside a shared one, whatever
     # the mixer above was
-    pre_ffn = lp["pre_ffn_norm"] if cfg.sandwich_norm else lp["post_norm"]
-    x = rms_norm(hidden, pre_ffn, cfg.rms_norm_eps, p1)
+    x = hidden
+    if cfg.norm_before:
+        pre_ffn = lp["pre_ffn_norm"] if cfg.norm_after else lp["post_norm"]
+        x = rms_norm(hidden, pre_ffn, cfg.rms_norm_eps, p1)
     expert_axes = tuple(a for a in (ep_axis, tp_axis) if a is not None)
     topi = None
     if cfg.is_moe and "router" in lp:  # a leading dense layer has no router
@@ -1749,8 +1844,8 @@ def decoder_layer(
         mlp_out = swiglu_mlp(lp, x, act_fn(cfg), lane_adapters=adapters)
         if tp_axis is not None:  # row-parallel down-proj
             mlp_out = jax.lax.psum(mlp_out, tp_axis)
-    if cfg.sandwich_norm:
-        mlp_out = rms_norm(mlp_out, lp["post_ffn_norm"], cfg.rms_norm_eps, p1)
+    if cfg.norm_after:
+        mlp_out = out_norm(mlp_out, lp["post_ffn_norm"])
     return hidden + scaled(mlp_out).astype(hidden.dtype), entry, topi
 
 

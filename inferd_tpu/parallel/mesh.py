@@ -105,7 +105,7 @@ def layer_param_specs(cfg: ModelConfig, layer_axis: Optional[str] = None) -> Dic
         "o_proj": P(*L, "tp", None),
         "post_norm": P(*L, None),
     }
-    if cfg.sandwich_norm:
+    if cfg.norm_placement == "both":
         specs["pre_ffn_norm"] = P(*L, None)
         specs["post_ffn_norm"] = P(*L, None)
     if cfg.qk_norm:
@@ -300,7 +300,7 @@ def grad_sync_axes(cfg: ModelConfig) -> Dict[str, Any]:
         "up_proj": data,
         "down_proj": data,
     }
-    if cfg.sandwich_norm:
+    if cfg.norm_placement == "both":
         # post-norms consume tp-psummed sublayer outputs (replicated):
         # their grads, like input_norm's, are complete without a tp sync
         layers["pre_ffn_norm"] = data

@@ -247,11 +247,11 @@ def sharded_decoder_layer(
     attn_out = psum_replicated(qdot(attn, lp["o_proj"]), (tp_axis,))
     if cfg.o_bias:  # replicated bias joins AFTER the partial-sum combine
         attn_out = attn_out + lp["o_bias"]
-    if cfg.sandwich_norm:  # Gemma: post-norm the sublayer output pre-residual
+    if cfg.norm_placement == "both":  # Gemma: post-norm the sublayer output pre-residual
         attn_out = rms_norm(attn_out, lp["post_norm"], cfg.rms_norm_eps, p1)
     hidden = hidden + attn_out.astype(hidden.dtype)
 
-    pre_ffn = lp["pre_ffn_norm"] if cfg.sandwich_norm else lp["post_norm"]
+    pre_ffn = lp["pre_ffn_norm"] if cfg.norm_placement == "both" else lp["post_norm"]
     x = rms_norm(hidden, pre_ffn, cfg.rms_norm_eps, p1)
     aux = jnp.float32(0.0)
     if cfg.is_moe:
@@ -267,7 +267,7 @@ def sharded_decoder_layer(
         gate = act_fn(cfg)(qdot(x, lp["gate_proj"]))
         up = qdot(x, lp["up_proj"])
         mlp_out = psum_replicated(qdot(gate * up, lp["down_proj"]), (tp_axis,))
-    if cfg.sandwich_norm:
+    if cfg.norm_placement == "both":
         mlp_out = rms_norm(mlp_out, lp["post_ffn_norm"], cfg.rms_norm_eps, p1)
     out = hidden + mlp_out.astype(hidden.dtype)
     if return_kv:

@@ -258,7 +258,7 @@ def decode_step_cost(
     mlp_f *= L
 
     # -- norms (small, but they ARE per-step HBM reads) ---------------------
-    per_layer_norms = 2 * h + (2 * h if cfg.sandwich_norm else 0)
+    per_layer_norms = 2 * h + (2 * h if cfg.norm_placement == "both" else 0)
     if cfg.qk_norm:
         per_layer_norms += 2 * d
     norm_b = (L * per_layer_norms + h) * dsize  # + final_norm

@@ -596,6 +596,54 @@ def test_qwen3_next_share_lane_programs_compile_with_state_and_rows_in_place(
     assert mem.argument_size_in_bytes + pm.temp_size_in_bytes < 15.75e9 * 0.8
 
 
+def test_olmo_hybrid_lane_programs_compile_with_the_state_unpadded_and_the_rows_in_place(
+    one_chip, no_compile_cache
+):
+    """The two programs a `--model olmo-hybrid-7b-16l --batch-lanes 16
+    --max-len 4096` node runs, at the published widths: 8.20 GB of weights,
+    twelve layers' float32 delta-rule state HELD two heads side by side,
+    `f32[12,16,15,96,384]` in whole tiles (as `[.., 30, 96, 192]` every row of
+    192 pads to 256, a third more), four full layers' keys and values as ONE
+    row of 3 840 a token (as `[.., 30, 128]` the head axis pads to 32 and the
+    decode step re-laid both stacks whole: 2 x 2.0 GB of temporaries, 15.93 GB
+    of 15.75, refused): 4.464 GB of cache. The decode step (with its sampler
+    and the lanes' `active` mask, as the executor calls it) aliases the whole
+    donated cache and holds under 0.1 GB of temporaries (0.007): no copy of
+    the state stack (the update as `gated_delta_mixer` writes it compiled to
+    three fusions a layer and a copy of the stack around each: 24 x 0.57 GB a
+    step), of a slab or of a weight stack; each linear layer reads the stack
+    in ONE reduce fusion (S^T k and S^T q together) and writes it in ONE
+    update where it lies. A 512-token prefill chunk: 0.65 GB of temporaries.
+    The numbers are the configuration's `deployment`."""
+    import re
+
+    cfg = get_config("olmo-hybrid-7b-16l")
+    shapes, step, prefill = _lane_programs(cfg, 16, 4096, one_chip, active=True)
+    assert shapes.k.shape == (4, 16, 4096, 3840) and shapes.s.shape == (12, 16, 15, 96, 384)
+    assert shapes.nbytes == 4_464_476_160 and shapes.state_bytes == 16 * 27_371_520
+    mem = step.memory_analysis()
+    assert 12.65e9 < mem.argument_size_in_bytes < 12.69e9  # 8.202 GB of weights + 4.464 of cache
+    assert mem.alias_size_in_bytes >= shapes.nbytes
+    assert mem.temp_size_in_bytes < 0.1e9
+    text = step.as_text()
+    # the state as it is held, in whole (8, 128) tiles: nothing padded
+    assert re.search(r"f32\[12,16,15,96,384\]\{4,3,2,1,0:T\(8,128\)\} parameter", text)
+    assert re.search(r"bf16\[4,16,4096,3840\]\{3,2,1,0:T\(8,128\)\(2,1\)\} parameter", text)
+    copies = re.findall(r"= (\S+?)\{[^ ]* copy\(", text)
+    assert not [c for c in copies
+                if c.startswith(("f32[12,16,15,", "f32[16,15,96,", "bf16[12,3840,", "bf16[4,3840,",
+                                 "bf16[12,11008,", "bf16[4,11008,"))]
+    assert _made_whole(text, r"bf16\[4,16,4096,", r"bf16\[1,16,\d+,3840", r"bf16\[16,\d{3,4},3840\]") == []
+    # the fused computations handed the state stack: a period's three linear
+    # layers, ONE that reads it and ONE that writes it where it lies each
+    takes = re.findall(r"^%\S+ \([^)]*f32\[12,16,15,96,384\][^)]*\) -> (\S+)", text, re.M)
+    assert len(takes) == 6 and sum(t.startswith("f32[12,16,15,96,384]") for t in takes) == 3, takes
+    pm = prefill.memory_analysis()
+    assert pm.alias_size_in_bytes >= shapes.nbytes and pm.temp_size_in_bytes < 0.9e9
+    assert not re.findall(r"= bf16\[4,(?:16|1),4096,[^ ]* copy\(", prefill.as_text())
+    assert mem.argument_size_in_bytes + pm.temp_size_in_bytes < 15.75e9 * 0.86
+
+
 def test_llama32_1b_lanes_keep_their_rows_where_they_lie(one_chip, no_compile_cache):
     """The other public model of this head size (8 kv heads of 64, 16
     layers), as `--model llama3.2-1b --batch-lanes 32 --max-len 4096` would

@@ -506,7 +506,7 @@ def test_gemma2_golden_parity_vs_hf():
         intermediate_size=128, num_layers=4, num_heads=4, num_kv_heads=2,
         head_dim=16, max_position_embeddings=512, rope_theta=1e4,
         dtype="float32", qk_norm=False, attn_bias=False,
-        sandwich_norm=True, rms_norm_plus_one=True, hidden_act="gelu_tanh",
+        norm_placement="both", rms_norm_plus_one=True, hidden_act="gelu_tanh",
         scale_embedding=True, attn_logit_softcap=50.0,
         final_logit_softcap=30.0, query_pre_attn_scalar=32.0,
         sliding_window=8,
@@ -1031,7 +1031,10 @@ def test_a_cells_program_lowers_to_its_recorded_text(lowered_programs, name):
     layers that route) and left the fourteen digests as they were. PR 49 left
     the sixteen as they were (a 64-slot slab is under the floor of the read
     by prefix, models/qwen3.read_rungs) and added `q4b.*.t1024`, the step and
-    the prefill over lanes of 1024 slots, where that rule engages. A PR that
+    the prefill over lanes of 1024 slots, where that rule engages. PR 51 left
+    the eighteen as they were (a third norm placement, a flat q/k norm, beta
+    to 2, a state held heads side by side and a two-pass update: each behind
+    a field that is absent by default) and added the two of `olmoh.*`. A PR that
     changes one of them on purpose runs `python tests/lowered_programs.py
     --record` and says so."""
     import json
