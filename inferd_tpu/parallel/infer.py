@@ -747,6 +747,14 @@ class PipelinedEngine:
         self.passes = 0
         self.stage_ticks = 0
         self.stage_ticks_useful = 0
+        # how a decode pass takes its tokens and keys (`dispatch_slots`):
+        # core.sampling.ahead_rows with its two results laid out one way on
+        # the mesh whatever it read them from, and what it reads where
+        # nothing comes from the pass before (a packed array's first three
+        # columns; placed on the mesh at the first pass)
+        repl = NamedSharding(mesh, P())
+        self._feed = jax.jit(samplib.ahead_rows, out_shardings=(repl, repl))
+        self._no_pass = None
 
     @property
     def sp_active(self) -> bool:
@@ -1017,37 +1025,66 @@ class PipelinedEngine:
             at["bytes"] = out.nbytes
         return out
 
-    def step_slots(self, tokens_by_slot, asks=None) -> dict:
-        """Decode ONE token for several slots in a single pipeline pass
-        (requires batch == 1 per slot — the serving shape). tokens_by_slot:
-        {slot: token}; `asks`: {slot: an ask with `.sampling`, `.key`,
-        `.want`, `.top_n` (runtime/executor.SampleAsk)} for the slots whose
-        token the pass chooses. Returns, a slot, its reply
-        (core.sampling.row_replies: {"tokens": [[id]], "key", ...}) where
-        it asked, else its logits [V] float32: ONE small transfer for all
-        the asked; a slot without an ask gets its logits row (that row
-        alone where it is the only one, else the pass's whole [MB, V])."""
+    def dispatch_slots(self, tokens_by_slot, asks=None, ahead=(), last=None):
+        """Dispatch ONE pipeline pass that decodes a token for several slots
+        (requires batch == 1 per slot — the serving shape) and return without
+        waiting for it: (logits [MB, V], packed, top_n), `packed` the pass's
+        one small array in core.sampling.pack_rows' layout, on its way to the
+        host once the pass is done. tokens_by_slot: {slot: token} from the
+        host; `asks`: {slot: an ask with `.sampling`, `.key`, `.want`,
+        `.top_n` (runtime/executor.SampleAsk)} for the slots whose token the
+        pass chooses; `ahead`: slots whose token and key are those the pass
+        before left ON THE DEVICES in its packed array `last` (no round trip:
+        core.sampling.ahead_rows).
+
+        Every pass takes its tokens and keys through ahead_rows (`_feed`), a
+        pass fed from the host alone too (over `_no_pass`): what it hands
+        back lies on the mesh, and a jitted program is compiled anew for
+        inputs that lie elsewhere, so `_step_raw_multi` sees ONE placement
+        of its inputs whoever feeds it and compiles once a `top_n`. The lengths
+        advance inside the pass (`PipelinedCaches.lengths`); the counters
+        count it here, at its dispatch."""
         if self.batch != 1:
             raise ValueError("step_slots supports batch=1 slots only")
         asks = asks or {}
         toks = np.zeros((self.mb,), np.int32)
         active = np.zeros((self.mb,), bool)
+        from_dev = np.zeros((self.mb,), bool)
         for slot, tok in tokens_by_slot.items():
             toks[slot] = tok
             active[slot] = True
+        for slot in ahead:
+            from_dev[slot] = active[slot] = True
         ask, top_n = samplib.RowAsk.of(self.mb, asks)
+        if self._no_pass is None:
+            self._no_pass = jax.device_put(
+                np.zeros((self.mb, 3), np.int32), NamedSharding(self.mesh, P(None, None))
+            )  # laid out as a pass leaves its packed array: `_feed` compiles once a width
+        toks, keys = self._feed(last if len(ahead) else self._no_pass, toks, ask.keys, from_dev)
+        self.caches, logits, packed = self._step_raw_multi(
+            self.params, self.caches, toks, active,
+            ask=samplib.RowAsk(keys, ask.warp), top_n=top_n,
+        )
+        packed.copy_to_host_async()  # queued behind the pass: on its way when it is done
+        self._count_pass(self.mb, int(active.sum()))
+        return logits, packed, top_n
+
+    def step_slots(self, tokens_by_slot, asks=None) -> dict:
+        """`dispatch_slots` and the wait for it, for a caller that keeps
+        nothing ahead: returns, a slot, its reply (core.sampling.row_replies:
+        {"tokens": [[id]], "key", ...}) where it asked, else its logits [V]
+        float32: ONE small transfer for all the asked; a slot without an ask
+        gets its logits row (that row alone where it is the only one, else
+        the pass's whole [MB, V])."""
+        asks = asks or {}
         plain = [slot for slot in tokens_by_slot if slot not in asks]
         live = len(tokens_by_slot)
         with tracelib.region(
             self.tracer, "device", kind="decode", tokens=live, cobatch=live,
             program=program_name(self._step_raw_multi),
         ):
-            self.caches, logits, packed = self._step_raw_multi(
-                self.params, self.caches, toks, active, ask=ask, top_n=top_n,
-            )
-            packed.copy_to_host_async()  # queued behind the pass: on its way when it is done
+            logits, packed, top_n = self.dispatch_slots(tokens_by_slot, asks)
             packed.block_until_ready()
-        self._count_pass(self.mb, live)
         with tracelib.region(self.tracer, "copy_out") as at:
             host = np.asarray(packed)
             rows, moved = samplib.logits_out(logits, plain)

@@ -44,7 +44,6 @@ Concurrency design (process() runs on the node's worker thread pool):
 from __future__ import annotations
 
 import contextlib
-import threading
 import time
 from typing import Any, Dict, Optional
 
@@ -65,6 +64,7 @@ from inferd_tpu.runtime.executor import (
     BlockCall, cache_intact, call_kind, parse_block, parse_decode_ask, parse_kstep,
 )
 from inferd_tpu.runtime.spec_serving import SpecForkMiss, SpecServing
+from inferd_tpu.runtime.step_ahead import StepAhead, _Ahead, _Step
 from inferd_tpu.runtime.window import WindowedBatcher
 from inferd_tpu.utils import lockwatch
 
@@ -75,71 +75,6 @@ class CapacityError(RuntimeError):
     """All lanes are serving in-flight requests — transient backpressure
     (the node maps this to a retryable 503, unlike deterministic KV
     overflow which is a 409)."""
-
-
-class _Step:
-    """One dispatched decode program of the dense lanes and what it ran
-    for. `finish` (BatchedExecutor._finish: any thread, once) waits for it,
-    copies its one packed array out and fills `toks` / `keys` / `replies` /
-    `rows` (`out`); until then only the device holds what it chose."""
-
-    __slots__ = (
-        "packed", "logits", "top_n", "asks", "plain", "lanes", "prev",
-        "program", "t_call", "t0", "t_done", "t_out", "lock", "done", "error",
-        "toks", "keys", "replies", "rows", "released",
-    )
-
-    def __init__(self, packed, logits, top_n, asks, plain, lanes, prev, program):
-        self.packed, self.logits, self.top_n = packed, logits, top_n
-        self.asks = asks  # {lane: SampleAsk} of the rows that chose a token
-        self.plain = plain  # lanes answered with their logits row
-        self.lanes = lanes  # every row run for a session
-        self.prev = prev  # the step dispatched before: `device` starts at its end
-        self.program = program
-        self.t_call = tracelib.now()
-        self.t0 = self.t_done = self.t_out = 0.0
-        self.lock = threading.Lock()
-        self.done = False
-        # set once the next drain has run the rows this step's riders
-        # promised (BatchedExecutor._carry); the steps of one drain share it
-        self.released = threading.Event()
-        self.error: Optional[Exception] = None
-        self.toks = self.keys = self.replies = self.rows = None
-
-    def reply(self, lane: int):
-        """What the hop of `lane` is answered with, once finished: the
-        token's reply, or the logits row of a hop that asked for none."""
-        return self.replies[lane] if lane in self.replies else self.rows[lane]
-
-    def span(self) -> Dict[str, Any]:
-        """The attributes of the step's `device` span."""
-        return {"kind": "decode", "tokens": len(self.lanes),
-                "cobatch": len(self.lanes), "program": self.program}
-
-    def fed(self, lane: int, tok, ask, ran) -> bool:
-        """Whether a hop of `lane` that carries `tok` under `ask` is the one
-        a row was run for under `ran`, from what this step (finished) chose:
-        its token and its key, the same sampling and log-probabilities."""
-        return (
-            ask.sampling == ran.sampling and ask.want == ran.want
-            and self.toks[lane] == tok and self.keys[lane] == ask.key.tolist()
-        )
-
-    def ends(self, lane: int, eos: int) -> bool:
-        """Whether what this step (finished) chose for `lane` ends its
-        generation."""
-        return self.toks[lane] == eos
-
-    def out(self, host: np.ndarray, ex: "BatchedExecutor") -> None:
-        """Read the step's packed array, on the host now (`_finish`)."""
-        self.replies, routed = samplib.row_replies(
-            host, self.top_n, self.asks,
-            ex.cfg.num_experts_per_tok if ex.engine.routes else 0,
-        )
-        self.toks = host[:, 0].tolist()
-        self.keys = host[:, 1:3].view(np.uint32).tolist()
-        if routed is not None and self.lanes:
-            ex._count_routing(routed[:, self.lanes], ex.engine.lanes)
 
 
 class _BlockStep(_Step):
@@ -196,20 +131,7 @@ class _BlockStep(_Step):
                 ex._count_routing(per_pass, ex.engine.lanes * blk)
 
 
-class _Ahead:
-    """A row run for a lane before its hop arrived (under the executor's
-    `_mu`): the position it was written at (the lane's length) and how many
-    positions it covers (1; a block), the step whose output row was its
-    input (`src`: the token and key its hop must carry), the step that ran
-    it (`step`: what answers the hop) and the ask it was run under."""
-
-    __slots__ = ("pos", "width", "src", "step", "ask")
-
-    def __init__(self, pos: int, width: int, src: _Step, ask):
-        self.pos, self.width, self.src, self.step, self.ask = pos, width, src, None, ask
-
-
-class BatchedExecutor(SpecServing, AdapterBindingMixin):
+class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
     """Whole-model, lane-per-session executor with windowed decode batching.
 
     Node executor contract (runtime/node.py): process(session_id, payload)
@@ -671,35 +593,6 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             self.ahead_dropped += 1
         self._carry.pop(lane, None)
 
-    def _claim(self, lane: int, pos: int, tok, ask) -> bool:
-        """Whether the call at `pos` of `lane` is the hop a row was run
-        ahead for: a one-token hop at the row's position, with the token
-        and key the step before chose and the ask the row was run under (a
-        block hop: nothing known of its block, the key the step before
-        left: `_Step.fed`).
-        Anything else drops the row and is served as if none had been run;
-        on a lane with a recurrent state, which the row has already moved,
-        it is refused as a replay of such a lane is, and the row stays for
-        the hop it was run for (a restart at 0 resets the state: dropped)."""
-        rec = self._ahead.get(lane)
-        if rec is None:
-            return False
-        src = rec.src
-        if (
-            ask is not None and src.done and src.error is None and rec.pos == pos
-            and src.fed(lane, tok, ask, rec.ask)
-        ):
-            return True
-        if self.cfg.has_state_layers and pos:
-            raise ValueError(
-                f"lane {lane}: a step already ran position {rec.pos} for the hop "
-                f"this session promised (`ahead`); {self.cfg.name} holds a recurrent "
-                "state, which does not roll back: send that hop, or restart the "
-                "session at 0"
-            )
-        self._forget(lane)
-        return False
-
     def _write_lens(self) -> np.ndarray:
         """The lanes' lengths as a program that writes EVERY lane's row
         takes them: a lane with an unclaimed row run ahead writes past it,
@@ -1032,23 +925,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         """Hand a hop (a decode hop's token, a block hop's tokens) and its
         ask to the window and return what it is answered with."""
         res = self._batcher.submit((lane, token, ks))
-        if not isinstance(res, _Step):
-            return res
-        # the hop rode a step that other sessions' drains do not wait for
-        # (`_decode_ahead`): this thread waits for it, and the window
-        # expects the lane again once a drain has answered it
-        self._batcher.unexpect(lambda p, _lane=lane: p[0] == _lane)
-        self._finish(res)
-        if res.error is not None:
-            raise res.error
-        tracelib.mark("t_out", res.t_out)  # `deliver` starts (runtime/window.py submit)
-        if lane in self._carry:
-            # the next drain runs this session's next row ahead if its hop
-            # is not back before it: give that drain a step's time to come
-            # (a session that returned at once would ride again, and again)
-            res.released.wait(min(0.1, res.t_done - res.t0))
-        self._batcher.delivered(lane)
-        return res.reply(lane)
+        # a step that other sessions' drains do not wait for (`_decode_ahead`):
+        # this thread waits for it
+        return self._ridden(res, lane) if isinstance(res, _Step) else res
 
     def _run_decode_batch(self, _empty) -> None:
         """Flush callback: ONE batched device step for every lane whose
@@ -1234,15 +1113,6 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             and not (src.done and (src.error is not None or src.ends(lane, ask.eos)))
         )
 
-    def _take_carry(self, conts, here=()) -> None:
-        """The promises of the riders since the last drain (under _mu): the
-        next row of every such lane that is not back (`here`: the lanes of
-        this drain's entries) goes into `conts` where `_runs_ahead` lets it."""
-        carried, self._carry = self._carry, {}
-        for lane, (src, ask) in carried.items():
-            if lane not in here and self._runs_ahead(lane, ask, src):
-                conts[lane] = (src, ask)
-
     def _settle(self) -> None:
         """Before a drain (under _dev_lock): while a prefill chunk is on the
         device the next step cannot start before the chunk ends, so nothing
@@ -1356,25 +1226,6 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         self._batcher.n_steps += programs - 1
         self._batcher.n_served += rows - len(claimed) - failed
 
-    def _wait_out(self, step: _Step) -> None:
-        """A drain waits for `step` (`_finish`) and leaves the window the
-        step's own time: what the drain waited for is only what was left of
-        it (runtime/window.py `step_hint`)."""
-        self._finish(step)
-        if step.error is None:
-            self._batcher.step_hint = step.t_done - step.t0
-
-    def _fed_by(self, conts):
-        """Where the rows of `conts` take what the step before left for them
-        (under _dev_lock, before a dispatch): the last step dispatched is
-        the only one whose output the device may still hold alone; an older
-        one is finished here."""
-        last = self._last_step
-        for src, _ in conts.values():
-            if not src.done and src is not last:
-                self._finish(src)
-        return last
-
     def _record_ahead(self, conts):
         """Record the rows of `conts` as run ahead (under _mu, before their
         dispatch; `_recorded` undoes it if that fails): each lies AT its
@@ -1390,15 +1241,6 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
         for lane in conts:
             lens[lane] -= width
         return made, lens
-
-    def _recorded(self, made: Dict[int, _Ahead], step: Optional[_Step]) -> None:
-        """The dispatch of the rows recorded in `made` returned `step`, or
-        failed (None): the records go (under _mu)."""
-        for lane, rec in made.items():
-            if step is not None:
-                rec.step = step
-            elif self._ahead.get(lane) is rec:
-                del self._ahead[lane]
 
     def _dispatch_rows(self, conts, riders) -> list:
         """ONE `_decode_logits` (under _dev_lock) over the rows of `conts`,
@@ -1571,45 +1413,6 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin):
             d["tokens"] += tokens
             d["rows"] += len(lanes) * blk * passes
         return step
-
-    def _finish(self, step: _Step) -> None:
-        """Wait for `step`, copy its one packed array out and keep what the
-        host reads of it on the step (`_Step.out`): once, by whoever needs
-        it first (the next drain's flusher under _dev_lock; a rider's own
-        thread under no lock). Its `device` span runs from its dispatch, or
-        from the end of the step before it where it queued behind that one,
-        to the moment this thread learnt it was done: where no one waited
-        for it while it ran (a turn longer than the step), that is the next
-        drain, and the span overstates the step by the difference."""
-        with step.lock:
-            if step.done:
-                return
-            prev, step.prev = step.prev, None
-            if prev is not None:
-                self._finish(prev)
-            try:
-                step.packed.block_until_ready()
-                step.t_done = tracelib.now()
-                step.t0 = max(step.t_call, prev.t_done if prev is not None else 0.0)
-                if self.tracer is not None and tracelib.enabled():
-                    self.tracer.record_span(
-                        "device", "device", step.t0, step.t_done, parent=tracelib.current(),
-                        attrs=step.span(),
-                    )
-                with tracelib.region(self.tracer, "copy_out") as at:
-                    host = np.asarray(step.packed)
-                    step.rows, moved = samplib.logits_out(step.logits, step.plain)
-                    at["bytes"] = host.nbytes + moved
-                step.t_out = tracelib.now()
-                step.out(host, self)
-            except Exception as exc:
-                step.error = exc
-                step.t_done = step.t_done or tracelib.now()
-            finally:
-                # `packed` stays: a drain that found the step unfinished may
-                # still be about to feed its next step from it
-                step.logits = None
-                step.done = True
 
     def _count_read(self, rows: int, longest: int, q_len: int = 1, passes: int = 1) -> None:
         """`kv.slots_read` / `kv.slots_held` of one dispatched program (under

@@ -24,7 +24,13 @@ a call is admitted under it too. Prefills run one at a time; decode steps
 go through the arrival window (runtime/window.py, formation), whose
 flusher takes every entry that is pending once it holds the lock: ONE
 pipeline pass advances every session that was waiting when the mesh freed,
-and chooses the token of every one whose hop asked for it.
+and chooses the token of every one whose hop asked for it. The drain keeps
+one pass AHEAD of the sessions (`_decode_ahead`, docs/SERVING.md "One step
+ahead"): a hop whose ask says more hops follow is answered from the row a
+pass ran for it before it arrived, and the drain that answers it first
+dispatches the pass of the hops after it, fed by the tokens and keys the
+last pass left on the devices, so the sessions take their turn beside a
+pass and not between two.
 """
 
 from __future__ import annotations
@@ -38,10 +44,12 @@ import numpy as np
 
 from inferd_tpu.config import ModelConfig
 from inferd_tpu.obs import trace as tracelib
+from inferd_tpu.obs.devtel import program_name
 from inferd_tpu.parallel import mesh as meshlib
 from inferd_tpu.parallel.infer import PipelinedEngine
 from inferd_tpu.runtime.executor import parse_decode_ask
 from inferd_tpu.runtime.spec_serving import SpecForkMiss, SpecServing
+from inferd_tpu.runtime.step_ahead import StepAhead, _Ahead, _Step
 
 log = logging.getLogger(__name__)
 
@@ -98,6 +106,10 @@ class SlotSessions:
     def free_slot(self, slot: int) -> None:
         self._free.append(slot)
 
+    def owner(self, slot: int) -> Optional[str]:
+        """The session that holds `slot`, if one does."""
+        return next((s for s, held in self._slots.items() if held == slot), None)
+
     def sweep(self) -> int:
         # Non-blocking: sweep() runs on the node's event loop, and a device
         # step (held under the same lock) can take seconds — blocking here
@@ -128,7 +140,7 @@ class SlotSessions:
         return list(self._slots)
 
 
-class MeshExecutor(SpecServing):
+class MeshExecutor(SpecServing, StepAhead):
     """Whole-model stage executor pipelined over an in-mesh pp axis."""
 
     def __init__(
@@ -192,6 +204,21 @@ class MeshExecutor(SpecServing):
         # and those answered with their [V] logits row (/stats `executor`)
         self.sampled_rows = 0
         self.logit_rows = 0
+        # one pass ahead (`_decode_ahead`; runtime/step_ahead.py names the
+        # records): slot -> the row a pass ran before its hop arrived,
+        # unclaimed; slot -> (the pass its waiting hop rode, the hop's ask)
+        # where that ask promised a hop to follow; the last pass dispatched;
+        # rows run ahead, those that answered a hop, those dropped. Guarded
+        # by _lock.
+        self._ahead: Dict[int, _Ahead] = {}
+        self._carry: Dict[int, tuple] = {}
+        self._last_step: Optional[_Step] = None
+        # the repair of a dropped row (`_forget`) is a small program of its
+        # own: compiled here, not under the first session that drops one
+        self.engine.set_slot_length(0, 0)
+        self.ahead_rows = 0
+        self.ahead_claimed = 0
+        self.ahead_dropped = 0
         # decode coalescing: the pipeline pass natively interleaves all MB
         # slots and costs the same whatever rides it, so a pass takes every
         # session that is waiting when the mesh frees
@@ -324,18 +351,7 @@ class MeshExecutor(SpecServing):
                 if slot is None:  # evicted in the unlocked window
                     raise SpecForkMiss("forked slot evicted before open")
             else:
-                slot = self.sessions.assign(
-                    session_id, protected=set(self._inflight)
-                )
-                self._session_len = {
-                    s: l for s, l in self._session_len.items()
-                    if s in self.sessions
-                }
-                self._ring_hi = {
-                    s: h for s, h in self._ring_hi.items()
-                    if s in self.sessions
-                }
-                self._ring_hi.pop(session_id, None)
+                slot = self._assign(session_id)
             self._inflight[session_id] = 1
             try:
                 start = pin_len if forked else 0
@@ -438,6 +454,9 @@ class MeshExecutor(SpecServing):
         start_pos = int(payload.get("start_pos", 0))
         real_len = int(payload.get("real_len", toks.shape[1]))
         decode = real_len == 1 and start_pos > 0
+        # a hop that asks for its token (parse_decode_ask) is answered with
+        # it; any other with its logits row
+        ask = parse_decode_ask(payload) if decode else None
 
         with self._lock:
             if self._inflight.get(session_id):
@@ -455,25 +474,17 @@ class MeshExecutor(SpecServing):
                         f"session {session_id}: unknown session resumed at "
                         f"start_pos {start_pos} (cache evicted or node restarted)"
                     )
-                slot = self.sessions.assign(
-                    session_id, protected=set(self._inflight)
-                )
-                # assign() may have evicted a session; drop orphaned lengths
-                self._session_len = {
-                    s: l for s, l in self._session_len.items() if s in self.sessions
-                }
-                self._ring_hi = {
-                    s: h for s, h in self._ring_hi.items() if s in self.sessions
-                }
-                # a leftover mark under this id belongs to a previous
-                # session's rings and would wrongly reject legal replays
-                self._ring_hi.pop(session_id, None)
+                slot = self._assign(session_id)
             else:
                 have = self._session_len.get(session_id, 0)
+                # whatever this call is, the slot is back: the next drain
+                # runs nothing ahead on the strength of the hop before it
+                self._carry.pop(slot, None)
                 if start_pos == 0 and have:
                     # session restart under the same id: reset the slot
                     self._session_len[session_id] = 0
                     self._ring_hi.pop(session_id, None)
+                    self._forget(slot, freed=True)  # the prefill resets the length
                     have = 0
                     new = True  # step with reset
                 if start_pos + real_len > self.cap:
@@ -485,6 +496,10 @@ class MeshExecutor(SpecServing):
                         f"session {session_id}: KV overflow "
                         f"({start_pos}+{real_len} > {self.cap})"
                     )
+                if slot in self._ahead:
+                    # before any frontier moves: a call that is not the hop
+                    # the row was run for drops it (`_claim`, `_forget`)
+                    self._claim(slot, start_pos, int(toks[0, 0]), ask)
                 if start_pos != have:
                     if 0 < start_pos < have:
                         # deterministic chunk REPLAY (a client re-sent after
@@ -525,11 +540,11 @@ class MeshExecutor(SpecServing):
 
         try:
             if decode:
-                # a hop that asks for its token (parse_decode_ask) is
-                # answered with it; any other with its logits row
-                res = self._batcher.submit(
-                    (slot, int(toks[0, 0]), session_id, parse_decode_ask(payload))
-                )
+                res = self._batcher.submit((slot, int(toks[0, 0]), session_id, ask))
+                if isinstance(res, _Step):
+                    # a pass that other sessions' drains do not wait for
+                    # (`_decode_ahead`): this thread waits for it
+                    res = self._ridden(res, slot)
                 if isinstance(res, dict):
                     return {**res, "real_len": 1, "start_pos": start_pos}
                 logits = res[None, :]
@@ -588,6 +603,7 @@ class MeshExecutor(SpecServing):
             for sid, slot in pairs:
                 if slot is None:
                     continue
+                self._forget(slot)  # the slot reads as long as the host believes
                 k, v, ln, kl, vl = self.engine.export_slot(slot)
                 if ln <= 0:
                     continue
@@ -624,19 +640,9 @@ class MeshExecutor(SpecServing):
             if session_id in self.sessions:
                 return False
             try:
-                slot = self.sessions.assign(
-                    session_id, protected=set(self._inflight)
-                )
+                slot = self._assign(session_id)
             except BufferError:
                 return False
-            # assign() may have evicted a session; drop orphaned lengths
-            # (same bookkeeping as process() and fork_session())
-            self._session_len = {
-                s: l for s, l in self._session_len.items() if s in self.sessions
-            }
-            self._ring_hi = {
-                s: h for s, h in self._ring_hi.items() if s in self.sessions
-            }
             try:
                 self.engine.import_slot(
                     slot, k, v, n, k_loc=dec["k_loc"], v_loc=dec["v_loc"]
@@ -662,6 +668,11 @@ class MeshExecutor(SpecServing):
             "kv_layout": self.engine.caches.layout,
             "sampled_rows": self.sampled_rows,
             "logit_rows": self.logit_rows,
+            # rows run ahead of their hops (`_decode_ahead`), those a hop
+            # claimed, those dropped (the lane executor's names and meanings)
+            "ahead_rows": self.ahead_rows,
+            "ahead_claimed": self.ahead_claimed,
+            "ahead_dropped": self.ahead_dropped,
             **self._batcher.stats(),
             # pipeline passes of the raw serving steps and how many of
             # their stage-ticks did a live session's work (the rest are
@@ -675,39 +686,218 @@ class MeshExecutor(SpecServing):
         }
 
     def _run_decode_batch(self, _entries) -> None:
-        """Flush callback (runtime/window.py, formation): ONE pipeline pass
-        advances every slot whose entry is pending once the mesh is ours."""
+        """Flush callback (runtime/window.py, formation): the hops of every
+        slot whose entry is pending once the mesh is ours, one pass ahead of
+        their sessions (`_decode_ahead`)."""
         with self._lock:
+            self._settle()
             # the batcher stamps each entry's lock_wait and batch_wait
             entries = self._batcher.drain_pending()
-            if not entries:
-                return  # every waiting entry was invalidated: no pass
-            asks = {e.payload[0]: e.payload[3] for e in entries if e.payload[3] is not None}
-            try:
-                out = self.engine.step_slots(
-                    {e.payload[0]: e.payload[1] for e in entries}, asks
-                )
-            except Exception as exc:
-                for e in entries:
-                    e.error = exc
-                # the drain counted every live entry as served; net failed
-                # entries to zero so /stats batched_tokens stays token-true
-                self._batcher.n_served -= len(entries)
-                return
-            self._batcher.stamp_out(entries)  # copy_out is over: `deliver` starts
-            self.sampled_rows += len(asks)
-            self.logit_rows += len(entries) - len(asks)
-            for e in entries:
-                slot, _tok, sid, _ask = e.payload
-                if self._dying.get(slot) != sid:  # ended-mid-flush: the
-                    # _dying drain discards the mirror anyway; everyone else
-                    # advances in lockstep with the device-side length
-                    self._session_len[sid] = self._session_len.get(sid, 0) + 1
-                    if self.engine.ring_active:
-                        self._ring_hi[sid] = max(
-                            self._ring_hi.get(sid, 0), self._session_len[sid]
-                        )
-                e.result = out[slot]  # its reply where it asked, else its logits
+            if entries:  # else every waiting entry was invalidated: no pass
+                self._decode_ahead(entries)
+
+    # -- one pass ahead (docs/SERVING.md "One step ahead"; call under _lock) --
+
+    def _assign(self, session_id: str, keep=()) -> int:
+        """A slot for a new session, whoever had to give it up (`keep`:
+        sessions that must not, beside those with a request in flight)."""
+        slot = self.sessions.assign(session_id, protected=set(self._inflight) | set(keep))
+        # assign() may have evicted a session: drop orphaned lengths and
+        # ring marks, a leftover mark under this id (it belongs to a previous
+        # session's rings and would wrongly reject legal replays), and
+        # whatever a pass ran ahead for the slot's last holder
+        self._session_len = {
+            s: l for s, l in self._session_len.items() if s in self.sessions
+        }
+        self._ring_hi = {
+            s: h for s, h in self._ring_hi.items() if s in self.sessions and s != session_id
+        }
+        self._forget(slot, freed=True)
+        return slot
+
+    def _forget(self, slot: int, freed: bool = False) -> None:
+        """Drop what was run ahead for `slot` (its session ended, was
+        evicted, forked from, exported, or sent something else than the hop
+        the row was run for). The pass moved the slot's length ON THE
+        DEVICES past the row; the host's mirror never moved. Whoever reads
+        the slot next has to find the length the host believes: it is set
+        back to the row's position, behind whatever pass is still running
+        (`freed`: the slot's next holder sets its length itself: a prefill
+        that resets it, a fork, an import). The row stays where it was
+        written, beyond that length, and whoever writes there next
+        overwrites it."""
+        rec = self._ahead.pop(slot, None)
+        if rec is not None:
+            self.ahead_dropped += 1
+            if not freed:
+                self.engine.set_slot_length(slot, rec.pos)
+        self._carry.pop(slot, None)
+
+    def _runs_ahead(self, slot: int, ask, src: _Step) -> bool:
+        """Whether the hop after the one `ask` came with gets its row run
+        before it arrives (the mirror of the slot's length is that row's
+        position): the ask promised the hop, the slot still has its session,
+        the row and the one after it fit, what `src` chose for it, where the
+        host knows it already, does not end the generation, and no
+        speculative round reads the slots' lengths."""
+        sid = self.sessions.owner(slot)
+        return (
+            ask is not None and ask.ahead >= 1 and self._spec is None and sid is not None
+            and self._session_len.get(sid, 0) + 2 <= self.cap
+            and not (src.done and (src.error is not None or src.ends(slot, ask.eos)))
+        )
+
+    def _settle(self) -> None:
+        """Before a drain: a prefill holds _lock to the end of its pass,
+        which ran behind the pass dispatched before it, so a drain that
+        follows a prefill finds that pass ended (and a session whose prefill
+        is ending never finds two passes between it and its first decode
+        hop: while anyone else holds _lock at most one pass is unfinished).
+        Such a pass is read now, so the next one is fed from the host's copy
+        of what it chose and runs no row past an `eos` the host can see."""
+        prev = self._last_step
+        if prev is not None and not prev.done and prev.packed.is_ready():
+            self._finish(prev)
+
+    def _decode_ahead(self, entries) -> None:
+        """The hops of a drain, one pass ahead of the sessions; the lanes'
+        order (BatchedExecutor._decode_ahead):
+
+        1. Each entry is CLAIMED (`_claim`: a pass already ran the row this
+           hop asks for, before it arrived: its mirror moves past the row
+           now) or RIDES (no row was run: a session's first decode hop, a
+           hop whose ask promised nothing, a raw /forward). ONE pass is
+           dispatched for the rows to come: for every claimed slot whose
+           ask says a hop follows, and for every slot that rode the last
+           pass under such an ask and is not back yet, the NEXT row, fed by
+           what the last pass's packed array holds for it where the devices
+           still hold it alone (no round trip); for every rider its own row
+           from what its hop carries.
+        2. The pass dispatched at the previous drain is waited for and
+           copied out (`_finish`), and each claimed entry is answered from
+           the pass that ran its row. The sessions take their turn while
+           the pass of (1) runs.
+        3. A rider is answered by its own thread (`_ridden` waits for the
+           pass it rode, under no lock). Where nobody is ahead (no claimed
+           entry, no row run ahead in this pass: a lone session, a cohort's
+           first hops, callers that promise nothing) the drain is what it
+           was: the flusher waits for the pass and answers; the riders'
+           next rows, where promised, are dispatched right behind it.
+
+        `batched_steps` counts the passes dispatched and `batched_tokens`
+        the rows they ran for a session (the drain counted one pass and its
+        entries); `pipeline.*` count a pass at its dispatch."""
+        prev = self._last_step
+        claimed, riders, answers, conts = [], [], {}, {}
+        for e in entries:
+            slot, tok, sid, ask = e.payload
+            mine = self._claim(slot, self._session_len.get(sid, 0), tok, ask)
+            (claimed if mine else riders).append(e)
+        for e in claimed:  # the hop takes its row: the mirror moves past it
+            slot, _, sid, ask = e.payload
+            answers[slot] = src = self._ahead.pop(slot).step
+            self._session_len[sid] += 1
+            if self._runs_ahead(slot, ask, src):
+                conts[slot] = (src, ask)
+        if self._carry:
+            self._take_carry(conts, {e.payload[0] for e in entries})
+        self.ahead_claimed += len(claimed)
+        self.sampled_rows += len(claimed)
+        # a dispatch that failed (None) ran no row and has failed its riders
+        step = self._dispatch_rows(conts, riders) if conts or riders else None
+        programs, rows = (1, len(conts)) if step is not None else (0, 0)
+        if prev is not None:
+            prev.released.set()
+        # nobody is ahead: the flusher answers its riders itself, and runs
+        # their next rows right behind the pass they ride
+        sync = bool(riders) and not claimed and not conts
+        if sync and step is not None:
+            nxt = {}
+            self._take_carry(nxt)
+            if nxt and self._dispatch_rows(nxt, []) is not None:
+                programs, rows = programs + 1, rows + len(nxt)
+        if prev is not None:
+            self._wait_out(prev)
+        if sync and step is not None:
+            self._wait_out(step)
+        self._batcher.stamp_out(claimed + riders if sync else claimed)  # `deliver` starts
+        for e in claimed:
+            slot = e.payload[0]
+            if answers[slot].error is not None:
+                e.error = answers[slot].error
+            else:
+                e.result = answers[slot].replies[slot]
+        for e in riders if step is not None else ():
+            if not sync:
+                e.result = step  # `process` waits for it (`_ridden`)
+            elif step.error is not None:
+                e.error = step.error
+            else:
+                e.result = step.reply(e.payload[0])
+        self._batcher.n_steps += programs - 1
+        self._batcher.n_served += rows - len(claimed) - (len(riders) if step is None else 0)
+
+    def _dispatch_rows(self, conts, riders) -> Optional[_Step]:
+        """ONE pass (PipelinedEngine.dispatch_slots) over the rows of
+        `conts`, {slot: (the pass whose output is the row's input, the ask
+        it runs under)}: rows run AHEAD of their hops, recorded in `_ahead`
+        (each AT the mirror of its slot's length; it counts as written: a
+        ring has given up its oldest slot); and of `riders`: entries waiting
+        for their row, run from their host token, their mirrors moved at
+        once. The lengths themselves move inside the pass. A dispatch that
+        raises fails its riders, leaves no row recorded and returns None."""
+        eng = self.engine
+        last = self._fed_by(conts)
+        toks, asks, plain, carry, ahead, made = {}, {}, [], {}, [], {}
+        for slot, (src, ask) in conts.items():
+            if src.done:  # the host has read it (a rider's thread may finish `last` any time)
+                toks[slot] = src.toks[slot]
+                asks[slot] = ask._replace(key=np.asarray(src.keys[slot], np.uint32))
+            else:
+                ahead.append(slot)
+                asks[slot] = ask
+            sid = self.sessions.owner(slot)
+            made[slot] = self._ahead[slot] = _Ahead(self._session_len[sid], 1, src, ask)
+            if eng.ring_active:
+                self._ring_hi[sid] = max(self._ring_hi.get(sid, 0), made[slot].pos + 1)
+        live = []  # riders whose session has not ended meanwhile (`_dying`)
+        for e in riders:
+            slot, tok, sid, ask = e.payload
+            toks[slot] = tok
+            alive = self._dying.get(slot) != sid
+            if alive:
+                live.append(sid)
+            if ask is None:
+                plain.append(slot)
+            else:
+                asks[slot] = ask
+                if ask.ahead >= 1 and alive:
+                    carry[slot] = ask
+        try:
+            logits, packed, top_n = eng.dispatch_slots(
+                toks, asks, ahead, last.packed if ahead else None
+            )
+        except Exception as exc:
+            self._recorded(made, None)
+            for e in riders:
+                e.error = exc
+            return None
+        step = self._last_step = _Step(
+            packed, logits if plain else None, top_n, asks, plain,
+            list(conts) + [e.payload[0] for e in riders], last,
+            program_name(eng._step_raw_multi),
+        )
+        self._recorded(made, step)
+        for sid in live:  # in lockstep with the device-side length
+            self._session_len[sid] = self._session_len.get(sid, 0) + 1
+            if eng.ring_active:
+                self._ring_hi[sid] = max(self._ring_hi.get(sid, 0), self._session_len[sid])
+        for slot, ask in carry.items():
+            self._carry[slot] = (step, ask)
+        self.ahead_rows += len(conts)
+        self.sampled_rows += len(riders) - len(plain)
+        self.logit_rows += len(plain)
+        return step
 
     def fork_session(
         self, new_session_id: str, parent_session_id: str, prefix_len: int
@@ -741,22 +931,10 @@ class MeshExecutor(SpecServing):
                 if phi - prefix_len > RING_MARGIN:
                     return False
             try:
-                slot = self.sessions.assign(
-                    new_session_id,
-                    protected=set(self._inflight) | {parent_session_id},
-                )
+                slot = self._assign(new_session_id, keep=(parent_session_id,))
             except BufferError:
                 return False
-            # assign() may have evicted a session; drop orphaned lengths
-            # AND ring marks (fork is the spec path's common admission —
-            # without the _ring_hi prune a pinned-heavy ring workload
-            # accumulates dead sessions' marks)
-            self._session_len = {
-                s: l for s, l in self._session_len.items() if s in self.sessions
-            }
-            self._ring_hi = {
-                s: h for s, h in self._ring_hi.items() if s in self.sessions
-            }
+            self._forget(pslot)  # a row run ahead for the parent: the child copies none of it
             self.engine.fork_slot(pslot, slot, prefix_len)
             self._session_len[new_session_id] = prefix_len
             if self.engine.ring_active:
@@ -778,6 +956,7 @@ class MeshExecutor(SpecServing):
                 lambda payload, _s=slot: payload[0] == _s,
                 ValueError(f"session {session_id} ended mid-request"),
             )
+            self._forget(slot, freed=True)
             if self._inflight.get(session_id):
                 self._dying[slot] = session_id
             else:

@@ -344,21 +344,20 @@ def test_served_generations_compile_nothing_after_the_warm_up(served, topology):
     # without and with top-8; on the lanes each counted once more where its
     # tokens and keys come from the device (a step run ahead of its hop): the
     # watch counts a new entry of the jit's call cache, the program is the
-    # same executable and nothing is compiled for it
+    # same executable and nothing is compiled for it. On the mesh such an
+    # entry WOULD be a compile (inputs that lie on the mesh are another
+    # program's), so every pass is fed one way (PipelinedEngine.dispatch_slots)
     assert run["compiles"] == run["warm_compiles"] == (2 if topology == "mesh" else 4)
     if topology == "latent":  # the experts rode the step's one array
         assert run["stats3"]["moe"]["steps"] > run["stats0"]["moe"]["steps"]
 
 
 @pytest.mark.parametrize("topology", list(TOPOLOGIES))
-def test_only_the_lanes_run_a_step_ahead_and_every_row_is_claimed(served, topology):
-    """/generate promises its hops (`ahead`): the lane executor ran every
-    hop's row but the first before the hop arrived, and none in vain (no
-    `eos` is sent); the mesh executor reads no such key and has no counter."""
+def test_every_executor_runs_a_step_ahead_and_every_row_is_claimed(served, topology):
+    """/generate promises its hops (`ahead`): the lane executor and the mesh
+    executor ran every hop's row but the first before the hop arrived, and
+    none in vain (no `eos` is sent)."""
     run = served(topology)
-    if topology == "mesh":
-        assert not any(k.startswith("ahead") for k in run["stats3"])
-        return
     d = {k: run["stats1"][k] - run["stats0"][k]
          for k in ("ahead_rows", "ahead_claimed", "ahead_dropped")}
     assert d == {"ahead_rows": NEW - 2, "ahead_claimed": NEW - 2, "ahead_dropped": 0}

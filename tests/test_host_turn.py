@@ -160,27 +160,17 @@ def test_every_hop_leaves_its_parts_and_every_step_its_turn(served, topology):
     mine = [s for s in steps if s["t0"] >= turns[0]["t1"]]
     computes = {h["compute"]["span"]: h for h in hops}
     beside = sum(d["t0"] < t["t1"] and t["t0"] < d["t1"] for t in turns for d in steps)
-    ahead = topology != "mesh"
-    if ahead:
-        # the lane executor keeps one step ahead, block steps too: a drain
-        # dispatches the NEXT step (two where nobody was ahead yet: the riders'
-        # own and the one behind it), so a step runs BESIDE the turn that
-        # follows its drain
-        assert len(mine) >= len(turns) >= NEW // per_hop - 1 and beside >= NEW // per_hop // 2
-    else:
-        assert len(turns) == len(mine) >= NEW // per_hop - 1 and beside == 0
-    for t, step in zip(turns, mine):
+    # both executors keep one step ahead, block steps and the mesh's passes
+    # too: a drain dispatches the NEXT step (two where nobody was ahead yet:
+    # the riders' own and the one behind it), so a step runs BESIDE the turn
+    # that follows its drain
+    assert len(mine) >= len(turns) >= NEW // per_hop - 1 and beside >= NEW // per_hop // 2
+    for t in turns:
         a = t["attrs"]
-        if ahead:
-            # the step dispatched at the drain that ended the turn (none
-            # where every entry was a generation's last hop)
-            step = min((s for s in steps if s["t0"] >= t["t1"]), key=lambda s: s["t0"],
-                       default=steps[-1])
-        else:
-            before = max((s for s in steps if s["t1"] <= t["t0"]), key=lambda s: s["t1"])
-            assert before["t1"] <= t["t0"] <= t["t1"] <= step["t0"]
-            assert steps.index(step) == steps.index(before) + 1
-            assert a["cobatch"] == step["attrs"]["cobatch"]
+        # the step dispatched at the drain that ended the turn (none where
+        # every entry was a generation's last hop)
+        step = min((s for s in steps if s["t0"] >= t["t1"]), key=lambda s: s["t0"],
+                   default=steps[-1])
         assert t["parent"] is None and t["phase"] == "turn"
         assert a["kind"] == step["attrs"]["kind"]
         assert a["how"] in ("full", "timeout", "solo") and 0 <= a["expected"] <= 2

@@ -287,7 +287,7 @@ def _together(ex, fns):
 
 def test_mesh_decode_steps_coalesce(mesh_parts):
     """Co-arriving sessions' decode steps must share ONE pipeline pass
-    (engine.step_slots): all three are pending when the flusher gets the
+    (engine.dispatch_slots): all three are pending when the flusher gets the
     mesh, and results must match solo slot steps."""
     from inferd_tpu.runtime.mesh_executor import MeshExecutor
 
@@ -339,14 +339,14 @@ def test_mesh_pass_takes_every_live_slot(mesh8, monkeypatch):
     tiny model's pass of rows on a loaded CPU does not, so the test holds
     the pass at 50 ms: the regime the wait is made for."""
     ex, steps = mesh8, 4
-    step_slots = ex.engine.step_slots
+    finish = ex._finish
 
-    def chip_long_pass(*args):
-        out = step_slots(*args)
-        time.sleep(0.05)
-        return out
+    def chip_long_pass(step):
+        if not step.done:
+            time.sleep(0.05)
+        finish(step)
 
-    monkeypatch.setattr(ex.engine, "step_slots", chip_long_pass)
+    monkeypatch.setattr(ex, "_finish", chip_long_pass)
     prompts = {f"e{i}": [3 + i, 7, 11 + i] for i in range(8)}
     solo = {}
     for s, ids in prompts.items():

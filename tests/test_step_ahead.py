@@ -31,7 +31,11 @@ SAMPLED = {"temperature": 0.9, "top_k": 12}
 # a dense lane's layouts: keys and values per head (heads as wide as a tile),
 # one row a token, latents, rings beside a slab, a recurrent state
 LAYOUTS = {"heads": "tiny-wide", "rows": "tiny", "latent": "tiny-dsv2",
-           "ring": "tiny-afmoe", "state": "tiny-granite-h"}
+           "ring": "tiny-afmoe", "state": "tiny-granite-h",
+           # the mesh executor (runtime/mesh_executor.py) over pp=2: slots for
+           # lanes, the lengths on the devices; uniform slabs, and rings
+           "mesh": "mesh:tiny", "mesh_ring": "mesh:tiny-gemma2"}
+KINDS = {"lanes": "tiny", "mesh": "mesh:tiny"}  # the two executors, one model
 
 
 def _config(model):
@@ -44,13 +48,48 @@ _made = {}
 
 
 def _executor(model, **kw):
-    """A fresh executor over one set of weights a model (made once)."""
+    """A fresh executor over one set of weights a model (made once):
+    `mesh:<model>` a MeshExecutor of as many slots as lanes were asked."""
+    mesh, model = model.startswith("mesh:"), model.removeprefix("mesh:")
     cfg = _config(model)
     if model not in _made:
         _made[model] = qwen3.init_params(cfg, jax.random.PRNGKey(0))
     kw.setdefault("lanes", 3)
     kw.setdefault("max_len", 64)
-    return BatchedExecutor(cfg, _made[model], **kw)
+    if not mesh:
+        return BatchedExecutor(cfg, _made[model], **kw)
+    from inferd_tpu.parallel.mesh import MeshPlan
+    from inferd_tpu.runtime.mesh_executor import MeshExecutor
+
+    ex = MeshExecutor(cfg, _made[model], MeshPlan(pp=2), num_slots=kw["lanes"],
+                      max_len=kw["max_len"], devices=jax.devices()[:2])
+    # the engine's programs are jitted closures of their own: a fresh engine
+    # of the same shapes takes the first one's, compiled once a file
+    first = _made.setdefault(("programs", model, kw["lanes"], kw["max_len"]), ex.engine)
+    for name in ("_step_raw", "_step_raw_multi", "_fork_slot", "_feed"):
+        setattr(ex.engine, name, getattr(first, name))
+    return ex
+
+
+def _lane(ex, sid):
+    """The lane, or the mesh's slot, that holds session `sid`."""
+    return ex._sessions[sid] if hasattr(ex, "_sessions") else ex.sessions._slots[sid]
+
+
+def _length(ex, sid):
+    """The length of `sid`'s lane as the host holds it. On the mesh the
+    lengths live on the devices and a row run ahead has moved them: they
+    must read the host's mirror, plus that row where one is unclaimed."""
+    lane = _lane(ex, sid)
+    if hasattr(ex, "_sessions"):
+        return ex.engine.lengths[lane]
+    assert ex.engine.slot_length(lane) == ex._session_len[sid] + (lane in ex._ahead)
+    return ex._session_len[sid]
+
+
+def _hi(ex, sid):
+    """How far `sid`'s rings count as written."""
+    return ex._lane_hi[_lane(ex, sid)] if hasattr(ex, "_sessions") else ex._ring_hi.get(sid)
 
 
 class Session:
@@ -141,7 +180,7 @@ def test_the_stream_is_the_one_the_hops_give_without_the_promise(layout, how):
     assert s["batched_tokens"] == 2 * (NEW - 1) == s["batched_steps"]
 
 
-@pytest.mark.parametrize("layout", ["rows", "ring", "state"])
+@pytest.mark.parametrize("layout", ["rows", "ring", "state", "mesh"])
 def test_sessions_side_by_side_each_read_their_own_stream(layout):
     ex = _executor(LAYOUTS[layout])
     alone = [Session(ex, f"alone{i}", prompt=p, ahead=False, new=NEW + i).run().end().out
@@ -168,20 +207,22 @@ def test_sessions_side_by_side_each_read_their_own_stream(layout):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("kind", list(KINDS))
 @pytest.mark.parametrize("new", [2, 3, 6])
-def test_no_row_is_run_past_the_budget(new):
-    ex = _executor("tiny")
+def test_no_row_is_run_past_the_budget(new, kind):
+    ex = _executor(KINDS[kind])
     s = Session(ex, "s", new=new).run()
     st = ex.stats()
     assert len(s.out) == new and _at_rest(ex) == 0 and st["ahead_dropped"] == 0
     assert st["ahead_rows"] == new - 2  # the first hop rides, the last is promised by the one before
-    assert not ex._ahead and ex.engine.lengths[ex._sessions["s"]] == len(PROMPT) + new - 1
+    assert not ex._ahead and _length(ex, "s") == len(PROMPT) + new - 1
     s.end()
     assert ex.stats()["ahead_dropped"] == 0
 
 
-def test_an_eos_costs_at_most_one_row_a_request():
-    ex = _executor("tiny")
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_an_eos_costs_at_most_one_row_a_request(kind):
+    ex = _executor(KINDS[kind])
     full = Session(ex, "full", sampling=SAMPLED, seed=3, new=12, ahead=False).run().end().out
     eos = full[5]
     cut = full[:full.index(eos) + 1]
@@ -196,11 +237,12 @@ def test_an_eos_costs_at_most_one_row_a_request():
     assert ex.stats()["ahead_dropped"] <= 3
 
 
-def test_the_last_position_of_a_lane_is_not_run_ahead():
-    ex = _executor("tiny", max_len=12)
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_the_last_position_of_a_lane_is_not_run_ahead(kind):
+    ex = _executor(KINDS[kind], max_len=12)
     s = Session(ex, "s", prompt=PROMPT, new=8).run()  # positions 5..11: the lane ends at 12
     assert len(s.out) == 8 and ex.stats()["ahead_dropped"] == 0 and _at_rest(ex) == 0
-    plain = Session(_executor("tiny", max_len=12), "p", new=8, ahead=False).run()
+    plain = Session(_executor(KINDS[kind], max_len=12), "p", new=8, ahead=False).run()
     assert s.out == plain.out
 
 
@@ -210,7 +252,7 @@ def test_the_last_position_of_a_lane_is_not_run_ahead():
 
 
 def _ahead_of(ex, sid):
-    return ex._ahead.get(ex._sessions[sid])
+    return ex._ahead.get(_lane(ex, sid))
 
 
 def _fresh_stream(model, prompt, **kw):
@@ -254,23 +296,38 @@ DROPS = {"end_session": _end, "another_token": _other_token, "replay": _replay,
          "prefill_chunk": _prefill_chunk, "fork": _fork, "export": _export}
 
 
-@pytest.mark.parametrize("layout", ["rows", "ring"])
+@pytest.mark.parametrize("layout", ["rows", "ring", "mesh", "mesh_ring"])
 @pytest.mark.parametrize("what", list(DROPS))
-def test_what_is_not_the_promised_hop_drops_the_row_and_nobody_reads_it(what, layout):
+def test_what_is_not_the_promised_hop_drops_the_row_and_nobody_reads_it(what, layout, monkeypatch):
     model = LAYOUTS[layout]
-    ex = _executor(model, lanes=2 if what == "fork" else 1)  # a fork's child takes a lane
+    mesh = layout.startswith("mesh")
+    # a fork's child takes a lane (the mesh: one shape of the pass for all six)
+    ex = _executor(model, lanes=2 if what == "fork" or mesh else 1)
     s = Session(ex, "s").run(hops=3)
-    lane = ex._sessions["s"]
+    lane = _lane(ex, "s")
     rec = _ahead_of(ex, "s")
-    assert rec is not None and rec.pos == s.pos == ex.engine.lengths[lane]
-    # the row counts as written: a ring has given up its oldest slot to it
-    assert ex._lane_hi[lane] == s.pos + 1
+    assert rec is not None and rec.pos == s.pos == _length(ex, "s")
+    if layout != "mesh":  # (uniform slabs on the mesh keep no mark)
+        # the row counts as written: a ring has given up its oldest slot to it
+        assert _hi(ex, "s") == s.pos + 1
+    end, read = ex.end_session, []
+    if mesh:
+        # whoever uses the slot next finds the length the host believes: the
+        # session itself where the drop left it its slot, until it ends
+
+        def ends(sid):
+            assert what == "end_session" or not ex._ahead  # the call before dropped it
+            read.append(_length(ex, sid))
+            end(sid)
+
+        monkeypatch.setattr(ex, "end_session", ends)
     DROPS[what](ex, s)
     st = ex.stats()
     assert st["ahead_dropped"] == 1 and _at_rest(ex) == 0 and not ex._ahead
+    assert read or not mesh
     # the lane's next session reads what a fresh executor's would
     nxt = Session(ex, "next", prompt=OTHER)
-    assert ex._sessions["next"] == lane
+    assert _lane(ex, "next") == lane
     assert nxt.run().end().out == _fresh_stream(model, OTHER)
 
 
@@ -304,12 +361,13 @@ def test_a_recurrent_state_lane_starts_its_next_session_clean():
     assert s.pos > 0 and _at_rest(ex) == 0
 
 
-def test_an_evicted_sessions_row_goes_with_it():
-    ex = _executor("tiny", lanes=1)
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_an_evicted_sessions_row_goes_with_it(kind):
+    ex = _executor(KINDS[kind], lanes=1)
     Session(ex, "old").run(hops=2)
     assert ex._ahead
     new = Session(ex, "new", prompt=OTHER).run().end()  # the one lane is taken from `old`
-    assert new.out == _fresh_stream("tiny", OTHER)
+    assert new.out == _fresh_stream(KINDS[kind], OTHER)
     assert ex.stats()["ahead_dropped"] == 1 and _at_rest(ex) == 0
 
 
@@ -318,8 +376,9 @@ def test_an_evicted_sessions_row_goes_with_it():
 # ---------------------------------------------------------------------------
 
 
-def test_a_lone_sessions_first_hop_is_answered_with_no_other_submit():
-    ex = _executor("tiny")
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_a_lone_sessions_first_hop_is_answered_with_no_other_submit(kind):
+    ex = _executor(KINDS[kind])
     s = Session(ex, "s")
     s.hop()  # returns: the flusher waited for the step itself
     st = ex.stats()
@@ -327,13 +386,14 @@ def test_a_lone_sessions_first_hop_is_answered_with_no_other_submit():
     assert st["batched_steps"] == 2 and st["ahead_rows"] == 1 and st["ahead_claimed"] == 0
     s.hop()
     assert ex.stats()["ahead_claimed"] == 1 and ex.stats()["batched_steps"] == 3
-    assert s.run().out == _fresh_stream("tiny", PROMPT)
+    assert s.run().out == _fresh_stream(KINDS[kind], PROMPT)
 
 
-def test_a_newcomer_rides_beside_the_running_and_their_rows_go_on():
-    ex = _executor("tiny")
-    alone = [_fresh_stream("tiny", p, new=24) for p in (PROMPT, OTHER)]
-    late_alone = _fresh_stream("tiny", PROMPT[:3])
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_a_newcomer_rides_beside_the_running_and_their_rows_go_on(kind):
+    ex = _executor(KINDS[kind])
+    alone = [_fresh_stream(KINDS[kind], p, new=24) for p in (PROMPT, OTHER)]
+    late_alone = _fresh_stream(KINDS[kind], PROMPT[:3])
     a, b = Session(ex, "a", new=24), Session(ex, "b", prompt=OTHER, new=24)
     a.run(hops=2), b.run(hops=2)
     running = [threading.Thread(target=x.run) for x in (a, b)]
@@ -349,9 +409,10 @@ def test_a_newcomer_rides_beside_the_running_and_their_rows_go_on():
     assert _at_rest(ex) == 0
 
 
-def test_a_session_that_comes_back_late_is_left_out_and_rejoins():
-    ex = _executor("tiny")
-    want_a, want_b = _fresh_stream("tiny", PROMPT, new=12), _fresh_stream("tiny", OTHER, new=12)
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_a_session_that_comes_back_late_is_left_out_and_rejoins(kind):
+    ex = _executor(KINDS[kind])
+    want_a, want_b = (_fresh_stream(KINDS[kind], p, new=12) for p in (PROMPT, OTHER))
     a, b = Session(ex, "a", new=12), Session(ex, "b", prompt=OTHER, new=12)
     a.run(hops=3), b.run(hops=3)
     rows = ex.stats()["ahead_rows"]
@@ -363,28 +424,31 @@ def test_a_session_that_comes_back_late_is_left_out_and_rejoins():
     assert ex.stats()["ahead_dropped"] == 0 and _at_rest(ex) == 0
 
 
+@pytest.mark.parametrize("kind", list(KINDS))
 @pytest.mark.parametrize("when", ["before_the_drain", "inside_the_drain"])
-def test_a_step_somebody_else_finished_still_feeds_the_next(when, monkeypatch):
+def test_a_step_somebody_else_finished_still_feeds_the_next(when, kind, monkeypatch):
     """A rider's own thread may finish the last step at any moment: before
     the drain looks (the host's copy feeds the next step), or between the
     drain's look and its dispatch (the device's copy must still be there)."""
-    ex = _executor("tiny")
+    ex = _executor(KINDS[kind])
     s = Session(ex, "s").run(hops=2)
     if when == "inside_the_drain":
         from inferd_tpu.core import sampling as samplib
 
-        real = samplib.ahead_rows
+        # where each executor takes what the step before left on the device
+        holder, name = (samplib, "ahead_rows") if kind == "lanes" else (ex.engine, "_feed")
+        real = getattr(holder, name)
 
         def finished_meanwhile(packed, *rest):
             ex._finish(ex._last_step)
             return real(packed, *rest)
 
-        monkeypatch.setattr(samplib, "ahead_rows", finished_meanwhile)
+        monkeypatch.setattr(holder, name, finished_meanwhile)
     while not s.over:
         if when == "before_the_drain":
             ex._finish(ex._last_step)
         s.hop()
-    assert s.out == _fresh_stream("tiny", PROMPT)
+    assert s.out == _fresh_stream(KINDS[kind], PROMPT)
     assert ex.stats()["ahead_dropped"] == 0 and _at_rest(ex) == 0
 
 
@@ -419,9 +483,10 @@ def test_behind_a_prefill_chunk_the_drain_waits_out_the_last_step_first():
     assert st["ahead_claimed"] == st["ahead_rows"] == NEW - 2 and st["ahead_dropped"] == 0
 
 
-def test_a_hop_with_logits_rides_beside_rows_run_ahead():
-    ex = _executor("tiny")
-    want = _fresh_stream("tiny", PROMPT, new=16)
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_a_hop_with_logits_rides_beside_rows_run_ahead(kind):
+    ex = _executor(KINDS[kind])
+    want = _fresh_stream(KINDS[kind], PROMPT, new=16)
     a = Session(ex, "a", new=16).run(hops=2)
     t = threading.Thread(target=a.run)
     t.start()
@@ -432,7 +497,7 @@ def test_a_hop_with_logits_rides_beside_rows_run_ahead():
         res = ex.process("raw", {"tokens": [[tok]], "start_pos": len(OTHER) + i, "real_len": 1})
         tok = int(np.argmax(res["logits"][0]))
     t.join(120)
-    assert a.out == want and got == _fresh_stream("tiny", OTHER, new=4)
+    assert a.out == want and got == _fresh_stream(KINDS[kind], OTHER, new=4)
     assert ex.stats()["logit_rows"] == 4
 
 
@@ -465,8 +530,16 @@ def _speculating(ex):
     Session(ex, "s").run().end()  # a promise, but speculative rounds read the lanes' lengths
 
 
+def _mesh_speculating(ex):
+    ex.enable_spec(2, 2, _made["tiny"])
+    assert ex.spec_enabled()
+    Session(ex, "s").run().end()  # as on the lanes: look-ahead is off for the whole executor
+
+
 OTHER_PATHS = {
     "no_budget_key": ("tiny", {}, _no_budget),
+    "mesh_no_budget_key": ("mesh:tiny", {}, _no_budget),
+    "mesh_speculating": ("mesh:tiny", {}, _mesh_speculating),
     "k_step_call": ("tiny", {}, _k_steps),
     "block_call": ("tiny-sdar", {}, _block),
     "paged_lane": ("tiny", {"block_size": 16, "kv_blocks": 24}, lambda ex: Session(ex, "s").run().end()),
@@ -853,5 +926,132 @@ def test_the_benchmark_reads_the_share_of_block_hops_that_found_their_block_run(
     got = read({"stats0": before, "stats1": after})
     if program == "this_tree":
         assert got == pytest.approx(100.0 * (a.hops - 1) / (a.hops + b.hops)) and 0 < got < 100
+    else:
+        assert got is None
+
+
+# ---------------------------------------------------------------------------
+# (g) the mesh executor alone: its lengths live on the devices, one lock is
+# its devices' and its session table's, one placement feeds its pass
+# ---------------------------------------------------------------------------
+
+
+def _unfinished(ex):
+    """The passes dispatched and not yet waited for, newest first."""
+    step, found = ex._last_step, []
+    while step is not None and not step.done:
+        found.append(step)
+        step = step.prev
+    return found
+
+
+def test_a_prefill_that_cuts_in_on_the_mesh_finds_at_most_one_pass_ahead_of_it():
+    """A drain waits the pass before its own out under `_lock`, so whoever
+    else holds the lock (a prefill: to the end of its pass) has one pass
+    between it and the devices at most; and the drain behind a prefill finds
+    that pass ended and feeds the next from the host's copy (`_settle`)."""
+    ex = _executor("mesh:tiny")
+    want_a, want_b = _fresh_stream("mesh:tiny", PROMPT, new=24), _fresh_stream("mesh:tiny", OTHER)
+    a = Session(ex, "a", new=24).run(hops=3)
+    queued, fed_by_host = [], []
+    prefill, feed = ex.engine.step_slot, ex.engine._feed
+
+    def cuts_in(*args, **kw):
+        queued.append(len(_unfinished(ex)))
+        out = prefill(*args, **kw)
+        fed_by_host.append(len(fed_by_host))  # the drain that follows is the one to look at
+        return out
+
+    def fed(packed, toks, keys, from_dev):
+        if fed_by_host and fed_by_host[-1] is not None:
+            fed_by_host[-1] = None
+            assert not from_dev.any() and not _unfinished(ex)
+        return feed(packed, toks, keys, from_dev)
+
+    ex.engine.step_slot, ex.engine._feed = cuts_in, fed
+    t = threading.Thread(target=a.run)
+    t.start()
+    b = Session(ex, "b", prompt=OTHER).run()  # its prefill cuts in between two of a's hops
+    t.join(120)
+    assert queued and max(queued) <= 1
+    assert a.out == want_a and b.out == want_b
+    assert ex.stats()["ahead_dropped"] == 0 and _at_rest(ex) == 0
+
+
+@pytest.mark.parametrize("who", ["the_row_run_ahead", "a_rider"])
+def test_a_mesh_dispatch_that_raises_fails_its_riders_and_records_no_row(who):
+    ex = _executor("mesh:tiny")
+    real = ex.engine.dispatch_slots
+
+    def boom(*a, **kw):
+        ex.engine.dispatch_slots = real  # once
+        raise RuntimeError("injected dispatch failure")
+
+    s = Session(ex, "s").run(hops=2)
+    st = ex.stats()
+    if who == "a_rider":
+        r = Session(ex, "r", prompt=OTHER, new=9)
+        ex.engine.dispatch_slots = boom
+        with pytest.raises(RuntimeError, match="injected dispatch failure"):
+            r.hop()
+        now = ex.stats()
+        # nothing moved on its slot, and nothing is left to answer from
+        slot = _lane(ex, "r")
+        assert _length(ex, "r") == r.pos and slot not in ex._ahead and slot not in ex._carry
+        assert r.run().end().out == _fresh_stream("mesh:tiny", OTHER)
+    else:
+        ex.engine.dispatch_slots = boom
+        s.hop()  # answered from the pass that ran its row; the next row's dispatch failed
+        now = ex.stats()
+        assert not ex._ahead and now["ahead_rows"] == st["ahead_rows"]
+        assert _length(ex, "s") == s.pos
+    # the counters stayed token-true: a pass that was not dispatched ran no row
+    assert now["batched_steps"] == st["batched_steps"]
+    assert now["batched_tokens"] == st["batched_tokens"]
+    assert now["pipeline"]["passes"] - st["pipeline"]["passes"] == (who == "a_rider")  # r's prefill
+    assert s.run().end().out == _fresh_stream("mesh:tiny", PROMPT)  # its next hop rode
+    assert _at_rest(ex) == 0 and ex.stats()["ahead_dropped"] == 0
+
+
+def test_the_mesh_compiles_one_pass_whoever_feeds_it():
+    """A jitted program is compiled anew for inputs that lie elsewhere: the
+    tokens and keys of EVERY pass come out of `_feed`, from the host or from
+    the pass before, so the pass compiles once a top-n width. The counters of
+    the passes count them when they are dispatched."""
+    from inferd_tpu.parallel.mesh import MeshPlan
+    from inferd_tpu.runtime.mesh_executor import MeshExecutor
+
+    ex = MeshExecutor(_config("tiny"), _made.setdefault("tiny", qwen3.init_params(
+        _config("tiny"), jax.random.PRNGKey(0))), MeshPlan(pp=2), num_slots=2, max_len=32,
+        devices=jax.devices()[:2])  # programs of its own
+    Session(ex, "s").run().end()
+    raw = ex.process("raw", {"tokens": [OTHER], "start_pos": 0, "real_len": len(OTHER)})
+    ex.process("raw", {"tokens": [[int(np.argmax(raw["logits"][0]))]], "start_pos": len(OTHER),
+                       "real_len": 1})  # a hop that asks nothing: its logits row
+    assert ex.engine._step_raw_multi._cache_size() == 1
+    s = ex.stats()
+    assert s["ahead_claimed"] == NEW - 2 and s["logit_rows"] == 1
+    # a pass a row here (one session at a time), and the two prefills
+    assert s["pipeline"]["passes"] == s["batched_steps"] + 2 == s["batched_tokens"] + 2 == NEW + 2
+    assert s["pipeline"]["stage_ticks_useful"] == 2 * s["pipeline"]["passes"]
+
+
+@pytest.mark.parametrize("program", ["this_tree", "the_parent", "no_hop"])
+def test_the_benchmark_reads_the_share_of_mesh_hops_that_found_their_row_run(program):
+    read = _reader("mesh.ahead_claimed_share")
+    ex = _executor("mesh:tiny")
+    before = {"executor": ex.stats()}
+    if program != "no_hop":
+        a = Session(ex, "a", new=12).run().end()
+        b = Session(ex, "b", prompt=OTHER, new=6, ahead=False).run().end()  # promises nothing
+    after = {"executor": ex.stats()}
+    if program == "the_parent":  # a program without the counters: nothing to read, no error
+        for stats in (before, after):
+            for key in ("ahead_rows", "ahead_claimed", "ahead_dropped"):
+                del stats["executor"][key]
+    got = read({"stats0": before, "stats1": after})
+    if program == "this_tree":
+        hops = len(a.out) - 1 + len(b.out) - 1
+        assert got == pytest.approx(100.0 * (len(a.out) - 2) / hops) and 0 < got < 100
     else:
         assert got is None
