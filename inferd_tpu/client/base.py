@@ -319,28 +319,40 @@ class GenerationClient:
 
     async def _traced_step(
         self, session_id: str, tokens: List[int], start_pos: int,
-        ask: Optional[Dict[str, Any]] = None,
+        ask: Optional[Dict[str, Any]] = None, first: bool = False,
     ):
         """One pipeline pass wrapped in a `wire`-phase span: the envelope
         the subclass transport builds inside parents to this span (the
         contextvar carries it), so node-side spans nest under the step.
         Returns last-token logits [V]; with an `ask` (a decode hop), the
-        reply dict of `_asking_step`."""
-        with self.tracer.span(
-            "step", "wire", attrs={"start_pos": start_pos, "n": len(tokens)}
-        ):
+        reply dict of `_asking_step`. A prefill chunk's span, and that of
+        the session's `first` decode hop (`first: 1`), is kept with all
+        beneath it (obs.trace: once a request); a later hop's is sampled."""
+        with self._step_span(start_pos, len(tokens), ask is None, first):
             if ask is not None:
                 return await self._asking_step(session_id, tokens, start_pos, ask)
             return await self._step(session_id, tokens, start_pos)
 
-    def _sample_traced(self, logits: np.ndarray, rng, s: SamplingConfig) -> int:
+    def _step_span(self, start_pos: int, n: int, prefill: bool, first: bool):
+        """The `step` span of one hop of the generation loop (see
+        `_traced_step` for which are kept)."""
+        attrs = {"start_pos": start_pos, "n": n}
+        if first:
+            attrs["first"] = 1
+        return self.tracer.span("step", "wire", attrs=attrs, keep=prefill or first)
+
+    def _sample_traced(
+        self, logits: np.ndarray, rng, s: SamplingConfig, keep: bool = False,
+    ) -> int:
         """Client-side sampling with a `sample`-phase span (sub-ms, but it
         closes the per-token timeline: step + sample account for the whole
-        decode iteration)."""
+        decode iteration). `sample` and `emit` are children of `generate`,
+        which is kept: they say themselves whether they are (the first
+        token's pair, once a request)."""
         t0 = tracelib.now()
         tok = sample_np(logits, rng, s.temperature, s.top_k, s.top_p, s.min_p)
         self.tracer.record_span(
-            "sample", "sample", t0, tracelib.now(), parent=tracelib.current()
+            "sample", "sample", t0, tracelib.now(), parent=tracelib.current(), keep=keep,
         )
         return tok
 
@@ -356,20 +368,35 @@ class GenerationClient:
             "top_logprobs": top_n,
         }
 
-    def _record_emit(self, t0: float, tokens: int) -> None:
+    def _record_emit(self, t0: float, tokens: int, keep: bool = False) -> None:
         """The `emit`-phase span of what the caller's `on_token` took for
         one hop's tokens (a token; a block's): with `sample` it fills the
         stretch between two `step`s, and a callback that yields to the
         event loop (a stream's write) shows here."""
         self.tracer.record_span(
             "emit", "emit", t0, tracelib.now(), parent=tracelib.current(),
-            attrs={"tokens": tokens},
+            attrs={"tokens": tokens}, keep=keep,
         )
 
-    async def _emit_traced(self, on_token, tok: int) -> None:
+    async def _emit_traced(self, on_token, tok: int, keep: bool = False) -> None:
         t0 = tracelib.now()
         await _emit(on_token, tok)
-        self._record_emit(t0, 1)
+        self._record_emit(t0, 1, keep)
+
+    def _record_open(self, t_open: float) -> None:
+        """The `open`-phase span: the loop's `generate` opened (`t_open`) ->
+        NOW, where the first chunk's `step` begins."""
+        self.tracer.record_span(
+            "open", "open", t_open, tracelib.now(), parent=tracelib.current()
+        )
+
+    async def _close_session(self, session_id: str) -> None:
+        """The loop gives its session back, under a `close`-phase span."""
+        with tracelib.region(self.tracer, "close"):
+            try:
+                await self._end_session(session_id)
+            except Exception:
+                pass  # best effort: nodes TTL-sweep orphaned sessions
 
     # -- shared helpers ------------------------------------------------------
 
@@ -512,13 +539,23 @@ class GenerationClient:
         # root span of the end-to-end timeline: one trace per generation,
         # retries included (restart attempts show up as extra step spans)
         try:
+            # `open` begins; and where a server's handler left the stamp of
+            # the request's arrival (runtime/node.py _accepted), `accept` ends
+            t_open, t_in = tracelib.now(), tracelib.marked("t_arrived")
+            if t_in is not None:
+                self.tracer.record_span(
+                    "accept", "accept", t_in, t_open, parent=tracelib.current(),
+                    attrs={"prompt": len(prompt_ids), "stream": int(on_token is not None)},
+                )
             with self.tracer.span(
                 "generate", "client",
                 attrs={"prompt": len(prompt_ids), "max_new": max_new_tokens},
+                keep=True,
             ):
                 last_err: Optional[Exception] = None
                 for attempt in range(1 + session_retries):
                     if attempt:
+                        t_open = None  # a restart's `open` begins with its attempt
                         assert last_err is not None
                         if not budget.try_acquire():
                             # retry budget dry: bounded retry rate beats a
@@ -545,7 +582,7 @@ class GenerationClient:
                         return await self._generate_once(
                             list(prompt_ids), max_new_tokens, eos_token_id, seed,
                             sampling or self.sampling, on_token, logprob_sink,
-                            top_n, top_sink,
+                            top_n, top_sink, t_open,
                         )
                     except ServerError as e:
                         if not e.retryable:
@@ -568,7 +605,7 @@ class GenerationClient:
     async def _step_resuming(
         self, session_id: str, toks: List[int], pos: int,
         known: List[int], resumes: List[int],
-        ask: Optional[Dict[str, Any]] = None,
+        ask: Optional[Dict[str, Any]] = None, first: bool = False,
     ):
         """_traced_step with standby-promotion resume: a session_state
         409 carrying `resume_from` F means the answering replica holds
@@ -585,7 +622,7 @@ class GenerationClient:
         same key: the retried hop draws the same token); the replayed
         chunks between are prefill and carry none."""
         try:
-            return await self._traced_step(session_id, toks, pos, ask)
+            return await self._traced_step(session_id, toks, pos, ask, first)
         except ServerError as e:
             f = e.resume_from
             if f is None or not 0 <= int(f) < pos or resumes[0] <= 0:
@@ -605,7 +642,7 @@ class GenerationClient:
                 )
                 p += len(chunk)
             return await self._step_resuming(
-                session_id, toks, pos, known, resumes, ask
+                session_id, toks, pos, known, resumes, ask, first
             )
 
     async def _generate_once(
@@ -619,12 +656,15 @@ class GenerationClient:
         logprob_sink: Optional[List[float]] = None,
         top_n: int = 0,
         top_sink: Optional[List] = None,
+        t_open: Optional[float] = None,
     ) -> List[int]:
+        t_open = t_open or tracelib.now()
         blk = await self._block_length()
         if blk > 1:
             return await self._generate_blocks(
                 blk, prompt_ids, max_new_tokens, eos_token_id, seed,
                 sampling or self.sampling, on_token, logprob_sink, top_n, top_sink,
+                t_open,
             )
         session_id = str(uuid.uuid4())
         rng = np.random.default_rng(seed)
@@ -669,6 +709,7 @@ class GenerationClient:
                         await self._end_session(session_id)
                     except Exception:
                         pass
+            self._record_open(t_open)
             for i in range(pos, len(prompt_ids), self.prefill_chunk):
                 chunk = prompt_ids[i : i + self.prefill_chunk]
                 logits = await self._step_resuming(
@@ -676,7 +717,8 @@ class GenerationClient:
                 )
                 pos += len(chunk)
             assert logits is not None
-            tok = self._sample_traced(logits, rng, s)
+            # the first token's `sample` and `emit` are once a request: kept
+            tok = self._sample_traced(logits, rng, s, keep=True)
             out.append(tok)
             known.append(tok)
             if logprob_sink is not None:
@@ -684,7 +726,7 @@ class GenerationClient:
             if top_sink is not None:
                 top_sink.append(top_logprobs_np(logits, top_n))
             if on_token is not None:
-                await self._emit_traced(on_token, tok)
+                await self._emit_traced(on_token, tok, keep=True)
             # every decode hop asks for its token: an executor that samples
             # on the device answers with it (and the session's next key:
             # `seed` roots the chain on the first such hop), any other with
@@ -704,6 +746,7 @@ class GenerationClient:
                 res = await self._step_resuming(
                     session_id, [tok], pos, known, resumes,
                     {**ask, **chain, "ahead": max_new_tokens - len(out) - 1},
+                    first=len(out) == 1,
                 )
                 pos += 1
                 if res.get("logits") is not None:
@@ -723,7 +766,7 @@ class GenerationClient:
                     # closes the per-token timeline (obs.merge counts them)
                     self.tracer.record_span(
                         "sample", "sample", t0, tracelib.now(),
-                        parent=tracelib.current(), attrs={"on": "device"},
+                        parent=tracelib.current(), attrs={"on": "device"}, keep=False,
                     )
                 out.append(tok)
                 known.append(tok)
@@ -734,16 +777,13 @@ class GenerationClient:
                 if on_token is not None:
                     await self._emit_traced(on_token, tok)
         finally:
-            try:
-                await self._end_session(session_id)
-            except Exception:
-                pass  # best effort: nodes TTL-sweep orphaned sessions
+            await self._close_session(session_id)
         return out
 
     async def _generate_blocks(
         self, blk: int, prompt_ids: List[int], max_new_tokens: int,
         eos_token_id: Optional[int], seed: int, s: SamplingConfig,
-        on_token, logprob_sink, top_n: int, top_sink,
+        on_token, logprob_sink, top_n: int, top_sink, t_open: float,
     ) -> List[int]:
         """The loop for a model generated by blocks of `blk`: the prompt's
         whole blocks are ingested in chunks (whose logits nobody reads: none
@@ -767,11 +807,10 @@ class GenerationClient:
         try:
             whole = len(prompt_ids) // blk * blk
             chunk = max(blk, self.prefill_chunk // blk * blk)
+            self._record_open(t_open)
             for pos in range(0, whole, chunk):
                 toks = prompt_ids[pos : min(pos + chunk, whole)]
-                with self.tracer.span(
-                    "step", "wire", attrs={"start_pos": pos, "n": len(toks)}
-                ):
+                with self._step_span(pos, len(toks), True, False):
                     await self._forward(session_id, toks, pos, want_logits=False)
             pos, head, key = whole, prompt_ids[whole:], None
             while len(out) < max_new_tokens and (not out or out[-1] != eos_token_id):
@@ -781,7 +820,7 @@ class GenerationClient:
                 left = max_new_tokens - len(out) - (blk - len(head))
                 call = dict(want, known=len(head), ahead=max(0, -(-left // blk)),
                             **({"seed": seed} if key is None else {"key": key}))
-                with self.tracer.span("step", "wire", attrs={"start_pos": pos, "n": blk}):
+                with self._step_span(pos, blk, False, key is None):
                     res = await self._forward(
                         session_id, head + [0] * (blk - len(head)), pos, block=call
                     )
@@ -804,10 +843,7 @@ class GenerationClient:
                     self._record_emit(t_emit, len(out) - had)  # one a block
                 pos, head, key = pos + blk, [], res["key"]
         finally:
-            try:
-                await self._end_session(session_id)
-            except Exception:
-                pass  # best effort: nodes TTL-sweep orphaned sessions
+            await self._close_session(session_id)
         return out
 
     async def generate(

@@ -31,6 +31,21 @@ executor), `resume` (-> its coroutine runs again on the event loop),
 `emit` (the generation loop's `on_token` callback) and, once a step and
 parentless, `turn` (runtime/window.py: the device freed -> the next
 drain).
+A request's admission and release (docs/OBSERVABILITY.md "A request's
+admission"), once a request: `accept` (/generate arrived -> the generation
+loop's `generate` opens), `open` (-> the first chunk's `step`), `lane`
+(the executor binds the session's lane or slot), `ride` (the first decode
+hop's own wait for the step it rode) and `close` (the loop gives the
+session back).
+
+What the ring may forget: a span is KEPT (`keep`) or sampled. Kept spans
+live in a ring of their own (`KEPT_CAP`), which a window of the
+benchmark does not fill: the roots of a request, every span of its
+admission (they inherit `keep` from their parent: `SpanContext.keep`,
+never on the wire), the spans that are once a step (`device`, `copy_out`,
+`turn`) and, while a profiler capture runs (`annotating`), every span.
+The spans that are once a HOP stay in the sampled ring, which holds a
+few seconds of them at a few thousand hops a second.
 Disabled-by-config tracing
 (INFERD_TRACE=0, read per call) records nothing and leaves the wire
 envelope byte-identical to the untraced format.
@@ -39,7 +54,7 @@ envelope byte-identical to the untraced format.
 from __future__ import annotations
 
 import contextvars
-import dataclasses
+import itertools
 import json
 import os
 import random
@@ -47,15 +62,24 @@ import threading
 import time
 from collections import deque
 from contextlib import contextmanager
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Dict, Iterable, List, NamedTuple, Optional
 
 PHASES = (
     "queue", "compute", "wire", "relay", "rescue", "handoff", "sample",
     "window",
     "batch_wait", "lock_wait", "device", "copy_out",
     "deliver", "resume", "emit", "turn",
+    "accept", "open", "lane", "ride", "close",
     "client", "server",
 )
+
+#: The ring of KEPT spans (SpanRecorder): sized from the benchmark's cell
+#: with the highest span rate, `g4hm-many-chat`, over a 45 s window: 36-60
+#: steps a second x 3 spans (`device`, `copy_out`, `turn`) is 5-8 thousand,
+#: 4 requests a second x (5 + 9 a prefill chunk + 13 for the first decode
+#: hop) is 5-7 thousand, and a profiler capture's 4 s at 12 thousand spans
+#: a second, all kept, is 48 thousand.
+KEPT_CAP = 65536
 
 #: HTTP header carrying "<trace_id>-<span_id>" (the /generate surface).
 TRACE_HEADER = "X-Inferd-Trace"
@@ -104,12 +128,20 @@ def new_id() -> str:
     return "%016x" % _ids.getrandbits(64)
 
 
-@dataclasses.dataclass(frozen=True)
-class SpanContext:
-    """The propagated half of a span: enough to parent remote children."""
+class SpanContext(NamedTuple):
+    """The propagated half of a span: enough to parent remote children.
+    `keep` (this process only: neither the wire nor the header carries it)
+    is what a child recorded without a `keep` of its own inherits. A tuple:
+    a hop makes seven of them, and a frozen dataclass takes twice as long
+    to make."""
 
     trace_id: str
     span_id: str
+    keep: bool = False
+
+    def child(self) -> "SpanContext":
+        """The context of a new span under this one: its trace, its `keep`."""
+        return SpanContext(self.trace_id, new_id(), self.keep)
 
     def to_wire(self) -> Dict[str, str]:
         return {"id": self.trace_id, "span": self.span_id}
@@ -154,11 +186,24 @@ def reset_current(token) -> None:
     _current.reset(token)
 
 
+def adopt(ctx: Optional[SpanContext]) -> Optional[SpanContext]:
+    """`ctx` as an envelope or a header gave it, with what only this
+    process knows of that span (`keep`): where it names the CURRENT span
+    (a hop the generation loop made as a call, not over a socket), that
+    one."""
+    cur = _current.get()
+    if ctx is not None and cur is not None and cur.span_id == ctx.span_id:
+        return cur
+    return ctx
+
+
 # Stamps a callee hands up to the owner of the span it runs under: the
 # owner `open_marks()` a dict in its own thread or task, whatever runs
 # beneath `mark(name, t)`s into it (a no-op where nobody opened one), and
 # the owner reads it once the call is back. `deliver`'s t0 travels this
-# way from the window's submit to Node._timed_process.
+# way from the window's submit to Node._timed_process. A stamp the OWNER
+# put there travels the other way (`marked`): `accept`'s t0, from the
+# /generate handler down to the generation loop that records the span.
 _marks: "contextvars.ContextVar[Optional[Dict[str, float]]]" = contextvars.ContextVar(
     "inferd_trace_marks", default=None
 )
@@ -178,6 +223,11 @@ def mark(name: str, t: float) -> None:
     marks = _marks.get()
     if marks is not None:
         marks[name] = t
+
+
+def marked(name: str) -> Optional[float]:
+    marks = _marks.get()
+    return None if marks is None else marks.get(name)
 
 
 def wire_ctx() -> Optional[Dict[str, str]]:
@@ -217,24 +267,20 @@ def header_ctx() -> Optional[Dict[str, str]]:
 
 
 @contextmanager
-def region(recorder: Optional["SpanRecorder"], name: str, parents=None, **attrs):
+def region(recorder: Optional["SpanRecorder"], name: str, parents=None, keep=None, **attrs):
     """Time the block as one span `name` (phase = name) on `recorder`, a
     child of the CURRENT context — or one span per context in `parents`
     (a flusher stamping a wait it served for every entry of its batch).
     Yields the attrs dict, so the block can add what it only knows at its
-    end (`bytes`). While the recorder is `annotating` (a profiler capture
+    end (`bytes`). `keep` as `SpanRecorder.record_span` takes it (None: the
+    parent's). While the recorder is `annotating` (a profiler capture
     is running) the block is also the profiler-trace annotation
     `inferd.<name>`. Does nothing without a recorder or with
     INFERD_TRACE=0."""
     if recorder is None or not enabled():
         yield attrs
         return
-    ann = None
-    if recorder.annotating:
-        import jax
-
-        ann = jax.profiler.TraceAnnotation("inferd." + name)
-        ann.__enter__()
+    ann = _annotation(recorder, name)
     t0 = now()
     try:
         yield attrs
@@ -244,43 +290,78 @@ def region(recorder: Optional["SpanRecorder"], name: str, parents=None, **attrs)
             ann.__exit__(None, None, None)
         for parent in (current(),) if parents is None else parents:
             recorder.record_span(
-                name, name, t0, t1, parent=parent, attrs=dict(attrs) or None
+                name, name, t0, t1, parent=parent, attrs=dict(attrs) or None, keep=keep
             )
 
 
+def _annotation(recorder: "SpanRecorder", name: str):
+    """The entered profiler annotation `inferd.<name>` while a capture runs
+    on `recorder`, else None (and jax is not imported)."""
+    if not recorder.annotating:
+        return None
+    import jax
+
+    ann = jax.profiler.TraceAnnotation("inferd." + name)
+    ann.__enter__()
+    return ann
+
+
 @contextmanager
-def holding(lock, recorder: Optional["SpanRecorder"], parents=None, **attrs):
-    """`with lock:` whose wait is the span `lock_wait` (see `region`)."""
-    with region(recorder, "lock_wait", parents, **attrs):
-        lock.acquire()
+def holding(lock, recorder: Optional["SpanRecorder"], **attrs):
+    """`with lock:` whose wait is the span `lock_wait` (see `region`),
+    recorded when the lock is RELEASED so that it can say how long the
+    block held it: `held_ms`, acquired -> released."""
+    if recorder is None or not enabled():
+        with lock:
+            yield
+        return
+    ann = _annotation(recorder, "lock_wait")
+    t0 = now()
+    lock.acquire()
+    t1 = now()
+    if ann is not None:
+        ann.__exit__(None, None, None)
     try:
         yield
     finally:
         lock.release()
+        attrs["held_ms"] = round((now() - t1) * 1e3, 3)
+        recorder.record_span("lock_wait", "lock_wait", t0, t1, parent=current(), attrs=attrs)
+
+
+#: one span as its JSONL line (an encoder made once: `json.dumps` with
+#: separators of its own makes one a call)
+_encode = json.JSONEncoder(separators=(",", ":")).encode
 
 
 class SpanRecorder:
-    """Bounded thread-safe span ring buffer for one process/service.
+    """Two bounded thread-safe span rings for one process/service.
 
     `service` names the recorder in every span (a node_id like
     "10.0.0.2:6050", or "client"); the merge CLI uses it as the clock
-    domain for skew correction. The ring drops the OLDEST spans on
-    overflow (`dropped` counts them): tracing must never grow RSS
-    unboundedly on a long-lived node.
+    domain for skew correction. A span goes to the ring of KEPT spans
+    (`KEPT_CAP`; the module docstring says which are) or to the sampled
+    ring (`cap`); each drops its OLDEST spans on overflow (`kept_dropped`,
+    `dropped` count them): tracing must never grow RSS unboundedly on a
+    long-lived node. Every reader gets both as one list in t0 order.
     """
 
     def __init__(self, service: str, cap: int = 8192):
         self.service = service
         self._lock = threading.Lock()
         self._buf: "deque[Dict[str, Any]]" = deque(maxlen=max(16, cap))
+        self._kept: "deque[Dict[str, Any]]" = deque(maxlen=KEPT_CAP)
         self.dropped = 0
+        self.kept_dropped = 0
         self.count = 0
+        self.kept = 0  # of `count`, those recorded into the kept ring
         self.overhead_ms = 0.0
-        self._flushed = 0  # high-water mark for flush_jsonl
+        self._flushed = [0, 0]  # high-water marks for flush_jsonl: sampled, kept
         # True while a jax.profiler capture runs in this process
         # (utils.profiling.Profiler sets it): `region`s then also enter a
         # jax.profiler.TraceAnnotation, so the written trace shows them
-        # above the device's operations on the profiler's own clock.
+        # above the device's operations on the profiler's own clock, and
+        # every span is kept: the capture's stretch is whole.
         # Otherwise no annotation object is made and jax is not imported.
         self.annotating = False
 
@@ -296,20 +377,25 @@ class SpanRecorder:
         parent: Optional[SpanContext] = None,
         ctx: Optional[SpanContext] = None,
         attrs: Optional[Dict[str, Any]] = None,
+        keep: Optional[bool] = None,
     ) -> Optional[SpanContext]:
         """Record a finished [t0, t1] span (wall-clock epoch seconds).
 
         `ctx` pre-allocates the span's own (trace, span) ids — used when
         the id already rode an envelope to remote children before the
         span finished. Otherwise the span joins `parent`'s trace (or
-        starts a fresh trace when parentless). Returns the span's
-        context, or None when tracing is disabled."""
+        starts a fresh trace when parentless). `keep` says which ring
+        holds it; None means `ctx`'s where one is given, else the
+        parent's. Returns the span's context, or None when tracing is
+        disabled."""
         if not enabled():
             return None
         r0 = time.perf_counter()
+        if keep is None:
+            keep = ctx.keep if ctx is not None else parent is not None and parent.keep
         if ctx is None:
             tid = parent.trace_id if parent is not None else new_id()
-            ctx = SpanContext(tid, new_id())
+            ctx = SpanContext(tid, new_id(), keep)
         span = {
             "trace": ctx.trace_id,
             "span": ctx.span_id,
@@ -323,9 +409,15 @@ class SpanRecorder:
         if attrs:
             span["attrs"] = attrs
         with self._lock:
-            if len(self._buf) == self._buf.maxlen:
-                self.dropped += 1
-            self._buf.append(span)
+            if keep or self.annotating:
+                if len(self._kept) == self._kept.maxlen:
+                    self.kept_dropped += 1
+                self._kept.append(span)
+                self.kept += 1
+            else:
+                if len(self._buf) == self._buf.maxlen:
+                    self.dropped += 1
+                self._buf.append(span)
             self.count += 1
             self.overhead_ms += (time.perf_counter() - r0) * 1e3
         return ctx
@@ -338,16 +430,20 @@ class SpanRecorder:
         *,
         parent: Optional[SpanContext] = None,
         attrs: Optional[Dict[str, Any]] = None,
+        keep: Optional[bool] = None,
     ):
         """Context manager: times the block, records the span, and makes
         it the CURRENT context inside (children — local blocks, wire
-        envelopes, HTTP headers — parent to it automatically). A no-op
-        yielding None when tracing is disabled."""
+        envelopes, HTTP headers — parent to it automatically, and inherit
+        its `keep`; None: its own parent's). A no-op yielding None when
+        tracing is disabled."""
         if not enabled():
             yield None
             return
         p = parent if parent is not None else current()
-        ctx = SpanContext(p.trace_id if p is not None else new_id(), new_id())
+        if keep is None:
+            keep = p is not None and p.keep
+        ctx = SpanContext(p.trace_id if p is not None else new_id(), new_id(), keep)
         token = _current.set(ctx)
         t0 = now()
         try:
@@ -362,34 +458,44 @@ class SpanRecorder:
 
     def __len__(self) -> int:
         with self._lock:
-            return len(self._buf)
+            return len(self._buf) + len(self._kept)
+
+    @staticmethod
+    def _in_order(sampled, kept) -> List[Dict[str, Any]]:
+        """Both rings as one list by t0 (each is in the order its spans
+        ENDED, so nearly sorted already)."""
+        return sorted(itertools.chain(sampled, kept), key=lambda s: s["t0"])
 
     def spans(self) -> List[Dict[str, Any]]:
-        """Point-in-time copy of the buffer (non-draining)."""
+        """Point-in-time copy of both rings (non-draining)."""
         with self._lock:
-            return list(self._buf)
+            rings = list(self._buf), list(self._kept)
+        return self._in_order(*rings)
 
     def drain(self) -> List[Dict[str, Any]]:
         with self._lock:
-            out = list(self._buf)
+            rings = list(self._buf), list(self._kept)
             self._buf.clear()
-            return out
+            self._kept.clear()
+        return self._in_order(*rings)
 
     def stats(self) -> Dict[str, Any]:
         with self._lock:
             return {
                 "service": self.service,
-                "buffered": len(self._buf),
+                "buffered": len(self._buf) + len(self._kept),
                 "recorded": self.count,
                 "dropped": self.dropped,
+                "kept": self.kept,
+                "kept_dropped": self.kept_dropped,
                 "overhead_ms": round(self.overhead_ms, 3),
             }
 
     # ------------------------------------------------------------ export
 
     def jsonl_lines(self, spans: Optional[Iterable[Dict[str, Any]]] = None):
-        for s in self.spans() if spans is None else spans:
-            yield json.dumps(s, separators=(",", ":"))
+        """`spans` (default: both rings, in t0 order) a line each."""
+        return map(_encode, self.spans() if spans is None else spans)
 
     def dump_jsonl(self, path: str, drain: bool = True) -> int:
         """Append the buffer (draining it by default) to a JSONL file;
@@ -400,15 +506,19 @@ class SpanRecorder:
 
     def flush_jsonl(self, path: str) -> int:
         """Append only the spans recorded since the last flush, WITHOUT
-        draining the ring — the periodic exporter's mode: /spans and the
+        draining the rings — the periodic exporter's mode: /spans and the
         gossiped hop quantiles keep seeing the live buffer, while the
         JSONL file still receives every span exactly once (ring overflow
-        between flushes loses the dropped spans, counted in `dropped`)."""
+        between flushes loses the dropped spans, counted in `dropped` /
+        `kept_dropped`)."""
         with self._lock:
-            n_new = min(len(self._buf), max(0, self.count - self._flushed))
-            spans = list(self._buf)[len(self._buf) - n_new:] if n_new else []
-            self._flushed = self.count
-        return self._append_jsonl(path, spans)
+            new = []
+            counts = (self.count - self.kept, self.kept)
+            for i, ring in enumerate((self._buf, self._kept)):
+                n_new = min(len(ring), max(0, counts[i] - self._flushed[i]))
+                new.append(list(ring)[len(ring) - n_new:] if n_new else [])
+                self._flushed[i] = counts[i]
+        return self._append_jsonl(path, self._in_order(*new))
 
     def _append_jsonl(self, path: str, spans: List[Dict[str, Any]]) -> int:
         if not spans:

@@ -1009,8 +1009,8 @@ class PipelinedEngine:
             padded[0, :, :s] = tokens
         else:
             padded = np.asarray(tokens, np.int32)[None]
-        with tracelib.region(
-            self.tracer, "device", kind="prefill" if s > 1 else "decode",
+        with tracelib.region(  # once a step: kept, as its copy_out is (obs.trace)
+            self.tracer, "device", keep=True, kind="prefill" if s > 1 else "decode",
             tokens=real_len, cobatch=1,
             program=program_name(self._step_raw),
         ):
@@ -1020,7 +1020,7 @@ class PipelinedEngine:
             )
             logits.block_until_ready()
         self._count_pass(1, 1)
-        with tracelib.region(self.tracer, "copy_out") as at:
+        with tracelib.region(self.tracer, "copy_out", keep=True) as at:
             out = np.asarray(logits)
             at["bytes"] = out.nbytes
         return out
@@ -1080,12 +1080,12 @@ class PipelinedEngine:
         plain = [slot for slot in tokens_by_slot if slot not in asks]
         live = len(tokens_by_slot)
         with tracelib.region(
-            self.tracer, "device", kind="decode", tokens=live, cobatch=live,
+            self.tracer, "device", keep=True, kind="decode", tokens=live, cobatch=live,
             program=program_name(self._step_raw_multi),
         ):
             logits, packed, top_n = self.dispatch_slots(tokens_by_slot, asks)
             packed.block_until_ready()
-        with tracelib.region(self.tracer, "copy_out") as at:
+        with tracelib.region(self.tracer, "copy_out", keep=True) as at:
             host = np.asarray(packed)
             rows, moved = samplib.logits_out(logits, plain)
             at["bytes"] = host.nbytes + moved

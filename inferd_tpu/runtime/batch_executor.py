@@ -64,7 +64,7 @@ from inferd_tpu.runtime.executor import (
     BlockCall, cache_intact, call_kind, parse_block, parse_decode_ask, parse_kstep,
 )
 from inferd_tpu.runtime.spec_serving import SpecForkMiss, SpecServing
-from inferd_tpu.runtime.step_ahead import StepAhead, _Ahead, _Step
+from inferd_tpu.runtime.step_ahead import Admissions, StepAhead, _Ahead, _Step
 from inferd_tpu.runtime.window import WindowedBatcher
 from inferd_tpu.utils import lockwatch
 
@@ -199,6 +199,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
         self.ahead_rows = 0
         self.ahead_claimed = 0
         self.ahead_dropped = 0
+        # a request's admission (/stats `executor`; the `lane` span)
+        self._admit = Admissions()
         # cumulative expert-routing counters over decode steps, or over
         # the passes of block steps (/stats `executor` `moe.*`); None where
         # the program returns no routing (no experts, or a paged / windowed
@@ -524,16 +526,22 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
     # -- lane/session bookkeeping (call under self._mu) ----------------------
 
     def _lane_for(self, session_id: str, new_ok: bool, protect=()) -> int:
+        return self._admit_lane(session_id, new_ok, protect)[0]
+
+    def _admit_lane(self, session_id: str, new_ok: bool, protect=()):
+        """The session's lane, and what binding it found (`Admissions.bound`;
+        `Admissions.KNOWN` for a session that had its lane)."""
         lane = self._sessions.get(session_id)
         if lane is not None:
             self._last_used[session_id] = time.monotonic()
-            return lane
+            return lane, Admissions.KNOWN
         if not new_ok:
             raise ValueError(
                 f"session {session_id}: unknown session resumed mid-stream "
                 "(cache evicted or node restarted)"
             )
-        if not self.engine.free:
+        evicted = not self.engine.free
+        if evicted:
             # LRU-evict a session with NO request in flight (neither waiting
             # in the decode batch nor mid-prefill on another thread);
             # `protect` shields a fork's parent from being its own victim
@@ -558,7 +566,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
         self._sessions[session_id] = lane
         self._last_used[session_id] = time.monotonic()
         self._lane_hi[lane] = 0  # fresh claimant: old marks are meaningless
-        return lane
+        return lane, self._admit.bound(lane, evicted)
 
     def _drop(self, session_id: str) -> None:
         lane = self._sessions.pop(session_id, None)
@@ -611,20 +619,23 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
         if self.pool is not None:
             self.pool.release_lane(lane)
         self.engine.free.append(lane)
+        self._admit.freed(lane)
 
     # -- executor contract ---------------------------------------------------
 
     def process(self, session_id: str, payload: Dict[str, Any]) -> Dict[str, Any]:
+        start_pos = int(payload.get("start_pos", 0))
+        # a session's first call: where its `lane` span begins (once a request)
+        t_in = tracelib.now() if start_pos == 0 else None
         toks = np.asarray(payload["tokens"], dtype=np.int32)
         if toks.ndim != 2 or toks.shape[0] != 1:
             raise ValueError(f"batched stage expects tokens [1, S], got {toks.shape}")
-        start_pos = int(payload.get("start_pos", 0))
         real_len = int(payload.get("real_len", toks.shape[1]))
 
         acquired = self._resolve_adapter(session_id, payload, start_pos)
         try:
             return self._process_inner(
-                session_id, payload, toks, start_pos, real_len, acquired
+                session_id, payload, toks, start_pos, real_len, acquired, t_in
             )
         except Exception:
             # an admission that died before _bind_adapter_locked consumed
@@ -635,7 +646,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
             raise
 
     def _process_inner(self, session_id: str, payload: Dict[str, Any],
-                       toks, start_pos: int, real_len: int, acquired):
+                       toks, start_pos: int, real_len: int, acquired, t_in=None):
         kind = call_kind(payload)
         decode = kind == "decode"
         blk = self.cfg.block_length
@@ -663,7 +674,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
                     f"session {session_id}: concurrent request (one step at "
                     "a time per session)"
                 )
-            lane = self._lane_for(session_id, new_ok=start_pos == 0)
+            lane, bound = self._admit_lane(session_id, new_ok=start_pos == 0)
             owner = f"session {session_id}, lane {lane}"
             have = self.engine.lengths[lane]
             # whatever this call is, the lane is back: the next drain runs
@@ -750,6 +761,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
                 # inside a prefill: no decode step waits for this lane
                 # until one has served it again
                 self._batcher.unexpect(lambda p, _lane=lane: p[0] == _lane)
+        self._lane_span(t_in, lane, bound)  # process entered -> _mu released
 
         try:
             if call is not None:
@@ -843,6 +855,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
         end = start + n
         step = self.prefill_chunk if self.prefill_chunk > 0 else end - pos
         logits = None
+        chunks = 0  # dispatches so far: the last one's `device` span says how many it stands for
         with contextlib.ExitStack() as dev:
             while pos < end:
                 c = min(step, end - pos)
@@ -868,15 +881,20 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
                         )
                     else:
                         fn = self.engine._prefill_lane_logits
+                        chunks += 1
                         if pos + c == end:
                             # the chunk whose logits are the response: its
                             # `device` span closes below, once the lock is
                             # released and the result is ready (earlier
                             # chunks of a --prefill-chunk admission are
-                            # dispatched without a wait and get no span)
+                            # dispatched without a wait and get no span:
+                            # `chunks` counts them into this one; how long
+                            # each held the lock is its `lock_wait`'s
+                            # `held_ms`)
                             dev.enter_context(tracelib.region(
-                                self.tracer, "device", kind="prefill",
+                                self.tracer, "device", keep=True, kind="prefill",
                                 tokens=c, cobatch=1, program=program_name(fn),
+                                chunks=chunks,
                             ))
                         self.engine.cache, logits = fn(
                             self.engine.params, self.engine.cache,
@@ -916,7 +934,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
             return None, saved
         # ONE boundary transfer: only the LAST chunk's logits are the
         # response — mid-chunk logits never leave the device
-        with tracelib.region(self.tracer, "copy_out") as at:
+        with tracelib.region(self.tracer, "copy_out", keep=True) as at:
             out = np.asarray(logits, np.float32)
             at["bytes"] = out.nbytes
         return out, saved
@@ -1082,7 +1100,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
             ads=self._ads(ids), ask=ask, top_n=top_n,
         )
         packed.copy_to_host_async()
-        with tracelib.region(self.tracer, "copy_out") as at:
+        self._batcher.step_seen(not packed.is_ready())  # `_finish`'s counters: the drain waits here
+        with tracelib.region(self.tracer, "copy_out", keep=True) as at:
             host = np.asarray(packed)
             rows, moved = samplib.logits_out(logits, plain)
             at["bytes"] = host.nbytes + moved
@@ -1958,6 +1977,7 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
                 ahead_rows=self.ahead_rows,
                 ahead_claimed=self.ahead_claimed,
                 ahead_dropped=self.ahead_dropped,
+                **self._admit.stats(),
                 **self._batcher.stats(),
             )
             # how a token's keys lie: "rows" (all kv heads in one row: a head

@@ -49,7 +49,7 @@ from inferd_tpu.parallel import mesh as meshlib
 from inferd_tpu.parallel.infer import PipelinedEngine
 from inferd_tpu.runtime.executor import parse_decode_ask
 from inferd_tpu.runtime.spec_serving import SpecForkMiss, SpecServing
-from inferd_tpu.runtime.step_ahead import StepAhead, _Ahead, _Step
+from inferd_tpu.runtime.step_ahead import Admissions, StepAhead, _Ahead, _Step
 
 log = logging.getLogger(__name__)
 
@@ -70,6 +70,8 @@ class SlotSessions:
         self._slots: Dict[str, int] = {}
         self._last_used: Dict[str, float] = {}
         self._free = list(range(num_slots))
+        # slots bound to new sessions (/stats `executor`, the `lane` span)
+        self.admit = Admissions()
 
     def get(self, session_id: str) -> Optional[int]:
         slot = self._slots.get(session_id)
@@ -78,7 +80,8 @@ class SlotSessions:
         return slot
 
     def assign(self, session_id: str, protected=()) -> int:
-        if not self._free:
+        evicted = not self._free
+        if evicted:
             # evict the least-recently-used session (the stage executor's
             # SessionStore policy — a stale session loses its cache) that
             # is not protected (e.g. has a request in flight)
@@ -90,12 +93,13 @@ class SlotSessions:
         slot = self._free.pop()
         self._slots[session_id] = slot
         self._last_used[session_id] = time.monotonic()
+        self.admit.bound(slot, evicted)
         return slot
 
     def drop(self, session_id: str) -> None:
         slot = self.unmap(session_id)
         if slot is not None:
-            self._free.append(slot)
+            self.free_slot(slot)
 
     def unmap(self, session_id: str):
         """Remove the session->slot mapping WITHOUT freeing the slot (the
@@ -105,6 +109,7 @@ class SlotSessions:
 
     def free_slot(self, slot: int) -> None:
         self._free.append(slot)
+        self.admit.freed(slot)
 
     def owner(self, slot: int) -> Optional[str]:
         """The session that holds `slot`, if one does."""
@@ -452,6 +457,8 @@ class MeshExecutor(SpecServing, StepAhead):
         if toks.ndim != 2 or toks.shape[0] != 1:
             raise ValueError(f"mesh stage expects tokens [1, S], got {toks.shape}")
         start_pos = int(payload.get("start_pos", 0))
+        # a session's first call: where its `lane` span begins (once a request)
+        t_in = tracelib.now() if start_pos == 0 else None
         real_len = int(payload.get("real_len", toks.shape[1]))
         decode = real_len == 1 and start_pos > 0
         # a hop that asks for its token (parse_decode_ask) is answered with
@@ -468,6 +475,7 @@ class MeshExecutor(SpecServing, StepAhead):
                 )
             slot = self.sessions.get(session_id)
             new = slot is None
+            bound = Admissions.KNOWN
             if new:
                 if start_pos != 0:
                     raise ValueError(
@@ -475,6 +483,7 @@ class MeshExecutor(SpecServing, StepAhead):
                         f"start_pos {start_pos} (cache evicted or node restarted)"
                     )
                 slot = self._assign(session_id)
+                bound = self.sessions.admit.last
             else:
                 have = self._session_len.get(session_id, 0)
                 # whatever this call is, the slot is back: the next drain
@@ -537,6 +546,9 @@ class MeshExecutor(SpecServing, StepAhead):
                 # inside a prefill: no decode pass waits for this slot
                 # until one has served it again
                 self._batcher.unexpect(lambda p, _s=slot: p[0] == _s)
+        # process entered -> _lock released (a drain holds _lock to the end
+        # of the pass before its own)
+        self._lane_span(t_in, slot, bound)
 
         try:
             if decode:
@@ -673,6 +685,7 @@ class MeshExecutor(SpecServing, StepAhead):
             "ahead_rows": self.ahead_rows,
             "ahead_claimed": self.ahead_claimed,
             "ahead_dropped": self.ahead_dropped,
+            **self.sessions.admit.stats(),
             **self._batcher.stats(),
             # pipeline passes of the raw serving steps and how many of
             # their stage-ticks did a live session's work (the rest are

@@ -328,10 +328,12 @@ class Node:
         # into this ring, periodically appended to
         # <trace_dir>/<node_id>.spans.jsonl when --trace-dir is set (the
         # merge CLI's per-node input), always served live at /spans. A
-        # decoded token leaves seven spans a session; the ring has to hold
-        # what a poller reads every few seconds (the benchmark's traced run:
-        # five) at some hundreds of tokens a second, or the `capture` span
-        # and a share of every window are gone before they are read
+        # decoded token leaves ten spans a session: this ring of SAMPLED
+        # spans holds what a poller reads every few seconds (the
+        # benchmark's traced run: five) at some thousands of hops a
+        # second; what is once a request, a chunk or a step, and the whole
+        # of a profiler capture, lies in the recorder's ring of kept spans
+        # (obs.trace.KEPT_CAP) and outlives a window
         self.tracer = tracelib.SpanRecorder(service=info.node_id, cap=32768)
         # fleet flight recorder (obs.events): typed events (migrations,
         # rescues, dead peers, lane evictions, compiles, ...) with the
@@ -1457,10 +1459,11 @@ class Node:
         # riding the envelope (a client step span or an upstream relay
         # span — its send/recv pair brackets this span for the merge
         # CLI's skew correction); queue/compute/relay children hang off it
-        parent = tracelib.SpanContext.from_wire(env.get(tracelib.WIRE_KEY))
-        tin = tracelib.SpanContext(
-            parent.trace_id if parent is not None else tracelib.new_id(),
-            tracelib.new_id(),
+        # (a hop of this node's own loop: the `step` itself, with its `keep`)
+        parent = tracelib.adopt(tracelib.SpanContext.from_wire(env.get(tracelib.WIRE_KEY)))
+        tin = (
+            parent.child() if parent is not None
+            else tracelib.SpanContext(tracelib.new_id(), tracelib.new_id())
         )
         t_wall = tracelib.now()
         try:
@@ -2450,7 +2453,7 @@ class Node:
         ctx = token = None
         marks: Dict[str, float] = {}
         if tin is not None and tracelib.enabled():
-            ctx = tracelib.SpanContext(tin.trace_id, tracelib.new_id())
+            ctx = tin.child()
             token = tracelib.set_current(ctx)
             marks, mtoken = tracelib.open_marks()
         try:
@@ -2694,7 +2697,7 @@ class Node:
             tin = e.payload[2]
             rctx = None
             if tin is not None and traced:
-                rctx = tracelib.SpanContext(tin.trace_id, tracelib.new_id())
+                rctx = tin.child()
                 next_env = {**next_env, tracelib.WIRE_KEY: rctx.to_wire()}
             envs.append(next_env)
             spans.append((tin, rctx))
@@ -2937,7 +2940,7 @@ class Node:
         relay_ctx: Optional[tracelib.SpanContext] = None
         t_wall = 0.0
         if tin is not None and tracelib.enabled():
-            relay_ctx = tracelib.SpanContext(tin.trace_id, tracelib.new_id())
+            relay_ctx = tin.child()
             env = {**env, tracelib.WIRE_KEY: relay_ctx.to_wire()}
             t_wall = tracelib.now()
         body = wire.pack(env)  # pack once: env carries multi-MB activations
@@ -3637,11 +3640,13 @@ class Node:
                 parent = tracelib.SpanContext.from_header(
                     request.headers.get(tracelib.TRACE_HEADER)
                 )
+                # the root of a request: kept, and so is all of its
+                # admission beneath it (obs.trace)
                 with self.tracer.span(
                     "generate", "server", parent=parent,
-                    attrs={"canary": 1} if is_canary else None,
+                    attrs={"canary": 1} if is_canary else None, keep=True,
                 ):
-                    resp = await self._handle_generate_inner(request, sli)
+                    resp = await self._accepted(request, sli)
             status = resp.status
             return resp
         finally:
@@ -3683,6 +3688,20 @@ class Node:
                 "generate.ttft_ms", sli["ttft_ms"],
                 bounds_ms=_GENERATE_BOUNDS_MS,
             )
+
+    async def _accepted(self, request: web.Request, sli: Dict[str, Any]) -> web.Response:
+        """`_handle_generate_inner`, with the request's arrival left as a
+        mark for the generation loop: its `accept`-phase span runs from
+        here to where the loop's `generate` span opens, and the loop
+        records it there (client/base.py generate_ids), not when the
+        request is over. A request that never reaches the loop (refused;
+        a speculative path) has no `accept`."""
+        marks, token = tracelib.open_marks()
+        marks["t_arrived"] = tracelib.now()
+        try:
+            return await self._handle_generate_inner(request, sli)
+        finally:
+            tracelib.close_marks(token)
 
     async def _handle_generate_inner(
         self, request: web.Request, sli: Optional[Dict[str, Any]] = None,
@@ -4813,7 +4832,7 @@ class Node:
     async def handle_spans(self, request: web.Request) -> web.Response:
         """GET /spans — the live span ring as newline-delimited JSON
         (non-draining; the merge CLI's ad-hoc input for a running node)."""
-        # off the event loop: a full ring is a fifth of a second of
+        # off the event loop: both rings full are most of a second of
         # json.dumps, and every session's token loop runs on this loop
         body = await asyncio.get_running_loop().run_in_executor(
             None,
@@ -4948,7 +4967,7 @@ class Node:
         t_start = self.profiler.started_at
         self.tracer.record_span(
             "capture", "capture", t_start, t_start + seconds,
-            attrs={"capture_id": capture_id, "dir": d},
+            attrs={"capture_id": capture_id, "dir": d}, keep=True,
         )
         if eventslib.enabled():
             self.metrics.inc("prof.captures")
@@ -4967,7 +4986,7 @@ class Node:
             # stop called -> trace written: what closing the capture cost
             self.tracer.record_span(
                 "capture_close", "capture", t_stop, tracelib.now(),
-                attrs={"capture_id": capture_id},
+                attrs={"capture_id": capture_id}, keep=True,
             )
             self.journal.emit(
                 "profile.capture_done", capture_id=capture_id, dir=d

@@ -37,7 +37,7 @@ class _Step:
     __slots__ = (
         "packed", "logits", "top_n", "asks", "plain", "lanes", "prev",
         "program", "t_call", "t0", "t_done", "t_out", "lock", "done", "error",
-        "toks", "keys", "replies", "rows", "released",
+        "toks", "keys", "replies", "rows", "released", "behind",
     )
 
     def __init__(self, packed, logits, top_n, asks, plain, lanes, prev, program):
@@ -47,6 +47,11 @@ class _Step:
         self.lanes = lanes  # every row run for a session
         self.prev = prev  # the step dispatched before: `device` starts at its end
         self.program = program
+        # the steps dispatched before this one that the device had not
+        # ended when this one was called (a rider's `ride` span says so)
+        self.behind, at = 0, prev
+        while at is not None and not (at.done or at.packed.is_ready()):
+            self.behind, at = self.behind + 1, at.prev
         self.t_call = tracelib.now()
         self.t0 = self.t_done = self.t_out = 0.0
         self.lock = threading.Lock()
@@ -93,6 +98,39 @@ class _Step:
             ex._count_routing(routed[:, self.lanes], ex.engine.lanes)
 
 
+class Admissions:
+    """Lanes (the mesh: slots) bound to new sessions, and for how long each
+    had stood free: what the `lane` span of a session's first call says
+    (`bound` returns its new, evicted, vacant_ms; `last` is the last bind's)
+    and /stats `executor` counts (`admissions`, `lane_vacant_ms_sum`). Under
+    the lock of the table that hands the lanes out."""
+
+    KNOWN = (0, 0, None)  # the session had its lane already
+
+    def __init__(self):
+        self.count = 0
+        self.vacant_ms_sum = 0.0
+        self._freed: Dict[int, float] = {}  # lane -> when it was given back
+        self.last = self.KNOWN
+
+    def freed(self, lane: int) -> None:
+        self._freed[lane] = tracelib.now()
+
+    def bound(self, lane: int, evicted: bool):
+        """`lane` goes to a new session (a lane never used has stood free
+        for no known time)."""
+        freed = self._freed.pop(lane, None)
+        vacant = None if freed is None else round((tracelib.now() - freed) * 1e3, 3)
+        self.count += 1
+        self.vacant_ms_sum += vacant or 0.0
+        self.last = (1, int(evicted), vacant)
+        return self.last
+
+    def stats(self) -> Dict[str, Any]:
+        return {"admissions": self.count,
+                "lane_vacant_ms_sum": round(self.vacant_ms_sum, 3)}
+
+
 class _Ahead:
     """A row run for a lane before its hop arrived (under the executor's
     lock of its session table): the position it was written at (the lane's
@@ -117,6 +155,18 @@ class StepAhead:
     `_runs_ahead(lane, ask, src)`; `_batcher`, `tracer`, `cfg`. The records
     are read and written under the executor's lock of its session table,
     the dispatching ones under its device's."""
+
+    def _lane_span(self, t_in: Optional[float], lane: int, bound) -> None:
+        """The `lane` span of a session's first call (`t_in`: `process`
+        entered, None for any later call), once its lane is bound (`bound`:
+        what `Admissions.bound` said, or `KNOWN`) and the table's lock
+        released."""
+        if t_in is not None and self.tracer is not None:
+            new, evicted, vacant = bound
+            self.tracer.record_span(
+                "lane", "lane", t_in, tracelib.now(), parent=tracelib.current(),
+                attrs={"lane": lane, "new": new, "evicted": evicted, "vacant_ms": vacant},
+            )
 
     def _claim(self, lane: int, pos: int, tok, ask) -> bool:
         """Whether the call at `pos` of `lane` is the hop a row was run
@@ -188,18 +238,22 @@ class StepAhead:
         """A hop rode `step`, which other sessions' drains do not wait for:
         its own thread waits for it (under no lock of the executor), and
         the window expects the lane again once a drain has answered it.
-        Returns what the hop is answered with."""
-        self._batcher.unexpect(lambda p, _lane=lane: p[0] == _lane)
-        self._finish(step)
-        if step.error is not None:
-            raise step.error
-        tracelib.mark("t_out", step.t_out)  # `deliver` starts (runtime/window.py submit)
-        if lane in self._carry:
-            # the next drain runs this session's next row ahead if its hop
-            # is not back before it: give that drain a step's time to come
-            # (a session that returned at once would ride again, and again)
-            step.released.wait(min(0.1, step.t_done - step.t0))
-        self._batcher.delivered(lane)
+        Returns what the hop is answered with. The whole of it is the
+        hop's `ride` span and a ride of /stats `executor` (`rides`,
+        `ride_ms_sum`)."""
+        t_ride = tracelib.now()
+        with tracelib.region(self.tracer, "ride", behind=step.behind):
+            self._batcher.unexpect(lambda p, _lane=lane: p[0] == _lane)
+            self._finish(step)
+            if step.error is not None:
+                raise step.error
+            tracelib.mark("t_out", step.t_out)  # `deliver` starts (runtime/window.py submit)
+            if lane in self._carry:
+                # the next drain runs this session's next row ahead if its hop
+                # is not back before it: give that drain a step's time to come
+                # (a session that returned at once would ride again, and again)
+                step.released.wait(min(0.1, step.t_done - step.t0))
+            self._batcher.delivered(lane, rode_s=tracelib.now() - t_ride)
         return step.reply(lane)
 
     def _finish(self, step: _Step) -> None:
@@ -208,10 +262,13 @@ class StepAhead:
         it first (the next drain's flusher under the device's lock; a
         rider's own thread under no lock). Its `device` span runs from its
         dispatch, or from the end of the step before it where it queued
-        behind that one, to the moment this thread learnt it was done: where
-        no one waited for it while it ran (a turn longer than the step),
-        that is the next drain, and the span overstates the step by the
-        difference."""
+        behind that one, to the moment this thread learnt it was done, and
+        says which that was (`waited`; /stats `executor` `steps_waited`,
+        `steps_found_done`): 1 where this thread waited for the step, so
+        the span's end is the step's to a wake-up; 0 where the step was
+        done when the thread came (a turn longer than the step: the next
+        drain), so the span overstates the step and the chip has been free
+        since some earlier moment. Once a step: both spans are kept."""
         with step.lock:
             if step.done:
                 return
@@ -219,15 +276,17 @@ class StepAhead:
             if prev is not None:
                 self._finish(prev)
             try:
+                waited = not step.packed.is_ready()
                 step.packed.block_until_ready()
                 step.t_done = tracelib.now()
                 step.t0 = max(step.t_call, prev.t_done if prev is not None else 0.0)
+                self._batcher.step_seen(waited)
                 if self.tracer is not None and tracelib.enabled():
                     self.tracer.record_span(
                         "device", "device", step.t0, step.t_done, parent=tracelib.current(),
-                        attrs=step.span(),
+                        attrs=dict(step.span(), waited=int(waited)), keep=True,
                     )
-                with tracelib.region(self.tracer, "copy_out") as at:
+                with tracelib.region(self.tracer, "copy_out", keep=True) as at:
                     host = np.asarray(step.packed)
                     step.rows, moved = samplib.logits_out(step.logits, step.plain)
                     at["bytes"] = host.nbytes + moved

@@ -163,6 +163,14 @@ class WindowedBatcher:
         self.session_turns = 0
         self.session_turn_ms_sum = 0.0
         self._formed = ("solo", 0)  # how the last formation ended, whom it was owed
+        # a step dispatched ahead of its sessions (runtime/step_ahead.py),
+        # as whoever came for it first found it: still running (that
+        # thread waited for its end) or done; the hops that rode such a
+        # step and waited for it in their own thread, and for how long
+        self.steps_waited = 0
+        self.steps_found_done = 0
+        self.rides = 0
+        self.ride_ms_sum = 0.0
         # the node's span recorder (obs.trace.SpanRecorder), handed to
         # the executor that owns this batcher; None records no span
         self.tracer: Optional[tracelib.SpanRecorder] = None
@@ -439,7 +447,7 @@ class WindowedBatcher:
             return
         last = max(batch, key=lambda e: e.t_submit)
         self.tracer.record_span(
-            "turn", "turn", t_freed, t_drain,
+            "turn", "turn", t_freed, t_drain, keep=True,  # once a step
             attrs={
                 "kind": self.kind, "cobatch": len(batch), "expected": owed,
                 "how": how,
@@ -460,13 +468,25 @@ class WindowedBatcher:
             for e in entries:
                 e.t_out = t_out
 
-    def delivered(self, key: Hashable) -> None:
+    def delivered(self, key: Hashable, rode_s: float) -> None:
         """The result of session `key` is out NOW, handed over by its
         caller and not by a drain's own delivery (an entry the callback
         answered with a step still running, which the submitter waited for
-        itself): where its next submit's turn is counted from."""
+        itself, `rode_s` seconds): where its next submit's turn is counted
+        from."""
         with self._mu:
             self._delivered[key] = tracelib.now()
+            self.rides += 1
+            self.ride_ms_sum += rode_s * 1e3
+
+    def step_seen(self, waited: bool) -> None:
+        """Whoever came first for a step dispatched ahead had to wait for
+        its end, or found it done."""
+        with self._mu:
+            if waited:
+                self.steps_waited += 1
+            else:
+                self.steps_found_done += 1
 
     def unexpect(self, pred: Callable[[Any], bool]) -> None:
         """Stop waiting for the sessions whose last served payload matches
@@ -498,6 +518,12 @@ class WindowedBatcher:
             "session_turns": self.session_turns,
             "session_turn_ms_sum": round(self.session_turn_ms_sum, 3),
             "turn_est_ms": round(self._turn_s * 1e3, 3),
+            # steps run ahead of their sessions: who saw them end, and the
+            # hops that waited for their own (runtime/step_ahead.py)
+            "steps_waited": self.steps_waited,
+            "steps_found_done": self.steps_found_done,
+            "rides": self.rides,
+            "ride_ms_sum": round(self.ride_ms_sum, 3),
         }
         return {
             **formation,

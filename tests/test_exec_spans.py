@@ -34,7 +34,7 @@ from inferd_tpu.runtime.window import WindowedBatcher
 from test_mesh_node import hold_flusher
 
 PROMPTS = {"a": [3, 7, 11], "b": [5, 13, 17]}
-PARTS = ("batch_wait", "lock_wait", "device", "copy_out", "deliver")
+PARTS = ("lane", "batch_wait", "lock_wait", "device", "copy_out", "deliver")
 PP, SLOTS = 4, 4
 HELD_S = 0.1  # how long the lanes' device lock is held under both entries
 
@@ -154,11 +154,20 @@ def test_prefill_call_leaves_its_three_parts(driven):
     assert len(calls) == 2
     for c in calls:
         kids = children(spans, c)
-        assert [k["name"] for k in kids] == ["lock_wait", "device", "copy_out"]
-        lock, dev, copy = kids
-        assert lock["attrs"] == {"kind": "prefill"}
+        # a session's first call binds its lane (slot) first: a lane never
+        # used before has stood free for no known time
+        assert [k["name"] for k in kids] == ["lane", "lock_wait", "device", "copy_out"]
+        lane, lock, dev, copy = kids
+        assert lane["attrs"] == {"lane": lane["attrs"]["lane"], "new": 1, "evicted": 0,
+                                 "vacant_ms": None}
+        # the wait for the lock, and how long the chunk's dispatch (the
+        # mesh: its whole pass) then held it
+        assert lock["attrs"] == {"kind": "prefill", "held_ms": lock["attrs"]["held_ms"]}
+        assert 0 < lock["attrs"]["held_ms"] < 60e3
         assert dev["attrs"] == {"kind": "prefill", "tokens": 3, "cobatch": 1,
-                                "program": driven["programs"]["prefill"]}
+                                "program": driven["programs"]["prefill"],
+                                # the lanes' span stands for every chunk dispatched
+                                **({"chunks": 1} if driven["stats"]["mode"] == "batched" else {})}
         assert copy["attrs"] == {"bytes": TINY.vocab_size * 4}
 
 
@@ -177,8 +186,9 @@ def test_decode_step_is_one_device_step_and_a_wait_per_entry(driven):
     ]
     dev = [s for s in spans if s["name"] == "device" and s["attrs"]["kind"] == "decode"]
     assert len(dev) == 1
+    # the flusher waited for the step it had just dispatched: it saw it end
     assert dev[0]["attrs"] == {"kind": "decode", "tokens": 2, "cobatch": 2,
-                               "program": driven["programs"]["decode"]}
+                               "program": driven["programs"]["decode"], "waited": 1}
     locks = [s for s in spans if s["name"] == "lock_wait" and s["attrs"]["kind"] == "decode"]
     assert len(locks) == 2
     # each from its own submit, for as long as the device was held

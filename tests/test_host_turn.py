@@ -163,10 +163,20 @@ def test_every_hop_leaves_its_parts_and_every_step_its_turn(served, topology):
     # both executors keep one step ahead, block steps and the mesh's passes
     # too: a drain dispatches the NEXT step (two where nobody was ahead yet:
     # the riders' own and the one behind it), so a step runs BESIDE the turn
-    # that follows its drain
-    assert len(mine) >= len(turns) >= NEW // per_hop - 1 and beside >= NEW // per_hop // 2
-    for t in turns:
+    # that follows its drain; a drain dispatches none where every hop it
+    # took was its generation's last (the sessions' ends: one such turn
+    # where the two end together, two where the machine's load has pulled
+    # them apart)
+    followed = {id(h["prev"]) for h in hops}
+    ended = [all(id(h) not in followed for h in hops if h["taken"] == t["t1"]) for t in turns]
+    assert 1 <= sum(ended) <= 2
+    assert len(mine) >= len(turns) - sum(ended) and len(turns) >= NEW // per_hop - 1
+    assert beside >= NEW // per_hop // 2
+    for i, t in enumerate(turns):
         a = t["attrs"]
+        # the drain that ended the turn dispatched a step before the next drain came
+        until = turns[i + 1]["t1"] if i + 1 < len(turns) else float("inf")
+        assert ended[i] or any(t["t1"] <= s["t0"] <= until for s in steps)
         # the step dispatched at the drain that ended the turn (none where
         # every entry was a generation's last hop)
         step = min((s for s in steps if s["t0"] >= t["t1"]), key=lambda s: s["t0"],
@@ -210,8 +220,19 @@ def test_the_last_entrys_parts_abut_and_sum_to_the_turn(served, topology):
         assert abs(queue["t1"] - hop["compute"]["t0"]) < 1e-4
         # the turn starts inside that session's `deliver` or, where the
         # session sat out a step, after it; what of the parts lies inside
-        # the turn is the turn (the parts are `order`'s neighbours)
-        assert deliver["t0"] <= t["t0"]
+        # the turn is the turn (the parts are `order`'s neighbours). A hop
+        # that RODE its step (its `ride` says so) was answered by its own
+        # thread, after the drain that dispatched the step had returned and
+        # the turn begun: there the parts start at that drain (the hop's
+        # `taken`), and the ride up to its step's end is one of them
+        rides = [s for s in run["spans"]
+                 if s["name"] == "ride" and s["parent"] == prev["compute"]["span"]]
+        if rides:
+            ride, = rides
+            assert ride["t0"] <= deliver["t0"] <= ride["t1"] <= prev["compute"]["t1"]
+            order = [prev["taken"], ride["t0"]] + order
+            assert order == sorted(order)
+        assert order[0] <= t["t0"]
         inside = sum(
             max(0.0, min(b, t["t1"]) - max(a, t["t0"])) for a, b in zip(order, order[1:])
         )

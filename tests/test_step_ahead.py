@@ -1055,3 +1055,158 @@ def test_the_benchmark_reads_the_share_of_mesh_hops_that_found_their_row_run(pro
         assert got == pytest.approx(100.0 * (len(a.out) - 2) / hops) and 0 < got < 100
     else:
         assert got is None
+
+
+# ---------------------------------------------------------------------------
+# (h) `device` says whether anyone saw the step end (docs/OBSERVABILITY.md
+# "A request's admission"): `waited`, and /stats `executor` `steps_waited` /
+# `steps_found_done`, from one `is_ready()` before the wait
+# ---------------------------------------------------------------------------
+
+
+class StillRunning:
+    """A step's packed array as a thread finds it that comes while the step
+    still runs: not ready until it is waited for."""
+
+    def __init__(self, packed):
+        self.packed = packed
+
+    def is_ready(self):
+        return False
+
+    def block_until_ready(self):
+        self.packed.block_until_ready()
+
+    def __array__(self, *args, **kwargs):
+        return np.asarray(self.packed, *args, **kwargs)
+
+
+SEEN = {"lanes": ("tiny", "decode"), "block": ("tiny-sdar", "block"), "mesh": ("mesh:tiny", "decode")}
+
+
+@pytest.mark.parametrize("found", ["still_running", "done"])
+@pytest.mark.parametrize("who", list(SEEN))
+def test_a_device_span_says_whether_its_step_was_waited_for(who, found):
+    from inferd_tpu.obs import trace as tracelib
+
+    model, kind = SEEN[who]
+    ex = _executor(model)
+    ex.tracer = rec = tracelib.SpanRecorder("t")
+    s = (BlockSession if who == "block" else Session)(ex, "s").run(hops=2)
+    step = ex._last_step  # run ahead of the session's next hop: nobody has come for it
+    assert not step.done
+    step.packed.block_until_ready()
+    if found == "still_running":
+        step.packed = StillRunning(step.packed)
+    before, had = ex.stats(), {x["span"] for x in rec.spans()}
+    ex._finish(step)
+    after = ex.stats()
+    moved = {k: after[k] - before[k] for k in ("steps_waited", "steps_found_done")}
+    assert moved == {"steps_waited": int(found == "still_running"),
+                     "steps_found_done": int(found == "done")}
+    dev, copy = sorted((x for x in rec.spans() if x["span"] not in had), key=lambda x: x["t1"])
+    assert (dev["name"], copy["name"]) == ("device", "copy_out")
+    assert dev["attrs"]["kind"] == kind and dev["attrs"]["waited"] == int(found == "still_running")
+    ex._finish(step)  # once a step, whoever comes again
+    assert ex.stats()["steps_waited"] == after["steps_waited"] and len(rec.spans()) == len(had) + 2
+    # both are once a step: the ring keeps them
+    assert rec.stats()["kept"] >= 2 and {"device", "copy_out"} <= {x["name"] for x in rec._kept}
+    s.run()
+    assert s.out == (BlockSession if who == "block" else Session)(
+        _executor(model), "fresh", ahead=False).run().out
+
+
+@pytest.mark.parametrize("program", ["this_tree", "the_parent", "no_step"])
+def test_the_benchmark_reads_who_saw_the_steps_end(program):
+    from inferd_tpu.obs import trace as tracelib
+
+    share, seen_ms = _reader("window.step_seen_share"), _reader("window.device_seen_ms_p50")
+    ex = _executor("tiny")
+    ex.tracer = rec = tracelib.SpanRecorder("t")
+    before, wall0 = {"executor": ex.stats()}, tracelib.now()
+    if program != "no_step":
+        s = Session(ex, "s").run(hops=2)
+        ex._last_step.packed.block_until_ready()  # the next drain finds this one done
+        s.run().end()
+    after = {"executor": ex.stats()}
+    spans = rec.spans()
+    if program == "the_parent":  # a program without the counters and the attribute
+        for stats in (before, after):
+            del stats["executor"]["steps_waited"], stats["executor"]["steps_found_done"]
+        for x in spans:
+            (x.get("attrs") or {}).pop("waited", None)
+    run = {"stats0": before, "stats1": after, "spans": spans,
+           "wall0": wall0, "wall1": tracelib.now()}
+    if program != "this_tree":
+        assert share(run) is None and seen_ms(run) is None
+        return
+    waited, done = (after["executor"][k] for k in ("steps_waited", "steps_found_done"))
+    assert done >= 1 and waited >= 1  # the lone session's first hop is waited for by its drain
+    assert share(run) == pytest.approx(100.0 * waited / (waited + done))
+    of = sorted((x["t1"] - x["t0"]) * 1e3 for x in spans
+                if x["name"] == "device" and x["attrs"].get("waited") == 1)
+    assert len(of) == waited and of[0] <= seen_ms(run) <= of[-1]
+
+
+@pytest.mark.parametrize("chunk_ms, own_ms", [(None, 40.0), (30.0, 40.0), (37.0, 1.0)])
+def test_a_step_seen_behind_a_prefill_chunk_is_left_out(chunk_ms, own_ms):
+    """The program starts a step's `device` span at the end of the STEP
+    before it; one that queued behind a prefill chunk carries the chunk, and
+    `window.device_seen_ms_p50` leaves it out. A chunk that queued behind a
+    step ends after it and takes nothing."""
+    import statistics
+
+    seen_ms = _reader("window.device_seen_ms_p50")
+
+    def device(t0_ms, t1_ms, kind, **attrs):
+        return {"name": "device", "t0": 100 + t0_ms / 1e3, "t1": 100 + t1_ms / 1e3,
+                "attrs": {"kind": kind, **attrs}}
+
+    spans = [
+        device(5.0, 38.0, "decode", waited=1),  # dispatched at 5: 33 ms to its end
+        device(38.0, 46.0, "decode", waited=1),  # behind that step alone: 8 ms
+        device(46.0, 46.0 + own_ms, "decode", waited=1),
+        device(50.0, 76.0 + own_ms, "prefill"),  # a chunk behind THAT one: it ends later
+        device(90.0, 99.0, "decode", waited=0),  # found done: not the step's end
+    ]
+    if chunk_ms is not None:  # a chunk ahead of the first step, its end seen at `chunk_ms`
+        spans.insert(0, device(0.0, chunk_ms, "prefill"))
+    run = {"spans": spans, "wall0": 100.0, "wall1": 101.0}
+    own = ([33.0] if chunk_ms is None else []) + [8.0, own_ms]
+    assert seen_ms(run) == pytest.approx(statistics.median(own), abs=1e-6)
+    only_behind = {**run, "spans": spans[:1] + [device(5.0, 38.0, "decode", waited=1)]}
+    assert chunk_ms is None or seen_ms(only_behind) is None
+
+
+@pytest.mark.parametrize("who", list(SEEN))
+def test_a_hop_that_waits_for_its_own_step_leaves_a_ride(who):
+    """`_ridden` is what a hop's thread does with a step no drain waits for:
+    the whole of it is the `ride` span under the hop's `compute`, and a ride
+    of /stats `executor`."""
+    from inferd_tpu.obs import trace as tracelib
+
+    model, _ = SEEN[who]
+    new = BlockSession if who == "block" else Session
+    ex = _executor(model)
+    ex.tracer = rec = tracelib.SpanRecorder("t")
+    s = new(ex, "s").run(hops=2)
+    step, lane = ex._last_step, _lane(ex, "s")
+    before = ex.stats()
+    compute = tracelib.SpanContext(tracelib.new_id(), tracelib.new_id(), keep=True)
+    token = tracelib.set_current(compute)
+    try:
+        reply = ex._ridden(step, lane)
+    finally:
+        tracelib.reset_current(token)
+    assert step.done and reply is step.reply(lane)
+    (ride,) = [x for x in rec.spans() if x["name"] == "ride"]
+    assert ride["parent"] == compute.span_id and ride["attrs"] == {"behind": step.behind}
+    assert 0 <= step.behind <= 1 and ride["span"] in {x["span"] for x in rec._kept}
+    dev = next(x for x in rec.spans() if x["name"] == "device" and x["parent"] == compute.span_id)
+    assert ride["t0"] <= dev["t1"] <= ride["t1"]  # the rider's own thread saw the step end
+    after = ex.stats()
+    assert after["rides"] - before["rides"] == 1
+    # the same stretch to a few microseconds: the count's ends where the span's last call begins
+    assert 0 < after["ride_ms_sum"] - before["ride_ms_sum"] <= (ride["t1"] - ride["t0"]) * 1e3 + 0.1
+    s.run()  # a step somebody else finished still feeds the next
+    assert s.out == new(_executor(model), "fresh", ahead=False).run().out
