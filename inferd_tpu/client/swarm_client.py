@@ -94,7 +94,11 @@ class SwarmClient(GenerationClient):
         from inferd_tpu.obs import trace as tracelib
 
         return tracelib.attach_wire({
-            "task_id": str(uuid.uuid4()),
+            # an opaque id, made without a system call: uuid4 reads the
+            # kernel's random source, and on a node's loop thread (the
+            # LocalClient's hops) every such call hands the GIL to whichever
+            # pool worker is awake (obs.trace.new_id, PERF.md section 6)
+            "task_id": tracelib.new_id(),
             "session_id": session_id,
             "stage": 0,
             "payload": {

@@ -160,6 +160,13 @@ class SwarmDHT:
         self.fanout = int(fanout)
         self.anti_entropy_every = max(1, int(anti_entropy_every))
         self._tick_n = 0
+        # the owner's hook, called before its own record is READ (merged
+        # into a local view, serialised for a peer): a node whose load
+        # moves many times between two reads rebuilds its record then,
+        # not at every move (runtime.node.Node._refresh_record). None =
+        # the record is whatever announce() last left (the simulator, the
+        # observers)
+        self.before_read: Optional[Callable[[], None]] = None
 
         self._records: Dict[str, Record] = {}  # owner -> record
         self._own_value: Dict[str, Any] = {}
@@ -222,8 +229,10 @@ class SwarmDHT:
         The only write path — a node can never clobber another's record.
         urgent=True gossips immediately (membership changes: join, migrate,
         withdraw); urgent=False only updates the local record and lets the
-        periodic gossip loop carry it (per-request load ticks — keeps
-        full-state serialization + UDP fan-out off the request hot path).
+        periodic gossip loop carry it (keeps full-state serialization +
+        UDP fan-out off the request hot path). What moves with every
+        request (a node's load) is not announced at all: the owner
+        rebuilds its record when it is about to be read (`before_read`).
 
         The version bumps only when the VALUE changes; re-announcing an
         identical payload is a liveness heartbeat (ts refresh) that peers
@@ -275,7 +284,22 @@ class SwarmDHT:
 
     # -- reads (local, already-merged) ---------------------------------
 
+    def _fresh_own(self) -> None:
+        """Let the owner bring its record up to date before a read. Not
+        before its first announce(), nor after withdraw(): a read must
+        neither create the record nor bring a tombstone back to life."""
+        if (
+            self.before_read is not None
+            and self.node_id in self._records
+            and not self._own_value.get("_tombstone")
+        ):
+            self.before_read()
+
     def alive_records(self) -> List[Record]:
+        self._fresh_own()
+        return self._alive()
+
+    def _alive(self) -> List[Record]:
         now = self._clock()
         out = []
         for r in self._records.values():
@@ -288,9 +312,11 @@ class SwarmDHT:
 
     def get_stage(self, stage: int) -> Dict[str, Dict[str, Any]]:
         """Reference schema view: {node_id: {"load": .., "cap": .., ...}}."""
+        if self._own_value.get("stage") == stage:
+            self._fresh_own()  # another stage's view never holds the own record
         return {
             r.owner: r.value
-            for r in self.alive_records()
+            for r in self._alive()
             if r.value.get("stage") == stage
         }
 
@@ -329,6 +355,9 @@ class SwarmDHT:
             log.warning("gossip send to %s failed: %s", addr, e)
 
     def _wire_records(self) -> List[Dict[str, Any]]:
+        """What every send serialises (push, HELLO answer, anti-entropy):
+        a peer receives the own record as it is AT the send."""
+        self._fresh_own()
         return [r.to_wire() for r in self._records.values()]
 
     def _prune(self) -> None:

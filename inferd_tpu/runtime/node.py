@@ -244,13 +244,27 @@ class NodeInfo:
 
 
 class TaskScheduler:
-    """Runs stage compute off the event loop; load = in-flight count."""
+    """Runs stage compute off the event loop; load = in-flight count.
 
-    def __init__(self, on_load_change, workers: int = 2):
-        self.inflight = 0
-        self._on_load_change = on_load_change
+    A hop that enters or leaves moves one integer and calls nobody: both
+    run on the one loop thread with no await between read and write, so
+    no lock is needed, and whoever is about to READ the load (the gossip
+    send, a local replica pick: Node._refresh_record) compares `ticks`
+    with what its record was built at."""
+
+    def __init__(self, workers: int = 2):
+        self.entered = 0  # hops handed to the pool
+        self.left = 0  # hops whose worker is done (or raised)
         self._pool = ThreadPoolExecutor(max_workers=workers, thread_name_prefix="stage")
-        self._lock = asyncio.Lock()
+
+    @property
+    def inflight(self) -> int:
+        return self.entered - self.left
+
+    @property
+    def ticks(self) -> int:
+        """Load changes seen: every hop is two."""
+        return self.entered + self.left
 
     async def run(self, fn, *args):
         return (await self.run_stamped(fn, *args))[0]
@@ -260,16 +274,12 @@ class TaskScheduler:
         again on the event loop once the worker was done: where the hop's
         `resume` span ends."""
         loop = asyncio.get_running_loop()
-        async with self._lock:
-            self.inflight += 1
-            self._on_load_change()
+        self.entered += 1
         try:
             out = await loop.run_in_executor(self._pool, fn, *args)
             return out, tracelib.now()
         finally:
-            async with self._lock:
-                self.inflight -= 1
-                self._on_load_change()
+            self.left += 1
 
     def shutdown(self) -> None:
         self._pool.shutdown(wait=False)
@@ -380,8 +390,8 @@ class Node:
         self._outlier_info: Optional[Dict[str, Any]] = None
         self._tsdb_task: Optional[asyncio.Task] = None
         self._windowed_cache: Tuple[float, Optional[Dict[str, float]]] = (0.0, None)
-        # SLO verdict + obs gossip fields, cached ~1 s (announce() runs
-        # per load change and /health may be polled aggressively)
+        # SLO verdict + obs gossip fields, cached ~1 s (a burst of urgent
+        # announces, and /health may be polled aggressively)
         self._health_cache: Tuple[float, Optional[Dict[str, Any]]] = (0.0, None)
         self.chaos = chaos
         self.enable_profiling = enable_profiling
@@ -564,9 +574,15 @@ class Node:
         # the batch window can never fill past the pool size
         lanes = mesh_slots if mesh_plan is not None else batch_lanes or stage_lanes
         self.scheduler = TaskScheduler(
-            self._announce_load,
             workers=max(2, lanes + 1) if lanes else 2,
         )
+        # the gossip record is rebuilt when somebody is about to read it
+        # (_refresh_record), not when the load moves: `scheduler.ticks`
+        # at the last build (-1: stale whatever the ticks say) and the
+        # records built (/stats `announce`)
+        self._record_tick = -1
+        self._record_builds = 0
+        dht.before_read = self._refresh_record
         self.balancer = Balancer(
             dht,
             info.num_stages,
@@ -1063,7 +1079,7 @@ class Node:
         gossiped: a replica that was slow an hour ago and recovered must
         stop reporting an elevated p99 within the window horizon, or
         routing and outlier detection act on history instead of now.
-        Cached ~1 s (announce() runs per load change); the inline
+        Cached ~1 s (announce() runs per session event); the inline
         sample() keeps the window current between telemetry ticks
         (mid-bucket samples merge idempotently). Keys are omitted when
         the window holds no observations — never backfilled from the
@@ -1116,8 +1132,10 @@ class Node:
                     self._update_gauges()
                 self.tsdb.sample()
                 if tick % 2 == 0:
-                    self._check_outlier()
+                    # announce first: the outlier check then reads the
+                    # record just built (a flip announces again, urgently)
                     self.announce(urgent=False)
+                    self._check_outlier()
             except Exception:
                 log.exception("telemetry tick failed")
 
@@ -1168,7 +1186,7 @@ class Node:
         """SLO verdict over this node's own registry + journal + gossiped
         peers, plus the obs gossip fields derived from the same snapshot
         (health column, hbm%, compile count for the dashboard). Cached
-        ~1 s: announce() runs per load change and must not re-evaluate
+        ~1 s: announce() runs per session event and must not re-evaluate
         the rule set (or re-scrape device memory) each time."""
         now = time.monotonic()
         ts, cached = self._health_cache
@@ -1253,6 +1271,14 @@ class Node:
         return round(free / total, 4) if total else None
 
     def announce(self, urgent: bool = True) -> None:
+        """Build this node's record and publish it: urgent (membership,
+        a session's arrival or departure, a flag a router acts on)
+        gossips at once; urgent=False leaves it to the next send. The
+        load a hop moves is NOT announced: see _refresh_record."""
+        # first, so that the reads this build makes itself (the health
+        # verdict's peers, an urgent send's serialisation) find it fresh
+        self._record_tick = self.scheduler.ticks
+        self._record_builds += 1
         sess = self._advertised_sessions()
         stand = self._advertised_standby()
         wq = self._windowed_gossip()
@@ -1263,13 +1289,13 @@ class Node:
         shedding = self._pool_under_reserve() is not None
         obs_gossip = (
             self._health_state()["gossip"]
-            if eventslib.enabled() and hasattr(self, "scheduler") else {}
+            if eventslib.enabled() else {}
         )
         self.dht.announce(
             {
                 "name": self.info.name,
                 "stage": self.info.stage,
-                "load": self.scheduler.inflight if hasattr(self, "scheduler") else 0,
+                "load": self.scheduler.inflight,
                 "cap": self.info.capacity,
                 "host": self.info.host,
                 "port": self.info.port,
@@ -1324,10 +1350,20 @@ class Node:
             urgent=urgent,
         )
 
-    def _announce_load(self) -> None:
-        # per-request load tick: update the local record only; the 1 s
-        # gossip loop carries it (keeps serialization + UDP off the hot path)
-        self.announce(urgent=False)
+    def _refresh_record(self) -> None:
+        """dht.before_read: the own record is about to be read (a gossip
+        send, a local `get_stage` / `get_all` that may rank this node by
+        its load). Rebuild it if a hop has entered or left the scheduler
+        since it was built, or _record_moved() marked it: a peer receives
+        the load AT the send, a local reader the exact `inflight`, and
+        the hops in between cost two integer updates."""
+        if self._record_tick != self.scheduler.ticks:
+            self.announce(urgent=False)
+
+    def _record_moved(self) -> None:
+        """Something the record advertises changed and no peer needs it
+        before the next gossip send: the next reader rebuilds it."""
+        self._record_tick = -1
 
     def _obs_file(self, suffix: str) -> Optional[str]:
         if not self.trace_dir:
@@ -1485,7 +1521,8 @@ class Node:
     ) -> Union[web.Response, Dict[str, Any]]:
         stage = int(env.get("stage", 0))
         session_id = env.get("session_id") or str(uuid.uuid4())
-        task_id = env.get("task_id") or str(uuid.uuid4())
+        # opaque on the wire; made without a system call (tracelib.new_id)
+        task_id = env.get("task_id") or tracelib.new_id()
         # end-to-end deadline riding the envelope (absent on deadline-less
         # traffic and from old peers — behavior is then identical to
         # before deadlines existed). An EXPIRED budget fast-fails with
@@ -2402,7 +2439,7 @@ class Node:
             had = session_id in self.standby
             self.standby.drop(session_id)
             if had:
-                self.announce(urgent=False)
+                self._record_moved()
             return web.Response(body=wire.pack({"ok": True, "length": 0}))
         if self._holds_session(session_id):
             # we SERVE this session (e.g. adopted it via drain handoff):
@@ -2434,7 +2471,7 @@ class Node:
             # the `standby` advert must reach peers before the primary
             # dies for the rescue path to find us — non-urgent: the 1 s
             # gossip loop carries it well inside the record TTL
-            self.announce(urgent=False)
+            self._record_moved()
         body = {"ok": ok, "length": have} if ok else {"ok": False, "have": have}
         return web.Response(body=wire.pack(body))
 
@@ -4627,7 +4664,7 @@ class Node:
                     except Exception:
                         pass  # holder unreachable: TTL sweep collects it
         self.executor.end_session(session_id)
-        self.announce(urgent=False)  # stop advertising the session's KV
+        self._record_moved()  # stop advertising the session's KV
         if self.replicator is not None:
             # EXPLICIT end: free the session's standby shadow now (fire-
             # and-forget) instead of letting a finished 8k-ctx session's
@@ -4877,6 +4914,11 @@ class Node:
                 "accept_rate": snap["counters"].get("spec.accepted", 0) / proposed,
             }
         snap["dht"] = {str(k): v for k, v in self.dht.get_all(self.info.num_stages).items()}
+        # the load tick's ledger: records built against load changes seen
+        # (a hop is two ticks and builds nothing: _refresh_record)
+        snap["announce"] = {
+            "builds": self._record_builds, "ticks": self.scheduler.ticks,
+        }
         # overload-containment state: drain flag + both budgets' ledgers
         # (the bench's hedge-extra-load and retry-amplification evidence)
         snap["overload"] = {

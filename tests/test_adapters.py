@@ -456,16 +456,16 @@ def test_kill_switch_no_registry_no_adapter_surfaces(base_params):
 def test_kill_switch_client_envelope_byte_identical(monkeypatch):
     """adapter=None leaves the /forward envelope byte-identical to the
     pre-adapter wire format (the PR 13/14 parity contract)."""
-    import uuid as uuidlib
-
     from inferd_tpu.client.swarm_client import SwarmClient
+    from inferd_tpu.obs import trace as tracelib
     from inferd_tpu.runtime import wire
 
     monkeypatch.setenv("INFERD_TRACE", "0")
-    monkeypatch.setattr(uuidlib, "uuid4", lambda: uuidlib.UUID(int=9))
+    # the envelope's opaque task id (no uuid4 since PR 54: obs.trace.new_id)
+    monkeypatch.setattr(tracelib, "new_id", lambda: "%016x" % 9)
     plain = SwarmClient([("h", 1)])._forward_env("s", [1, 2], 0)
     manual = {
-        "task_id": str(uuidlib.UUID(int=9)),
+        "task_id": "%016x" % 9,
         "session_id": "s", "stage": 0,
         "payload": {
             "tokens": np.asarray([[1, 2]], dtype=np.int32),

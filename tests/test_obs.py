@@ -107,14 +107,13 @@ def test_recorder_flush_jsonl_keeps_ring_live(tmp_path):
 def test_disabled_tracing_envelope_byte_identical(monkeypatch):
     """Acceptance: tracing disabled-by-config leaves the /forward envelope
     byte-identical to the untraced format."""
-    import uuid as uuidlib
-
     monkeypatch.setenv("INFERD_TRACE", "0")
-    monkeypatch.setattr(uuidlib, "uuid4", lambda: uuidlib.UUID(int=7))
+    # the envelope's opaque task id (no uuid4 since PR 54: trace.new_id)
+    monkeypatch.setattr(trace, "new_id", lambda: "%016x" % 7)
     env = SwarmClient([("127.0.0.1", 1)])._forward_env("sess", [1, 2, 3], 5)
     assert set(env) == {"task_id", "session_id", "stage", "payload"}
     manual = {
-        "task_id": str(uuidlib.UUID(int=7)),
+        "task_id": "%016x" % 7,
         "session_id": "sess",
         "stage": 0,
         "payload": {
