@@ -271,6 +271,46 @@ class ModelConfig:
     partial_rotary_factor: float = 1.0
     shared_expert_gate: bool = False
 
+    # The Xing4.0 family (DeepSeek-V3's layer under a changed residual path;
+    # all absent elsewhere):
+    #   q_lora_rank    — > 0: latent attention's queries are compressed too:
+    #                    c_q = RMSNorm(x W_qa) (this wide), q = c_q W_qb; the
+    #                    layer holds q_a_proj / q_a_norm / q_b_proj and no
+    #                    q_proj. 0 = one q_proj (DeepSeek-V2-Lite)
+    #   hc_mult        — n > 0: manifold-constrained hyper-connections
+    #                    (arXiv:2512.24880). The residual is a STREAM of n
+    #                    hidden states, [n, B, S, H]: the embedding enters as
+    #                    n copies, each sublayer reads one mix of them
+    #                    (Hpre, 1 x n), writes its output back by another
+    #                    (Hpost, 1 x n) while the stream itself is mixed by a
+    #                    doubly-stochastic Hres (n x n), and the n rows are
+    #                    summed before the final norm. The three maps are
+    #                    made per token from the RMS-normed stream, in
+    #                    float32 (models/qwen3.stream_read / stream_join).
+    #                    0 = one hidden state and the plain add
+    #   hc_sinkhorn_iters — rounds of column-then-row normalisation that make
+    #                    exp(H~res) doubly stochastic
+    #   hc_eps         — added to each of those rounds' denominators
+    #   hc_res_clamp   — H~res is clipped to +- this before the exp
+    #   seeded_routed_scale — read by the SEEDED draw alone (models/qwen3.
+    #                    init_params; a checkpoint's weights are what they
+    #                    are): a routed expert's down-projection is drawn at
+    #                    this times the other projections' deviation. At 1 a
+    #                    near-tie between the 4th and 5th of 64 scores that
+    #                    bf16 and float32 break differently swaps a quarter
+    #                    of a layer's routed output for an unrelated one (in
+    #                    a trained model near-tied experts are near-
+    #                    substitutes) and moves the log-probabilities by
+    #                    0.34 at a sixth of the positions, so that no limit
+    #                    told the sound program from its 8-bit control
+    #                    (PERF.md section 4)
+    q_lora_rank: int = 0
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
+    seeded_routed_scale: float = 1.0
+
     def __post_init__(self):
         if self.layer_types:
             odd = set(self.layer_types) - set(STATE_KINDS) - {"attention", "sliding", "global"}
@@ -313,6 +353,13 @@ class ModelConfig:
                 )
         if self.norm_placement not in ("before", "both", "after"):
             raise ValueError(f"{self.name}: unknown norm_placement {self.norm_placement!r}")
+        if self.q_lora_rank and not self.is_mla:
+            raise ValueError(f"{self.name}: q_lora_rank compresses latent attention's queries")
+        if self.hc_mult and (self.hc_mult < 2 or self.hc_sinkhorn_iters < 1):
+            raise ValueError(
+                f"{self.name}: a residual stream is hc_mult >= 2 hidden states wide and its "
+                "mixing matrix takes at least one Sinkhorn round"
+            )
         if self.qk_norm_flat and not self.qk_norm:
             raise ValueError(f"{self.name}: qk_norm_flat says which norm qk_norm is")
         if self.position_embedding not in ("rope", "nope"):
@@ -454,6 +501,11 @@ class ModelConfig:
     def linear_conv_dim(self) -> int:
         """Channels through the delta rule's convolution: q, k, then v."""
         return 2 * self.linear_key_dim + self.linear_value_dim
+
+    @property
+    def hc_maps(self) -> int:
+        """Outputs of a sublayer's stream projection: Hpre | Hpost | Hres."""
+        return self.hc_mult * (2 + self.hc_mult)
 
     @property
     def router_width(self) -> int:
@@ -822,7 +874,7 @@ SDAR_30B_A3B = dataclasses.replace(
 SDAR_30B_A3B_7L = dataclasses.replace(SDAR_30B_A3B.with_layers(7), name="sdar-30b-a3b-7l")
 
 # DeepSeek-V2-Lite (deepseek-ai/DeepSeek-V2-Lite config.json): latent
-# attention without query compression (q_lora_rank null), one dense layer,
+# attention without query compression (q_lora_rank null: 0 here), one dense layer,
 # then 26 layers of 64 routed experts (softmax over all, greedy top-6, not
 # renormalised) beside two shared ones, YaRN over the 64 rope dimensions.
 # The -8l preset is the same model cut to its first 8 layers (the dense one
@@ -859,6 +911,58 @@ DEEPSEEK_V2_LITE = ModelConfig(
 
 DEEPSEEK_V2_LITE_8L = dataclasses.replace(
     DEEPSEEK_V2_LITE.with_layers(8), name="deepseek-v2-lite-8l"
+)
+
+# Xing4.0-29B-A4B (XingChen-AGI/Xing4.0-29B-A4B config.json, `xing4_0`):
+# DeepSeek-V3's layer at 3584 wide (latent attention WITH query compression,
+# 32 heads of 128 + 64 roped, YaRN 64 x 4096 with mscale = mscale_all_dim = 1;
+# two dense layers, then 38 of 64 sigmoid-routed experts, top 4 by score +
+# selection bias, renormalised, x 2, beside one shared expert) under
+# manifold-constrained hyper-connections: a residual stream of four hidden
+# states. Its multi-token-prediction module is left out. The -6l preset is
+# the same model cut in depth to one dense and five sparse layers: what one
+# v5e chip holds with every expert and the whole vocabulary
+# (benchmark/configs/xing4.0-29b-a4b-1chip.json has the arithmetic).
+XING4_29B_A4B = ModelConfig(
+    name="xing4.0-29b-a4b",
+    vocab_size=131072,
+    hidden_size=3584,
+    intermediate_size=9216,
+    num_layers=40,
+    num_heads=32,
+    num_kv_heads=32,
+    head_dim=192,
+    rope_theta=10_000.0,
+    max_position_embeddings=262144,
+    tie_word_embeddings=False,
+    qk_norm=False,
+    rope_scaling="yarn",
+    rope_scaling_factor=64.0,
+    rope_original_max_position=4096,
+    rope_mscale=1.0,
+    rope_mscale_all_dim=1.0,
+    kv_lora_rank=512,
+    q_lora_rank=768,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    num_experts=64,
+    num_experts_per_tok=4,
+    moe_intermediate_size=1024,
+    moe_router_mode="sigmoid_topk",
+    norm_topk_prob=True,
+    routed_scaling_factor=2.0,
+    n_shared_experts=1,
+    first_k_dense_replace=2,
+    hc_mult=4,
+    hc_sinkhorn_iters=20,
+    hc_eps=1e-6,
+    hc_res_clamp=30.0,
+    seeded_routed_scale=0.125,
+)
+
+XING4_29B_A4B_6L = dataclasses.replace(
+    XING4_29B_A4B.with_layers(6), name="xing4.0-29b-a4b-6l", first_k_dense_replace=1,
 )
 
 # Granite-4.0-H-Micro (ibm-granite/granite-4.0-h-micro config.json,
@@ -1108,6 +1212,16 @@ TINY_DSV2 = dataclasses.replace(
     norm_topk_prob=False, n_shared_experts=2, first_k_dense_replace=1,
 )
 
+# tiny-xing4: the Xing4.0 layer at toy widths: a stream of four hidden states
+# of 64, queries compressed to 24, a dense layer then three of 8
+# sigmoid-routed experts (top 2, renormalised, x 2) beside one shared.
+TINY_XING4 = dataclasses.replace(
+    TINY_DSV2, name="tiny-xing4", rope_scaling_factor=64.0, rope_mscale=1.0,
+    rope_mscale_all_dim=1.0, q_lora_rank=24, moe_router_mode="sigmoid_topk",
+    norm_topk_prob=True, routed_scaling_factor=2.0, n_shared_experts=1,
+    hc_mult=4,
+)
+
 TINY_GRANITE_H = dataclasses.replace(
     TINY, name="tiny-granite-h", qk_norm=False, num_layers=8, rms_norm_eps=1e-5,
     query_pre_attn_scalar=256.0,  # 1/16 where head_dim 16 would give 1/4
@@ -1181,6 +1295,8 @@ PRESETS = {
         SDAR_30B_A3B_7L,
         DEEPSEEK_V2_LITE,
         DEEPSEEK_V2_LITE_8L,
+        XING4_29B_A4B,
+        XING4_29B_A4B_6L,
         GRANITE_4_H_MICRO,
         TRINITY_LARGE,
         TRINITY_LARGE_EP8_5L,
@@ -1197,6 +1313,7 @@ PRESETS = {
         TINY_GEMMA2,
         TINY_GPT_OSS,
         TINY_DSV2,
+        TINY_XING4,
         TINY_GRANITE_H,
         TINY_AFMOE,
         TINY_QWEN3_NEXT,
@@ -1225,6 +1342,7 @@ HF_REPOS = {
     "gpt-oss-20b": "openai/gpt-oss-20b",
     "gpt-oss-120b": "openai/gpt-oss-120b",
     "deepseek-v2-lite": "deepseek-ai/DeepSeek-V2-Lite",
+    "xing4.0-29b-a4b": "XingChen-AGI/Xing4.0-29B-A4B",
     "granite-4.0-h-micro": "ibm-granite/granite-4.0-h-micro",
     "qwen3-next-80b-a3b": "Qwen/Qwen3-Next-80B-A3B-Instruct",
     "olmo-hybrid-7b": "allenai/Olmo-Hybrid-7B",

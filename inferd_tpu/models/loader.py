@@ -215,13 +215,24 @@ def params_from_hf_state_dict(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params
     return params
 
 
+# a latent model's leaves that stay float32 whatever the model's dtype
+FLOAT32_LEAVES = ("router_select_bias", "hc_attn_bias", "hc_attn_scale", "hc_ffn_bias",
+                  "hc_ffn_scale")
+
+
 def _params_from_deepseek_v2(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:
-    """HF `deepseek_v2` names -> the grouped tree (`dense_layers` for the
-    leading dense layers, `layers` for the sparse ones). The checkpoint
-    stores each rope dimension pair interleaved (x0 y0 x1 y1 ...); the
-    program's `apply_rope` turns the half-split layout (x0 x1 ... y0 y1
-    ...), so the rope columns of q_proj and of kv_a_proj_with_mqa are
-    permuted here, once."""
+    """HF `deepseek_v2` / `deepseek_v3`-style names -> the grouped tree
+    (`dense_layers` for the leading dense layers, `layers` for the sparse
+    ones). The checkpoint stores each rope dimension pair interleaved (x0 y0
+    x1 y1 ...); the program's `apply_rope` turns the half-split layout (x0 x1
+    ... y0 y1 ...), so the rope columns of q_proj (with cfg.q_lora_rank: of
+    q_b_proj, beside q_a_proj and q_a_layernorm) and of kv_a_proj_with_mqa are
+    permuted here, once. A sigmoid router brings `mlp.gate.e_score_correction_bias`
+    (float32). A residual stream (cfg.hc_mult) brings a sublayer's maps as
+    `layers.{i}.hc_attn.*` / `hc_ffn.*`: `weight` [m (2 + m), m H] (rows pre |
+    post | res), `bias`, `scale` [3], the last two float32 (the names are this
+    loader's: no checkpoint of the family is described). Names the tree has
+    no place for (`mtp.*`, the multi-token-prediction module) are not read."""
     dt = cfg.jnp_dtype
     dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     halves = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
@@ -230,28 +241,42 @@ def _params_from_deepseek_v2(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:
         key = f"{name}.weight"
         return _to_np(sd[key if key in sd else f"model.{key}"])
 
+    def plain(key: str) -> np.ndarray:  # a tensor that is no module's `weight`
+        return _to_np(sd[key if key in sd else f"model.{key}"])
+
     def w(name: str) -> np.ndarray:  # a linear weight as [in, out]
         return raw(name).T
 
     def layer(i: int) -> Params:
         at, mlp = f"layers.{i}.self_attn", f"layers.{i}.mlp"
-        q = w(f"{at}.q_proj").reshape(cfg.hidden_size, cfg.num_heads, dn + dr)
+        q = w(f"{at}.q_b_proj" if cfg.q_lora_rank else f"{at}.q_proj")
+        q = q.reshape(q.shape[0], cfg.num_heads, dn + dr)
         q = np.concatenate([q[..., :dn], q[..., dn:][..., halves]], axis=-1)
+        q = q.reshape(q.shape[0], -1)
         kv_a = w(f"{at}.kv_a_proj_with_mqa")
         out = {
             "input_norm": raw(f"layers.{i}.input_layernorm"),
-            "q_proj": q.reshape(cfg.hidden_size, -1),
+            **({"q_a_proj": w(f"{at}.q_a_proj"), "q_a_norm": raw(f"{at}.q_a_layernorm"),
+                "q_b_proj": q} if cfg.q_lora_rank else {"q_proj": q}),
             "kv_a_proj": np.concatenate([kv_a[:, :r], kv_a[:, r:][:, halves]], axis=-1),
             "kv_a_norm": raw(f"{at}.kv_a_layernorm"),
             "kv_b_proj": w(f"{at}.kv_b_proj"),
             "o_proj": w(f"{at}.o_proj"),
             "post_norm": raw(f"layers.{i}.post_attention_layernorm"),
         }
+        for sub in ("attn", "ffn") if cfg.hc_mult else ():
+            hc = f"layers.{i}.hc_{sub}"
+            out[f"hc_{sub}_proj"] = raw(hc).reshape(cfg.hc_maps, cfg.hc_mult, cfg.hidden_size)
+            out[f"hc_{sub}_bias"] = plain(f"{hc}.bias").astype(np.float32)
+            out[f"hc_{sub}_scale"] = plain(f"{hc}.scale").astype(np.float32)
         if i < cfg.num_dense_layers:
             for proj in ("gate_proj", "up_proj", "down_proj"):
                 out[proj] = w(f"{mlp}.{proj}")
             return out
         out["router"] = w(f"{mlp}.gate")
+        if cfg.moe_router_mode == "sigmoid_topk":
+            out["router_select_bias"] = plain(
+                f"{mlp}.gate.e_score_correction_bias").astype(np.float32)
         for proj in ("gate_proj", "up_proj", "down_proj"):
             out[proj] = np.stack(
                 [w(f"{mlp}.experts.{e}.{proj}") for e in range(cfg.num_experts)])
@@ -260,7 +285,8 @@ def _params_from_deepseek_v2(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:
 
     def group(ids) -> Params:
         per_layer = [layer(i) for i in ids]
-        return {k: jnp.asarray(np.stack([lp[k] for lp in per_layer]), dtype=dt)
+        return {k: jnp.asarray(np.stack([lp[k] for lp in per_layer]),
+                               dtype=jnp.float32 if k in FLOAT32_LEAVES else dt)
                 for k in per_layer[0]}
 
     nd = cfg.num_dense_layers

@@ -70,6 +70,9 @@ def _init_ffn_params(cfg: ModelConfig, w, n: int, dense: bool, ks, key) -> Param
     p["gate_proj"] = w(ks[1], e, h, mi)
     p["up_proj"] = w(ks[2], e, h, mi)
     p["down_proj"] = w(ks[3], e, mi, h)
+    if cfg.seeded_routed_scale != 1.0:  # an expert that comes or goes at a near-tie moves little
+        p["down_proj"] = (
+            p["down_proj"].astype(jnp.float32) * cfg.seeded_routed_scale).astype(dt)
     if cfg.router_bias:
         p["router_bias"] = jnp.zeros((n, e), dtype=dt)
     if cfg.moe_bias:
@@ -118,11 +121,16 @@ def init_layer_params(
 
     p = {
         **_sublayer_norms(cfg, norm1((n, h), dtype=dt)),  # Gemma: around the MLP too; Olmo: outputs only
-        "q_proj": w(ks[0], h, q),
         "k_proj": w(ks[1], h, kv),
         "v_proj": w(ks[2], h, kv),
         "o_proj": w(ks[3], q, h),
     }
+    if cfg.q_lora_rank:  # latent attention's queries compressed too: two projections around a norm
+        p["q_a_proj"] = w(jax.random.fold_in(key, 16), h, cfg.q_lora_rank)
+        p["q_a_norm"] = jnp.ones((n, cfg.q_lora_rank), dtype=dt)
+        p["q_b_proj"] = w(jax.random.fold_in(key, 17), cfg.q_lora_rank, q)
+    else:
+        p["q_proj"] = w(ks[0], h, q)
     if cfg.qk_norm:  # Qwen3's per-head q/k RMSNorm; Olmo's over the whole projection
         p["q_norm"] = norm1((n, q if cfg.qk_norm_flat else d), dtype=dt)
         p["k_norm"] = norm1((n, kv if cfg.qk_norm_flat else d), dtype=dt)
@@ -144,8 +152,43 @@ def init_layer_params(
         p["kv_a_norm"] = jnp.ones((n, r), dtype=dt)
         p["kv_b_proj"] = w(jax.random.fold_in(key, 9), r, heads_out)
         p["o_proj"] = w(ks[3], cfg.num_heads * cfg.v_head_dim, h)
+    if cfg.hc_mult:
+        p.update(_init_stream_params(cfg, n, jax.random.fold_in(key, 18)))
     p.update(_init_ffn_params(cfg, w, n, dense, ks[4:8], key))
     return p
+
+
+# a layer's two sublayers, as the stream's maps name them
+STREAM_SUBLAYERS = ("attn", "ffn")
+
+
+def _init_stream_params(cfg: ModelConfig, n: int, key: jax.Array) -> Params:
+    """A layer stack's hyper-connection maps, one set a sublayer (`hc_attn_*`
+    before the mixer, `hc_ffn_*` before the feed-forward): `proj`
+    [n, m (2 + m), m, H] in the model's dtype, its rows Hpre (m) | Hpost (m) |
+    Hres (m x m, row after row), each over the stream of m = cfg.hc_mult
+    hidden states, one after the other; `bias` [n, m (2 + m)] and `scale` [n, 3] (a_pre, a_post,
+    a_res) in float32. Drawn so that the maps are neither dead nor saturated
+    at any width, and so that twenty Sinkhorn rounds reach a doubly-stochastic
+    Hres: the projection normal with deviation 2.4 / sqrt(m H) (0.02 at 4 x
+    3584), so that what it makes of the unit-RMS stream has deviation 2.4;
+    a_pre = a_post = 0.5 (the sigmoids' arguments spread over +- 1.2, Hpre
+    over about 0.1-0.9); a_res = 0.2 and the biases 0 but Hres's diagonal at
+    1: Hres keeps about 0.46 of a hidden state where it is and trades the
+    rest, differently a token (an entry off the diagonal 0.18 +- 0.07), and
+    its columns sum to one within 2e-5 (at a_res 0.5 and a diagonal of 2 one
+    token in a thousand was 4e-3 off after twenty rounds)."""
+    m, wide = cfg.hc_mult, cfg.hc_mult * cfg.hidden_size
+    bias = jnp.concatenate([jnp.zeros((2 * m,)), jnp.eye(m).reshape(-1)])
+    out = {}
+    for j, sub in enumerate(STREAM_SUBLAYERS):
+        proj = jax.random.normal(
+            jax.random.fold_in(key, j), (n, cfg.hc_maps, m, cfg.hidden_size), jnp.float32)
+        out[f"hc_{sub}_proj"] = (proj * (2.4 / math.sqrt(wide))).astype(cfg.jnp_dtype)
+        out[f"hc_{sub}_bias"] = jnp.broadcast_to(bias, (n, cfg.hc_maps)).astype(jnp.float32)
+        out[f"hc_{sub}_scale"] = jnp.broadcast_to(
+            jnp.asarray([0.5, 0.5, 0.2], jnp.float32), (n, 3))
+    return out
 
 
 def init_state_layer_params(cfg: ModelConfig, key: jax.Array, num_layers: int) -> Params:
@@ -1333,13 +1376,20 @@ def mla_attend(
 
 
 def _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx):
-    """Latent attention's side of decoder_layer: queries per head, ONE
+    """Latent attention's side of decoder_layer: queries per head (from x by
+    one projection, or with cfg.q_lora_rank by two around a norm), ONE
     latent and ONE roped key per token written at layer `at` of the stacked
     entries (core.cache.LatentEntry; None = no cache, the chunk attends to
     itself), attention over that layer -> (attn [B, S, N * Dv], entry')."""
     b, s, _h = x.shape
     dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
-    q = qdot(x, lp["q_proj"]).reshape(b, s, -1, dn + dr)
+    if "q_a_proj" in lp:  # cfg.q_lora_rank: the queries through a latent of their own
+        with jax.named_scope("mla_q_lora"):
+            c_q = rms_norm(x @ lp["q_a_proj"], lp["q_a_norm"], cfg.rms_norm_eps)
+            q = c_q @ lp["q_b_proj"]
+    else:
+        q = qdot(x, lp["q_proj"])
+    q = q.reshape(b, s, -1, dn + dr)
     q_nope, q_pe = q[..., :dn], apply_rope(q[..., dn:], cos, sin)
     kv_a = x @ lp["kv_a_proj"]
     c = rms_norm(kv_a[..., :r], lp["kv_a_norm"], cfg.rms_norm_eps)
@@ -1711,10 +1761,108 @@ def gated_delta_mixer(lp: Params, cfg: ModelConfig, x: jax.Array, entry, at, ctx
     return out, _state_leave(entry, at, ctx, s_new, kept, s_old, kept_old)
 
 
+# ---------------------------------------------------------------------------
+# The residual path: one hidden state and the plain add, or (cfg.hc_mult) a
+# stream of hidden states under manifold-constrained hyper-connections. ONE
+# pair of functions stands between decoder_layer and either
+# ---------------------------------------------------------------------------
+
+
+SINKHORN_TRIP = 5  # rounds a trip of the loop: a trip costs the device a few microseconds,
+#   an unrolled round costs the compiler some eighty operations a sublayer
+
+
+def sinkhorn(m, iters: int, eps: float):
+    """n rows of n positive arrays of one shape (a matrix a token, its
+    entries apart) -> the same, doubly stochastic: `iters` rounds of column
+    normalisation (every column divided by its sum over the rows + eps), then
+    row normalisation. float32. Every entry is an array of its own, so a
+    round is elementwise work between equal shapes and the rounds of a trip
+    fuse: no slice, no reduction, no axis of n for the compiler to lay out.
+    The rounds run as a loop of trips of SINKHORN_TRIP rounds each."""
+    n = len(m)
+    assert iters % SINKHORN_TRIP == 0, (iters, SINKHORN_TRIP)
+
+    def trip(_, m):
+        for _ in range(SINKHORN_TRIP):
+            cols = [sum(m[i][j] for i in range(n)) + eps for j in range(n)]
+            m = [[m[i][j] / cols[j] for j in range(n)] for i in range(n)]
+            rows = [sum(m[i]) + eps for i in range(n)]
+            m = tuple(tuple(m[i][j] / rows[i] for j in range(n)) for i in range(n))
+        return m
+
+    m = tuple(map(tuple, m))  # the loop's carry keeps ONE structure
+    return jax.lax.fori_loop(0, iters // SINKHORN_TRIP, trip, m)
+
+
+class StreamMaps(NamedTuple):
+    """A sublayer's three maps over the stream, a token each: float32 arrays
+    [B, S], one an entry."""
+
+    pre: tuple  # n: Hpre, what the sublayer reads of each hidden state
+    post: tuple  # n: Hpost, what each takes of the sublayer's output
+    res: tuple  # n rows of n: Hres, doubly stochastic; stream' = Hres stream
+
+
+def stream_maps(lp: Params, cfg: ModelConfig, stream: jax.Array, sub: str) -> StreamMaps:
+    """The maps of sublayer `sub` (STREAM_SUBLAYERS) from the stream
+    [n, B, S, H] itself: x' = RMSNorm over all n H values of a token (no
+    weight), H~ = scale * (x' proj) + bias, Hpre = sigmoid, Hpost = 2 sigmoid,
+    Hres = Sinkhorn(exp(clip(H~res))). All of it in float32 whatever the
+    stream is carried in; the norm's factor multiplies the 24 products, not
+    the 14 336 values."""
+    n, f32 = cfg.hc_mult, jnp.float32
+    with jax.named_scope("hc_map"):
+        xf = stream.astype(f32)
+        raw = jnp.einsum("nbsh,knh->kbs", xf, lp[f"hc_{sub}_proj"].astype(f32), precision=_HI)
+        ms = jnp.sum(xf * xf, axis=(0, 3)) / (n * cfg.hidden_size)  # [B, S]
+        scale, bias = lp[f"hc_{sub}_scale"], lp[f"hc_{sub}_bias"]
+        a_of = [0] * n + [1] * n + [2] * (n * n)  # which of a_pre, a_post, a_res a row takes
+        inv = jax.lax.rsqrt(ms + cfg.rms_norm_eps)
+        h = [raw[k] * inv * scale[a_of[k]] + bias[k] for k in range(cfg.hc_maps)]
+        pre = tuple(jax.nn.sigmoid(x) for x in h[:n])
+        post = tuple(2.0 * jax.nn.sigmoid(x) for x in h[n : 2 * n])
+    with jax.named_scope("hc_sinkhorn"):
+        lim = cfg.hc_res_clamp
+        m = [[jnp.exp(jnp.clip(h[2 * n + i * n + j], -lim, lim)) for j in range(n)]
+             for i in range(n)]
+        res = sinkhorn(m, cfg.hc_sinkhorn_iters, cfg.hc_eps)
+    return StreamMaps(pre, post, res)
+
+
+def stream_read(lp: Params, cfg: ModelConfig, hidden: jax.Array, sub: str):
+    """What sublayer `sub` reads of the residual -> (x [B, S, H], maps).
+    One hidden state: itself, and no maps. A stream [n, B, S, H]: Hpre's mix
+    of its n hidden states, and the maps that stream_join writes back by."""
+    if not cfg.hc_mult:
+        return hidden, None
+    maps = stream_maps(lp, cfg, hidden, sub)
+    with jax.named_scope("hc_read"):
+        x = sum(maps.pre[i][..., None] * hidden[i].astype(jnp.float32)
+                for i in range(cfg.hc_mult))
+    return x.astype(hidden.dtype), maps
+
+
+def stream_join(hidden: jax.Array, y: jax.Array, maps: Optional[StreamMaps]) -> jax.Array:
+    """A sublayer's output y [B, S, H] joins the residual: hidden + y, or for
+    a stream Hres stream + Hpost^T y (float32, carried on in the stream's
+    dtype)."""
+    if maps is None:
+        return hidden + y.astype(hidden.dtype)
+    with jax.named_scope("hc_join"):
+        n, yf = hidden.shape[0], y.astype(jnp.float32)
+        rows = [hidden[i].astype(jnp.float32) for i in range(n)]
+        return jnp.stack([
+            sum(maps.res[m][i][..., None] * rows[i] for i in range(n))
+            + maps.post[m][..., None] * yf
+            for m in range(n)
+        ]).astype(hidden.dtype)
+
+
 def decoder_layer(
     lp: Params,
     cfg: ModelConfig,
-    hidden: jax.Array,  # [B, S, H]
+    hidden: jax.Array,  # [B, S, H]; a stream of cfg.hc_mult of them [n, B, S, H]
     cos: jax.Array,
     sin: jax.Array,
     q_positions: jax.Array,  # [B, S]
@@ -1739,7 +1887,9 @@ def decoder_layer(
     cfg.norm_placement puts it: on its input (the pre-norm block), on its
     input and its output (Gemma's sandwich), or on its output alone (Olmo:
     h = x + Norm(Mixer(x)), y = h + Norm(MLP(h)), both reading the residual
-    stream as it is). The mixer is GQA + per-head q/k RMSNorm (the Qwen3
+    stream as it is). What a sublayer reads of the residual and how its output
+    joins it is stream_read / stream_join's: the plain add, or with
+    cfg.hc_mult the hyper-connection maps over a stream of hidden states. The mixer is GQA + per-head q/k RMSNorm (the Qwen3
     signature feature — reference qwen3_server_module.py:123-124; Olmo's norm
     over the whole projection), latent attention (cfg.is_mla), or for a layer
     of the state kind's stack a Mamba-2 block or the gated delta rule; the
@@ -1778,7 +1928,9 @@ def decoder_layer(
         with jax.named_scope("out_norm"):
             return rms_norm(y, w, cfg.rms_norm_eps, p1)
 
-    x = rms_norm(hidden, lp["input_norm"], cfg.rms_norm_eps, p1) if cfg.norm_before else hidden
+    x, maps = stream_read(lp, cfg, hidden, "attn")
+    if cfg.norm_before:
+        x = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps, p1)
     if "in_proj" in lp:  # a state layer: its stack holds no q / k / v
         if tp_axis or ep_axis or adapters is not None or not isinstance(
                 entry, (type(None), cachelib.StateEntry)):
@@ -1811,14 +1963,14 @@ def decoder_layer(
             attn_out = attn_out + lp["o_bias"]
     if cfg.norm_after:  # Gemma, Olmo: the mixer's output normed pre-residual
         attn_out = out_norm(attn_out, lp["post_norm"])
-    hidden = hidden + scaled(attn_out).astype(hidden.dtype)
+    hidden = stream_join(hidden, scaled(attn_out), maps)
 
     # the feed-forward: dense, or routed experts beside a shared one, whatever
     # the mixer above was
-    x = hidden
+    x, maps = stream_read(lp, cfg, hidden, "ffn")
     if cfg.norm_before:
         pre_ffn = lp["pre_ffn_norm"] if cfg.norm_after else lp["post_norm"]
-        x = rms_norm(hidden, pre_ffn, cfg.rms_norm_eps, p1)
+        x = rms_norm(x, pre_ffn, cfg.rms_norm_eps, p1)
     expert_axes = tuple(a for a in (ep_axis, tp_axis) if a is not None)
     topi = None
     if cfg.is_moe and "router" in lp:  # a leading dense layer has no router
@@ -1846,7 +1998,7 @@ def decoder_layer(
             mlp_out = jax.lax.psum(mlp_out, tp_axis)
     if cfg.norm_after:
         mlp_out = out_norm(mlp_out, lp["post_ffn_norm"])
-    return hidden + scaled(mlp_out).astype(hidden.dtype), entry, topi
+    return stream_join(hidden, scaled(mlp_out), maps), entry, topi
 
 
 # ---------------------------------------------------------------------------
@@ -2322,11 +2474,16 @@ def embed(params: Params, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
         e = e * jnp.asarray(math.sqrt(cfg.hidden_size), e.dtype)
     if cfg.embedding_multiplier != 1.0:  # Granite
         e = e * jnp.asarray(cfg.embedding_multiplier, e.dtype)
+    if cfg.hc_mult:  # the residual stream: the embedding enters as n copies [n, B, S, H]
+        e = jnp.broadcast_to(e, (cfg.hc_mult, *e.shape))
     return e
 
 
 def unembed(params: Params, cfg: ModelConfig, hidden: jax.Array) -> jax.Array:
-    """Final norm + LM head -> float32 logits (+ Gemma final softcapping)."""
+    """Final norm + LM head -> float32 logits (+ Gemma final softcapping);
+    a residual stream's hidden states are summed first."""
+    if cfg.hc_mult:
+        hidden = jnp.sum(hidden.astype(jnp.float32), axis=0).astype(hidden.dtype)
     x = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps, cfg.rms_norm_plus_one)
     if cfg.tie_word_embeddings:
         if "lm_head_q" in params:  # quantized shadow of embed.T (ops.quant)

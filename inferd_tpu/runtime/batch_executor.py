@@ -2004,6 +2004,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
                         ring_bytes_per_session=2 * int(self.engine.cache.k_loc.nbytes)
                         // self.engine.lanes,
                     )
+            if self.cfg.hc_mult:  # what a token's residual stream is between two sublayers
+                out["stream_bytes_per_token"] = (
+                    self.cfg.hc_mult * self.cfg.hidden_size * self.cfg.jnp_dtype.itemsize)
             if self._moe is not None:
                 out["moe"] = dict(self._moe, experts=self.cfg.router_width,
                                   experts_held=self.cfg.num_experts)

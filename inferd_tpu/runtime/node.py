@@ -4901,6 +4901,8 @@ class Node:
         # what a client has to know of the model to drive it: a model
         # generated by blocks is asked for blocks (client/base.py)
         snap["model"] = {"name": self.info.model_name, "block_length": self._block_length()}
+        if self.cfg.hc_mult:  # a residual stream of this many hidden states (absent: the plain add)
+            snap["model"]["stream_width"] = self.cfg.hc_mult
         if self.compile_cache is not None:
             snap["compile_cache"] = self.compile_cache.as_dict()
         snap["trace"] = self.tracer.stats()

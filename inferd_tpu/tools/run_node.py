@@ -409,7 +409,10 @@ def check_servable(cfg, args, num_stages: int = 1) -> None:
     part; --quant runs it too (ops/quant quantizes both weight stacks'
     projections, the held experts and the shared expert; a quantised expert
     weight takes the dense expert product), but where a dense group leads,
-    which the last table refuses."""
+    which the last table refuses. That table also keeps a model whose
+    residual is a stream of hidden states (cfg.hc_mult) off every path with a
+    boundary inside the model: a mesh tick, a stage's lanes, a relay's hop and
+    a self-draft all hand on one hidden state a token."""
     if cfg.nope_kinds or cfg.attn_gate or cfg.router_experts:
         _refuse(cfg, {
             "--mesh (a traced rank knows no layer's kind, no sharding rule names the "
@@ -459,11 +462,22 @@ def check_servable(cfg, args, num_stages: int = 1) -> None:
                 args.backend == "qwen3" and args.batch_lanes <= 0,
             "a manifest of several stages (a block step runs the whole model)": num_stages > 1,
         })
-    if not (cfg.is_mla or cfg.num_dense_layers):
+    if not (cfg.is_mla or cfg.num_dense_layers or cfg.hc_mult):
         return
+    # a residual stream of cfg.hc_mult hidden states lives inside one program:
+    # every boundary between two hands on ONE, [B, S, H]
+    stream = cfg.hc_mult > 0
     _refuse(cfg, {
         "--mesh (no latent cache or layer groups under a mesh)": args.mesh,
+        "--mesh (a tick hands the next rank one hidden state, the residual stream is "
+        f"{cfg.hc_mult})": stream and args.mesh,
         "--stage-lanes (a stage holds one group of layers)": args.stage_lanes > 0,
+        "--stage-lanes (a stage's lanes take and give one hidden state a token, not the "
+        f"stream's {cfg.hc_mult})": stream and args.stage_lanes > 0,
+        "a manifest of several stages (a relay's hop carries one hidden state a token, not "
+        f"the stream's {cfg.hc_mult})": stream and num_stages > 1,
+        "--spec-draft-layers (a draft over the first layers would read the head off a "
+        "stream that the layers left out still mix)": stream and args.spec_draft_layers > 0,
         "--paged-kv (the paged pool has no latent entry)": args.paged_kv > 0,
         "--quant (the latent projections and a leading dense group have no quantized form)":
             args.quant != "none",

@@ -5,7 +5,8 @@ two of `q3n-long-docs` (the prefill and the step the window runs; its
 preset keeps its own heads: 2 kv heads of 32 are one row a token, as the
 cell's 2 of 256 are) and the two of `olmoh-reason-chat` (PR 51; its preset
 keeps the published head sizes of its state, 96 x 192, held two heads side by
-side as the cell's are), lowered at the tiny presets, and (PR 49) the decode step
+side as the cell's are) and the two lane programs of `tiny-xing4` (PR 55: a stream of
+four hidden states around every sublayer), lowered at the tiny presets, and (PR 49) the decode step
 and the prefill of `q4b-*` once more over lanes of 1024 slots, where a slab
 is read by its prefix (models/qwen3.read_rungs: the 64-slot lanes of the
 sixteen are under that rule's floor and keep the text they had):
@@ -54,7 +55,8 @@ def texts() -> dict:
     for cell, model, lanes, widths in (
             ("q4b", "tiny", 5, (0, 8)), ("dsv2l", "tiny-dsv2", 16, (0, 8)), ("sdar", "tiny-sdar", 16, ()),
             ("g4hm", "tiny-granite-h", 4, (8,)), ("trinl", "tiny-afmoe", 16, (0,)),
-            ("q3n", "tiny-qwen3-next", 16, (0,)), ("olmoh", "tiny-olmo-hybrid", 16, (0,))):
+            ("q3n", "tiny-qwen3-next", 16, (0,)), ("olmoh", "tiny-olmo-hybrid", 16, (0,)),
+            ("xing", "tiny-xing4", 16, (0,))):
         cfg = cell_config(model)
         params = qwen3.init_params(cfg, jax.random.PRNGKey(0))
         eng = BatchedEngine(cfg, params, lanes=lanes, max_len=64)
@@ -100,7 +102,7 @@ NAMES = ("q4b.prefill", "q4b.decode.top0", "q4b.decode.top8", "dsv2l.prefill", "
          "dsv2l.decode.top8", "sdar.prefill", "sdar.block", "q8b-pp4.prefill", "q8b-pp4.decode.top0",
          "g4hm.prefill", "g4hm.decode.top8", "trinl.prefill", "trinl.decode.top0",
          "q3n.prefill", "q3n.decode.top0", "q4b.prefill.t1024", "q4b.decode.top0.t1024",
-         "olmoh.prefill", "olmoh.decode.top0")
+         "olmoh.prefill", "olmoh.decode.top0", "xing.prefill", "xing.decode.top0")
 
 
 def digests(found: dict) -> dict:

@@ -644,6 +644,46 @@ def test_olmo_hybrid_lane_programs_compile_with_the_state_unpadded_and_the_rows_
     assert mem.argument_size_in_bytes + pm.temp_size_in_bytes < 15.75e9 * 0.86
 
 
+def test_xing4_lane_programs_compile_with_the_stream_inside_and_the_latents_in_place(
+    one_chip, no_compile_cache, expert_kernel
+):
+    """The two programs a `--model xing4.0-29b-a4b-6l --batch-lanes 16
+    --max-len 16384` node runs, at the published widths: 9.585 GB of weights
+    (one dense and five sparse layers, all 64 experts, the whole vocabulary)
+    and 16 lanes of 16 384 latent slots, 1.812 GB (6 x 1 152 B a token). The
+    decode step (with its sampler, as the executor calls it) aliases the whole
+    donated cache, holds 0.43 GB of temporaries and is absorbed: nothing per
+    head over the 16 384 slots. A 512-token chunk attends the whole lane in
+    the expanded form: 1.33 GB of temporaries (the float32 scores
+    [1, 32, 512, 16384] are 1.07 of them), so weights, lanes and a chunk are
+    12.73 GB of the chip's 15.75. The residual stream stays inside both
+    programs: no argument or result is four hidden states wide, and the
+    Sinkhorn rounds are a loop of four trips. The numbers are the
+    configuration's `deployment`."""
+    import re
+
+    cfg = get_config("xing4.0-29b-a4b-6l")
+    shapes, step, prefill = _lane_programs(cfg, 16, 16384, one_chip, active=False)
+    assert shapes.k.shape == (6, 16, 16384, 512) and shapes.v.shape == (6, 16, 16384, 64)
+    assert shapes.nbytes == 1_811_939_328
+    mem = step.memory_analysis()
+    assert 11.39e9 < mem.argument_size_in_bytes < 11.41e9  # 9.585 GB of weights + 1.812 of latents
+    assert mem.alias_size_in_bytes >= shapes.nbytes
+    assert mem.temp_size_in_bytes < 0.6e9  # 0.43
+    pm = prefill.memory_analysis()
+    assert pm.alias_size_in_bytes >= shapes.nbytes
+    assert pm.temp_size_in_bytes < 1.6e9  # 1.33
+    assert mem.argument_size_in_bytes + pm.temp_size_in_bytes < 15.75e9 * 0.85
+    text = step.as_text()
+    over_cache = set(re.findall(r"(?:bf16|f32)\[[0-9,]*16384[0-9,]*\]", text))
+    assert "bf16[6,16,16384,512]" in over_cache and "bf16[6,16,16384,64]" in over_cache
+    per_head = [s for s in over_cache if re.search(r",16384,32,(192|128)\]|,32,16384,(192|128)\]", s)]
+    assert not per_head, per_head
+    signature = text[text.index("ENTRY"):].split("\n")[0]  # arguments and result
+    assert "bf16[4,16,1,3584]" not in signature and "bf16[4,16,3584]" not in signature
+    assert "hc_sinkhorn" in text and "while" in text
+
+
 def test_llama32_1b_lanes_keep_their_rows_where_they_lie(one_chip, no_compile_cache):
     """The other public model of this head size (8 kv heads of 64, 16
     layers), as `--model llama3.2-1b --batch-lanes 32 --max-len 4096` would

@@ -1034,7 +1034,10 @@ def test_a_cells_program_lowers_to_its_recorded_text(lowered_programs, name):
     the prefill over lanes of 1024 slots, where that rule engages. PR 51 left
     the eighteen as they were (a third norm placement, a flat q/k norm, beta
     to 2, a state held heads side by side and a two-pass update: each behind
-    a field that is absent by default) and added the two of `olmoh.*`. A PR that
+    a field that is absent by default) and added the two of `olmoh.*`. PR 55
+    left the twenty as they were (the residual's read / join pair is the plain
+    add where `hc_mult` is unset, and a compressed query sits behind
+    `q_lora_rank`) and added the two of `xing.*`. A PR that
     changes one of them on purpose runs `python tests/lowered_programs.py
     --record` and says so."""
     import json
