@@ -1028,39 +1028,54 @@ _READ_SLICE_BYTES = 64 * 2**20
 
 
 def read_rungs(
-    cfg: ModelConfig, t: int, q_len: int, batch: int, compressed_kv: bool, heads: bool
+    cfg: ModelConfig, stacks, q_len: int, batch: int, compressed_kv: bool
 ) -> Tuple[int, ...]:
-    """The static lengths a full layer's `t`-slot dense-lane slab may be read
-    to by `q_len` queries a row over `batch` rows (`compressed_kv`: the slab is
-    stored narrower than the activations; `heads`: with a head axis, not one
-    row a token), the last of them `t` (the whole slab): from static shapes
-    alone, so the program (_lanes_read) and its counter (`kv.slots_read`,
+    """The static lengths a full layer's slab of dense lanes may be read to by
+    `q_len` queries a row over `batch` rows, the last of them the whole slab.
+    `stacks`: the two stacked arrays [L, B, T, ...] the read is taken from (or
+    their shapes: keys and values per head or one row a token, a latent
+    cache's latents and roped keys), which say what the config cannot: the
+    slab's length and what a slot of each holds; `compressed_kv`: they are
+    stored narrower than the activations. From static shapes alone, so the
+    program (_lanes_read) and its counter (`kv.slots_read`,
     runtime/batch_executor) agree and nothing recompiles with the lengths.
-    One rung, `t`, where a rung would not be a whole number of tiles, and
+    One rung, the slab, where a rung would not be a whole number of tiles, and
     where the Pallas kernel is chosen (by the slab's length: its block loop is
-    bounded by the valid length already). Where the slice is made before the
-    dots (read_pinned), no rung whose slice is over _READ_SLICE_BYTES."""
-    if t % (_READ_RUNGS * _READ_TILE) or attention_ops.flash_enabled(
+    bounded by the valid length already). Where a slice is made before the
+    dots (read_pinned), no rung at which it is over _READ_SLICE_BYTES."""
+    t = stacks[0].shape[2]
+    step = read_step(t)
+    if not step or attention_ops.flash_enabled(
             cfg, t, compressed_kv=compressed_kv, q_len=q_len, batch=batch):
         return (t,)
-    step = t // _READ_RUNGS
-    longest = t
-    if read_pinned(heads, q_len):
-        longest = _READ_SLICE_BYTES // (batch * cfg.kv_dim * cfg.kv_jnp_dtype.itemsize)
+    # what a slot of a pinned stack holds: its trailing widths in its own dtype
+    pinned = [math.prod(stack.shape[3:]) * jnp.dtype(stack.dtype).itemsize
+              for stack in stacks if read_pinned(stack, q_len)]
+    longest = _READ_SLICE_BYTES // (batch * max(pinned)) if pinned else t
     return tuple(r for r in range(step, t, step) if r <= longest) + (t,)
 
 
-def read_pinned(heads: bool, q_len: int) -> bool:
-    """Whether a rung's slice keeps the stack's own row-major layout, and so
-    is made before the dots (_lanes_read). Where a dot asks another layout of
-    its keys (heads before slots of a stack with a head axis; slots innermost
-    for a chunk's weighted sum over rows) that layout otherwise runs back
-    through the slice to the branch's parameter, and every branch copies the
-    WHOLE stack (described-v5e compiles: 1.51 GB of temporaries in
-    `q4b-sat-chat`'s step where pinned has 0.002; a `copy` of
-    `bf16[4,1,4096,512]{2,3,1,0}` in granite's chunk). A decode step over
-    rows takes them as they lie, and unpinned its slice fuses into the dots."""
-    return heads or q_len > 1
+def read_step(t: int) -> int:
+    """What two neighbouring rungs of a `t`-slot slab's ladder lie apart, 0
+    where it has none: a rung would not be a whole number of tiles."""
+    return 0 if t % (_READ_RUNGS * _READ_TILE) else t // _READ_RUNGS
+
+
+def read_pinned(stack, q_len: int) -> bool:
+    """Whether a rung's slice of `stack` [L, B, T, ...] keeps the stack's own
+    row-major layout, and so is made before the dots (_lanes_read). Where a
+    dot asks another layout of its keys (heads before slots of a stack with a
+    head axis; slots innermost for a chunk's weighted sum over rows, and for
+    every product with a row narrower than a tile's 128 lanes: a latent
+    cache's roped keys) that layout otherwise runs back through the slice to
+    the branch's parameter, and every branch copies the WHOLE stack
+    (described-v5e compiles: 1.51 GB of temporaries in `q4b-sat-chat`'s step
+    where pinned has 0.002; a `copy` of `bf16[4,1,4096,512]{2,3,1,0}` in
+    granite's chunk; sixteen of `bf16[6,16,16384,64]{2,3,1,0}` in
+    `xing-latent-docs`' step, 0.500 GB where pinned has 0.415). A decode step
+    over rows as wide as a tile takes them as they lie, and unpinned its slice
+    fuses into the dots."""
+    return stack.ndim == 5 or q_len > 1 or stack.shape[-1] < cachelib.TILE_LANES
 
 
 def read_rung(longest, rungs: Tuple[int, ...]):
@@ -1073,7 +1088,8 @@ def read_rung(longest, rungs: Tuple[int, ...]):
 
 def _lanes_read(cfg, k_stack, v_stack, at, ctx, q, window, attend):
     """What the chunk of queries `q` [B, S, ...] reads of layer `at` of the
-    stacked dense-lane slabs [L, B, T, ...] (either layout: T is axis 2),
+    stacked dense-lane slabs [L, B, T, ...] (any layout: T is axis 2; keys and
+    values, or a latent cache's latents and roped keys),
     handed to `attend(k, v, kv_positions, valid length, window, flash)` -> its
     result; `flash`: whether the Pallas kernel is chosen, by the length of the
     buffer the read is taken from (the slab's, or the window's slice). A STATIC
@@ -1093,8 +1109,7 @@ def _lanes_read(cfg, k_stack, v_stack, at, ctx, q, window, attend):
     s, t = q.shape[1], k_stack.shape[2]
     shapes = dict(compressed_kv=k_stack.dtype != q.dtype, q_len=s, batch=q.shape[0])
     windowed = isinstance(window, int) and window > 0
-    heads = k_stack.ndim == 5
-    rungs = read_rungs(cfg, t, heads=heads, **shapes)
+    rungs = read_rungs(cfg, (k_stack, v_stack), **shapes)
     by_prefix = not windowed and len(rungs) > 1
     if not by_prefix:
         k_slab, v_slab = _slab(k_stack, at), _slab(v_stack, at)
@@ -1118,9 +1133,10 @@ def _lanes_read(cfg, k_stack, v_stack, at, ctx, q, window, attend):
                 stack, (at,) + (0,) * (stack.ndim - 1),
                 (1, stack.shape[1], rung) + stack.shape[3:])[0]
             k_att, v_att = head(ks), head(vs)
-            if read_pinned(heads, s):
-                row_major = Layout(major_to_minor=tuple(range(k_att.ndim)))
+            row_major = Layout(major_to_minor=tuple(range(k_att.ndim)))
+            if read_pinned(ks, s):
                 k_att = with_layout_constraint(k_att, row_major)
+            if read_pinned(vs, s):
                 v_att = with_layout_constraint(v_att, row_major)
             return attend(k_att, v_att, None, end, window, False)
         return read
@@ -1340,8 +1356,10 @@ def mla_attend(
     kv_valid_len,  # scalar or [B]
     kv_positions: Optional[jax.Array] = None,
     absorbed: bool = False,
+    sum_step: int = 0,
 ) -> jax.Array:
-    """Latent attention -> [B, S, N * Dv]; softmax in float32.
+    """Latent attention -> [B, S, N * Dv]; softmax in float32 (with
+    `sum_step` its sum is taken `sum_step` slots at a time: _softmax_by_steps).
 
     Expanded: keys and values per head are made from the latents
     (k_nope_i, v_i = c W_kvb,i) and attended as ordinary heads. Absorbed:
@@ -1366,7 +1384,8 @@ def mla_attend(
         scores = jnp.einsum("bsnd,btnd->bnst", q_nope, k_nope)
     scores = (scores.astype(jnp.float32) + rope_scores.astype(jnp.float32)) * cfg.attn_scale
     scores = jnp.where(mask[:, None], scores, jnp.float32(-1e30))
-    probs = jax.nn.softmax(scores, axis=-1).astype(q_nope.dtype)
+    probs = _softmax_by_steps(scores, sum_step) if sum_step else jax.nn.softmax(scores, axis=-1)
+    probs = probs.astype(q_nope.dtype)
     if absorbed:
         o_lat = jnp.einsum("bnst,btr->bsnr", probs, c)
         out = jnp.einsum("bsnr,rnd->bsnd", o_lat, w_uv)
@@ -1375,12 +1394,33 @@ def mla_attend(
     return out.reshape(b, s, n * dv)
 
 
+def _softmax_by_steps(scores: jax.Array, step: int) -> jax.Array:
+    """jax.nn.softmax over the last axis (a whole number of `step`s), its SUM
+    taken step by step and the steps' sums added in slot order. A step masked
+    whole adds an exact zero, so a row gives the same bits at every length
+    that covers its valid slots: where several rows share one read, whose
+    length the LONGEST sets (_lanes_read), a session's tokens must not turn on
+    who else is on the chip. One reduction over the axis splits it as its
+    length suggests (my chip run, PR 56: the sums of `xing-latent-docs`' step
+    over 6144 and over 8192, 12288 or 16384 slots differ in their last bits;
+    the scores and both products over the slots do not), and the cell's
+    16-lane probe then answered other tokens beside sessions than alone."""
+    e = jnp.exp(scores - scores.max(-1, keepdims=True))
+    sums = e.reshape(*e.shape[:-1], -1, step).sum(-1)
+    total = sums[..., 0]
+    for i in range(1, sums.shape[-1]):
+        total = total + sums[..., i]
+    return e / total[..., None]
+
+
 def _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx):
     """Latent attention's side of decoder_layer: queries per head (from x by
     one projection, or with cfg.q_lora_rank by two around a norm), ONE
     latent and ONE roped key per token written at layer `at` of the stacked
     entries (core.cache.LatentEntry; None = no cache, the chunk attends to
-    itself), attention over that layer -> (attn [B, S, N * Dv], entry')."""
+    itself), attention over that layer's lanes (what of them: _lanes_read;
+    absorbed for one query a row, expanded for a chunk) ->
+    (attn [B, S, N * Dv], entry')."""
     b, s, _h = x.shape
     dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     if "q_a_proj" in lp:  # cfg.q_lora_rank: the queries through a latent of their own
@@ -1405,12 +1445,13 @@ def _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx):
         c=_lanes_write(entry.c, at, c, ctx.write_pos, ctx.write_mask),
         r=_lanes_write(entry.r, at, k_pe, ctx.write_pos, ctx.write_mask),
     )
+    # rows that share a read: the longest sets its length, no row's bits may turn on it
+    sum_step = read_step(entry.c.shape[2]) if b > 1 else 0
+    attend = lambda c_att, r_att, kvpos, valid, win, flash: mla_attend(
+        cfg, q_nope, q_pe, c_att, r_att, lp["kv_b_proj"], q_positions, valid,
+        kv_positions=kvpos, absorbed=s == 1, sum_step=sum_step)
     with jax.named_scope("mla_attend"):
-        attn = mla_attend(
-            cfg, q_nope, q_pe, _slab(new.c, at), _slab(new.r, at), lp["kv_b_proj"],
-            q_positions, ctx.write_pos + s, absorbed=s == 1,
-        )
-    return attn, new
+        return _lanes_read(cfg, new.c, new.r, at, ctx, q_nope, None, attend), new
 
 
 # ---------------------------------------------------------------------------

@@ -813,6 +813,10 @@ def flash_enabled(
         # a model generated by blocks attends to the end of the query's
         # block (models/qwen3.visible_until), which only the XLA path masks
         return False
+    if getattr(cfg, "is_mla", False):
+        # no kernel serves latent attention: models/qwen3.mla_attend is the
+        # XLA path in both its forms and never asks
+        return False
     if FORCE_FLASH is not None:
         return FORCE_FLASH
     impl = getattr(cfg, "attn_impl", "auto")

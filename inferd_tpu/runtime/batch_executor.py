@@ -212,11 +212,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
         )
         # what the programs read of the dense lanes' slabs (/stats `executor`
         # `kv.slots_read` / `kv.slots_held`, _count_read); None where no
-        # program reads a slab by its prefix (a paged pool, a latent cache)
-        self._read = (
-            dict(slots_read=0, slots_held=0)
-            if self.pool is None and not cfg.is_mla else None
-        )
+        # program reads a slab by its prefix (a paged pool)
+        self._read = dict(slots_read=0, slots_held=0) if self.pool is None else None
         # a model generated by blocks (/stats `executor` `diffusion.*`):
         # dispatches, live lanes x passes, places made known (those the
         # caller filled are not counted), rows computed, block hops answered
@@ -1443,9 +1440,9 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
         slice and is not counted; K-step and speculative dispatches are not."""
         if self._read is None:
             return
+        cache = self.engine.cache
         rungs = qwen3.read_rungs(
-            self.cfg, self.max_len, q_len, rows, self.cfg.kv_jnp_dtype != self.cfg.jnp_dtype,
-            heads=self.engine.cache.k.ndim == 5)
+            self.cfg, (cache.k, cache.v), q_len, rows, self.cfg.kv_jnp_dtype != self.cfg.jnp_dtype)
         self._read["slots_read"] += passes * rows * rungs[int(qwen3.read_rung(longest, rungs))]
         self._read["slots_held"] += passes * rows * self.max_len
 

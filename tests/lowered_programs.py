@@ -7,9 +7,9 @@ cell's 2 of 256 are) and the two of `olmoh-reason-chat` (PR 51; its preset
 keeps the published head sizes of its state, 96 x 192, held two heads side by
 side as the cell's are) and the two lane programs of `tiny-xing4` (PR 55: a stream of
 four hidden states around every sublayer), lowered at the tiny presets, and (PR 49) the decode step
-and the prefill of `q4b-*` once more over lanes of 1024 slots, where a slab
-is read by its prefix (models/qwen3.read_rungs: the 64-slot lanes of the
-sixteen are under that rule's floor and keep the text they had):
+and the prefill of `q4b-*`, (PR 56) of `dsv2l-*` and of `xing-*` once more over lanes of 1024 slots,
+where a slab (a latent lane too) is read by its prefix (models/qwen3.read_rungs: the 64-slot lanes of
+the others are under that rule's floor and keep the text they had):
 `texts()` gives their StableHLO text by name. What the text holds is the traced
 program; sizes are not the point: a change that leaves these configurations
 alone leaves every byte alone. ONE width is the point: the cells' heads are as
@@ -76,15 +76,17 @@ def texts() -> dict:
         for top_n in widths:
             out[f"{cell}.decode.top{top_n}"] = eng._decode_logits.lower(
                 eng.params, eng.cache, toks, toks, ask=ask, top_n=top_n, **mask).as_text()
-    # the two programs of `q4b-*` over lanes long enough for the read by prefix
-    cfg = cell_config("tiny")
-    eng = BatchedEngine(cfg, qwen3.init_params(cfg, jax.random.PRNGKey(0)), lanes=5, max_len=1024)
-    toks = jnp.zeros((5,), jnp.int32)
-    ask = samplib.RowAsk(jnp.zeros((5, 2), jnp.uint32), jnp.zeros((5, 4), jnp.float32))
-    out["q4b.prefill.t1024"] = eng._prefill_lane_logits.lower(
-        eng.params, eng.cache, jnp.zeros((1, 32), jnp.int32), i32, i32, i32).as_text()
-    out["q4b.decode.top0.t1024"] = eng._decode_logits.lower(
-        eng.params, eng.cache, toks, toks, ask=ask, top_n=0).as_text()
+    # the two programs of `q4b-*`, and (PR 56) of the two latent cells, over
+    # lanes long enough for the read by prefix
+    for cell, model, lanes in (("q4b", "tiny", 5), ("dsv2l", "tiny-dsv2", 16), ("xing", "tiny-xing4", 16)):
+        cfg = cell_config(model)
+        eng = BatchedEngine(cfg, qwen3.init_params(cfg, jax.random.PRNGKey(0)), lanes=lanes, max_len=1024)
+        toks = jnp.zeros((lanes,), jnp.int32)
+        ask = samplib.RowAsk(jnp.zeros((lanes, 2), jnp.uint32), jnp.zeros((lanes, 4), jnp.float32))
+        out[f"{cell}.prefill.t1024"] = eng._prefill_lane_logits.lower(
+            eng.params, eng.cache, jnp.zeros((1, 32), jnp.int32), i32, i32, i32).as_text()
+        out[f"{cell}.decode.top0.t1024"] = eng._decode_logits.lower(
+            eng.params, eng.cache, toks, toks, ask=ask, top_n=0).as_text()
     cfg = cell_config("tiny")
     mesh = meshlib.make_mesh(meshlib.MeshPlan(pp=4), jax.devices()[:4])
     eng = PipelinedEngine(cfg, qwen3.init_params(cfg, jax.random.PRNGKey(0)), mesh,
@@ -102,7 +104,8 @@ NAMES = ("q4b.prefill", "q4b.decode.top0", "q4b.decode.top8", "dsv2l.prefill", "
          "dsv2l.decode.top8", "sdar.prefill", "sdar.block", "q8b-pp4.prefill", "q8b-pp4.decode.top0",
          "g4hm.prefill", "g4hm.decode.top8", "trinl.prefill", "trinl.decode.top0",
          "q3n.prefill", "q3n.decode.top0", "q4b.prefill.t1024", "q4b.decode.top0.t1024",
-         "olmoh.prefill", "olmoh.decode.top0", "xing.prefill", "xing.decode.top0")
+         "olmoh.prefill", "olmoh.decode.top0", "xing.prefill", "xing.decode.top0",
+         "dsv2l.prefill.t1024", "dsv2l.decode.top0.t1024", "xing.prefill.t1024", "xing.decode.top0.t1024")
 
 
 def digests(found: dict) -> dict:

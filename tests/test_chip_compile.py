@@ -311,7 +311,8 @@ def test_q4b_step_and_chunk_read_their_slabs_by_prefix_and_copy_none(one_chip, n
 
     cfg = get_config("qwen3-4b")
     lanes, max_len = 5, 4096
-    rungs = qwen3.read_rungs(cfg, max_len, 1, lanes, False, heads=True)
+    stack = jax.ShapeDtypeStruct((cfg.num_layers, lanes, max_len, cfg.num_kv_heads, cfg.head_dim), BF16)
+    rungs = qwen3.read_rungs(cfg, (stack, stack), 1, lanes, False)
     assert rungs == tuple(range(512, 4097, 512))
     params = _on(jax.eval_shape(lambda: qwen3.init_params(cfg, jax.random.PRNGKey(0))), one_chip)
     eng = BatchedEngine(cfg, None, lanes=lanes, max_len=64)
@@ -363,15 +364,20 @@ def test_dsv2l_lane_programs_compile_and_decode_expands_no_head_over_the_cache(
     # second copy of the 0.60 GB of lanes
     assert mem.temp_size_in_bytes < 0.25e9
     assert mem.alias_size_in_bytes > 0.6e9
-    over_cache = set(re.findall(r"(?:bf16|f32)\[[0-9,]*4096[0-9,]*\]", decode.as_text()))
+    # over any rung of the lanes (PR 56: eighths of 4096), as over the lanes themselves
+    rungs = "|".join(str(r) for r in range(512, 4097, 512))
+    over_cache = set(re.findall(rf"(?:bf16|f32)\[(?:[0-9]+,)*(?:{rungs})(?:,[0-9]+)*\]", decode.as_text()))
     assert "bf16[8,16,4096,512]" in over_cache and "bf16[8,16,4096,64]" in over_cache
-    per_head = [s for s in over_cache
-                if re.search(r"\b16,(192|128)\]|,16,4096,(192|128)\]", s)]
+    per_head = [s for s in over_cache  # [lanes, slots, heads, a head's width], or heads before slots
+                if re.search(r"\b16,[0-9]+,16,(192|128)\]|\b16,16,[0-9]+,(192|128)\]", s)]
     assert not per_head, per_head
     i32 = _sds((), jnp.int32, one_chip)
     chunk = _sds((1, 512), jnp.int32, one_chip)
     prefill = eng._prefill_lane_logits.lower(params, cache, chunk, i32, i32, i32).compile()
-    assert prefill.memory_analysis().temp_size_in_bytes < 0.25e9  # 0.15 GB
+    assert prefill.memory_analysis().temp_size_in_bytes < 0.25e9  # 0.18 GB (0.15 with the lane read whole)
+    # both read a lane by its prefix: the step's temporaries are what they were (0.14 GB)
+    assert mem.temp_size_in_bytes < 0.15e9
+    _assert_latent_lanes_read_by_prefix(cfg, decode, prefill, 8, lanes, max_len, r_copies=2)
 
 
 @pytest.mark.parametrize("model, lanes", [("qwen3-4b", 5), ("deepseek-v2-lite-8l", 16)],
@@ -476,6 +482,31 @@ def _lane_programs(cfg, lanes, max_len, one_chip, active):
     chunk = _sds((1, 512), jnp.int32, one_chip)
     prefill = eng._prefill_lane_logits.lower(params, cache, chunk, i32, i32, i32).compile()
     return shapes, step, prefill
+
+
+def _assert_latent_lanes_read_by_prefix(cfg, step, prefill, layers, lanes, max_len, r_copies):
+    """What models/qwen3._lanes_read promises of a latent cell's two programs
+    at the cell's own shapes: the conditional with a branch a rung (a slice
+    [1, rows, rung, R] and [1, rows, rung, Dr] of each stack at every eighth
+    of the lane), no copy of the latents' stack anywhere (all 16 lanes' in the
+    step, the one lane's in the chunk: the parent's chunk made two of its
+    lane's), and of the roped keys' stack (64 columns: the chip lays it
+    slots-minor, its rows are re-laid for the scatter and back) `r_copies` of
+    all lanes' in the step at most, and two of the one lane's in the chunk:
+    once in, once out, none a branch."""
+    import re
+
+    r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    rungs = tuple(range(max_len // 8, max_len + 1, max_len // 8))
+    for program, rows, most in ((step, lanes, r_copies), (prefill, 1, 2)):
+        text = program.as_text()
+        assert " conditional(" in text
+        for rung in rungs:
+            assert f"dynamic_slice_sizes={{1,{rows},{rung},{r}}}" in text
+            assert f"dynamic_slice_sizes={{1,{rows},{rung},{dr}}}" in text
+        copies = re.findall(r"= (\S+?)\{[^ ]* copy\(", text)
+        assert f"bf16[{layers},{rows},{max_len},{r}]" not in copies
+        assert copies.count(f"bf16[{layers},{rows},{max_len},{dr}]") <= most
 
 
 def _made_whole(text, *shapes):
@@ -669,16 +700,20 @@ def test_xing4_lane_programs_compile_with_the_stream_inside_and_the_latents_in_p
     mem = step.memory_analysis()
     assert 11.39e9 < mem.argument_size_in_bytes < 11.41e9  # 9.585 GB of weights + 1.812 of latents
     assert mem.alias_size_in_bytes >= shapes.nbytes
-    assert mem.temp_size_in_bytes < 0.6e9  # 0.43
+    assert mem.temp_size_in_bytes < 0.44e9  # 0.415 (0.429 with the lanes read whole)
     pm = prefill.memory_analysis()
     assert pm.alias_size_in_bytes >= shapes.nbytes
-    assert pm.temp_size_in_bytes < 1.6e9  # 1.33
+    assert pm.temp_size_in_bytes < 1.45e9  # 1.40, the top branch's (1.335 with the lane read whole)
     assert mem.argument_size_in_bytes + pm.temp_size_in_bytes < 15.75e9 * 0.85
     text = step.as_text()
-    over_cache = set(re.findall(r"(?:bf16|f32)\[[0-9,]*16384[0-9,]*\]", text))
+    # over any rung of the lanes (PR 56: eighths of 16 384), as over the lanes themselves
+    rungs = "|".join(str(r) for r in range(2048, 16385, 2048))
+    over_cache = set(re.findall(rf"(?:bf16|f32)\[(?:[0-9]+,)*(?:{rungs})(?:,[0-9]+)*\]", text))
     assert "bf16[6,16,16384,512]" in over_cache and "bf16[6,16,16384,64]" in over_cache
-    per_head = [s for s in over_cache if re.search(r",16384,32,(192|128)\]|,32,16384,(192|128)\]", s)]
+    per_head = [s for s in over_cache
+                if re.search(rf",(?:{rungs}),32,(192|128)\]|,32,(?:{rungs}),(192|128)\]", s)]
     assert not per_head, per_head
+    _assert_latent_lanes_read_by_prefix(cfg, step, prefill, 6, 16, 16384, r_copies=2)
     signature = text[text.index("ENTRY"):].split("\n")[0]  # arguments and result
     assert "bf16[4,16,1,3584]" not in signature and "bf16[4,16,3584]" not in signature
     assert "hc_sinkhorn" in text and "while" in text

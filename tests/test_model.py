@@ -1037,7 +1037,10 @@ def test_a_cells_program_lowers_to_its_recorded_text(lowered_programs, name):
     a field that is absent by default) and added the two of `olmoh.*`. PR 55
     left the twenty as they were (the residual's read / join pair is the plain
     add where `hc_mult` is unset, and a compressed query sits behind
-    `q_lora_rank`) and added the two of `xing.*`. A PR that
+    `q_lora_rank`) and added the two of `xing.*`. PR 56 left the twenty-two
+    as they were (a 64-slot latent lane is under the floor too, and the dense
+    callers of `_lanes_read` hand it what they did) and added `dsv2l.*.t1024`
+    and `xing.*.t1024`, where a latent lane is read by its prefix. A PR that
     changes one of them on purpose runs `python tests/lowered_programs.py
     --record` and says so."""
     import json

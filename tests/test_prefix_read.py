@@ -1,5 +1,6 @@
 # jaxlint: file-disable=J003 -- test code: loops here sync per-iteration to ASSERT on values
-"""The read rule of dense lanes (models/qwen3._lanes_read): a layer's slab is
+"""The read rule of dense lanes (models/qwen3._lanes_read; keys and values per
+head, as rows, or a latent cache's latents and roped keys): a layer's slab is
 read to the shortest rung of `read_rungs(T)` that covers the longest row's
 valid length, chosen inside the program, against the same program reading the
 whole slab (`read_rungs` patched to the one rung `T`).
@@ -36,7 +37,7 @@ ENDS = [r + d for r in (W, 3 * W, 7 * W) for d in (-1, 0, 1)] + [T]
 @contextlib.contextmanager
 def _whole():
     """Programs traced in here read every slab whole, as before the rule."""
-    rungs, qwen3.read_rungs = qwen3.read_rungs, lambda cfg, t, *shapes, **named: (t,)
+    rungs, qwen3.read_rungs = qwen3.read_rungs, lambda cfg, stacks, *shapes, **named: (stacks[0].shape[2],)
     try:
         yield
     finally:
@@ -46,12 +47,24 @@ def _whole():
 def _config(model: str):
     if model.endswith("-wide"):  # heads as wide as a tile: DenseEntry lanes (tests/test_kv_rows.py)
         return dataclasses.replace(get_config(model[:-5]), name=model, head_dim=128)
-    return get_config(model)  # 16-wide heads: RowEntry lanes
+    return get_config(model)  # 16-wide heads: RowEntry lanes; a latent model: LatentEntry
+
+
+def _stacks(t: int, row: tuple, second: tuple = None, lanes: int = LANES, dtype=jnp.bfloat16):
+    """The shapes of a cache's two stacks over `t` slots: what a slot holds
+    in the first is `row`, in the other `second` (the same where None)."""
+    return tuple(jax.ShapeDtypeStruct((2, lanes, t, *w), dtype) for w in (row, second or row))
 
 
 def _rungs(t: int, q_len: int = 1):
-    return qwen3.read_rungs(_config("tiny"), t, q_len, LANES, False, heads=False)
+    return qwen3.read_rungs(_config("tiny"), _stacks(t, (256,)), q_len, LANES, False)
 
+
+# the lanes' layouts: keys per head, one row a token, and a latent cache (without and with the
+# stream of hidden states around it)
+LAYOUTS = pytest.mark.parametrize(
+    "model", ["tiny-wide", "tiny", "tiny-dsv2", "tiny-xing4"],
+    ids=["heads", "rows", "latent", "latent-stream"])
 
 _ENGINES = {}
 
@@ -100,7 +113,7 @@ def _same(got, want):
 
 
 @pytest.mark.parametrize("end", ENDS)
-@pytest.mark.parametrize("model", ["tiny-wide", "tiny"], ids=["heads", "rows"])
+@LAYOUTS
 def test_decode_step_over_ragged_lanes_reads_to_the_longest_lanes_rung(model, end):
     """Four lanes at ragged lengths, one of them idle (length 0): the step
     reads to the rung of the longest, whose row lies at `end` - 1."""
@@ -115,7 +128,7 @@ def test_decode_step_over_ragged_lanes_reads_to_the_longest_lanes_rung(model, en
 
 
 @pytest.mark.parametrize("end", ENDS)
-@pytest.mark.parametrize("model", ["tiny-wide", "tiny"], ids=["heads", "rows"])
+@LAYOUTS
 def test_prefill_chunk_at_a_traced_start_reads_to_the_chunks_rung(model, end):
     """A 32-token bucket holding 29 tokens, written at a traced start into
     lane 2: the chunk reads to the rung of start + 32."""
@@ -184,7 +197,7 @@ def test_traced_window_layer_is_masked_and_prefix_bounded(model, end):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
 
 
-@pytest.mark.parametrize("model", ["tiny-wide", "tiny"], ids=["heads", "rows"])
+@LAYOUTS
 def test_a_row_that_writes_nothing_does_not_widen_the_read(model):
     """Lane 1 sits the step out (`active` False, the write_mask) at a stale
     length of 900: the read stops at the rung of the longest ACTIVE lane, and
@@ -200,16 +213,36 @@ def test_a_row_that_writes_nothing_does_not_widen_the_read(model):
     _same(*_run(model, step, 201))
 
 
-@pytest.mark.parametrize("max_len", [64, 512, 1000])
-def test_a_slab_under_the_floor_is_read_whole_by_the_program_it_always_was(max_len):
+@pytest.mark.parametrize("valid", [1, W - 1, W, 2 * W + 5])
+def test_a_latent_rows_softmax_is_the_same_bits_at_every_rung_that_covers_it(valid):
+    """Rows of a latent step share one read, whose length the longest row
+    sets: the softmax sums a rung step by step in slot order
+    (`_softmax_by_steps`), so the probabilities of a row with `valid` slots
+    are the same BITS at every rung that covers them (a masked step adds an
+    exact zero), and jax.nn.softmax's to rounding. (On the chip one reduction
+    over the axis gave other last bits at 8192 slots than at 6144, and
+    `xing-latent-docs`' probe other tokens beside sessions than alone.)"""
+    scores = jax.random.normal(jax.random.PRNGKey(valid), (LANES, 4, 1, T), jnp.float32) * 3
+    scores = jnp.where(jnp.arange(T) < valid, scores, jnp.float32(-1e30))
+    rungs = [r for r in _rungs(T) if r >= valid]
+    at = {r: np.asarray(jax.jit(lambda x, r=r: qwen3._softmax_by_steps(x[..., :r], W))(scores)) for r in rungs}
+    for r in rungs:
+        assert (at[r][..., :rungs[0]].view(np.uint32) == at[rungs[0]].view(np.uint32)).all()
+        assert not at[r][..., valid:].any()
+    np.testing.assert_allclose(at[T], np.asarray(jax.nn.softmax(scores, axis=-1)), rtol=1e-6, atol=1e-9)
+
+
+@pytest.mark.parametrize("model,max_len", [
+    ("tiny-wide", 64), ("tiny-wide", 512), ("tiny-wide", 1000), ("tiny-dsv2", 64), ("tiny-xing4", 64)])
+def test_a_slab_under_the_floor_is_read_whole_by_the_program_it_always_was(model, max_len):
     """Under 1024 slots (or not a whole number of tiles a rung) the ladder is
-    the slab: no conditional enters the program, and its text is the text of
-    the whole-slab read."""
+    the slab: the read adds no conditional to the program, and its text is the
+    text of the whole-slab read (a latent lane's as a lane of heads')."""
     assert _rungs(max_len) == (max_len,)
     toks = jnp.zeros((LANES,), jnp.int32)
 
     def text(whole):
-        cfg = _config("tiny-wide")
+        cfg = _config(model)
         eng = BatchedEngine(cfg, qwen3.init_params(cfg, jax.random.PRNGKey(0)),
                             lanes=LANES, max_len=max_len)
         return eng._decode_logits.lower(eng.params, eng.cache, toks, toks).as_text()
@@ -217,23 +250,39 @@ def test_a_slab_under_the_floor_is_read_whole_by_the_program_it_always_was(max_l
     here = text(False)
     with _whole():
         assert here == text(True)
-    assert "stablehlo.case" not in here
+    if not _config(model).is_mla:  # a routed layer has conditionals of its own
+        assert "stablehlo.case" not in here
 
 
-def test_the_ladder_engages_from_1024_slots_in_one_program():
-    """At 1024 slots the step holds ONE conditional of eight branches in its
-    layer scan, whatever the lengths: nothing static was added to the jit."""
-    eng = _engine("tiny-wide", False)
+@pytest.mark.parametrize("model,scans", [("tiny-wide", 1), ("tiny-dsv2", 2), ("tiny-xing4", 2)],
+                         ids=["heads", "latent", "latent-stream"])
+def test_the_ladder_engages_from_1024_slots_in_one_program(model, scans):
+    """At 1024 slots the step and the chunk hold ONE conditional of eight
+    branches a layer scan (a latent model's dense lead is a scan of its own),
+    whatever the lengths and wherever the chunk starts: nothing static was
+    added to a jit."""
+    eng = _engine(model, False)
     toks = jnp.zeros((LANES,), jnp.int32)
-    text = eng._decode_logits.lower(eng.params, eng.cache, toks, toks).as_text()
-    assert text.count("stablehlo.case") == 1
+    chunk = jnp.zeros((1, 32), jnp.int32)
+    lower = lambda e: (
+        e._decode_logits.lower(e.params, e.cache, toks, toks).as_text(),
+        e._prefill_lane_logits.lower(
+            e.params, e.cache, chunk, jnp.int32(0), jnp.int32(0), jnp.int32(3)).as_text())
+    with _whole():
+        whole = lower(_engine(model, True))
+    for text, ref in zip(lower(eng), whole):
+        assert text.count("stablehlo.case") - ref.count("stablehlo.case") == scans
     assert _rungs(T) == tuple(W * i for i in range(1, 9))
     assert _rungs(4096)[0] == 512 and _rungs(4096)[-1] == 4096
-    before = eng._decode_logits._cache_size()
+    before = eng._decode_logits._cache_size(), eng._prefill_lane_logits._cache_size()
     for lens in ([5, 0, 0, 0], [700, 3, 0, 0], [1023, 1023, 1023, 1023]):
         eng.cache, _, _ = eng._decode_logits(
             eng.params, eng.cache, toks, jnp.asarray(lens, jnp.int32))
-    assert eng._decode_logits._cache_size() - before <= 1
+    for start in (0, 300, T - 32):
+        eng.cache, _ = eng._prefill_lane_logits(
+            eng.params, eng.cache, chunk, jnp.int32(1), jnp.int32(start), jnp.int32(32))
+    assert eng._decode_logits._cache_size() - before[0] <= 1
+    assert eng._prefill_lane_logits._cache_size() - before[1] <= 1
 
 
 def test_a_slice_made_before_the_dots_is_never_longer_than_fast_memory_keeps():
@@ -244,8 +293,11 @@ def test_a_slice_made_before_the_dots_is_never_longer_than_fast_memory_keeps():
     byte); `q4b-sat-chat`, `sdar-block-chat` and a stage of `q8b-pp4-sat-chat`
     keep all eight; a decode step over rows (`q3n-long-docs`) fuses its slice
     into the dots and keeps all eight whatever their size."""
-    rungs = lambda model, t, lanes, q_len, heads: qwen3.read_rungs(
-        get_config(model), t, q_len, lanes, False, heads=heads)
+    def rungs(model, t, lanes, q_len, heads):
+        cfg = get_config(model)
+        row = (cfg.num_kv_heads, cfg.head_dim) if heads else (cfg.kv_dim,)
+        return qwen3.read_rungs(cfg, _stacks(t, row, lanes=lanes), q_len, lanes, False)
+
     assert rungs("trinity-large-ep8-5l", 16384, 16, 1, True) == (2048, 16384)
     assert rungs("trinity-large-ep8-5l", 16384, 1, 512, True)[:2] == (2048, 4096)
     assert rungs("qwen3-4b", 4096, 5, 1, True) == tuple(range(512, 4097, 512))
@@ -253,6 +305,34 @@ def test_a_slice_made_before_the_dots_is_never_longer_than_fast_memory_keeps():
     assert rungs("qwen3-8b", 4096, 8, 1, True) == tuple(range(512, 4097, 512))
     assert rungs("qwen3-next-80b-ep4-8l", 32768, 16, 1, False) == tuple(range(4096, 32769, 4096))
     assert len(rungs("qwen3-next-80b-ep4-8l", 32768, 1, 512, False)) == 8
+
+
+def test_a_latent_lane_keeps_its_ladder_where_the_chip_would_pick_the_kernel(monkeypatch):
+    """On a TPU `auto` hands a chunk whose float32 scores pass 256 MiB to the
+    Pallas kernel, and the ladder gives way to the kernel's own bound (one
+    rung): `xing-latent-docs`' chunk is 4 x 32 x 512 x 16 384 = 1.07 GB. No
+    kernel serves latent attention, so `flash_enabled` says no for a latent
+    model and both latent cells keep eight rungs in both programs: a latent
+    row is 512 columns of bf16 (and 64 of roped key), so a chunk's slice of a
+    whole lane is 16 MiB. A chunk over 16 rows (K-step, a speculative verify)
+    keeps the 64 MiB cap as lanes of heads do."""
+    from inferd_tpu.ops import attention as attention_ops
+
+    monkeypatch.setattr(attention_ops, "is_tpu", lambda: True)
+    eighths = lambda t: tuple(range(t // 8, t + 1, t // 8))
+    for model, t in (("xing4.0-29b-a4b-6l", 16384), ("deepseek-v2-lite-8l", 4096)):
+        cfg = get_config(model)
+        assert not attention_ops.flash_enabled(cfg, t, q_len=512, batch=1)
+        latent = lambda q_len, batch: qwen3.read_rungs(
+            cfg, _stacks(t, (cfg.kv_lora_rank,), (cfg.qk_rope_head_dim,), lanes=batch), q_len, batch, False)
+        assert latent(512, 1) == eighths(t)  # a prefill chunk: its slice of a whole lane is 16 MiB
+        assert latent(1, 16) == eighths(t)  # a decode step: the latents' slice fuses into the dots
+        row = cfg.kv_lora_rank * 2  # the wider of the two stacks' rows, in bf16
+        assert latent(4, 16) == tuple(r for r in eighths(t) if 16 * row * r <= 64 * 2**20 or r == t)
+    assert latent(4, 16) == eighths(4096)
+    # the rule still makes way for the kernel where one serves the model
+    dense = get_config("qwen3-4b")
+    assert qwen3.read_rungs(dense, _stacks(16384, (dense.num_kv_heads, 128), lanes=1), 512, 1, False) == (16384,)
 
 
 @pytest.mark.parametrize("end", [W, 2 * W, 2 * W + 1, 5 * W, T])
@@ -263,7 +343,8 @@ def test_a_ladder_with_rungs_left_out_reads_to_the_next_it_has(end, monkeypatch)
     cfg = _config("tiny-wide")
     row = LANES * cfg.kv_dim * 4  # float32 lanes
     monkeypatch.setattr(qwen3, "_READ_SLICE_BYTES", 2 * W * row)
-    rungs = qwen3.read_rungs(cfg, T, 1, LANES, False, heads=True)
+    rungs = qwen3.read_rungs(
+        cfg, _stacks(T, (cfg.num_kv_heads, cfg.head_dim), dtype=jnp.float32), 1, LANES, False)
     assert rungs == (W, 2 * W, T)
     eng = BatchedEngine(cfg, qwen3.init_params(cfg, jax.random.PRNGKey(0)), lanes=LANES, max_len=T)
     lens = jnp.asarray([end - 1, 0, end // 2, 7], jnp.int32)
@@ -277,7 +358,7 @@ def test_a_ladder_with_rungs_left_out_reads_to_the_next_it_has(end, monkeypatch)
     _same(got, want)
 
 
-@pytest.mark.parametrize("t", [1024, 4096, 32768])
+@pytest.mark.parametrize("t", [1024, 4096, 16384, 32768])  # 4096, 16384: the latent cells' lanes
 def test_host_and_program_choose_the_same_rung(t):
     """`read_rung` is the one function both ask: on Python ints (the host's
     counter) and traced (the program) it names the same rung, the shortest
@@ -296,7 +377,7 @@ def test_host_and_program_choose_the_same_rung(t):
 # ---------------------------------------------------------------------------
 
 
-def _reader():
+def _reader(metric="engine.slab_read_share"):
     import importlib.util
     import os
     import sys
@@ -305,7 +386,7 @@ def _reader():
     sys.path.insert(0, bench)  # the reader imports `arith` as the harness has it
     try:
         spec = importlib.util.spec_from_file_location(
-            "slab_read_share", os.path.join(bench, "layer_metrics", "engine.slab_read_share.py"))
+            metric.replace(".", "_"), os.path.join(bench, "layer_metrics", f"{metric}.py"))
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
     finally:
@@ -330,7 +411,7 @@ def _serve(model, prompt_len, new, max_len=T, lanes=3):
     return before, {"executor": ex.stats()}
 
 
-@pytest.mark.parametrize("model", ["tiny-wide", "tiny"], ids=["heads", "rows"])
+@LAYOUTS
 def test_the_counter_counts_the_rung_the_program_is_handed(model):
     """A prompt of 200 tokens (one bucket of 256: two rungs) and six decode
     steps whose row lies at 200..205 (three lanes reading two rungs, the two
@@ -375,11 +456,24 @@ def test_a_block_step_counts_every_pass_and_its_prefill_the_real_end():
     assert kv["slots_held"] == T + 2 * 3 * 3 * T
 
 
-def test_a_latent_cache_has_no_such_counter_and_the_reader_says_nothing():
+def test_a_latent_cache_counts_its_lanes_and_the_parents_stats_say_nothing():
+    """A latent lane under the floor counts rows x `--max-len` over the same,
+    and past a rung boundary rows x the rung the program chose (rows at 254,
+    255 | 256, 257 of 1024 slots); a `/stats` without the counter (the
+    parent's latent node) is no number."""
+    read = _reader("engine.latent_read_share")
     before, after = _serve("tiny-dsv2", 20, 2, max_len=64)
-    assert "slots_read" not in after["executor"].get("kv", {})
-    assert _reader()({"stats0": before, "stats1": after}) is None
-    assert _reader()({"stats0": {}, "stats1": {"executor": {}}}) is None  # the parent's /stats
+    kv = after["executor"]["kv"]
+    assert kv["slots_read"] == kv["slots_held"] == 64 + 2 * 3 * 64
+    assert read({"stats0": before, "stats1": after}) == 100.0
+    before, after = _serve("tiny-xing4", 254, 4)
+    kv = after["executor"]["kv"]
+    assert kv["slots_read"] == 2 * W + 3 * (2 * 2 * W + 2 * 3 * W)
+    assert kv["slots_held"] == T + 4 * 3 * T
+    assert read({"stats0": before, "stats1": after}) == pytest.approx(100 * kv["slots_read"] / kv["slots_held"])
+    parent = lambda stats: {"executor": {k: v for k, v in stats["executor"].items() if k != "kv"}}
+    assert read({"stats0": parent(before), "stats1": parent(after)}) is None
+    assert read({"stats0": {}, "stats1": {"executor": {}}}) is None
 
 
 # ---------------------------------------------------------------------------
