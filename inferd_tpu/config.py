@@ -17,6 +17,11 @@ import jax.numpy as jnp
 
 # the kinds of cfg.layer_types that hold a recurrent state and no keys and values
 STATE_KINDS = ("mamba", "delta")
+# the kinds that are a feed-forward and NO mixer: a layer of a model whose
+# layers are one sublayer each (cfg.single_sublayer)
+FFN_KINDS = ("moe",)
+# how a `nemotron_h` config spells a layer's kind in its hybrid_override_pattern
+PATTERN_LETTERS = {"M": "mamba", "E": "moe", "*": "attention"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -311,14 +316,43 @@ class ModelConfig:
     hc_res_clamp: float = 30.0
     seeded_routed_scale: float = 1.0
 
+    # The Nemotron-H family (all absent elsewhere):
+    #   layer_types with "moe" among them — every layer is ONE sublayer,
+    #                    x <- x + F(RMSNorm(x)): a mixer ("mamba", "attention")
+    #                    and nothing after it, or routed experts ("moe") and no
+    #                    mixer before them; num_layers counts sublayers
+    #                    (`single_sublayer`). A mixer's stack then holds no
+    #                    feed-forward and the experts lie in a stack of their
+    #                    own, params["ffn_layers"]
+    #   moe_latent_size — > 0: the routed experts work in a latent this wide
+    #                    (LatentMoE): one projection down before them and one
+    #                    up after their combine, shared by all experts
+    #                    (p["latent_in_proj"], p["latent_out_proj"]); the
+    #                    router and the shared expert read the full width
+    #   ffn_gated      — False: a feed-forward is down(act(up(x))), TWO
+    #                    matrices (experts, the shared one): no gate_proj
+    #   hidden_act "relu2" — relu(x)^2
+    moe_latent_size: int = 0
+    ffn_gated: bool = True
+
     def __post_init__(self):
         if self.layer_types:
-            odd = set(self.layer_types) - set(STATE_KINDS) - {"attention", "sliding", "global"}
+            odd = (set(self.layer_types) - set(STATE_KINDS) - set(FFN_KINDS)
+                   - {"attention", "sliding", "global"})
             if odd or (self.has_state_layers and self.num_layers % len(self.layer_types)):
                 raise ValueError(
                     f"{self.name}: layer_types is one period of 'mamba' / 'delta' / 'attention' "
-                    f"/ 'sliding' / 'global' (with a state layer it divides num_layers "
+                    f"/ 'sliding' / 'global' / 'moe' (with a state layer it divides num_layers "
                     f"{self.num_layers}); got {self.layer_types}"
+                )
+            if self.single_sublayer and not (
+                    self.has_state_layers and self.is_moe and self.norm_placement == "before"
+                    and not self.hc_mult and self.layer_types[0] not in FFN_KINDS):
+                raise ValueError(
+                    f"{self.name}: layers that are one sublayer each ('moe' among layer_types) "
+                    "are state mixers, global GQA layers and routed experts, a mixer first, "
+                    "each behind ONE norm on its input (norm_placement 'before'), the "
+                    "residual one hidden state wide"
                 )
             if ("sliding" in self.layer_types) != (self.sliding_window > 0):
                 raise ValueError(
@@ -351,6 +385,15 @@ class ModelConfig:
                     f"{self.name}: a Gated-DeltaNet layer has linear_value_heads a whole "
                     "multiple of linear_key_heads, and both head sizes"
                 )
+        if self.moe_latent_size and not self.is_moe:
+            raise ValueError(f"{self.name}: moe_latent_size is the width the EXPERTS work in")
+        if self.hidden_act not in ("silu", "gelu_tanh", "relu2"):
+            raise ValueError(f"{self.name}: unknown hidden_act {self.hidden_act!r}")
+        if not self.ffn_gated and (self.swiglu_limit or self.moe_bias or self.shared_expert_gate):
+            raise ValueError(
+                f"{self.name}: an ungated feed-forward (ffn_gated False) is down(act(up(x))) "
+                "and nothing else: no clamp, no bias, no gate on the shared expert"
+            )
         if self.norm_placement not in ("before", "both", "after"):
             raise ValueError(f"{self.name}: unknown norm_placement {self.norm_placement!r}")
         if self.q_lora_rank and not self.is_mla:
@@ -421,6 +464,26 @@ class ModelConfig:
     def has_state_layers(self) -> bool:
         """Some layer holds a recurrent state and not keys and values."""
         return self.state_kind is not None
+
+    @property
+    def single_sublayer(self) -> bool:
+        """Every layer is ONE sublayer (a mixer, or a feed-forward), not a
+        mixer and then a feed-forward: some kind of layer_types is a
+        feed-forward alone."""
+        return any(k in FFN_KINDS for k in self.layer_types)
+
+    @property
+    def sublayer_counts(self) -> dict:
+        """How many of the model's layers are of each kind of layer_pattern."""
+        return {k: self.layers_of(k) for k in dict.fromkeys(self.layer_pattern)}
+
+    @property
+    def hybrid_override_pattern(self) -> str:
+        """Every layer's kind as a `nemotron_h` config spells them: M (Mamba-2),
+        E (experts), * (attention); "" for a model with another kind."""
+        letters = {kind: letter for letter, kind in PATTERN_LETTERS.items()}
+        names = self.layer_type_names
+        return "".join(letters[k] for k in names) if set(names) <= set(letters) else ""
 
     @property
     def state_kind(self) -> Optional[str]:
@@ -999,6 +1062,79 @@ GRANITE_4_H_MICRO = ModelConfig(
     position_embedding="nope",
 )
 
+# NVIDIA-Nemotron-3-Super-120B-A12B (nvidia/...-BF16 config.json, `nemotron_h`):
+# 88 layers that are ONE sublayer each, by hybrid_override_pattern: 40 Mamba-2
+# mixers (128 heads of 64, state 128, 8 groups), 40 expert layers (LatentMoE: 512
+# sigmoid-routed experts of 2688 in a 1024-wide latent, top 22, scaling 5,
+# squared ReLU without a gate, beside a full-width shared expert of 5376) and 8
+# GQA layers (32 query heads over 2 kv heads of 128) that rotate nothing. Its
+# multi-token-prediction module is left out (generation does not run it).
+# The -ep4-11l preset is ONE chip's share of a four-chip expert-parallel group
+# over the first eleven sublayers, MEMEMEM*EME (the published 40 : 40 : 8):
+# experts 0..127 of the router's 512, vocabulary ids 0..32 767 of 131 072, every
+# width as published (benchmark/configs/nemotron-3-super-120b-ep4-1chip.json
+# has the arithmetic).
+NEMOTRON_3_SUPER_PATTERN = (
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
+
+
+def pattern_kinds(pattern: str) -> tuple:
+    """A `nemotron_h` hybrid_override_pattern as layer_types. Its '-' (a dense
+    MLP alone) has no kind here: a model with one is refused."""
+    odd = set(pattern) - set(PATTERN_LETTERS)
+    if odd:
+        raise ValueError(
+            f"hybrid_override_pattern {pattern!r}: the layer kinds are M (Mamba-2), E "
+            f"(experts) and * (attention); {sorted(odd)} is not runnable")
+    return tuple(PATTERN_LETTERS[c] for c in pattern)
+
+
+NEMOTRON_3_SUPER_120B = ModelConfig(
+    name="nemotron-3-super-120b-a12b",
+    vocab_size=131072,
+    hidden_size=4096,
+    intermediate_size=2688,
+    num_layers=88,
+    num_heads=32,
+    num_kv_heads=2,
+    head_dim=128,
+    rms_norm_eps=1e-5,
+    rope_theta=10_000.0,  # in the published config; no layer rotates anything
+    max_position_embeddings=262144,
+    tie_word_embeddings=False,
+    qk_norm=False,
+    position_embedding="nope",
+    layer_types=pattern_kinds(NEMOTRON_3_SUPER_PATTERN),
+    mamba_heads=128,
+    mamba_head_dim=64,
+    mamba_state=128,
+    mamba_groups=8,
+    mamba_conv=4,
+    mamba_expand=2,
+    mamba_chunk_size=128,
+    num_experts=512,
+    num_experts_per_tok=22,
+    moe_intermediate_size=2688,
+    moe_latent_size=1024,
+    moe_router_mode="sigmoid_topk",
+    norm_topk_prob=True,
+    routed_scaling_factor=5.0,
+    n_shared_experts=2,  # ONE shared expert of 5376 = 2 x moe_intermediate_size
+    hidden_act="relu2",
+    ffn_gated=False,
+    # the SEEDED draw alone: 22 of 512 scores lie 0.002 apart at the cut, bf16 and
+    # float32 break nearly every token-layer's last places differently, and at 1 an
+    # expert that comes or goes moved the log-probabilities by 0.019-0.034 in the mean
+    # (the 8-bit control: 0.050-0.071, a ratio of 1.5; PERF.md section 4)
+    seeded_routed_scale=0.25,
+)
+
+NEMOTRON_3_SUPER_EP4_11L = dataclasses.replace(
+    NEMOTRON_3_SUPER_120B, name="nemotron-3-super-120b-ep4-11l", num_layers=11,
+    layer_types=pattern_kinds(NEMOTRON_3_SUPER_PATTERN[:11]), vocab_size=131072 // 4,
+    num_experts=512 // 4, router_experts=512,
+)
+
 # Trinity-Large-Preview (arcee-ai/Trinity-Large-Preview config.json, `afmoe`,
 # 400B-A13B): 60 layers, three windowed (4096, rope) to one full (no rope),
 # a gated attention output, sandwich norms, 6 dense layers, then 256 sigmoid-
@@ -1270,6 +1406,21 @@ TINY_OLMO_HYBRID = dataclasses.replace(
     linear_conv=4, linear_chunk_size=8, linear_allow_neg_eigval=True,
 )
 
+# tiny-nemotron-h: the Nemotron-H layers at toy widths, one sublayer each,
+# M E M * E M E: all three kinds, a mixer with no experts behind it (the M
+# before the *), Mamba-2 in 4 groups, 4 held of 16 sigmoid-routed experts (top
+# 4) in a latent of 32 under a hidden state of 64, squared ReLU without a gate.
+TINY_NEMOTRON_H = dataclasses.replace(
+    TINY, name="tiny-nemotron-h", qk_norm=False, num_layers=7, rms_norm_eps=1e-5,
+    tie_word_embeddings=False, position_embedding="nope",
+    layer_types=pattern_kinds("MEM*EME"),
+    mamba_heads=8, mamba_head_dim=16, mamba_state=16, mamba_groups=4, mamba_conv=4,
+    mamba_expand=2, mamba_chunk_size=8,
+    num_experts=4, router_experts=16, num_experts_per_tok=4, moe_intermediate_size=48,
+    moe_latent_size=32, moe_router_mode="sigmoid_topk", norm_topk_prob=True,
+    routed_scaling_factor=5.0, n_shared_experts=2, hidden_act="relu2", ffn_gated=False,
+)
+
 PRESETS = {
     c.name: c
     for c in [
@@ -1304,6 +1455,8 @@ PRESETS = {
         QWEN3_NEXT_80B_EP4_8L,
         OLMO_HYBRID_7B,
         OLMO_HYBRID_7B_16L,
+        NEMOTRON_3_SUPER_120B,
+        NEMOTRON_3_SUPER_EP4_11L,
         BENCH_PIPE,
         TINY,
         TINY_MOE,
@@ -1318,6 +1471,7 @@ PRESETS = {
         TINY_AFMOE,
         TINY_QWEN3_NEXT,
         TINY_OLMO_HYBRID,
+        TINY_NEMOTRON_H,
     ]
 }
 
@@ -1346,6 +1500,7 @@ HF_REPOS = {
     "granite-4.0-h-micro": "ibm-granite/granite-4.0-h-micro",
     "qwen3-next-80b-a3b": "Qwen/Qwen3-Next-80B-A3B-Instruct",
     "olmo-hybrid-7b": "allenai/Olmo-Hybrid-7B",
+    "nemotron-3-super-120b-a12b": "nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16",
 }
 
 

@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from inferd_tpu.config import ModelConfig
+from inferd_tpu.config import FFN_KINDS, ModelConfig
 
 # Extra ring slots past the (16-rounded) window. Bounds how far "newer"
 # data may sit in a slot whose formula position is already inside some
@@ -81,11 +81,19 @@ def rows_layout(cfg: ModelConfig) -> bool:
     of them (30 of 128: the head axis pads to 32, and the described-v5e
     compile of the decode step re-lays both stacks whole, 2 x 2.0 GB of
     temporaries at 4 x 16 x 4096, over the chip's memory): the row is
-    the shape that is written and read where it lies. Rings and paged pools
+    the shape that is written and read where it lies. Fewer heads than
+    sublanes, each as wide as a tile (2 of 128), are stored unpadded
+    (T(2,128)) but every step copies the prefix it reads T-minor before its
+    dot (for a described v5e, 32 x 4096: two copies of up to 67 MB a step); a
+    model with state layers takes the row there (Nemotron-H), the per-head
+    models that were measured with heads (4 of 128: `sdar-block-chat`) keep
+    them until a PR measures them the other way. Rings and paged pools
     keep heads; a latent cache has none."""
     if cfg.is_mla:
         return False
     if cfg.head_dim == TILE_LANES:  # a head axis over one tile's sublanes that pads (30 to 32)
+        if cfg.num_kv_heads < TILE_SUBLANES:
+            return cfg.has_state_layers
         return cfg.num_kv_heads > TILE_SUBLANES and cfg.num_kv_heads % TILE_SUBLANES > 0
     return cfg.head_dim < TILE_LANES or cfg.num_kv_heads < TILE_SUBLANES
 
@@ -309,8 +317,9 @@ class KVCache:
         if cfg.has_state_layers:
             # keys and values (in the kv dtype) for the attention layers, a
             # state and the convolution's last inputs for the others
+            # (of layers that are one sublayer each, the experts hold neither)
             la = cfg.layers_of("attention", num_layers)
-            lm = num_layers - la
+            lm = cfg.layers_of(cfg.state_kind, num_layers)
             shape = (la, batch, max_len, *lane)
             return KVCache(
                 k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt), length=jnp.int32(0),
@@ -386,13 +395,15 @@ class KVCache:
             "sliding": None if self.k_loc is None else RingEntry(
                 k=self.k_loc, v=self.v_loc, window=int(cfg.sliding_window)),
         }
-        return tuple(by_kind.get(kind) or glob for kind in dict.fromkeys(cfg.layer_pattern))
+        return tuple(  # a kind that is a feed-forward alone holds nothing
+            None if kind in FFN_KINDS else by_kind.get(kind) or glob
+            for kind in dict.fromkeys(cfg.layer_pattern))
 
     def with_entries(self, entries: tuple) -> "KVCache":
         """Inverse of `entries`: the same cache (and length) over new buffers."""
         if isinstance(entries[0], LatentEntry):
             return KVCache(k=entries[0].c, v=entries[0].r, length=self.length)
-        by_type = {type(e): e for e in entries}
+        by_type = {type(e): e for e in entries if e is not None}
         glob = by_type.get(RowEntry) or by_type[DenseEntry]
         ring, state = by_type.get(RingEntry), by_type.get(StateEntry)
         return KVCache(

@@ -78,6 +78,8 @@ def params_from_hf_state_dict(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params
         return _params_from_olmo_hybrid(cfg, sd)
     if cfg.state_kind == "delta":
         return _params_from_qwen3_next(cfg, sd)
+    if cfg.single_sublayer:
+        return _params_from_nemotron_h(cfg, sd)
     if cfg.has_state_layers:
         return _params_from_granite_hybrid(cfg, sd)
     if cfg.moe_router_mode == "sigmoid_topk":
@@ -359,6 +361,18 @@ def _params_from_afmoe(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:
     return params
 
 
+def _mamba2_leaves(raw, m: str) -> Params:
+    """A published Mamba-2 mixer `m` (`raw(name)` reads a tensor) as the
+    leaves `mamba_mixer` reads: linear weights [in, out], `conv1d.weight`
+    [C, 1, K] as the taps [K, C]."""
+    return dict(
+        in_proj=raw(f"{m}.in_proj.weight").T, out_proj=raw(f"{m}.out_proj.weight").T,
+        conv_w=raw(f"{m}.conv1d.weight")[:, 0, :].T, conv_b=raw(f"{m}.conv1d.bias"),
+        dt_bias=raw(f"{m}.dt_bias"), A_log=raw(f"{m}.A_log"), D=raw(f"{m}.D"),
+        gate_norm=raw(f"{m}.norm.weight"),
+    )
+
+
 def _params_from_granite_hybrid(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:
     """HF `granitemoehybrid` names (no experts) -> `layers` for the attention
     kind, `state_layers` for the Mamba kind, each in layer order. The
@@ -385,13 +399,7 @@ def _params_from_granite_hybrid(cfg: ModelConfig, sd: Mapping[str, Any]) -> Para
             for proj in ("q_proj", "k_proj", "v_proj", "o_proj"):
                 out[proj] = w(f"layers.{i}.self_attn.{proj}")
             return out
-        m = f"layers.{i}.mamba"
-        out.update(
-            in_proj=w(f"{m}.in_proj"), out_proj=w(f"{m}.out_proj"),
-            conv_w=raw(f"{m}.conv1d.weight")[:, 0, :].T, conv_b=raw(f"{m}.conv1d.bias"),
-            dt_bias=raw(f"{m}.dt_bias"), A_log=raw(f"{m}.A_log"), D=raw(f"{m}.D"),
-            gate_norm=raw(f"{m}.norm.weight"),
-        )
+        out.update(_mamba2_leaves(raw, f"layers.{i}.mamba"))
         return out
 
     def group(kind: str) -> Params:
@@ -408,6 +416,66 @@ def _params_from_granite_hybrid(cfg: ModelConfig, sd: Mapping[str, Any]) -> Para
     if not cfg.tie_word_embeddings:
         params["lm_head"] = jnp.asarray(w("lm_head"), dtype=dt)
     return params
+
+
+def _params_from_nemotron_h(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:
+    """HF `nemotron_h` names -> a stack a KIND of sublayer (`layers` the
+    attention kind, `state_layers` the Mamba kind, `ffn_layers` the experts),
+    each in layer order. Every published layer is `backbone.layers.N` with ONE
+    `norm` and ONE `mixer`, whatever the mixer is: Mamba-2 (`in_proj`,
+    `conv1d` [C, 1, K] -> taps [K, C], `dt_bias`, `A_log`, `D`, `norm`,
+    `out_proj`), attention (`q_proj` .. `o_proj`) or experts (`gate` with
+    `e_score_correction_bias`, `experts.E.{up,down}_proj`,
+    `shared_experts.{up,down}_proj`, `fc1_latent_proj` into and
+    `fc2_latent_proj` out of the latent). The norm becomes `input_norm` of a
+    mixer and `post_norm` of the experts, the names a layer of two sublayers
+    has for them. A preset that holds a share reads the experts
+    cfg.expert_offset .. + cfg.num_experts, the router whole, and the first
+    cfg.vocab_size rows of the vocabulary. No published checkpoint was at
+    hand: the names are as the published modelling code has them, to the
+    builder's knowledge (PR 57; benchmark/configs/
+    nemotron-3-super-120b-ep4-1chip.json lists them under `assumed`)."""
+    dt = cfg.jnp_dtype
+    held = range(cfg.expert_offset, cfg.expert_offset + cfg.num_experts)
+
+    def raw(name: str) -> np.ndarray:
+        return _to_np(sd[name if name in sd else f"backbone.{name}"])
+
+    def w(name: str) -> np.ndarray:  # a linear weight as [in, out]
+        return raw(f"{name}.weight").T
+
+    def layer(i: int, kind: str) -> Params:
+        m, norm = f"layers.{i}.mixer", raw(f"layers.{i}.norm.weight")
+        if kind == "attention":
+            return {"input_norm": norm,
+                    **{proj: w(f"{m}.{proj}") for proj in ("q_proj", "k_proj", "v_proj", "o_proj")}}
+        if kind == "mamba":
+            return {"input_norm": norm, **_mamba2_leaves(raw, m)}
+        return dict(
+            post_norm=norm, router=w(f"{m}.gate"),
+            router_select_bias=raw(f"{m}.gate.e_score_correction_bias").astype(np.float32),
+            up_proj=np.stack([w(f"{m}.experts.{e}.up_proj") for e in held]),
+            down_proj=np.stack([w(f"{m}.experts.{e}.down_proj") for e in held]),
+            shared_up_proj=w(f"{m}.shared_experts.up_proj"),
+            shared_down_proj=w(f"{m}.shared_experts.down_proj"),
+            latent_in_proj=w(f"{m}.fc1_latent_proj"), latent_out_proj=w(f"{m}.fc2_latent_proj"),
+        )
+
+    def group(kind: str) -> Params:
+        per_layer = [layer(i, kind) for i, k in enumerate(cfg.layer_type_names) if k == kind]
+        return {k: jnp.asarray(np.stack([lp[k] for lp in per_layer]),
+                               dtype=jnp.float32 if k == "router_select_bias" else dt)
+                for k in per_layer[0]}
+
+    v = cfg.vocab_size
+    return {
+        "embed": jnp.asarray(raw("embeddings.weight")[:v], dtype=dt),
+        "layers": group("attention"),
+        "state_layers": group("mamba"),
+        "ffn_layers": group("moe"),
+        "final_norm": jnp.asarray(raw("norm_f.weight"), dtype=dt),
+        "lm_head": jnp.asarray(_to_np(sd["lm_head.weight"]).T[:, :v], dtype=dt),
+    }
 
 
 def _params_from_qwen3_next(cfg: ModelConfig, sd: Mapping[str, Any]) -> Params:

@@ -31,7 +31,7 @@ import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-from inferd_tpu.config import STATE_KINDS, ModelConfig, yarn_mscale
+from inferd_tpu.config import FFN_KINDS, STATE_KINDS, ModelConfig, yarn_mscale
 from inferd_tpu.core import cache as cachelib
 from inferd_tpu.ops import attention as attention_ops
 from inferd_tpu.ops import lora as lora_ops
@@ -50,13 +50,19 @@ def _init_ffn_params(cfg: ModelConfig, w, n: int, dense: bool, ks, key) -> Param
     """A layer stack's feed-forward leaves: the dense MLP, or (a model with
     experts, not `dense`) the router, the held experts and the shared one.
     `w(key, *shape)` draws a stacked projection, `ks` four keys (router, gate,
-    up, down); what else is drawn folds `key`."""
+    up, down); what else is drawn folds `key`. An ungated feed-forward
+    (cfg.ffn_gated False) has no `gate_proj`; experts that work in a latent
+    (cfg.moe_latent_size) are that wide, between `latent_in_proj` and
+    `latent_out_proj`."""
     h, i, dt = cfg.hidden_size, cfg.intermediate_size, cfg.jnp_dtype
     if not (cfg.is_moe and not dense):
-        return {"gate_proj": w(ks[1], h, i), "up_proj": w(ks[2], h, i),
-                "down_proj": w(ks[3], i, h)}
+        p = {"up_proj": w(ks[2], h, i), "down_proj": w(ks[3], i, h)}
+        if cfg.ffn_gated:
+            p["gate_proj"] = w(ks[1], h, i)
+        return p
     p = {}
     e, mi = cfg.num_experts, cfg.moe_intermediate_size  # the experts HELD here
+    he = cfg.moe_latent_size or h  # what an expert reads and writes
     p["router"] = w(ks[0], h, cfg.router_width)
     if cfg.moe_router_mode == "sigmoid_topk":
         # drawn so that the router's logits have unit variance whatever
@@ -67,12 +73,16 @@ def _init_ffn_params(cfg: ModelConfig, w, n: int, dense: bool, ks, key) -> Param
         p["router"] = (p["router"].astype(jnp.float32) * (50.0 / math.sqrt(h))).astype(dt)
         p["router_select_bias"] = 0.01 * jax.random.normal(
             jax.random.fold_in(key, 14), (n, cfg.router_width), dtype=jnp.float32)
-    p["gate_proj"] = w(ks[1], e, h, mi)
-    p["up_proj"] = w(ks[2], e, h, mi)
-    p["down_proj"] = w(ks[3], e, mi, h)
+    if cfg.ffn_gated:
+        p["gate_proj"] = w(ks[1], e, he, mi)
+    p["up_proj"] = w(ks[2], e, he, mi)
+    p["down_proj"] = w(ks[3], e, mi, he)
     if cfg.seeded_routed_scale != 1.0:  # an expert that comes or goes at a near-tie moves little
         p["down_proj"] = (
             p["down_proj"].astype(jnp.float32) * cfg.seeded_routed_scale).astype(dt)
+    if cfg.moe_latent_size:
+        p["latent_in_proj"] = w(jax.random.fold_in(key, 19), h, he)
+        p["latent_out_proj"] = w(jax.random.fold_in(key, 20), he, h)
     if cfg.router_bias:
         p["router_bias"] = jnp.zeros((n, e), dtype=dt)
     if cfg.moe_bias:
@@ -81,7 +91,8 @@ def _init_ffn_params(cfg: ModelConfig, w, n: int, dense: bool, ks, key) -> Param
         p["down_bias"] = jnp.zeros((n, e, h), dtype=dt)
     if cfg.n_shared_experts:
         si = cfg.n_shared_experts * mi
-        p["shared_gate_proj"] = w(jax.random.fold_in(key, 10), h, si)
+        if cfg.ffn_gated:
+            p["shared_gate_proj"] = w(jax.random.fold_in(key, 10), h, si)
         p["shared_up_proj"] = w(jax.random.fold_in(key, 11), h, si)
         p["shared_down_proj"] = w(jax.random.fold_in(key, 12), si, h)
         if cfg.shared_expert_gate:  # Qwen3-Next: the shared expert's own gate, a vector
@@ -89,13 +100,17 @@ def _init_ffn_params(cfg: ModelConfig, w, n: int, dense: bool, ks, key) -> Param
     return p
 
 
-def _sublayer_norms(cfg: ModelConfig, norm: jax.Array) -> Params:
+def _sublayer_norms(cfg: ModelConfig, norm: jax.Array, sublayer: str) -> Params:
     """A layer stack's RMSNorms around its two sublayers, each drawn as
     `norm`, by cfg.norm_placement. "before": `input_norm`, and `post_norm`
     before the feed-forward. "both": `post_norm` stands on the MIXER's output,
     and the feed-forward has `pre_ffn_norm` and `post_ffn_norm`. "after":
     `post_norm` on the mixer's output, `post_ffn_norm` on the
-    feed-forward's, and no other."""
+    feed-forward's, and no other. A stack of layers that are ONE `sublayer`
+    ("mixer" or "ffn": cfg.single_sublayer, placement "before") has that
+    sublayer's norm alone, under the name it has in a layer of two."""
+    if cfg.single_sublayer:
+        return {"input_norm" if sublayer == "mixer" else "post_norm": norm}
     names = {"before": ("input_norm", "post_norm"),
              "both": ("input_norm", "post_norm", "pre_ffn_norm", "post_ffn_norm"),
              "after": ("post_norm", "post_ffn_norm")}[cfg.norm_placement]
@@ -120,7 +135,8 @@ def init_layer_params(
     norm1 = jnp.zeros if cfg.rms_norm_plus_one else jnp.ones
 
     p = {
-        **_sublayer_norms(cfg, norm1((n, h), dtype=dt)),  # Gemma: around the MLP too; Olmo: outputs only
+        # Gemma: around the MLP too; Olmo: outputs only
+        **_sublayer_norms(cfg, norm1((n, h), dtype=dt), "mixer"),
         "k_proj": w(ks[1], h, kv),
         "v_proj": w(ks[2], h, kv),
         "o_proj": w(ks[3], q, h),
@@ -154,8 +170,23 @@ def init_layer_params(
         p["o_proj"] = w(ks[3], cfg.num_heads * cfg.v_head_dim, h)
     if cfg.hc_mult:
         p.update(_init_stream_params(cfg, n, jax.random.fold_in(key, 18)))
-    p.update(_init_ffn_params(cfg, w, n, dense, ks[4:8], key))
+    if not cfg.single_sublayer:  # else the mixer is the layer: no feed-forward drawn
+        p.update(_init_ffn_params(cfg, w, n, dense, ks[4:8], key))
     return p
+
+
+def init_ffn_layer_params(cfg: ModelConfig, key: jax.Array, num_layers: int) -> Params:
+    """Stacked layers that are a feed-forward and no mixer (cfg.single_sublayer:
+    the "moe" kind), `post_norm` the norm on their input: every leaf has
+    leading dim `num_layers`."""
+    n, dt = num_layers, cfg.jnp_dtype
+    norm1 = jnp.zeros if cfg.rms_norm_plus_one else jnp.ones
+
+    def w(k, *shape):
+        return (jax.random.normal(k, (n, *shape), dtype=jnp.float32) * 0.02).astype(dt)
+
+    return {**_sublayer_norms(cfg, norm1((n, cfg.hidden_size), dtype=dt), "ffn"),
+            **_init_ffn_params(cfg, w, n, False, jax.random.split(key, 4), key)}
 
 
 # a layer's two sublayers, as the stream's maps name them
@@ -220,7 +251,7 @@ def init_state_layer_params(cfg: ModelConfig, key: jax.Array, num_layers: int) -
 
     step = jnp.exp(uniform(ks[4], math.log(0.01), math.log(0.5)))
     p = {
-        **_sublayer_norms(cfg, norm1((n, h), dtype=dt)),
+        **_sublayer_norms(cfg, norm1((n, h), dtype=dt), "mixer"),
         "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),  # softplus^-1(step)
         "A_log": jnp.log(uniform(ks[5], 0.1, 1.0)).astype(dt),
     }
@@ -246,7 +277,8 @@ def init_state_layer_params(cfg: ModelConfig, key: jax.Array, num_layers: int) -
             gate_norm=jnp.ones((n, di), dtype=dt),
             out_proj=w(ks[3], di, h),
         )
-    p.update(_init_ffn_params(cfg, w, n, False, ks[6:10], key))
+    if not cfg.single_sublayer:
+        p.update(_init_ffn_params(cfg, w, n, False, ks[6:10], key))
     return p
 
 
@@ -256,15 +288,19 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     dt = cfg.jnp_dtype
     norm1 = jnp.zeros if cfg.rms_norm_plus_one else jnp.ones
     n_state = cfg.layers_of(cfg.state_kind) if cfg.has_state_layers else 0
+    n_ffn = sum(cfg.layers_of(kind) for kind in FFN_KINDS)  # layers that are a feed-forward alone
     params = {
         "embed": (jax.random.normal(k_embed, (cfg.vocab_size, cfg.hidden_size), dtype=jnp.float32) * 0.02).astype(dt),
         "layers": init_layer_params(
-            cfg, k_layers, cfg.num_layers - cfg.num_dense_layers - n_state),
+            cfg, k_layers, cfg.num_layers - cfg.num_dense_layers - n_state - n_ffn),
         "final_norm": norm1((cfg.hidden_size,), dtype=dt),
     }
     if n_state:  # the state kind's stack, beside the attention kind's `layers`
         params["state_layers"] = init_state_layer_params(
             cfg, jax.random.fold_in(k_layers, 2), n_state)
+    if n_ffn:  # and the stack of the layers that are experts alone
+        params["ffn_layers"] = init_ffn_layer_params(
+            cfg, jax.random.fold_in(k_layers, 3), n_ffn)
     if cfg.num_dense_layers:  # a leading group with leaves of its own
         params["dense_layers"] = init_layer_params(
             cfg, jax.random.fold_in(k_layers, 1), cfg.num_dense_layers, dense=True
@@ -298,10 +334,12 @@ def rms_norm(
 
 
 def act_fn(cfg: ModelConfig):
-    """MLP gate activation: SiLU (Qwen/Llama) or tanh-approx GeLU (Gemma —
-    torch's gelu_pytorch_tanh)."""
+    """MLP gate activation: SiLU (Qwen/Llama), tanh-approx GeLU (Gemma —
+    torch's gelu_pytorch_tanh) or the squared ReLU (Nemotron-H)."""
     if cfg.hidden_act == "gelu_tanh":
         return lambda x: jax.nn.gelu(x, approximate=True)
+    if cfg.hidden_act == "relu2":
+        return lambda x: jnp.square(jax.nn.relu(x))
     return jax.nn.silu
 
 
@@ -499,13 +537,15 @@ def swiglu_mlp(
     (multi-tenant registry — ops.lora.apply_lane_delta) adds each lane's
     per-projection LoRA delta BEFORE the activation, matching where a
     merged adapter's weights would act."""
-    gate = act(lora_ops.apply_lane_delta(
-        qdot(x, p["gate_proj"]), x, "gate_proj", lane_adapters
-    ))
+    gate = None  # an ungated feed-forward (cfg.ffn_gated False) is down(act(up(x)))
+    if "gate_proj" in p:
+        gate = act(lora_ops.apply_lane_delta(
+            qdot(x, p["gate_proj"]), x, "gate_proj", lane_adapters
+        ))
     up = lora_ops.apply_lane_delta(
         qdot(x, p["up_proj"]), x, "up_proj", lane_adapters
     )
-    h = gate * up
+    h = act(up) if gate is None else gate * up
     return lora_ops.apply_lane_delta(
         qdot(h, p["down_proj"]), h, "down_proj", lane_adapters
     )
@@ -579,13 +619,16 @@ def _expert_rhs(w) -> jax.Array:
 _EXPERT_BLOCK = 3 * 2**20
 
 
-def expert_row_tile(rows: int, k: int, held: int) -> int:
+def expert_row_tile(rows: int, k: int, held: int, width: int = 0) -> int:
     """The row tile of the routed layer's expert product for `rows` tokens
-    that each chose `k` experts, over `held` experts: how many of the sorted
+    that each chose `k` experts of a router `width` wide (0: `held`), over
+    the `held` experts that are here: how many of the sorted
     (token, expert) pairs one visit of an expert multiplies. 0 = the dense
     product, every row through every held expert. From static shapes alone,
     so that the program and its counter (`moe.rows_multiplied`,
     expert_rows_multiplied) agree and nothing recompiles with the routing.
+    A share of a wider router expects rows x k x held / width pairs here, and
+    it is those that leave held experts untouched or do not.
 
     Measured alone on a v5e (PR 46, PERF.md section 6; ms a layer, dense
     against grouped): under 3 pairs a held expert the rows leave experts
@@ -600,18 +643,25 @@ def expert_row_tile(rows: int, k: int, held: int) -> int:
     multiplies by zero (256 rows 1.98 / 1.91 and 1.77 / 1.78; 512 rows
     3.78 / 2.18, 3.38 / 2.06, 5.27 / 2.75 for 32 held experts of 3072 x 3072),
     and a tile of 64 or 128 feeds the MXU."""
-    if rows * k < 3 * held:
+    if rows * k < 3 * (width or held):  # under 3 expected pairs a held expert
         return 16
     return 0 if rows <= 128 else 128
 
 
-def routed_row_tile(w, rows: int, k: int, held: int) -> int:
+def routed_row_tile(w, rows: int, k: int, held: int, width: int = 0) -> int:
     """expert_row_tile for a routed layer whose expert weight is `w` (or the
     stack it lies in): 0, the dense product, for a quantised weight, which
     the grouped kernel does not take."""
     if isinstance(w, (QuantWeight, Int4Weight)):
         return 0
-    return expert_row_tile(rows, k, held)
+    return expert_row_tile(rows, k, held, width)
+
+
+def routed_weight(params: Params):
+    """An expert weight of the model's routed layers (their up-projection,
+    which every flavour has), in whichever stack holds the routers."""
+    return next(params[g]["up_proj"] for g in ("ffn_layers", "layers", "state_layers")
+                if "router" in params.get(g, ()))
 
 
 def expert_rows_multiplied(counts: np.ndarray, tile: int, rows: int) -> int:
@@ -640,18 +690,27 @@ def _glu(cfg: ModelConfig, gate: jax.Array, up: jax.Array) -> jax.Array:
     return jax.nn.silu(gate) * up
 
 
+def _expert_act(cfg: ModelConfig, gate: Optional[jax.Array], up: jax.Array) -> jax.Array:
+    """What an expert's down-projection reads: _glu of the two products, or
+    for an ungated expert (cfg.ffn_gated False: TWO matrices, no product
+    against a gate, `gate` None) the activation of `up` alone."""
+    return act_fn(cfg)(up) if gate is None else _glu(cfg, gate, up)
+
+
 def expert_ffn(p: Params, cfg: ModelConfig, xt: jax.Array) -> jax.Array:
     """The dense expert product: [T, H] -> [T, E, H], every token through
     every held expert (the caller's combine weights zero what it did not
     choose); biases where the model has them (GPT-OSS). The arm of
     moe_routed_part for a quantised weight and for the few rows that touch
     every expert anyway (expert_row_tile)."""
-    gate = qeinsum("th,ehi->tei", xt, _expert_slab(p["gate_proj"]))
+    gated = "gate_proj" in p
+    gate = qeinsum("th,ehi->tei", xt, _expert_slab(p["gate_proj"])) if gated else None
     up = qeinsum("th,ehi->tei", xt, _expert_slab(p["up_proj"]))
     if cfg.moe_bias:
         gate = gate + p["gate_bias"][None]
         up = up + p["up_bias"][None]
-    expert_out = qeinsum("tei,eih->teh", _glu(cfg, gate, up), _expert_slab(p["down_proj"]))
+    expert_out = qeinsum(
+        "tei,eih->teh", _expert_act(cfg, gate, up), _expert_slab(p["down_proj"]))
     if cfg.moe_bias:
         expert_out = expert_out + p["down_bias"][None]
     return expert_out
@@ -678,7 +737,7 @@ def grouped_expert_ffn(
     accumulate in float32, and a row's K results are weighted and summed in
     float32 and rounded once."""
     t, k = local.shape
-    w = p["gate_proj"]
+    w = p["up_proj"]
     held = experts_held(w)
     n = t * k
     m = -(-n // tile) * tile  # whole tiles: the padding belongs to no group
@@ -701,12 +760,14 @@ def grouped_expert_ffn(
     grouped = (of < held)[:, None]
     of = jnp.minimum(of, held - 1)
     xs = jnp.where(grouped, xt[jnp.minimum(order // k, t - 1)], 0)  # [m, H]
-    gate = jnp.where(grouped, mm(xs, _expert_rhs(w)), 0.0)
-    up = jnp.where(grouped, mm(xs, _expert_rhs(p["up_proj"])), 0.0)
+    gate = None
+    if "gate_proj" in p:
+        gate = jnp.where(grouped, mm(xs, _expert_rhs(p["gate_proj"])), 0.0)
+    up = jnp.where(grouped, mm(xs, _expert_rhs(w)), 0.0)
     if cfg.moe_bias:
         gate = gate + p["gate_bias"][of]
         up = up + p["up_bias"][of]
-    out = mm(_glu(cfg, gate, up).astype(xt.dtype), _expert_rhs(p["down_proj"]))
+    out = mm(_expert_act(cfg, gate, up).astype(xt.dtype), _expert_rhs(p["down_proj"]))
     if cfg.moe_bias:
         out = out + p["down_bias"][of]
     # back to the rows: pair j lies at place back[j] of the sorted order; what
@@ -745,22 +806,29 @@ def moe_routed_part(
     weights zero what it did not choose."""
     with jax.named_scope("moe_route"):
         topw, topi = route_topk(cfg, router_logits(p, cfg, xt), p.get("router_select_bias"))
-    w = p["gate_proj"]
+    w = p["up_proj"]
     held = experts_held(w)
     local = topi - offset
     here = (local >= 0) & (local < held)
-    tile = routed_row_tile(w, *topi.shape, held)
+    tile = routed_row_tile(w, *topi.shape, held, cfg.router_width)
+    if "latent_in_proj" in p:  # LatentMoE: the router read the full width, the experts do not
+        with jax.named_scope("moe_latent_in"):
+            xt = qdot(xt, p["latent_in_proj"])
     with jax.named_scope("moe_experts"):
         if tile:
-            return grouped_expert_ffn(p, cfg, xt, topw, local, here, tile), topi
-        t = xt.shape[0]
-        comb = (  # the combine weights [T, held] f32; column `held`: chosen, not held here
-            jnp.zeros((t, held + 1), jnp.float32)
-            .at[jnp.arange(t)[:, None], jnp.where(here, local, held)]
-            .add(topw)[:, :held]
-        )
-        expert_out = expert_ffn(p, cfg, xt)
-        out = jnp.einsum("teh,te->th", expert_out, comb.astype(expert_out.dtype))
+            out = grouped_expert_ffn(p, cfg, xt, topw, local, here, tile)
+        else:
+            t = xt.shape[0]
+            comb = (  # the combine weights [T, held] f32; column `held`: chosen, not held here
+                jnp.zeros((t, held + 1), jnp.float32)
+                .at[jnp.arange(t)[:, None], jnp.where(here, local, held)]
+                .add(topw)[:, :held]
+            )
+            expert_out = expert_ffn(p, cfg, xt)
+            out = jnp.einsum("teh,te->th", expert_out, comb.astype(expert_out.dtype))
+    if "latent_out_proj" in p:  # ONE projection back, after the combine
+        with jax.named_scope("moe_latent_out"):
+            out = qdot(out, p["latent_out_proj"])
     return out, topi
 
 
@@ -778,8 +846,9 @@ def moe_mlp_routed(p: Params, cfg: ModelConfig, x: jax.Array) -> Tuple[jax.Array
     out, topi = moe_routed_part(p, cfg, xt, cfg.expert_offset)
     if cfg.n_shared_experts:
         with jax.named_scope("moe_shared"):
-            shared = {k: p[f"shared_{k}"] for k in ("gate_proj", "up_proj", "down_proj")}
-            shared_out = swiglu_mlp(shared, xt)
+            shared = {k: p[f"shared_{k}"] for k in ("gate_proj", "up_proj", "down_proj")
+                      if f"shared_{k}" in p}
+            shared_out = swiglu_mlp(shared, xt, jax.nn.silu if cfg.ffn_gated else act_fn(cfg))
             if "shared_expert_gate" in p:  # Qwen3-Next: sigmoid(x . w) on the shared expert
                 with jax.named_scope("moe_shared_gate"):
                     gate = jax.nn.sigmoid(jnp.einsum(
@@ -1923,7 +1992,9 @@ def decoder_layer(
     #   "scale": [B] f32} — slot-0 (base) lanes carry zero A/B and apply
     #   nothing (ops.lora.apply_lane_delta)
 ):
-    """One residual decoder block: a mixer, then a feed-forward, two
+    """One residual decoder block: a mixer, then a feed-forward (of a model
+    whose layers are one sublayer each, whichever of the two the layer's stack
+    holds), two
     independent choices, each sublayer with its RMSNorm where
     cfg.norm_placement puts it: on its input (the pre-norm block), on its
     input and its output (Gemma's sandwich), or on its output alone (Olmo:
@@ -1969,42 +2040,48 @@ def decoder_layer(
         with jax.named_scope("out_norm"):
             return rms_norm(y, w, cfg.rms_norm_eps, p1)
 
-    x, maps = stream_read(lp, cfg, hidden, "attn")
-    if cfg.norm_before:
-        x = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps, p1)
-    if "in_proj" in lp:  # a state layer: its stack holds no q / k / v
-        if tp_axis or ep_axis or adapters is not None or not isinstance(
-                entry, (type(None), cachelib.StateEntry)):
-            raise ValueError(
-                f"{cfg.name}: a state-space layer runs whole on its device over a "
-                "StateEntry (no tensor/expert parallel shard, no adapter)"
-            )
-        mixer = gated_delta_mixer if "ba_proj" in lp else mamba_mixer
-        attn_out, entry = mixer(lp, cfg, x, entry, at, ctx)
-    else:
-        if cfg.is_mla:
-            if (tp_axis or ep_axis or window is not None or adapters is not None
-                    or not isinstance(entry, (type(None), cachelib.LatentEntry))):
+    # a layer of a model whose layers are ONE sublayer each (cfg.single_sublayer)
+    # is what its stack holds: a mixer and nothing after it, or a
+    # feed-forward and no mixer before it; no product stands in for the other
+    if "in_proj" in lp or "o_proj" in lp:
+        x, maps = stream_read(lp, cfg, hidden, "attn")
+        if cfg.norm_before:
+            x = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps, p1)
+        if "in_proj" in lp:  # a state layer: its stack holds no q / k / v
+            if tp_axis or ep_axis or adapters is not None or not isinstance(
+                    entry, (type(None), cachelib.StateEntry)):
                 raise ValueError(
-                    f"{cfg.name}: latent attention runs on the dense lane layout only "
-                    "(no tensor/expert parallel shard, paged pool, ring, window or adapter)"
+                    f"{cfg.name}: a state-space layer runs whole on its device over a "
+                    "StateEntry (no tensor/expert parallel shard, no adapter)"
                 )
-            attn, entry = _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx)
+            mixer = gated_delta_mixer if "ba_proj" in lp else mamba_mixer
+            attn_out, entry = mixer(lp, cfg, x, entry, at, ctx)
         else:
-            attn, entry = _gqa_attend_update(
-                lp, cfg, x, cos, sin, q_positions, entry, at, ctx, window, adapters
-            )
+            if cfg.is_mla:
+                if (tp_axis or ep_axis or window is not None or adapters is not None
+                        or not isinstance(entry, (type(None), cachelib.LatentEntry))):
+                    raise ValueError(
+                        f"{cfg.name}: latent attention runs on the dense lane layout only "
+                        "(no tensor/expert parallel shard, paged pool, ring, window or adapter)"
+                    )
+                attn, entry = _mla_attend_update(lp, cfg, x, cos, sin, q_positions, entry, at, ctx)
+            else:
+                attn, entry = _gqa_attend_update(
+                    lp, cfg, x, cos, sin, q_positions, entry, at, ctx, window, adapters
+                )
 
-        attn_out = lora_ops.apply_lane_delta(
-            qdot(attn, lp["o_proj"]), attn, "o_proj", adapters
-        )
-        if tp_axis is not None:  # row-parallel o_proj: partial sums per rank
-            attn_out = jax.lax.psum(attn_out, tp_axis)
-        if cfg.o_bias:  # replicated bias joins AFTER the partial-sum combine
-            attn_out = attn_out + lp["o_bias"]
-    if cfg.norm_after:  # Gemma, Olmo: the mixer's output normed pre-residual
-        attn_out = out_norm(attn_out, lp["post_norm"])
-    hidden = stream_join(hidden, scaled(attn_out), maps)
+            attn_out = lora_ops.apply_lane_delta(
+                qdot(attn, lp["o_proj"]), attn, "o_proj", adapters
+            )
+            if tp_axis is not None:  # row-parallel o_proj: partial sums per rank
+                attn_out = jax.lax.psum(attn_out, tp_axis)
+            if cfg.o_bias:  # replicated bias joins AFTER the partial-sum combine
+                attn_out = attn_out + lp["o_bias"]
+        if cfg.norm_after:  # Gemma, Olmo: the mixer's output normed pre-residual
+            attn_out = out_norm(attn_out, lp["post_norm"])
+        hidden = stream_join(hidden, scaled(attn_out), maps)
+    if "down_proj" not in lp:
+        return hidden, entry, None
 
     # the feed-forward: dense, or routed experts beside a shared one, whatever
     # the mixer above was
@@ -2100,6 +2177,8 @@ def forward_layers(
     state_layers: Optional[Params] = None,  # a model with state layers: the
     #   state kind's stack, `layers` then being the attention kind's; the two
     #   ride the scan by kind, as a cache split by kind does
+    ffn_layers: Optional[Params] = None,  # and, of a model whose layers are one
+    #   sublayer each, the stack of those that are a feed-forward alone
 ):
     """Run a stack of decoder layers via ONE lax.scan over periods of
     cfg.layer_pattern -> (hidden, entries', chosen experts [L, B, S, K] or
@@ -2130,7 +2209,7 @@ def forward_layers(
     n = _stack_len(layers)
     split = state_layers is not None  # a weight stack per kind
     if split:
-        n += _stack_len(state_layers)
+        n += _stack_len(state_layers) + (_stack_len(ffn_layers) if ffn_layers else 0)
 
     # multi-tenant LoRA: one per-lane gather of the stacked pools, then
     # the layer-leading slices ride the scan as ordinary xs (None = no
@@ -2160,7 +2239,7 @@ def forward_layers(
     def without_experts(stack):  # -> (the stack's other leaves, its expert weights)
         held = {
             name: stack[name] for name in ("gate_proj", "up_proj", "down_proj")
-            if "router" in stack and isinstance(stack[name], jax.Array)
+            if "router" in stack and isinstance(stack.get(name), jax.Array)
         }
         return {name: a for name, a in stack.items() if name not in held}, held
 
@@ -2190,8 +2269,9 @@ def forward_layers(
             "weight stacks from a static offset (one stage, no adapter)"
         )
     if split:  # a weight stack per kind, and beside each its expert weights
-        stacks, experts_of = zip(*(
-            without_experts(state_layers if kind in STATE_KINDS else layers) for kind in uniq))
+        stack_of = lambda kind: (  # noqa: E731
+            state_layers if kind in STATE_KINDS else ffn_layers if kind in FFN_KINDS else layers)
+        stacks, experts_of = zip(*(without_experts(stack_of(kind)) for kind in uniq))
         per_layer = (stacks, None, None)
 
     def place(j):  # of place j in a period: (its kind's stack, its rank among the
@@ -2246,8 +2326,12 @@ def forward_layers(
             return lp, None, None
         return tree if period == 1 else jax.tree.map(lambda a: a[j], tree)
 
-    def pack(vals):  # the period's layers' values -> leaves [period, ...]
-        return vals[0] if period == 1 else jax.tree.map(lambda *a: jnp.stack(a), *vals)
+    def pack(vals):  # the period's layers' values -> leaves [period, ...]; of layers
+        #   with a router and layers without, [the period's routers, ...]
+        if period == 1:
+            return vals[0]
+        vals = [v for v in vals if v is not None]
+        return jax.tree.map(lambda *a: jnp.stack(a), *vals) if vals else None
 
     chosen = []
 
@@ -2273,7 +2357,7 @@ def forward_layers(
             body, (hidden, entries), (fold(per_layer, head, nper), periods)
         )
         if period > 1:  # [nper, period, ...] -> [nper * period, ...]
-            tops = jax.tree.map(lambda a: a.reshape(nper * period, *a.shape[2:]), tops)
+            tops = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), tops)
         chosen.append(tops)
     for i in range(n - tail, n):
         hidden, entries = single(hidden, entries, i)
@@ -2306,6 +2390,7 @@ def forward_layers_cached(
     tp_axis: Optional[str] = None,
     ep_axis: Optional[str] = None,
     state_layers: Optional[Params] = None,  # the state kind's stack (forward_layers)
+    ffn_layers: Optional[Params] = None,  # the stack of layers that are a feed-forward alone
 ):
     """THE cached stage/model forward: every layout of core.cache (dense
     lanes, latent, ring-split, paged pool) goes through here and through
@@ -2315,7 +2400,7 @@ def forward_layers_cached(
     hidden, entries, topi = forward_layers(
         layers, cfg, hidden, positions, cache.entries(cfg),
         cache.ctx(cache_write_pos, real_end, write_mask),
-        tp_axis, ep_axis, layer_offset, cache_offset, adapters, state_layers,
+        tp_axis, ep_axis, layer_offset, cache_offset, adapters, state_layers, ffn_layers,
     )
     return hidden, cache.with_entries(entries), topi
 
@@ -2350,7 +2435,7 @@ def forward_cached(
             layers, cfg, hidden, positions, cache, cache_write_pos,
             real_end, layer_offset=offset, cache_offset=offset,
             write_mask=write_mask, adapters=adapters,
-            state_layers=params.get("state_layers"),
+            state_layers=params.get("state_layers"), ffn_layers=params.get("ffn_layers"),
         )
         if chosen is not None:
             topi = chosen  # the one group with routers
@@ -2565,6 +2650,6 @@ def forward(
     for layers in layer_groups(params):
         hidden, _, _ = forward_layers(
             layers, cfg, hidden, positions, layer_offset=offset,
-            state_layers=params.get("state_layers"))
+            state_layers=params.get("state_layers"), ffn_layers=params.get("ffn_layers"))
         offset += _stack_len(layers)
     return unembed(params, cfg, hidden), None, None

@@ -390,6 +390,7 @@ _LAYER_LINEARS = (
     "in_proj", "out_proj",  # a Mamba-2 layer's two projections (`state_layers`)
     "attn_gate_proj",  # the attention output's gate (cfg.attn_gate)
     "shared_gate_proj", "shared_up_proj", "shared_down_proj",  # the shared expert beside routed ones
+    "latent_in_proj", "latent_out_proj",  # into and out of the experts' latent (cfg.moe_latent_size)
 )
 
 
@@ -411,7 +412,7 @@ def quantize_params(
     """
     out = dict(params)
     qtypes = (QuantWeight, Int4Weight)
-    for group in ("layers", "state_layers"):
+    for group in ("layers", "state_layers", "ffn_layers"):
         if group not in out:
             continue
         layers = dict(out[group])

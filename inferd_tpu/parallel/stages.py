@@ -165,7 +165,7 @@ def extract_stage_params(full: Params, cfg: ModelConfig, spec: StageSpec) -> Par
     """The param subset a stage needs: its layer slice, plus embed on the
     first stage and final-norm/lm-head on the last (reference
     split_model.py:92-102 semantics, as pytree slicing)."""
-    groups = [g for g in ("dense_layers", "state_layers") if g in full]
+    groups = [g for g in ("dense_layers", "state_layers", "ffn_layers") if g in full]
     if groups:
         # more than one stack of layers (models/qwen3.layer_groups, or a
         # stack per kind of layer): kept whole, in the one stage that the

@@ -1462,7 +1462,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
                      for layer in chosen]
         here = slice(cfg.expert_offset, cfg.expert_offset + cfg.num_experts)
         tile = qwen3.routed_row_tile(
-            self.engine.params["layers"]["gate_proj"], rows, chosen.shape[2], cfg.num_experts)
+            qwen3.routed_weight(self.engine.params), rows, chosen.shape[2], cfg.num_experts,
+            cfg.router_width)
         multiplied = sum(
             qwen3.expert_rows_multiplied(c[here], tile, chosen.shape[1]) for c in per_layer)
         with self._mu:
@@ -2007,6 +2008,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
             if self._moe is not None:
                 out["moe"] = dict(self._moe, experts=self.cfg.router_width,
                                   experts_held=self.cfg.num_experts)
+                if self.cfg.moe_latent_size:  # the width the routed experts work in
+                    out["moe"]["latent_size"] = self.cfg.moe_latent_size
             if self._diffusion is not None:
                 out["diffusion"] = dict(self._diffusion)
             if self.adapters is not None:

@@ -4903,6 +4903,8 @@ class Node:
         snap["model"] = {"name": self.info.model_name, "block_length": self._block_length()}
         if self.cfg.hc_mult:  # a residual stream of this many hidden states (absent: the plain add)
             snap["model"]["stream_width"] = self.cfg.hc_mult
+        if self.cfg.single_sublayer:  # layers that are one sublayer each, counted by kind
+            snap["model"]["sublayers"] = self.cfg.sublayer_counts
         if self.compile_cache is not None:
             snap["compile_cache"] = self.compile_cache.as_dict()
         snap["trace"] = self.tracer.stats()

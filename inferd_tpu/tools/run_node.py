@@ -412,7 +412,23 @@ def check_servable(cfg, args, num_stages: int = 1) -> None:
     which the last table refuses. That table also keeps a model whose
     residual is a stream of hidden states (cfg.hc_mult) off every path with a
     boundary inside the model: a mesh tick, a stage's lanes, a relay's hop and
-    a self-draft all hand on one hidden state a token."""
+    a self-draft all hand on one hidden state a token. A model whose layers
+    are ONE sublayer each (cfg.single_sublayer: three weight stacks, by kind)
+    comes first, with the reason that is its own: everything that cuts a
+    model between layers counts a layer as a mixer and its feed-forward."""
+    if cfg.single_sublayer:
+        _refuse(cfg, {
+            "--mesh (a pipeline rank's share is counted in layers of a mixer and a "
+            "feed-forward; stacks of single sublayers are not sharded)": args.mesh,
+            "--stage-lanes (a stage holds one stack of whole layers, not a stack a "
+            "kind of sublayer)": args.stage_lanes > 0,
+            "--paged-kv (the paged pool holds keys and values for every layer, and "
+            "here one sublayer in eleven has any)": args.paged_kv > 0,
+            "--spec-draft-layers (a self-draft is the first layers of ONE stack; a "
+            "recurrent state does not roll back either)": args.spec_draft_layers > 0,
+            "a manifest of several stages (parallel/stages slices one stack of whole "
+            "layers; the three stacks of sublayers are kept whole)": num_stages > 1,
+        })
     if cfg.nope_kinds or cfg.attn_gate or cfg.router_experts:
         _refuse(cfg, {
             "--mesh (a traced rank knows no layer's kind, no sharding rule names the "

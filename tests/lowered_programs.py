@@ -6,7 +6,8 @@ preset keeps its own heads: 2 kv heads of 32 are one row a token, as the
 cell's 2 of 256 are) and the two of `olmoh-reason-chat` (PR 51; its preset
 keeps the published head sizes of its state, 96 x 192, held two heads side by
 side as the cell's are) and the two lane programs of `tiny-xing4` (PR 55: a stream of
-four hidden states around every sublayer), lowered at the tiny presets, and (PR 49) the decode step
+four hidden states around every sublayer) and the two of `tiny-nemotron-h` (PR 57: layers
+that are one sublayer each, three weight stacks by kind), lowered at the tiny presets, and (PR 49) the decode step
 and the prefill of `q4b-*`, (PR 56) of `dsv2l-*` and of `xing-*` once more over lanes of 1024 slots,
 where a slab (a latent lane too) is read by its prefix (models/qwen3.read_rungs: the 64-slot lanes of
 the others are under that rule's floor and keep the text they had):
@@ -56,7 +57,7 @@ def texts() -> dict:
             ("q4b", "tiny", 5, (0, 8)), ("dsv2l", "tiny-dsv2", 16, (0, 8)), ("sdar", "tiny-sdar", 16, ()),
             ("g4hm", "tiny-granite-h", 4, (8,)), ("trinl", "tiny-afmoe", 16, (0,)),
             ("q3n", "tiny-qwen3-next", 16, (0,)), ("olmoh", "tiny-olmo-hybrid", 16, (0,)),
-            ("xing", "tiny-xing4", 16, (0,))):
+            ("xing", "tiny-xing4", 16, (0,)), ("nem3s", "tiny-nemotron-h", 8, (0,))):
         cfg = cell_config(model)
         params = qwen3.init_params(cfg, jax.random.PRNGKey(0))
         eng = BatchedEngine(cfg, params, lanes=lanes, max_len=64)
@@ -105,7 +106,8 @@ NAMES = ("q4b.prefill", "q4b.decode.top0", "q4b.decode.top8", "dsv2l.prefill", "
          "g4hm.prefill", "g4hm.decode.top8", "trinl.prefill", "trinl.decode.top0",
          "q3n.prefill", "q3n.decode.top0", "q4b.prefill.t1024", "q4b.decode.top0.t1024",
          "olmoh.prefill", "olmoh.decode.top0", "xing.prefill", "xing.decode.top0",
-         "dsv2l.prefill.t1024", "dsv2l.decode.top0.t1024", "xing.prefill.t1024", "xing.decode.top0.t1024")
+         "dsv2l.prefill.t1024", "dsv2l.decode.top0.t1024", "xing.prefill.t1024", "xing.decode.top0.t1024",
+         "nem3s.prefill", "nem3s.decode.top0")
 
 
 def digests(found: dict) -> dict:

@@ -719,6 +719,48 @@ def test_xing4_lane_programs_compile_with_the_stream_inside_and_the_latents_in_p
     assert "hc_sinkhorn" in text and "while" in text
 
 
+def test_nemotron_h_share_lane_programs_compile_with_three_stacks_and_the_cache_in_place(
+    one_chip, no_compile_cache, expert_kernel
+):
+    """The two programs a `--model nemotron-3-super-120b-ep4-11l --batch-lanes
+    32 --max-len 4096` node runs, at the published widths: 9.30 GB of weights
+    in three stacks by kind of sublayer (5 Mamba-2, 1 attention, 5 LatentMoE of
+    128 held experts under a 512-wide router), five float32 states
+    `f32[5,32,128,64,128]` and their columns, ONE attention sublayer's keys
+    and values as one row of 256 a token: 0.815 GB of cache. The decode step
+    (with its sampler and the lanes' `active` mask, as the executor calls it)
+    aliases the whole donated cache (the states updated where they lie) and
+    holds under 0.1 GB of temporaries (0.019): no second copy of the state
+    stack, of the slab or of a layer's experts (the grouped product finds
+    them in the stack: Mosaic kernels in both programs). As `[.., 2, 128]`
+    the slab compiled unpadded (T(2,128)) but the prefix a step reads was
+    copied T-minor before its dot, twice a step (core.cache.rows_layout). A
+    512-token prefill chunk: 0.23 GB of temporaries. The numbers are the
+    configuration's `deployment`."""
+    import re
+
+    cfg = get_config("nemotron-3-super-120b-ep4-11l")
+    shapes, step, prefill = _lane_programs(cfg, 32, 4096, one_chip, active=True)
+    assert shapes.k.shape == (1, 32, 4096, 256) and shapes.s.shape == (5, 32, 128, 64, 128)
+    assert shapes.nbytes == 815_136_768 and shapes.state_bytes == 32 * 21_278_720
+    mem = step.memory_analysis()
+    assert 10.10e9 < mem.argument_size_in_bytes < 10.12e9  # 9.296 GB of weights + 0.815 of cache
+    assert mem.alias_size_in_bytes >= shapes.nbytes
+    assert mem.temp_size_in_bytes < 0.1e9
+    text = step.as_text()
+    _assert_kernel(text)
+    assert re.search(r"f32\[5,32,128,64,128\]\{4,3,2,1,0:T\(8,128\)\} parameter", text)
+    copies = re.findall(r"= (\S+?)\{[^ ]* copy\(", text)
+    assert not [c for c in copies
+                if c.startswith(("f32[5,32,128,64,128]", "f32[32,128,64,128]", "bf16[5,128,", "bf16[128,1024,",
+                                 "bf16[128,2688,"))]
+    assert _made_whole(text, r"bf16\[1,32,4096,", r"bf16\[32,4096,\d+[,\]]") == []
+    pm = prefill.memory_analysis()
+    _assert_kernel(prefill.as_text())
+    assert pm.alias_size_in_bytes >= shapes.nbytes and pm.temp_size_in_bytes < 0.4e9
+    assert mem.argument_size_in_bytes + pm.temp_size_in_bytes < 15.75e9 * 0.8
+
+
 def test_llama32_1b_lanes_keep_their_rows_where_they_lie(one_chip, no_compile_cache):
     """The other public model of this head size (8 kv heads of 64, 16
     layers), as `--model llama3.2-1b --batch-lanes 32 --max-len 4096` would

@@ -1040,7 +1040,12 @@ def test_a_cells_program_lowers_to_its_recorded_text(lowered_programs, name):
     `q_lora_rank`) and added the two of `xing.*`. PR 56 left the twenty-two
     as they were (a 64-slot latent lane is under the floor too, and the dense
     callers of `_lanes_read` hand it what they did) and added `dsv2l.*.t1024`
-    and `xing.*.t1024`, where a latent lane is read by its prefix. A PR that
+    and `xing.*.t1024`, where a latent lane is read by its prefix. PR 57 left
+    the twenty-six as they were (a layer's sublayers are optional by what its
+    stack holds, and every held stack holds both; an expert without a gate and
+    a latent sit behind `ffn_gated` and `moe_latent_size`; the row tile is told
+    the router's width, which is the held count at every tiny preset) and
+    added the two of `nem3s.*`. A PR that
     changes one of them on purpose runs `python tests/lowered_programs.py
     --record` and says so."""
     import json
