@@ -131,6 +131,22 @@ class _BlockStep(_Step):
                 ex._count_routing(per_pass, ex.engine.lanes * blk)
 
 
+class _Hop:
+    """One admitted call between `_enter` and `_leave`: what the parts of
+    `process` hand each other. `entry` is the window's, for a hop no thread
+    waits on (`begin_hop`)."""
+
+    __slots__ = ("session_id", "lane", "kind", "toks", "start_pos", "real_len",
+                 "ask", "acquired", "payload", "entry")
+
+    def __init__(self, session_id, lane, kind, toks, start_pos, real_len, ask,
+                 acquired, payload):
+        self.session_id, self.lane, self.kind, self.toks = session_id, lane, kind, toks
+        self.start_pos, self.real_len, self.ask = start_pos, real_len, ask
+        self.acquired, self.payload = acquired, payload
+        self.entry = None
+
+
 class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
     """Whole-model, lane-per-session executor with windowed decode batching.
 
@@ -182,6 +198,12 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
         # and those answered with their [V] logits row (/stats `executor`)
         self.sampled_rows = 0
         self.logit_rows = 0
+        # decode and block hops by who carried them (/stats `executor`):
+        # answered on their caller's event loop, no worker thread taken
+        # (`begin_hop` / `end_hop`), or served on one (`process`; a hop that
+        # rode a step and waited for it)
+        self.hops_inline = 0
+        self.hops_pooled = 0
         # one step ahead (dense lanes, `_decode_ahead`; a row is a token's,
         # or a whole block's for a model generated by blocks): lane -> the
         # row a step ran before its hop arrived, unclaimed; lane -> (the step
@@ -621,29 +643,109 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
     # -- executor contract ---------------------------------------------------
 
     def process(self, session_id: str, payload: Dict[str, Any]) -> Dict[str, Any]:
+        """One call, on the caller's thread, in three parts: before the
+        window (`_enter`), the device's part (a prefill's chunks; a decode
+        or block hop's wait in the window), after it (`_leave`, `_reply`).
+        A caller that must not block makes the first and the last itself,
+        around an entry no thread waits on: `begin_hop` / `end_hop`."""
+        hop = self._enter(session_id, payload)
+        try:
+            try:
+                if hop.kind == "prefill":
+                    res = self._prefill_solo(
+                        session_id, hop.lane, hop.toks, hop.start_pos, hop.real_len,
+                        want_logits=payload.get("want_logits", True),
+                    )
+                else:
+                    res = self._decode_batched(session_id, hop.lane, *self._asks(hop))
+            finally:
+                self._leave(hop, pooled=True)
+            return self._reply(hop, res)
+        except Exception:
+            self._unacquire(hop.acquired)
+            raise
+
+    def _unacquire(self, acquired) -> None:
+        """A call died: an admission that died before _bind_adapter_locked
+        consumed the reference must give it back, or the slot leaks a
+        refcount and can never be evicted."""
+        if acquired is not None and acquired[1]:
+            self.adapters.release(acquired[0])
+
+    #: the longest a caller that must not block waits for `_mu` (`begin_hop`,
+    #: `end_hop`): its holders are short and host-only but for a session's
+    #: export or import, which reads or writes the lane's slab under it
+    LOOP_MU_WAIT_S = 0.002
+
+    def begin_hop(self, session_id: str, payload: Dict[str, Any], hand) -> "Optional[_Hop]":
+        """The part of `process` before the window, for a caller that must
+        not block (the node's event loop): the call is admitted as
+        `process` admits it, by the same code, and handed to the window
+        with no thread waiting on it (WindowedBatcher.submit_nowait: `hand`
+        is called once the entry is answered, with every other entry of the
+        drain that answered it). Returns the hop, to be ended by `end_hop`
+        then; raises what `process` would have raised before its wait.
+
+        None where this is not a call to make that way, and nothing has
+        been done: anything but a decode or block hop of a known session on
+        dense lanes whose ask a step's sampler covers (`_decode_ahead`'s
+        hops; a prefill chunk spends tens of ms inside device calls, K-step
+        and paged dispatches wait for their step inside the drain, a
+        speculating executor runs nothing ahead), or `_mu` was not to be
+        had within LOOP_MU_WAIT_S (somebody exports or imports a
+        session). `process` serves those, on a thread."""
+        hop = self._enter(session_id, payload, loop=True)
+        if hop is None:
+            return None
+        try:
+            hop.entry = self._batcher.submit_nowait((hop.lane, *self._asks(hop)), hand)
+        except Exception:
+            self._leave(hop, pooled=None)
+            raise
+        return hop
+
+    def end_hop(self, hop: "_Hop", block: bool = True) -> Optional[Dict[str, Any]]:
+        """The part of `process` after the window, once the hop's entry has
+        been handed back: the reply, or the entry's error raised. With
+        `block` false (the event loop) None where ending the hop would
+        block, and nothing has been done: the drain answered with a step to
+        ride, which is waited for (`_ridden`), or `_mu` is held for long;
+        a worker thread calls again, with `block`."""
+        e = hop.entry
+        if e.error is None and isinstance(e.result, _Step):
+            if not block:
+                return None
+            self._batcher.record_waits(e)
+            try:
+                res = self._ridden(e.result, hop.lane)
+            finally:
+                self._leave(hop, pooled=True)
+            return self._reply(hop, res)
+        if not self._leave(hop, pooled=block, wait_s=-1 if block else self.LOOP_MU_WAIT_S):
+            return None
+        self._batcher.record_waits(e)
+        if block and e.t_out is not None:
+            tracelib.mark("t_out", e.t_out)  # `deliver` ends with this worker (Node._timed)
+        if e.error is not None:
+            raise e.error
+        return self._reply(hop, e.result)
+
+    def _enter(self, session_id: str, payload: Dict[str, Any], loop: bool = False):
+        """Before the window or the device: what the call is, whether its
+        session may make it now (under `_mu`; no device call), its lane.
+        `loop`: for `begin_hop`, which see for the None."""
         start_pos = int(payload.get("start_pos", 0))
+        if loop and (
+            start_pos <= 0 or self.pool is not None or self._spec is not None
+            or payload.get("adapter") is not None  # `_resolve_adapter` takes `_mu` for it
+        ):
+            return None
         # a session's first call: where its `lane` span begins (once a request)
         t_in = tracelib.now() if start_pos == 0 else None
         toks = np.asarray(payload["tokens"], dtype=np.int32)
         if toks.ndim != 2 or toks.shape[0] != 1:
             raise ValueError(f"batched stage expects tokens [1, S], got {toks.shape}")
         real_len = int(payload.get("real_len", toks.shape[1]))
-
-        acquired = self._resolve_adapter(session_id, payload, start_pos)
-        try:
-            return self._process_inner(
-                session_id, payload, toks, start_pos, real_len, acquired, t_in
-            )
-        except Exception:
-            # an admission that died before _bind_adapter_locked consumed
-            # the reference must give it back, or the slot leaks a
-            # refcount and can never be evicted
-            if acquired is not None and acquired[1]:
-                self.adapters.release(acquired[0])
-            raise
-
-    def _process_inner(self, session_id: str, payload: Dict[str, Any],
-                       toks, start_pos: int, real_len: int, acquired, t_in=None):
         kind = call_kind(payload)
         decode = kind == "decode"
         blk = self.cfg.block_length
@@ -663,7 +765,12 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
         # a hop that asks for its token (parse_decode_ask) is answered with
         # it; any other with its logits row; a block hop's ask is its call
         ask = parse_decode_ask(payload) if decode and not payload.get("decode_steps") else call
-        with self._mu:
+        if loop and ask is None:
+            return None
+        acquired = self._resolve_adapter(session_id, payload, start_pos)
+        if not self._mu.acquire(timeout=self.LOOP_MU_WAIT_S if loop else -1):
+            return None
+        try:  # under _mu: the admission; the session is in flight from here to `_leave`
             if self._inflight.get(session_id):
                 # a duplicate/replayed request racing the original would
                 # pass the frontier check and double-advance the lane
@@ -758,43 +865,64 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
                 # inside a prefill: no decode step waits for this lane
                 # until one has served it again
                 self._batcher.unexpect(lambda p, _lane=lane: p[0] == _lane)
-        self._lane_span(t_in, lane, bound)  # process entered -> _mu released
-
-        try:
-            if call is not None:
-                res = self._decode_batched(session_id, lane, toks[0, :blk], call)
-                return {**res, "real_len": blk, "start_pos": start_pos}
-            if decode:
-                ks = parse_kstep(payload, self.cap - start_pos)
-                if ks is not None:
-                    # multi-step fused decode: K on-device-sampled tokens
-                    # per dispatch; co-arrived K-step lanes fuse into one
-                    # K-step scan (see _run_decode_batch)
-                    res = self._decode_batched(
-                        session_id, lane, int(toks[0, 0]), ks
-                    )
-                    return {**res, "start_pos": start_pos}
-                res = self._decode_batched(session_id, lane, int(toks[0, 0]), ask)
-                if isinstance(res, dict):
-                    return {**res, "real_len": 1, "start_pos": start_pos}
-                logits, saved = res, 0
-            else:
-                logits, saved = self._prefill_solo(
-                    session_id, lane, toks, start_pos, real_len,
-                    want_logits=payload.get("want_logits", True),
-                )
+        except Exception:
+            self._unacquire(acquired)
+            raise
         finally:
-            with self._mu:
-                self._inflight.pop(session_id, None)
-                if self._dying.get(lane) == session_id:  # ended mid-request
-                    del self._dying[lane]
-                    self._free_lane(lane)
+            self._mu.release()
+        self._lane_span(t_in, lane, bound)  # process entered -> _mu released
+        return _Hop(session_id, lane, kind, toks, start_pos, real_len, ask, acquired, payload)
+
+    def _asks(self, hop: "_Hop"):
+        """What the window is handed beside the hop's lane: its token (a
+        block hop: its block's tokens) and its ask (a K-step call: its
+        budget-clamped parse_kstep; None: its logits row)."""
+        if hop.kind == "block":
+            return hop.toks[0, :self.cfg.block_length], hop.ask
+        ks = parse_kstep(hop.payload, self.cap - hop.start_pos)
+        # multi-step fused decode: K on-device-sampled tokens per dispatch;
+        # co-arrived K-step lanes fuse into one K-step scan (see
+        # _run_decode_batch)
+        return int(hop.toks[0, 0]), hop.ask if ks is None else ks
+
+    def _leave(self, hop: "_Hop", pooled: Optional[bool], wait_s: float = -1) -> bool:
+        """After the window or the device: the session is no longer in
+        flight, and a lane whose session ended meanwhile is freed. Counts
+        the decode or block hop as one a worker thread served (`pooled`) or
+        none did (None: the hop never reached the window, not counted).
+        False where `_mu` was not to be had within `wait_s` (nothing
+        done)."""
+        if not self._mu.acquire(timeout=wait_s):
+            return False
+        try:
+            self._inflight.pop(hop.session_id, None)
+            if self._dying.get(hop.lane) == hop.session_id:  # ended mid-request
+                del self._dying[hop.lane]
+                self._free_lane(hop.lane)
+            if pooled is not None and hop.kind != "prefill":
+                if pooled:
+                    self.hops_pooled += 1
+                else:
+                    self.hops_inline += 1
+        finally:
+            self._mu.release()
+        return True
+
+    def _reply(self, hop: "_Hop", res) -> Dict[str, Any]:
+        """The call's reply from what the window or the prefill gave."""
+        if isinstance(res, dict):
+            if "decode_steps" in res:  # a K-step call says what it committed
+                return {**res, "start_pos": hop.start_pos}
+            # a block hop; a decode hop answered with its token
+            width = self.cfg.block_length if hop.kind == "block" else 1
+            return {**res, "real_len": width, "start_pos": hop.start_pos}
+        logits, saved = (res, 0) if hop.kind == "decode" else res
         return {
             # None where the caller asked for none (`want_logits` false: a
             # chunk of a prompt whose last row nobody samples from)
             "logits": None if logits is None else logits[None, :],
-            "real_len": real_len,
-            "start_pos": start_pos,
+            "real_len": hop.real_len,
+            "start_pos": hop.start_pos,
             # per-request shared-prefix saving (stage_batch contract):
             # span attr + kv.saved_tokens at the node, stripped before
             # the reply; omitted on cold prefills
@@ -1975,6 +2103,8 @@ class BatchedExecutor(SpecServing, AdapterBindingMixin, StepAhead):
                 ahead_rows=self.ahead_rows,
                 ahead_claimed=self.ahead_claimed,
                 ahead_dropped=self.ahead_dropped,
+                hops_inline=self.hops_inline,
+                hops_pooled=self.hops_pooled,
                 **self._admit.stats(),
                 **self._batcher.stats(),
             )

@@ -253,8 +253,10 @@ class TaskScheduler:
     with what its record was built at."""
 
     def __init__(self, workers: int = 2):
-        self.entered = 0  # hops handed to the pool
-        self.left = 0  # hops whose worker is done (or raised)
+        # hops taken in (handed to the pool, or to the executor's window
+        # from the loop: Node._process_hop) and hops done (or raised)
+        self.entered = 0
+        self.left = 0
         self._pool = ThreadPoolExecutor(max_workers=workers, thread_name_prefix="stage")
 
     @property
@@ -273,13 +275,17 @@ class TaskScheduler:
         """run(), and beside the result the moment this coroutine ran
         again on the event loop once the worker was done: where the hop's
         `resume` span ends."""
-        loop = asyncio.get_running_loop()
         self.entered += 1
         try:
-            out = await loop.run_in_executor(self._pool, fn, *args)
+            out = await self.offload(fn, *args)
             return out, tracelib.now()
         finally:
             self.left += 1
+
+    def offload(self, fn, *args) -> "asyncio.Future":
+        """`fn` on a worker of the pool, uncounted: part of a hop that is
+        counted already, or work that is nobody's hop."""
+        return asyncio.get_running_loop().run_in_executor(self._pool, fn, *args)
 
     def shutdown(self) -> None:
         self._pool.shutdown(wait=False)
@@ -1806,11 +1812,8 @@ class Node:
                     executor.window.submit, (session_id, env, tin, t_q)
                 )
             else:
-                (result, pure_ms, w0, w1, cctx), t_res = (
-                    await self.scheduler.run_stamped(
-                        self._timed_process, executor, session_id,
-                        env.get("payload", {}), tin,
-                    )
+                (result, pure_ms, w0, w1, cctx), t_res = await self._process_hop(
+                    executor, session_id, env.get("payload", {}), tin,
                 )
         except BufferError as e:  # KV budget exceeded: deterministic
             # the executors' BufferError now names the session AND lane
@@ -2475,6 +2478,111 @@ class Node:
         body = {"ok": ok, "length": have} if ok else {"ok": False, "have": have}
         return web.Response(body=wire.pack(body))
 
+    async def _process_hop(self, executor, session_id: str,
+                           payload: Dict[str, Any], tin=None):
+        """One executor call and where it ran: ((the result, its pure
+        compute time in ms, its start and end stamps, the `compute` span's
+        context), the moment this coroutine ran again).
+
+        Where the executor offers the call a form that needs no thread
+        (`begin_hop`: runtime/batch_executor.py says which calls; what
+        decides is the payload and the executor, nothing else) the hop
+        stays on the event loop: it is admitted here, handed to the
+        executor's window, and this coroutine sleeps on a future that the
+        drain which answers it resolves, with the futures of every other
+        hop it answered, in ONE `call_soon_threadsafe` (`_hops_answered`).
+        `compute` then runs from the admission to that hand-over, `deliver`
+        from the step's copy-out to it, `resume` from it to here, and
+        `queue` is the few microseconds before the admission. Only a hop
+        whose answer is a step still to be waited for (a request's first:
+        `end_hop` says so) hands that wait to a worker. Any other call
+        (`begin_hop` absent or None) takes a worker for its whole length,
+        `_timed_process`."""
+        begin = getattr(executor, "begin_hop", None)
+        hop = cctx = None
+        if begin is not None:
+            token = None
+            if tin is not None and tracelib.enabled():
+                # current while the entry is made: its waits parent to it
+                cctx = tin.child()
+                token = tracelib.set_current(cctx)
+            try:
+                w0 = tracelib.now()
+                hop = begin(session_id, payload, self._hops_answered)
+            finally:
+                if token is not None:
+                    tracelib.reset_current(token)
+        if hop is None:
+            return await self.scheduler.run_stamped(
+                self._timed_process, executor, session_id, payload, tin,
+            )
+        sched = self.scheduler
+        sched.entered += 1
+        try:
+            # the window's hand-over finds the future on the hop's entry
+            fut = hop.entry.waiter = asyncio.get_running_loop().create_future()
+            try:
+                await fut
+            except asyncio.CancelledError:
+                self._abandon_hop(executor, hop, fut)
+                raise
+            t_res = tracelib.now()
+            entry = hop.entry
+            w1 = entry.t_handed
+            result = executor.end_hop(hop, block=False)
+            if result is None:
+                # a step to wait for, or the session table is held for
+                # long: the rest of the hop is a worker's
+                result, _, _, w1, _ = await sched.offload(
+                    self._timed, executor, cctx, executor.end_hop, hop,
+                )
+                t_res = tracelib.now()
+            elif cctx is not None and entry.t_out is not None:
+                self.tracer.record_span(
+                    "deliver", "deliver", entry.t_out, w1, parent=cctx,
+                )
+            return (result, (w1 - w0) * 1e3, w0, w1, cctx), t_res
+        finally:
+            sched.left += 1
+
+    def _hops_answered(self, entries) -> None:
+        """The window's hand-over (any thread): the hops of `entries` have
+        their answers. ONE wake-up of the loop for all of them."""
+        try:
+            self._loop.call_soon_threadsafe(self._resume_hops, entries)
+        except RuntimeError:
+            pass  # the loop is closed: nobody waits any more
+
+    @staticmethod
+    def _resume_hops(entries) -> None:
+        for e in entries:
+            if not e.waiter.done():
+                e.waiter.set_result(None)
+
+    def _abandon_hop(self, executor, hop, fut) -> None:
+        """The hop's coroutine was cancelled while its entry was in the
+        window (its caller went away): the entry still gets its answer,
+        and a worker ends the hop then as `process` would have (the
+        session leaves flight, a dying lane is freed)."""
+        def end(_=None):
+            try:
+                self.scheduler.offload(self._end_quietly, executor, hop)
+            except RuntimeError:
+                pass  # the pool is shut down
+
+        if fut.done() and not fut.cancelled():
+            end()
+        else:
+            hop.entry.waiter = asyncio.get_running_loop().create_future()
+            hop.entry.waiter.add_done_callback(end)
+
+    @staticmethod
+    def _end_quietly(executor, hop) -> None:
+        try:
+            executor.end_hop(hop)
+        except Exception:
+            pass  # nobody is left to tell
+
     def _timed_process(self, executor, session_id: str,
                        payload: Dict[str, Any], tin=None):
         """Executor call + its pure compute time in ms and wall-clock
@@ -2483,20 +2591,27 @@ class Node:
         compute span and bound the queue span). The executor is passed
         in, bound at request entry — see handle_forward's migration-race
         note."""
-        # the compute span's context is allocated NOW and made current in
-        # this worker thread, so what the executor stamps inside the call
-        # (obs.trace.region: batch_wait, lock_wait, device, copy_out)
-        # parents to the span the caller records afterwards
-        ctx = token = None
+        ctx = tin.child() if tin is not None and tracelib.enabled() else None
+        return Node._timed(executor, ctx, executor.process, session_id, payload)
+
+    @staticmethod
+    def _timed(executor, ctx, call, *args):
+        """`call(*args)` on this worker thread under the `compute` span's
+        context `ctx` (None: no span is wanted), timed: what
+        `_timed_process` returns."""
+        # the compute span's context is allocated by the caller and made
+        # current in this worker thread, so what the executor stamps inside
+        # the call (obs.trace.region: batch_wait, lock_wait, device,
+        # copy_out) parents to the span the caller records afterwards
+        token = None
         marks: Dict[str, float] = {}
-        if tin is not None and tracelib.enabled():
-            ctx = tin.child()
+        if ctx is not None:
             token = tracelib.set_current(ctx)
             marks, mtoken = tracelib.open_marks()
         try:
             w0 = tracelib.now()
             t = time.perf_counter()
-            result = executor.process(session_id, payload)
+            result = call(*args)
             pure_ms = (time.perf_counter() - t) * 1e3
         finally:
             if token is not None:

@@ -33,6 +33,14 @@ Three modes, and who runs each (docs/SERVING.md):
     the step, and recorded as a parentless `turn` span by the flusher
     once its step is delivered (docs/OBSERVABILITY.md "Between two
     steps"); `stamp_out` is where each served entry's `deliver` begins.
+    An entry of this mode need not have a thread waiting on it
+    (`submit_nowait`: a caller that must not block, the node's event
+    loop): it joins the same queue and the same formation, and the drain
+    that answers it hands it, with every other such entry it answered, to
+    the submitter's callback in ONE call. Where such an entry finds no
+    flusher, a thread the window owns becomes one (`_own`); one that is
+    not answered within the wait timeout is failed by the window's
+    watcher (`_watch`) as a blocked thread would have failed itself.
   * `swap_in_run` + `gang_target`: `--stage-lanes` (the window lives on
     the node, runtime/node.py `_attach_window` + runtime/stage_batch.py).
     The flusher polls until `gang_target()` entries are pending or
@@ -45,6 +53,7 @@ Three modes, and who runs each (docs/SERVING.md):
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from typing import Any, Callable, Dict, Hashable, List, Optional  # noqa: F401
@@ -52,14 +61,28 @@ from typing import Any, Callable, Dict, Hashable, List, Optional  # noqa: F401
 from inferd_tpu.obs import trace as tracelib
 from inferd_tpu.utils import lockwatch
 
+log = logging.getLogger(__name__)
+
 
 class Entry:
     __slots__ = ("payload", "event", "result", "error",
-                 "t_submit", "t_lock", "t_taken", "t_out", "ctx")
+                 "t_submit", "t_lock", "t_taken", "t_out", "ctx",
+                 "hand", "t_handed", "waiter", "i_flush")
 
-    def __init__(self, payload: Any):
+    def __init__(self, payload: Any, hand: Optional[Callable[[list], None]] = None):
         self.payload = payload
-        self.event = threading.Event()
+        # a thread sleeps on `event`; an entry submitted without one
+        # (`submit_nowait`) names `hand` instead: what the window calls,
+        # once, with all the entries of that submitter it answered
+        # together. `t_handed` is when (None: not yet); `waiter` is the
+        # submitter's own (what its callback finds its way back by)
+        self.event = threading.Event() if hand is None else None
+        self.hand = hand
+        self.t_handed: Optional[float] = None
+        self.waiter: Any = None
+        # this entry's submit found no flusher and made one: its own thread,
+        # or the window's on its behalf (the `flusher` of its `batch_wait`)
+        self.i_flush = False
         self.result: Any = None
         self.error: Optional[Exception] = None
         # the arrival-window wait: submit() entry -> a flush takes the
@@ -138,6 +161,15 @@ class WindowedBatcher:
         # session -> its last served payload, for the last step and the
         # one before it: the sessions a formation waits for
         self._served: List[Dict[Hashable, Any]] = [{}, {}]
+        # entries without a thread (`submit_nowait`), until they are handed
+        # back, oldest first; the formation such an entry found without a
+        # flusher is `_owed` to the window's own thread (its `wait`), which
+        # sleeps on `_own_cv`; the window's two threads (`_own`, `_watch`)
+        # are started with the first such entry and stay
+        self._threadless: Dict[Entry, None] = {}
+        self._owed: Optional[tuple] = None
+        self._own_cv = threading.Condition(self._mu)
+        self._own_started = False
         self._delivered: Dict[Hashable, float] = {}  # session -> result out
         self._turn_s = window_s  # running mean: result out -> next submit
         self._step_s: Optional[float] = None  # the last measured step
@@ -218,7 +250,7 @@ class WindowedBatcher:
         entry = Entry(payload)
         with self._mu:
             self._pending.append(entry)
-            i_flush = not self._flusher_active
+            i_flush = entry.i_flush = not self._flusher_active
             if i_flush:
                 self._flusher_active = True
             wait = self._co_possible()
@@ -227,21 +259,29 @@ class WindowedBatcher:
         try:
             return self._serve(entry, i_flush, wait)
         finally:
-            if self.tracer is not None and entry.t_taken is not None:
-                t_wait = entry.t_submit
-                if entry.t_lock is not None:
-                    t_wait = entry.t_lock
-                    self.tracer.record_span(
-                        "lock_wait", "lock_wait", entry.t_submit, t_wait,
-                        parent=entry.ctx, attrs={"kind": self.kind},
-                    )
-                self.tracer.record_span(
-                    "batch_wait", "batch_wait", t_wait, entry.t_taken,
-                    parent=entry.ctx, attrs={"flusher": int(i_flush)},
-                )
-                if entry.t_out is not None:
-                    # handed up to whoever records this call's `compute`
-                    tracelib.mark("t_out", entry.t_out)
+            self.record_waits(entry)
+            if self.tracer is not None and entry.t_out is not None:
+                # handed up to whoever records this call's `compute`
+                tracelib.mark("t_out", entry.t_out)
+
+    def record_waits(self, entry: Entry) -> None:
+        """The `lock_wait` and `batch_wait` spans of an entry a drain took,
+        under the span that was current at its submit: by the submitter,
+        once the entry is answered (`submit` itself; whoever called
+        `submit_nowait`, when the entry is handed back)."""
+        if self.tracer is None or entry.t_taken is None:
+            return
+        t_wait = entry.t_submit
+        if entry.t_lock is not None:
+            t_wait = entry.t_lock
+            self.tracer.record_span(
+                "lock_wait", "lock_wait", entry.t_submit, t_wait,
+                parent=entry.ctx, attrs={"kind": self.kind},
+            )
+        self.tracer.record_span(
+            "batch_wait", "batch_wait", t_wait, entry.t_taken,
+            parent=entry.ctx, attrs={"flusher": int(entry.i_flush)},
+        )
 
     def _await(self, entry: Entry, where: str) -> Any:
         """Block until another thread's step delivers `entry`."""
@@ -324,6 +364,107 @@ class WindowedBatcher:
             raise entry.error
         return entry.result
 
+    # -- entries without a thread (formation only) ---------------------------
+
+    def submit_nowait(self, payload: Any, hand: Callable[[List[Entry]], None]) -> Entry:
+        """`submit` for a caller that must not block: the entry joins the
+        queue and the formation as any other and this returns at once.
+        When the entry has its result or its error (`entry.result`,
+        `entry.error`: what `submit` would have returned or raised) the
+        window calls `hand` with a list that holds it: ONE call for all the
+        entries of this `hand` that one drain answered (or one
+        invalidation, one failure, one sweep of the watcher), from the
+        thread that answered them, `t_handed` stamped on each. The spans of
+        its wait are the caller's to record then (`record_waits`)."""
+        if self._expect is None:
+            raise ValueError("submit_nowait needs a formation (expect)")
+        entry = Entry(payload, hand)
+        with self._mu:
+            self._pending.append(entry)
+            self._threadless[entry] = None
+            if not self._own_started:
+                self._own_started = True
+                for name, target in (("window-flush", self._own), ("window-watch", self._watch)):
+                    threading.Thread(target=target, name=name, daemon=True).start()
+            if not self._flusher_active:
+                # nobody forms: the window's own thread does, on this
+                # entry's behalf (one wake-up a formation, whoever else
+                # arrives joins it)
+                self._flusher_active = entry.i_flush = True
+                self._owed = (self._co_possible(), entry.ctx)
+                self._own_cv.notify()
+            self._returned(entry)
+        return entry
+
+    def _own(self) -> None:
+        """The window's own flusher: sleeps until a formation is owed to it
+        (`submit_nowait` found none active) and runs it as a submitting
+        thread would (`_flush_formed`)."""
+        while True:
+            with self._mu:
+                while self._owed is None:
+                    self._own_cv.wait()
+                (wait, ctx), self._owed = self._owed, None
+            # what the flush stamps (a step's `device` and `copy_out`) hangs
+            # where it would had the entry's submitter flushed itself: under
+            # the span that was current at that submit
+            token = tracelib.set_current(ctx)
+            try:
+                self._flush_formed(None, wait)
+            except Exception:  # the entries have their own errors by now
+                log.exception("window: the flush of a formation failed")
+            finally:
+                tracelib.reset_current(token)
+
+    def _watch(self) -> None:
+        """What a blocked thread does for itself (`_await`), for the entries
+        that have none: one not answered `wait_timeout_s` after its submit
+        gets the same TimeoutError, leaves the same `window.stall` event,
+        and is handed back. Sleeps until the oldest such entry's limit."""
+        while True:
+            with self._mu:
+                now = tracelib.now()
+                late, nap = [], self._wait_timeout_s
+                for e in self._threadless:  # a few: at most one a session
+                    left = e.t_submit + self._wait_timeout_s - now
+                    if left <= 0:
+                        late.append(e)
+                    else:
+                        nap = min(nap, left)
+                handed = self._mark_handed(
+                    late, TimeoutError("batched decode flusher never completed")
+                )
+            for mine in handed.values():
+                for _ in mine:
+                    self._stall("co_arrival")
+            self._hand(handed)
+            time.sleep(max(nap, 0.005))
+
+    def _mark_handed(self, entries, error: Optional[Exception] = None):
+        """Under self._mu: of `entries`, those without a thread that nobody
+        has handed back yet, by their `hand`, stamped as handed now (an
+        entry is handed once, whoever comes first: its drain, an
+        invalidation, the watcher); `error` is theirs if they had none."""
+        handed: Dict[Any, List[Entry]] = {}
+        now = tracelib.now()
+        for e in entries:
+            if e.hand is not None and e.t_handed is None:
+                e.t_handed = now
+                if error is not None and e.error is None:
+                    e.error = error
+                self._threadless.pop(e, None)
+                handed.setdefault(e.hand, []).append(e)
+        return handed
+
+    @staticmethod
+    def _hand(handed) -> None:
+        """ONE call of each submitter's `hand`, with all of its entries."""
+        for hand, mine in handed.items():
+            try:
+                hand(mine)
+            except Exception:  # a submitter's fault is not the step's
+                log.exception("window: handing %d entries back failed", len(mine))
+
     # -- formation (expect=...) ---------------------------------------------
 
     def _returned(self, entry: Entry) -> None:
@@ -392,7 +533,7 @@ class WindowedBatcher:
         self._t_formed = tracelib.now()
         self._formed = (how, owed)
 
-    def _flush_formed(self, entry: Entry, wait: bool) -> Any:
+    def _flush_formed(self, entry: Optional[Entry], wait: bool) -> Any:
         """Form, let the callback take the device and drain, then note
         whom the step served and when, and deliver."""
         # drain_pending() leaves the step's entries and the turn it ended
@@ -431,11 +572,26 @@ class WindowedBatcher:
                 self._served = [served, self._served[0]]
                 self._delivered.update(dict.fromkeys(served, now))
             self._cv.notify_all()
+        # each entry has its result or its error: the threads that sleep on
+        # theirs are woken first, as ever; the entries without one go back
+        # to their submitters once the `turn` span is recorded (such an
+        # entry may end its request the moment it has its answer, and the
+        # step's spans are whole by then, as they were when the flusher's
+        # own hop could not end before this returned)
+        threadless = False
         for e in batch:
-            e.event.set()
+            if e.hand is None:
+                e.event.set()
+            else:
+                threadless = True
         if drained and ticket[1] is not None:
             self._record_turn(batch, *ticket[1])
-        return self._await(entry, "formation")
+        if threadless:
+            with self._mu:
+                handed = self._mark_handed(batch)
+            self._hand(handed)
+        # the window's own thread (`_own`) has no entry to wait for
+        return None if entry is None else self._await(entry, "formation")
 
     def _record_turn(
         self, batch: List[Entry], t_freed: float, t_drain: float,
@@ -585,13 +741,17 @@ class WindowedBatcher:
         flush are the executor's responsibility via its in-flight
         accounting)."""
         with self._mu:
-            still = []
+            still, failed = [], []
             for e in self._pending:
                 if pred(e.payload):
                     e.error = error
-                    e.event.set()
+                    failed.append(e)
+                    if e.hand is None:
+                        e.event.set()
                 else:
                     still.append(e)
             self._pending[:] = still
+            handed = self._mark_handed(failed)
             if self._expect is not None:
                 self._unexpect(pred)
+        self._hand(handed)

@@ -11,13 +11,25 @@ Each topology is served once (a module fixture): one node of three lanes
 two chunks; four streamed /generate requests: a long one, two newcomers side by
 side once the long one is decoding (their first hops RIDE: the long one's rows
 are run ahead, so no drain waits for them), and one more when all are done (it
-takes a lane that has stood free)."""
+takes a lane that has stood free).
+
+A rider gives the next drain ONE STEP'S TIME to come and run the row it
+promised (`StepAhead._ridden`: `released.wait`), else its next hop is back first
+and rides again. The tiny model's step is a millisecond or two on the CPU,
+under a host turn on any busy machine (the reverse of a chip's), so a newcomer
+rode twice whenever the long session's turn was slow: one run in six of
+`-k "tiled or counters"` beside eight busy processes, on the parent tree as on
+this one (PERF.md section 7, PR 58 (f)). The topologies are therefore served
+with that wait held at the code's own cap, whatever the step took
+(`_patient_riders`): a released rider still goes at once, and one whom no drain
+releases still rides again."""
 
 import asyncio
 import importlib.util
 import json
 import os
 import sys
+import threading
 
 import aiohttp
 import jax
@@ -30,7 +42,7 @@ from inferd_tpu.models import qwen3
 from inferd_tpu.obs import trace as tracelib
 from inferd_tpu.parallel.mesh import MeshPlan
 from inferd_tpu.parallel.stages import Manifest, split_and_save
-from inferd_tpu.runtime import wire
+from inferd_tpu.runtime import step_ahead, wire
 from inferd_tpu.runtime.node import Node, NodeInfo
 from inferd_tpu.runtime.window import Entry
 
@@ -222,6 +234,24 @@ def test_a_held_lock_says_how_long_it_was_held():
 # ---------------------------------------------------------------------------
 
 
+class _Patient(threading.Event):
+    """A step's `released`, waited for as long as `_ridden` ever waits (its
+    cap, 0.1 s) where it asks for a step's time."""
+
+    def wait(self, timeout=None):
+        return super().wait(None if timeout is None else 0.1)
+
+
+def _patient_riders(mp):
+    made = step_ahead._Step.__init__
+
+    def init(self, *a, **kw):
+        made(self, *a, **kw)
+        self.released = _Patient()
+
+    mp.setattr(step_ahead._Step, "__init__", init)
+
+
 async def _serve(idx, model, kw, parts_dir, tmp):
     cfg = TINY if model == "tiny" else get_config(model)
     info = NodeInfo(name=f"ad{idx}", host=HOST, port=BASE + idx, stage=0,
@@ -294,9 +324,11 @@ def served(parts, devices8, tmp_path_factory):
     def of(topology):
         if topology not in cache:
             idx, model, kw = TOPOLOGIES[topology]
-            cache[topology] = asyncio.run(asyncio.wait_for(
-                _serve(idx, model, kw, parts[model], str(tmp_path_factory.mktemp("ad-spans"))),
-                LIMIT_S))
+            with pytest.MonkeyPatch.context() as mp:
+                _patient_riders(mp)
+                cache[topology] = asyncio.run(asyncio.wait_for(
+                    _serve(idx, model, kw, parts[model], str(tmp_path_factory.mktemp("ad-spans"))),
+                    LIMIT_S))
         return cache[topology]
 
     return of

@@ -227,9 +227,11 @@ async def _serve(idx, model, kw, parts_dir):
             return process(session_id, {k: v for k, v in payload.items() if k != "sampling"})
 
         ex.process = without_ask
+        ex.begin_hop = None  # no hop goes round `process` (the lanes' form for the loop)
         run["loop"] = await _generate(node, GREEDY)
         run["stats2"] = ex.stats()
         ex.process = process
+        del ex.begin_hop
         run["sampled"] = [await _generate(node, SAMPLED, seed=11) for _ in range(2)]
         run["other_seed"] = await _generate(node, SAMPLED, seed=12)
         calls = []
@@ -241,10 +243,12 @@ async def _serve(idx, model, kw, parts_dir):
             return process(session_id, payload)
 
         ex.process = failing_once
+        ex.begin_hop = None
         retrylib.backoff_delay = lambda *a, **k: 0.0
         run["restarted"] = await _generate(node, SAMPLED, seed=11)
         run["sessions_tried"] = len(set(calls))
         ex.process = process
+        del ex.begin_hop
         run["compiles"] = _compiles(node)
         run["stats3"] = ex.stats()
         return run

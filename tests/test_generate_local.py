@@ -274,25 +274,33 @@ async def test_errors_come_back_as_over_http(parts, monkeypatch, case):
         await _stop([node])
 
 
+@pytest.mark.parametrize("entry", ["process", "begin_hop"])
 @pytest.mark.asyncio
-async def test_a_failure_mid_generation_restarts_the_stream(parts, monkeypatch):
+async def test_a_failure_mid_generation_restarts_the_stream(parts, monkeypatch, entry):
     """A retryable failure after tokens were streamed: a {"restart": true}
-    line, then the deterministic re-run's tokens, the same as undisturbed."""
-    node = _mk_node(20, parts[1], batch_lanes=2)
+    line, then the deterministic re-run's tokens, the same as undisturbed.
+    Whichever way the hop went in: on a worker (`process`; the loop's form
+    taken away) or on the event loop (`begin_hop`: a prefill and a request's
+    first decode hop, which rides a step, still enter `process`)."""
+    node = _mk_node(20 + (entry == "begin_hop"), parts[1], batch_lanes=2)
     monkeypatch.setattr(retrylib, "backoff_delay", lambda *a, **k: 0.0)
     await _start([node])
     try:
         async with SwarmClient([(HOST, node.info.port)], sampling=GREEDY) as c:
             want = await c.generate_server_side(PROMPT, NEW)
-        process, calls = node.executor.process, []
+        enter, calls = getattr(node.executor, entry), []
 
-        def failing_once(session_id, payload):
+        def failing_once(session_id, payload, *hand):
             calls.append(session_id)
             if len(calls) == 4:  # the prefill and two decode steps went through
                 raise RuntimeError("injected compute failure")
-            return process(session_id, payload)
+            return enter(session_id, payload, *hand)
 
-        node.executor.process = failing_once
+        if entry == "process":
+            node.executor.begin_hop = None  # every hop takes a worker, through `process`
+        # (`begin_hop` sees every call on its way in: the prefill, which it
+        # leaves to `process`, and every decode hop)
+        setattr(node.executor, entry, failing_once)
         body = wire.pack({
             "prompt_ids": PROMPT, "max_new_tokens": NEW, "stream": True,
             "sampling": {"temperature": 0.0},
