@@ -53,6 +53,41 @@ if os.environ.get("INFERD_LOCKWATCH", "").strip().lower() not in (
     lockwatch.instrument(strict=True)
 
 
+class Ports:
+    """One test module's block of ports on 127.0.0.1: node `idx` of the
+    module serves HTTP on `http(idx)` and gossips (UDP) on `gossip(idx)`."""
+
+    #: a module's `idx` stays under HTTP_SLOTS; a gossip port is GOSSIP_AT
+    #: past the node's HTTP port (TCP and UDP do not meet)
+    HTTP_SLOTS, GOSSIP_AT = 200, 100
+    WIDTH = HTTP_SLOTS + GOSSIP_AT
+    #: blocks start here and end under the kernel's ephemeral range (32768)
+    FIRST, END = 4096, 32768
+
+    def __init__(self, base: int):
+        self.base = base
+
+    def http(self, idx: int = 0) -> int:
+        assert 0 <= idx < self.HTTP_SLOTS, idx
+        return self.base + idx
+
+    def gossip(self, idx: int = 0) -> int:
+        return self.http(idx) + self.GOSSIP_AT
+
+
+def port_block(test_file: str) -> Ports:
+    """The block of the test module in `test_file` (hand it `__file__`): by
+    the module's place among tests/test_*.py, so no two modules overlap
+    whichever xdist workers run them, and nobody keeps a table. A helper
+    imported from another module takes the IMPORTER's block as an argument
+    (test_node_e2e._mk_node's `ports`)."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    files = sorted(f for f in os.listdir(here) if f.startswith("test_") and f.endswith(".py"))
+    base = Ports.FIRST + files.index(os.path.basename(test_file)) * Ports.WIDTH
+    assert Ports.FIRST + len(files) * Ports.WIDTH <= Ports.END, "tests/ outgrew its ports: narrow Ports.WIDTH"
+    return Ports(base)
+
+
 def pytest_configure(config):
     config.addinivalue_line("markers", "asyncio: run test in an event loop")
     config.addinivalue_line(
@@ -93,6 +128,22 @@ def devices8():
     devs = jax.devices()
     assert len(devs) >= 8, f"expected 8 virtual CPU devices, got {len(devs)}"
     return devs[:8]
+
+
+def own_lane_programs(eng):
+    """Give a BatchedEngine programs of its own, apart from its
+    configuration's shared set (core.batch.lane_programs): for a test that
+    patches what the model reads when it is TRACED (a function, a module
+    constant) and so must not hand its traces to, or take them from, any
+    other engine. The instance's attributes shadow; the shared set is not
+    touched."""
+    from inferd_tpu.core import batch
+
+    made = batch.lane_programs.__wrapped__(
+        eng.cfg, eng.sampling, eng.lanes, eng.pool is not None)
+    for name, program in made._asdict().items():
+        setattr(eng, "_" + name, program)
+    return eng
 
 
 @pytest.fixture

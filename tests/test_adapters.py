@@ -19,6 +19,10 @@ from inferd_tpu.runtime.adapters import (
     combine_affinity, parse_adapter_dirs,
 )
 
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
+
 SIM_DATA = os.path.join(os.path.dirname(__file__), "data", "sim")
 
 PROMPT = [3, 17, 42, 9, 5, 8, 2, 11]
@@ -382,9 +386,9 @@ async def test_mixed_version_gossip_ada_key():
         return SwarmDHT(node_id, port, bootstrap=bootstrap or [], ttl_s=5.0,
                         gossip_period_s=0.05, host="127.0.0.1")
 
-    new = mk("new", 17361)
-    old = mk("old", 17362, bootstrap=[("127.0.0.1", 17361)])
-    obs = mk("obs", 17363, bootstrap=[("127.0.0.1", 17361)])
+    new = mk("new", PORTS.gossip(1))
+    old = mk("old", PORTS.gossip(2), bootstrap=[("127.0.0.1", PORTS.gossip(1))])
+    obs = mk("obs", PORTS.gossip(3), bootstrap=[("127.0.0.1", PORTS.gossip(1))])
     await new.start(); await old.start(); await obs.start()
     try:
         new.announce({

@@ -46,7 +46,9 @@ from inferd_tpu.runtime import step_ahead, wire
 from inferd_tpu.runtime.node import Node, NodeInfo
 from inferd_tpu.runtime.window import Entry
 
-BASE, HOST = 20400, "127.0.0.1"  # distinct port block (test_host_turn holds 20000)
+from conftest import port_block  # noqa: E402
+
+PORTS, HOST = port_block(__file__), "127.0.0.1"
 GREEDY = SamplingConfig(temperature=0.0)
 PROMPTS = ([3, 7, 11, 19, 23, 29, 31, 37], [5, 13, 17, 41, 43, 47, 53, 59])
 NEW, LONG_NEW = 12, 120
@@ -254,9 +256,9 @@ def _patient_riders(mp):
 
 async def _serve(idx, model, kw, parts_dir, tmp):
     cfg = TINY if model == "tiny" else get_config(model)
-    info = NodeInfo(name=f"ad{idx}", host=HOST, port=BASE + idx, stage=0,
+    info = NodeInfo(name=f"ad{idx}", host=HOST, port=PORTS.http(idx), stage=0,
                     num_stages=1, capacity=8, model_name=model)
-    dht = SwarmDHT(info.node_id, BASE + 200 + idx, bootstrap=[], host=HOST,
+    dht = SwarmDHT(info.node_id, PORTS.gossip(idx), bootstrap=[], host=HOST,
                    gossip_period_s=0.05, ttl_s=5.0)
     node = Node(info, cfg, parts_dir, dht, backend="qwen3", max_len=160,
                 rebalance_period_s=600.0, **kw)

@@ -16,7 +16,9 @@ from inferd_tpu.models import qwen3
 from inferd_tpu.parallel.stages import Manifest, split_and_save
 from inferd_tpu.runtime.node import Node, NodeInfo
 
-BASE = 18700  # distinct block from test_mesh_node (18600)
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
 
 
 @pytest.fixture(scope="module")
@@ -30,11 +32,11 @@ def whole_parts(tmp_path_factory):
 
 def _mk_batched_node(idx, parts, lanes=4):
     info = NodeInfo(
-        name=f"bn{idx}", host="127.0.0.1", port=BASE + idx,
+        name=f"bn{idx}", host="127.0.0.1", port=PORTS.http(idx),
         stage=0, num_stages=1, capacity=8, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 100 + idx, bootstrap=[],
+        info.node_id, PORTS.gossip(idx), bootstrap=[],
         host="127.0.0.1", gossip_period_s=0.05, ttl_s=5.0,
     )
     return Node(
@@ -55,7 +57,7 @@ async def test_concurrent_generations_match_solo(whole_parts):
         want = [engine.generate(p, max_new_tokens=8, seed=0) for p in prompts]
 
         async def one(p):
-            async with SwarmClient([("127.0.0.1", BASE)], sampling=sc) as c:
+            async with SwarmClient([("127.0.0.1", PORTS.http())], sampling=sc) as c:
                 return await c.generate_ids(p, max_new_tokens=8)
 
         got = await asyncio.gather(*(one(p) for p in prompts))
@@ -64,7 +66,7 @@ async def test_concurrent_generations_match_solo(whole_parts):
         await node.stop()
 
 
-def test_decode_steps_actually_batch(whole_parts):
+def test_closed_loops_ride_the_same_device_steps(whole_parts):
     """Five closed-loop sessions (a client's token loop each: one decode
     step, sample, the next) ride the same device steps: the batch forms
     when the device frees and waits for the sessions just served
@@ -74,7 +76,9 @@ def test_decode_steps_actually_batch(whole_parts):
     closed loops are the only timing there is; the tiny model's step is
     stretched to 20 ms, a step's length against a host turn as on a chip
     (where a loaded test machine's scheduler cannot pass for a client
-    that went away)."""
+    that went away). Programs are counted against hops over 47 hops a
+    session: 3.5 rows a step is what a loaded machine still shows, and an
+    executor that does not batch shows 1.0, one that pairs 2.0."""
     import threading
     import time
 
@@ -88,7 +92,7 @@ def test_decode_steps_actually_batch(whole_parts):
         "s0": [3, 7, 11], "s1": [2, 5, 13, 17], "s2": [23, 29],
         "s3": [31, 37, 41, 43, 47], "s4": [53, 59, 61],
     }
-    new = 32
+    new = 48
     engine = Engine(TINY, params, max_len=64,
                     sampling_cfg=SamplingConfig(temperature=0.0))
     want = {s: engine.generate(p, max_new_tokens=new, seed=0)
@@ -104,7 +108,10 @@ def test_decode_steps_actually_batch(whole_parts):
     ex.end_session("warm")
     program = ex.engine._decode_logits
 
+    programs = []
+
     def slow_step(*args, **kwargs):
+        programs.append(1)
         time.sleep(0.02)
         return program(*args, **kwargs)
 
@@ -131,7 +138,8 @@ def test_decode_steps_actually_batch(whole_parts):
     tokens = st["batched_tokens"] - before["batched_tokens"]
     steps = st["batched_steps"] - before["batched_steps"]
     assert tokens == 5 * (new - 1)  # token-true: one count a decoded token
-    assert tokens / steps >= 4.0, (tokens, steps, st)
+    assert steps == len(programs)  # a step the stats count is a program that ran
+    assert tokens / len(programs) >= 3.5, (tokens, steps, st)
     assert st["gang_full"] >= steps - st["gang_timeout"] - 1
     assert st["empty_drains"] == 0
 
@@ -151,7 +159,7 @@ async def test_lane_eviction_and_restart(whole_parts):
         want = [engine.generate(p, max_new_tokens=6, seed=0) for p in prompts]
 
         async def one(p):
-            async with SwarmClient([("127.0.0.1", BASE + 2)], sampling=sc) as c:
+            async with SwarmClient([("127.0.0.1", PORTS.http(2))], sampling=sc) as c:
                 # capacity backpressure (503 busy) retries the whole
                 # generation; under full-suite load the in-flight ones
                 # finish slowly, so give the retry loop more headroom than
@@ -173,11 +181,11 @@ async def test_quantized_batched_node_matches_quantized_engine(whole_parts):
 
     parts, params = whole_parts
     info = NodeInfo(
-        name="bq0", host="127.0.0.1", port=BASE + 40,
+        name="bq0", host="127.0.0.1", port=PORTS.http(40),
         stage=0, num_stages=1, capacity=8, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 140, bootstrap=[],
+        info.node_id, PORTS.gossip(40), bootstrap=[],
         host="127.0.0.1", gossip_period_s=0.05, ttl_s=5.0,
     )
     node = Node(
@@ -195,7 +203,7 @@ async def test_quantized_batched_node_matches_quantized_engine(whole_parts):
         want = [engine.generate(p, max_new_tokens=6, seed=0) for p in prompts]
 
         async def one(p):
-            async with SwarmClient([("127.0.0.1", BASE + 40)], sampling=sc) as c:
+            async with SwarmClient([("127.0.0.1", PORTS.http(40))], sampling=sc) as c:
                 return await c.generate_ids(p, max_new_tokens=6)
 
         got = await asyncio.gather(*(one(p) for p in prompts))
@@ -214,11 +222,11 @@ async def test_int4_node_matches_int4_engine(whole_parts):
 
     parts, params = whole_parts
     info = NodeInfo(
-        name="i4", host="127.0.0.1", port=BASE + 41,
+        name="i4", host="127.0.0.1", port=PORTS.http(41),
         stage=0, num_stages=1, capacity=8, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 141, bootstrap=[],
+        info.node_id, PORTS.gossip(41), bootstrap=[],
         host="127.0.0.1", gossip_period_s=0.05, ttl_s=5.0,
     )
     node = Node(
@@ -234,7 +242,7 @@ async def test_int4_node_matches_int4_engine(whole_parts):
         engine = Engine(TINY, qparams, max_len=64, sampling_cfg=sc)
         prompt = [3, 7, 11, 19]
         want = engine.generate(prompt, max_new_tokens=6)
-        async with SwarmClient([("127.0.0.1", BASE + 41)], sampling=sc) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(41))], sampling=sc) as c:
             got = await c.generate_ids(prompt, max_new_tokens=6)
         assert got == want
     finally:
@@ -255,7 +263,7 @@ async def test_chain_client_against_batched_node(whole_parts):
         engine = Engine(TINY, params, max_len=64, sampling_cfg=sc)
         prompt = [3, 7, 11, 19]
         want = engine.generate(prompt, max_new_tokens=6, seed=0)
-        async with ChainClient([("127.0.0.1", BASE + 5)], sampling=sc) as c:
+        async with ChainClient([("127.0.0.1", PORTS.http(5))], sampling=sc) as c:
             got = await c.generate_ids(prompt, max_new_tokens=6)
         assert got == want
     finally:
@@ -272,12 +280,12 @@ async def test_batched_replica_graceful_death_failover(whole_parts):
     nodes = []
     for i in range(2):
         info = NodeInfo(
-            name=f"gf{i}", host="127.0.0.1", port=BASE + 30 + i,
+            name=f"gf{i}", host="127.0.0.1", port=PORTS.http(30 + i),
             stage=0, num_stages=1, capacity=8, model_name="tiny",
         )
         dht = SwarmDHT(
-            info.node_id, BASE + 130 + i,
-            bootstrap=[] if i == 0 else [("127.0.0.1", BASE + 130)],
+            info.node_id, PORTS.gossip(30 + i),
+            bootstrap=[] if i == 0 else [("127.0.0.1", PORTS.gossip(30))],
             host="127.0.0.1", gossip_period_s=0.05, ttl_s=5.0,
         )
         nodes.append(Node(
@@ -312,7 +320,7 @@ async def test_batched_replica_graceful_death_failover(whole_parts):
                 await asyncio.sleep(0.005)
 
         async with SwarmClient(
-            [("127.0.0.1", BASE + 30), ("127.0.0.1", BASE + 31)],
+            [("127.0.0.1", PORTS.http(30)), ("127.0.0.1", PORTS.http(31))],
             sampling=SamplingConfig(temperature=0.0), timeout_s=60.0,
         ) as c:
             task = asyncio.create_task(kill_serving_entry())

@@ -11,6 +11,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import time
 
 import aiohttp
 import jax
@@ -32,7 +33,9 @@ CFG = get_config("tiny-sdar")
 CONFIDENT = dataclasses.replace(CFG, remask="low_confidence")
 BLK = CFG.block_length
 GREEDY = SamplingConfig(temperature=0.0)
-BASE, HOST = 19800, "127.0.0.1"  # distinct port block (test_generate_local holds 19600)
+from conftest import port_block  # noqa: E402
+
+PORTS, HOST = port_block(__file__), "127.0.0.1"
 
 
 def _load_reference():
@@ -177,6 +180,13 @@ def test_cobatched_lanes_get_what_they_get_alone(params):
     alone = [generate(BatchedExecutor(CFG, params, lanes=4, max_len=128), p, n)[0]
              for p, n in jobs]
     ex = BatchedExecutor(CFG, params, lanes=4, max_len=128, window_ms=20.0)
+    step = ex.engine._block_step
+
+    def slow_step(*a, **kw):  # a block step outlasts the stagger: the sessions overlap
+        time.sleep(0.03)
+        return step(*a, **kw)
+
+    ex.engine._block_step = slow_step
 
     async def together():
         async def one(p, n, delay):
@@ -371,9 +381,9 @@ def parts(tmp_path_factory, params):
 
 @pytest.mark.asyncio
 async def test_generate_streams_blocks_and_tells_a_client_of_them(parts, params):
-    info = NodeInfo(name="bd0", host=HOST, port=BASE, stage=0, num_stages=1, capacity=8,
+    info = NodeInfo(name="bd0", host=HOST, port=PORTS.http(), stage=0, num_stages=1, capacity=8,
                     model_name="tiny-sdar")
-    dht = SwarmDHT(info.node_id, BASE + 200, bootstrap=[], host=HOST,
+    dht = SwarmDHT(info.node_id, PORTS.gossip(), bootstrap=[], host=HOST,
                    gossip_period_s=0.05, ttl_s=5.0)
     node = Node(info, CFG, parts, dht, backend="qwen3", max_len=64, batch_lanes=4,
                 rebalance_period_s=600.0)
@@ -394,17 +404,17 @@ async def test_generate_streams_blocks_and_tells_a_client_of_them(parts, params)
         programs = (node.executor.engine._block_step, samplib.ahead_block_keys)
         compiled = [f._cache_size() for f in programs]
         async with aiohttp.ClientSession() as http:
-            async with http.get(f"http://{HOST}:{BASE}/stats") as r:
+            async with http.get(f"http://{HOST}:{PORTS.http()}/stats") as r:
                 stats = await r.json()
             assert stats["model"] == {"name": "tiny-sdar", "block_length": BLK}
             body = {"prompt_ids": prompt, "max_new_tokens": new, "stream": True, "logprobs": True,
                     "top_logprobs": 3, "sampling": {"temperature": 0.0, "top_k": 0, "top_p": 1.0}}
             lines = []
-            async with http.post(f"http://{HOST}:{BASE}/generate", data=wire.pack(body)) as r:
+            async with http.post(f"http://{HOST}:{PORTS.http()}/generate", data=wire.pack(body)) as r:
                 assert r.status == 200
                 async for raw in r.content:
                     lines.append(json.loads(raw))
-            async with http.post(f"http://{HOST}:{BASE}/generate", data=wire.pack(
+            async with http.post(f"http://{HOST}:{PORTS.http()}/generate", data=wire.pack(
                     dict(body, pin_prefix_len=4))) as r:
                 assert r.status == 400 and b"pinned prefix" in await r.read()
         toks = [m["t"] for m in lines if "t" in m]
@@ -414,7 +424,7 @@ async def test_generate_streams_blocks_and_tells_a_client_of_them(parts, params)
             ids, lps = m["top"]
             assert m["t"] == ids[0] and abs(m["lp"] - lps[0]) < 1e-6
             np.testing.assert_allclose(lps, ref[j][ids], atol=2e-4)
-        async with SwarmClient([(HOST, BASE)], sampling=GREEDY) as c:
+        async with SwarmClient([(HOST, PORTS.http())], sampling=GREEDY) as c:
             assert await c.generate_ids(prompt, new) == toks  # it asked /stats for the block
             assert c._block == BLK
             with pytest.raises(ValueError, match="pinned prefix"):

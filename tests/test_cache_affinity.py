@@ -30,6 +30,10 @@ from inferd_tpu.obs import health as healthlib
 from inferd_tpu.obs import tsdb as tsdblib
 from inferd_tpu.utils.metrics import Metrics
 
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
+
 TINY = PRESETS["tiny"]
 FLEET_FIXTURE = os.path.join(os.path.dirname(__file__), "data", "fleet")
 SIM_DATA = os.path.join(os.path.dirname(__file__), "data", "sim")
@@ -477,9 +481,9 @@ async def test_mixed_version_gossip_digest_keys():
         return SwarmDHT(node_id, port, bootstrap=bootstrap or [], ttl_s=5.0,
                         gossip_period_s=0.05, host="127.0.0.1")
 
-    new = mk("new", 17351)
-    old = mk("old", 17352, bootstrap=[("127.0.0.1", 17351)])
-    obs = mk("obs", 17353, bootstrap=[("127.0.0.1", 17351)])
+    new = mk("new", PORTS.gossip(1))
+    old = mk("old", PORTS.gossip(2), bootstrap=[("127.0.0.1", PORTS.gossip(1))])
+    obs = mk("obs", PORTS.gossip(3), bootstrap=[("127.0.0.1", PORTS.gossip(1))])
     await new.start(); await old.start(); await obs.start()
     try:
         digest = _digest_for(PROMPT)

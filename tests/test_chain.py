@@ -3,6 +3,8 @@ directly with `relay: false` — parity with the reference's gRPC slice
 (/root/reference/models/qwen3/client/rpc_client.py:36-57) served by the
 same unified node runtime as the swarm path."""
 
+import functools
+
 import pytest
 
 from inferd_tpu.client.chain_client import ChainClient
@@ -10,7 +12,12 @@ from inferd_tpu.client.swarm_client import SwarmClient
 from inferd_tpu.config import TINY, SamplingConfig
 from inferd_tpu.core.generate import Engine
 
-from test_node_e2e import BASE, _mk_node, _start_all, _stop_all, tiny_parts  # noqa: F401
+import test_node_e2e as e2e
+from conftest import port_block
+from test_node_e2e import _start_all, _stop_all, tiny_parts  # noqa: F401
+
+PORTS = port_block(__file__)
+_mk_node = functools.partial(e2e._mk_node, ports=PORTS)  # its nodes, this module's ports
 
 
 @pytest.mark.asyncio
@@ -21,12 +28,12 @@ async def test_chain_counter_no_relay():
     await _start_all(nodes)
     try:
         async with ChainClient(
-            [("127.0.0.1", BASE + 30 + i) for i in range(3)]
+            [("127.0.0.1", PORTS.http(30 + i)) for i in range(3)]
         ) as c:
             payload = {}
             for stage in range(3):
                 resp = await c._post(
-                    ("127.0.0.1", BASE + 30 + stage),
+                    ("127.0.0.1", PORTS.http(30 + stage)),
                     "/forward",
                     {
                         "stage": stage,
@@ -36,7 +43,7 @@ async def test_chain_counter_no_relay():
                     },
                 )
                 # hub-and-spoke: the serving node answers for itself only
-                assert resp["served_by"] == f"127.0.0.1:{BASE + 30 + stage}"
+                assert resp["served_by"] == f"127.0.0.1:{PORTS.http(30 + stage)}"
                 payload = dict(resp["result"])
                 payload.pop("result_for_user", None)
             assert payload["state"] == 3
@@ -60,7 +67,7 @@ async def test_chain_generation_matches_engine(tiny_parts):  # noqa: F811
         prompt = [3, 7, 11, 19]
         expected = engine.generate(prompt, max_new_tokens=6)
         async with ChainClient(
-            [("127.0.0.1", BASE + 40), ("127.0.0.1", BASE + 41)],
+            [("127.0.0.1", PORTS.http(40)), ("127.0.0.1", PORTS.http(41))],
             sampling=SamplingConfig(temperature=0.0),
         ) as c:
             got = await c.generate_ids(prompt, max_new_tokens=6)
@@ -83,14 +90,14 @@ async def test_chain_end_session_is_local(tiny_parts):  # noqa: F811
     await _start_all(nodes)
     try:
         async with ChainClient(
-            [("127.0.0.1", BASE + 50), ("127.0.0.1", BASE + 51)],
+            [("127.0.0.1", PORTS.http(50)), ("127.0.0.1", PORTS.http(51))],
             sampling=SamplingConfig(temperature=0.0),
         ) as c:
             await c._forward_through_chain("s-local", [1, 2, 3], 0)
             assert len(nodes[0].executor.sessions) == 1
             assert len(nodes[1].executor.sessions) == 1
             await c._post(
-                ("127.0.0.1", BASE + 50),
+                ("127.0.0.1", PORTS.http(50)),
                 "/end_session",
                 {"session_id": "s-local", "stage": 0, "relay": False},
             )
@@ -108,7 +115,7 @@ async def test_chain_wrong_stage_fails_loudly():
     nodes = [_mk_node(60 + i, i, 2, bootstrap_idx=60) for i in range(2)]
     await _start_all(nodes)
     try:
-        async with SwarmClient([("127.0.0.1", BASE + 61)]) as c:  # node serving stage 1
+        async with SwarmClient([("127.0.0.1", PORTS.http(61))]) as c:  # node serving stage 1
             with pytest.raises(RuntimeError, match="wrong stage"):
                 await c._post(
                     "/forward",

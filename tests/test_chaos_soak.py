@@ -32,7 +32,9 @@ from inferd_tpu.models import qwen3
 from inferd_tpu.parallel.stages import Manifest, split_and_save
 from inferd_tpu.runtime.node import Node, NodeInfo
 
-BASE = 19300
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
 GREEDY = SamplingConfig(temperature=0.0)
 PROMPTS = [
     [3, 7, 11, 19, 5],
@@ -53,7 +55,7 @@ def soak_parts(tmp_path_factory):
 
 def _mk_node(idx, stage, *, parts, rebalance_period_s=600.0):
     info = NodeInfo(
-        name=f"s{idx}", host="127.0.0.1", port=BASE + idx,
+        name=f"s{idx}", host="127.0.0.1", port=PORTS.http(idx),
         stage=stage, num_stages=2, capacity=4, model_name="tiny",
     )
     # gossip: longer TTL + period than the microtests — five nodes, five
@@ -63,8 +65,8 @@ def _mk_node(idx, stage, *, parts, rebalance_period_s=600.0):
     # on TTL death, so ttl_s must stay comfortably under its 6 s crash
     # cadence + 2 s respawn gap — retune BOTH tests together.
     dht = SwarmDHT(
-        info.node_id, BASE + 100 + idx,
-        bootstrap=[("127.0.0.1", BASE + 100)] if idx else [],
+        info.node_id, PORTS.gossip(idx),
+        bootstrap=[("127.0.0.1", PORTS.gossip())] if idx else [],
         host="127.0.0.1", gossip_period_s=0.2, ttl_s=5.0,
     )
     return Node(
@@ -92,7 +94,7 @@ async def _bring_up_swarm(parts):
         await asyncio.sleep(0.05)
     else:
         raise TimeoutError("swarm never converged")
-    return nodes, ("127.0.0.1", BASE + 2)
+    return nodes, ("127.0.0.1", PORTS.http(2))
 
 
 @pytest.mark.asyncio
@@ -139,8 +141,8 @@ async def test_chaos_soak_mixed_load(soak_parts):
 
     async def routed_load():
         obs = SwarmDHT(
-            "soak-observer", BASE + 99,
-            bootstrap=[("127.0.0.1", BASE + 100)],
+            "soak-observer", PORTS.gossip(99),
+            bootstrap=[("127.0.0.1", PORTS.gossip())],
             host="127.0.0.1", gossip_period_s=0.2, ttl_s=5.0,
         )
         await obs.start()

@@ -10,6 +10,10 @@ import pytest
 from inferd_tpu.parallel.stages import Manifest
 from inferd_tpu.tools.run_node import build_parser, get_own_ip, parse_bootstrap
 
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
+
 EXAMPLE = os.path.join(os.path.dirname(__file__), "..", "examples", "cluster.yaml")
 
 
@@ -73,7 +77,7 @@ def test_what_left_the_command_line_is_refused_not_ignored(module, argv, said, c
 
 
 @pytest.mark.asyncio
-async def test_run_node_entrypoint_counter_swarm(tmp_path, unused_tcp_port_base=18600):
+async def test_run_node_entrypoint_counter_swarm(tmp_path):
     """Start a 2-stage counter swarm via the run_node module's wiring (not
     raw Node construction) and drive one task through it."""
     from inferd_tpu.client.swarm_client import SwarmClient
@@ -89,16 +93,15 @@ nodes:
     mpath = tmp_path / "cluster.yaml"
     mpath.write_text(manifest_text)
 
-    base = unused_tcp_port_base
     tasks = []
     stop_events = []
 
     async def start_one(name, stage, idx):
         argv = [
             "--manifest", str(mpath), "--name", name, "--backend", "counter",
-            "--host", "127.0.0.1", "--port", str(base + idx),
-            "--gossip-port", str(base + 100 + idx),
-            "--bootstrap", f"127.0.0.1:{base + 100}" if idx else "",
+            "--host", "127.0.0.1", "--port", str(PORTS.http(idx)),
+            "--gossip-port", str(PORTS.gossip(idx)),
+            "--bootstrap", f"127.0.0.1:{PORTS.gossip()}" if idx else "",
             "--rebalance-period", "600",
         ]
         args = rn.build_parser().parse_args(argv)
@@ -137,7 +140,7 @@ nodes:
     await start_one("node1", 1, 1)
     try:
         # wait for convergence then run a counter task end to end
-        async with SwarmClient([("127.0.0.1", base)]) as client:
+        async with SwarmClient([("127.0.0.1", PORTS.http())]) as client:
             for _ in range(100):
                 try:
                     resp = await client._post(
@@ -208,18 +211,17 @@ async def test_send_cli_against_live_swarm(tmp_path):
     from inferd_tpu.runtime.node import Node, NodeInfo
     from inferd_tpu.tools.send import _run, build_parser
 
-    base = 18900
     params = qw.init_params(TINY, jax.random.PRNGKey(0))
     split_and_save(params, TINY, Manifest.even_split("tiny", 2), str(tmp_path))
     nodes = []
     for i in range(2):
         info = NodeInfo(
-            name=f"sc{i}", host="127.0.0.1", port=base + i,
+            name=f"sc{i}", host="127.0.0.1", port=PORTS.http(20 + i),
             stage=i, num_stages=2, capacity=4, model_name="tiny",
         )
         dht = SwarmDHT(
-            info.node_id, base + 100 + i,
-            bootstrap=[] if i == 0 else [("127.0.0.1", base + 100)],
+            info.node_id, PORTS.gossip(20 + i),
+            bootstrap=[] if i == 0 else [("127.0.0.1", PORTS.gossip(20))],
             host="127.0.0.1", gossip_period_s=0.05, ttl_s=1.5,
         )
         nodes.append(Node(info, TINY, str(tmp_path), dht, backend="qwen3",
@@ -228,7 +230,7 @@ async def test_send_cli_against_live_swarm(tmp_path):
         await n.start()
     try:
         args = build_parser().parse_args([
-            "--entry", f"127.0.0.1:{base}", "--prompt-ids", "3,7,11",
+            "--entry", f"127.0.0.1:{PORTS.http(20)}", "--prompt-ids", "3,7,11",
             "--max-new-tokens", "5", "--temperature", "0",
             "--session-retries", "5",
         ])
@@ -236,14 +238,14 @@ async def test_send_cli_against_live_swarm(tmp_path):
         # --routed: the chain is planned by D*-Lite over the gossip view
         # (bootstraps off node 0's gossip port as a records-less observer)
         args = build_parser().parse_args([
-            "--routed", f"127.0.0.1:{base + 100}", "--num-stages", "2",
+            "--routed", f"127.0.0.1:{PORTS.gossip(20)}", "--num-stages", "2",
             "--prompt-ids", "3,7,11", "--max-new-tokens", "5",
             "--temperature", "0", "--session-retries", "5",
         ])
         assert await _run(args) == 0
         # --routed without --num-stages is a usage error
         args = build_parser().parse_args([
-            "--routed", f"127.0.0.1:{base + 100}", "--prompt-ids", "3",
+            "--routed", f"127.0.0.1:{PORTS.gossip(20)}", "--prompt-ids", "3",
         ])
         assert await _run(args) == 2
     finally:
@@ -295,8 +297,8 @@ def test_package_import_initializes_no_jax_backend():
 
 @pytest.mark.parametrize("argv", [
     ["-m", "inferd_tpu.tools.run_node", "--model", "tiny", "--batch-lanes",
-     "2", "--device", "tpu", "--host", "127.0.0.1", "--port", "18690",
-     "--gossip-port", "18691"],
+     "2", "--device", "tpu", "--host", "127.0.0.1", "--port", str(PORTS.http(90)),
+     "--gossip-port", str(PORTS.gossip(90))],
     ["bench.py", "--device", "tpu", "--tiny", "--steps", "2", "--reps", "1"],
     ["bench.py", "--device", "tpu", "--config", "swarm-agg", "--tiny"],
     ["-m", "inferd_tpu.tools.generate", "--model", "tiny", "--random-init",

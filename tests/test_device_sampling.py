@@ -39,7 +39,9 @@ from inferd_tpu.runtime import executor as execlib
 from inferd_tpu.runtime.node import Node, NodeInfo
 from inferd_tpu.utils import retry as retrylib
 
-BASE, HOST = 20400, "127.0.0.1"  # distinct port block (test_host_turn holds 20000)
+from conftest import port_block  # noqa: E402
+
+PORTS, HOST = port_block(__file__), "127.0.0.1"
 GREEDY = SamplingConfig(temperature=0.0)
 SAMPLED = SamplingConfig(temperature=0.8, top_k=20, top_p=0.95)
 PROMPT = [3, 7, 11, 19, 23, 29, 31, 37]
@@ -179,9 +181,9 @@ def parts(tmp_path_factory):
 
 
 def _node(idx, model, parts_dir, **kw):
-    info = NodeInfo(name=f"ds{idx}", host=HOST, port=BASE + idx, stage=0,
+    info = NodeInfo(name=f"ds{idx}", host=HOST, port=PORTS.http(idx), stage=0,
                     num_stages=1, capacity=8, model_name=model)
-    dht = SwarmDHT(info.node_id, BASE + 200 + idx, bootstrap=[], host=HOST,
+    dht = SwarmDHT(info.node_id, PORTS.gossip(idx), bootstrap=[], host=HOST,
                    gossip_period_s=0.05, ttl_s=5.0)
     return Node(info, _cfg(model), parts_dir, dht, backend="qwen3", max_len=64,
                 rebalance_period_s=600.0, **kw)

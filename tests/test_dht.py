@@ -7,6 +7,9 @@ import pytest
 
 from inferd_tpu.control.dht import SwarmDHT
 
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
 
 def _mk(node_id, port, bootstrap=None, ttl=5.0, period=0.05):
     return SwarmDHT(
@@ -27,7 +30,7 @@ async def _wait_for(cond, timeout=5.0, interval=0.05):
 
 @pytest.mark.asyncio
 async def test_gossip_propagation_three_nodes():
-    ports = [17101, 17102, 17103]
+    ports = [PORTS.gossip(1), PORTS.gossip(2), PORTS.gossip(3)]
     a = _mk("a", ports[0])
     b = _mk("b", ports[1], bootstrap=[("127.0.0.1", ports[0])])
     c = _mk("c", ports[2], bootstrap=[("127.0.0.1", ports[0])])
@@ -53,8 +56,8 @@ async def test_gossip_propagation_three_nodes():
 async def test_owner_only_writes_no_clobber():
     """Concurrent announces from different nodes can never clobber each
     other (the reference's shared-record RMW race, SURVEY B6)."""
-    a = _mk("a", 17111)
-    b = _mk("b", 17112, bootstrap=[("127.0.0.1", 17111)])
+    a = _mk("a", PORTS.gossip(11))
+    b = _mk("b", PORTS.gossip(12), bootstrap=[("127.0.0.1", PORTS.gossip(11))])
     await a.start(); await b.start()
     try:
         for i in range(20):  # interleaved rapid announces
@@ -72,8 +75,8 @@ async def test_owner_only_writes_no_clobber():
 
 @pytest.mark.asyncio
 async def test_ttl_expires_dead_node():
-    a = _mk("a", 17121, ttl=0.6)
-    b = _mk("b", 17122, bootstrap=[("127.0.0.1", 17121)], ttl=0.6)
+    a = _mk("a", PORTS.gossip(21), ttl=0.6)
+    b = _mk("b", PORTS.gossip(22), bootstrap=[("127.0.0.1", PORTS.gossip(21))], ttl=0.6)
     await a.start(); await b.start()
     a.announce({"stage": 0, "load": 0, "cap": 1})
     b.announce({"stage": 1, "load": 0, "cap": 1})
@@ -87,8 +90,8 @@ async def test_ttl_expires_dead_node():
 
 @pytest.mark.asyncio
 async def test_withdraw_tombstone():
-    a = _mk("a", 17131)
-    b = _mk("b", 17132, bootstrap=[("127.0.0.1", 17131)])
+    a = _mk("a", PORTS.gossip(31))
+    b = _mk("b", PORTS.gossip(32), bootstrap=[("127.0.0.1", PORTS.gossip(31))])
     await a.start(); await b.start()
     a.announce({"stage": 0, "load": 0, "cap": 1})
     b.announce({"stage": 1, "load": 0, "cap": 1})
@@ -102,10 +105,10 @@ async def test_withdraw_tombstone():
 
 @pytest.mark.asyncio
 async def test_late_joiner_bootstrap_state():
-    a = _mk("a", 17141)
+    a = _mk("a", PORTS.gossip(41))
     await a.start()
     a.announce({"stage": 0, "load": 3, "cap": 2})
-    late = _mk("late", 17142, bootstrap=[("127.0.0.1", 17141)])
+    late = _mk("late", PORTS.gossip(42), bootstrap=[("127.0.0.1", PORTS.gossip(41))])
     await late.start()
     try:
         assert await _wait_for(lambda: late.get_stage(0).get("a", {}).get("load") == 3)
@@ -149,9 +152,9 @@ async def test_mixed_version_gossip_windowed_and_outlier_keys():
     gossip store is schema-agnostic), and an old-style record LACKING
     them must coexist in the same stage map without defaults being
     invented for it."""
-    new = _mk("new", 17151)
-    old = _mk("old", 17152, bootstrap=[("127.0.0.1", 17151)])
-    obs = _mk("obs", 17153, bootstrap=[("127.0.0.1", 17151)])
+    new = _mk("new", PORTS.gossip(51))
+    old = _mk("old", PORTS.gossip(52), bootstrap=[("127.0.0.1", PORTS.gossip(51))])
+    obs = _mk("obs", PORTS.gossip(53), bootstrap=[("127.0.0.1", PORTS.gossip(51))])
     await new.start(); await old.start(); await obs.start()
     try:
         new.announce({

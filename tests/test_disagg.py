@@ -17,7 +17,9 @@ from inferd_tpu.models import qwen3
 from inferd_tpu.parallel.stages import Manifest, split_and_save
 from inferd_tpu.runtime.node import Node, NodeInfo
 
-BASE = 18900
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
 GREEDY = SamplingConfig(temperature=0.0)
 
 
@@ -31,12 +33,12 @@ def whole_parts(tmp_path_factory):
 
 def _mk_node(idx, parts, batch_lanes=0):
     info = NodeInfo(
-        name=f"dg{idx}", host="127.0.0.1", port=BASE + idx,
+        name=f"dg{idx}", host="127.0.0.1", port=PORTS.http(idx),
         stage=0, num_stages=1, capacity=8, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 100 + idx, bootstrap=(
-            [] if idx == 0 else [("127.0.0.1", BASE + 100)]
+        info.node_id, PORTS.gossip(idx), bootstrap=(
+            [] if idx == 0 else [("127.0.0.1", PORTS.gossip())]
         ),
         host="127.0.0.1", gossip_period_s=0.05, ttl_s=5.0,
     )
@@ -62,9 +64,9 @@ async def test_prefill_on_a_decode_on_b_token_exact(whole_parts):
         want = Engine(TINY, params, max_len=64, sampling_cfg=GREEDY).generate(
             prompt, max_new_tokens=12
         )
-        async with SwarmClient([("127.0.0.1", BASE)], sampling=GREEDY) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http())], sampling=GREEDY) as c:
             got = await c.generate_ids_disaggregated(
-                prompt, ("127.0.0.1", BASE + 1), max_new_tokens=12
+                prompt, ("127.0.0.1", PORTS.http(1)), max_new_tokens=12
             )
         assert got == want
         snap = a.metrics.snapshot()
@@ -94,10 +96,10 @@ async def test_disagg_across_executor_types(whole_parts):
             prompt, max_new_tokens=10
         )
         async with SwarmClient(
-            [("127.0.0.1", BASE + 2)], sampling=GREEDY
+            [("127.0.0.1", PORTS.http(2))], sampling=GREEDY
         ) as c:
             got = await c.generate_ids_disaggregated(
-                prompt, ("127.0.0.1", BASE + 3), max_new_tokens=10
+                prompt, ("127.0.0.1", PORTS.http(3)), max_new_tokens=10
             )
         assert got == want
     finally:
@@ -114,13 +116,13 @@ async def test_export_unknown_session_404(whole_parts):
         from inferd_tpu.client.base import ServerError
 
         async with SwarmClient(
-            [("127.0.0.1", BASE + 4)], sampling=GREEDY
+            [("127.0.0.1", PORTS.http(4))], sampling=GREEDY
         ) as c:
             with pytest.raises(ServerError) as ei:
                 await c._post(
                     "/export_session",
                     {"session_id": "nope", "target_host": "127.0.0.1",
-                     "target_port": BASE + 4},
+                     "target_port": PORTS.http(4)},
                 )
             assert ei.value.status == 404
     finally:
@@ -138,12 +140,12 @@ async def test_disagg_between_mesh_replicas(whole_parts, devices8):
 
     def mk_mesh(idx):
         info = NodeInfo(
-            name=f"dgm{idx}", host="127.0.0.1", port=BASE + 10 + idx,
+            name=f"dgm{idx}", host="127.0.0.1", port=PORTS.http(10 + idx),
             stage=0, num_stages=1, capacity=8, model_name="tiny",
         )
         dht = SwarmDHT(
-            info.node_id, BASE + 110 + idx, bootstrap=(
-                [] if idx == 0 else [("127.0.0.1", BASE + 110)]
+            info.node_id, PORTS.gossip(10 + idx), bootstrap=(
+                [] if idx == 0 else [("127.0.0.1", PORTS.gossip(10))]
             ),
             host="127.0.0.1", gossip_period_s=0.05, ttl_s=5.0,
         )
@@ -162,10 +164,10 @@ async def test_disagg_between_mesh_replicas(whole_parts, devices8):
             prompt, max_new_tokens=10
         )
         async with SwarmClient(
-            [("127.0.0.1", BASE + 10)], sampling=GREEDY
+            [("127.0.0.1", PORTS.http(10))], sampling=GREEDY
         ) as c:
             got = await c.generate_ids_disaggregated(
-                prompt, ("127.0.0.1", BASE + 11), max_new_tokens=10
+                prompt, ("127.0.0.1", PORTS.http(11)), max_new_tokens=10
             )
         assert got == want
         assert a.metrics.snapshot()["counters"]["sessions.handed_off"] == 1

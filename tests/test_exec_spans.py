@@ -31,7 +31,10 @@ from inferd_tpu.obs.devtel import CompileWatch
 from inferd_tpu.parallel.mesh import MeshPlan
 from inferd_tpu.runtime.node import Node
 from inferd_tpu.runtime.window import WindowedBatcher
+from conftest import port_block
 from test_mesh_node import hold_flusher
+
+PORTS = port_block(__file__)
 
 PROMPTS = {"a": [3, 7, 11], "b": [5, 13, 17]}
 PARTS = ("lane", "batch_wait", "lock_wait", "device", "copy_out", "deliver")
@@ -426,16 +429,16 @@ async def test_capture_span_is_there_before_the_capture_closes(tmp_path):
     import aiohttp
 
     from inferd_tpu.runtime import wire
-    from test_node_e2e import BASE, _mk_node
+    from test_node_e2e import _mk_node
 
-    node = _mk_node(190, 0, 1, bootstrap_idx=190)
+    node = _mk_node(190, 0, 1, bootstrap_idx=190, ports=PORTS)
     node.enable_profiling = True
     node.profiler.base_dir = str(tmp_path / "profiles")
     await node.start()
     try:
         async with aiohttp.ClientSession() as http:
             body = wire.pack({"action": "window", "seconds": 1.0, "capture_id": "t"})
-            async with http.post(f"http://127.0.0.1:{BASE + 190}/profile", data=body) as r:
+            async with http.post(f"http://127.0.0.1:{PORTS.http(190)}/profile", data=body) as r:
                 assert r.status == 200
             assert node.profiler.active_dir is not None  # still capturing
             open_spans = {s["name"]: s for s in node.tracer.spans()}
@@ -490,8 +493,8 @@ async def test_collector_capture_fleet(tmp_path):
     from test_node_e2e import _mk_node, _start_all, _stop_all
 
     nodes = [
-        _mk_node(170, 0, 2, bootstrap_idx=170),
-        _mk_node(171, 1, 2, bootstrap_idx=170),
+        _mk_node(170, 0, 2, bootstrap_idx=170, ports=PORTS),
+        _mk_node(171, 1, 2, bootstrap_idx=170, ports=PORTS),
     ]
     cap, no_cap = nodes[0], nodes[1]
     cap.enable_profiling = True
@@ -562,12 +565,12 @@ async def test_profile_endpoint_edges(case, tmp_path):
     first to close, and `start` / `stop` need not share a thread."""
     import aiohttp
 
-    from test_node_e2e import BASE, _mk_node
+    from test_node_e2e import _mk_node
 
-    node = _mk_node(172, 0, 1, bootstrap_idx=172)
+    node = _mk_node(172, 0, 1, bootstrap_idx=172, ports=PORTS)
     node.enable_profiling = True
     node.profiler.base_dir = str(tmp_path / "profiles")
-    port = BASE + 172
+    port = PORTS.http(172)
     await node.start()
     try:
         async with aiohttp.ClientSession() as http:

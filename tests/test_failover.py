@@ -23,7 +23,9 @@ from inferd_tpu.runtime import wire
 from inferd_tpu.runtime.node import Node, NodeInfo
 from inferd_tpu.utils.chaos import Chaos, ChaosDrop
 
-BASE = 19400  # distinct port block from test_chaos_soak (19300)
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
 
 
 # --------------------------------------------------------------- fixtures
@@ -60,13 +62,13 @@ def _batched_executor(parts, block_size=8):
 
 def _mk(idx, *, parts, bootstrap_idx=0, chaos=None, **node_kw):
     info = NodeInfo(
-        name=f"f{idx}", host="127.0.0.1", port=BASE + idx,
+        name=f"f{idx}", host="127.0.0.1", port=PORTS.http(idx),
         stage=0, num_stages=1, capacity=4, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 100 + idx,
+        info.node_id, PORTS.gossip(idx),
         bootstrap=(
-            [("127.0.0.1", BASE + 100 + bootstrap_idx)]
+            [("127.0.0.1", PORTS.gossip(bootstrap_idx))]
             if idx != bootstrap_idx else []
         ),
         host="127.0.0.1", gossip_period_s=0.05, ttl_s=1.5,
@@ -337,7 +339,7 @@ async def test_standby_promotion_e2e_token_exact(tiny_parts1):
             await asyncio.sleep(0.06)
 
         async with SwarmClient(
-            [("127.0.0.1", BASE + 0), ("127.0.0.1", BASE + 1)],
+            [("127.0.0.1", PORTS.http(0)), ("127.0.0.1", PORTS.http(1))],
             sampling=SamplingConfig(temperature=0.0),
         ) as c:
             got = await c.generate_ids(
@@ -370,7 +372,7 @@ async def test_ended_session_drops_shadow_promptly(tiny_parts1):
     await _start_all(nodes)
     try:
         async with SwarmClient(
-            [("127.0.0.1", BASE + 0), ("127.0.0.1", BASE + 1)],
+            [("127.0.0.1", PORTS.http(0)), ("127.0.0.1", PORTS.http(1))],
             sampling=SamplingConfig(temperature=0.0),
         ) as c:
 
@@ -433,7 +435,7 @@ async def test_stale_standby_degrades_to_restart_token_exact(tiny_parts1):
                 await nodes[0].crash()
 
         async with SwarmClient(
-            [("127.0.0.1", BASE + 0), ("127.0.0.1", BASE + 1)],
+            [("127.0.0.1", PORTS.http(0)), ("127.0.0.1", PORTS.http(1))],
             sampling=SamplingConfig(temperature=0.0),
         ) as c:
             got = await c.generate_ids(
@@ -465,7 +467,7 @@ async def test_kill_switch_parity_flag_off(tiny_parts1):
     await _start_all(nodes)
     try:
         async with SwarmClient(
-            [("127.0.0.1", BASE + 0)],
+            [("127.0.0.1", PORTS.http(0))],
             sampling=SamplingConfig(temperature=0.0),
         ) as c:
             await c.generate_ids([3, 7, 11, 19], max_new_tokens=4)
@@ -482,7 +484,7 @@ async def test_kill_switch_parity_flag_off(tiny_parts1):
             assert not any(k.startswith("repl.") for k in snap["gauges"])
         async with aiohttp.ClientSession() as s:
             async with s.post(
-                f"http://127.0.0.1:{BASE}/replicate_session",
+                f"http://127.0.0.1:{PORTS.http()}/replicate_session",
                 data=wire.pack({"session_id": "x", "stage": 0,
                                 "k": np.zeros((1, 1, 1, 1, 1)),
                                 "v": np.zeros((1, 1, 1, 1, 1)),
@@ -491,7 +493,7 @@ async def test_kill_switch_parity_flag_off(tiny_parts1):
                 assert r.status == 501
                 body = wire.unpack(await r.read())
                 assert body["code"] == "repl_off"
-            async with s.get(f"http://127.0.0.1:{BASE}/stats") as r:
+            async with s.get(f"http://127.0.0.1:{PORTS.http()}/stats") as r:
                 assert "repl" not in await r.json()
     finally:
         await _stop_all(nodes)
@@ -507,7 +509,7 @@ async def test_rescue_failed_event_and_bounce_flag(tiny_parts1):
     nodes = [_mk(0, parts=parts, rescue_bounces=2)]
     await _start_all(nodes)
     try:
-        async with SwarmClient([("127.0.0.1", BASE + 0)]) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(0))]) as c:
             with pytest.raises(ServerError) as ei:
                 await c._post("/forward", {
                     "stage": 0, "session_id": "ghost",
@@ -552,7 +554,7 @@ async def test_partial_handoff_no_loss_no_double_adopt(tiny_parts1):
     await _start_all(nodes)
     try:
         sids = ["h1", "h2", "h3"]
-        await _seed_sessions(BASE + 0, sids)
+        await _seed_sessions(PORTS.http(0), sids)
         calls = {"n": 0}
         real_import = nodes[2].executor.import_session
 
@@ -592,18 +594,18 @@ async def test_partial_handoff_peer_death_no_hang(tiny_parts1):
         finally:
             writer.close()
 
-    server = await asyncio.start_server(black_hole, "127.0.0.1", BASE + 50)
+    server = await asyncio.start_server(black_hole, "127.0.0.1", PORTS.http(50))
     await _start_all(nodes)
     try:
         sids = ["p1", "p2"]
-        await _seed_sessions(BASE + 0, sids)
+        await _seed_sessions(PORTS.http(0), sids)
         real_get_stage = nodes[0].dht.get_stage
 
         def with_fake(stage):
             m = dict(real_get_stage(stage))
             # the stalled corpse sorts FIRST so every ship tries it
             # before the live peer
-            m = {"000:fake": {"host": "127.0.0.1", "port": BASE + 50,
+            m = {"000:fake": {"host": "127.0.0.1", "port": PORTS.http(50),
                              "stage": 0, "load": 0, "cap": 4}, **m}
             return m
 

@@ -6,6 +6,7 @@ postmortem CLI assembling it from the per-node JSONL artifacts), and the
 on-demand jax.profiler endpoint."""
 
 import asyncio
+import functools
 import glob
 import os
 
@@ -16,7 +17,12 @@ from inferd_tpu.config import TINY, SamplingConfig
 from inferd_tpu.core.generate import Engine
 from inferd_tpu.utils.chaos import Chaos, ChaosDrop
 
-from test_node_e2e import BASE, _mk_node, _start_all, _stop_all, tiny_parts  # noqa: F401
+import test_node_e2e as e2e
+from conftest import port_block
+from test_node_e2e import _start_all, _stop_all, tiny_parts  # noqa: F401
+
+PORTS = port_block(__file__)
+_mk_node = functools.partial(e2e._mk_node, ports=PORTS)  # its nodes, this module's ports
 
 
 def test_chaos_parse():
@@ -45,7 +51,7 @@ async def test_chaos_drop_surfaces_as_500():
     nodes[0].chaos = Chaos(drop=1.0)  # stage 0 drops everything
     await _start_all(nodes)
     try:
-        async with SwarmClient([("127.0.0.1", BASE + 70)]) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(70))]) as c:
             with pytest.raises(RuntimeError, match="chaos drop"):
                 await c._post(
                     "/forward", {"stage": 0, "session_id": "s", "payload": {}}
@@ -75,7 +81,7 @@ async def test_node_death_mid_generation_recovers(tiny_parts):  # noqa: F811
         expected = engine.generate(prompt, max_new_tokens=6)
 
         async with SwarmClient(
-            [("127.0.0.1", BASE + 80)], sampling=SamplingConfig(temperature=0.0)
+            [("127.0.0.1", PORTS.http(80))], sampling=SamplingConfig(temperature=0.0)
         ) as c:
             # healthy first pass
             assert await c.generate_ids(prompt, max_new_tokens=6) == expected
@@ -153,7 +159,7 @@ async def test_incident_journal_and_postmortem(tiny_parts3, tmp_path):
         expected = engine.generate(prompt, max_new_tokens=24)
 
         async with SwarmClient(
-            [("127.0.0.1", BASE + 44)],
+            [("127.0.0.1", PORTS.http(44))],
             sampling=SamplingConfig(temperature=0.0),
         ) as c:
             tokens = []
@@ -248,7 +254,7 @@ async def test_profile_endpoint_writes_trace(tmp_path):
     nodes[0].profiler.base_dir = str(tmp_path)  # confine traces to tmp
     await _start_all(nodes)
     try:
-        async with SwarmClient([("127.0.0.1", BASE + 95)]) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(95))]) as c:
             d = str(tmp_path / "trace")
             r = await c._post("/profile", {"action": "start", "name": "trace"})
             assert r["ok"] and r["dir"] == d
@@ -539,7 +545,7 @@ async def test_deadline_expired_entry_fast_fails(tiny_parts):  # noqa: F811
     nodes = [_mk_node(60 + i, i, 2, bootstrap_idx=60) for i in range(2)]
     await _start_all(nodes)
     try:
-        async with SwarmClient([("127.0.0.1", BASE + 60)]) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(60))]) as c:
             with pytest.raises(ServerError) as ei:
                 await c._post("/forward", {
                     "stage": 0, "session_id": "dl", "task_id": "t",
@@ -575,7 +581,7 @@ async def test_deadline_expires_mid_chain_no_downstream_relay():
     nodes[0].chaos = Chaos(delay_ms=400)  # slower than the budget below
     await _start_all(nodes)
     try:
-        async with SwarmClient([("127.0.0.1", BASE + 64)]) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(64))]) as c:
             with pytest.raises(ServerError) as ei:
                 await c._post("/forward", {
                     "stage": 0, "session_id": "dm", "task_id": "t",
@@ -663,7 +669,7 @@ async def test_admission_shed_pool_watermark_and_retry_after():
         # duck-typed pool counters on the live executor: 2 free of 100
         # is under the 5% reserve
         n0.executor.pool = SimpleNamespace(num_blocks=100, blocks_free=2)
-        async with SwarmClient([("127.0.0.1", BASE + 73)]) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(73))]) as c:
             with pytest.raises(ServerError) as ei:
                 await c._post("/forward", {
                     "stage": 0, "session_id": "new", "task_id": "t",
@@ -720,7 +726,7 @@ async def test_drain_hands_off_resident_session_token_exact(tiny_parts):  # noqa
         expected = engine.generate(prompt, max_new_tokens=10)
 
         async with SwarmClient(
-            [("127.0.0.1", BASE + 84)],
+            [("127.0.0.1", PORTS.http(84))],
             sampling=SamplingConfig(temperature=0.0),
         ) as c:
             state = {}

@@ -30,7 +30,9 @@ from inferd_tpu.runtime.node import Node, NodeInfo
 from inferd_tpu.utils import retry as retrylib
 from inferd_tpu.utils.chaos import Chaos
 
-BASE = 19600  # distinct port block (test_failover holds 19400)
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
 HOST = "127.0.0.1"
 GREEDY = SamplingConfig(temperature=0.0)
 SAMPLED = SamplingConfig(temperature=0.8, top_k=20, top_p=0.95)
@@ -52,12 +54,12 @@ def parts(tmp_path_factory):
 
 def _mk_node(idx, parts_dir, stage=0, num_stages=1, bootstrap_idx=None, **kw):
     info = NodeInfo(
-        name=f"gl{idx}", host=HOST, port=BASE + idx, stage=stage,
+        name=f"gl{idx}", host=HOST, port=PORTS.http(idx), stage=stage,
         num_stages=num_stages, capacity=8, model_name="tiny",
     )
-    boot = [] if bootstrap_idx in (None, idx) else [(HOST, BASE + 200 + bootstrap_idx)]
+    boot = [] if bootstrap_idx in (None, idx) else [(HOST, PORTS.gossip(bootstrap_idx))]
     dht = SwarmDHT(
-        info.node_id, BASE + 200 + idx, bootstrap=boot, host=HOST,
+        info.node_id, PORTS.gossip(idx), bootstrap=boot, host=HOST,
         gossip_period_s=0.05, ttl_s=5.0,
     )
     node = Node(

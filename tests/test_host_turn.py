@@ -25,7 +25,9 @@ from inferd_tpu.parallel.mesh import MeshPlan
 from inferd_tpu.parallel.stages import Manifest, split_and_save
 from inferd_tpu.runtime.node import Node, NodeInfo
 
-BASE, HOST = 20000, "127.0.0.1"  # distinct port block (test_block_diffusion holds 19800)
+from conftest import port_block  # noqa: E402
+
+PORTS, HOST = port_block(__file__), "127.0.0.1"
 GREEDY = SamplingConfig(temperature=0.0)
 PROMPTS = ([3, 7, 11, 19, 23, 29, 31, 37], [5, 13, 17, 41, 43, 47, 53, 59])
 NEW = 12
@@ -43,9 +45,9 @@ async def _serve(idx, model, kw, parts_dir):
     """Start the node, stream both prompts side by side, stop it; what it
     left: the spans, `/stats` `executor` before and after, the tokens."""
     cfg = TINY if model == "tiny" else get_config(model)
-    info = NodeInfo(name=f"ht{idx}", host=HOST, port=BASE + idx, stage=0,
+    info = NodeInfo(name=f"ht{idx}", host=HOST, port=PORTS.http(idx), stage=0,
                     num_stages=1, capacity=8, model_name=model)
-    dht = SwarmDHT(info.node_id, BASE + 200 + idx, bootstrap=[], host=HOST,
+    dht = SwarmDHT(info.node_id, PORTS.gossip(idx), bootstrap=[], host=HOST,
                    gossip_period_s=0.05, ttl_s=5.0)
     node = Node(info, cfg, parts_dir, dht, backend="qwen3", max_len=64,
                 rebalance_period_s=600.0, **kw)

@@ -35,7 +35,9 @@ from inferd_tpu.runtime.node import Node, NodeInfo
 from test_step_ahead import Session
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-BASE, HOST = 20600, "127.0.0.1"  # distinct port block (test_host_turn holds 20000)
+from conftest import port_block  # noqa: E402
+
+PORTS, HOST = port_block(__file__), "127.0.0.1"
 SESSIONS, NEW, TOP = 8, 10, 3
 GREEDY = {"temperature": 0.0, "top_k": 0, "top_p": 1.0}
 SAMPLED = {"temperature": 0.9, "top_k": 12, "top_p": 1.0}
@@ -55,9 +57,9 @@ def parts_dir(tmp_path_factory):
 
 
 async def _node(idx, parts_dir, lanes=SESSIONS):
-    info = NodeInfo(name=f"ih{idx}", host=HOST, port=BASE + idx, stage=0,
+    info = NodeInfo(name=f"ih{idx}", host=HOST, port=PORTS.http(idx), stage=0,
                     num_stages=1, capacity=8, model_name="tiny")
-    dht = SwarmDHT(info.node_id, BASE + 200 + idx, bootstrap=[], host=HOST,
+    dht = SwarmDHT(info.node_id, PORTS.gossip(idx), bootstrap=[], host=HOST,
                    gossip_period_s=0.05, ttl_s=5.0)
     node = Node(info, TINY, parts_dir, dht, backend="qwen3", max_len=64,
                 rebalance_period_s=600.0, batch_lanes=lanes)

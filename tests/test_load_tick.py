@@ -22,7 +22,9 @@ from inferd_tpu.control.path_finder import min_load_node
 from inferd_tpu.runtime import wire
 from inferd_tpu.runtime.node import Node, NodeInfo, TaskScheduler
 
-BASE = 20200  # distinct port block (test_disagg holds 18900)
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
@@ -31,11 +33,11 @@ def _mk_node(idx, *, bootstrap=(), gossip_period_s=600.0):
     (gossip and telemetry periods of ten minutes): every record build a
     test counts is one the test asked for."""
     info = NodeInfo(
-        name=f"n{idx}", host="127.0.0.1", port=BASE + idx,
+        name=f"n{idx}", host="127.0.0.1", port=PORTS.http(idx),
         stage=0, num_stages=1, capacity=4, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 100 + idx, bootstrap=list(bootstrap),
+        info.node_id, PORTS.gossip(idx), bootstrap=list(bootstrap),
         host="127.0.0.1", gossip_period_s=gossip_period_s, ttl_s=30.0,
     )
     node = Node(info, TINY, "", dht, backend="counter", max_len=64,
@@ -165,7 +167,7 @@ async def test_hops_build_no_record_until_a_reader_asks():
 
 @pytest.mark.asyncio
 async def test_a_gossip_send_carries_the_load_at_that_send():
-    node = _mk_node(1, bootstrap=[("127.0.0.1", BASE + 199)])
+    node = _mk_node(1, bootstrap=[("127.0.0.1", PORTS.gossip(99))])
     await node.start()
     try:
         frames = _sent(node)
@@ -182,13 +184,13 @@ async def test_a_gossip_send_carries_the_load_at_that_send():
             async with _Held(node, 2):
                 del frames[:]
                 node.dht._on_message(
-                    {"t": "hello", "from": "x:1", "port": BASE + 198},
-                    ("127.0.0.1", BASE + 198))
+                    {"t": "hello", "from": "x:1", "port": PORTS.gossip(98)},
+                    ("127.0.0.1", PORTS.gossip(98)))
                 assert _own(frames[-1][0], node)["value"]["load"] == 5
         del frames[:]
         node.dht._on_message(
             {"t": "state", "from": "x:1", "recs": [], "reply": True},
-            ("127.0.0.1", BASE + 198))
+            ("127.0.0.1", PORTS.gossip(98)))
         assert _own(frames[-1][0], node)["value"]["load"] == 0
     finally:
         await node.stop()
@@ -196,7 +198,7 @@ async def test_a_gossip_send_carries_the_load_at_that_send():
 
 @pytest.mark.asyncio
 async def test_versions_rise_with_each_load_a_peer_is_sent():
-    node = _mk_node(2, bootstrap=[("127.0.0.1", BASE + 199)])
+    node = _mk_node(2, bootstrap=[("127.0.0.1", PORTS.gossip(99))])
     await node.start()
     try:
         frames = _sent(node)
@@ -215,7 +217,7 @@ async def test_versions_rise_with_each_load_a_peer_is_sent():
 @pytest.mark.asyncio
 async def test_a_local_replica_pick_sees_the_exact_inflight_mid_burst():
     a = _mk_node(3, gossip_period_s=0.05)
-    b = _mk_node(4, bootstrap=[("127.0.0.1", BASE + 103)], gossip_period_s=0.05)
+    b = _mk_node(4, bootstrap=[("127.0.0.1", PORTS.gossip(3))], gossip_period_s=0.05)
     await a.start()
     await b.start()
     try:
@@ -249,7 +251,7 @@ async def test_a_local_replica_pick_sees_the_exact_inflight_mid_burst():
 
 @pytest.mark.asyncio
 async def test_an_urgent_announce_still_gossips_at_once():
-    node = _mk_node(5, bootstrap=[("127.0.0.1", BASE + 199)])
+    node = _mk_node(5, bootstrap=[("127.0.0.1", PORTS.gossip(99))])
     await node.start()
     try:
         frames = _sent(node)
@@ -259,7 +261,7 @@ async def test_an_urgent_announce_still_gossips_at_once():
             node.announce()  # as /drain, a migration, a session import do
             assert len(frames) == 1  # sent inside the call, to the one target
             frame, addr = frames[0]
-            assert addr == ("127.0.0.1", BASE + 199) and frame["t"] == "gossip"
+            assert addr == ("127.0.0.1", PORTS.gossip(99)) and frame["t"] == "gossip"
             value = _own(frame, node)["value"]
             assert value["draining"] == 1 and value["load"] == 2
             # the send read the record the call had just built: ONE build
@@ -326,7 +328,7 @@ async def test_a_disabled_planes_record_is_the_bytes_it_always_was(monkeypatch):
         nid = node.info.node_id
         want = {
             "name": "n8", "stage": 0, "load": 0, "cap": 4,
-            "host": "127.0.0.1", "port": BASE + 8, "model": "tiny",
+            "host": "127.0.0.1", "port": PORTS.http(8), "model": "tiny",
         }
         value = node.dht.get_stage(0)[nid]
         assert msgpack.packb(value, use_bin_type=True) == msgpack.packb(

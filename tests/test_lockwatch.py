@@ -24,6 +24,10 @@ from inferd_tpu.utils.lockwatch import (
     WatchedLock,
 )
 
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
+
 
 @pytest.fixture
 def lw(monkeypatch):
@@ -384,12 +388,12 @@ async def test_profile_window_under_traffic_takes_no_lock_out_of_order(tmp_path)
     ):
         pytest.skip("lockwatch killed via INFERD_LOCKWATCH")
     assert lockwatch.watching() and lockwatch.strict()
-    host, port = "127.0.0.1", 20700
+    host, port = "127.0.0.1", PORTS.http()
     split_and_save(qwen3.init_params(TINY, jax.random.PRNGKey(0)), TINY,
                    Manifest.even_split("tiny", 1), str(tmp_path / "parts"))
     info = NodeInfo(name="lw", host=host, port=port, stage=0, num_stages=1,
                     capacity=8, model_name="tiny")
-    dht = SwarmDHT(info.node_id, port + 200, bootstrap=[], host=host,
+    dht = SwarmDHT(info.node_id, PORTS.gossip(), bootstrap=[], host=host,
                    gossip_period_s=0.05, ttl_s=5.0)
     node = Node(info, TINY, str(tmp_path / "parts"), dht, backend="qwen3", max_len=64,
                 rebalance_period_s=600.0, batch_lanes=2, enable_profiling=True)

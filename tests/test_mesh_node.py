@@ -24,7 +24,9 @@ from inferd_tpu.parallel.stages import Manifest, split_and_save
 from inferd_tpu.runtime.node import Node, NodeInfo
 
 
-BASE = 18600
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
 GREEDY = SamplingConfig(temperature=0.0)
 
 
@@ -39,11 +41,11 @@ def mesh_parts(tmp_path_factory):
 
 def _mk_mesh_node(idx, parts, pp=2, slots=3, max_len=64, tp=1):
     info = NodeInfo(
-        name=f"m{idx}", host="127.0.0.1", port=BASE + idx,
+        name=f"m{idx}", host="127.0.0.1", port=PORTS.http(idx),
         stage=0, num_stages=1, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 100 + idx, bootstrap=[],
+        info.node_id, PORTS.gossip(idx), bootstrap=[],
         host="127.0.0.1", gossip_period_s=0.05, ttl_s=1.5,
     )
     return Node(
@@ -64,7 +66,7 @@ async def test_mesh_node_generation_matches_engine(mesh_parts, devices8):
         engine = Engine(TINY, params, max_len=64, sampling_cfg=GREEDY)
         prompt = [3, 7, 11, 19, 23]
         expected = engine.generate(prompt, max_new_tokens=6)
-        async with SwarmClient([("127.0.0.1", BASE + 0)], sampling=GREEDY) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(0))], sampling=GREEDY) as c:
             got = await c.generate_ids(prompt, max_new_tokens=6)
         assert got == expected
     finally:
@@ -83,7 +85,7 @@ async def test_tp_mesh_node_generation_matches_engine(mesh_parts, devices8):
         engine = Engine(TINY, params, max_len=64, sampling_cfg=GREEDY)
         prompt = [3, 7, 11, 19, 23]
         expected = engine.generate(prompt, max_new_tokens=6)
-        async with SwarmClient([("127.0.0.1", BASE + 5)], sampling=GREEDY) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(5))], sampling=GREEDY) as c:
             got = await c.generate_ids(prompt, max_new_tokens=6)
         assert got == expected
     finally:
@@ -105,7 +107,7 @@ async def test_mesh_node_fork_e2e(mesh_parts, devices8):
         expected = engine.generate(prompt, 5)
         from inferd_tpu.client.swarm_client import SwarmClient
 
-        async with SwarmClient([("127.0.0.1", BASE + 7)], sampling=GREEDY) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(7))], sampling=GREEDY) as c:
             await c.pin_prefix(prefix)
             got = [await c.generate_ids(prompt, 5) for _ in range(2)]
         assert got == [expected, expected]
@@ -127,7 +129,7 @@ async def test_mesh_node_concurrent_sessions(mesh_parts, devices8):
         expected = [engine.generate(p, max_new_tokens=5) for p in prompts]
 
         async def gen(p):
-            async with SwarmClient([("127.0.0.1", BASE + 1)], sampling=GREEDY) as c:
+            async with SwarmClient([("127.0.0.1", PORTS.http(1))], sampling=GREEDY) as c:
                 return await c.generate_ids(p, max_new_tokens=5)
 
         got = await asyncio.gather(*(gen(p) for p in prompts))
@@ -177,9 +179,9 @@ async def test_mesh_node_slot_eviction_and_refill(mesh_parts, devices8):
 def test_mesh_requires_single_stage(mesh_parts, devices8):
     parts, _ = mesh_parts
     info = NodeInfo(
-        name="bad", host="127.0.0.1", port=BASE + 50, stage=0, num_stages=2
+        name="bad", host="127.0.0.1", port=PORTS.http(50), stage=0, num_stages=2
     )
-    dht = SwarmDHT(info.node_id, BASE + 150, bootstrap=[], host="127.0.0.1")
+    dht = SwarmDHT(info.node_id, PORTS.gossip(50), bootstrap=[], host="127.0.0.1")
     with pytest.raises(ValueError, match="single-stage"):
         Node(info, TINY, parts, dht, mesh_plan=MeshPlan(pp=2))
 
@@ -438,10 +440,10 @@ def test_worker_pool_admits_a_thread_per_session(mesh_parts, devices8, flags, wo
     --batch-lanes, two for a plain stage."""
     parts, _params = mesh_parts
     info = NodeInfo(
-        name="pool", host="127.0.0.1", port=BASE + 90,
+        name="pool", host="127.0.0.1", port=PORTS.http(90),
         stage=0, num_stages=1, model_name="tiny",
     )
-    dht = SwarmDHT(info.node_id, BASE + 190, bootstrap=[], host="127.0.0.1")
+    dht = SwarmDHT(info.node_id, PORTS.gossip(90), bootstrap=[], host="127.0.0.1")
     node = Node(info, TINY, parts, dht, backend="qwen3", max_len=64, **flags)
     try:
         assert node.scheduler._pool._max_workers == workers

@@ -857,9 +857,12 @@ def test_every_layout_runs_through_the_one_scan(layout):
 
     # row r's whole sequence: its prefill tokens, then its decoded ones
     seqs = [np.concatenate([toks[r, : lens[r]], toks[r, 6:9]]) for r in range(2)]
-    want = [np.asarray(free(jnp.asarray(s)[None]))[0] for s in seqs]
+    # compiled (a program a shape) where op-by-op dispatch paid every call;
+    # the jaxpr that is counted below is the function's own
+    run, whole = jax.jit(cached), jax.jit(free)
+    want = [np.asarray(whole(jnp.asarray(s)[None]))[0] for s in seqs]
     pos = jnp.broadcast_to(jnp.arange(6), (2, 6))
-    logits, cache = cached(toks[:, :6], pos, cache, jnp.int32(0), jnp.asarray(lens))
+    logits, cache = run(toks[:, :6], pos, cache, jnp.int32(0), jnp.asarray(lens))
     for r in range(2):
         np.testing.assert_allclose(
             logits[r, : lens[r]], want[r][: lens[r]], rtol=1e-4, atol=1e-4
@@ -867,10 +870,10 @@ def test_every_layout_runs_through_the_one_scan(layout):
     for i in range(3):
         at = jnp.asarray(lens) + i if per_row else jnp.int32(6 + i)
         pos = jnp.broadcast_to(jnp.asarray(lens)[:, None] + i, (2, 1))
-        step = lambda c: cached(toks[:, 6 + i : 7 + i], pos, c, at, at + 1)
+        step = lambda c, f=cached: f(toks[:, 6 + i : 7 + i], pos, c, at, at + 1)
         if i == 0:
             assert _scan_lengths(jax.make_jaxpr(step)(cache).jaxpr) == want_scans
-        logits, cache = step(cache)
+        logits, cache = step(cache, run)
         for r in range(2):
             np.testing.assert_allclose(
                 logits[r, 0], want[r][lens[r] + i], rtol=1e-4, atol=1e-4

@@ -17,21 +17,26 @@ from inferd_tpu.parallel.stages import Manifest, split_and_save
 from inferd_tpu.runtime import wire
 from inferd_tpu.runtime.node import Node, NodeInfo
 
-BASE = 18200
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
 
 
 def _mk_node(
     idx, stage, num_stages, *, backend="counter", parts="", bootstrap_idx=0,
-    rebalance_period_s=600.0, capacity=4, lora="",
+    rebalance_period_s=600.0, capacity=4, lora="", ports=None,
 ):
-    """Node with HTTP on BASE+idx, gossip UDP on BASE+100+idx."""
+    """Node `idx` of the block `ports` (a module that imports this helper
+    hands its own; default: this module's): HTTP on ports.http(idx), gossip
+    UDP on ports.gossip(idx)."""
+    ports = ports or PORTS
     info = NodeInfo(
-        name=f"n{idx}", host="127.0.0.1", port=BASE + idx,
+        name=f"n{idx}", host="127.0.0.1", port=ports.http(idx),
         stage=stage, num_stages=num_stages, capacity=capacity, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 100 + idx,
-        bootstrap=[("127.0.0.1", BASE + 100 + bootstrap_idx)] if idx != bootstrap_idx else [],
+        info.node_id, ports.gossip(idx),
+        bootstrap=[("127.0.0.1", ports.gossip(bootstrap_idx))] if idx != bootstrap_idx else [],
         host="127.0.0.1", gossip_period_s=0.05, ttl_s=1.5,
     )
     return Node(
@@ -71,7 +76,7 @@ async def test_counter_pipeline_three_stages():
     nodes = [_mk_node(i, i, 3) for i in range(3)]
     await _start_all(nodes)
     try:
-        async with SwarmClient([("127.0.0.1", BASE + 0)]) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(0))]) as c:
             resp = await c._post(
                 "/forward",
                 {"stage": 0, "session_id": "s1", "payload": {}},
@@ -90,7 +95,7 @@ async def test_wrong_entry_node_relays():
     nodes = [_mk_node(i, i, 3) for i in range(3)]
     await _start_all(nodes)
     try:
-        async with SwarmClient([("127.0.0.1", BASE + 2)]) as c:  # entry = stage 2
+        async with SwarmClient([("127.0.0.1", PORTS.http(2))]) as c:  # entry = stage 2
             resp = await c._post("/forward", {"stage": 0, "session_id": "s2", "payload": {}})
         assert resp["result_for_user"]["result_for_user"]["trace"] == [0, 1, 2]
     finally:
@@ -121,7 +126,7 @@ async def test_distributed_generation_matches_engine(tiny_parts):
         prompt = [3, 7, 11, 19]
         expected = engine.generate(prompt, max_new_tokens=6)
         async with SwarmClient(
-            [("127.0.0.1", BASE + 10)], sampling=SamplingConfig(temperature=0.0)
+            [("127.0.0.1", PORTS.http(10))], sampling=SamplingConfig(temperature=0.0)
         ) as c:
             got = await c.generate_ids(prompt, max_new_tokens=6)
         assert got == expected
@@ -196,7 +201,7 @@ async def test_lora_swarm_matches_merged_engine(tiny_parts, tmp_path):
         base_engine = Engine(TINY, params, max_len=64, sampling_cfg=SamplingConfig(temperature=0.0))
         assert base_engine.generate(prompt, max_new_tokens=6) != expected
         async with SwarmClient(
-            [("127.0.0.1", BASE + 70)], sampling=SamplingConfig(temperature=0.0)
+            [("127.0.0.1", PORTS.http(70))], sampling=SamplingConfig(temperature=0.0)
         ) as c:
             got = await c.generate_ids(prompt, max_new_tokens=6)
         assert got == expected
@@ -222,7 +227,7 @@ async def test_reassign_endpoint(tiny_parts):
 
         async with aiohttp.ClientSession() as s:
             async with s.post(
-                f"http://127.0.0.1:{BASE + 22}/reassign", data=wire.pack({"stage": 1})
+                f"http://127.0.0.1:{PORTS.http(22)}/reassign", data=wire.pack({"stage": 1})
             ) as r:
                 assert r.status == 200
         assert extra.info.stage == 1
@@ -241,7 +246,7 @@ async def test_reassign_endpoint(tiny_parts):
         assert len(nodes[0].dht.get_stage(1)) == 2
         # and the moved node actually serves stage 1 traffic end to end
         async with SwarmClient(
-            [("127.0.0.1", BASE + 20)], sampling=SamplingConfig(temperature=0.0)
+            [("127.0.0.1", PORTS.http(20))], sampling=SamplingConfig(temperature=0.0)
         ) as c:
             out = await c.generate_ids([5, 6], max_new_tokens=3)
         assert len(out) == 3
@@ -263,7 +268,7 @@ async def test_dead_stage_adoption():
         await n0.stop()  # silent death; TTL (1.5 s) expires its record
         await asyncio.sleep(2.0)
         assert len(n1a.dht.get_stage(0)) == 0
-        async with SwarmClient([("127.0.0.1", BASE + 31)], timeout_s=30.0) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(31))], timeout_s=30.0) as c:
             resp = await c._post("/forward", {"stage": 0, "session_id": "s3", "payload": {}})
         r = resp["result_for_user"]["result_for_user"]
         assert r["state"] == 2
@@ -293,7 +298,7 @@ async def test_reassign_hands_off_sessions(tiny_parts):
         prompt = [3, 7, 11, 19]
         expected = engine.generate(prompt, max_new_tokens=6)
         async with SwarmClient(
-            [("127.0.0.1", BASE + 60)], sampling=SamplingConfig(temperature=0.0)
+            [("127.0.0.1", PORTS.http(60))], sampling=SamplingConfig(temperature=0.0)
         ) as c:
             sid = "mig-session"
             logits = await c._step(sid, prompt, 0)
@@ -354,7 +359,7 @@ async def test_reassign_without_replica_degrades_to_restart(tiny_parts):
         prompt = [3, 7, 11, 19]
         expected = engine.generate(prompt, max_new_tokens=4)
         async with SwarmClient(
-            [("127.0.0.1", BASE + 70), ("127.0.0.1", BASE + 71)],
+            [("127.0.0.1", PORTS.http(70)), ("127.0.0.1", PORTS.http(71))],
             sampling=SamplingConfig(temperature=0.0), timeout_s=60.0,
         ) as c:
             # start a session, then migrate stage 1's ONLY node to stage 0:
@@ -536,11 +541,11 @@ async def test_chunked_prefill_matches_single_shot(tiny_parts):
     try:
         prompt = [3, 7, 11, 19, 23, 29, 31, 37, 41, 2]
         async with SwarmClient(
-            [("127.0.0.1", BASE + 50)], sampling=SamplingConfig(temperature=0.0)
+            [("127.0.0.1", PORTS.http(50))], sampling=SamplingConfig(temperature=0.0)
         ) as c:
             whole = await c.generate_ids(prompt, max_new_tokens=6)
         async with SwarmClient(
-            [("127.0.0.1", BASE + 50)], sampling=SamplingConfig(temperature=0.0),
+            [("127.0.0.1", PORTS.http(50))], sampling=SamplingConfig(temperature=0.0),
             prefill_chunk=3,
         ) as c:
             chunked = await c.generate_ids(prompt, max_new_tokens=6)
@@ -560,12 +565,12 @@ async def test_fp8_kv_swarm_matches_fp8_engine(tiny_parts):
     nodes = []
     for i in range(2):
         info = NodeInfo(
-            name=f"f{i}", host="127.0.0.1", port=BASE + 60 + i,
+            name=f"f{i}", host="127.0.0.1", port=PORTS.http(60 + i),
             stage=i, num_stages=2, capacity=4, model_name="tiny",
         )
         dht = SwarmDHT(
-            info.node_id, BASE + 160 + i,
-            bootstrap=[] if i == 0 else [("127.0.0.1", BASE + 160)],
+            info.node_id, PORTS.gossip(60 + i),
+            bootstrap=[] if i == 0 else [("127.0.0.1", PORTS.gossip(60))],
             host="127.0.0.1", gossip_period_s=0.05, ttl_s=1.5,
         )
         nodes.append(Node(
@@ -578,7 +583,7 @@ async def test_fp8_kv_swarm_matches_fp8_engine(tiny_parts):
         prompt = [3, 7, 11, 19]
         want = engine.generate(prompt, max_new_tokens=6)
         async with SwarmClient(
-            [("127.0.0.1", BASE + 60)], sampling=SamplingConfig(temperature=0.0)
+            [("127.0.0.1", PORTS.http(60))], sampling=SamplingConfig(temperature=0.0)
         ) as c:
             got = await c.generate_ids(prompt, max_new_tokens=6)
         assert got == want
@@ -605,7 +610,7 @@ async def test_entry_failover_rescued_via_gossip_sessions(tiny_parts):
         expected = engine.generate(prompt, max_new_tokens=6)
         sid = "failover-session"
         async with SwarmClient(
-            [("127.0.0.1", BASE + 80)], sampling=SamplingConfig(temperature=0.0)
+            [("127.0.0.1", PORTS.http(80))], sampling=SamplingConfig(temperature=0.0)
         ) as c_a:
             logits = await c_a._step(sid, prompt, 0)
             toks = [int(np.argmax(logits))]
@@ -627,7 +632,7 @@ async def test_entry_failover_rescued_via_gossip_sessions(tiny_parts):
             raise TimeoutError("session advert never gossiped")
         # client fails over: remaining chunks enter via n0b
         async with SwarmClient(
-            [("127.0.0.1", BASE + 81)], sampling=SamplingConfig(temperature=0.0)
+            [("127.0.0.1", PORTS.http(81))], sampling=SamplingConfig(temperature=0.0)
         ) as c_b:
             for _ in range(3):
                 logits = await c_b._step(sid, [toks[-1]], pos)
@@ -672,7 +677,7 @@ async def test_trace_merged_timeline_three_stage_swarm(tiny_parts3, tmp_path):
     try:
         prompt = [3, 7, 11, 19]
         async with SwarmClient(
-            [("127.0.0.1", BASE + 91)],  # stage-1 entry: every chunk
+            [("127.0.0.1", PORTS.http(91))],  # stage-1 entry: every chunk
             # arrives at the wrong node and relays to stage 0 first
             sampling=SamplingConfig(temperature=0.0),
         ) as c:
@@ -744,7 +749,7 @@ async def test_trace_server_side_generate_joins_client_trace(
     spans_dir = tmp_path / "spans"
     try:
         async with SwarmClient(
-            [("127.0.0.1", BASE + 98)], sampling=SamplingConfig(temperature=0.0)
+            [("127.0.0.1", PORTS.http(98))], sampling=SamplingConfig(temperature=0.0)
         ) as c:
             ids = await c.generate_server_side([3, 7, 11, 19], max_new_tokens=3)
             assert len(ids) == 3
@@ -779,16 +784,16 @@ async def test_metrics_endpoint_prometheus_and_spans():
     nodes = [_mk_node(95, 0, 1)]
     await _start_all(nodes)
     try:
-        async with SwarmClient([("127.0.0.1", BASE + 95)]) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(95))]) as c:
             await c._post(
                 "/forward", {"stage": 0, "session_id": "m1", "payload": {}}
             )
         async with aiohttp.ClientSession() as s:
-            async with s.get(f"http://127.0.0.1:{BASE + 95}/metrics") as r:
+            async with s.get(f"http://127.0.0.1:{PORTS.http(95)}/metrics") as r:
                 assert r.status == 200
                 assert "text/plain" in r.headers["Content-Type"]
                 text = await r.text()
-            async with s.get(f"http://127.0.0.1:{BASE + 95}/spans") as r:
+            async with s.get(f"http://127.0.0.1:{PORTS.http(95)}/spans") as r:
                 assert r.status == 200
                 ndjson = await r.text()
         assert obs_export.validate_exposition(text) == []
@@ -828,7 +833,7 @@ async def test_tracing_disabled_leaves_envelope_and_behavior_intact(
                         sampling_cfg=SamplingConfig(temperature=0.0))
         prompt = [3, 7, 11, 19]
         async with SwarmClient(
-            [("127.0.0.1", BASE + 96)], sampling=SamplingConfig(temperature=0.0)
+            [("127.0.0.1", PORTS.http(96))], sampling=SamplingConfig(temperature=0.0)
         ) as c:
             got = await c.generate_ids(prompt, max_new_tokens=4)
             assert got == engine.generate(prompt, max_new_tokens=4)
@@ -858,7 +863,7 @@ async def test_graceful_entry_death_hands_off_and_failover_continues(tiny_parts)
         expected = engine.generate(prompt, max_new_tokens=6)
         sid = "dying-entry-session"
         async with SwarmClient(
-            [("127.0.0.1", BASE + 85), ("127.0.0.1", BASE + 86)],
+            [("127.0.0.1", PORTS.http(85)), ("127.0.0.1", PORTS.http(86))],
             sampling=SamplingConfig(temperature=0.0),
         ) as c:
             logits = await c._step(sid, prompt, 0)
@@ -912,9 +917,9 @@ async def test_node_says_what_device_it_computes_on(tiny_parts):
             "device_count": len(jax.devices()),
         }
         async with aiohttp.ClientSession() as http:
-            async with http.get(f"http://127.0.0.1:{BASE + 40}/stats") as r:
+            async with http.get(f"http://127.0.0.1:{PORTS.http(40)}/stats") as r:
                 stats = await r.json()
-            async with http.get(f"http://127.0.0.1:{BASE + 45}/stats") as r:
+            async with http.get(f"http://127.0.0.1:{PORTS.http(45)}/stats") as r:
                 cstats = await r.json()
         memory = stats["device"].pop("memory")
         assert stats["device"] == want and isinstance(memory, list)

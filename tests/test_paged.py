@@ -737,15 +737,12 @@ def test_chunked_prefill_interleaves_with_decode_windows(whole_stage):
 # ---------------------------------------------------------------------------
 
 
-def _decode_tokens(cfg, params, cache, logits, n, steps):
+def _decode_tokens(forward, cache, logits, n, steps):
     toks = [int(np.argmax(np.asarray(logits)[0, n - 1]))]
     lens = n
     for _ in range(steps):
-        l, cache, _ = qwen3.forward_cached(
-            params, cfg, jnp.asarray([[toks[-1]]], jnp.int32),
-            jnp.asarray([[lens]], jnp.int32), cache, jnp.int32(lens),
-            real_end=jnp.int32(lens + 1),
-        )
+        l, cache, _ = forward(jnp.asarray([[toks[-1]]], jnp.int32),
+                              jnp.asarray([[lens]], jnp.int32), cache, jnp.int32(lens))
         toks.append(int(np.argmax(np.asarray(l)[0, 0])))  # jaxlint: disable=J003 -- per-token decode loop: one boundary sync per emitted token is the pattern under test
         lens += 1
     return toks, cache
@@ -761,32 +758,28 @@ def test_grow_then_decode_token_exact(preset):
     n = prompt.shape[1]
     pos = jnp.broadcast_to(jnp.arange(n), (1, n))
 
+    @jax.jit  # one program a chunk width and bucket, where op-by-op dispatch paid a step
+    def forward(toks, pos, cache, at):
+        return qwen3.forward_cached(params, cfg, toks, pos, cache, at, real_end=at + toks.shape[1])
+
     small = KVCache.create(cfg, cfg.num_layers, 1, 32)
     big = KVCache.create(cfg, cfg.num_layers, 1, 64)
-    ls, cs, _ = qwen3.forward_cached(params, cfg, jnp.asarray(prompt), pos,
-                                  small, jnp.int32(0), real_end=jnp.int32(n))
-    lb, cb, _ = qwen3.forward_cached(params, cfg, jnp.asarray(prompt), pos,
-                                  big, jnp.int32(0), real_end=jnp.int32(n))
-    toks_small, cs = _decode_tokens(cfg, params, cs, ls, n, 8)
+    ls, cs, _ = forward(jnp.asarray(prompt), pos, small, jnp.int32(0))
+    lb, cb, _ = forward(jnp.asarray(prompt), pos, big, jnp.int32(0))
+    toks_small, cs = _decode_tokens(forward, cs, ls, n, 8)
     # grow mid-stream, decode past the old 32-slot bucket
     cs = grow(cs, 64)
     assert cs.max_len == 64
-    toks_big, cb = _decode_tokens(cfg, params, cb, lb, n, 8)
+    toks_big, cb = _decode_tokens(forward, cb, lb, n, 8)
     assert toks_small == toks_big
     # continue decoding in the grown cache vs the always-big cache
     lens = n + 8
     tok = toks_big[-1]
     for _ in range(16):
-        l1, cs, _ = qwen3.forward_cached(
-            params, cfg, jnp.asarray([[tok]], jnp.int32),
-            jnp.asarray([[lens]], jnp.int32), cs, jnp.int32(lens),
-            real_end=jnp.int32(lens + 1),
-        )
-        l2, cb, _ = qwen3.forward_cached(
-            params, cfg, jnp.asarray([[tok]], jnp.int32),
-            jnp.asarray([[lens]], jnp.int32), cb, jnp.int32(lens),
-            real_end=jnp.int32(lens + 1),
-        )
+        l1, cs, _ = forward(jnp.asarray([[tok]], jnp.int32), jnp.asarray([[lens]], jnp.int32),
+                            cs, jnp.int32(lens))
+        l2, cb, _ = forward(jnp.asarray([[tok]], jnp.int32), jnp.asarray([[lens]], jnp.int32),
+                            cb, jnp.int32(lens))
         t1 = int(np.argmax(np.asarray(l1)[0, 0]))  # jaxlint: disable=J003 -- per-token parity loop: the grown-vs-big comparison IS per step
         t2 = int(np.argmax(np.asarray(l2)[0, 0]))  # jaxlint: disable=J003 -- same per-step comparison
         assert t1 == t2

@@ -28,7 +28,9 @@ from inferd_tpu.parallel.stages import (
 from inferd_tpu.runtime.executor import Qwen3StageExecutor
 from inferd_tpu.runtime.node import Node, NodeInfo
 
-BASE = 18800  # distinct port block from test_batch_node (18700)
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
 
 PREFIX = [3, 7, 11, 19, 5, 2, 17, 13]
 GREEDY = SamplingConfig(temperature=0.0)
@@ -210,12 +212,12 @@ def test_mesh_executor_fork_parity(tiny_params):
 
 def _mk_node(idx, stage, num_stages, *, parts, bootstrap_idx):
     info = NodeInfo(
-        name=f"px{idx}", host="127.0.0.1", port=BASE + idx,
+        name=f"px{idx}", host="127.0.0.1", port=PORTS.http(idx),
         stage=stage, num_stages=num_stages, capacity=4, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 100 + idx,
-        bootstrap=[("127.0.0.1", BASE + 100 + bootstrap_idx)]
+        info.node_id, PORTS.gossip(idx),
+        bootstrap=[("127.0.0.1", PORTS.gossip(bootstrap_idx))]
         if idx != bootstrap_idx else [],
         host="127.0.0.1", gossip_period_s=0.05, ttl_s=1.5,
     )
@@ -263,7 +265,7 @@ async def test_swarm_fork_e2e(tiny_parts, tiny_params):
         tails = ([4, 9], [8, 6, 1])
         expected = [engine.generate(PREFIX + list(t), 5) for t in tails]
         async with SwarmClient(
-            [("127.0.0.1", BASE + 0)], sampling=GREEDY, prefill_chunk=4
+            [("127.0.0.1", PORTS.http(0))], sampling=GREEDY, prefill_chunk=4
         ) as c:
             await c.pin_prefix(PREFIX)
             got = [await c.generate_ids(PREFIX + list(t), 5) for t in tails]
@@ -290,7 +292,7 @@ async def test_swarm_fork_fallback_after_parent_eviction(tiny_parts, tiny_params
         prompt = PREFIX + [4, 9]
         expected = engine.generate(prompt, 5)
         async with SwarmClient(
-            [("127.0.0.1", BASE + 10)], sampling=GREEDY
+            [("127.0.0.1", PORTS.http(10))], sampling=GREEDY
         ) as c:
             await c.pin_prefix(PREFIX)
             parent_sid, _ = c._pins[tuple(PREFIX)]
@@ -317,7 +319,7 @@ async def test_server_side_generate(tiny_parts, tiny_params):
         prompt = PREFIX + [4, 9]
         expected = engine.generate(prompt, 5)
         async with SwarmClient(
-            [("127.0.0.1", BASE + 30)], sampling=GREEDY, timeout_s=60.0
+            [("127.0.0.1", PORTS.http(30))], sampling=GREEDY, timeout_s=60.0
         ) as c:
             got = await c.generate_server_side(prompt, max_new_tokens=5)
             assert got == expected
@@ -336,7 +338,7 @@ async def test_server_side_generate(tiny_parts, tiny_params):
         )
         # entering at the WRONG node still works (relay to stage 0)
         async with SwarmClient(
-            [("127.0.0.1", BASE + 31)], sampling=GREEDY, timeout_s=60.0
+            [("127.0.0.1", PORTS.http(31))], sampling=GREEDY, timeout_s=60.0
         ) as c:
             got = await c.generate_server_side(prompt, max_new_tokens=5)
         assert got == expected
@@ -363,7 +365,7 @@ async def test_server_side_generate_logprobs(tiny_parts, tiny_params):
     try:
         prompt = [3, 7, 11, 5]
         async with SwarmClient(
-            [("127.0.0.1", BASE + 90)], sampling=GREEDY, timeout_s=60.0
+            [("127.0.0.1", PORTS.http(90))], sampling=GREEDY, timeout_s=60.0
         ) as c:
             lps: list = []
             tops: list = []
@@ -416,7 +418,7 @@ async def test_server_side_generate_stream(tiny_parts, tiny_params):
         expected = engine.generate(prompt, 5)
         streamed = []
         async with SwarmClient(
-            [("127.0.0.1", BASE + 40)], sampling=GREEDY, timeout_s=60.0
+            [("127.0.0.1", PORTS.http(40))], sampling=GREEDY, timeout_s=60.0
         ) as c:
             got = await c.generate_server_side_stream(
                 prompt, streamed.append, max_new_tokens=5
@@ -447,7 +449,7 @@ async def test_server_side_generate_concurrent_sampling(tiny_parts, tiny_params)
         from inferd_tpu.client.base import sample_np
 
         async with SwarmClient(
-            [("127.0.0.1", BASE + 60)], sampling=GREEDY, timeout_s=60.0
+            [("127.0.0.1", PORTS.http(60))], sampling=GREEDY, timeout_s=60.0
         ) as c:
             pairs = await asyncio.gather(
                 c.generate_server_side(prompt, max_new_tokens=6, seed=0),
@@ -462,7 +464,7 @@ async def test_server_side_generate_concurrent_sampling(tiny_parts, tiny_params)
         # sampler over a locally-driven session would need logits; instead
         # assert determinism of the hot path itself (same seed -> same out)
         async with SwarmClient(
-            [("127.0.0.1", BASE + 60)], sampling=GREEDY, timeout_s=60.0
+            [("127.0.0.1", PORTS.http(60))], sampling=GREEDY, timeout_s=60.0
         ) as c:
             sampled2 = await c.generate_server_side(
                 prompt, max_new_tokens=6, seed=3, sampling=hot
@@ -483,11 +485,11 @@ async def test_speculative_server_side_generate(tiny_params):
     work = tempfile.mkdtemp(prefix="prefix_spec_")
     split_and_save(tiny_params, TINY, Manifest.even_split("tiny", 1), work)
     info = NodeInfo(
-        name="sp0", host="127.0.0.1", port=BASE + 70,
+        name="sp0", host="127.0.0.1", port=PORTS.http(70),
         stage=0, num_stages=1, capacity=4, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 170, bootstrap=[], host="127.0.0.1",
+        info.node_id, PORTS.gossip(70), bootstrap=[], host="127.0.0.1",
         gossip_period_s=0.05, ttl_s=1.5,
     )
     node = Node(
@@ -500,7 +502,7 @@ async def test_speculative_server_side_generate(tiny_params):
         prompt = [3, 7, 11, 19, 5]
         expected = engine.generate(prompt, 8)
         async with SwarmClient(
-            [("127.0.0.1", BASE + 70)], sampling=GREEDY, timeout_s=60.0
+            [("127.0.0.1", PORTS.http(70))], sampling=GREEDY, timeout_s=60.0
         ) as c:
             resp = await c._post(
                 "/generate",
@@ -519,7 +521,7 @@ async def test_speculative_server_side_generate(tiny_params):
             prompt, 8, logprob_sink=elps, top_n=3, top_sink=etops
         )
         async with SwarmClient(
-            [("127.0.0.1", BASE + 70)], sampling=GREEDY, timeout_s=60.0
+            [("127.0.0.1", PORTS.http(70))], sampling=GREEDY, timeout_s=60.0
         ) as c:
             resp_lp = await c._post(
                 "/generate",
@@ -537,7 +539,7 @@ async def test_speculative_server_side_generate(tiny_params):
         # so and carries the acceptance rate; /stats accumulates the
         # production counters
         async with SwarmClient(
-            [("127.0.0.1", BASE + 70)], sampling=GREEDY, timeout_s=120.0
+            [("127.0.0.1", PORTS.http(70))], sampling=GREEDY, timeout_s=120.0
         ) as c:
             resp2 = await c._post(
                 "/generate",
@@ -553,7 +555,7 @@ async def test_speculative_server_side_generate(tiny_params):
         # sampled + logprobs falls back to the regular loop (the rejection
         # step has no per-token logprob trail)
         async with SwarmClient(
-            [("127.0.0.1", BASE + 70)], sampling=GREEDY, timeout_s=120.0
+            [("127.0.0.1", PORTS.http(70))], sampling=GREEDY, timeout_s=120.0
         ) as c:
             resp3 = await c._post(
                 "/generate",
@@ -587,11 +589,11 @@ async def test_speculative_sampled_distribution_over_http(tiny_params):
     work = tempfile.mkdtemp(prefix="prefix_spec_tv_")
     split_and_save(tiny_params, TINY, Manifest.even_split("tiny", 1), work)
     info = NodeInfo(
-        name="sptv0", host="127.0.0.1", port=BASE + 71,
+        name="sptv0", host="127.0.0.1", port=PORTS.http(71),
         stage=0, num_stages=1, capacity=4, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 171, bootstrap=[], host="127.0.0.1",
+        info.node_id, PORTS.gossip(71), bootstrap=[], host="127.0.0.1",
         gossip_period_s=0.05, ttl_s=1.5,
     )
     node = Node(
@@ -617,7 +619,7 @@ async def test_speculative_sampled_distribution_over_http(tiny_params):
         counts = np.zeros(TINY.vocab_size)
         trials = 250
         async with SwarmClient(
-            [("127.0.0.1", BASE + 71)], sampling=GREEDY, timeout_s=120.0
+            [("127.0.0.1", PORTS.http(71))], sampling=GREEDY, timeout_s=120.0
         ) as c:
             for seed in range(trials):
                 r = await c._post(
@@ -659,11 +661,11 @@ async def test_batched_node_fork_e2e(tiny_params):
     work = tempfile.mkdtemp(prefix="prefix_batch_")
     split_and_save(tiny_params, TINY, Manifest.even_split("tiny", 1), work)
     info = NodeInfo(
-        name="pb0", host="127.0.0.1", port=BASE + 50,
+        name="pb0", host="127.0.0.1", port=PORTS.http(50),
         stage=0, num_stages=1, capacity=4, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 150, bootstrap=[], host="127.0.0.1",
+        info.node_id, PORTS.gossip(50), bootstrap=[], host="127.0.0.1",
         gossip_period_s=0.05, ttl_s=1.5,
     )
     node = Node(
@@ -676,7 +678,7 @@ async def test_batched_node_fork_e2e(tiny_params):
         prompt = PREFIX + [4, 9]
         expected = engine.generate(prompt, 5)
         async with SwarmClient(
-            [("127.0.0.1", BASE + 50)], sampling=GREEDY
+            [("127.0.0.1", PORTS.http(50))], sampling=GREEDY
         ) as c:
             await c.pin_prefix(PREFIX)
             got = [await c.generate_ids(prompt, 5) for _ in range(2)]
@@ -699,7 +701,7 @@ async def test_chain_fork_e2e(tiny_parts, tiny_params):
         prompt = PREFIX + [4, 9]
         expected = engine.generate(prompt, 5)
         async with ChainClient(
-            [("127.0.0.1", BASE + 20), ("127.0.0.1", BASE + 21)], sampling=GREEDY
+            [("127.0.0.1", PORTS.http(20)), ("127.0.0.1", PORTS.http(21))], sampling=GREEDY
         ) as c:
             await c.pin_prefix(PREFIX)
             got = await c.generate_ids(prompt, 5)

@@ -26,6 +26,8 @@ from inferd_tpu.core import cache as cachelib
 from inferd_tpu.core.batch import BatchedEngine
 from inferd_tpu.models import qwen3
 
+from conftest import own_lane_programs  # noqa: E402
+
 T = 1024  # the shortest slab the ladder engages on: eight rungs of 128 slots
 W = T // 8
 LANES = 4
@@ -76,7 +78,9 @@ def _engine(model: str, whole: bool, max_len: int = T) -> BatchedEngine:
     if key not in _ENGINES:
         cfg = _config(model)
         params = qwen3.init_params(cfg, jax.random.PRNGKey(0))
-        _ENGINES[key] = BatchedEngine(cfg, params, lanes=LANES, max_len=max_len)
+        eng = BatchedEngine(cfg, params, lanes=LANES, max_len=max_len)
+        # the whole-slab read is patched in at trace time: programs of its own
+        _ENGINES[key] = own_lane_programs(eng) if whole else eng
     return _ENGINES[key]
 
 
@@ -314,7 +318,7 @@ def test_a_latent_lane_keeps_its_ladder_where_the_chip_would_pick_the_kernel(mon
     kernel serves latent attention, so `flash_enabled` says no for a latent
     model and both latent cells keep eight rungs in both programs: a latent
     row is 512 columns of bf16 (and 64 of roped key), so a chunk's slice of a
-    whole lane is 16 MiB. A chunk over 16 rows (K-step, a speculative verify)
+    whole lane is 16 MiB. A chunk over 16 rows (a speculative verify)
     keeps the 64 MiB cap as lanes of heads do."""
     from inferd_tpu.ops import attention as attention_ops
 
@@ -346,7 +350,8 @@ def test_a_ladder_with_rungs_left_out_reads_to_the_next_it_has(end, monkeypatch)
     rungs = qwen3.read_rungs(
         cfg, _stacks(T, (cfg.num_kv_heads, cfg.head_dim), dtype=jnp.float32), 1, LANES, False)
     assert rungs == (W, 2 * W, T)
-    eng = BatchedEngine(cfg, qwen3.init_params(cfg, jax.random.PRNGKey(0)), lanes=LANES, max_len=T)
+    eng = own_lane_programs(  # traced under the patched limit
+        BatchedEngine(cfg, qwen3.init_params(cfg, jax.random.PRNGKey(0)), lanes=LANES, max_len=T))
     lens = jnp.asarray([end - 1, 0, end // 2, 7], jnp.int32)
     toks = jnp.asarray([3, 0, 5, 9], jnp.int32)
     rung = rungs[int(qwen3.read_rung(end, rungs))]

@@ -303,6 +303,13 @@ def test_ring_fuzz_random_chunks_and_rollbacks():
     cfg = _dc.replace(TINY_GEMMA2, num_layers=2)  # 1 sliding + 1 global
     params = qwen3.init_params(cfg, jax.random.PRNGKey(21))
     rng = np.random.RandomState(42)
+
+    @jax.jit  # one program a chunk size and layout, where op-by-op dispatch paid a trial
+    def forward(cache, chunk, pos):
+        s = chunk.shape[1]
+        return qwen3.forward_cached(
+            params, cfg, chunk, pos + jnp.arange(s)[None, :], cache, pos, real_end=pos + s)[:2]
+
     for trial in range(4):
         max_len = 192
         ring = KVCache.create(cfg, cfg.num_layers, 1, max_len)
@@ -316,15 +323,8 @@ def test_ring_fuzz_random_chunks_and_rollbacks():
             s = int(rng.choice([1, 3, 16, 90]))
             s = min(s, max_len - pos)
             chunk = rng.randint(0, cfg.vocab_size, size=(1, s)).astype(np.int32)
-            pos_arr = pos + jnp.arange(s)[None, :]
-            lr, ring, _ = qwen3.forward_cached(
-                params, cfg, jnp.asarray(chunk), pos_arr, ring,
-                jnp.int32(pos), real_end=jnp.int32(pos + s),
-            )
-            lf, flat, _ = qwen3.forward_cached(
-                params, cfg, jnp.asarray(chunk), pos_arr, flat,
-                jnp.int32(pos), real_end=jnp.int32(pos + s),
-            )
+            lr, ring = forward(ring, jnp.asarray(chunk), jnp.int32(pos))
+            lf, flat = forward(flat, jnp.asarray(chunk), jnp.int32(pos))
             np.testing.assert_allclose(
                 np.asarray(lr[:, s - 1]), np.asarray(lf[:, s - 1]),
                 rtol=2e-4, atol=2e-4,

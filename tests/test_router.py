@@ -31,7 +31,9 @@ from inferd_tpu.models import qwen3
 from inferd_tpu.parallel.stages import Manifest, split_and_save
 from inferd_tpu.runtime.node import Node, NodeInfo
 
-BASE = 19000  # distinct port block from test_prefix (18800)
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
 
 GREEDY = SamplingConfig(temperature=0.0)
 
@@ -186,13 +188,13 @@ def tiny_parts(tmp_path_factory, tiny_params):
 
 def _mk_node(idx, stage, num_stages, *, parts, capacity=4):
     info = NodeInfo(
-        name=f"r{idx}", host="127.0.0.1", port=BASE + idx,
+        name=f"r{idx}", host="127.0.0.1", port=PORTS.http(idx),
         stage=stage, num_stages=num_stages, capacity=capacity,
         model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 100 + idx,
-        bootstrap=[("127.0.0.1", BASE + 100)] if idx else [],
+        info.node_id, PORTS.gossip(idx),
+        bootstrap=[("127.0.0.1", PORTS.gossip())] if idx else [],
         host="127.0.0.1", gossip_period_s=0.05, ttl_s=1.5,
     )
     return Node(
@@ -250,7 +252,7 @@ async def test_relay_follows_planned_route(tiny_params, tiny_parts):
                 break
             await asyncio.sleep(0.05)
         async with SwarmClient(
-            [("127.0.0.1", BASE)], sampling=GREEDY, prefill_chunk=4
+            [("127.0.0.1", PORTS.http())], sampling=GREEDY, prefill_chunk=4
         ) as c:
             got = await c.generate_ids(PROMPT, max_new_tokens=6)
         assert got == want
@@ -310,8 +312,8 @@ async def test_routed_client_mid_pass_spike_replans(tiny_params, tiny_parts):
         nodes[2].announce()
 
         obs = SwarmDHT(
-            "router-client", BASE + 99,
-            bootstrap=[("127.0.0.1", BASE + 100)],
+            "router-client", PORTS.gossip(99),
+            bootstrap=[("127.0.0.1", PORTS.gossip())],
             host="127.0.0.1", gossip_period_s=0.05, ttl_s=1.5,
         )
         await obs.start()
@@ -388,8 +390,8 @@ async def test_routed_client_empty_stage_raises(tiny_parts):
     try:
         await node.start()
         obs = SwarmDHT(
-            "router-client-2", BASE + 98,
-            bootstrap=[("127.0.0.1", BASE + 100)],
+            "router-client-2", PORTS.gossip(98),
+            bootstrap=[("127.0.0.1", PORTS.gossip())],
             host="127.0.0.1", gossip_period_s=0.05, ttl_s=1.5,
         )
         await obs.start()
@@ -433,8 +435,8 @@ async def test_routed_client_mid_session_failover_via_gossip(
     try:
         await _start_all(nodes)
         obs = SwarmDHT(
-            "router-failover-client", BASE + 98,
-            bootstrap=[("127.0.0.1", BASE + 100)],
+            "router-failover-client", PORTS.gossip(98),
+            bootstrap=[("127.0.0.1", PORTS.gossip())],
             host="127.0.0.1", gossip_period_s=0.05, ttl_s=1.5,
         )
         await obs.start()

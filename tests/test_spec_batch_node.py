@@ -18,7 +18,9 @@ from inferd_tpu.models import qwen3
 from inferd_tpu.parallel.stages import Manifest, split_and_save
 from inferd_tpu.runtime.node import Node, NodeInfo
 
-BASE = 18750  # distinct block from test_batch_node (18700)
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
 
 
 async def _start(node):
@@ -42,11 +44,11 @@ def whole_parts(tmp_path_factory):
 
 def _mk_node(idx, parts, lanes=4, draft_layers=2, k=3):
     info = NodeInfo(
-        name=f"sbn{idx}", host="127.0.0.1", port=BASE + idx,
+        name=f"sbn{idx}", host="127.0.0.1", port=PORTS.http(idx),
         stage=0, num_stages=1, capacity=8, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 100 + idx, bootstrap=[],
+        info.node_id, PORTS.gossip(idx), bootstrap=[],
         host="127.0.0.1", gossip_period_s=0.05, ttl_s=5.0,
     )
     return Node(
@@ -72,7 +74,7 @@ async def test_concurrent_generate_all_speculative_greedy_exact(whole_parts):
         want = [engine.generate(p, max_new_tokens=10) for p in prompts]
 
         async def one(p):
-            async with SwarmClient([("127.0.0.1", BASE)], sampling=sc) as c:
+            async with SwarmClient([("127.0.0.1", PORTS.http())], sampling=sc) as c:
                 return await c.generate_server_side(
                     p, max_new_tokens=10, return_payload=True
                 )
@@ -102,7 +104,7 @@ async def test_rounds_coalesce_across_sessions(whole_parts):
         sc = SamplingConfig(temperature=0.0)
 
         async def one(p):
-            async with SwarmClient([("127.0.0.1", BASE + 1)], sampling=sc) as c:
+            async with SwarmClient([("127.0.0.1", PORTS.http(1))], sampling=sc) as c:
                 return await c.generate_server_side(p, max_new_tokens=10)
 
         await asyncio.gather(*(one(p) for p in prompts))
@@ -135,7 +137,7 @@ async def test_streaming_speculative(whole_parts):
 
         async with aiohttp.ClientSession() as http:
             async with http.post(
-                f"http://127.0.0.1:{BASE + 2}/generate",
+                f"http://127.0.0.1:{PORTS.http(2)}/generate",
                 data=wire.pack({
                     "prompt_ids": prompt, "max_new_tokens": 10,
                     "sampling": {"temperature": 0.0}, "stream": True,
@@ -171,13 +173,13 @@ async def test_spec_and_regular_sessions_interleave(whole_parts):
 
         async def regular():
             async with SwarmClient(
-                [("127.0.0.1", BASE + 3)], sampling=sc
+                [("127.0.0.1", PORTS.http(3))], sampling=sc
             ) as c:
                 return await c.generate_ids(reg_prompt, max_new_tokens=12)
 
         async def spec():
             async with SwarmClient(
-                [("127.0.0.1", BASE + 3)], sampling=sc
+                [("127.0.0.1", PORTS.http(3))], sampling=sc
             ) as c:
                 return await c.generate_server_side([3, 7, 11], max_new_tokens=12)
 
@@ -202,7 +204,7 @@ async def test_sampled_spec_serving_deterministic_per_seed(whole_parts):
 
         async def one():
             async with SwarmClient(
-                [("127.0.0.1", BASE + 4)], sampling=sc
+                [("127.0.0.1", PORTS.http(4))], sampling=sc
             ) as c:
                 return await c.generate_server_side(
                     [3, 7, 11], max_new_tokens=12, seed=5,
@@ -232,7 +234,7 @@ async def test_capacity_cap_and_fallback(whole_parts):
         from inferd_tpu.client.base import ServerError
 
         sc = SamplingConfig(temperature=0.0)
-        async with SwarmClient([("127.0.0.1", BASE + 5)], sampling=sc) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(5))], sampling=sc) as c:
             with pytest.raises(ServerError):
                 await c.generate_server_side(
                     list(range(1, 51)), max_new_tokens=20
@@ -260,7 +262,7 @@ async def test_streaming_solo_spec_node(whole_parts):
 
     parts, params = whole_parts
     # no batch_lanes: the stage executor hosts the whole 1-stage model
-    info_port = BASE + 30
+    info_port = PORTS.http(30)
     from inferd_tpu.runtime.node import Node, NodeInfo
 
     info = NodeInfo(
@@ -268,7 +270,7 @@ async def test_streaming_solo_spec_node(whole_parts):
         stage=0, num_stages=1, capacity=8, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 130, bootstrap=[],
+        info.node_id, PORTS.gossip(30), bootstrap=[],
         host="127.0.0.1", gossip_period_s=0.05, ttl_s=5.0,
     )
     node = Node(
@@ -315,7 +317,7 @@ async def test_forward_overflow_at_spec_cap(whole_parts):
     await _start(node)
     try:
         sc = SamplingConfig(temperature=0.0)
-        async with SwarmClient([("127.0.0.1", BASE + 6)], sampling=sc) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(6))], sampling=sc) as c:
             with pytest.raises(ServerError) as ei:
                 # 59-token prompt + 2 new: the second decode step would
                 # write past cap=60
@@ -346,7 +348,7 @@ async def test_pinned_prefix_composes_with_spec(whole_parts):
         want_full = engine.generate(full, max_new_tokens=10)
         want_pfx = engine.generate(prefix, max_new_tokens=10)
 
-        async with SwarmClient([("127.0.0.1", BASE + 7)], sampling=sc) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(7))], sampling=sc) as c:
             p1 = await c.generate_server_side(
                 full, max_new_tokens=10, pin_prefix_len=len(prefix),
                 return_payload=True,
@@ -386,7 +388,7 @@ async def test_greedy_logprobs_ride_the_lane_spec_path(whole_parts):
         want = engine.generate(
             prompt, max_new_tokens=10, logprob_sink=want_lps
         )
-        async with SwarmClient([("127.0.0.1", BASE + 8)], sampling=sc) as c:
+        async with SwarmClient([("127.0.0.1", PORTS.http(8))], sampling=sc) as c:
             lps = []
             tops = []
             p = await c.generate_server_side(
@@ -430,7 +432,7 @@ async def test_spec_serving_mixed_load_soak(whole_parts):
         prefix = [3, 7, 11, 13]
         want_pin = engine.generate(prefix + [9], max_new_tokens=8)
 
-        entry = [("127.0.0.1", BASE + 9)]
+        entry = [("127.0.0.1", PORTS.http(9))]
 
         async def retry503(fn):
             # 503 = documented retryable backpressure (all lanes busy with
@@ -487,7 +489,7 @@ async def test_spec_serving_mixed_load_soak(whole_parts):
             for attempt in range(12):
                 async with aiohttp.ClientSession() as http:
                     async with http.post(
-                        f"http://127.0.0.1:{BASE + 9}/generate",
+                        f"http://127.0.0.1:{PORTS.http(9)}/generate",
                         data=wire.pack({
                             "prompt_ids": p, "max_new_tokens": 8,
                             "sampling": {"temperature": 0.0}, "stream": True,

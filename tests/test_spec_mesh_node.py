@@ -20,7 +20,9 @@ from inferd_tpu.parallel.stages import Manifest, split_and_save
 from inferd_tpu.runtime.node import Node, NodeInfo
 
 
-BASE = 18800
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
 GREEDY = SamplingConfig(temperature=0.0)
 
 
@@ -34,11 +36,11 @@ def mesh_parts(tmp_path_factory):
 
 def _mk_node(idx, parts, pp=2, slots=3, max_len=64, draft_layers=2, k=3):
     info = NodeInfo(
-        name=f"sm{idx}", host="127.0.0.1", port=BASE + idx,
+        name=f"sm{idx}", host="127.0.0.1", port=PORTS.http(idx),
         stage=0, num_stages=1, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 100 + idx, bootstrap=[],
+        info.node_id, PORTS.gossip(idx), bootstrap=[],
         host="127.0.0.1", gossip_period_s=0.05, ttl_s=5.0,
     )
     return Node(
@@ -72,7 +74,7 @@ async def test_mesh_concurrent_generate_speculative_exact(
 
         async def one(p):
             async with SwarmClient(
-                [("127.0.0.1", BASE)], sampling=GREEDY
+                [("127.0.0.1", PORTS.http())], sampling=GREEDY
             ) as c:
                 return await c.generate_server_side(
                     p, max_new_tokens=10, return_payload=True
@@ -106,13 +108,13 @@ async def test_mesh_spec_and_regular_sessions_interleave(
 
         async def regular():
             async with SwarmClient(
-                [("127.0.0.1", BASE + 1)], sampling=GREEDY
+                [("127.0.0.1", PORTS.http(1))], sampling=GREEDY
             ) as c:
                 return await c.generate_ids(reg_prompt, max_new_tokens=10)
 
         async def spec():
             async with SwarmClient(
-                [("127.0.0.1", BASE + 1)], sampling=GREEDY
+                [("127.0.0.1", PORTS.http(1))], sampling=GREEDY
             ) as c:
                 return await c.generate_server_side(
                     [3, 7, 11], max_new_tokens=10
@@ -135,7 +137,7 @@ async def test_mesh_sampled_spec_deterministic(mesh_parts, devices8):
 
         async def one():
             async with SwarmClient(
-                [("127.0.0.1", BASE + 2)], sampling=sc
+                [("127.0.0.1", PORTS.http(2))], sampling=sc
             ) as c:
                 return await c.generate_server_side(
                     [3, 7, 11], max_new_tokens=10, seed=5,
@@ -164,7 +166,7 @@ async def test_mesh_pinned_prefix_composes_with_spec(mesh_parts, devices8):
         full = prefix + [2, 5]
         want = engine.generate(full, max_new_tokens=8)
         async with SwarmClient(
-            [("127.0.0.1", BASE + 3)], sampling=GREEDY
+            [("127.0.0.1", PORTS.http(3))], sampling=GREEDY
         ) as c:
             p = await c.generate_server_side(
                 full, max_new_tokens=8, pin_prefix_len=len(prefix),

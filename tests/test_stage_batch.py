@@ -592,7 +592,9 @@ def test_stage_batch_dispatch_failure_is_isolated(single_stage_setup):
 # Node e2e: 2-stage swarm, concurrent sessions, coalesced relay
 # ---------------------------------------------------------------------------
 
-BASE = 18700
+from conftest import port_block  # noqa: E402
+
+PORTS = port_block(__file__)
 
 
 def _mk_node(idx, stage, parts, bootstrap_idx, lanes=8, window_ms=10.0):
@@ -601,13 +603,13 @@ def _mk_node(idx, stage, parts, bootstrap_idx, lanes=8, window_ms=10.0):
     from inferd_tpu.runtime.node import Node, NodeInfo
 
     info = NodeInfo(
-        name=f"n{idx}", host="127.0.0.1", port=BASE + idx, stage=stage,
+        name=f"n{idx}", host="127.0.0.1", port=PORTS.http(idx), stage=stage,
         num_stages=2, capacity=16, model_name="tiny",
     )
     dht = SwarmDHT(
-        info.node_id, BASE + 100 + idx,
+        info.node_id, PORTS.gossip(idx),
         bootstrap=(
-            [("127.0.0.1", BASE + 100 + bootstrap_idx)]
+            [("127.0.0.1", PORTS.gossip(bootstrap_idx))]
             if idx != bootstrap_idx else []
         ),
         host="127.0.0.1", gossip_period_s=0.05, ttl_s=1.5,
@@ -667,7 +669,7 @@ async def test_swarm_cobatch_token_exact_e2e(tiny_parts):
         ]
         budgets = [3 + i % 5 for i in range(len(prompts))]
         async with SwarmClient(
-            [("127.0.0.1", BASE + 0)],
+            [("127.0.0.1", PORTS.http(0))],
             sampling=SamplingConfig(temperature=0.0),
         ) as c:
             outs = await asyncio.gather(*(
@@ -701,7 +703,7 @@ async def test_swarm_cobatch_token_exact_e2e(tiny_parts):
         from inferd_tpu.obs.export import validate_exposition
 
         async with aiohttp.ClientSession() as s:
-            async with s.get(f"http://127.0.0.1:{BASE}/metrics") as r:
+            async with s.get(f"http://127.0.0.1:{PORTS.http()}/metrics") as r:
                 text = await r.text()
         assert r.status == 200
         validate_exposition(text)
@@ -750,7 +752,7 @@ async def test_swarm_chain_mode_cobatch_no_relay(tiny_parts):
         )
         prompts = [[3, 7, 11, 19], [5, 2], [9, 9, 4]]
         async with ChainClient(
-            [("127.0.0.1", BASE + 10), ("127.0.0.1", BASE + 11)],
+            [("127.0.0.1", PORTS.http(10)), ("127.0.0.1", PORTS.http(11))],
             sampling=SamplingConfig(temperature=0.0),
         ) as c:
             outs = await asyncio.gather(*(

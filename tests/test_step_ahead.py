@@ -63,8 +63,10 @@ def _executor(model, **kw):
 
     ex = MeshExecutor(cfg, _made[model], MeshPlan(pp=2), num_slots=kw["lanes"],
                       max_len=kw["max_len"], devices=jax.devices()[:2])
-    # the engine's programs are jitted closures of their own: a fresh engine
-    # of the same shapes takes the first one's, compiled once a file
+    # the MESH engine's programs are still jitted closures of an instance
+    # (ROADMAP.md D18: `PipelinedEngine`): a fresh engine of the same shapes
+    # takes the first one's, compiled once a file. The lanes need nothing:
+    # core.batch.lane_programs makes theirs once a configuration.
     first = _made.setdefault(("programs", model, kw["lanes"], kw["max_len"]), ex.engine)
     for name in ("_step_raw", "_step_raw_multi", "_fork_slot", "_feed"):
         setattr(ex.engine, name, getattr(first, name))
@@ -555,6 +557,33 @@ def test_every_other_path_runs_no_row_ahead(path):
     s = ex.stats()
     assert s["ahead_rows"] == s["ahead_claimed"] == s["ahead_dropped"] == 0
     assert not ex._ahead
+
+
+@pytest.mark.parametrize("lanes", ["dense", "paged"])
+def test_a_row_dispatch_that_raises_fails_its_own_entries_only(lanes):
+    """A one-token step that dies before it is dispatched (`_decode_ahead`
+    on dense lanes, `_decode_dispatch` on paged ones) fails the hops of ITS
+    drain: nothing moved on the lane, no token is counted as served, and
+    the same hop, sent again, is served as if nothing had been."""
+    kw = {"block_size": 16, "kv_blocks": 24} if lanes == "paged" else {}
+    name = "_decode_logits_paged" if lanes == "paged" else "_decode_logits"
+    ex = _executor("tiny", **kw)
+    real = getattr(ex.engine, name)
+
+    def boom(*a, **k):
+        setattr(ex.engine, name, real)  # once
+        raise RuntimeError("injected dispatch failure")
+
+    s = Session(ex, "s", ahead=False).run(hops=2)
+    before = ex.stats()
+    setattr(ex.engine, name, boom)
+    with pytest.raises(RuntimeError, match="injected dispatch failure"):
+        s.hop()
+    after = ex.stats()
+    assert _length(ex, "s") == s.pos and ex._inflight == {}
+    assert after["batched_tokens"] == before["batched_tokens"]
+    assert s.run().end().out == _fresh_stream("tiny", PROMPT)
+    assert _at_rest(ex) == 0
 
 
 def test_the_ask_carries_the_budget():

@@ -7,6 +7,7 @@ acceptance (a slowed stage replica is flagged, routed around, and shows
 up in `obs fleet` output assembled from per-node artifacts alone)."""
 
 import asyncio
+import functools
 import json
 import os
 
@@ -19,7 +20,12 @@ from inferd_tpu.obs import tsdb as tsdblib
 from inferd_tpu.obs.__main__ import main as obs_main
 from inferd_tpu.utils.metrics import Metrics
 
-from test_node_e2e import BASE, _mk_node, _start_all, _stop_all, tiny_parts  # noqa: F401
+import test_node_e2e as e2e
+from conftest import port_block
+from test_node_e2e import _start_all, _stop_all, tiny_parts  # noqa: F401
+
+PORTS = port_block(__file__)
+_mk_node = functools.partial(e2e._mk_node, ports=PORTS)  # its nodes, this module's ports
 
 FLEET_FIXTURE = os.path.join(os.path.dirname(__file__), "data", "fleet")
 BURN_FIXTURE = os.path.join(os.path.dirname(__file__), "data", "health_burn")
@@ -751,7 +757,7 @@ async def test_metrics_history_endpoint_schema(tiny_parts):  # noqa: F811
     try:
         async with aiohttp.ClientSession() as s:
             async with s.get(
-                f"http://127.0.0.1:{BASE + 131}/metrics/history"
+                f"http://127.0.0.1:{PORTS.http(131)}/metrics/history"
             ) as r:
                 assert r.status == 200
                 h = await r.json()
@@ -809,7 +815,7 @@ async def test_outlier_flagging_routing_and_fleet_report(
 
         # chain warmup: compiles the entry's token buckets + self-client
         async with SwarmClient(
-            [("127.0.0.1", BASE + 140)],
+            [("127.0.0.1", PORTS.http(140))],
             sampling=SamplingConfig(temperature=0.0),
         ) as c:
             await c.generate_ids([3, 7, 11, 19], max_new_tokens=2)
@@ -888,7 +894,7 @@ async def test_outlier_flagging_routing_and_fleet_report(
 
         # canary probes through the (healthy remainder of the) fleet
         prober = canarylib.CanaryProber(
-            lambda: [("127.0.0.1", BASE + 140)], nodes[0].metrics,
+            lambda: [("127.0.0.1", PORTS.http(140))], nodes[0].metrics,
             journal=nodes[0].journal, tracer=nodes[0].tracer,
             interval_s=60.0, timeout_s=60.0,
         )
@@ -910,7 +916,7 @@ async def test_outlier_flagging_routing_and_fleet_report(
 
         # a real user request still completes, routed around the outlier
         async with SwarmClient(
-            [("127.0.0.1", BASE + 140)],
+            [("127.0.0.1", PORTS.http(140))],
             sampling=SamplingConfig(temperature=0.0),
         ) as c:
             out = await c.generate_ids([3, 7, 11, 19], max_new_tokens=4)
